@@ -17,6 +17,20 @@ executor.py -- one jitted computation per training step, not per-op kernel
 dispatch. Data parallelism is GSPMD sharding over a jax Mesh
 (parallel_executor.py), not threaded op handles + NCCL.
 """
+import os as _os
+
+import jax as _jax
+
+# The persistent XLA compile cache, placed from outside. JAX reads
+# JAX_COMPILATION_CACHE_DIR itself, so when it is set nothing is set
+# here; otherwise the cache sits at a fixed path beside the package
+# (the path is part of the cache key: a directory that moves never
+# hits). This is the only place the repository sets it.
+if 'JAX_COMPILATION_CACHE_DIR' not in _os.environ:
+    _jax.config.update('jax_compilation_cache_dir', _os.path.join(
+        _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+        '.jax_cache'))
+
 from . import ops            # registers all operators (import side effect)
 from . import framework
 from .framework import (Program, Block, Operator, Variable, Parameter,

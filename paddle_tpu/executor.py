@@ -88,7 +88,11 @@ class Place(object):
     def jax_device(self):
         devs = (jax.devices(self.platform) if self.platform
                 else jax.devices())
-        return devs[self.device_id % len(devs)]
+        if not 0 <= self.device_id < len(devs):
+            raise IndexError(
+                '%r: JAX sees %d %s device(s)'
+                % (self, len(devs), devs[0].platform))
+        return devs[self.device_id]
 
     def __repr__(self):
         return '%s(%d)' % (type(self).__name__, self.device_id)
@@ -106,9 +110,10 @@ class CPUPlace(Place):
 
 
 class TPUPlace(Place):
-    """The default-accelerator place: whatever JAX's default backend is
-    (TPU on hardware, CPU elsewhere) -- the analog of fluid.CUDAPlace and
-    the north star's fluid.XLAPlace."""
+    """A device of JAX's default backend -- the analog of fluid.CUDAPlace
+    and the north star's fluid.XLAPlace. JAX itself falls back to the CPU
+    when it finds no accelerator, so this place does not prove a chip:
+    entry points that measure one call obs.perf.require_tpu() first."""
     platform = None
 
 
@@ -523,19 +528,18 @@ class Executor(object):
                 'segment_misses': self._segment_misses}
 
     def compiled_hlo_texts(self):
-        """Optimized-HLO text of each compiled device segment (re-lowered
-        from the stashed abstract arg signature; hits the jit cache)."""
+        """Optimized-HLO text of each compiled device segment, re-lowered
+        from the stashed abstract arg signature. A segment that fails to
+        re-lower raises: callers assert on what the text contains, and a
+        dropped segment would read as a pass."""
         texts = []
         for prepared in self._prepared_cache.values():
             for step in prepared.steps:
                 if isinstance(step, _DeviceSegment) \
                         and step.jitted is not None \
                         and step._arg_struct is not None:
-                    try:
-                        texts.append(step.jitted.lower(*step._arg_struct)
-                                     .compile().as_text())
-                    except Exception:
-                        pass
+                    texts.append(step.jitted.lower(*step._arg_struct)
+                                 .compile().as_text())
         return texts
 
     @property
@@ -617,9 +621,8 @@ class Executor(object):
                                     scope, program)
         self._step += 1
         if return_numpy:
-            # the host fetch below IS the device sync (PERF.md: the one
-            # reliable barrier on the remoted transport) — stamp the
-            # step after it so perf.step_latency covers real work
+            # the host fetch below is the device sync — stamp the step
+            # after it so perf.step_latency covers real work
             result = [self._to_numpy(r) for r in result]
             if t0_perf is not None:
                 _perf.step_end(t0_perf, prepared, device=self.device,
@@ -656,10 +659,9 @@ class Executor(object):
             # written by host ops (load_inference_model's load ops, set
             # vars) arrive as numpy; without this, every run() of a
             # program that only READS them (inference!) re-uploads all
-            # parameters through the transport — measured 5 s/call for
-            # ResNet-50 and minutes for a 740M-param LM over the
-            # remoted link (reference analog: parameters live on-device
-            # in the Scope, framework/tensor.h holder semantics).
+            # parameters host-to-device (reference analog: parameters
+            # live on-device in the Scope, framework/tensor.h holder
+            # semantics).
             # (64-bit dtypes excluded: with x64 off, device_put would
             # narrow them and the narrowed array would leak back into
             # host-side save paths)
@@ -688,6 +690,13 @@ class Executor(object):
                     if _passthrough_exception(e):
                         raise
                     raise _wrap_op_error(e, step.op, block) from e
+                if step.op.type == 'read':
+                    # a py_reader batch is a feed: the reader's placer
+                    # thread put it on one device, the executor places
+                    # it where its step wants it (sharded over dp under
+                    # a ParallelExecutor mesh; already there otherwise)
+                    for name in step.op.output('Out'):
+                        local[name] = self._put_feed(name, local[name])
                 continue
 
             donated = {}
@@ -725,13 +734,15 @@ class Executor(object):
                     self._segment_hits += 1
                     _perf.jit_cache_hit()
                 if getattr(step, '_arg_struct', None) is None:
-                    # abstract arg signature kept so the profiler can
-                    # re-lower this segment and read the compiled HLO
-                    # (instr -> op_name metadata join; profiler.py)
+                    # abstract arg signature (with each array's
+                    # sharding, so a mesh step re-lowers to the program
+                    # that ran) kept so compiled_hlo_texts and the
+                    # profiler can read the compiled HLO
                     step._arg_struct = jax.tree.map(
                         lambda a: jax.ShapeDtypeStruct(
                             np.shape(a), getattr(a, 'dtype', None)
-                            or np.asarray(a).dtype),
+                            or np.asarray(a).dtype,
+                            sharding=getattr(a, 'sharding', None)),
                         (donated, const, key_arg))
                 if fresh_compile and (_perf.enabled()
                                       or _trace.enabled()):

@@ -138,16 +138,10 @@ Known flags:
   mesh_shape             MeshConfig.from_flags axis spec, e.g.
                          'dp=2,tp=2' ('' = pure data parallelism over
                          every local device)
-  perf_sync_steps        block_until_ready un-fetched Executor.run
-                         results before stamping perf.step_latency
-                         (obs/perf.py). Default on; disable on the
-                         remoted transport where block_until_ready is
-                         unreliable (PERF.md) and a return_numpy fetch
-                         or async window should time steps instead
   perf_peak_tflops       peak dense bf16 TFLOP/s used as the perf.mfu
-                         denominator (0 = auto from the TPU device-kind
-                         table; must be set explicitly for nonzero MFU
-                         on CPU/GPU backends)
+                         denominator (0 = the exact-device_kind table
+                         in obs/perf.py, which knows TPUs only; off-TPU
+                         the gauge stays unset unless this pins a peak)
   slo_rules              declarative SLO rule list for obs/slo.py —
                          inline JSON (list of {name, metric, kind,
                          threshold[, min_count]}) or @/path/rules.json
@@ -407,14 +401,8 @@ _DEFAULTS = {
     'obs_dir': '',
     'obs_role': '',
     'obs_flush_secs': 2.0,
-    # perf observatory (obs/perf.py): block_until_ready un-fetched run
-    # results before stamping perf.step_latency (disable on the remoted
-    # transport, where block_until_ready is documented-unreliable —
-    # PERF.md — and throughput should be measured over an async
-    # window); peak dense bf16 TFLOP/s override for the perf.mfu
-    # denominator (0 = look up the TPU device-kind table; set
-    # explicitly on CPU/GPU backends)
-    'perf_sync_steps': True,
+    # perf observatory (obs/perf.py): peak dense bf16 TFLOP/s override
+    # for the perf.mfu denominator (0 = the device_kind table)
     'perf_peak_tflops': 0.0,
     # SLO watchdog (obs/slo.py): declarative rule list — inline JSON or
     # @/path/rules.json ('' = off); evaluation cadence in seconds.

@@ -14,13 +14,9 @@ the device. The Executor's JIT pipeline reports into it at two points:
 - step time (`step_begin` / `step_end`) — wall latency of each
   `Executor.run` call lands in the `perf.step_latency` histogram, and
   combined with the compile-time FLOP count yields
-  `perf.achieved_tflops` and `perf.mfu` gauges. Timing follows the
-  PERF.md discipline: a `return_numpy=True` fetch has already
-  synchronized through the host transfer, otherwise we
-  `block_until_ready` the fetched arrays first (disable with
-  `FLAGS_perf_sync_steps=0` on the remoted transport, where
-  block_until_ready is documented-unreliable and throughput should be
-  measured over an async window instead).
+  `perf.achieved_tflops` and `perf.mfu` gauges. A `return_numpy=True`
+  fetch has already synchronized through the host transfer; otherwise
+  the fetched arrays are `block_until_ready`'d before the clock stops.
 
 Every hook is a no-op while telemetry is disabled — same
 one-global-bool fast path as the rest of the registry — so the
@@ -31,9 +27,11 @@ lower().compile() does not warm jax's call cache), which doubles a
 once-per-program cost. That is why it is gated on telemetry being
 enabled rather than free-running.
 
-MFU needs a peak-FLOPs denominator: on TPU it is looked up from the
-device kind (same table as bench.py); elsewhere — and in CPU tests —
-set `FLAGS_perf_peak_tflops` to pin it explicitly.
+MFU needs a peak-FLOPs denominator: PEAK_BF16_FLOPS below, keyed by
+the exact `device_kind` (the one table; bench.py and chip_smoke.py
+import it). A kind that is not in it raises. Off-TPU there is no peak,
+so `perf.mfu` is set only when `FLAGS_perf_peak_tflops` pins one (CPU
+tests).
 
 HBM gauges (`hbm.bytes_in_use`, `hbm.peak_bytes`, `hbm.bytes_limit`,
 `hbm.scope_bytes`, `hbm.watermark_bytes`) are refreshed on every
@@ -51,7 +49,8 @@ from .. import flags
 
 __all__ = ['enabled', 'step_begin', 'step_end', 'jit_cache_hit',
            'jit_cache_miss', 'record_compile', 'segment_cost',
-           'device_peak_flops', 'update_hbm', 'compile_span']
+           'PEAK_BF16_FLOPS', 'device_peak_flops', 'describe_device',
+           'require_tpu', 'update_hbm', 'compile_span']
 
 # --- instruments (registered at import; zero until enabled) ---------
 _compile_latency = telemetry.histogram('xla.compile_latency')
@@ -70,13 +69,16 @@ _hbm_watermark = telemetry.gauge('hbm.watermark_bytes')
 _watermark = 0          # process-local high-water of bytes_in_use
 _slo_started = False    # lazy FLAGS_slo_rules watchdog, armed once
 
-# Dense peak bf16 FLOP/s by device kind prefix (same table bench.py
-# uses for its MFU math; longest-prefix match on device.device_kind).
-_PEAK_BF16 = {
-    'TPU v4': 275e12,
-    'TPU v5 lite': 197e12,
-    'TPU v5': 459e12,
-    'TPU v6 lite': 918e12,
+# Peak dense bf16 FLOP/s of one chip, keyed by the exact
+# jax.Device.device_kind. Source of every figure: Google Cloud TPU
+# documentation, the system-architecture page of the generation named
+# in the comment. Only 'TPU v5 lite' has been seen on hardware by this
+# repository (chip_smoke.py).
+PEAK_BF16_FLOPS = {
+    'TPU v4': 275e12,        # "TPU v4"
+    'TPU v5 lite': 197e12,   # "TPU v5e"
+    'TPU v5': 459e12,        # "TPU v5p"
+    'TPU v6 lite': 918e12,   # "TPU v6e" (Trillium)
 }
 
 
@@ -84,22 +86,61 @@ def enabled():
     return telemetry._enabled
 
 
-def device_peak_flops(device=None):
-    """Peak dense bf16 FLOP/s for MFU attribution: the
+def _pinned_peak_flops():
+    """FLAGS_perf_peak_tflops in FLOP/s, or 0.0 when it pins nothing."""
+    return max(float(flags.get_flag('perf_peak_tflops', 0.0)), 0.0) * 1e12
+
+
+def device_peak_flops(device):
+    """Peak dense bf16 FLOP/s of `device` for MFU attribution: the
     FLAGS_perf_peak_tflops override if set (TFLOP/s; the only way to
-    get a nonzero MFU on CPU), else the device-kind table, else 0.0
-    (MFU gauge stays unset)."""
-    override = float(flags.get_flag('perf_peak_tflops', 0.0))
-    if override > 0.0:
-        return override * 1e12
-    if device is None:
-        return 0.0
-    kind = getattr(device, 'device_kind', '') or ''
-    best, best_len = 0.0, -1
-    for prefix, peak in _PEAK_BF16.items():
-        if kind.startswith(prefix) and len(prefix) > best_len:
-            best, best_len = peak, len(prefix)
-    return best
+    get an MFU off-TPU), else the PEAK_BF16_FLOPS entry of its exact
+    device_kind. An unknown kind raises: a guessed peak makes every MFU
+    computed from it wrong without saying so."""
+    pinned = _pinned_peak_flops()
+    if pinned:
+        return pinned
+    kind = device.device_kind
+    if kind not in PEAK_BF16_FLOPS:
+        raise ValueError(
+            'no peak bf16 FLOP/s known for device_kind %r (platform %r); '
+            'known kinds: %s. Add it to obs/perf.py PEAK_BF16_FLOPS with '
+            'its source, or pin FLAGS_perf_peak_tflops.'
+            % (kind, device.platform, sorted(PEAK_BF16_FLOPS)))
+    return PEAK_BF16_FLOPS[kind]
+
+
+def describe_device():
+    """{'platform', 'device_kind', 'n_devices'} as JAX reports the
+    default backend — stamped on every row a measurement prints."""
+    import jax
+    devs = jax.devices()
+    return {'platform': devs[0].platform,
+            'device_kind': devs[0].device_kind,
+            'n_devices': len(devs)}
+
+
+def require_tpu(min_devices=1):
+    """For entry points that measure the chip (chip_smoke.py, bench.py,
+    the --full tools): raise unless JAX's default backend is a TPU
+    whose device_kind is in PEAK_BF16_FLOPS, with at least min_devices
+    visible. JAX falls back to the CPU without an error when it finds
+    no accelerator; a measurement must not. Returns describe_device()."""
+    dev = describe_device()
+    if dev['platform'] != 'tpu':
+        raise RuntimeError(
+            'this entry point needs a TPU; JAX found platform=%r '
+            'device_kind=%r (%d device(s))'
+            % (dev['platform'], dev['device_kind'], dev['n_devices']))
+    if dev['device_kind'] not in PEAK_BF16_FLOPS:
+        raise RuntimeError(
+            'TPU device_kind %r is not in obs/perf.py PEAK_BF16_FLOPS '
+            '(known: %s)' % (dev['device_kind'], sorted(PEAK_BF16_FLOPS)))
+    if dev['n_devices'] < min_devices:
+        raise RuntimeError(
+            'this run needs %d TPU devices; JAX sees %d'
+            % (min_devices, dev['n_devices']))
+    return dev
 
 
 # --- compile-time hooks ---------------------------------------------
@@ -183,14 +224,9 @@ def step_end(t0, prepared=None, device=None, scope=None, sync=None):
     means the host fetch already synchronized."""
     if t0 is None or not telemetry._enabled:
         return
-    if sync is not None and flags.get_flag('perf_sync_steps', True):
-        try:
-            import jax
-            jax.block_until_ready(
-                [r for r in sync if r is not None
-                 and hasattr(r, 'block_until_ready')])
-        except Exception:
-            pass
+    if sync is not None:
+        import jax
+        jax.block_until_ready(sync)
     dt = time.perf_counter() - t0
     _step_latency.observe(dt)
     _steps.inc()
@@ -198,9 +234,10 @@ def step_end(t0, prepared=None, device=None, scope=None, sync=None):
     if dt > 0.0 and flops > 0.0:
         achieved = flops / dt
         _achieved_tflops.set(achieved / 1e12)
-        peak = device_peak_flops(device)
-        if peak > 0.0:
-            _mfu.set(achieved / peak)
+        # the peak table knows TPUs only: off-TPU the gauge is set only
+        # against a pinned FLAGS_perf_peak_tflops
+        if device.platform == 'tpu' or _pinned_peak_flops():
+            _mfu.set(achieved / device_peak_flops(device))
     update_hbm(device=device, scope=scope)
     _maybe_start_slo()
 

@@ -11,6 +11,8 @@ feeds, and validity is expressed as masking (the beam-search lattice
 idiom), never as a dynamic shape."""
 from __future__ import annotations
 
+import functools
+
 import jax.numpy as jnp
 
 from ..registry import register_op, op_emitter, register_vjp_grad, \
@@ -56,21 +58,38 @@ register_vjp_grad('ring_attention', in_slots=('Q', 'K', 'V'))
 
 @op_emitter('flash_attention')
 def _flash_attention_emit(ctx, op):
-    """Single-device flash attention (paddle_tpu/pallas/flash_attention
-    — blockwise online-softmax kernel; measured on v5e: 2.1x over the
-    naive XLA contraction at T=4k and the only path that runs at
-    T>=8k, where the [T, T] score tensor exceeds HBM)."""
+    """Flash attention over the full sequence on each device
+    (paddle_tpu/pallas/flash_attention — blockwise online-softmax
+    kernel; measured on v5e: 2.1x over the naive XLA contraction at
+    T=4k and the only path that runs at T>=8k, where the [T, T] score
+    tensor exceeds HBM).
+
+    Under a multi-device mesh the kernel runs per shard: JAX refuses to
+    lower a bare Mosaic call there ("Mosaic kernels cannot be
+    automatically partitioned"), and attention is independent per
+    (batch, head), so the batch splits over dp and the heads over tp
+    exactly as the ring op's operands do. A sequence sharded over sp is
+    gathered to full length here; ring_attention is the op for that."""
     from ..pallas.flash_attention import flash_attention as _fa
     from ..flags import get_flag
     q = ctx.get(op.single_input('Q'))
     k = ctx.get(op.single_input('K'))
     v = ctx.get(op.single_input('V'))
     q, k, v = amp_cast(ctx, q, k, v)
-    causal = op.attr('causal', True)
-    sm_scale = op.attr('sm_scale', None)
-    out = _fa(q, k, v, causal=causal, sm_scale=sm_scale,
-              force_naive=not get_flag('use_flash_attention'))
-    ctx.set(op.single_output('Out'), out)
+    fa = functools.partial(
+        _fa, causal=op.attr('causal', True),
+        sm_scale=op.attr('sm_scale', None),
+        force_naive=not get_flag('use_flash_attention'))
+    mesh = getattr(ctx, 'mesh', None)
+    if mesh is not None and mesh.size > 1:
+        from jax import shard_map
+        from ..parallel.ring_attention import _ring_spec
+        spec, _ = _ring_spec(mesh, q, None, 'dp', 'tp')
+        # check_vma=False: pallas_call outputs carry no varying-mesh-
+        # axes annotation (as in ring_flash_attention_global)
+        fa = shard_map(fa, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
+    ctx.set(op.single_output('Out'), fa(q, k, v))
 
 
 register_op('flash_attention', infer_shape=_ring_infer)

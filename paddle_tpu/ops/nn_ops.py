@@ -281,23 +281,14 @@ def _bn_local_mode(ctx, op):
 def _bn_shard_map(ctx, fn, n_big, n_small, out_specs):
     """shard_map wrapper for the local-stats paths: the first n_big args
     are batch-dim-sharded activations, the rest are replicated channel
-    vectors. check_rep=False because per-device statistics outputs are
+    vectors. check_vma=False because per-device statistics outputs are
     deliberately divergent across devices (reference per-device BN
     state)."""
-    import inspect
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:                       # older jax
-        from jax.experimental.shard_map import shard_map
-    # the replication-check kwarg was renamed check_rep -> check_vma;
-    # probe the signature rather than the import path
-    sig = inspect.signature(shard_map).parameters
-    kw = ({'check_vma': False} if 'check_vma' in sig
-          else {'check_rep': False})
     in_specs = tuple([P('dp')] * n_big + [P()] * n_small)
     return shard_map(fn, mesh=ctx.mesh, in_specs=in_specs,
-                     out_specs=out_specs, **kw)
+                     out_specs=out_specs, check_vma=False)
 
 
 @op_emitter('batch_norm')

@@ -52,14 +52,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both so the
-# kernels (and their interpret-mode CI) run on either side of the rename
-_CompilerParams = getattr(pltpu, 'CompilerParams', None) \
-    or getattr(pltpu, 'TPUCompilerParams')
+from ..obs import telemetry as _tm
 
 __all__ = ['flash_attention']
 
 _NEG_INF = -1e30
+
+# Which route flash_attention() took, bumped once per trace (the Python
+# body of a jitted caller runs only while tracing): a shape that misses
+# the kernel shows up in `pallas.flash.naive` instead of running the
+# [T, T] contraction under a flash label.
+_ROUTE_KERNEL = _tm.counter('pallas.flash.kernel')
+_ROUTE_NAIVE = _tm.counter('pallas.flash.naive')
 
 # Backward-arm selection. Three arms, all grad-parity-tested:
 #   split    — dq kernel + dk/dv kernel (7 block-matmuls, 2 exp streams)
@@ -705,7 +709,7 @@ def _fwd_online(q, k, v, causal, sm_scale, interpret, bq, bk, nq, nk):
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary')),
         interpret=interpret,
     )(q, k, v)
@@ -741,7 +745,7 @@ def _fwd_twopass(q, k, v, causal, sm_scale, interpret, bq, bk, nq, nk):
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary')),
         interpret=interpret,
     )(q, k)
@@ -770,7 +774,7 @@ def _fwd_twopass(q, k, v, causal, sm_scale, interpret, bq, bk, nq, nk):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((BH, T, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(**params),
+        compiler_params=pltpu.CompilerParams(**params),
         interpret=interpret,
     )(q, k, v, lse)
     return o, lse
@@ -847,7 +851,7 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, interpret=False):
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
                         pltpu.VMEM((nk, bk, d), jnp.float32),
                         pltpu.VMEM((nk, bk, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'arbitrary', 'arbitrary'),
             # T=8192/d=128 needs ~18 MB (8 MB fp32 accumulators + 4 MB
             # resident outputs + double-buffered blocks) — above the
@@ -890,7 +894,7 @@ def _bwd_split(q, k, v, do, lse, delta, causal, sm_scale, interpret,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((BH, T, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary')),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -925,7 +929,7 @@ def _bwd_split(q, k, v, do, lse, delta, causal, sm_scale, interpret,
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary')),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -982,7 +986,7 @@ def _bwd_kvmajor(q, k, v, do, lse, delta, causal, sm_scale, interpret,
         scratch_shapes=[pltpu.VMEM((nq, bq, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'arbitrary', 'arbitrary'),
             vmem_limit_bytes=_kvmajor_vmem_bytes(
                 T, d, bq, bk, q.dtype.itemsize)),
@@ -1018,12 +1022,14 @@ def _supported(T, d):
 def flash_attention(q, k, v, causal=True, sm_scale=None,
                     force_naive=False):
     """softmax(q·kᵀ·scale [+ causal mask])·v without materializing the
-    [T, T] scores. q, k, v: [B, H, T, d] (or [BH, T, d]). Falls back to
-    the naive XLA contraction for shapes the kernel does not tile
-    (T or d not lane-aligned), on non-TPU backends (interpret mode
-    covers CPU tests via the pallas_interpret flag), and when
-    force_naive is set (the FLAGS_use_flash_attention=false path —
-    same entry point so both flag states accept the same layouts)."""
+    [T, T] scores. q, k, v: [B, H, T, d] (or [BH, T, d]). The Pallas
+    kernel runs on a TPU, or off-TPU in interpreter mode when
+    FLAGS_pallas_interpret is set (CPU numerics tests). The naive XLA
+    contraction takes every other call: shapes the kernel does not tile
+    (T or d not lane-aligned), a non-TPU backend without the flag, and
+    force_naive (the FLAGS_use_flash_attention=false path — same entry
+    point so both flag states accept the same layouts). Each call bumps
+    `pallas.flash.kernel` or `pallas.flash.naive` at trace time."""
     squeeze = False
     if q.ndim == 4:
         B, H, T, d = q.shape
@@ -1037,13 +1043,13 @@ def flash_attention(q, k, v, causal=True, sm_scale=None,
     scale = float(sm_scale) if sm_scale is not None else d ** -0.5
 
     from ..flags import get_flag
-    interpret = jax.default_backend() != 'tpu'
-    use_kernel = (not force_naive) and _supported(T, d) and (
-        jax.default_backend() == 'tpu' or bool(get_flag(
-            'pallas_interpret')))
-    if use_kernel:
-        out = _flash(qf, kf, vf, causal, scale, interpret)
+    on_tpu = jax.default_backend() == 'tpu'
+    if (not force_naive) and _supported(T, d) and (
+            on_tpu or bool(get_flag('pallas_interpret'))):
+        _ROUTE_KERNEL.inc()
+        out = _flash(qf, kf, vf, causal, scale, not on_tpu)
     else:
+        _ROUTE_NAIVE.inc()
         out = _naive(qf, kf, vf, causal, scale)
     if not squeeze:
         out = out.reshape(q.shape)

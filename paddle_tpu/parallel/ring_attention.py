@@ -30,12 +30,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-    _SHARD_MAP_KW = {}
-except ImportError:                      # older jax
-    from jax.experimental.shard_map import shard_map
-    _SHARD_MAP_KW = {'check_rep': False}
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ['ring_attention', 'ring_attention_global',
@@ -158,7 +153,7 @@ def ring_attention_global(q, k, v, mesh, causal=True, sm_scale=None,
     fn = functools.partial(ring_attention, axis_name=seq_axis,
                            causal=causal, sm_scale=sm_scale)
     return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, **_SHARD_MAP_KW)(q, k, v)
+                     out_specs=spec)(q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -389,19 +384,16 @@ def ring_flash_attention_global(q, k, v, mesh, causal=True,
         return _fa(q, k, v, causal=causal, sm_scale=sm_scale)
     spec, seq_ok = _ring_spec(mesh, q, seq_axis, batch_axis, head_axis)
     if not seq_ok:
-        # mesh present but no usable sp axis: a bare pallas_call on
-        # GSPMD-sharded globals would all-gather (no partitioning rule
-        # for the custom call) — use the einsum fallback, which XLA
+        # mesh present but no usable sp axis: JAX refuses to lower a
+        # bare pallas_call on GSPMD-sharded globals (a Mosaic kernel has
+        # no partitioning rule) — use the einsum fallback, which XLA
         # partitions over dp/tp like any other op
         return ring_attention_global(q, k, v, None, causal=causal,
                                      sm_scale=sm_scale)
     fn = functools.partial(ring_flash_attention, axis_name=seq_axis,
                            causal=causal, sm_scale=sm_scale)
     # pallas_call outputs carry no varying-mesh-axes annotation, which
-    # the new shard_map's check_vma rejects — disable the check (the
-    # per-device computation is manifestly per-shard)
-    kw = dict(_SHARD_MAP_KW)
-    if 'check_rep' not in kw:
-        kw['check_vma'] = False
+    # shard_map's check_vma rejects — disable the check (the per-device
+    # computation is manifestly per-shard)
     return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, **kw)(q, k, v)
+                     out_specs=spec, check_vma=False)(q, k, v)

@@ -299,7 +299,6 @@ class ParallelExecutor(Executor):
             else:
                 # device-resident values reshard on device; np.asarray
                 # here would round-trip every parameter through the host
-                # (GBs over a remoted-PJRT link for billion-param models)
                 if not isinstance(val, jax.Array):
                     val = np.asarray(val)
                 self._scope.set_var(name, jax.device_put(val, target))

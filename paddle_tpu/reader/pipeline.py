@@ -12,9 +12,7 @@ the TPU execution model:
   `jax.device_put`s them AHEAD of consumption into a small device-side
   queue, so the training step receives arrays already resident in HBM —
   the per-step host cost is a queue pop, and the host->device copy
-  overlaps the previous step's compute. On a remoted-PJRT link
-  (~91 ms RTT, PERF.md) this is the difference between wire-bound and
-  compute-bound training.
+  overlaps the previous step's compute.
 
 The `read` host op (ops/io_ops.py) pops from the front queue each step
 and raises core.EOFException when the pass ends (reference

@@ -178,9 +178,7 @@ def run_driver():
 
 def run_overload_driver():
     # the bit-exact reference below runs jax in THIS process — pin it
-    # to CPU before anything touches a backend (the chaos sweep strips
-    # JAX_PLATFORMS from every role's env, and TPU probing takes
-    # minutes to give up)
+    # to CPU before anything touches a backend
     import jax
     jax.config.update('jax_platforms', 'cpu')
     from paddle_tpu.serving import FleetRouter, OverloadError

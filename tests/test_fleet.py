@@ -78,7 +78,8 @@ def _launch_replicas(model_dir, n, slots=4, extra_env=None):
                    SERVE_ENDPOINT=ep, SERVE_SLOTS=str(slots),
                    SERVE_WORKERS='1')
         env.pop('XLA_FLAGS', None)
-        env.pop('JAX_PLATFORMS', None)
+        env['JAX_PLATFORMS'] = 'cpu'    # the replica takes its platform
+                                        # from its environment
         env.update((extra_env or {}).get(i, {}))
         procs.append(subprocess.Popen(
             [sys.executable, _SERVE_REPLICA], env=env,

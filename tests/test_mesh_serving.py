@@ -278,8 +278,7 @@ def test_server_stats_carry_mesh_shape(lm_dir, ref_stream):
 @pytest.mark.slow
 @pytest.mark.timeout(600)
 def test_chaos_sweep_mesh_serve_leg():
-    env = dict(os.environ)
-    env.pop('JAX_PLATFORMS', None)
+    env = dict(os.environ, JAX_PLATFORMS='cpu')
     env.pop('XLA_FLAGS', None)
     proc = subprocess.run(
         [sys.executable,

@@ -20,8 +20,8 @@ What must hold:
   timeline as device lanes distinct from the host lanes, on the same
   clock;
 - a deliberately breached SLO rule emits a slo.breach event;
-- tools/perf_gate.py exits 0 on the committed BENCH trajectory and
-  nonzero on a synthetically regressed fixture.
+- tools/perf_gate.py exits nonzero on a synthetically regressed
+  fixture trajectory, and refuses to run with nothing to gate.
 """
 import json
 import os
@@ -422,11 +422,12 @@ def test_perf_gate_smoke():
     assert 'smoke: ok' in out.stdout
 
 
-def test_perf_gate_real_trajectory_clean():
+def test_perf_gate_needs_something_to_gate():
+    """The repository commits no trajectory: a bare invocation is an
+    error, not a vacuous pass."""
     out = _gate()
-    assert out.returncode == 0, \
-        'committed BENCH trajectory must gate clean:\n%s' % out.stdout
-    assert 'no regressions' in out.stdout
+    assert out.returncode == 2, out.stdout + out.stderr
+    assert 'nothing to gate' in out.stderr
 
 
 def test_perf_gate_trips_on_regressed_fixture(tmp_path):

@@ -331,7 +331,7 @@ def _launch_paged_replicas(model_dir, n):
                    SERVE_PAGE_TOKENS='4', SERVE_KV_PAGES='6',
                    SERVE_PREFILL_CHUNK=str(fw.CFG.max_len))
         env.pop('XLA_FLAGS', None)
-        env.pop('JAX_PLATFORMS', None)
+        env['JAX_PLATFORMS'] = 'cpu'
         procs.append(subprocess.Popen(
             [sys.executable, os.path.join(root, 'tools',
                                           'serve_replica.py')],

@@ -18,7 +18,8 @@ family running under every execution engine:
 Usage:
   python tools/bench_suite.py                     # quick sweep, tiny shapes
   python tools/bench_suite.py --model resnet --mode parallel --steps 20
-  python tools/bench_suite.py --full              # benchmark shapes (TPU)
+  python tools/bench_suite.py --full              # benchmark shapes; fails
+                                                  # without a TPU
 
 Prints one row per (model, mode): samples/sec + final loss.
 """
@@ -615,7 +616,8 @@ def main():
     ap.add_argument('--dist-trainers', type=int, default=2)
     ap.add_argument('--steps', type=int, default=5)
     ap.add_argument('--full', action='store_true',
-                    help='benchmark shapes (needs a real accelerator)')
+                    help='benchmark shapes; needs a TPU and fails '
+                         'without one')
     ap.add_argument('--bn-local-stats', action='store_true',
                     help='scaling mode: per-device BN statistics '
                          '(FLAGS_bn_local_stats — reference semantics)')
@@ -634,7 +636,11 @@ def main():
                     help='print the full row list as one JSON array '
                          'on the last stdout line')
     args = ap.parse_args()
-    if not args.full:
+    if args.full:
+        from paddle_tpu.obs import perf
+        print(json.dumps(dict(perf.require_tpu(), mode='device')),
+              flush=True)
+    else:
         os.environ.setdefault(
             'XLA_FLAGS', '--xla_force_host_platform_device_count=8')
         import jax

@@ -206,7 +206,7 @@ def _run_seed(plan_json, model, steps, trainers, pservers, budget,
               obs_dir=None):
     eps = ','.join('127.0.0.1:%d' % p for p in _free_ports(pservers))
     base_env = dict(os.environ)
-    base_env.pop('JAX_PLATFORMS', None)
+    base_env['JAX_PLATFORMS'] = 'cpu'
     base_env.pop('XLA_FLAGS', None)
     base_env.update({'PS_MODEL': model, 'PS_ENDPOINTS': eps,
                      'PS_TRAINERS': str(trainers), 'PS_STEPS': str(steps),
@@ -271,7 +271,7 @@ def _run_kill_seed(seed, model, steps, trainers, pservers, budget,
     plan = FaultPlan.from_kill_seed(seed, role)
     eps = ','.join('127.0.0.1:%d' % p for p in _free_ports(pservers))
     base_env = dict(os.environ)
-    base_env.pop('JAX_PLATFORMS', None)
+    base_env['JAX_PLATFORMS'] = 'cpu'
     base_env.pop('XLA_FLAGS', None)
     base_env.update({'PS_MODEL': model, 'PS_ENDPOINTS': eps,
                      'PS_TRAINERS': str(trainers), 'PS_STEPS': str(steps),
@@ -332,7 +332,7 @@ def _run_mesh_seed(kill_nth, steps, budget, workdir, obs_dir=None,
     from paddle_tpu.distributed.supervisor import Supervisor
 
     env = dict(os.environ)
-    env.pop('JAX_PLATFORMS', None)
+    env['JAX_PLATFORMS'] = 'cpu'
     env.pop('XLA_FLAGS', None)
     env.update({'MESH_STEPS': str(steps), 'MESH_CKPT':
                 os.path.join(workdir, 'ckpt'), 'MESH_CKPT_EVERY': '2',
@@ -493,7 +493,7 @@ def _run_fleet_seed(seed, budget, workdir, model_dir, baseline,
             'type': '*', 'nth': rng.randint(15, 90),
             'action': 'exit'}]})
     base_env = dict(os.environ)
-    base_env.pop('JAX_PLATFORMS', None)
+    base_env['JAX_PLATFORMS'] = 'cpu'
     base_env.pop('XLA_FLAGS', None)
     if obs_dir:
         base_env['FLAGS_obs_flush_secs'] = '0.5'
@@ -569,7 +569,7 @@ def _run_overload_seed(seed, budget, workdir, model_dir, n_replicas=2,
         'when': 'recv', 'type': '*', 'nth': rng.randint(15, 90),
         'action': 'exit'}]})
     base_env = dict(os.environ)
-    base_env.pop('JAX_PLATFORMS', None)
+    base_env['JAX_PLATFORMS'] = 'cpu'
     base_env.pop('XLA_FLAGS', None)
     if obs_dir:
         base_env['FLAGS_obs_flush_secs'] = '0.5'
@@ -642,7 +642,7 @@ def _run_grayfail_seed(seed, budget, workdir, model_dir, n_replicas=2,
     victim = 'replica0'
     plan_spec = 'grayfail:replica0:%d' % seed
     base_env = dict(os.environ)
-    base_env.pop('JAX_PLATFORMS', None)
+    base_env['JAX_PLATFORMS'] = 'cpu'
     base_env.pop('XLA_FLAGS', None)
     if obs_dir:
         base_env['FLAGS_obs_flush_secs'] = '0.5'
@@ -726,7 +726,7 @@ def _run_disagg_seed(seed, budget, workdir, model_dir, streams=16,
         rule['secs'] = round(20.0 + 20.0 * rng.random(), 1)
     plan_json = json.dumps({'rules': [rule]})
     base_env = dict(os.environ)
-    base_env.pop('JAX_PLATFORMS', None)
+    base_env['JAX_PLATFORMS'] = 'cpu'
     base_env.pop('XLA_FLAGS', None)
     if obs_dir:
         base_env['FLAGS_obs_flush_secs'] = '0.5'

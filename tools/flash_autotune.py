@@ -1,8 +1,8 @@
 """Interleaved in-process flash-attention block autotune.
 
-The round-4 sweep ran one process per config and the ±10-20% chip/
-transport noise swallowed every difference (PERF.md round-4 autotune
-— honest null). Round-5's mul A/B showed the fix: keep EVERY arm in
+An earlier sweep ran one process per config and ±10-20% run-to-run
+noise swallowed every difference (an honest null, PERF.md). The fix
+that resolved it: keep EVERY arm in
 ONE process, alternate arms across rounds, and difference in-jit N/2N
 loops. This tool re-runs the (block_q, block_k) sweep that way.
 

@@ -53,8 +53,7 @@ def _run(fn, reps, blocks=BLOCKS, bq=BQ, bk=BK, iters=ITERS):
         def body(c, _):
             return call(c), None
         y, _ = jax.lax.scan(body, x, None, length=iters)
-        # scalar fetch forces device completion through the remoted
-        # transport (block_until_ready returns early there)
+        # the scalar fetch waits for the device
         return y[0, 0, 0]
 
     np.asarray(loop(x))

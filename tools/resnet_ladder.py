@@ -73,8 +73,8 @@ def main():
         float(np.asarray(l[0]))
         return time.perf_counter() - t0
 
-    # differencing cancels the per-fetch transport RTT constant
-    # (bench._run_steps uses the same pattern; PERF.md round-4 note)
+    # differencing cancels the per-fetch constant (bench._run_steps
+    # uses the same pattern)
     w1 = timed(10)
     w2 = timed(20)
     step_ms = max(w2 - w1, 1e-9) / 10 * 1e3

@@ -22,7 +22,8 @@ tools/obs_report.py.
 Usage:
   python tools/serve_bench.py               # CPU-sized sweep, bs 1..64
   python tools/serve_bench.py --quick       # one tiny shape (CI smoke)
-  python tools/serve_bench.py --full        # L4/D1024/T512 (accelerator)
+  python tools/serve_bench.py --full        # L4/D1024/T512; needs a TPU
+                                            # and fails without one
 """
 from __future__ import annotations
 
@@ -37,6 +38,14 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 import numpy as np
+
+
+def _replica_env():
+    """Environment of a serve_replica.py child: the CPU. (--full, whose
+    parent holds the chip, refuses the legs that start replicas.)"""
+    env = dict(os.environ, JAX_PLATFORMS='cpu')
+    env.pop('XLA_FLAGS', None)
+    return env
 
 
 def _build_predictor(cfg):
@@ -581,8 +590,7 @@ def _fleet_leg(cfg, quick, replicas=2):
             s.bind(('127.0.0.1', 0))
             eps.append('127.0.0.1:%d' % s.getsockname()[1])
             s.close()
-        env = dict(os.environ)
-        env.pop('XLA_FLAGS', None)
+        env = _replica_env()
         try:
             for ep in eps:
                 procs.append(subprocess.Popen(
@@ -733,8 +741,7 @@ def _hedge_leg(cfg, quick, replicas=2):
             s.bind(('127.0.0.1', 0))
             eps.append('127.0.0.1:%d' % s.getsockname()[1])
             s.close()
-        env = dict(os.environ)
-        env.pop('XLA_FLAGS', None)
+        env = _replica_env()
         # replica0: stall each of the first n_stalls SRV_POLL replies
         # for stall_secs — the gray window the hedges must cover
         plan = json.dumps({'rules': [
@@ -862,8 +869,7 @@ def _disagg_leg(cfg, quick, replicas=2):
             eps.append('127.0.0.1:%d' % s.getsockname()[1])
             s.close()
         decode_eps, prefill_eps = eps[:replicas], eps[replicas:]
-        env = dict(os.environ)
-        env.pop('XLA_FLAGS', None)
+        env = _replica_env()
         procs = []
         try:
             for ep in eps:
@@ -1055,7 +1061,9 @@ def main():
     ap.add_argument('--quick', action='store_true',
                     help='one tiny shape, bs 1 + 4 (CI smoke)')
     ap.add_argument('--full', action='store_true',
-                    help='L4/D1024/T512 benchmark shape (accelerator)')
+                    help='L4/D1024/T512 benchmark shape; needs a TPU and '
+                         'fails without one; refuses --fleet/--hedge/'
+                         '--disagg (this process holds the chip)')
     ap.add_argument('--refresh', action='store_true',
                     help='add the online-refresh cost leg: the engine '
                          'burst with vs without a concurrent '
@@ -1108,7 +1116,16 @@ def main():
                     help="mesh axis spec for --mesh (default 'tp=2')")
     ap.add_argument('--iters', type=int, default=20)
     args = ap.parse_args()
-    if not args.full:
+    if args.full:
+        legs = [leg for leg in ('fleet', 'hedge', 'disagg')
+                if getattr(args, leg)]
+        if legs:
+            ap.error('--full builds its predictor on the chip in this '
+                     'process, and a chip belongs to one process: the '
+                     'replica children of --%s could not hold it. Run '
+                     'those legs without --full (CPU counts).'
+                     % ' --'.join(legs))
+    else:
         os.environ.setdefault('JAX_PLATFORMS', 'cpu')
         if args.mesh:
             # must land before jax initializes its backend: the CPU
@@ -1118,6 +1135,11 @@ def main():
 
     from paddle_tpu.models import transformer as tfm
     if args.full:
+        from paddle_tpu.obs import perf
+        print(json.dumps(dict(perf.require_tpu(), mode='device')),
+              flush=True)
+        # 16 heads of d=64 miss the flash kernel's d % 128 tiling: this
+        # shape runs the naive contraction (pallas.flash.naive counts it)
         cfg = tfm.TransformerConfig(vocab=32768, dim=1024, heads=16,
                                     layers=4, ffn=4096, max_len=512,
                                     use_tp=False, use_sp=False,

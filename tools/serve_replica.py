@@ -34,16 +34,19 @@ Environment contract (everything a Supervisor role env can carry):
                         poll loop instead (the PR-9 standalone mode)
   SERVE_SUBSCRIBER_ID   subscriber identity          (default pid)
 
+The replica serves on JAX's default backend, taken from its
+environment like any JAX program: one replica process per chip on a TPU
+host. A chip belongs to one process, so a launcher that has itself
+touched JAX on the chip must not start replicas there, and the CPU
+harnesses (tests, chaos_sweep, serve_bench's non---full legs) pass
+JAX_PLATFORMS=cpu to the child.
+
 Prints 'READY <port>' on stdout once serving. Fault plans
 (FLAGS_fault_plan) apply to the wire layer as everywhere else, so
 chaos_sweep --fleet can kill a replica at a deterministic message.
 """
 import os
 import sys
-
-import jax
-
-jax.config.update('jax_platforms', 'cpu')
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
