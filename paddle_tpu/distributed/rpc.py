@@ -115,7 +115,7 @@ class _Pending(object):
         self.gen = -1
         self.attempts = 0        # REPLY_ERR-retryable resubmissions
         self.sid = sid           # trace span id (None: untraced)
-        self.t0 = time.time()    # span clock
+        self.t0 = time.perf_counter()    # span clock
         self.tm0 = time.monotonic()   # latency clock
 
 
@@ -577,7 +577,7 @@ class PSClient(object):
         _CALL_LATENCY.observe(time.monotonic() - p.tm0)
         if p.sid is not None:
             _trace.record_span('rpc.%s' % _msg_name(p.msg_type),
-                               'client', p.sid, p.t0, time.time(),
+                               'client', p.sid, p.t0, time.perf_counter(),
                                endpoint=self.endpoint, seq=p.seq)
         if err is not None:
             p.future.set_exception(err)
@@ -607,7 +607,7 @@ class PSClient(object):
             _CALL_LATENCY.observe(time.monotonic() - p.tm0)
             if p.sid is not None:
                 _trace.record_span('rpc.%s' % _msg_name(p.msg_type),
-                                   'client', p.sid, p.t0, time.time(),
+                                   'client', p.sid, p.t0, time.perf_counter(),
                                    endpoint=self.endpoint, seq=p.seq,
                                    error=True)
             p.future.set_exception(err)

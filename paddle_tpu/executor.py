@@ -392,12 +392,8 @@ class PreparedProgram(object):
         # buffers must not be invalidated in place
         self.donate = donate
         # perf observatory (obs/perf.py): fingerprint tags this
-        # prepared program's xla.compile spans; cost_* accumulate the
-        # XLA cost analysis of each compiled segment (complete once
-        # every segment has run) — the work model behind perf.mfu
+        # prepared program's exe.run and xla.compile spans
         self.fingerprint = None
-        self.cost_flops = 0.0
-        self.cost_bytes = 0.0
         self.steps = []          # list of _DeviceSegment | _HostStep
         self._build_segments()
         self._analyze_dataflow()
@@ -564,6 +560,7 @@ class Executor(object):
             feed_var_name='feed', fetch_var_name='fetch', scope=None,
             return_numpy=True, use_program_cache=True):
         from .obs import perf as _perf
+        from .profiler import RecordEvent
         t0_perf = _perf.step_begin()
         program = program or default_main_program()
         if not isinstance(program, Program):
@@ -575,6 +572,44 @@ class Executor(object):
         fetch_names = [v.name if isinstance(v, Variable) else str(v)
                        for v in fetch_list]
 
+        with RecordEvent('exe.run', n_feeds=len(feed)) as run_ev:
+            with RecordEvent('exe.feed'):
+                feed_arrays = self._place_feeds(program, feed)
+            with RecordEvent('exe.prepare'):
+                feed_sig = tuple(sorted(
+                    (n, a.shape, str(a.dtype))
+                    for n, a in feed_arrays.items()))
+                cache_key = (program._uid, program._version, 0, feed_sig,
+                             tuple(fetch_names))
+                prepared = self._prepared_cache.get(cache_key) \
+                    if use_program_cache else None
+                if prepared is None:
+                    prepared = PreparedProgram(program, 0,
+                                               feed_arrays.keys(),
+                                               fetch_names)
+                    if use_program_cache:
+                        self._prepared_cache[cache_key] = prepared
+                if prepared.fingerprint is None:
+                    prepared.fingerprint = hashlib.md5(
+                        repr(cache_key).encode()).hexdigest()[:12]
+            run_ev.attrs['fingerprint'] = prepared.fingerprint
+
+            result = self._run_prepared(prepared, feed_arrays, fetch_names,
+                                        scope, program)
+            self._step += 1
+            if return_numpy and result:
+                # the host fetch is the wait for the device: only a run
+                # that fetched something has a latency worth the name
+                with RecordEvent('exe.fetch'):
+                    result = [self._to_numpy(r) for r in result]
+        if t0_perf is not None:
+            _perf.step_end(t0_perf, device=self.device, scope=scope,
+                           fetched=return_numpy and bool(result))
+        return result
+
+    def _place_feeds(self, program, feed):
+        """name -> placed array for every fed value (converted to the
+        declared dtype; a device-resident value stays on the device)."""
         feed_arrays = {}
         feed = _expand_sequence_feeds(program, feed)
         for name, value in feed.items():
@@ -601,37 +636,7 @@ class Executor(object):
                     var.dtype != 'bfloat16':
                 arr = arr.astype(var.dtype)
             feed_arrays[name] = self._put_feed(name, arr)
-
-        feed_sig = tuple(sorted(
-            (n, a.shape, str(a.dtype)) for n, a in feed_arrays.items()))
-        cache_key = (program._uid, program._version, 0, feed_sig,
-                     tuple(fetch_names))
-        prepared = self._prepared_cache.get(cache_key) \
-            if use_program_cache else None
-        if prepared is None:
-            prepared = PreparedProgram(program, 0, feed_arrays.keys(),
-                                       fetch_names)
-            if use_program_cache:
-                self._prepared_cache[cache_key] = prepared
-        if prepared.fingerprint is None:
-            prepared.fingerprint = hashlib.md5(
-                repr(cache_key).encode()).hexdigest()[:12]
-
-        result = self._run_prepared(prepared, feed_arrays, fetch_names,
-                                    scope, program)
-        self._step += 1
-        if return_numpy:
-            # the host fetch below is the device sync — stamp the step
-            # after it so perf.step_latency covers real work
-            result = [self._to_numpy(r) for r in result]
-            if t0_perf is not None:
-                _perf.step_end(t0_perf, prepared, device=self.device,
-                               scope=scope)
-            return result
-        if t0_perf is not None:
-            _perf.step_end(t0_perf, prepared, device=self.device,
-                           scope=scope, sync=result)
-        return result
+        return feed_arrays
 
     def _to_numpy(self, value):
         """Hook: fetch one result to host (ParallelExecutor overrides to
@@ -675,7 +680,6 @@ class Executor(object):
 
         from . import flags as flags_mod
         from . import profiler as _prof
-        from .obs import trace as _trace
         check_nan_inf = flags_mod.get_flag('check_nan_inf')
 
         for step_idx, step in enumerate(prepared.steps):
@@ -744,39 +748,21 @@ class Executor(object):
                             or np.asarray(a).dtype,
                             sharding=getattr(a, 'sharding', None)),
                         (donated, const, key_arg))
-                if fresh_compile and (_perf.enabled()
-                                      or _trace.enabled()):
-                    # time the FIRST call: trace+lower+XLA-compile all
-                    # happen inside it (an explicit lower().compile()
-                    # does NOT warm jax's jit call cache), so this span
-                    # is the user-visible compile stall
+                seg_event = _prof.RecordEvent(
+                    'device_segment:%d(%d ops)' % (step_idx, len(step.ops)))
+                if fresh_compile and _perf.enabled():
+                    # time the FIRST call: trace+lower+XLA-compile (or
+                    # the persistent cache's retrieval) all happen
+                    # inside it, so this span is the user-visible
+                    # compile stall
                     t0c = time.perf_counter()
-                    # discard any extra-flops notes left over from
-                    # traces outside this segment (direct tool calls
-                    # into the pallas kernels) so they aren't billed
-                    # to us
-                    _perf.pallas_extra_flops()
                     with _perf.compile_span(prepared.fingerprint,
                                             step_idx, len(step.ops)):
-                        with _prof.RecordEvent(
-                                'device_segment:%d(%d ops)'
-                                % (step_idx, len(step.ops))):
+                        with seg_event:
                             outs = step.jitted(donated, const, key_arg)
-                    # the compiling call above is what traces the inner
-                    # pallas jits — drain the work this segment's arms
-                    # reported beyond the analytical cost model
-                    extra = _perf.pallas_extra_flops()
-                    flops, nbytes = _perf.segment_cost(
-                        step.jitted, step._arg_struct)
-                    flops += extra
-                    prepared.cost_flops += flops
-                    prepared.cost_bytes += nbytes
-                    _perf.record_compile(time.perf_counter() - t0c,
-                                         flops, nbytes)
+                    _perf.record_compile(time.perf_counter() - t0c)
                 else:
-                    with _prof.RecordEvent(
-                            'device_segment:%d(%d ops)'
-                            % (step_idx, len(step.ops))):
+                    with seg_event:
                         outs = step.jitted(donated, const, key_arg)
             for name, val in zip(step.out_names, outs):
                 local[name] = val

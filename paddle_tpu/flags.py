@@ -81,9 +81,10 @@ Known flags:
                          escalation fires
   obs_dir                observability root (paddle_tpu/obs/): when set,
                          the telemetry registry exports metric
-                         snapshots and the trace layer appends span /
-                         fault / RecordEvent records as JSONL under
-                         this directory ('' = observability off, the
+                         snapshots and the trace layer drains its span
+                         buffer (RecordEvent scopes, RPC spans) and
+                         writes fault records as JSONL under this
+                         directory ('' = observability off, the
                          default — every instrument is a near-free
                          no-op). The Supervisor gives each role its
                          own subdir; tools/obs_report.py merges them.
@@ -138,10 +139,11 @@ Known flags:
   mesh_shape             MeshConfig.from_flags axis spec, e.g.
                          'dp=2,tp=2' ('' = pure data parallelism over
                          every local device)
-  perf_peak_tflops       peak dense bf16 TFLOP/s used as the perf.mfu
-                         denominator (0 = the exact-device_kind table
-                         in obs/perf.py, which knows TPUs only; off-TPU
-                         the gauge stays unset unless this pins a peak)
+  perf_peak_tflops       peak dense bf16 TFLOP/s that
+                         obs/perf.device_peak_flops returns, the
+                         denominator of an MFU (0 = the exact-
+                         device_kind table in obs/perf.py, which knows
+                         TPUs only and raises on any other device)
   slo_rules              declarative SLO rule list for obs/slo.py —
                          inline JSON (list of {name, metric, kind,
                          threshold[, min_count]}) or @/path/rules.json
@@ -402,7 +404,7 @@ _DEFAULTS = {
     'obs_role': '',
     'obs_flush_secs': 2.0,
     # perf observatory (obs/perf.py): peak dense bf16 TFLOP/s override
-    # for the perf.mfu denominator (0 = the device_kind table)
+    # of device_peak_flops (0 = the device_kind table)
     'perf_peak_tflops': 0.0,
     # SLO watchdog (obs/slo.py): declarative rule list — inline JSON or
     # @/path/rules.json ('' = off); evaluation cadence in seconds.

@@ -1,5 +1,5 @@
-"""Device-side performance observatory: compile/JIT telemetry, live
-MFU, and HBM watermarks.
+"""Device-side performance observatory: compile/JIT telemetry, step
+counts and HBM watermarks.
 
 The cluster half of obs/ (PR 4) watches the wire; this module watches
 the device. The Executor's JIT pipeline reports into it at two points:
@@ -7,48 +7,48 @@ the device. The Executor's JIT pipeline reports into it at two points:
 - compile time (`record_compile` / `jit_cache_miss` / `jit_cache_hit`)
   — every lazily-compiled device segment stamps an
   `xla.compile_latency` observation and bumps the
-  `xla.jit_cache.{hit,miss}` counters, and its analytical cost
-  (FLOPs / bytes accessed, from jax's compiled cost analysis) is
-  accumulated onto the owning PreparedProgram so step attribution
-  below has a work model to divide by.
-- step time (`step_begin` / `step_end`) — wall latency of each
-  `Executor.run` call lands in the `perf.step_latency` histogram, and
-  combined with the compile-time FLOP count yields
-  `perf.achieved_tflops` and `perf.mfu` gauges. A `return_numpy=True`
-  fetch has already synchronized through the host transfer; otherwise
-  the fetched arrays are `block_until_ready`'d before the clock stops.
+  `xla.jit_cache.{hit,miss}` counters; its first (compiling) call runs
+  inside an `xla.compile` span (`compile_span`).
+- step time (`step_begin` / `step_end`) — every `Executor.run` bumps
+  `perf.steps`. `perf.step_latency` is the wall time of a run **whose
+  fetch came back to the host** (`return_numpy=True` with a non-empty
+  fetch list): there the fetch has waited for the device, so the time
+  is the step's. A run that hands back device arrays, or fetches
+  nothing, is not observed: its wall time would be the enqueue.
 
-Every hook is a no-op while telemetry is disabled — same
-one-global-bool fast path as the rest of the registry — so the
-executor hot loop pays nothing by default. The one deliberate
-exception: capturing a segment's cost analysis requires a second
-lower+compile of the already-jitted function (an explicit
-lower().compile() does not warm jax's call cache), which doubles a
-once-per-program cost. That is why it is gated on telemetry being
-enabled rather than free-running.
-
-MFU needs a peak-FLOPs denominator: PEAK_BF16_FLOPS below, keyed by
-the exact `device_kind` (the one table; bench.py and chip_smoke.py
-import it). A kind that is not in it raises. Off-TPU there is no peak,
-so `perf.mfu` is set only when `FLAGS_perf_peak_tflops` pins one (CPU
-tests).
+No hook waits on the device, reads the allocator or walks a scope: an
+observatory that synchronises changes the schedule it watches (a
+prefill chunk that returns nothing can be queued in front of the next
+decode step only if nobody waits for it). Every hook is a no-op while
+telemetry is disabled — the same one-global-bool fast path as the rest
+of the registry.
 
 HBM gauges (`hbm.bytes_in_use`, `hbm.peak_bytes`, `hbm.bytes_limit`,
-`hbm.scope_bytes`, `hbm.watermark_bytes`) are refreshed on every
-step_end from memory.hbm_snapshot(); on backends without PJRT memory
-stats (CPU) bytes_in_use falls back to the scope footprint so the
-series stay live in tests. `hbm.watermark_bytes` is a process-local
-high-water mark that survives allocator-level peak resets.
+`hbm.scope_bytes`, `hbm.watermark_bytes`) are read ON DEMAND: whenever
+`telemetry.snapshot()` is taken (by a caller, the exporter thread or
+the SLO watchdog), `update_hbm` asks memory.hbm_snapshot() for the
+device and the scope of the newest `Executor.run`. On backends without
+PJRT memory stats (CPU) bytes_in_use falls back to the scope footprint
+so the series stay live in tests. `hbm.watermark_bytes` is a
+process-local high-water mark that survives allocator-level peak
+resets.
+
+PEAK_BF16_FLOPS below is the one table of chip peaks, keyed by the
+exact `device_kind` (bench.py and chip_smoke.py divide by it). A kind
+that is not in it raises. Model FLOPs utilisation is not a gauge here:
+it needs the FLOPs the MODEL requires, from its shapes, and XLA's cost
+analysis counts recomputation and elementwise work (PERF.md).
 """
 from __future__ import annotations
 
 import time
+import weakref
 
-from . import telemetry, trace
+from . import telemetry
 from .. import flags
 
 __all__ = ['enabled', 'step_begin', 'step_end', 'jit_cache_hit',
-           'jit_cache_miss', 'record_compile', 'segment_cost',
+           'jit_cache_miss', 'record_compile',
            'PEAK_BF16_FLOPS', 'device_peak_flops', 'describe_device',
            'require_tpu', 'update_hbm', 'compile_span']
 
@@ -58,8 +58,6 @@ _jit_hits = telemetry.counter('xla.jit_cache.hit')
 _jit_misses = telemetry.counter('xla.jit_cache.miss')
 _step_latency = telemetry.histogram('perf.step_latency')
 _steps = telemetry.counter('perf.steps')
-_mfu = telemetry.gauge('perf.mfu')
-_achieved_tflops = telemetry.gauge('perf.achieved_tflops')
 _hbm_in_use = telemetry.gauge('hbm.bytes_in_use')
 _hbm_peak = telemetry.gauge('hbm.peak_bytes')
 _hbm_limit = telemetry.gauge('hbm.bytes_limit')
@@ -68,6 +66,8 @@ _hbm_watermark = telemetry.gauge('hbm.watermark_bytes')
 
 _watermark = 0          # process-local high-water of bytes_in_use
 _slo_started = False    # lazy FLAGS_slo_rules watchdog, armed once
+_last_device = None     # of the newest Executor.run: what update_hbm
+_last_scope = None      # reads when a snapshot asks (scope: a weakref)
 
 # Peak dense bf16 FLOP/s of one chip, keyed by the exact
 # jax.Device.device_kind. Source of every figure: Google Cloud TPU
@@ -92,9 +92,9 @@ def _pinned_peak_flops():
 
 
 def device_peak_flops(device):
-    """Peak dense bf16 FLOP/s of `device` for MFU attribution: the
-    FLAGS_perf_peak_tflops override if set (TFLOP/s; the only way to
-    get an MFU off-TPU), else the PEAK_BF16_FLOPS entry of its exact
+    """Peak dense bf16 FLOP/s of `device`, the denominator of an MFU:
+    the FLAGS_perf_peak_tflops override if set (TFLOP/s; the only way
+    to get one off-TPU), else the PEAK_BF16_FLOPS entry of its exact
     device_kind. An unknown kind raises: a guessed peak makes every MFU
     computed from it wrong without saying so."""
     pinned = _pinned_peak_flops()
@@ -154,53 +154,18 @@ def jit_cache_miss():
 
 
 def compile_span(fingerprint, segment, n_ops):
-    """Trace span wrapping a device segment's first (compiling) call.
-    The program fingerprint tag lets a timeline reader join the span
-    to the jit_cache series and to rerun-vs-rerun comparisons."""
-    return trace.span('xla.compile', fingerprint=fingerprint,
-                      segment=segment, n_ops=n_ops)
+    """The span around a device segment's first (compiling) call: trace,
+    lowering and the XLA compile (or the persistent cache's retrieval)
+    all happen inside it. The program fingerprint tag lets a timeline
+    reader join the span to the jit_cache series and to rerun-vs-rerun
+    comparisons."""
+    from ..profiler import RecordEvent
+    return RecordEvent('xla.compile', fingerprint=fingerprint,
+                       segment=segment, n_ops=n_ops)
 
 
-def record_compile(latency_s, flops=0.0, bytes_accessed=0.0):
+def record_compile(latency_s):
     _compile_latency.observe(latency_s)
-
-
-def segment_cost(jitted, arg_struct):
-    """Analytical (flops, bytes_accessed) for a jitted segment via the
-    XLA cost model. Requires a fresh lower+compile (jax's jit call
-    cache is not warmed by an explicit .lower().compile(), so this is
-    a duplicated compile — acceptable once per segment when telemetry
-    is on). Returns (0.0, 0.0) on any backend that can't answer."""
-    try:
-        cost = jitted.lower(*arg_struct).compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
-        flops = float(cost.get('flops', 0.0) or 0.0)
-        nbytes = float(cost.get('bytes accessed', 0.0) or 0.0)
-        return (max(flops, 0.0), max(nbytes, 0.0))
-    except Exception:
-        return (0.0, 0.0)
-
-
-def pallas_extra_flops():
-    """Drain trace-time extra-work notes from the Pallas kernels.
-
-    XLA's cost model cannot see inside a Pallas custom call, so the
-    flash segment is priced by the analytical 2-matmul attention model.
-    Arms that execute MORE than that model (the twopass forward's
-    second QK sweep) note the surplus at trace time; the executor
-    drains it here right after the compiling call and folds it into
-    the segment's cost_flops so live MFU divides by work that actually
-    ran. Granularity is once-per-trace: a second program hitting the
-    same inner-jit cache contributes nothing new (and needs nothing
-    new — cost_flops is per-prepared-program, priced at its own
-    compile). Draining is destructive; callers that only want to
-    discard stale notes call this and ignore the return."""
-    try:
-        from paddle_tpu.pallas import flash_attention as _fa
-        return float(_fa.take_extra_flops())
-    except Exception:
-        return 0.0
 
 
 # --- step-time hooks ------------------------------------------------
@@ -213,43 +178,37 @@ def step_begin():
     return time.perf_counter()
 
 
-def step_end(t0, prepared=None, device=None, scope=None, sync=None):
-    """Close out one Executor.run: observe step latency, derive
-    achieved TFLOP/s + MFU from the prepared program's compile-time
-    cost, refresh the hbm.* gauges, and (once) arm the FLAGS_slo_rules
-    watchdog.
-
-    `sync` is the fetched result list when the caller did NOT request
-    numpy (so the timer must block on device completion first); None
-    means the host fetch already synchronized."""
+def step_end(t0, device=None, scope=None, fetched=False):
+    """Close out one Executor.run: count it, observe its latency if
+    the caller's fetch already waited for the device (`fetched`),
+    remember whose memory a snapshot should read, and (once) arm the
+    FLAGS_slo_rules watchdog. Waits for nothing and reads nothing."""
+    global _last_device, _last_scope
     if t0 is None or not telemetry._enabled:
         return
-    if sync is not None:
-        import jax
-        jax.block_until_ready(sync)
-    dt = time.perf_counter() - t0
-    _step_latency.observe(dt)
     _steps.inc()
-    flops = float(getattr(prepared, 'cost_flops', 0.0) or 0.0)
-    if dt > 0.0 and flops > 0.0:
-        achieved = flops / dt
-        _achieved_tflops.set(achieved / 1e12)
-        # the peak table knows TPUs only: off-TPU the gauge is set only
-        # against a pinned FLAGS_perf_peak_tflops
-        if device.platform == 'tpu' or _pinned_peak_flops():
-            _mfu.set(achieved / device_peak_flops(device))
-    update_hbm(device=device, scope=scope)
+    if fetched:
+        _step_latency.observe(time.perf_counter() - t0)
+    _last_device = device
+    if scope is not None and (_last_scope is None
+                              or _last_scope() is not scope):
+        _last_scope = weakref.ref(scope)
     _maybe_start_slo()
 
 
 def update_hbm(device=None, scope=None):
     """Export memory.hbm_snapshot() as gauges + the process-local
-    watermark. Callable standalone (bench_suite stamps it between
-    steps of hand-rolled loops)."""
+    watermark. Registered with telemetry.on_read, so every snapshot()
+    sees fresh numbers of the newest run's device and scope; callable
+    standalone with others."""
     global _watermark
     if not telemetry._enabled:
         return
     from .. import memory
+    if device is None:
+        device = _last_device
+    if scope is None and _last_scope is not None:
+        scope = _last_scope()
     try:
         snap = memory.hbm_snapshot(device=device, scope=scope)
     except Exception:
@@ -263,6 +222,9 @@ def update_hbm(device=None, scope=None):
     if snap['peak_bytes'] > _watermark:
         _watermark = snap['peak_bytes']
     _hbm_watermark.set(_watermark)
+
+
+telemetry.on_read(update_hbm)
 
 
 def _maybe_start_slo():
@@ -281,6 +243,7 @@ def _maybe_start_slo():
 
 def _reset_for_tests():
     """Zero the module-local state telemetry.reset() can't see."""
-    global _watermark, _slo_started
+    global _watermark, _slo_started, _last_device, _last_scope
     _watermark = 0
     _slo_started = False
+    _last_device = _last_scope = None

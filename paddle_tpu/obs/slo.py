@@ -3,8 +3,8 @@ registry.
 
 A rule names one metric series, a comparison kind, and a threshold:
 
-    {"name": "mfu_floor",    "metric": "perf.mfu",
-     "kind": "gauge_min",    "threshold": 0.45}
+    {"name": "hbm_ceiling",  "metric": "hbm.bytes_in_use",
+     "kind": "gauge_max",    "threshold": 15e9}
     {"name": "step_p99",     "metric": "perf.step_latency",
      "kind": "p99_max",      "threshold": 0.250, "min_count": 20}
     {"name": "ttft",         "metric": "serving.ttft",

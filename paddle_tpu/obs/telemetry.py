@@ -32,7 +32,7 @@ import threading
 import time
 
 __all__ = ['Counter', 'Gauge', 'Histogram', 'counter', 'gauge',
-           'histogram', 'hist_quantile', 'snapshot', 'flush',
+           'histogram', 'hist_quantile', 'snapshot', 'on_read', 'flush',
            'enabled', 'enable', 'disable', 'reset']
 
 _lock = threading.Lock()
@@ -40,6 +40,7 @@ _enabled = False
 _counters = {}
 _gauges = {}
 _hists = {}
+_on_read = []       # refreshers of gauges that are read, not pushed
 _exporter = None
 
 
@@ -174,10 +175,21 @@ def enabled():
     return _enabled
 
 
+def on_read(refresh):
+    """Register `refresh()`, called before every snapshot() while the
+    registry is enabled: a gauge whose value is expensive to take (the
+    allocator's stats, a walk over a scope) is set there, when somebody
+    reads it, and costs the hot path nothing."""
+    _on_read.append(refresh)
+
+
 def snapshot():
     """One consistent dict of every registered series. Untouched series
     are included at zero — the rollup sums them away for free and the
     catalog stays visible in every export."""
+    if _enabled:
+        for refresh in _on_read:
+            refresh()
     with _lock:
         return {
             'counters': {n: c.value for n, c in _counters.items()},
@@ -214,9 +226,11 @@ class _Exporter(object):
         self._thread.start()
 
     def _loop(self):
+        from . import trace
         while not self._stop.wait(timeout=self.period):
             try:
                 self.write_line()
+                trace.flush()       # the span buffer, to the event log
             except OSError:
                 pass   # a torn-down obs dir must not kill the process
 
@@ -241,10 +255,13 @@ class _Exporter(object):
 
 
 def flush():
-    """Force a metric-snapshot line now (chaos tests call this before
-    asserting on a freshly merged rollup)."""
+    """Force a metric-snapshot line now, and the buffered spans into
+    the event log (chaos tests call this before asserting on a freshly
+    merged rollup; a fault rule calls it before it kills the process)."""
     if _exporter is not None:
         _exporter.write_line()
+    from . import trace
+    trace.flush()
 
 
 def _default_role():

@@ -46,7 +46,7 @@ import numpy as np
 from ..flags import get_flag
 from ..integrity import crc32
 from ..obs import telemetry as _tm
-from ..obs import trace as _trace
+from ..profiler import RecordEvent
 
 __all__ = ['ParamSubscriber', 'RefreshError']
 
@@ -181,7 +181,7 @@ class ParamSubscriber(object):
         failure."""
         t0 = time.monotonic()
         try:
-            with _trace.span('online.refresh', kind='serving',
+            with RecordEvent('online.refresh', kind='serving',
                              endpoints=len(self.endpoints)):
                 version = self._refresh()
         except Exception as e:

@@ -127,29 +127,6 @@ if _FORCE_FWD_ARM not in _FWD_ARMS:
 # measurement tools must cross-check this before ranking
 _RESOLVED_FWD_ARM = ''
 
-# Trace-time note of pallas work that XLA's cost analysis cannot see
-# inside the custom call: the twopass forward executes a second QK
-# matmul per visited block that the 2-matmul attention work model does
-# not include. obs/perf drains this into the owning PreparedProgram's
-# cost_flops so live MFU divides by what actually ran.
-_PENDING_EXTRA_FLOPS = 0.0
-
-
-def _note_extra_flops(flops):
-    global _PENDING_EXTRA_FLOPS
-    _PENDING_EXTRA_FLOPS += float(flops)
-
-
-def take_extra_flops():
-    """Drain the extra-work notes accumulated since the last drain
-    (trace-time; one note per fresh _fwd trace, so a segment compile
-    that re-uses an already-traced _fwd shape contributes nothing —
-    the same once-per-trace granularity as the jit cache itself)."""
-    global _PENDING_EXTRA_FLOPS
-    flops, _PENDING_EXTRA_FLOPS = _PENDING_EXTRA_FLOPS, 0.0
-    return flops
-
-
 # clamp block index maps during causally-skipped grid steps so the
 # dead prefetch DMAs are elided (trace-time; off only for A/B)
 _CLAMP_SKIPPED_DMA = True
@@ -662,17 +639,6 @@ def _fwd(q, k, v, causal, sm_scale, interpret=False):
     _RESOLVED_FWD_ARM = arm
     nq, nk = T // bq, T // bk
     if arm == 'twopass':
-        # the second QK sweep is real executed work the 2-matmul
-        # attention model (and XLA's cost analysis, blind inside the
-        # custom call) does not count — note it for obs/perf so live
-        # MFU divides by what actually ran. Visited blocks only: the
-        # causal sweep stops at the diagonal.
-        if causal:
-            visited = sum(((i + 1) * bq - 1) // bk + 1
-                          for i in range(nq))
-        else:
-            visited = nq * nk
-        _note_extra_flops(2.0 * BH * visited * bq * bk * d)
         return _fwd_twopass(q, k, v, causal, sm_scale, interpret,
                             bq, bk, nq, nk)
     return _fwd_online(q, k, v, causal, sm_scale, interpret,

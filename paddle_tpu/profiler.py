@@ -15,7 +15,13 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
+from .obs import telemetry as _telemetry
 from .obs import trace as _obs_trace
+
+# whether JAX's profiler is capturing: the annotation's own flag
+_profile_running = _TraceAnnotation.is_enabled
 
 __all__ = ['RecordEvent', 'record_event', 'profiler', 'start_profiler',
            'stop_profiler', 'reset_profiler', 'cuda_profiler']
@@ -26,24 +32,42 @@ _events = []     # (name, thread_id, start_s, end_s)
 
 
 class RecordEvent(object):
-    """RAII timing scope (reference platform/profiler.h RecordEvent).
+    """The program's one scoped span (reference platform/profiler.h
+    RecordEvent): `with RecordEvent('exe.run', n_feeds=3) as ev:`.
+    One scope, three readers, each with a switch that is already there:
 
-    Doubles as an observability source: when FLAGS_obs_dir is set
-    (obs/trace.py enabled), every scope also lands in the per-process
-    obs event log — independent of start_profiler/stop_profiler — so
-    executor segments share the merged cluster timeline with RPC spans
-    and FaultEvents."""
+    - while JAX's profiler captures a trace, the scope is a
+      `jax.profiler.TraceAnnotation('pt.' + name)`: the span lands in
+      the xplane's host plane, on the DEVICE TRACE'S clock, beside the
+      device's ops (otherwise one flag check);
+    - while the telemetry registry is on, the scope is recorded in
+      obs/trace.py's buffer on `perf_counter()`, with its parent (the
+      scope it was opened in, on this thread) and its attributes
+      (otherwise one boolean read). `FLAGS_obs_dir` drains that buffer
+      to the merged cluster timeline's event log;
+    - between start_profiler() and stop_profiler() it also lands in the
+      Fluid-style profiling report and chrome trace.
 
-    def __init__(self, name):
+    `ev.attrs` may be filled in until the scope ends."""
+    __slots__ = ('name', 'kind', 'attrs', 'start', '_ann', '_span')
+
+    def __init__(self, name, kind='host', **attrs):
         self.name = name
+        self.kind = kind
+        self.attrs = attrs
         self.start = None
-        self._obs_t0 = None
+        self._ann = None
+        self._span = None
 
     def __enter__(self):
+        if _profile_running():
+            self._ann = _TraceAnnotation('pt.' + self.name)
+            self._ann.__enter__()
+        if _telemetry._enabled:
+            self._span = _obs_trace.begin(self.name, self.kind,
+                                          attrs=self.attrs)
         if _enabled:
             self.start = time.perf_counter()
-        if _obs_trace.enabled():
-            self._obs_t0 = time.time()
         return self
 
     def __exit__(self, *exc):
@@ -58,8 +82,10 @@ class RecordEvent(object):
                 if _enabled:
                     _events.append((self.name, threading.get_ident(),
                                     self.start, end))
-        if self._obs_t0 is not None:
-            _obs_trace.host_span(self.name, self._obs_t0, time.time())
+        if self._span is not None:
+            _obs_trace.end(self._span)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 

@@ -23,10 +23,12 @@ from __future__ import annotations
 import queue
 import sys
 import threading
+import time
 
 import numpy as np
 
 from ..obs import telemetry as _tm
+from ..obs import trace as _trace
 
 __all__ = ['PyReader', 'get_reader', 'EOFException', 'leaked_threads']
 
@@ -211,7 +213,14 @@ class PyReader(object):
         if self._dev_q is not None:
             _DEV_DEPTH.set(self._dev_q.qsize())
         q = self._dev_q if self.use_double_buffer else self._host_q
-        item = q.get()
+        if _tm._enabled:
+            # the wait goes onto the span the pop runs in: the executor's
+            # `host_op:read`
+            t0 = time.perf_counter()
+            item = q.get()
+            _trace.annotate(waited_ms=1e3 * (time.perf_counter() - t0))
+        else:
+            item = q.get()
         if isinstance(item, _SourceError):
             self._started = False
             raise RuntimeError('py_reader %r data source failed'

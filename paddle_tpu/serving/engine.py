@@ -28,6 +28,19 @@ Telemetry (paddle_tpu/obs/, exported when FLAGS_obs_dir is set):
   serving/preempt.py (serving.preemptions / serving.swapped_pages /
   serving.swap_bytes / serving.resume_latency /
   serving.preempted_streams).
+
+Spans (profiler.RecordEvent; recorded while the registry is on, and on
+the device trace's clock while a profile runs): `serve.iter` is one
+pass of a worker's loop (attrs lanes, ready, prefilling, queued) with
+children `serve.admit`, `serve.prefill_tick`, `serve.pack` and
+`serve.accept`; the decoder's own spans (serving/paged.py) nest under
+it. A request that reaches a terminal state leaves three spans of kind
+'request' that share sid = its id: `serve.queue` (submitted_at ->
+admitted_at), `serve.prefill` (admitted_at -> first_token_at) and
+`serve.decode` (first_token_at -> done_at; attrs n_prompt,
+max_new_tokens, n_tokens, state, prefill_chunks, preemptions and
+gaps_ms, the times between its tokens). A preempted request adds one
+`serve.requeue` (preempted -> slot taken again) per resumption.
 """
 from __future__ import annotations
 
@@ -41,6 +54,8 @@ import numpy as np
 
 from ..flags import get_flag
 from ..obs import telemetry
+from ..obs import trace as _trace
+from ..profiler import RecordEvent
 from . import preempt as _preempt
 from .paging import CacheExhaustedError
 from .preempt import HostSwapBudget, pick_victim, preempt_policy
@@ -152,15 +167,51 @@ class Request(object):
         # arrival — None (the old-peer / no-key path) means no deadline
         self.deadline_at = None if deadline_ms is None \
             else self.submitted_at + float(deadline_ms) / 1000.0
+        # all on perf_counter(), like submitted_at: admitted_at is when
+        # a slot was FIRST taken (before open_stream), token_at one
+        # reading per accepted token (token_at[0] is first_token_at)
+        self.admitted_at = None
         self.first_token_at = None
+        self.token_at = []
+        self.prefill_chunks = 0
+        self.preemptions = 0
         self.done_at = None
         self._done = threading.Event()
+
+    def _admitted(self):
+        """A slot was taken for this request (again, if it was
+        preempted): the queue wait ends at the first."""
+        if self.admitted_at is None:
+            self.admitted_at = time.perf_counter()
 
     def _finish(self, state, error=None):
         self.state = state
         self.error = error
         self.done_at = time.perf_counter()
+        if telemetry._enabled:
+            self._record_spans()
         self._done.set()
+
+    def _record_spans(self):
+        """The request's life as three spans that share sid = id, from
+        its own timestamps: a phase it never reached is left out, and
+        the one it ended in runs to done_at."""
+        marks = [('serve.queue', self.submitted_at),
+                 ('serve.prefill', self.admitted_at),
+                 ('serve.decode', self.first_token_at)]
+        marks = [(n, t) for n, t in marks if t is not None]
+        ends = [t for _, t in marks[1:]] + [self.done_at]
+        attrs = dict(n_prompt=len(self.prompt),
+                     max_new_tokens=self.max_new_tokens,
+                     n_tokens=len(self.tokens), state=self.state,
+                     prefill_chunks=self.prefill_chunks,
+                     preemptions=self.preemptions)
+        for (name, t0), t1 in zip(marks, ends):
+            if name == 'serve.decode':
+                at = self.token_at
+                attrs['gaps_ms'] = [1e3 * (b - a)
+                                    for a, b in zip(at, at[1:])]
+            _trace.record_span(name, 'request', self.id, t0, t1, **attrs)
 
     def wait(self, timeout=None):
         return self._done.wait(timeout)
@@ -578,8 +629,10 @@ class ServingEngine(object):
         re-admitted; its snapshot, if any, was already restored)."""
         if req.preempted_at is None:
             return
-        _preempt.resume_latency.observe(time.perf_counter()
-                                        - req.preempted_at)
+        now = time.perf_counter()
+        _preempt.resume_latency.observe(now - req.preempted_at)
+        _trace.record_span('serve.requeue', 'request', req.id,
+                           req.preempted_at, now)
         req.preempted_at = None
         with self._cond:
             self._preempted -= 1
@@ -609,6 +662,7 @@ class ServingEngine(object):
         with self._cond:
             self._preempted += 1
             self._preemptions_n += 1
+            req.preemptions += 1
             req.state = QUEUED
             req.snapshot = snap
             req.preempted_at = time.perf_counter()
@@ -644,18 +698,20 @@ class ServingEngine(object):
             self._finish_lane(lanes, slot, CANCELLED, pred=pred,
                               wstate=wstate)
             return False
+        now = time.perf_counter()
         req.tokens.append(int(tok))
+        req.token_at.append(now)
         _tokens_out.inc()
         if req.first_token_at is None:
-            req.first_token_at = time.perf_counter()
-            _ttft.observe(req.first_token_at - req.submitted_at)
+            req.first_token_at = now
+            _ttft.observe(now - req.submitted_at)
         if len(req.tokens) >= req.max_new_tokens or \
                 (req.eos_id is not None and int(tok) == req.eos_id):
             self._finish_lane(lanes, slot, DONE, pred=pred,
                               wstate=wstate)
             return False
         lane.tok = int(tok)
-        lane.last_active = time.perf_counter()
+        lane.last_active = now
         return True
 
     def _admit(self, pred, lanes):
@@ -668,6 +724,7 @@ class ServingEngine(object):
             if req is None:
                 break
             req.state = RUNNING
+            req._admitted()
             self._inflight[req.id] = req
             slot = free.pop(0)
             batch.append((req, slot))
@@ -743,6 +800,7 @@ class ServingEngine(object):
                 else:
                     self._swap_budget.release(req.snapshot['nbytes'])
                     req.snapshot = None
+                    req._admitted()
                     self._resume(req)
                     req.state = RUNNING
                     self._inflight[req.id] = req
@@ -752,6 +810,7 @@ class ServingEngine(object):
                     _admitted.inc()
                     continue
             req.state = RUNNING
+            req._admitted()
             self._inflight[req.id] = req
             self._active_total += 1
             try:
@@ -837,6 +896,7 @@ class ServingEngine(object):
                                   pred=pred, wstate=wstate)
                 return
             _prefills.inc()
+            req.prefill_chunks += 1
             if out is None:
                 return               # more chunks remain — next iteration
             prefilling.popleft()
@@ -846,11 +906,6 @@ class ServingEngine(object):
             return
 
     def _worker_loop(self, wid, pred):
-        paged = getattr(pred, 'paged', False)
-        # a speculative predictor's step is one draft->verify iteration
-        # (serving/speculative.py): same feed ABI, but each live lane
-        # gets 1..k+1 tokens back instead of exactly one
-        speculative = getattr(pred, 'speculative', False)
         lanes = {}                       # slot -> _Lane
         prefilling = collections.deque()  # paged: slots mid-prefill
         wstate = {'cache_wait': False}
@@ -867,109 +922,131 @@ class ServingEngine(object):
             # one gate-read section per iteration: a waiting weight
             # swap (request_swap) runs between iterations — i.e. at a
             # step boundary — never under a prefill or decode step
-            with self._gate.read():
-                if paged:
-                    self._admit_paged(pred, lanes, prefilling, wstate)
-                    self._prefill_tick(pred, lanes, prefilling, wstate)
-                else:
-                    self._admit(pred, lanes)
-                _occupancy.set(self._active_total)
-                self._slot_tokens[wid] = {s: ln.pos
-                                          for s, ln in lanes.items()}
-                # deadline check at the step boundary: an expired ready
-                # lane is evicted (pages freed) before it buys another
-                # decode step. Prefilling lanes are checked at the
-                # prefill-queue head (_prefill_tick), matching how
-                # cancellation reaches them.
-                now = time.perf_counter()
-                for slot, ln in list(lanes.items()):
-                    if ln.ready and ln.req.deadline_at is not None \
-                            and now > ln.req.deadline_at:
+            with self._gate.read(), RecordEvent('serve.iter') as it:
+                self._iterate(it, wid, pred, lanes, prefilling, wstate,
+                              tokens, positions)
+
+    def _iterate(self, it, wid, pred, lanes, prefilling, wstate, tokens,
+                 positions):
+        """One pass of a worker's loop, inside its `serve.iter` span
+        `it`: admission, at most one prefill chunk, then one decode
+        step over the ready lanes and the acceptance of its tokens."""
+        paged = getattr(pred, 'paged', False)
+        # a speculative predictor's step is one draft->verify iteration
+        # (serving/speculative.py): same feed ABI, but each live lane
+        # gets 1..k+1 tokens back instead of exactly one
+        speculative = getattr(pred, 'speculative', False)
+        if paged:
+            with RecordEvent('serve.admit'):
+                self._admit_paged(pred, lanes, prefilling, wstate)
+            with RecordEvent('serve.prefill_tick'):
+                self._prefill_tick(pred, lanes, prefilling, wstate)
+        else:
+            with RecordEvent('serve.admit'):
+                self._admit(pred, lanes)
+        _occupancy.set(self._active_total)
+        self._slot_tokens[wid] = {s: ln.pos
+                                  for s, ln in lanes.items()}
+        # deadline check at the step boundary: an expired ready
+        # lane is evicted (pages freed) before it buys another
+        # decode step. Prefilling lanes are checked at the
+        # prefill-queue head (_prefill_tick), matching how
+        # cancellation reaches them.
+        now = time.perf_counter()
+        for slot, ln in list(lanes.items()):
+            if ln.ready and ln.req.deadline_at is not None \
+                    and now > ln.req.deadline_at:
+                self._finish_lane(
+                    lanes, slot, FAILED,
+                    error='DeadlineExceededError: expired '
+                          'mid-decode',
+                    pred=pred, wstate=wstate)
+                _deadline_expired.inc()
+        ready = [s for s, ln in lanes.items() if ln.ready]
+        if telemetry._enabled:
+            with self._cond:
+                queued = self._qsize_locked()
+            it.attrs.update(lanes=len(lanes), ready=len(ready),
+                            prefilling=len(prefilling), queued=queued)
+        if not ready:
+            return
+        with RecordEvent('serve.pack'):
+            for slot in ready:
+                tokens[slot] = lanes[slot].tok
+                positions[slot] = lanes[slot].pos
+        t0 = time.perf_counter()
+        try:
+            if speculative:
+                emitted = pred.spec_step(tokens, positions)
+            else:
+                ids = pred.decode_step(tokens, positions)
+        except CacheExhaustedError as e:
+            # preempt-first (serving/preempt.py): instead of
+            # failing the named victims, the lowest-tier
+            # longest-idle stream gives its pages back (swap or
+            # drop) and every survivor retries the IDENTICAL
+            # step next iteration — the transactional rollback
+            # already undid this call's allocations, so the
+            # retry is bit-exact. policy 'off' restores the
+            # legacy typed shed (the fleet router retries it
+            # cross-replica).
+            _cache_exhausted.inc()
+            policy = preempt_policy()
+            preempted = False
+            if policy != 'off':
+                for slot in list(e.slots):
+                    lane = lanes.get(slot)
+                    if lane is not None and \
+                            lane.pos + 1 > pred.window:
+                        # outgrew its own page window: no
+                        # preemption can ever make it fit
                         self._finish_lane(
                             lanes, slot, FAILED,
-                            error='DeadlineExceededError: expired '
-                                  'mid-decode',
+                            error='CacheExhaustedError: %s' % e,
                             pred=pred, wstate=wstate)
-                        _deadline_expired.inc()
-                ready = [s for s, ln in lanes.items() if ln.ready]
-                if not ready:
-                    continue
+                victim = pick_victim(lanes)
+                if victim is not None:
+                    self._preempt_lane(pred, lanes, victim,
+                                       wstate, policy)
+                    preempted = True
+            if not preempted:
+                for slot in e.slots:
+                    if slot in lanes:
+                        self._finish_lane(
+                            lanes, slot, FAILED,
+                            error='CacheExhaustedError: %s' % e,
+                            pred=pred, wstate=wstate)
+            return
+        except Exception as e:   # noqa: BLE001 — engine survives
+            for slot in ready:
+                if slot in lanes:
+                    self._finish_lane(lanes, slot, FAILED,
+                                      error=repr(e), pred=pred,
+                                      wstate=wstate)
+            return
+        dt = time.perf_counter() - t0
+        _decode_steps.inc()
+        _token_latency.observe(dt)
+        _decode_batch.observe(len(ready))
+        with RecordEvent('serve.accept'):
+            if speculative:
+                # per-slot mixed accept lengths in the SAME iteration:
+                # each lane consumes its own emitted prefix, stopping
+                # early on eos/budget/cancel
                 for slot in ready:
-                    tokens[slot] = lanes[slot].tok
-                    positions[slot] = lanes[slot].pos
-                t0 = time.perf_counter()
-                try:
-                    if speculative:
-                        emitted = pred.spec_step(tokens, positions)
-                    else:
-                        ids = pred.decode_step(tokens, positions)
-                except CacheExhaustedError as e:
-                    # preempt-first (serving/preempt.py): instead of
-                    # failing the named victims, the lowest-tier
-                    # longest-idle stream gives its pages back (swap or
-                    # drop) and every survivor retries the IDENTICAL
-                    # step next iteration — the transactional rollback
-                    # already undid this call's allocations, so the
-                    # retry is bit-exact. policy 'off' restores the
-                    # legacy typed shed (the fleet router retries it
-                    # cross-replica).
-                    _cache_exhausted.inc()
-                    policy = preempt_policy()
-                    preempted = False
-                    if policy != 'off':
-                        for slot in list(e.slots):
-                            lane = lanes.get(slot)
-                            if lane is not None and \
-                                    lane.pos + 1 > pred.window:
-                                # outgrew its own page window: no
-                                # preemption can ever make it fit
-                                self._finish_lane(
-                                    lanes, slot, FAILED,
-                                    error='CacheExhaustedError: %s' % e,
-                                    pred=pred, wstate=wstate)
-                        victim = pick_victim(lanes)
-                        if victim is not None:
-                            self._preempt_lane(pred, lanes, victim,
-                                               wstate, policy)
-                            preempted = True
-                    if not preempted:
-                        for slot in e.slots:
-                            if slot in lanes:
-                                self._finish_lane(
-                                    lanes, slot, FAILED,
-                                    error='CacheExhaustedError: %s' % e,
-                                    pred=pred, wstate=wstate)
-                    continue
-                except Exception as e:   # noqa: BLE001 — engine survives
-                    for slot in ready:
-                        if slot in lanes:
-                            self._finish_lane(lanes, slot, FAILED,
-                                              error=repr(e), pred=pred,
-                                              wstate=wstate)
-                    continue
-                dt = time.perf_counter() - t0
-                _decode_steps.inc()
-                _token_latency.observe(dt)
-                _decode_batch.observe(len(ready))
-                if speculative:
-                    # per-slot mixed accept lengths in the SAME
-                    # iteration: each lane consumes its own emitted
-                    # prefix, stopping early on eos/budget/cancel
-                    for slot in ready:
-                        for tok in emitted.get(slot, ()):
-                            lanes[slot].pos += 1
-                            if not self._lane_accept(lanes, slot,
-                                                     int(tok),
-                                                     pred=pred,
-                                                     wstate=wstate):
-                                break
-                else:
-                    for slot in ready:
+                    for tok in emitted.get(slot, ()):
                         lanes[slot].pos += 1
-                        self._lane_accept(lanes, slot, int(ids[slot]),
-                                          pred=pred, wstate=wstate)
-                _occupancy.set(self._active_total)
-                # re-snapshot after evictions so an idle worker reports
-                # zero held tokens, not its last busy state
-                self._slot_tokens[wid] = {s: ln.pos
-                                          for s, ln in lanes.items()}
+                        if not self._lane_accept(lanes, slot, int(tok),
+                                                 pred=pred,
+                                                 wstate=wstate):
+                            break
+            else:
+                for slot in ready:
+                    lanes[slot].pos += 1
+                    self._lane_accept(lanes, slot, int(ids[slot]),
+                                      pred=pred, wstate=wstate)
+        _occupancy.set(self._active_total)
+        # re-snapshot after evictions so an idle worker reports
+        # zero held tokens, not its last busy state
+        self._slot_tokens[wid] = {s: ln.pos
+                                  for s, ln in lanes.items()}

@@ -105,6 +105,7 @@ from ..distributed import wire
 from ..flags import get_flag
 from ..obs import telemetry
 from ..obs import trace as _trace
+from ..profiler import RecordEvent
 from .engine import QUEUED, RUNNING, DONE, CANCELLED, FAILED
 
 __all__ = ['FleetRouter', 'FleetAutoscaler', 'FleetRequest',
@@ -1614,7 +1615,7 @@ class FleetRouter(object):
         timeout = float(timeout if timeout is not None
                         else get_flag('fleet_deploy_timeout'))
         results = {}
-        with _trace.span('fleet.deploy', kind='fleet',
+        with RecordEvent('fleet.deploy', kind='fleet',
                          replicas=len(self._reps),
                          min_version=min_version or 0):
             for ep in self.replicas():
@@ -1655,7 +1656,7 @@ class FleetRouter(object):
                 if req.priority <= 0:
                     rep.active.pop(req.id, None)
                     self._requeue_locked(req)
-        with _trace.span('fleet.drain', kind='fleet',
+        with RecordEvent('fleet.drain', kind='fleet',
                          endpoint=rep.endpoint):
             while True:
                 with self._mu:

@@ -34,6 +34,14 @@ after a release is deterministic and bit-exact.
 Telemetry: serving.kv_pages_in_use / serving.kv_pages_free gauges,
 serving.prefix_hits / serving.prefix_tokens_reused counters,
 serving.prefill_chunks histogram (chunks per admitted prompt).
+
+Spans (profiler.RecordEvent), the same four in each step:
+`paged.decode.tables` / `paged.prefill.tables` (copy-on-write, page
+allocation, the page-table rows and the other feed arrays), the
+executor's `exe.run` with its children, `paged.*.book` (unref, lengths,
+prefix registration, gauges: host work that overlaps the device's),
+and `paged.*.fetch` (`np.asarray(ids)`: the wait for the device and
+the transfer; in prefill only on a prompt's last chunk).
 """
 from __future__ import annotations
 
@@ -42,6 +50,7 @@ import numpy as np
 from ..executor import Scope
 from ..flags import get_flag
 from ..obs import telemetry
+from ..profiler import RecordEvent
 from .decode import DecodePredictor
 from .paging import (CacheExhaustedError, PagePool, PageTable, PrefixCache,
                      chain_keys)
@@ -367,50 +376,53 @@ class PagedDecodePredictor(DecodePredictor):
         C, P, pt = self.prefill_chunk, self.pages_per_slot, self.page_tokens
         n = min(C, len(prompt) - start)
         cows, grows = [], []
-        before = len(table.pages)
-        try:
-            pair = table.cow_for_append(start)
-            if pair is not None:
-                cows.append((table, start // pt, pair))
-            table.ensure(start + n)
-        except CacheExhaustedError as e:
-            self._rollback(cows, grows)
-            raise CacheExhaustedError(str(e), slots=[slot])
-        if len(table.pages) > before:
-            grows.append((table, before))
-        tokens = np.zeros((1, C, 1), np.int64)
-        tokens[0, :n, 0] = prompt[start:start + n]
-        positions = (start + np.arange(C, dtype=np.int32))
-        table_feed = np.zeros((1, P), np.int32)
-        table.row(table_feed[0])
-        cow_src = np.zeros((1,), np.int32)
-        cow_dst = np.zeros((1,), np.int32)
-        if cows:
-            cow_src[0], cow_dst[0] = cows[0][2]
+        with RecordEvent('paged.prefill.tables'):
+            before = len(table.pages)
+            try:
+                pair = table.cow_for_append(start)
+                if pair is not None:
+                    cows.append((table, start // pt, pair))
+                table.ensure(start + n)
+            except CacheExhaustedError as e:
+                self._rollback(cows, grows)
+                raise CacheExhaustedError(str(e), slots=[slot])
+            if len(table.pages) > before:
+                grows.append((table, before))
+            tokens = np.zeros((1, C, 1), np.int64)
+            tokens[0, :n, 0] = prompt[start:start + n]
+            positions = (start + np.arange(C, dtype=np.int32))
+            table_feed = np.zeros((1, P), np.int32)
+            table.row(table_feed[0])
+            cow_src = np.zeros((1,), np.int32)
+            cow_dst = np.zeros((1,), np.int32)
+            if cows:
+                cow_src[0], cow_dst[0] = cows[0][2]
+            feed = {'prefill_tokens': tokens,
+                    'prefill_positions': positions,
+                    'prefill_len': np.array([n], np.int32),
+                    'prefill_last': np.array([n - 1], np.int32),
+                    'prefill_page_table': table_feed,
+                    'prefill_cow_src': cow_src,
+                    'prefill_cow_dst': cow_dst}
         logits, ids = self._exe.run(
-            self._pair.prefill_program,
-            feed={'prefill_tokens': tokens,
-                  'prefill_positions': positions,
-                  'prefill_len': np.array([n], np.int32),
-                  'prefill_last': np.array([n - 1], np.int32),
-                  'prefill_page_table': table_feed,
-                  'prefill_cow_src': cow_src,
-                  'prefill_cow_dst': cow_dst},
+            self._pair.prefill_program, feed=feed,
             fetch_list=self._pair.prefill_fetches,
             scope=self._scope, return_numpy=False)
-        for table_, _idx, (src, _dst) in cows:
-            table_.pool.unref(src)
-        table.length = start + n
-        st.chunks += 1
-        self._update_gauges()
-        if table.length < len(prompt):
-            return None
-        self._prefix.register(prompt, table)
-        del self._pending[slot]
-        _prefill_chunks.observe(st.chunks)
-        tok = int(np.asarray(ids)[0])
-        if return_logits:
-            return tok, np.asarray(logits)[0]
+        with RecordEvent('paged.prefill.book'):
+            for table_, _idx, (src, _dst) in cows:
+                table_.pool.unref(src)
+            table.length = start + n
+            st.chunks += 1
+            self._update_gauges()
+            if table.length < len(prompt):
+                return None
+            self._prefix.register(prompt, table)
+            del self._pending[slot]
+            _prefill_chunks.observe(st.chunks)
+        with RecordEvent('paged.prefill.fetch'):
+            tok = int(np.asarray(ids)[0])
+            if return_logits:
+                return tok, np.asarray(logits)[0]
         return tok
 
     def decode_step(self, tokens, positions, return_logits=False):
@@ -425,58 +437,61 @@ class PagedDecodePredictor(DecodePredictor):
         CacheExhaustedError(slots=[...]) names the victims — the
         caller releases or evicts them and retries the same feed."""
         S, P, pt = self.slots, self.pages_per_slot, self.page_tokens
-        tokens = np.asarray(tokens, np.int64).reshape(S, 1, 1)
-        positions = np.asarray(positions, np.int32).reshape(S)
-        table_feed = np.zeros((S, P), np.int32)
-        pos_feed = np.zeros((S,), np.int32)
-        cow_src = np.zeros((S,), np.int32)
-        cow_dst = np.zeros((S,), np.int32)
-        cows, grows, failed, live = [], [], [], []
-        for slot in sorted(self._tables):
-            if slot in self._pending:
-                continue              # mid-prefill: stays on null pages
-            table = self._tables[slot]
-            pos = int(positions[slot])
-            before = len(table.pages)
-            try:
-                pair = table.cow_for_append(pos)
+        with RecordEvent('paged.decode.tables'):
+            tokens = np.asarray(tokens, np.int64).reshape(S, 1, 1)
+            positions = np.asarray(positions, np.int32).reshape(S)
+            table_feed = np.zeros((S, P), np.int32)
+            pos_feed = np.zeros((S,), np.int32)
+            cow_src = np.zeros((S,), np.int32)
+            cow_dst = np.zeros((S,), np.int32)
+            cows, grows, failed, live = [], [], [], []
+            for slot in sorted(self._tables):
+                if slot in self._pending:
+                    continue          # mid-prefill: stays on null pages
+                table = self._tables[slot]
+                pos = int(positions[slot])
+                before = len(table.pages)
+                try:
+                    pair = table.cow_for_append(pos)
+                    if pair is not None:
+                        cows.append((table, pos // pt, pair))
+                    table.ensure(pos + 1)
+                except CacheExhaustedError:
+                    failed.append(slot)
+                    continue
+                if len(table.pages) > before:
+                    grows.append((table, before))
+                table.row(table_feed[slot])
+                pos_feed[slot] = pos
                 if pair is not None:
-                    cows.append((table, pos // pt, pair))
-                table.ensure(pos + 1)
-            except CacheExhaustedError:
-                failed.append(slot)
-                continue
-            if len(table.pages) > before:
-                grows.append((table, before))
-            table.row(table_feed[slot])
-            pos_feed[slot] = pos
-            if pair is not None:
-                cow_src[slot], cow_dst[slot] = pair
-            live.append(slot)
-        if failed:
-            self._rollback(cows, grows)
-            self._update_gauges()
-            raise CacheExhaustedError(
-                'KV page pool exhausted for slot(s) %s'
-                % ','.join(map(str, failed)), slots=failed)
+                    cow_src[slot], cow_dst[slot] = pair
+                live.append(slot)
+            if failed:
+                self._rollback(cows, grows)
+                self._update_gauges()
+                raise CacheExhaustedError(
+                    'KV page pool exhausted for slot(s) %s'
+                    % ','.join(map(str, failed)), slots=failed)
+            feed = {'decode_tokens': tokens,
+                    'decode_step_idx': pos_feed,
+                    'decode_page_table': table_feed,
+                    'decode_cow_src': cow_src,
+                    'decode_cow_dst': cow_dst}
         logits, ids = self._exe.run(
-            self._pair.decode_program,
-            feed={'decode_tokens': tokens,
-                  'decode_step_idx': pos_feed,
-                  'decode_page_table': table_feed,
-                  'decode_cow_src': cow_src,
-                  'decode_cow_dst': cow_dst},
+            self._pair.decode_program, feed=feed,
             fetch_list=self._pair.decode_fetches,
             scope=self._scope, return_numpy=False)
-        for table, _idx, (src, _dst) in cows:
-            table.pool.unref(src)
-        for slot in live:
-            table = self._tables[slot]
-            table.length = max(table.length, int(positions[slot]) + 1)
-        self._update_gauges()
-        if return_logits:
-            return np.asarray(ids), np.asarray(logits)
-        return np.asarray(ids)
+        with RecordEvent('paged.decode.book'):
+            for table, _idx, (src, _dst) in cows:
+                table.pool.unref(src)
+            for slot in live:
+                table = self._tables[slot]
+                table.length = max(table.length, int(positions[slot]) + 1)
+            self._update_gauges()
+        with RecordEvent('paged.decode.fetch'):
+            if return_logits:
+                return np.asarray(ids), np.asarray(logits)
+            return np.asarray(ids)
 
     def prefill(self, prompts, slot_ids, return_logits=False):
         """Dense-ABI prefill (the parity / generate() path): each

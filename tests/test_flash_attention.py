@@ -401,47 +401,6 @@ def test_unknown_fwd_arm_env_raises_at_import():
 
 
 @pytest.mark.parametrize('causal', [False, True])
-def test_twopass_extra_flops_noted_for_work_model(causal):
-    """The twopass forward executes a second QK sweep that the
-    2-matmul cost model (and XLA's cost analysis, blind inside the
-    custom call) cannot see; the arm notes it at trace time and
-    obs/perf drains it so live MFU divides by work that actually ran.
-    Exact bookkeeping: 2*BH*visited_blocks*bq*bk*d, visited stopping
-    at the diagonal under causal."""
-    from paddle_tpu.obs import perf as obsperf
-    from paddle_tpu.pallas import flash_attention as fa
-    rng = np.random.RandomState(8)
-    BH, T, d = 2, 256, 128
-    q = jnp.asarray(rng.randn(BH, T, d).astype('float32')) * 0.3
-    k = jnp.asarray(rng.randn(BH, T, d).astype('float32')) * 0.3
-    v = jnp.asarray(rng.randn(BH, T, d).astype('float32'))
-    try:
-        _force_fwd_arm(fa, 'online')
-        fa.take_extra_flops()   # discard notes from earlier tests
-        fa._fwd(q, k, v, causal, d ** -0.5, INTERPRET)
-        assert fa.take_extra_flops() == 0.0   # online = the model
-        _force_fwd_arm(fa, 'twopass')
-        fa._fwd(q, k, v, causal, d ** -0.5, INTERPRET)
-        bq, bk = fa._block_sizes(T, d, fwd=True, arm='twopass')
-        nq, nk = T // bq, T // bk
-        if causal:
-            visited = sum(((i + 1) * bq - 1) // bk + 1
-                          for i in range(nq))
-        else:
-            visited = nq * nk
-        want = 2.0 * BH * visited * bq * bk * d
-        # drained through the obs/perf hook the executor uses
-        assert obsperf.pallas_extra_flops() == want
-        assert obsperf.pallas_extra_flops() == 0.0   # destructive
-        # a second call with the same shapes hits the jit cache: no
-        # re-trace, no double-count
-        fa._fwd(q, k, v, causal, d ** -0.5, INTERPRET)
-        assert fa.take_extra_flops() == 0.0
-    finally:
-        _force_fwd_arm(fa, '')
-
-
-@pytest.mark.parametrize('causal', [False, True])
 def test_twopass_block_table_is_per_arm(causal):
     """The lane-parallel bk sweep tunes the twopass arm separately:
     an entry in _BLOCK_TABLE_FWD_TWOPASS must bind ONLY the twopass

@@ -180,6 +180,7 @@ def _fast_retry():
 
 
 def _events_of(obs_dir):
+    trace.flush()       # spans are buffered: move them into the log
     out = []
     for fn in sorted(os.listdir(obs_dir)):
         if fn.startswith('events-'):

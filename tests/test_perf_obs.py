@@ -59,6 +59,7 @@ def obs_on(tmp_path):
 
 
 def _events(obs_dir):
+    trace.flush()       # spans are buffered: move them into the log
     out = []
     for dirpath, _, files in os.walk(obs_dir):
         for fn in files:
@@ -85,51 +86,45 @@ def test_two_step_train_emits_perf_telemetry(obs_on):
     """The headline acceptance path: two identical train steps -> jit
     hit+miss counts, nonzero step latency, live hbm gauges, and the
     second run adds NO new xla.compile span."""
-    fluid.set_flags({'FLAGS_perf_peak_tflops': 1.0})
-    try:
-        loss, feed = _tiny_train()
-        exe = fluid.Executor()
-        exe.run(fluid.default_startup_program())
-        exe.run(feed=feed, fetch_list=[loss])
+    loss, feed = _tiny_train()
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    exe.run(feed=feed, fetch_list=[loss])
 
-        snap = telemetry.snapshot()
-        assert snap['counters']['xla.jit_cache.miss'] >= 2  # startup+main
-        compiles_before = [e for e in _events(obs_on)
-                           if e.get('name') == 'xla.compile']
-        assert compiles_before, 'first run must trace xla.compile spans'
-        for e in compiles_before:
-            assert e.get('fingerprint'), 'span must carry a fingerprint'
-        assert snap['hists']['xla.compile_latency']['count'] == \
-            snap['counters']['xla.jit_cache.miss']
+    snap = telemetry.snapshot()
+    assert snap['counters']['xla.jit_cache.miss'] >= 2  # startup+main
+    compiles_before = [e for e in _events(obs_on)
+                       if e.get('name') == 'xla.compile']
+    assert compiles_before, 'first run must trace xla.compile spans'
+    for e in compiles_before:
+        assert e.get('fingerprint'), 'span must carry a fingerprint'
+    assert snap['hists']['xla.compile_latency']['count'] == \
+        snap['counters']['xla.jit_cache.miss']
 
-        exe.run(feed=feed, fetch_list=[loss])   # identical -> pure hit
-        snap = telemetry.snapshot()
-        assert snap['counters']['xla.jit_cache.hit'] >= 1
-        compiles_after = [e for e in _events(obs_on)
-                          if e.get('name') == 'xla.compile']
-        assert len(compiles_after) == len(compiles_before), \
-            'cache hit must not emit a new compile span'
+    exe.run(feed=feed, fetch_list=[loss])   # identical -> pure hit
+    snap = telemetry.snapshot()
+    assert snap['counters']['xla.jit_cache.hit'] >= 1
+    compiles_after = [e for e in _events(obs_on)
+                      if e.get('name') == 'xla.compile']
+    assert len(compiles_after) == len(compiles_before), \
+        'cache hit must not emit a new compile span'
 
-        # live step attribution
-        assert snap['hists']['perf.step_latency']['count'] == 3
-        assert snap['hists']['perf.step_latency']['sum'] > 0
-        assert snap['counters']['perf.steps'] == 3
-        # hbm gauges live even on CPU (scope-footprint fallback): the
-        # fc weight/bias are persistable device arrays by now
-        assert snap['gauges']['hbm.bytes_in_use'] > 0
-        assert snap['gauges']['hbm.watermark_bytes'] >= \
-            snap['gauges']['hbm.bytes_in_use']
-        assert snap['gauges']['hbm.scope_bytes'] > 0
-        # cost analysis fed the work model -> nonzero MFU against the
-        # pinned 1-TFLOP/s peak
-        assert snap['gauges']['perf.achieved_tflops'] > 0
-        assert snap['gauges']['perf.mfu'] > 0
+    # live step attribution: every run is counted, and the two
+    # whose fetch came back to the host (the startup program
+    # fetches nothing) have a latency
+    assert snap['hists']['perf.step_latency']['count'] == 2
+    assert snap['hists']['perf.step_latency']['sum'] > 0
+    assert snap['counters']['perf.steps'] == 3
+    # hbm gauges live even on CPU (scope-footprint fallback): the
+    # fc weight/bias are persistable device arrays by now
+    assert snap['gauges']['hbm.bytes_in_use'] > 0
+    assert snap['gauges']['hbm.watermark_bytes'] >= \
+        snap['gauges']['hbm.bytes_in_use']
+    assert snap['gauges']['hbm.scope_bytes'] > 0
 
-        stats = exe.jit_cache_stats()
-        assert stats['segment_misses'] == stats['compiled_segments']
-        assert stats['segment_hits'] >= 1
-    finally:
-        fluid.set_flags({'FLAGS_perf_peak_tflops': 0.0})
+    stats = exe.jit_cache_stats()
+    assert stats['segment_misses'] == stats['compiled_segments']
+    assert stats['segment_hits'] >= 1
 
 
 def test_prepared_program_fingerprint_and_cost(obs_on):
@@ -140,8 +135,10 @@ def test_prepared_program_fingerprint_and_cost(obs_on):
     prepared = [p for k, p in exe._prepared_cache.items()
                 if k[0] != 'block_run']
     assert all(p.fingerprint for p in prepared)
-    # the train program's matmul segment must report analytical flops
-    assert any(p.cost_flops > 0 for p in prepared)
+    # each run's span carries its prepared program's fingerprint
+    runs = [s['fingerprint'] for s in trace.spans()
+            if s['name'] == 'exe.run']
+    assert runs == [p.fingerprint for p in prepared]
 
 
 def test_disabled_mode_records_nothing():
@@ -472,5 +469,4 @@ def test_bench_suite_quick_stamps_gauges():
     assert row['model'] == 'mnist' and 'error' not in row
     assert row['compile_ms'] > 0
     assert row['hbm_peak'] > 0
-    assert 'mfu' in row
     assert 'decode_speedup' not in row   # subprocess extras skipped

@@ -230,17 +230,20 @@ def _run_contended(eng, ref_a, ref_b):
 @pytest.mark.timeout(600)
 def test_preempt_swap_resume_bit_exact(predictor, ref_dec,
                                        policy_flags):
-    from paddle_tpu.obs import telemetry
+    from paddle_tpu.obs import telemetry, trace
     ref_a, ref_b = ref_dec.generate(PA, GEN), ref_dec.generate(PB, GEN)
     _dec, eng = _tight_engine(predictor)
     telemetry.enable()
     try:
         telemetry.reset()
+        trace.clear()
         st = _run_contended(eng, ref_a, ref_b)
         snap = telemetry.snapshot()
+        spans = trace.spans()
     finally:
         telemetry.disable(final_flush=False)
         telemetry.reset()
+        trace.clear()
     assert st['preemptions'] >= 1 and st['resumes'] >= 1
     assert st['preempted_streams'] == 0   # everyone came back
     assert st['swap_host_bytes'] == 0     # ... and gave its budget back
@@ -249,6 +252,20 @@ def test_preempt_swap_resume_bit_exact(predictor, ref_dec,
     assert snap['counters']['serving.swap_bytes'] >= 1
     assert snap['hists']['serving.resume_latency']['count'] \
         == st['resumes']
+    # the preempted stream's spans: one serve.requeue per resumption
+    # beside its three phases, under its id; serve.queue stays the
+    # FIRST wait, so the requeues lie after it
+    requeues = [s for s in spans if s['name'] == 'serve.requeue']
+    assert len(requeues) == st['resumes']
+    victim = requeues[0]['sid']
+    mine = {s['name']: s for s in spans
+            if s['kind'] == 'request' and s['sid'] == victim}
+    assert set(mine) == {'serve.queue', 'serve.prefill', 'serve.decode',
+                         'serve.requeue'}
+    assert mine['serve.decode']['preemptions'] >= 1
+    assert mine['serve.decode']['state'] == 'DONE'
+    assert all(r['t0'] >= mine['serve.queue']['t1'] and r['t1'] > r['t0']
+               for r in requeues)
 
 
 @pytest.mark.timeout(600)

@@ -138,7 +138,7 @@ def run_one(model, mode, steps, full, quick=False):
     import jax
     if quick:
         # perf-gate feed: record through the obs perf observatory so
-        # the row carries compile/MFU/HBM columns alongside throughput
+        # the row carries compile/HBM columns alongside throughput
         from paddle_tpu.obs import telemetry, perf
         telemetry.reset()
         telemetry.enable()
@@ -164,7 +164,6 @@ def run_one(model, mode, steps, full, quick=False):
            'loss': round(float(np.asarray(lv[0]).mean()), 4)}
     if quick:
         snap = telemetry.snapshot()
-        row['mfu'] = round(snap['gauges']['perf.mfu'], 4)
         row['compile_ms'] = round(
             snap['hists']['xla.compile_latency']['sum'] * 1e3, 1)
         row['hbm_peak'] = int(snap['gauges']['hbm.watermark_bytes'])
@@ -628,7 +627,7 @@ def main():
                          'attention over the mesh (longcontext model)')
     ap.add_argument('--quick', action='store_true',
                     help='fast perf-gate feed: local mode on a small '
-                         'model set, obs-gauge mfu/compile_ms/hbm_peak '
+                         'model set, obs-gauge compile_ms/hbm_peak '
                          'stamped into each row, slow subprocess '
                          'extras skipped (tools/perf_gate.py '
                          '--run-suite consumes this)')

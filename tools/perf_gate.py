@@ -22,7 +22,7 @@ Modes:
 
     python tools/perf_gate.py --run-suite [--baseline base.json]
         run `tools/bench_suite.py --quick` now, stamp its rows (incl.
-        the obs-gauge mfu/compile_ms/hbm_peak columns) into a metric
+        the obs-gauge compile_ms/hbm_peak columns) into a metric
         set, and gate it against --baseline (a previous --save file)
 
     python tools/perf_gate.py --smoke
