@@ -389,8 +389,10 @@ def phase_serve(cfg, env):
         'compiled_segments': jit['compiled_segments'],
         'ref_first_token_gap_spreads': round(gap, 4),
         'ref_first_token_is_argmax': bool(streams[0][0] == ref.argmax()),
-        # the paged programs attend with the naive ops; the reference
-        # forward is the flash kernel on fp32 operands
+        # the paged prefill program attends with the naive ops and the
+        # decode program with the paged_attention kernel (neither is a
+        # flash route); the reference forward is the flash kernel on
+        # fp32 operands
         'flash_route': _flash_route(before),
         'pool': dec.pool_stats(),
         'memory_stats': _memory(stats),

@@ -548,7 +548,7 @@ def build_decode_program(spec, slots):
 # The dense ring generalized to a vLLM-style page pool: one
 # [num_pages, page_tokens, H, dk] pool var per layer per K/V, and a
 # per-slot page TABLE fed each step mapping logical position j to
-# pool[table[j // pt], j % pt]. Both programs stay static-shape (pool
+# pool[table[j // pt], j % pt]. Every program stays static-shape (pool
 # size, table width, chunk width fixed at build time), so each compiles
 # exactly once; allocation, COW and prefix sharing are HOST decisions
 # (serving/paging.py) that only ever change feed VALUES. Physical page
@@ -556,6 +556,16 @@ def build_decode_program(spec, slots):
 # always masked. Validity is absolute (j <= position): no ring wrap,
 # so running out of pages is a typed host-side error, never a silent
 # slide (COVERAGE divergence 8).
+#
+# Who reads the pool how. The DECODE program, which runs every step
+# over every slot, holds one paged_attention op a layer: it reads each
+# lane's live pages in place (a Pallas kernel on a TPU; off it, the
+# gather composition below as the op's reference lowering). The
+# PREFILL and VERIFY programs still gather: kv_page_gather copies the
+# table's whole window into a dense [B, P*pt, H, dk] tensor and matmul,
+# a paged mask, softmax and matmul run over all of it. A prefill chunk
+# gathers one slot's window, a sixteenth of what the decode step
+# copied; verify gathers every slot's.
 
 
 def _create_pool_vars(spec, num_pages, page_tokens):
@@ -620,6 +630,10 @@ def _paged_prefill_attention(x, spec, blk, pool, table, positions,
 
 def _paged_decode_attention(x, spec, blk, pool, table, positions,
                             cow_src, cow_dst):
+    """One decode step's attention: COW, append the new K/V row, then
+    ONE paged_attention op that reads each lane's live pages through
+    its table (no gathered window; see the op's docstring for its two
+    lowerings)."""
     q1, k1, v1 = _qkv_parts(x, spec, blk, 1)           # [S, 1, H, dh]
     for pool_var, new in ((pool[0], k1), (pool[1], v1)):
         _block_op('kv_page_cow',
@@ -630,19 +644,13 @@ def _paged_decode_attention(x, spec, blk, pool, table, positions,
                   inputs={'Pool': [pool_var], 'X': [new],
                           'Table': [table], 'Positions': [positions]},
                   outputs={'Out': [pool_var]})
-    q = sharding_constraint(L.transpose(q1, perm=[0, 2, 1, 3]),
-                            (None, _tp_ax(spec), None, None))
-    kt = _paged_gather(pool[0], table, spec)           # [S, H, J, dh]
-    vt = _paged_gather(pool[1], table, spec)
-    scores = L.matmul(q, kt, transpose_y=True,
-                      alpha=1.0 / np.sqrt(spec.dh))    # [S, H, 1, J]
-    masked = _tmp_var()
-    _block_op('paged_decode_mask',
-              inputs={'X': [scores], 'Positions': [positions]},
-              outputs={'Out': [masked]})
-    probs = L.softmax(masked)
-    ctx = L.matmul(probs, vt)                          # [S, H, 1, dh]
-    ctx = L.transpose(ctx, perm=[0, 2, 1, 3])
+    ctx = _tmp_var()
+    _block_op('paged_attention',
+              inputs={'Q': [q1], 'KPool': [pool[0]], 'VPool': [pool[1]],
+                      'Table': [table], 'Positions': [positions]},
+              outputs={'Out': [ctx]},
+              attrs={'sm_scale': float(1.0 / np.sqrt(spec.dh)),
+                     'head_axis': _tp_ax(spec) or ''})  # [S, 1, H, dh]
     ctx = L.reshape(ctx, shape=[-1, 1, spec.dim])
     ctx = sharding_constraint(ctx, (None, None, None))
     return _named_fc(ctx, spec.dim, blk['proj'])

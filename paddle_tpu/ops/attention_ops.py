@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 
 from ..registry import register_op, op_emitter, register_vjp_grad, \
@@ -266,19 +267,35 @@ def _kv_page_append_emit(ctx, op):
             pool.at[page, positions % pt].set(x[:, 0].astype(pool.dtype)))
 
 
+def _gather_pages(pool, table):
+    """pool[table] as each row's dense sequence: [B, P*pt, H, dk]."""
+    B, P = table.shape
+    return pool[table].reshape(B, P * pool.shape[1],
+                               pool.shape[2], pool.shape[3])
+
+
+def _mask_after(x, positions):
+    """x [slots, H, 1, J] with the columns j > positions[s] set to
+    -1e9."""
+    j = jnp.arange(x.shape[-1], dtype=jnp.int32)
+    valid = j[None, :] <= positions[:, None]           # [slots, J]
+    valid = valid[:, None, None, :]                    # [slots, 1, 1, J]
+    return jnp.where(valid, x, -1e9)
+
+
 @op_emitter('kv_page_gather')
 def _kv_page_gather_emit(ctx, op):
     """Assemble each row's logical K or V sequence from the pool:
-    Pool [N, pt, H, dk], Table [B, P] int32 -> [B, P*pt, H, dk] (the
-    dense-cache layout attention already knows how to contract over).
-    Unpopulated table entries gather the null page — garbage that the
-    paged masks set to -1e9 before the softmax."""
+    Pool [N, pt, H, dk], Table [B, P] int32 -> [B, P*pt, H, dk], the
+    whole window of every row as a dense cache that matmul + mask +
+    softmax + matmul contract over. The prefill and verify programs
+    attend this way (a prefill chunk gathers one row); the decode
+    program does not gather: its `paged_attention` reads the live pages
+    in place. Unpopulated table entries gather the null page — garbage
+    that the paged masks set to -1e9 before the softmax."""
     pool = ctx.get(op.single_input('Pool'))
     table = ctx.get(op.single_input('Table')).astype(jnp.int32)
-    B, P = table.shape
-    out = pool[table].reshape(B, P * pool.shape[1],
-                              pool.shape[2], pool.shape[3])
-    ctx.set(op.single_output('Out'), out)
+    ctx.set(op.single_output('Out'), _gather_pages(pool, table))
 
 
 @op_emitter('paged_decode_mask')
@@ -289,13 +306,85 @@ def _paged_decode_mask_emit(ctx, op):
     position j, valid iff j <= positions[s] (the token being appended
     this step included). No ring wrap to undo; same set-to--1e9
     semantics as decode_mask so masked lanes underflow to exactly 0.0
-    after the softmax's exp — the bit-exactness contract."""
+    after the softmax's exp — the bit-exactness contract. It is the
+    mask inside paged_attention's reference lowering."""
     x = ctx.get(op.single_input('X'))
     positions = ctx.get(op.single_input('Positions')).astype(jnp.int32)
-    j = jnp.arange(x.shape[-1], dtype=jnp.int32)
-    valid = j[None, :] <= positions[:, None]           # [slots, J]
-    valid = valid[:, None, None, :]                    # [slots, 1, 1, J]
-    ctx.set(op.single_output('Out'), jnp.where(valid, x, -1e9))
+    ctx.set(op.single_output('Out'), _mask_after(x, positions))
+
+
+def _paged_attention_reference(q, k_pool, v_pool, table, positions,
+                               sm_scale, pin):
+    """The composition the decode program held before this op, op for
+    op (kv_page_gather, transpose, matmul with alpha, paged_decode_mask,
+    softmax, matmul): the arithmetic every bit-exact serving contract
+    was written against. `pin` places the heads axis on a mesh."""
+    qt = pin(jnp.transpose(q, (0, 2, 1, 3)))                   # [S,H,1,dh]
+    kt = pin(jnp.transpose(_gather_pages(k_pool, table), (0, 2, 1, 3)))
+    vt = pin(jnp.transpose(_gather_pages(v_pool, table), (0, 2, 1, 3)))
+    scores = jnp.matmul(qt, jnp.swapaxes(kt, -1, -2)) * sm_scale
+    scores = _mask_after(scores, positions)                    # [S,H,1,J]
+    probs = jax.nn.softmax(scores.astype(jnp.float32),
+                           axis=-1).astype(scores.dtype)
+    return jnp.transpose(jnp.matmul(probs, vt), (0, 2, 1, 3))
+
+
+@op_emitter('paged_attention')
+def _paged_attention_emit(ctx, op):
+    """One decode step's attention through the page tables: Q
+    [S, 1, H, dh], KPool / VPool [N, pt, H, dh], Table [S, P] int32,
+    Positions [S] int32, attrs sm_scale and head_axis -> Out
+    [S, 1, H, dh]. Lane s attends to its logical positions
+    0..Positions[s] (the row appended this step included), which the
+    table maps to pool[Table[s, j // pt], j % pt]. Idle and mid-prefill
+    lanes arrive with a zero table row and position 0: they read the
+    null page's first row and their output is discarded downstream.
+
+    Two lowerings of the one sum, chosen by what is there to see. On a
+    TPU (or under FLAGS_pallas_interpret), for pages the kernel tiles
+    (pallas/paged_attention.supported), the Pallas kernel reads each
+    lane's live pages out of the pool and nothing else; on a mesh it
+    runs per shard of the heads axis, as flash_attention does.
+    Everywhere else the reference composition gathers the window."""
+    from ..pallas import paged_attention as _pa
+    from ..flags import get_flag
+    q = ctx.get(op.single_input('Q'))
+    k_pool = ctx.get(op.single_input('KPool'))
+    v_pool = ctx.get(op.single_input('VPool'))
+    table = ctx.get(op.single_input('Table')).astype(jnp.int32)
+    positions = ctx.get(op.single_input('Positions')).astype(jnp.int32)
+    sm_scale = op.attr('sm_scale')
+    mesh = getattr(ctx, 'mesh', None)
+    axis = op.attr('head_axis', '')
+    if mesh is None or axis not in mesh.axis_names \
+            or q.shape[2] % mesh.shape[axis]:
+        axis = None
+    on_tpu = jax.default_backend() == 'tpu'
+    if _pa.supported(k_pool.shape[1], k_pool.shape[3]) and (
+            on_tpu or bool(get_flag('pallas_interpret'))):
+        kernel = functools.partial(_pa.paged_attention, sm_scale=sm_scale,
+                                   interpret=not on_tpu)
+        if mesh is not None and mesh.size > 1:
+            from jax import shard_map
+            from jax.sharding import PartitionSpec as P
+            lane, pool = P(None, axis, None), P(None, None, axis, None)
+            kernel = shard_map(kernel, mesh=mesh,
+                               in_specs=(lane, pool, pool, P(), P()),
+                               out_specs=lane, check_vma=False)
+        out = kernel(q[:, 0], k_pool, v_pool,
+                     jnp.clip(table, 0, k_pool.shape[0] - 1),
+                     positions)[:, None]
+    elif mesh is None:
+        out = _paged_attention_reference(q, k_pool, v_pool, table,
+                                         positions, sm_scale, lambda x: x)
+    else:
+        from ..parallel.mesh import named_sharding
+        pin = functools.partial(
+            jax.lax.with_sharding_constraint,
+            shardings=named_sharding(mesh, (None, axis, None, None)))
+        out = _paged_attention_reference(q, k_pool, v_pool, table,
+                                         positions, sm_scale, pin)
+    ctx.set(op.single_output('Out'), out)
 
 
 @op_emitter('spec_verify_mask')
@@ -394,6 +483,8 @@ register_op('kv_page_write', infer_shape=_kv_pool_update_infer,
 register_op('kv_page_append', infer_shape=_kv_pool_update_infer,
             no_grad=True)
 register_op('kv_page_gather', infer_shape=_kv_page_gather_infer,
+            no_grad=True)
+register_op('paged_attention', infer_shape=_ring_infer,
             no_grad=True)
 register_op('paged_decode_mask', infer_shape=_decode_mask_infer,
             no_grad=True)

@@ -1,0 +1,177 @@
+"""Decode attention over a paged K/V pool, in Pallas, for TPU: each
+lane reads its LIVE pages straight out of the pool through its page
+table and nothing else (the op is `paged_attention`,
+ops/attention_ops.py; the reference lowering there gathers the whole
+window and masks).
+
+One query row per lane: q [S, H, dh], pools [N, pt, H, dh] (the layout
+kv_page_cow/write/append keep), table [S, P] int32, positions [S]
+int32. Lane s attends to logical positions 0..positions[s].
+
+The pools stay in HBM; table and positions are scalar-prefetched. The
+grid is one step per lane, and a lane walks ceil((pos + 1) / pt) pages
+in blocks of `_BLOCK_PAGES`, each page one contiguous DMA (all heads
+together: pt * H * dh floats) into a double-buffered VMEM block. The
+block after the one being worked on is always in flight, across lanes
+too: a lane's last block starts the next lane's first. Pages past a
+lane's last are never copied.
+
+The heads stay together in a block, so a block is the [C, dh] matrix of
+C = pages * pt * H (token, head) rows and both contractions run on the
+MXU over all of it: scores [H, C] = q . block^T, of which row h keeps
+the columns of its own head (the others are masked like the dead tail
+of the last page) and an online softmax in fp32 (m, l, acc [H, dh])
+folds the blocks. That is H times the multiplies the sum needs, on a
+unit that has nothing else to do in a decode step; what the kernel
+waits for is the pages. The contractions run at Mosaic's default
+precision for float32 operands, one bfloat16 pass like every other
+matmul of the float32 serving path (1.7e-3 of the result on a v5e;
+Precision.HIGHEST reads 1.5e-7 and doubles the kernel's time where few
+lanes are live, because a block is multiplied whole: PERF.md, PR 25).
+
+A masked column contributes exp(-1e30 - m) = 0.0 times whatever the
+buffer holds there, so the V buffer is zeroed once (a page slot that
+was never copied into must not hold a NaN); K's never reaches the
+result unselected.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ['paged_attention', 'supported']
+
+_NEG_INF = -1e30
+# pages a block holds: 8 pages of 16 tokens x 16 heads x 128 floats are
+# 1 MB of K and 1 MB of V a block (2.5 us of HBM time, several times a
+# loop step's fixed cost), 4 MB of VMEM double-buffered. Measured on a
+# v5e at the 1.3B serving shapes (PERF.md, PR 25): 2 / 4 / 8 pages read
+# a full pool at 559 / 661 / 725 GB/s, 16 no faster than 8 and slower
+# where few lanes are live (a block is worked on whole, however few of
+# its pages are).
+_BLOCK_PAGES = 8
+
+
+def supported(page_tokens, head_dim):
+    """Shapes the kernel tiles: a (token, head) row is a whole number
+    of lanes and a page a whole number of sublane tiles."""
+    return head_dim % 128 == 0 and page_tokens % 8 == 0
+
+
+def _kernel(table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+            kbuf, vbuf, sems, slot_ref, *, sm_scale, pt, heads, bp,
+            pages_per_slot, lanes):
+    s = pl.program_id(0)
+    cols = bp * pt * heads
+
+    def n_pages(lane):
+        return jnp.minimum(pos_ref[lane] // pt + 1, pages_per_slot)
+
+    def copies(lane, blk, slot, wait=False):
+        """Start (or wait for) the copies of block `blk` of `lane`, its
+        live pages only, into buffer `slot`."""
+        live = n_pages(lane)
+        for j in range(bp):
+            g = blk * bp + j
+
+            @pl.when(g < live)
+            def _():
+                page = table_ref[lane * pages_per_slot + g]
+                for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                    dma = pltpu.make_async_copy(
+                        hbm.at[page], buf.at[slot, j], sems.at[which, slot])
+                    dma.wait() if wait else dma.start()
+
+    @pl.when(s == 0)
+    def _():
+        vbuf[...] = jnp.zeros_like(vbuf)
+        slot_ref[0] = 0
+        copies(0, 0, 0)
+
+    slot0 = slot_ref[0]
+    pos = pos_ref[s]
+    n_blk = pl.cdiv(n_pages(s), bp)
+    q = q_ref[...].astype(jnp.float32) * sm_scale            # [H, dh]
+    col = jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 1)
+    tok = col // heads                      # token of a column, in block
+    own = (col - tok * heads) == \
+        jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 0)
+
+    def block(i, carry):
+        m, l, acc = carry
+        slot = (slot0 + i) % 2
+
+        @pl.when(i + 1 < n_blk)
+        def _():
+            copies(s, i + 1, 1 - slot)
+
+        @pl.when(jnp.logical_and(i + 1 == n_blk, s + 1 < lanes))
+        def _():
+            copies(s + 1, 0, 1 - slot)
+
+        copies(s, i, slot, wait=True)
+        k = kbuf[slot].reshape(cols, k_hbm.shape[-1])
+        v = vbuf[slot].reshape(cols, v_hbm.shape[-1])
+        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        live = jnp.logical_and(own, tok <= pos - i * (bp * pt))
+        sc = jnp.where(live, sc, _NEG_INF)                   # [H, cols]
+        m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + p.sum(axis=-1, keepdims=True)
+        acc = alpha * acc + jnp.dot(p, v,
+                                    preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m, l, acc = jax.lax.fori_loop(
+        0, n_blk, block,
+        (jnp.full((heads, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((heads, 1), jnp.float32),
+         jnp.zeros((heads, q.shape[-1]), jnp.float32)))
+    slot_ref[0] = (slot0 + n_blk) % 2
+    o_ref[...] = (acc / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=('sm_scale', 'interpret'))
+def paged_attention(q, k_pool, v_pool, table, positions, sm_scale,
+                    interpret=False):
+    """q [S, H, dh], pools [N, pt, H, dh], table [S, P] int32,
+    positions [S] int32 -> [S, H, dh]: softmax over lane s's positions
+    0..positions[s] of sm_scale * q . k, times v, in fp32. A table
+    entry is read only below a lane's page count; it must name a page
+    of the pool (the caller clips)."""
+    S, H, dh = q.shape
+    N, pt = k_pool.shape[:2]
+    P = table.shape[1]
+    bp = min(_BLOCK_PAGES, P)
+    # a page as the [pt * H, dh] matrix it is in memory
+    k3 = k_pool.reshape(N, pt * H, dh)
+    v3 = v_pool.reshape(N, pt * H, dh)
+    kernel = functools.partial(
+        _kernel, sm_scale=float(sm_scale), pt=pt, heads=H, bp=bp,
+        pages_per_slot=P, lanes=S)
+    lane = pl.BlockSpec((None, H, dh), lambda s, *_: (s, 0, 0))
+    buf = pltpu.VMEM((2, bp, pt * H, dh), k_pool.dtype)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S,),
+            in_specs=[lane, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=lane,
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        # lanes run in order: the buffer parity and the block in flight
+        # are carried from one to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',)),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name='paged_attention',
+    )(table.reshape(-1), positions, q, k3, v3)

@@ -33,7 +33,10 @@ after a release is deterministic and bit-exact.
 
 Telemetry: serving.kv_pages_in_use / serving.kv_pages_free gauges,
 serving.prefix_hits / serving.prefix_tokens_reused counters,
-serving.prefill_chunks histogram (chunks per admitted prompt).
+serving.prefill_chunks histogram (chunks per admitted prompt),
+serving.decode_pages_read / serving.decode_pages_window counters (the
+pages the decode steps' attention read, of slots x pages_per_slot a
+step; the same pair is on every `paged.decode.tables` span).
 
 Spans (profiler.RecordEvent), the same four in each step:
 `paged.decode.tables` / `paged.prefill.tables` (copy-on-write, page
@@ -62,6 +65,8 @@ _pages_free = telemetry.gauge('serving.kv_pages_free')
 _prefix_hits = telemetry.counter('serving.prefix_hits')
 _prefix_tokens = telemetry.counter('serving.prefix_tokens_reused')
 _prefill_chunks = telemetry.histogram('serving.prefill_chunks')
+_decode_pages_read = telemetry.counter('serving.decode_pages_read')
+_decode_pages_window = telemetry.counter('serving.decode_pages_window')
 
 
 class _PendingPrefill(object):
@@ -437,7 +442,7 @@ class PagedDecodePredictor(DecodePredictor):
         CacheExhaustedError(slots=[...]) names the victims — the
         caller releases or evicts them and retries the same feed."""
         S, P, pt = self.slots, self.pages_per_slot, self.page_tokens
-        with RecordEvent('paged.decode.tables'):
+        with RecordEvent('paged.decode.tables') as ev:
             tokens = np.asarray(tokens, np.int64).reshape(S, 1, 1)
             positions = np.asarray(positions, np.int32).reshape(S)
             table_feed = np.zeros((S, P), np.int32)
@@ -472,6 +477,14 @@ class PagedDecodePredictor(DecodePredictor):
                 raise CacheExhaustedError(
                     'KV page pool exhausted for slot(s) %s'
                     % ','.join(map(str, failed)), slots=failed)
+            # what the step's attention has to read against what a
+            # gather of every slot's window would: the lanes that take
+            # part hold ceil((pos + 1) / pt) pages each
+            ev.attrs['pages_read'] = pages_read = \
+                sum(int(pos_feed[slot]) // pt + 1 for slot in live)
+            ev.attrs['pages_window'] = S * P
+            _decode_pages_read.inc(pages_read)
+            _decode_pages_window.inc(S * P)
             feed = {'decode_tokens': tokens,
                     'decode_step_idx': pos_feed,
                     'decode_page_table': table_feed,
