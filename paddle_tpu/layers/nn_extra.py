@@ -20,6 +20,7 @@ __all__ = [
     'fake_quantize', 'polygon_box_transform', 'flash_attention',
     'auc', 'precision_recall', 'positive_negative_pair',
     'fused_softmax_cross_entropy',
+    'rms_norm', 'short_conv', 'gated_delta_rule',
 ]
 
 
@@ -559,6 +560,67 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, name=None):
                      inputs={'Q': [q], 'K': [k], 'V': [v]},
                      outputs={'Out': [out]},
                      attrs={'causal': causal, 'sm_scale': sm_scale})
+    return out
+
+
+def rms_norm(input, begin_norm_axis=1, epsilon=1e-6, param_attr=None,
+             name=None):
+    """x / sqrt(mean(x^2) + epsilon) * weight over the axes from
+    begin_norm_axis on, in float32 (op rms_norm). No bias, no mean."""
+    import numpy as np
+    from ..initializer import Constant
+    helper = LayerHelper('rms_norm', param_attr=param_attr, name=name)
+    w = helper.create_parameter(
+        attr=helper.param_attr,
+        shape=[int(np.prod(input.shape[begin_norm_axis:]))],
+        dtype=input.dtype, default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(type='rms_norm', inputs={'X': [input], 'Scale': [w]},
+                     outputs={'Y': [out]},
+                     attrs={'epsilon': epsilon,
+                            'begin_norm_axis': begin_norm_axis})
+    return out
+
+
+def short_conv(input, kernel=4, param_attr=None, name=None):
+    """Causal depthwise convolution of `kernel` taps over the sequence of
+    input [B, T, C], zeros before each row's first token, then silu (op
+    short_conv; its stateful chunk and step forms are what the paged
+    serving programs hold)."""
+    helper = LayerHelper('short_conv', param_attr=param_attr, name=name)
+    w = helper.create_parameter(attr=helper.param_attr,
+                                shape=[kernel, input.shape[-1]],
+                                dtype=input.dtype)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(type='short_conv', inputs={'X': [input], 'W': [w]},
+                     outputs={'Out': [out]})
+    return out
+
+
+def gated_delta_rule(qkv, ba, heads, key_dim, value_dim, beta_scale=1.0,
+                     a_log_attr=None, dt_bias_attr=None, block=64,
+                     name=None):
+    """The gated delta rule over whole sequences from zero state (op
+    gated_delta_chunk, ops/delta_rule_ops.py): qkv [B, T, H*(2dk+dv)]
+    holds q, k, v side by side, ba [B, T, 2H] the write-strength and the
+    decay logits; A_log and dt_bias are one learned scalar a head.
+    Returns [B, T, H*dv]."""
+    from ..initializer import Constant
+    helper = LayerHelper('gated_delta_rule', name=name)
+    a_log = helper.create_parameter(attr=a_log_attr, shape=[heads],
+                                    dtype='float32',
+                                    default_initializer=Constant(0.0))
+    dt_bias = helper.create_parameter(attr=dt_bias_attr, shape=[heads],
+                                      dtype='float32',
+                                      default_initializer=Constant(0.0))
+    out = helper.create_variable_for_type_inference(qkv.dtype)
+    helper.append_op(
+        type='gated_delta_chunk',
+        inputs={'QKV': [qkv], 'BA': [ba], 'ALog': [a_log],
+                'DtBias': [dt_bias]},
+        outputs={'Out': [out]},
+        attrs={'heads': heads, 'key_dim': key_dim, 'value_dim': value_dim,
+               'beta_scale': float(beta_scale), 'block': block})
     return out
 
 

@@ -213,6 +213,22 @@ def train_network(tokens, labels, cfg):
 # softmax) over same-length reduction axes, which is what makes greedy
 # decode bit-exact against full-prefix recompute (tests/test_serving.py).
 
+class DecodeTranspileError(ValueError):
+    """The loaded program is not a transpilable decoder-only LM, or what
+    is asked of it cannot serve its layer kinds."""
+
+
+def refuse_recurrent(spec, what):
+    """Raise for a spec with recurrent layers where `what` knows a
+    stream's state as pages only."""
+    if spec.recurrent_layers:
+        raise DecodeTranspileError(
+            '%s cannot serve a model with %s layers (layers %s): their '
+            'recurrent state is not in the page pool'
+            % (what, spec.kinds[spec.recurrent_layers[0]],
+               ','.join(map(str, spec.recurrent_layers))))
+
+
 class DecodeSpec(object):
     """Dims + parameter names extracted from a loaded LM program.
 
@@ -226,13 +242,23 @@ class DecodeSpec(object):
     dist_attr / surviving sharding_constraint ops so mesh serving can
     re-shard the same scope. mesh is the serving mesh spec string
     ('tp=2'; '' = single-chip), stamped by prepare_decoding.
+
+    kinds names each layer's mixer: 'full_attention' (K/V in the cache:
+    every layer of this block) or 'linear_attention' (a recurrent state
+    instead: models/hybrid.py, whose spec extends this one). The cache
+    variables exist for the layers in kv_layers only.
     """
 
     def __init__(self, vocab, dim, heads, layers, ffn, max_len, pos_len,
                  emb_w, pos_w, blocks, final_ln, head, use_flash=False,
-                 param_specs=None, mesh=''):
+                 param_specs=None, mesh='', kinds=None):
         self.vocab, self.dim, self.heads = vocab, dim, heads
         self.layers, self.ffn = layers, ffn
+        self.kinds = tuple(kinds or ('full_attention',) * layers)
+        self.kv_layers = [i for i, k in enumerate(self.kinds)
+                          if k == 'full_attention']
+        self.recurrent_layers = [i for i, k in enumerate(self.kinds)
+                                 if k == 'linear_attention']
         self.max_len, self.pos_len = max_len, pos_len
         self.dh = dim // heads
         self.emb_w, self.pos_w = emb_w, pos_w
@@ -249,7 +275,7 @@ class DecodeSpec(object):
             return ('kv_cache.layer%d.k' % layer,
                     'kv_cache.layer%d.v' % layer)
         out = []
-        for i in range(self.layers):
+        for i in self.kv_layers:
             out.extend(self.cache_names(i))
         return out
 
@@ -262,12 +288,31 @@ class DecodeSpec(object):
             return ('kv_pool.layer%d.k' % layer,
                     'kv_pool.layer%d.v' % layer)
         out = []
-        for i in range(self.layers):
+        for i in self.kv_layers:
             out.extend(self.pool_names(i))
         return out
 
+    def state_names(self):
+        """Recurrent-state var names (none: every layer here keeps K/V)."""
+        return []
+
+    @property
+    def pool_heads(self):
+        """Heads a page holds: the model's, except where a spec pads
+        them to whole tiles (models/hybrid.py)."""
+        return self.heads
+
     def pool_shape(self, num_pages, page_tokens):
-        return (num_pages, page_tokens, self.heads, self.dh)
+        return (num_pages, page_tokens, self.pool_heads, self.dh)
+
+    def build_paged_programs(self, slots, chunk, num_pages, page_tokens,
+                             pages_per_slot):
+        """The paged pair that serves this spec's block, as (prefill
+        program, feeds, fetches, decode program, feeds, fetches)."""
+        return build_paged_prefill_program(
+            self, chunk, num_pages, page_tokens, pages_per_slot) + \
+            build_paged_decode_program(
+                self, slots, num_pages, page_tokens, pages_per_slot)
 
     def cache_spec(self):
         """PartitionSpec (tuple form) for the K/V state: heads axis
@@ -359,21 +404,25 @@ def _create_cache_vars(spec, slots):
     return caches
 
 
-def _qkv_parts(x, spec, blk, t):
+def _qkv_parts(x, spec, blk, t, qk_norm=None):
     """qkv fc + per-part slice/reshape to [-1, t, H, dh] — the full
     path's heads() up to (not including) the transpose, which is the
     cache's storage layout. On a mesh each part is pinned heads-sharded
     (the cache/pool layout), a no-op single-chip; the qkv contraction
-    dim stays whole either way, so every element is bit-exact."""
+    dim stays whole either way, so every element is bit-exact.
+    `qk_norm(part, 'q' | 'k')`, where the caller's block norms q and k
+    whole before the heads are split."""
     qkv = _named_fc(x, 3 * spec.dim, blk['qkv'])
     D = spec.dim
 
-    def part(s, e):
+    def part(s, e, which=None):
         p = L.slice(qkv, axes=[2], starts=[s], ends=[e])
+        if qk_norm is not None and which:
+            p = qk_norm(p, which)
         p = L.reshape(p, shape=[-1, t, spec.heads, spec.dh])
         return sharding_constraint(p, (None, None, _tp_ax(spec), None))
 
-    return part(0, D), part(D, 2 * D), part(2 * D, 3 * D)
+    return part(0, D, 'q'), part(D, 2 * D, 'k'), part(2 * D, 3 * D)
 
 
 def _prefill_attention(x, spec, blk, cache, slot_idx):
@@ -569,20 +618,37 @@ def build_decode_program(spec, slots):
 
 
 def _create_pool_vars(spec, num_pages, page_tokens):
-    """Per-layer K/V page-pool vars: persistable + donated like the
-    ring caches (in-place device update), is_cache (never checkpointed)."""
+    """{layer: (K, V)} page-pool vars of the layers that keep K/V:
+    persistable + donated like the ring caches (in-place device update),
+    is_cache (never checkpointed)."""
     from ..framework import default_main_program
     block = default_main_program().global_block()
-    pools = []
-    for i in range(spec.layers):
+    pools = {}
+    for i in spec.kv_layers:
         kn, vn = spec.pool_names(i)
-        pools.append(tuple(
+        pools[i] = tuple(
             block.create_var(name=n,
                              shape=spec.pool_shape(num_pages, page_tokens),
                              dtype='float32', persistable=True,
                              stop_gradient=True, is_cache=True)
-            for n in (kn, vn)))
+            for n in (kn, vn))
     return pools
+
+
+def _pool_heads(x, spec):
+    """x [B, t, H, dh] with zero heads appended up to spec.pool_heads
+    (nothing where the pool holds the model's heads as they are)."""
+    extra = spec.pool_heads - spec.heads
+    if not extra:
+        return x
+    return L.pad(x, paddings=[0, 0, 0, 0, 0, extra, 0, 0])
+
+
+def _model_heads(ctx, spec, t):
+    """ctx [B, t, pool_heads, dh] -> [B, t, dim]: the model's heads."""
+    if spec.pool_heads != spec.heads:
+        ctx = L.slice(ctx, axes=[2], starts=[0], ends=[spec.heads])
+    return L.reshape(ctx, shape=[-1, t, spec.dim])
 
 
 def _paged_gather(pool_var, table, spec):
@@ -595,11 +661,13 @@ def _paged_gather(pool_var, table, spec):
 
 
 def _paged_prefill_attention(x, spec, blk, pool, table, positions,
-                             length, cow_src, cow_dst, chunk):
+                             length, cow_src, cow_dst, chunk, qk_norm=None):
     """One chunk of prefill attention: COW any forked page, scatter the
     chunk's K/V rows through the table, then attend the chunk's queries
     over the WHOLE gathered history (earlier pages + this chunk)."""
-    q4, k4, v4 = _qkv_parts(x, spec, blk, chunk)       # [1, C, H, dh]
+    q4, k4, v4 = (_pool_heads(a, spec)
+                  for a in _qkv_parts(x, spec, blk, chunk,
+                                      qk_norm))         # [1, C, H, dh]
     for pool_var, new in ((pool[0], k4), (pool[1], v4)):
         _block_op('kv_page_cow',
                   inputs={'Pool': [pool_var], 'Src': [cow_src],
@@ -622,19 +690,20 @@ def _paged_prefill_attention(x, spec, blk, pool, table, positions,
               outputs={'Out': [masked]})
     probs = L.softmax(masked)
     ctx = L.matmul(probs, vt)                          # [1, H, C, dh]
-    ctx = L.transpose(ctx, perm=[0, 2, 1, 3])
-    ctx = L.reshape(ctx, shape=[-1, chunk, spec.dim])
+    ctx = _model_heads(L.transpose(ctx, perm=[0, 2, 1, 3]), spec, chunk)
     ctx = sharding_constraint(ctx, (None, None, None))
     return _named_fc(ctx, spec.dim, blk['proj'])
 
 
 def _paged_decode_attention(x, spec, blk, pool, table, positions,
-                            cow_src, cow_dst):
+                            cow_src, cow_dst, qk_norm=None):
     """One decode step's attention: COW, append the new K/V row, then
     ONE paged_attention op that reads each lane's live pages through
     its table (no gathered window; see the op's docstring for its two
     lowerings)."""
-    q1, k1, v1 = _qkv_parts(x, spec, blk, 1)           # [S, 1, H, dh]
+    q1, k1, v1 = (_pool_heads(a, spec)
+                  for a in _qkv_parts(x, spec, blk, 1,
+                                      qk_norm))         # [S, 1, H, dh]
     for pool_var, new in ((pool[0], k1), (pool[1], v1)):
         _block_op('kv_page_cow',
                   inputs={'Pool': [pool_var], 'Src': [cow_src],
@@ -651,7 +720,7 @@ def _paged_decode_attention(x, spec, blk, pool, table, positions,
               outputs={'Out': [ctx]},
               attrs={'sm_scale': float(1.0 / np.sqrt(spec.dh)),
                      'head_axis': _tp_ax(spec) or ''})  # [S, 1, H, dh]
-    ctx = L.reshape(ctx, shape=[-1, 1, spec.dim])
+    ctx = _model_heads(ctx, spec, 1)
     ctx = sharding_constraint(ctx, (None, None, None))
     return _named_fc(ctx, spec.dim, blk['proj'])
 
@@ -844,6 +913,7 @@ def build_verify_program(spec, slots, k1, num_pages, page_tokens,
     Returns (program, feed_names, fetch_vars[logits, ids]).
     """
     from ..framework import Program, program_guard
+    refuse_recurrent(spec, 'the speculative verify program')
     prog, startup = Program(), Program()
     prog._is_test = True
     with program_guard(prog, startup):

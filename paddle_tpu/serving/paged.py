@@ -31,12 +31,26 @@ happen here. decode_step is transactional: on exhaustion every page
 allocated for THAT call is rolled back, so retrying the same feed
 after a release is deterministic and bit-exact.
 
+A model with recurrent layers (models/hybrid.py) keeps, beside the
+pools, per-slot state that is not pages: `state_names` of the pair,
+[slots, ...] arrays in the same child Scope, updated in place by both
+programs. A stream's first chunk starts its slot from zero state by a
+flag it feeds (no dispatch at open_stream); save_stream / restore_stream
+carry the slot's rows with the pages. The prefix cache hands out
+nothing for such a model: a prefix's pages without the recurrent state
+at that boundary would be a wrong stream, and keeping that state
+(13 MB a boundary at the 7B widths, against 1 MB a page) is not built.
+
 Telemetry: serving.kv_pages_in_use / serving.kv_pages_free gauges,
 serving.prefix_hits / serving.prefix_tokens_reused counters,
 serving.prefill_chunks histogram (chunks per admitted prompt),
 serving.decode_pages_read / serving.decode_pages_window counters (the
 pages the decode steps' attention read, of slots x pages_per_slot a
-step; the same pair is on every `paged.decode.tables` span).
+step; the same pair is on every `paged.decode.tables` span);
+serving.state_lanes counter (lanes whose recurrent state the decode
+steps updated; attr `state_lanes` of the same span),
+serving.recurrent_state_bytes and serving.state_resets gauges (bytes
+the recurrent state holds; streams started from zero state so far).
 
 Spans (profiler.RecordEvent), the same four in each step:
 `paged.decode.tables` / `paged.prefill.tables` (copy-on-write, page
@@ -45,6 +59,9 @@ executor's `exe.run` with its children, `paged.*.book` (unref, lengths,
 prefix registration, gauges: host work that overlaps the device's),
 and `paged.*.fetch` (`np.asarray(ids)`: the wait for the device and
 the transfer; in prefill only on a prompt's last chunk).
+`paged.state.save` / `paged.state.restore` (attr `nbytes`) inside
+save_stream / restore_stream: the recurrent rows' way to the host and
+back.
 """
 from __future__ import annotations
 
@@ -67,6 +84,18 @@ _prefix_tokens = telemetry.counter('serving.prefix_tokens_reused')
 _prefill_chunks = telemetry.histogram('serving.prefill_chunks')
 _decode_pages_read = telemetry.counter('serving.decode_pages_read')
 _decode_pages_window = telemetry.counter('serving.decode_pages_window')
+_state_lanes = telemetry.counter('serving.state_lanes')
+_state_bytes = telemetry.gauge('serving.recurrent_state_bytes')
+_state_resets = telemetry.gauge('serving.state_resets')
+
+
+def _set_row(state, slot, rows):
+    """state with state[slot] = rows: functional for a device array (the
+    caller reinstalls the result), in place for a host one."""
+    if hasattr(state, 'at'):
+        return state.at[slot].set(rows)
+    state[slot] = rows
+    return state
 
 
 class _PendingPrefill(object):
@@ -113,6 +142,9 @@ class PagedDecodePredictor(DecodePredictor):
                     prefill_chunk=prefill_chunk)
             self._weight_scope = predictor._scope
             self._mesh, self._mesh_shape = serving_mesh(mesh)
+            if self._mesh is not None:
+                self._refuse_recurrent('mesh serving (%s)'
+                                       % self._mesh_shape)
             self._pair.spec.mesh = self._mesh_shape
         self._exe = self._make_executor(predictor._place)
         if _clone_of is None:
@@ -147,8 +179,21 @@ class PagedDecodePredictor(DecodePredictor):
         cache pressure LMServer.stats() exposes to the fleet router."""
         return {slot: t.length for slot, t in self._tables.items()}
 
+    @property
+    def recurrent(self):
+        """True for a model with per-slot recurrent state."""
+        return bool(self._pair.state_names)
+
+    def _recurrent_state_bytes(self):
+        shapes = self._pair.spec.state_shapes(self.slots) \
+            if self.recurrent else ()
+        return 4 * len(self._pair.spec.recurrent_layers) * int(
+            sum(np.prod(s) for s in shapes))
+
     def pool_stats(self):
         return {'page_tokens': self.page_tokens,
+                'recurrent_state_bytes': self._recurrent_state_bytes(),
+                'state_resets': self._resets,
                 'num_pages': self.num_pages,
                 'pages_in_use': self._pool.pages_in_use,
                 'pages_free': self._pool.pages_free,
@@ -172,11 +217,18 @@ class PagedDecodePredictor(DecodePredictor):
         for name in self._pair.cache_names:
             self._scope.set_var(name, self._place_cache(
                 name, np.zeros(shape, np.float32)))
+        spec = self._pair.spec
+        for layer in spec.recurrent_layers:
+            for name, shape in zip(spec.state_names(layer),
+                                   spec.state_shapes(self.slots)):
+                self._scope.set_var(name, np.zeros(shape, np.float32))
         self._pool = PagePool(self.num_pages, self.page_tokens)
         self._prefix = PrefixCache(self._pool)
         self._pool.set_evict(self._prefix.evict_one)
         self._tables = {}             # slot -> PageTable
         self._pending = {}            # slot -> _PendingPrefill
+        self._resets = 0              # streams started from zero state
+        _state_bytes.set(self._recurrent_state_bytes())
         self._update_gauges()
 
     def clone(self):
@@ -199,7 +251,10 @@ class PagedDecodePredictor(DecodePredictor):
             raise ValueError('prompt length %d outside [1, %d] (max_len)'
                              % (len(prompt), self.max_len))
         table = PageTable(self._pool, self.pages_per_slot)
-        pages, shared = self._prefix.match(prompt, limit=len(prompt) - 1)
+        # never pages without their state: nothing is shared where a
+        # stream has recurrent state (see the module docstring)
+        pages, shared = ([], 0) if self.recurrent else \
+            self._prefix.match(prompt, limit=len(prompt) - 1)
         if shared:
             table.adopt_shared(pages, shared)
             _prefix_hits.inc()
@@ -238,9 +293,18 @@ class PagedDecodePredictor(DecodePredictor):
         pools = [self._scope.find_var(name)
                  for name in self._pair.cache_names]
         data = self._pool.save_pages(pools, table.pages)
-        return {'length': table.length, 'pages': len(table.pages),
+        snap = {'length': table.length, 'pages': len(table.pages),
                 'data': data,
                 'nbytes': int(sum(d.nbytes for d in data))}
+        if self.recurrent:
+            with RecordEvent('paged.state.save') as ev:
+                snap['state'] = [
+                    np.asarray(self._scope.find_var(name)[slot])
+                    for name in self._pair.state_names]
+                ev.attrs['nbytes'] = nbytes = \
+                    int(sum(a.nbytes for a in snap['state']))
+            snap['nbytes'] += nbytes
+        return snap
 
     def restore_stream(self, slot, snapshot, prompt=None):
         """Re-seat a save_stream() snapshot on `slot`: allocate fresh
@@ -263,6 +327,14 @@ class PagedDecodePredictor(DecodePredictor):
             # heads-sharded layout so the donated pool never flips
             # sharding (which would recompile the decode step)
             self._scope.set_var(name, self._place_cache(name, pool))
+        if self.recurrent:
+            with RecordEvent('paged.state.restore') as ev:
+                for name, rows in zip(self._pair.state_names,
+                                      snapshot['state']):
+                    self._scope.set_var(name, _set_row(
+                        self._scope.find_var(name), slot, rows))
+                ev.attrs['nbytes'] = \
+                    int(sum(a.nbytes for a in snapshot['state']))
         table = PageTable(self._pool, self.pages_per_slot)
         table.pages = list(ids)
         table.length = int(snapshot['length'])
@@ -278,6 +350,7 @@ class PagedDecodePredictor(DecodePredictor):
         chain order), 'tokens', 'data' (one [n, page_tokens, ...] array
         per layer pool), 'nbytes'}. A pure read: refcounts, tables and
         LRU stamps are untouched."""
+        self._refuse_recurrent('page shipping (export_prefix)')
         prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
         digests, pages = self._prefix.chain(prompt,
                                             limit=len(prompt) - 1)
@@ -315,6 +388,7 @@ class PagedDecodePredictor(DecodePredictor):
         are deduped without allocation. Returns (installed, deduped)
         page counts; raises the retryable CacheExhaustedError with
         nothing taken when the pool cannot fit the fresh rows."""
+        self._refuse_recurrent('page shipping (install_prefix)')
         prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
         keys = list(keys)
         skip = int(skip)
@@ -347,6 +421,10 @@ class PagedDecodePredictor(DecodePredictor):
             parent, [bytes.fromhex(k) for k in keys[have:n]], ids)
         self._update_gauges()
         return n - have, have
+
+    def _refuse_recurrent(self, what):
+        from ..models.transformer import refuse_recurrent
+        refuse_recurrent(self._pair.spec, what)
 
     def prefix_report(self):
         """Drain the prefix cache's registered/evicted delta (the
@@ -409,6 +487,14 @@ class PagedDecodePredictor(DecodePredictor):
                     'prefill_page_table': table_feed,
                     'prefill_cow_src': cow_src,
                     'prefill_cow_dst': cow_dst}
+            if self.recurrent:
+                # a stream's first chunk starts its slot from zero state
+                feed['prefill_state_slot'] = np.array([slot], np.int32)
+                feed['prefill_state_reset'] = \
+                    np.array([start == 0], np.int32)
+                if start == 0:
+                    self._resets += 1
+                    _state_resets.set(self._resets)
         logits, ids = self._exe.run(
             self._pair.prefill_program, feed=feed,
             fetch_list=self._pair.prefill_fetches,
@@ -421,7 +507,8 @@ class PagedDecodePredictor(DecodePredictor):
             self._update_gauges()
             if table.length < len(prompt):
                 return None
-            self._prefix.register(prompt, table)
+            if not self.recurrent:
+                self._prefix.register(prompt, table)
             del self._pending[slot]
             _prefill_chunks.observe(st.chunks)
         with RecordEvent('paged.prefill.fetch'):
@@ -490,6 +577,12 @@ class PagedDecodePredictor(DecodePredictor):
                     'decode_page_table': table_feed,
                     'decode_cow_src': cow_src,
                     'decode_cow_dst': cow_dst}
+            if self.recurrent:
+                state_live = np.zeros((S,), np.int32)
+                state_live[live] = 1
+                feed['decode_state_live'] = state_live
+                ev.attrs['state_lanes'] = len(live)
+                _state_lanes.inc(len(live))
         logits, ids = self._exe.run(
             self._pair.decode_program, feed=feed,
             fetch_list=self._pair.decode_fetches,

@@ -18,7 +18,24 @@ GSPMD-style TP keeps full LOGICAL weight shapes, so a use_tp=True
 program walks identically; its sharding is RECOVERED into
 DecodeSpec.param_specs — from dist_attr annotations when the program
 is still in memory, else from the sharding_constraint ops that survive
-save_inference_model (see _recover_param_specs). Genuinely
+save_inference_model (see _recover_param_specs).
+
+A second recognized shape is the hybrid LM of models/hybrid.py
+(`hybrid.language_model_logits`): lookup_table, no position op, per
+block one of two mixers, told apart by their marker op, and a gated
+MLP, every sublayer's output through an rms_norm —
+  linear_attention  [qkv mul, ba mul, out_gate mul, short_conv,
+                    gated_delta_chunk, rms_norm (heads), out mul]
+  full_attention    [qkv mul, rms_norm (q), rms_norm (k),
+                    matmul/causal_mask/softmax, proj mul]
+then [rms_norm, gate mul, up mul, down mul, rms_norm], a final
+rms_norm and the lm_head mul. Its spec (HybridDecodeSpec) carries the
+layer kinds and the delta rule's sizes; it serves through the paged
+pair only, whose programs keep K/V pools for the full-attention layers
+and per-slot recurrent state for the others. Speculative decoding,
+page shipping and mesh serving refuse it by the layer kind's name.
+
+Genuinely
 unsupported layouts (MoE expert-sharded FFN, ring attention, a
 constraint on an axis the serving mesh cannot honor) still raise
 DecodeTranspileError naming the offending op/axis — better a loud
@@ -27,18 +44,15 @@ time.
 """
 from __future__ import annotations
 
-from ..models.transformer import (DecodeSpec, build_prefill_program,
+from ..models import hybrid
+from ..models.transformer import (DecodeSpec, DecodeTranspileError,
+                                  refuse_recurrent, build_prefill_program,
                                   build_decode_program,
-                                  build_paged_prefill_program,
-                                  build_paged_decode_program,
                                   build_verify_program)
 
 __all__ = ['DecodeTranspileError', 'DecodePair', 'PagedDecodePair',
-           'SpecDecodePair', 'DecodeTranspiler', 'extract_decode_spec']
-
-
-class DecodeTranspileError(ValueError):
-    """The loaded program is not a transpilable decoder-only LM."""
+           'SpecDecodePair', 'DecodeTranspiler', 'extract_decode_spec',
+           'refuse_recurrent']
 
 
 class DecodePair(object):
@@ -94,6 +108,12 @@ class PagedDecodePair(DecodePair):
     @property
     def cache_names(self):
         return self.spec.pool_names()
+
+    @property
+    def state_names(self):
+        """Recurrent-state vars (delta state, then convolution rows, a
+        recurrent layer): per-slot state that is not pages."""
+        return self.spec.state_names()
 
     @property
     def pool_shape(self):
@@ -234,9 +254,102 @@ def _recover_param_specs(block, spec, muls, add_out_of, act_out_of,
     spec.param_specs = {n: specs.get(n) for n in spec.param_names()}
 
 
+def _extract_hybrid_spec(block):
+    """The hybrid LM's spec (see the module docstring): weights and norms
+    in op order, dealt out to layers by each layer's marker op."""
+    emb_w = ids = None
+    muls, norms, markers = [], [], []
+    conv_w = None
+    eps = 1e-6
+    for op in block.ops:
+        t = op.type
+        if t == 'lookup_table' and emb_w is None:
+            emb_w, ids = op.single_input('W'), op.single_input('Ids')
+        elif t == 'mul':
+            muls.append(op.single_input('Y'))
+        elif t == 'rms_norm':
+            norms.append(op.single_input('Scale'))
+            eps = op.attr('epsilon', eps)
+        elif t == 'short_conv':
+            conv_w = op.single_input('W')
+        elif t == 'gated_delta_chunk':
+            markers.append(('linear_attention', op, conv_w))
+        elif t == 'causal_mask':
+            markers.append(('full_attention', op, None))
+        elif t in ('layer_norm', 'position_embedding', 'moe_ffn',
+                   'flash_attention', 'ring_attention'):
+            _fail('op %s inside an rms_norm model: not the hybrid block '
+                  'of models/hybrid.py' % t)
+    if emb_w is None:
+        _fail('no lookup_table op (token embedding)')
+    if not markers:
+        _fail('no gated_delta_chunk or causal_mask op marks a layer')
+    want_muls = sum(7 if k == 'linear_attention' else 5
+                    for k, _, _ in markers) + 1
+    want_norms = sum(3 if k == 'linear_attention' else 4
+                     for k, _, _ in markers) + 1
+    if len(muls) != want_muls or len(norms) != want_norms:
+        _fail('%d mul and %d rms_norm ops for layer kinds %s (want %d and '
+              '%d)' % (len(muls), len(norms), [k for k, _, _ in markers],
+                       want_muls, want_norms))
+    vocab, dim = (int(d) for d in block.var_recursive(emb_w).shape)
+    max_len = int(block.var_recursive(ids).shape[1])
+    muls, norms = iter(muls), iter(norms)
+
+    def w():
+        return (next(muls), None)
+    blocks, heads, sizes = [], None, None
+    for i, (kind, op, conv) in enumerate(markers):
+        if kind == 'linear_attention':
+            if conv is None:
+                _fail('layer %d: gated_delta_chunk without a short_conv'
+                      % i)
+            blk = {'qkv': w(), 'ba': w(), 'out_gate': w(), 'conv': conv,
+                   'a_log': op.single_input('ALog'),
+                   'dt_bias': op.single_input('DtBias'),
+                   'head_norm': next(norms), 'out': w()}
+            got = (int(op.attr('heads')), int(op.attr('key_dim')),
+                   int(op.attr('value_dim')),
+                   float(op.attr('beta_scale', 1.0)),
+                   int(block.var_recursive(conv).shape[0]))
+            if sizes not in (None, got):
+                _fail('layer %d: delta rule sizes %r differ from %r'
+                      % (i, got, sizes))
+            sizes, heads = got, got[0]
+        else:
+            blk = {'qkv': w(), 'q_norm': next(norms),
+                   'k_norm': next(norms), 'proj': w()}
+            if tuple(block.var_recursive(blk['qkv'][0]).shape) != \
+                    (dim, 3 * dim):
+                _fail('layer %d qkv weight %r is not the full logical '
+                      '(%d, %d)' % (i, blk['qkv'][0], dim, 3 * dim))
+        blk.update(mixer_norm=next(norms), gate=w(), up=w(), down=w(),
+                   mlp_norm=next(norms))
+        blocks.append(blk)
+    if heads is None:
+        # attention only: the head count is the scores' second axis
+        heads = int(block.var_recursive(
+            markers[0][1].single_input('X')).shape[1])
+    if sizes is None:
+        sizes = (heads, 0, 0, 1.0, 1)
+    if dim % heads:
+        _fail('%d heads do not divide dim %d' % (heads, dim))
+    ffn = int(block.var_recursive(blocks[0]['gate'][0]).shape[1])
+    spec = hybrid.HybridDecodeSpec(
+        vocab=vocab, dim=dim, heads=heads, ffn=ffn, max_len=max_len,
+        kinds=[k for k, _, _ in markers], key_dim=sizes[1],
+        value_dim=sizes[2], conv_kernel=sizes[4], eps=eps,
+        beta_scale=sizes[3], emb_w=emb_w, blocks=blocks,
+        final_norm=next(norms), head=w())
+    spec.param_specs = {n: None for n in spec.param_names()}
+    return spec
+
+
 def extract_decode_spec(program):
     """Scan the loaded program and return its DecodeSpec."""
     block = program.global_block()
+    if any(op.type == 'rms_norm' for op in block.ops):
+        return _extract_hybrid_spec(block)
     emb_w = pos_w = None
     lns = []          # (scale_name, bias_name) in op order
     muls = []         # (w_name, out_name) in op order
@@ -363,6 +476,7 @@ class DecodeTranspiler(object):
         if paged:
             return self._transpile_paged(spec, slots, page_tokens,
                                          kv_pages, prefill_chunk)
+        refuse_recurrent(spec, 'the dense ring cache (paged=False)')
         pp, pf, pv = build_prefill_program(spec, slots,
                                            batch=prefill_batch)
         dp, df, dv = build_decode_program(spec, slots)
@@ -383,6 +497,8 @@ class DecodeTranspiler(object):
         spec_k = int(spec_k if spec_k is not None else get_flag('spec_k'))
         if spec_k < 1:
             raise ValueError('spec_k must be >= 1, got %r' % spec_k)
+        refuse_recurrent(extract_decode_spec(program),
+                         'speculative decoding')
         target = self.transpile(program, slots=slots, paged=True,
                                 page_tokens=page_tokens,
                                 kv_pages=kv_pages,
@@ -431,10 +547,7 @@ class DecodeTranspiler(object):
                              'reserved null page), got %d' % num_pages)
         chunk = int(prefill_chunk or get_flag('serving_prefill_chunk'))
         chunk = max(1, min(chunk, spec.max_len))
-        pp, pf, pv = build_paged_prefill_program(
-            spec, chunk, num_pages, pt, pages_per_slot)
-        dp, df, dv = build_paged_decode_program(
-            spec, slots, num_pages, pt, pages_per_slot)
-        return PagedDecodePair(spec, slots, pt, pages_per_slot,
-                               num_pages, chunk,
-                               pp, pf, pv, dp, df, dv)
+        return PagedDecodePair(
+            spec, slots, pt, pages_per_slot, num_pages, chunk,
+            *spec.build_paged_programs(slots, chunk, num_pages, pt,
+                                       pages_per_slot))

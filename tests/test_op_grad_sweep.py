@@ -372,6 +372,15 @@ CONFIGS = _configs()
 # Ops covered by OpTest subclasses in other files are found by
 # _optest_checked_ops() through class introspection and need no entry.
 COVERED_ELSEWHERE = {
+    'rms_norm': ('test_delta_rule_ops.py',
+                 'test_rms_norm_forward_and_gradient',
+                 'grad parity vs jax.grad of the formula'),
+    'gated_delta_chunk': ('test_delta_rule_ops.py',
+                          'test_no_backward_and_the_error_names_the_op',
+                          'serving only: its grad maker raises by name'),
+    'short_conv': ('test_delta_rule_ops.py',
+                   'test_no_backward_and_the_error_names_the_op',
+                   'serving only: its grad maker raises by name'),
     'flash_attention': ('test_flash_attention.py',
                         'test_kernel_grads_match_naive',
                         'grad parity vs naive reference'),
