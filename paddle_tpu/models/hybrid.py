@@ -36,8 +36,9 @@ from __future__ import annotations
 import numpy as np
 
 from .. import layers as L
-from .transformer import (DecodeSpec, _block_op, _create_pool_vars,
-                          _named_attr, _named_fc, _paged_decode_attention,
+from .transformer import (PAGED_DECODE_FEEDS, DecodeSpec, _block_op,
+                          _create_pool_vars, _named_attr, _named_fc,
+                          _paged_decode_attention, _paged_decode_tokens,
                           _paged_prefill_attention, _qkv_parts, _tmp_var)
 
 KINDS = ('linear_attention', 'full_attention')
@@ -321,7 +322,8 @@ def build_paged_prefill_program(spec, slots, chunk, num_pages, page_tokens,
 def build_paged_decode_program(spec, slots, num_pages, page_tokens,
                                pages_per_slot):
     """One token a lane over the whole slot pool:
-    models/transformer.py's paged decode feeds, and decode_state_live
+    models/transformer.py's paged decode feeds (the carried token's
+    pair among them), and decode_state_live
     [slots] (1 for the lanes that take part: the others' recurrent state
     stays as it was, as their K/V writes land on the null page).
     Returns (program, feed_names, fetch_vars[logits, ids])."""
@@ -329,7 +331,7 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
     prog, startup = Program(), Program()
     prog._is_test = True
     with program_guard(prog, startup):
-        tokens = _data('decode_tokens', [slots, 1, 1], 'int64')
+        tokens = _paged_decode_tokens(slots)
         step_idx = _data('decode_step_idx', [slots])
         table = _data('decode_page_table', [slots, pages_per_slot])
         cow_src = _data('decode_cow_src', [slots])
@@ -349,6 +351,4 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
                 _qk_norm(sp, blk))})
         logits = L.reshape(logits3, shape=[-1, spec.vocab])
         ids = L.argmax(logits, axis=-1)
-    return prog, ['decode_tokens', 'decode_step_idx', 'decode_page_table',
-                  'decode_cow_src', 'decode_cow_dst', 'decode_state_live'], \
-        [logits, ids]
+    return prog, PAGED_DECODE_FEEDS + ['decode_state_live'], [logits, ids]

@@ -840,11 +840,37 @@ def build_paged_prefill_program(spec, chunk, num_pages, page_tokens,
                   'prefill_cow_src', 'prefill_cow_dst'], [logits, ids]
 
 
+PAGED_DECODE_FEEDS = ['decode_tokens', 'decode_prev_ids', 'decode_carry',
+                      'decode_step_idx', 'decode_page_table',
+                      'decode_cow_src', 'decode_cow_dst']
+
+
+def _paged_decode_tokens(slots):
+    """The token a lane is fed: the host's (decode_tokens), or, where
+    decode_carry is set, the lane's entry of decode_prev_ids, the ids
+    the step before left on the device. One select in front of the
+    embedding lookup, so the step that carries a token on and the one
+    that takes every token from the host are one executable."""
+    tokens = L.data('decode_tokens', [slots, 1, 1],
+                    append_batch_size=False, dtype='int64')
+    prev = L.data('decode_prev_ids', [slots],
+                  append_batch_size=False, dtype='int64')
+    carry = L.data('decode_carry', [slots],
+                   append_batch_size=False, dtype='int32')
+    return L.where_select(L.cast(carry, 'bool'),
+                          L.reshape(prev, shape=[slots, 1, 1]), tokens)
+
+
 def build_paged_decode_program(spec, slots, num_pages, page_tokens,
                                pages_per_slot):
     """One-token decode step over the whole slot pool, page-indexed.
 
     Feeds:  decode_tokens [slots, 1, 1] int64,
+            decode_prev_ids [slots] int64 and decode_carry [slots]
+            int32 (a lane with carry set is fed its entry of prev_ids —
+            the greedy ids an earlier step left on the device — and not
+            its decode_tokens entry: serving/paged.py dispatches a step
+            before it has fetched the one before),
             decode_step_idx [slots] int32 (absolute position of the
             incoming token — same ABI as the dense step, but the write
             lands at pool[table[pos // pt], pos % pt], never wrapped),
@@ -860,8 +886,7 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
     prog, startup = Program(), Program()
     prog._is_test = True
     with program_guard(prog, startup):
-        tokens = L.data('decode_tokens', [slots, 1, 1],
-                        append_batch_size=False, dtype='int64')
+        tokens = _paged_decode_tokens(slots)
         step_idx = L.data('decode_step_idx', [slots],
                           append_batch_size=False, dtype='int32')
         table = L.data('decode_page_table', [slots, pages_per_slot],
@@ -885,9 +910,7 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
         logits3 = _named_fc(x, spec.vocab, spec.head)          # [S, 1, V]
         logits = L.reshape(logits3, shape=[-1, spec.vocab])
         ids = L.argmax(logits, axis=-1)
-    return prog, ['decode_tokens', 'decode_step_idx',
-                  'decode_page_table', 'decode_cow_src',
-                  'decode_cow_dst'], [logits, ids]
+    return prog, list(PAGED_DECODE_FEEDS), [logits, ids]
 
 
 def build_verify_program(spec, slots, k1, num_pages, page_tokens,

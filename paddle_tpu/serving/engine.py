@@ -10,6 +10,25 @@ writes). Worker threads each own a DecodePredictor clone — private
 cache scope + executor, weights shared through the parent Scope — and
 pull from one shared queue.
 
+A worker's loop over a paged predictor keeps ONE decode step in flight
+(a predictor that offers `deferred_decode`; the dense and the
+speculative predictors keep the serial loop: fetch, accept, pack,
+dispatch). An iteration admits, advances one prefill chunk, packs step
+n from what the host knows without step n-1's tokens (positions,
+budgets, page tables; a lane that carries on is fed its token on the
+device), dispatches it, and only then fetches and accepts step n-1. So
+the host's work between two programs runs beside the one in flight,
+and the device goes from one decode program to the next. A lane that
+ends on what only the token or the clock can tell (an eos_id, a
+cancel, a deadline) has been fed to one step too many: that lane
+result is dropped (serving.decode_lanes_dropped), its stream is what
+the serial loop gives. Whatever needs the lanes whole on the host
+collects the step in flight first: a preemption, the handling of
+CacheExhaustedError, a weight swap, an iteration with no lane ready,
+the worker's exit. So does a prompt's last chunk, between its dispatch
+and the synchronous wait for its token (the device runs the step in
+flight before the chunk: its tokens need not wait for the chunk).
+
 Requests carry a PRIORITY tier (submit(priority=), higher = more
 important, 0 = the default lowest tier): one queue per tier, popped
 highest-tier first, and the queue-full admission bound applies only to
@@ -21,12 +40,15 @@ victim re-enters the FRONT of its own tier and resumes bit-exact.
 Telemetry (paddle_tpu/obs/, exported when FLAGS_obs_dir is set):
   serving.requests.{submitted,admitted,completed,cancelled,rejected,
   failed}  counters; serving.tokens_generated / serving.decode_steps /
+  serving.decode_steps_overlapped (steps dispatched while the one
+  before was in flight) / serving.decode_lanes_dropped /
   serving.prefills  counters; serving.queue_depth /
   serving.slot_occupancy  gauges; serving.ttft /
   serving.token_latency / serving.decode_batch  histograms (seconds /
-  seconds / active lanes per step); plus the preemption set from
-  serving/preempt.py (serving.preemptions / serving.swapped_pages /
-  serving.swap_bytes / serving.resume_latency /
+  seconds / active lanes per step; the last two take one observation
+  a dispatched step, token_latency the host time of its call); plus
+  the preemption set from serving/preempt.py (serving.preemptions /
+  serving.swapped_pages / serving.swap_bytes / serving.resume_latency /
   serving.preempted_streams).
 
 Spans (profiler.RecordEvent; recorded while the registry is on, and on
@@ -34,9 +56,14 @@ the device trace's clock while a profile runs): `serve.iter` is one
 pass of a worker's loop (attrs lanes, ready, prefilling, queued) with
 children `serve.admit`, `serve.prefill_tick`, `serve.pack` and
 `serve.accept`; the decoder's own spans (serving/paged.py) nest under
-it. A request that reaches a terminal state leaves three spans of kind
-'request' that share sid = its id: `serve.queue` (submitted_at ->
-admitted_at), `serve.prefill` (admitted_at -> first_token_at) and
+it. In the pipelined loop `serve.accept` follows the call and holds
+the tokens of the step BEFORE the one the call dispatched (none behind
+a burst's first step); a step collected without a call leaves a
+`paged.decode.fetch` and a `serve.accept` of its own in the iteration
+that collected it. A request that reaches a terminal state leaves
+three spans of kind 'request' that share sid = its id: `serve.queue`
+(submitted_at -> admitted_at), `serve.prefill` (admitted_at ->
+first_token_at) and
 `serve.decode` (first_token_at -> done_at; attrs n_prompt,
 max_new_tokens, n_tokens, state, prefill_chunks, preemptions and
 gaps_ms, the times between its tokens). A preempted request adds one
@@ -82,6 +109,9 @@ _rejected = telemetry.counter('serving.requests.rejected')
 _failed = telemetry.counter('serving.requests.failed')
 _tokens_out = telemetry.counter('serving.tokens_generated')
 _decode_steps = telemetry.counter('serving.decode_steps')
+_decode_steps_overlapped = telemetry.counter(
+    'serving.decode_steps_overlapped')
+_decode_lanes_dropped = telemetry.counter('serving.decode_lanes_dropped')
 _prefills = telemetry.counter('serving.prefills')
 _queue_depth = telemetry.gauge('serving.queue_depth')
 _occupancy = telemetry.gauge('serving.slot_occupancy')
@@ -104,7 +134,14 @@ class _StepGate(object):
     and releases — the ISSUE's step-boundary swap contract: in-flight
     decode steps finish on the old weights, the next step reads the
     new ones, and the writer's critical section is only the staged
-    pointer swap (never a network pull)."""
+    pointer swap (never a network pull).
+
+    A worker that keeps one decode step in flight from one iteration to
+    the next (the paged engine's pipeline) stays a reader across them
+    and leaves only with nothing in flight: it looks at
+    `writer_waiting` after every iteration, collects its step and
+    leaves. So the writer still runs between two steps, and no step is
+    in flight while it does."""
 
     def __init__(self):
         self._mu = threading.Condition()
@@ -112,19 +149,29 @@ class _StepGate(object):
         self._writers_waiting = 0
         self._writing = False
 
-    @contextlib.contextmanager
-    def read(self):
+    def acquire_read(self):
         with self._mu:
             while self._writing or self._writers_waiting:
                 self._mu.wait()
             self._readers += 1
+
+    def release_read(self):
+        with self._mu:
+            self._readers -= 1
+            if not self._readers:
+                self._mu.notify_all()
+
+    @property
+    def writer_waiting(self):
+        return self._writers_waiting > 0
+
+    @contextlib.contextmanager
+    def read(self):
+        self.acquire_read()
         try:
             yield
         finally:
-            with self._mu:
-                self._readers -= 1
-                if not self._readers:
-                    self._mu.notify_all()
+            self.release_read()
 
     @contextlib.contextmanager
     def exclusive(self):
@@ -234,7 +281,10 @@ class _Lane(object):
     `ready` is False while a paged stream is still prefilling in
     chunks — the lane occupies its slot but sits out decode steps.
     `last_active` (last accepted-token time) is the idleness key the
-    preemption policy sorts victims by within a tier."""
+    preemption policy sorts victims by within a tier. In the pipelined
+    loop a lane fed to the decode step in flight has `pos` already at
+    the position after that step, and its next token still on the
+    device (`tok` is then the last one accepted)."""
     __slots__ = ('req', 'pos', 'tok', 'ready', 'last_active')
 
     def __init__(self, req, pos, tok, ready=True):
@@ -861,9 +911,17 @@ class ServingEngine(object):
                                   pred=pred, wstate=wstate)
                 _deadline_expired.inc()
                 continue
+            # a prompt's last chunk is fetched synchronously. The decode
+            # step in flight runs on the device before the chunk: its
+            # tokens are accepted before that wait, not behind it
+            kw = {} if wstate['flight'] is None else {
+                'before_fetch': lambda: self._collect(pred, lanes, wstate)}
             try:
-                out = pred.prefill_step(slot)
+                out = pred.prefill_step(slot, **kw)
             except CacheExhaustedError as e:
+                # a victim's tokens and pages must agree: the decode
+                # step in flight is accepted before one is picked
+                self._collect(pred, lanes, wstate)
                 _cache_exhausted.inc()
                 policy = preempt_policy()
                 if policy != 'off':
@@ -908,34 +966,108 @@ class ServingEngine(object):
     def _worker_loop(self, wid, pred):
         lanes = {}                       # slot -> _Lane
         prefilling = collections.deque()  # paged: slots mid-prefill
-        wstate = {'cache_wait': False}
+        # flight: the (slot, lane) pairs fed to the decode step that is
+        # dispatched and not yet accepted (the pipelined loop), or None
+        wstate = {'cache_wait': False, 'flight': None, 'wid': wid}
         tokens = np.zeros((pred.slots,), np.int64)
         positions = np.zeros((pred.slots,), np.int32)
-        while True:
-            with self._cond:
-                while self._running and not self._qsize_locked() \
-                        and not lanes:
-                    self._cond.wait(self._idle_wait)
-                if not self._running and not self._qsize_locked() \
-                        and not lanes:
-                    return
-            # one gate-read section per iteration: a waiting weight
-            # swap (request_swap) runs between iterations — i.e. at a
-            # step boundary — never under a prefill or decode step
-            with self._gate.read(), RecordEvent('serve.iter') as it:
-                self._iterate(it, wid, pred, lanes, prefilling, wstate,
-                              tokens, positions)
+        reading = False
+        try:
+            while True:
+                with self._cond:
+                    while self._running and not self._qsize_locked() \
+                            and not lanes:
+                        self._cond.wait(self._idle_wait)
+                    if not self._running and not self._qsize_locked() \
+                            and not lanes:
+                        return
+                # one gate-read section per iteration: a waiting weight
+                # swap (request_swap) runs between iterations — i.e. at
+                # a step boundary — never under a prefill or decode
+                # step. With a decode step in flight the section runs on
+                # into the next iteration; a swap that waits has the
+                # step collected and accepted first, so it sees none.
+                if not reading:
+                    self._gate.acquire_read()
+                    reading = True
+                with RecordEvent('serve.iter') as it:
+                    self._iterate(it, wid, pred, lanes, prefilling, wstate,
+                                  tokens, positions)
+                    if self._gate.writer_waiting:
+                        self._collect(pred, lanes, wstate)
+                if wstate['flight'] is None:
+                    self._gate.release_read()
+                    reading = False
+        finally:
+            # on any way out, nothing stays in flight on the predictor
+            self._collect(pred, lanes, wstate)
+            if reading:
+                self._gate.release_read()
+
+    def _collect(self, pred, lanes, wstate):
+        """Fetch and accept the decode step in flight, if there is one,
+        without dispatching another: before anything that needs the
+        lanes' state whole on the host (a preemption's save_stream, the
+        handling of CacheExhaustedError, a weight swap), and when no
+        lane is ready for a next step."""
+        flight, wstate['flight'] = wstate['flight'], None
+        if flight is None:
+            return
+        try:
+            ids = pred.collect()
+        except Exception as e:   # noqa: BLE001 — engine survives
+            for slot, lane in flight:
+                if lanes.get(slot) is lane:
+                    self._finish_lane(lanes, slot, FAILED, error=repr(e),
+                                      pred=pred, wstate=wstate)
+            return
+        with RecordEvent('serve.accept'):
+            self._accept_flight(flight, ids, pred, lanes, wstate)
+        self._report(wstate['wid'], lanes)
+
+    def _report(self, wid, lanes):
+        """The occupancy gauge and this worker's {slot: tokens held},
+        again after every round of evictions, so that an idle worker
+        reports zero held tokens and not its last busy state."""
+        _occupancy.set(self._active_total)
+        self._slot_tokens[wid] = {s: ln.pos for s, ln in lanes.items()}
+
+    def _accept_flight(self, flight, ids, pred, lanes, wstate):
+        """The tokens of a deferred step, one fetch late. A lane that
+        ended meanwhile on what only the token or the clock could tell
+        (an eos_id, a cancel, a deadline) was fed to this step all the
+        same: its result is dropped here. Its pages were released with
+        the step's write into them still queued; the device executes
+        that write before any later program that could own them
+        (serving/paged.py release())."""
+        for slot, lane in flight:
+            if lanes.get(slot) is not lane:
+                _decode_lanes_dropped.inc()
+                continue
+            self._lane_accept(lanes, slot, int(ids[slot]), pred=pred,
+                              wstate=wstate)
 
     def _iterate(self, it, wid, pred, lanes, prefilling, wstate, tokens,
                  positions):
         """One pass of a worker's loop, inside its `serve.iter` span
         `it`: admission, at most one prefill chunk, then one decode
-        step over the ready lanes and the acceptance of its tokens."""
+        step over the ready lanes and the acceptance of its tokens.
+
+        With a predictor that defers (`deferred_decode`: paged, not
+        speculative) the pass is one stage of a one-deep pipeline: it
+        packs step n from what the host knows without step n-1's
+        tokens (positions, budgets; a lane that carries on takes its
+        token on the device), dispatches it, and accepts the tokens of
+        step n-1, which the same call hands back. The host's work
+        between two programs then runs beside the one in flight."""
         paged = getattr(pred, 'paged', False)
         # a speculative predictor's step is one draft->verify iteration
         # (serving/speculative.py): same feed ABI, but each live lane
-        # gets 1..k+1 tokens back instead of exactly one
+        # gets 1..k+1 tokens back instead of exactly one — and where a
+        # lane stands after a step depends on how many, so it cannot be
+        # packed ahead
         speculative = getattr(pred, 'speculative', False)
+        deferred = getattr(pred, 'deferred_decode', False)
         if paged:
             with RecordEvent('serve.admit'):
                 self._admit_paged(pred, lanes, prefilling, wstate)
@@ -944,9 +1076,7 @@ class ServingEngine(object):
         else:
             with RecordEvent('serve.admit'):
                 self._admit(pred, lanes)
-        _occupancy.set(self._active_total)
-        self._slot_tokens[wid] = {s: ln.pos
-                                  for s, ln in lanes.items()}
+        self._report(wid, lanes)
         # deadline check at the step boundary: an expired ready
         # lane is evicted (pages freed) before it buys another
         # decode step. Prefilling lanes are checked at the
@@ -962,13 +1092,21 @@ class ServingEngine(object):
                           'mid-decode',
                     pred=pred, wstate=wstate)
                 _deadline_expired.inc()
-        ready = [s for s, ln in lanes.items() if ln.ready]
+        # the lanes whose next token is still on the device, in the step
+        # in flight (the pipelined loop alone has any); one whose budget
+        # ends with that token sits this step out
+        carried = {s for s, ln in wstate['flight'] or ()
+                   if lanes.get(s) is ln}
+        ready = [s for s, ln in lanes.items() if ln.ready and
+                 len(ln.req.tokens) + (s in carried)
+                 < ln.req.max_new_tokens]
         if telemetry._enabled:
             with self._cond:
                 queued = self._qsize_locked()
             it.attrs.update(lanes=len(lanes), ready=len(ready),
                             prefilling=len(prefilling), queued=queued)
         if not ready:
+            self._collect(pred, lanes, wstate)
             return
         with RecordEvent('serve.pack'):
             for slot in ready:
@@ -978,9 +1116,16 @@ class ServingEngine(object):
         try:
             if speculative:
                 emitted = pred.spec_step(tokens, positions)
+            elif deferred:
+                ids = pred.decode_step(
+                    tokens, positions, lanes=ready, defer=True,
+                    carry=[s for s in ready if s in carried])
             else:
                 ids = pred.decode_step(tokens, positions)
         except CacheExhaustedError as e:
+            # nothing of this step ran; the one in flight is accepted
+            # first, so that a victim's tokens and pages agree
+            self._collect(pred, lanes, wstate)
             # preempt-first (serving/preempt.py): instead of
             # failing the named victims, the lowest-tier
             # longest-idle stream gives its pages back (swap or
@@ -1018,6 +1163,7 @@ class ServingEngine(object):
                             pred=pred, wstate=wstate)
             return
         except Exception as e:   # noqa: BLE001 — engine survives
+            self._collect(pred, lanes, wstate)
             for slot in ready:
                 if slot in lanes:
                     self._finish_lane(lanes, slot, FAILED,
@@ -1028,25 +1174,35 @@ class ServingEngine(object):
         _decode_steps.inc()
         _token_latency.observe(dt)
         _decode_batch.observe(len(ready))
-        with RecordEvent('serve.accept'):
-            if speculative:
-                # per-slot mixed accept lengths in the SAME iteration:
-                # each lane consumes its own emitted prefix, stopping
-                # early on eos/budget/cancel
-                for slot in ready:
-                    for tok in emitted.get(slot, ()):
+        if deferred:
+            flight = wstate['flight']
+            wstate['flight'] = fed = [(s, lanes[s]) for s in ready]
+            for _slot, lane in fed:
+                lane.pos += 1
+            if flight is not None:
+                _decode_steps_overlapped.inc()
+                with RecordEvent('serve.accept'):
+                    self._accept_flight(flight, ids, pred, lanes, wstate)
+            if not any(lanes.get(s) is ln for s, ln in fed):
+                # every lane of the step just dispatched has ended:
+                # nothing waits for it, and the worker may go idle
+                self._collect(pred, lanes, wstate)
+        else:
+            with RecordEvent('serve.accept'):
+                if speculative:
+                    # per-slot mixed accept lengths in the SAME
+                    # iteration: each lane consumes its own emitted
+                    # prefix, stopping early on eos/budget/cancel
+                    for slot in ready:
+                        for tok in emitted.get(slot, ()):
+                            lanes[slot].pos += 1
+                            if not self._lane_accept(
+                                    lanes, slot, int(tok), pred=pred,
+                                    wstate=wstate):
+                                break
+                else:
+                    for slot in ready:
                         lanes[slot].pos += 1
-                        if not self._lane_accept(lanes, slot, int(tok),
-                                                 pred=pred,
-                                                 wstate=wstate):
-                            break
-            else:
-                for slot in ready:
-                    lanes[slot].pos += 1
-                    self._lane_accept(lanes, slot, int(ids[slot]),
-                                      pred=pred, wstate=wstate)
-        _occupancy.set(self._active_total)
-        # re-snapshot after evictions so an idle worker reports
-        # zero held tokens, not its last busy state
-        self._slot_tokens[wid] = {s: ln.pos
-                                  for s, ln in lanes.items()}
+                        self._lane_accept(lanes, slot, int(ids[slot]),
+                                          pred=pred, wstate=wstate)
+        self._report(wid, lanes)

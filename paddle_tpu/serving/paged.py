@@ -17,7 +17,11 @@ Streams replace the dense path's whole-row prefill:
                                 returns the first greedy token once the
                                 prompt is complete (None before that)
     decode_step(tokens, pos)    one compiled step over ALL slots; pages
-                                are allocated on demand per live stream
+                                are allocated on demand per live stream;
+                                with defer=True the step before's ids
+                                come back instead of this step's, which
+                                stay on the device (a one-deep pipeline)
+    collect()                   the ids of the deferred step in flight
     release(slot)               drop the stream's page refs
     save_stream(slot)           copy the stream's pages to host RAM
                                 (preempt-first capacity); paired with
@@ -46,7 +50,10 @@ serving.prefix_hits / serving.prefix_tokens_reused counters,
 serving.prefill_chunks histogram (chunks per admitted prompt),
 serving.decode_pages_read / serving.decode_pages_window counters (the
 pages the decode steps' attention read, of slots x pages_per_slot a
-step; the same pair is on every `paged.decode.tables` span);
+step; the same pair is on every `paged.decode.tables` span, beside
+`overlapped`, 1 if the step was dispatched while the one before was in
+flight, and `carried`, the lanes whose token it took from that step on
+the device);
 serving.state_lanes counter (lanes whose recurrent state the decode
 steps updated; attr `state_lanes` of the same span),
 serving.recurrent_state_bytes and serving.state_resets gauges (bytes
@@ -58,7 +65,10 @@ allocation, the page-table rows and the other feed arrays), the
 executor's `exe.run` with its children, `paged.*.book` (unref, lengths,
 prefix registration, gauges: host work that overlaps the device's),
 and `paged.*.fetch` (`np.asarray(ids)`: the wait for the device and
-the transfer; in prefill only on a prompt's last chunk).
+the transfer; in prefill only on a prompt's last chunk). In a deferred
+decode step the fetch is that of the step BEFORE, with this one already
+queued behind it: the device's lead over the host, not a whole step's
+time. collect() leaves a `paged.decode.fetch` alone.
 `paged.state.save` / `paged.state.restore` (attr `nbytes`) inside
 save_stream / restore_stream: the recurrent rows' way to the host and
 back.
@@ -112,6 +122,9 @@ class PagedDecodePredictor(DecodePredictor):
     this directly."""
 
     paged = True
+    # decode_step(defer=True) and collect(): a caller may keep one step
+    # in flight (the serving engine's pipelined loop looks for this)
+    deferred_decode = True
 
     def __init__(self, predictor, slots=None, page_tokens=None,
                  kv_pages=None, prefill_chunk=None, _clone_of=None,
@@ -228,6 +241,10 @@ class PagedDecodePredictor(DecodePredictor):
         self._tables = {}             # slot -> PageTable
         self._pending = {}            # slot -> _PendingPrefill
         self._resets = 0              # streams started from zero state
+        # the newest decode step's greedy ids, on the device once a step
+        # ran: what a carried lane of the next step is fed from
+        self._last_ids = np.zeros((self.slots,), np.int64)
+        self._in_flight = False       # a deferred step awaits its fetch
         _state_bytes.set(self._recurrent_state_bytes())
         self._update_gauges()
 
@@ -269,7 +286,11 @@ class PagedDecodePredictor(DecodePredictor):
 
     def release(self, slot):
         """Drop a stream's page refs (cache-registered prefix pages
-        stay resident for future hits)."""
+        stay resident for future hits). With a deferred decode step in
+        flight that still writes this stream's last page, the release
+        is safe as it is: that write is already queued, and a program
+        that could own the page again is dispatched after it, so the
+        device runs it after it."""
         slot = int(slot)
         table = self._tables.pop(slot, None)
         self._pending.pop(slot, None)
@@ -445,13 +466,17 @@ class PagedDecodePredictor(DecodePredictor):
             table.pool.unref(dst)
 
     # -- execution ---------------------------------------------------------
-    def prefill_step(self, slot, return_logits=False):
+    def prefill_step(self, slot, return_logits=False, before_fetch=None):
         """Advance one stream's prefill by ONE chunk. Returns None
         while more chunks remain; on the final chunk, registers the
         prompt with the prefix cache and returns the first greedy
         token (with return_logits: (token, logits [vocab])). Raises
         CacheExhaustedError — with this call's allocations rolled
-        back — when the pool cannot cover the chunk."""
+        back — when the pool cannot cover the chunk. `before_fetch`,
+        if given, is called on the final chunk once it is dispatched
+        and booked, before the wait for its token: the place to
+        collect() a deferred decode step, which the device runs before
+        this chunk, without waiting for the chunk first."""
         slot = int(slot)
         st = self._pending[slot]
         table = self._tables[slot]
@@ -511,24 +536,53 @@ class PagedDecodePredictor(DecodePredictor):
                 self._prefix.register(prompt, table)
             del self._pending[slot]
             _prefill_chunks.observe(st.chunks)
+        if before_fetch is not None:
+            before_fetch()
         with RecordEvent('paged.prefill.fetch'):
             tok = int(np.asarray(ids)[0])
             if return_logits:
                 return tok, np.asarray(logits)[0]
         return tok
 
-    def decode_step(self, tokens, positions, return_logits=False):
+    def decode_step(self, tokens, positions, return_logits=False,
+                    lanes=None, carry=(), defer=False):
         """One step for the WHOLE pool — same ABI as the dense path:
         tokens [slots], positions [slots] (each stream's next append
         position, which must be its current length). Only open,
-        fully-prefilled streams take part; every other lane is fed the
+        fully-prefilled streams take part (`lanes`, an iterable of
+        slots, names fewer: a stream left out keeps its pages and its
+        recurrent state as they are); every other lane is fed the
         null-page table row, so its mandatory write is dead weight
         exactly like the dense ring's idle-slot append. New pages are
         allocated on demand; if ANY stream cannot grow, the step runs
         nothing, this call's allocations are rolled back, and
         CacheExhaustedError(slots=[...]) names the victims — the
-        caller releases or evicts them and retries the same feed."""
+        caller releases or evicts them and retries the same feed.
+
+        Called so, the step is synchronous: it returns this step's
+        greedy ids [slots] (and logits with return_logits). With
+        `defer=True` it is one stage of a one-deep pipeline: the step
+        is dispatched, and what comes back is the ids of the deferred
+        step BEFORE it (None if none was in flight), fetched only now,
+        behind this step's dispatch, so the device has its next program
+        while the host waits for the last. This step's ids stay on the
+        device until the next deferred call or collect(). A lane in
+        `carry` (slots that took part in the step in flight) is fed
+        that step's id for it, on the device, by a select inside the
+        program: its `tokens` entry is not read. CacheExhaustedError
+        keeps its contract in both forms, and leaves the step in
+        flight as it was, still to be collected."""
         S, P, pt = self.slots, self.pages_per_slot, self.page_tokens
+        overlapped = self._in_flight
+        if overlapped and not defer:
+            raise RuntimeError('a deferred decode step is in flight — '
+                               'collect() it before a synchronous one')
+        if defer and return_logits:
+            raise ValueError('a deferred step hands back ids only')
+        carry = [int(s) for s in carry]
+        if carry and not overlapped:
+            raise ValueError('carry names slot(s) %s but no step is in '
+                             'flight to take their tokens from' % carry)
         with RecordEvent('paged.decode.tables') as ev:
             tokens = np.asarray(tokens, np.int64).reshape(S, 1, 1)
             positions = np.asarray(positions, np.int32).reshape(S)
@@ -536,8 +590,11 @@ class PagedDecodePredictor(DecodePredictor):
             pos_feed = np.zeros((S,), np.int32)
             cow_src = np.zeros((S,), np.int32)
             cow_dst = np.zeros((S,), np.int32)
+            carry_feed = np.zeros((S,), np.int32)
+            carry_feed[carry] = 1
             cows, grows, failed, live = [], [], [], []
-            for slot in sorted(self._tables):
+            for slot in sorted(self._tables if lanes is None
+                               else map(int, lanes)):
                 if slot in self._pending:
                     continue          # mid-prefill: stays on null pages
                 table = self._tables[slot]
@@ -570,9 +627,13 @@ class PagedDecodePredictor(DecodePredictor):
             ev.attrs['pages_read'] = pages_read = \
                 sum(int(pos_feed[slot]) // pt + 1 for slot in live)
             ev.attrs['pages_window'] = S * P
+            ev.attrs['overlapped'] = int(overlapped)
+            ev.attrs['carried'] = len(carry)
             _decode_pages_read.inc(pages_read)
             _decode_pages_window.inc(S * P)
             feed = {'decode_tokens': tokens,
+                    'decode_prev_ids': self._last_ids,
+                    'decode_carry': carry_feed,
                     'decode_step_idx': pos_feed,
                     'decode_page_table': table_feed,
                     'decode_cow_src': cow_src,
@@ -594,10 +655,30 @@ class PagedDecodePredictor(DecodePredictor):
                 table = self._tables[slot]
                 table.length = max(table.length, int(positions[slot]) + 1)
             self._update_gauges()
+        prev, self._last_ids, self._in_flight = self._last_ids, ids, defer
         with RecordEvent('paged.decode.fetch'):
+            if defer:
+                # the wait for the step BEFORE this one, with this one
+                # already queued on the device behind it
+                return np.asarray(prev) if overlapped else None
             if return_logits:
                 return np.asarray(ids), np.asarray(logits)
             return np.asarray(ids)
+
+    @property
+    def in_flight(self):
+        """True while a deferred step's ids have not been fetched."""
+        return self._in_flight
+
+    def collect(self):
+        """Fetch the ids [slots] of the deferred step in flight without
+        dispatching another (the last step of a burst, a drain, an
+        error path); None if none is. Not a step: no tables, no run."""
+        if not self._in_flight:
+            return None
+        self._in_flight = False
+        with RecordEvent('paged.decode.fetch'):
+            return np.asarray(self._last_ids)
 
     def prefill(self, prompts, slot_ids, return_logits=False):
         """Dense-ABI prefill (the parity / generate() path): each

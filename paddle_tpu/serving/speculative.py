@@ -98,6 +98,9 @@ class SpeculativeDecodePredictor(PagedDecodePredictor):
     end."""
 
     speculative = True
+    # where a lane stands after spec_step depends on how many tokens
+    # were accepted: a step cannot be packed before the last is fetched
+    deferred_decode = False
 
     def __init__(self, predictor, slots=None, spec_k=None,
                  draft_layers=None, draft_predictor=None,

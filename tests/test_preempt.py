@@ -311,6 +311,84 @@ def test_preempt_policy_off_keeps_legacy_shed(predictor, ref_dec,
 
 
 @pytest.mark.timeout(600)
+@pytest.mark.parametrize('policy', ['swap', 'reprefill', 'off'])
+def test_exhaustion_at_a_deferred_step_accepts_the_one_in_flight(
+        predictor, ref_dec, policy_flags, policy):
+    """The pipelined loop meets CacheExhaustedError with a step in
+    flight: that step is collected and accepted before a victim is
+    saved, released or failed, and every stream that finishes is the
+    reference's."""
+    from paddle_tpu.obs import telemetry
+    set_flags({'FLAGS_serving_preempt_policy': policy})
+    pb = PB[:4]
+    ref_a, ref_b = ref_dec.generate(PA, GEN), ref_dec.generate(pb, GEN)
+    dec, eng = _tight_engine(predictor)
+    seen = []                 # (what, was a step in flight)
+    later = []
+    step = dec.decode_step
+
+    def decode_step(*a, **kw):
+        # PA holds three of the five pages from its first step on; a
+        # prompt of one page joins at PA's second step and takes the
+        # other two (its own first step opens a page); PA's fifth step
+        # (position 12) finds none, with the step before it in flight
+        seen.append(('step', dec.in_flight))
+        if len([ev for ev in seen if ev[0] == 'step']) == 2:
+            later.append(eng.submit(pb, max_new_tokens=GEN, priority=1))
+        try:
+            return step(*a, **kw)
+        except CacheExhaustedError:
+            seen.append(('exhausted', dec.in_flight))
+            raise
+
+    def noting(what, call):
+        def noted(slot):
+            seen.append((what, dec.in_flight))
+            return call(slot)
+        return noted
+    dec.decode_step = decode_step
+    dec.save_stream = noting('save', dec.save_stream)
+    dec.release = noting('release', dec.release)
+    telemetry.enable()
+    try:
+        telemetry.reset()
+        eng.start()
+        try:
+            ra = eng.submit(PA, max_new_tokens=GEN, priority=0)
+            _wait_tokens(ra)
+            assert ra.wait(240)
+            rb, = later
+            assert rb.wait(240)
+            st = eng.stats()
+        finally:
+            eng.stop()
+        counters = telemetry.snapshot()['counters']
+    finally:
+        telemetry.disable(final_flush=False)
+        telemetry.reset()
+    seen = [ev for ev in seen if ev[0] != 'step']
+    assert counters['serving.decode_steps_overlapped'] >= 1
+    hits = [i for i, ev in enumerate(seen) if ev == ('exhausted', True)]
+    assert hits, seen         # it did happen with a step in flight
+    for i in hits:
+        # what follows the refusal sees the predictor whole again
+        assert seen[i + 1][0] in ('save', 'release') \
+            and seen[i + 1][1] is False, seen
+    if policy == 'off':
+        assert sorted([ra.state, rb.state]) == ['DONE', 'FAILED']
+        assert st['preemptions'] == 0
+    else:
+        assert st['preemptions'] >= 1 and st['resumes'] >= 1
+    for req, ref in ((ra, ref_a), (rb, ref_b)):
+        if req.state == 'DONE':
+            assert np.array_equal(req.tokens, ref), (req.tokens, ref)
+        else:
+            assert 'CacheExhausted' in req.error
+            assert req.tokens == list(ref[:len(req.tokens)])
+    assert not dec.in_flight and not dec.slot_tokens()
+
+
+@pytest.mark.timeout(600)
 def test_speculative_preemption_bit_exact(predictor, ref_dec,
                                           policy_flags):
     ref_a, ref_b = ref_dec.generate(PA, GEN), ref_dec.generate(PB, GEN)
