@@ -403,12 +403,19 @@ def bench_inference():
     # that per-call mean IS the full-recompute tokens/s baseline
     # (tbs next-tokens per call). The KV-cached pair (serving/)
     # prefills once, then each decode step touches one token against
-    # the ring caches — O(1) per token vs O(T).
+    # the page pool — O(1) per token vs O(T).
     out['infer_decode_config'] = 'L%d_D%d_T%d_bs%d' % (
         cfg.layers, cfg.dim, cfg.max_len, tbs)
     out['infer_decode_recompute_tokens_per_sec'] = round(tbs / mean, 2)
-    dec = predictor.prepare_decoding(slots=tbs, prefill_batch=1)
-    prompts = [toks[i, :, 0] for i in range(tbs)]
+    # every stream one token short of its window, the step appending
+    # the last; a page more a lane than the window, because that append
+    # forks the tail page the prefix cache shares
+    pages_per_slot = -(-cfg.max_len
+                       // fluid.flags.get_flag('serving_page_tokens'))
+    dec = predictor.prepare_decoding(
+        slots=tbs, kv_pages=tbs * (pages_per_slot + 1) + 1,
+        prefill_chunk=cfg.max_len)
+    prompts = [toks[i, :-1, 0] for i in range(tbs)]
     t0 = time.perf_counter()
     for i in range(tbs):
         dec.prefill([prompts[i]], [i])

@@ -15,7 +15,7 @@ seeded random weights, in one process (a chip belongs to one process):
            (and pallas.flash.naive == 0), a real peak_bytes_in_use, a
            non-empty compile cache.
   serve    save_inference_model -> AnalysisPredictor ->
-           prepare_decoding(paged=True) -> ServingEngine, as
+           prepare_decoding() -> ServingEngine, as
            tools/serve_bench.py does: eight requests of mixed prompt
            lengths, two of them identical, complete with identical
            streams for the identical pair, prefill and decode each
@@ -333,7 +333,7 @@ def phase_serve(cfg, env):
             fluid.io.save_inference_model(tmp, ['tokens'], [logits], exe,
                                           main_program=main_prog)
         pred = AnalysisPredictor(AnalysisConfig(tmp))
-    dec = pred.prepare_decoding(slots=SLOTS, paged=True)
+    dec = pred.prepare_decoding(slots=SLOTS)
 
     # prompt lengths 32..448 and 32 new tokens at T=512, scaled with T
     unit = cfg.max_len // 16

@@ -94,12 +94,9 @@ Known flags:
   obs_flush_secs         seconds between periodic metric-snapshot
                          export lines (a final line is flushed at
                          clean exit regardless)
-  serving_slots          KV-cache slot-pool size per DecodePredictor
+  serving_slots          slot-pool size per PagedDecodePredictor
                          (paddle_tpu/serving/): decode runs one
                          compiled step over this many lanes
-  serving_prefill_batch  prompts per compiled prefill call (admissions
-                         are grouped up to this; 1 = one prefill per
-                         request)
   serving_max_queue      ServingEngine admission queue bound — submit()
                          past this raises instead of buffering
                          unboundedly
@@ -108,7 +105,7 @@ Known flags:
   serving_page_tokens    paged KV cache: tokens per page (page-pool
                          granularity for alloc/COW/prefix sharing)
   serving_kv_pages       paged KV cache: physical pages in the pool
-                         (0 = auto-size to dense-equivalent capacity,
+                         (0 = auto-size to a full window per slot,
                          slots * ceil(max_len/page_tokens) + 1)
   serving_prefill_chunk  chunked prefill: tokens admitted per engine
                          iteration while a prompt prefills, so long
@@ -288,8 +285,8 @@ _DEFAULTS = {
     # contract via 3D dot_general on the ORIGINAL shape instead of
     # flattening to 2D first, so the vjp-derived dW is a batch-dims
     # contraction over the un-flattened activation (measured faster on
-    # the bench transformer; tools/probe_dw_layout.py + PERF.md
-    # round-5 A/B). Off = the reshape-to-2D formulation.
+    # the bench transformer of an earlier machine; not measured on this
+    # one). Off = the reshape-to-2D formulation.
     'mul_dotgen': True,
     # flash-attention kernel block overrides (0 = use the tuned table
     # in pallas/flash_attention.py:_block_sizes)
@@ -368,10 +365,8 @@ _DEFAULTS = {
     # params stay fp32). Off by default for exact-fp32 parity.
     'bf16_momentum': False,
     # serving engine (paddle_tpu/serving/): decode slot-pool size,
-    # prompts per compiled prefill, admission queue bound, idle worker
-    # poll interval
+    # admission queue bound, idle worker poll interval
     'serving_slots': 8,
-    'serving_prefill_batch': 1,
     'serving_max_queue': 256,
     'serving_idle_wait': 0.05,
     # paged KV cache (serving/paging.py): tokens per page, pool size in

@@ -157,18 +157,15 @@ class AnalysisPredictor(Predictor):
     def clone(self):
         return AnalysisPredictor(self._config, _clone_of=self)
 
-    def prepare_decoding(self, slots=None, prefill_batch=None,
-                         paged=False, page_tokens=None, kv_pages=None,
+    def prepare_decoding(self, slots=None, page_tokens=None, kv_pages=None,
                          prefill_chunk=None, speculative=False,
                          spec_k=None, draft_layers=None,
-                         draft_predictor=None, mesh=None):
-        """Transpile the loaded LM into the KV-cached prefill + decode
-        pair and return a serving.DecodePredictor over this predictor's
-        weight scope (see paddle_tpu/serving/decode.py). paged=True
-        returns a serving.PagedDecodePredictor instead — page-pool
-        cache with copy-on-write prefix sharing and chunked prefill
-        (serving/paged.py; page_tokens / kv_pages / prefill_chunk
-        default from FLAGS_serving_*). speculative=True (implies paged)
+                         draft_predictor=None, mesh=None, paged=True):
+        """Transpile the loaded LM into the paged prefill + decode pair
+        and return a serving.PagedDecodePredictor over this predictor's
+        weight scope — page-pool cache with copy-on-write prefix sharing
+        and chunked prefill (serving/paged.py; page_tokens / kv_pages /
+        prefill_chunk default from FLAGS_serving_*). speculative=True
         returns a serving.SpeculativeDecodePredictor: draft/verify
         greedy speculation with bit-exact acceptance
         (serving/speculative.py; spec_k / draft_layers default from
@@ -180,6 +177,12 @@ class AnalysisPredictor(Predictor):
         bit-exact vs single-chip (serving/mesh.py). Raises
         transpiler.DecodeTranspileError if the program is not a
         recognizable decoder-only LM."""
+        # `paged` is accepted only because benchmarks/builders still pass
+        # paged=True (ROADMAP R0c drops it there, then here)
+        if not paged:
+            raise ValueError(
+                'prepare_decoding(paged=False): the dense ring KV cache '
+                'was removed; every decoder serves from the page pool')
         if speculative:
             from .serving import SpeculativeDecodePredictor
             return SpeculativeDecodePredictor(
@@ -188,16 +191,12 @@ class AnalysisPredictor(Predictor):
                 draft_predictor=draft_predictor,
                 page_tokens=page_tokens, kv_pages=kv_pages,
                 prefill_chunk=prefill_chunk, mesh=mesh)
-        if paged:
-            from .serving import PagedDecodePredictor
-            return PagedDecodePredictor(self, slots=slots,
-                                        page_tokens=page_tokens,
-                                        kv_pages=kv_pages,
-                                        prefill_chunk=prefill_chunk,
-                                        mesh=mesh)
-        from .serving import DecodePredictor
-        return DecodePredictor(self, slots=slots,
-                               prefill_batch=prefill_batch, mesh=mesh)
+        from .serving import PagedDecodePredictor
+        return PagedDecodePredictor(self, slots=slots,
+                                    page_tokens=page_tokens,
+                                    kv_pages=kv_pages,
+                                    prefill_chunk=prefill_chunk,
+                                    mesh=mesh)
 
 
 def create_analysis_predictor(config):

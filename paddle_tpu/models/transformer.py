@@ -189,29 +189,18 @@ def train_network(tokens, labels, cfg):
 
 
 # ---------------------------------------------------------------------------
-# Cached-attention mode (paddle_tpu/serving/): prefill + decode builders
+# Cached-attention mode (paddle_tpu/serving/): the spec the builders bind
 # ---------------------------------------------------------------------------
 #
 # A loaded language-model program is transpiled
 # (transpiler/decode_transpiler.py) into a DecodeSpec — the discovered
-# dims plus the exact parameter NAMES of the source program — and these
-# builders emit two fresh programs that bind those names, so both run
-# against the Predictor's existing weight Scope without copying a byte:
-#
-#   prefill: [pb, T, 1] prompt tokens (+ per-prompt last position and
-#            target slot) -> full causal attention, K/V written into the
-#            [slots, T, H, dk] ring caches, last-real-position logits
-#   decode:  [slots, 1, 1] one token per slot + per-slot step_idx ->
-#            ring append at step_idx % T, attention over the cache,
-#            next-token logits. O(1) per token instead of O(T).
-#
-# Everything is static-shape (slot count, T, heads fixed at build time)
-# so each program compiles exactly once through the executor's
-# whole-block jit cache; slot liveness is a masking question
-# (decode_mask), never a shape question. The decode attention reuses the
-# SAME ops as the full path (mul, matmul+alpha, set-to--1e9 mask, fp32
-# softmax) over same-length reduction axes, which is what makes greedy
-# decode bit-exact against full-prefix recompute (tests/test_serving.py).
+# dims plus the exact parameter NAMES of the source program — and the
+# paged builders further down emit fresh programs that bind those
+# names, so all run against the Predictor's existing weight Scope
+# without copying a byte. Their attention reuses the SAME ops as the
+# full path (mul, matmul+alpha, set-to--1e9 mask, fp32 softmax) over
+# same-length reduction axes, which is what keeps greedy decode equal
+# to full-prefix recompute (tests/test_serving.py, tests/test_paged.py).
 
 class DecodeTranspileError(ValueError):
     """The loaded program is not a transpilable decoder-only LM, or what
@@ -269,19 +258,6 @@ class DecodeSpec(object):
         self.param_specs = dict(param_specs or {})
         self.mesh = mesh
 
-    def cache_names(self, layer=None):
-        """Ring-cache var names; shared by the prefill/decode pair."""
-        if layer is not None:
-            return ('kv_cache.layer%d.k' % layer,
-                    'kv_cache.layer%d.v' % layer)
-        out = []
-        for i in self.kv_layers:
-            out.extend(self.cache_names(i))
-        return out
-
-    def cache_shape(self, slots):
-        return (slots, self.max_len, self.heads, self.dh)
-
     def pool_names(self, layer=None):
         """Paged K/V pool var names; shared by the paged pair."""
         if layer is not None:
@@ -314,12 +290,10 @@ class DecodeSpec(object):
             build_paged_decode_program(
                 self, slots, num_pages, page_tokens, pages_per_slot)
 
-    def cache_spec(self):
-        """PartitionSpec (tuple form) for the K/V state: heads axis
-        sharded over tp. Dim 2 is H in BOTH layouts — ring caches
-        [slots, T, H, dh] and page pools [pages, pt, H, dh] — so one
-        spec covers dense and paged serving. Flash-attention specs
-        serve replicated: the Pallas kernel is opaque to GSPMD."""
+    def pool_spec(self):
+        """PartitionSpec (tuple form) for the page pools
+        [pages, pt, H, dh]: heads axis sharded over tp. Flash-attention
+        specs serve replicated: the Pallas kernel is opaque to GSPMD."""
         return (None, None, _tp_ax(self), None)
 
     def serve_param_specs(self):
@@ -387,23 +361,6 @@ def _tmp_var(dtype='float32'):
         name=unique_name.generate('kv_decode.tmp'), dtype=dtype)
 
 
-def _create_cache_vars(spec, slots):
-    """Per-layer K/V ring vars: persistable (the executor writes them
-    back to the Scope each run — and donates them, so the update is
-    in-place on device) but is_cache (io.py save/load skip them)."""
-    from ..framework import default_main_program
-    block = default_main_program().global_block()
-    caches = []
-    for i in range(spec.layers):
-        kn, vn = spec.cache_names(i)
-        caches.append(tuple(
-            block.create_var(name=n, shape=spec.cache_shape(slots),
-                             dtype='float32', persistable=True,
-                             stop_gradient=True, is_cache=True)
-            for n in (kn, vn)))
-    return caches
-
-
 def _qkv_parts(x, spec, blk, t, qk_norm=None):
     """qkv fc + per-part slice/reshape to [-1, t, H, dh] — the full
     path's heads() up to (not including) the transpose, which is the
@@ -425,64 +382,6 @@ def _qkv_parts(x, spec, blk, t, qk_norm=None):
     return part(0, D, 'q'), part(D, 2 * D, 'k'), part(2 * D, 3 * D)
 
 
-def _prefill_attention(x, spec, blk, cache, slot_idx):
-    q4, k4, v4 = _qkv_parts(x, spec, blk, spec.max_len)
-    for cache_var, new in ((cache[0], k4), (cache[1], v4)):
-        _block_op('kv_cache_write',
-                  inputs={'Cache': [cache_var], 'X': [new],
-                          'Slots': [slot_idx]},
-                  outputs={'Out': [cache_var]})
-    ax = _tp_ax(spec)
-    q = sharding_constraint(L.transpose(q4, perm=[0, 2, 1, 3]),
-                            (None, ax, None, None))    # [pb, H, T, dh]
-    k = sharding_constraint(L.transpose(k4, perm=[0, 2, 1, 3]),
-                            (None, ax, None, None))
-    v = sharding_constraint(L.transpose(v4, perm=[0, 2, 1, 3]),
-                            (None, ax, None, None))
-    if spec.use_flash:
-        ctx = L.flash_attention(q, k, v, causal=True)
-    else:
-        scores = L.matmul(q, k, transpose_y=True,
-                          alpha=1.0 / np.sqrt(spec.dh))
-        probs = L.softmax(L.causal_mask_bias(scores))
-        ctx = L.matmul(probs, v)
-    ctx = L.transpose(ctx, perm=[0, 2, 1, 3])
-    ctx = L.reshape(ctx, shape=[-1, spec.max_len, spec.dim])
-    # replicate before the proj contraction: the all-gather of the
-    # per-head context is pure data movement, and the full-D dot then
-    # reduces in single-chip order — the bit-exactness invariant
-    ctx = sharding_constraint(ctx, (None, None, None))
-    return _named_fc(ctx, spec.dim, blk['proj'])
-
-
-def _decode_attention(x, spec, blk, cache, step_idx):
-    q1, k1, v1 = _qkv_parts(x, spec, blk, 1)           # [S, 1, H, dh]
-    for cache_var, new in ((cache[0], k1), (cache[1], v1)):
-        _block_op('kv_cache_append',
-                  inputs={'Cache': [cache_var], 'X': [new],
-                          'StepIdx': [step_idx]},
-                  outputs={'Out': [cache_var]})
-    ax = _tp_ax(spec)
-    q = sharding_constraint(L.transpose(q1, perm=[0, 2, 1, 3]),
-                            (None, ax, None, None))    # [S, H, 1, dh]
-    kt = sharding_constraint(L.transpose(cache[0], perm=[0, 2, 1, 3]),
-                             (None, ax, None, None))   # [S, H, T, dh]
-    vt = sharding_constraint(L.transpose(cache[1], perm=[0, 2, 1, 3]),
-                             (None, ax, None, None))
-    scores = L.matmul(q, kt, transpose_y=True,
-                      alpha=1.0 / np.sqrt(spec.dh))    # [S, H, 1, T]
-    masked = _tmp_var()
-    _block_op('decode_mask',
-              inputs={'X': [scores], 'StepIdx': [step_idx]},
-              outputs={'Out': [masked]})
-    probs = L.softmax(masked)
-    ctx = L.matmul(probs, vt)                          # [S, H, 1, dh]
-    ctx = L.transpose(ctx, perm=[0, 2, 1, 3])
-    ctx = L.reshape(ctx, shape=[-1, 1, spec.dim])
-    ctx = sharding_constraint(ctx, (None, None, None))
-    return _named_fc(ctx, spec.dim, blk['proj'])
-
-
 def _cached_block(x, spec, i, attention):
     blk = spec.blocks[i]
     attn = attention(_named_ln(x, blk['ln1']), spec, blk)
@@ -497,104 +396,11 @@ def _cached_block(x, spec, i, attention):
     return L.elementwise_add(x, ffn)
 
 
-def build_prefill_program(spec, slots, batch=1):
-    """Prefill program over `batch` prompt rows (padded to max_len).
-
-    Feeds:  prefill_tokens [batch, T, 1] int64, prefill_pos [batch]
-            int32 (index of each prompt's LAST real token, i.e.
-            len - 1), prefill_slots [batch] int32 (target cache slots).
-    Writes every layer's K/V rows for the fed slots (whole-row
-    overwrite), then gathers each prompt's last real position before
-    the lm_head — logits [batch, vocab] + greedy ids [batch].
-    Returns (program, feed_names, fetch_vars[logits, ids]).
-    """
-    from ..framework import Program, program_guard
-    prog, startup = Program(), Program()
-    prog._is_test = True
-    with program_guard(prog, startup):
-        tokens = L.data('prefill_tokens', [batch, spec.max_len, 1],
-                        append_batch_size=False, dtype='int64')
-        pos_idx = L.data('prefill_pos', [batch],
-                         append_batch_size=False, dtype='int32')
-        slot_idx = L.data('prefill_slots', [batch],
-                          append_batch_size=False, dtype='int32')
-        caches = _create_cache_vars(spec, slots)
-        emb = L.embedding(tokens, size=[spec.vocab, spec.dim],
-                          param_attr=_named_attr(spec.emb_w))
-        pos = L.position_embedding(emb, spec.pos_len,
-                                   param_attr=_named_attr(spec.pos_w))
-        x = L.elementwise_add(emb, pos)
-        for i in range(spec.layers):
-            x = _cached_block(
-                x, spec, i,
-                lambda ln, sp, blk, _i=i: _prefill_attention(
-                    ln, sp, blk, caches[_i], slot_idx))
-        x = _named_ln(x, spec.final_ln)
-        last = _tmp_var()
-        _block_op('gather_time',
-                  inputs={'X': [x], 'Index': [pos_idx]},
-                  outputs={'Out': [last]})               # [batch, D]
-        logits = _named_fc(last, spec.vocab, spec.head,
-                           num_flatten_dims=1)           # [batch, V]
-        ids = L.argmax(logits, axis=-1)
-    return prog, ['prefill_tokens', 'prefill_pos', 'prefill_slots'], \
-        [logits, ids]
-
-
-def build_decode_program(spec, slots):
-    """One-token decode step over the whole slot pool.
-
-    Feeds:  decode_tokens [slots, 1, 1] int64 (the token each slot
-            generated last), decode_step_idx [slots] int32 (its
-            absolute position; the ring write lands at step_idx % T).
-    Appends one K/V row per layer per slot, attends over the ring with
-    decode_mask validity, and returns next-token logits [slots, vocab]
-    + greedy ids [slots]. Idle slots compute garbage that the caller
-    ignores — their cache rows are rewritten wholesale at admission.
-    Returns (program, feed_names, fetch_vars[logits, ids]).
-    """
-    from ..framework import Program, program_guard
-    prog, startup = Program(), Program()
-    prog._is_test = True
-    with program_guard(prog, startup):
-        tokens = L.data('decode_tokens', [slots, 1, 1],
-                        append_batch_size=False, dtype='int64')
-        step_idx = L.data('decode_step_idx', [slots],
-                          append_batch_size=False, dtype='int32')
-        caches = _create_cache_vars(spec, slots)
-        emb = L.embedding(tokens, size=[spec.vocab, spec.dim],
-                          param_attr=_named_attr(spec.emb_w))      # [S,1,D]
-        # per-slot gather of the positional TABLE row for this step —
-        # the prefill path's pos[:T] broadcast slice has no analog when
-        # every slot sits at a different position
-        from ..layer_helper import LayerHelper
-        helper = LayerHelper('position_embedding',
-                             param_attr=_named_attr(spec.pos_w))
-        pos_var = helper.create_parameter(
-            attr=helper.param_attr, shape=[spec.pos_len, spec.dim],
-            dtype='float32')
-        pos = _tmp_var()
-        _block_op('position_embedding_at',
-                  inputs={'Pos': [pos_var], 'Index': [step_idx]},
-                  outputs={'Out': [pos]})                # [S, 1, D]
-        x = L.elementwise_add(emb, pos)
-        for i in range(spec.layers):
-            x = _cached_block(
-                x, spec, i,
-                lambda ln, sp, blk, _i=i: _decode_attention(
-                    ln, sp, blk, caches[_i], step_idx))
-        x = _named_ln(x, spec.final_ln)
-        logits3 = _named_fc(x, spec.vocab, spec.head)    # [S, 1, V]
-        logits = L.reshape(logits3, shape=[-1, spec.vocab])
-        ids = L.argmax(logits, axis=-1)
-    return prog, ['decode_tokens', 'decode_step_idx'], [logits, ids]
-
-
 # ---------------------------------------------------------------------------
 # Paged-cache mode (paddle_tpu/serving/paged.py): page-table builders
 # ---------------------------------------------------------------------------
 #
-# The dense ring generalized to a vLLM-style page pool: one
+# A vLLM-style page pool: one
 # [num_pages, page_tokens, H, dk] pool var per layer per K/V, and a
 # per-slot page TABLE fed each step mapping logical position j to
 # pool[table[j // pt], j % pt]. Every program stays static-shape (pool
@@ -602,7 +408,7 @@ def build_decode_program(spec, slots):
 # exactly once; allocation, COW and prefix sharing are HOST decisions
 # (serving/paging.py) that only ever change feed VALUES. Physical page
 # 0 is the reserved null page — dead rows write there, reads of it are
-# always masked. Validity is absolute (j <= position): no ring wrap,
+# always masked. Validity is absolute (j <= position): nothing wraps,
 # so running out of pages is a typed host-side error, never a silent
 # slide (COVERAGE divergence 8).
 #
@@ -619,8 +425,9 @@ def build_decode_program(spec, slots):
 
 def _create_pool_vars(spec, num_pages, page_tokens):
     """{layer: (K, V)} page-pool vars of the layers that keep K/V:
-    persistable + donated like the ring caches (in-place device update),
-    is_cache (never checkpointed)."""
+    persistable (the executor writes them back to the Scope each run —
+    and donates them, so the update is in-place on device) but is_cache
+    (io.py save/load skip them)."""
     from ..framework import default_main_program
     block = default_main_program().global_block()
     pools = {}
@@ -872,8 +679,8 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
             its decode_tokens entry: serving/paged.py dispatches a step
             before it has fetched the one before),
             decode_step_idx [slots] int32 (absolute position of the
-            incoming token — same ABI as the dense step, but the write
-            lands at pool[table[pos // pt], pos % pt], never wrapped),
+            incoming token: the write lands at
+            pool[table[pos // pt], pos % pt], never wrapped),
             decode_page_table [slots, P] int32 (all-zero rows for idle
             or mid-prefill slots: their appends hit the null page),
             decode_cow_src / decode_cow_dst [slots] int32 (page copies

@@ -77,7 +77,7 @@ class ParamSubscriber(object):
     def __init__(self, endpoints, predictor, engine=None,
                  subscriber_id=0, poll_secs=None, pull_timeout=None):
         """endpoints: the pserver fleet (the transpile's
-        pserver_endpoints). predictor: the serving DecodePredictor
+        pserver_endpoints). predictor: the serving PagedDecodePredictor
         whose parent scope receives installs. engine: the
         ServingEngine whose step boundary gates installs (None: direct
         install — single-threaded/benchmark use). subscriber_id:

@@ -3,12 +3,12 @@
 single-device Executor (no mesh) it lowers to ordinary fused attention,
 so programs are portable between local debugging and sp meshes.
 
-KV-cache ops (serving/): static-shape ring-buffer cache primitives for
-the prefill/decode program pair (models/transformer.py builders). Every
-shape is fixed at build time — slots, max_len, heads — so the decode
-step compiles once for the life of the server; per-slot positions are
-feeds, and validity is expressed as masking (the beam-search lattice
-idiom), never as a dynamic shape."""
+KV-cache ops (serving/): static-shape page-pool primitives for the
+paged prefill/decode/verify programs (models/transformer.py builders).
+Every shape is fixed at build time — slots, pages, heads — so the
+decode step compiles once for the life of the server; per-slot
+positions and page tables are feeds, and validity is expressed as
+masking (the beam-search lattice idiom), never as a dynamic shape."""
 from __future__ import annotations
 
 import functools
@@ -101,61 +101,11 @@ register_vjp_grad('flash_attention', in_slots=('Q', 'K', 'V'))
 # KV-cache primitives (paddle_tpu/serving/)
 # ---------------------------------------------------------------------------
 
-@op_emitter('kv_cache_write')
-def _kv_cache_write_emit(ctx, op):
-    """Prefill: scatter a whole prompt's K or V rows into their slots.
-    Cache [slots, T, H, dk], X [pb, T, H, dk], Slots [pb] int32 — the
-    entire [T] row is overwritten, so stale ring contents from a slot's
-    previous occupant can never leak into a new request."""
-    cache = ctx.get(op.single_input('Cache'))
-    x = ctx.get(op.single_input('X'))
-    slots = ctx.get(op.single_input('Slots')).astype(jnp.int32)
-    ctx.set(op.single_output('Out'), cache.at[slots].set(x.astype(cache.dtype)))
-
-
-@op_emitter('kv_cache_append')
-def _kv_cache_append_emit(ctx, op):
-    """Decode: per-slot ring write of one new K or V row.
-    Cache [slots, T, H, dk], X [slots, 1, H, dk], StepIdx [slots] int32
-    (absolute position of the incoming token; the write lands at
-    StepIdx % T). Every slot writes every step — an idle slot writes at
-    its own ring position 0, which is dead weight masked by decode_mask
-    and fully overwritten by the prefill that next admits the slot."""
-    cache = ctx.get(op.single_input('Cache'))
-    x = ctx.get(op.single_input('X'))
-    step = ctx.get(op.single_input('StepIdx')).astype(jnp.int32)
-    T = cache.shape[1]
-    rows = jnp.arange(cache.shape[0], dtype=jnp.int32)
-    ctx.set(op.single_output('Out'),
-            cache.at[rows, step % T].set(x[:, 0].astype(cache.dtype)))
-
-
-@op_emitter('decode_mask')
-def _decode_mask_emit(ctx, op):
-    """Ring-aware validity mask for decode attention scores.
-    X [slots, H, 1, T] (scores against the full cache), StepIdx [slots].
-    Cache index j holds the token at absolute position
-    t_j = step - ((step - j) mod T); it is a real, in-window token iff
-    t_j >= 0. For step < T this reduces to j <= step (plain causal);
-    for step >= T the whole ring is valid. Same set-to--1e9 semantics
-    as the causal_mask op so masked lanes underflow to exactly 0.0
-    after the softmax's exp — the bit-exactness contract with the
-    full-recompute path."""
-    x = ctx.get(op.single_input('X'))
-    step = ctx.get(op.single_input('StepIdx')).astype(jnp.int32)
-    T = x.shape[-1]
-    j = jnp.arange(T, dtype=jnp.int32)
-    s = step[:, None]                                  # [slots, 1]
-    valid = (s - ((s - j[None, :]) % T)) >= 0          # [slots, T]
-    valid = valid[:, None, None, :]                    # [slots, 1, 1, T]
-    ctx.set(op.single_output('Out'), jnp.where(valid, x, -1e9))
-
-
 @op_emitter('position_embedding_at')
 def _position_embedding_at_emit(ctx, op):
     """Gather one positional-embedding row per slot: Pos [max_len, D],
-    Index [slots] int32 -> [slots, 1, D] (ring position Index % T_pos,
-    matching the prefill path's pos[:T] table slice). A 2-D Index
+    Index [slots] int32 -> [slots, 1, D] (table row Index % T_pos). A
+    2-D Index
     [slots, R] gathers a row per (slot, row) -> [slots, R, D] — the
     verify program's per-proposal positions."""
     pos = ctx.get(op.single_input('Pos'))
@@ -181,17 +131,17 @@ def _gather_time_emit(ctx, op):
 # ---------------------------------------------------------------------------
 # Paged KV-cache primitives (serving/paging.py + serving/paged.py)
 #
-# The ring idiom generalized to a page-indexed address space: one
-# [num_pages, page_tokens, H, dk] pool per layer instead of per-slot
-# rings, a per-slot page TABLE (a feed) mapping logical position j to
-# pool[table[j // pt], j % pt]. Physical page 0 is RESERVED as the null
+# A page-indexed address space: one [num_pages, page_tokens, H, dk]
+# pool per layer, a per-slot page TABLE (a feed) mapping logical
+# position j to pool[table[j // pt], j % pt]. Physical page 0 is
+# RESERVED as the null
 # page: never allocated, the redirect target for dead rows and
 # unpopulated table entries, always masked on read — so every slot can
 # be written every step (the static-shape contract) without liveness
 # ever becoming a shape question. Validity is absolute (j <= position):
 # pages are allocated on demand rather than wrapped, which is what lets
-# exhaustion surface as a typed host-side error instead of the dense
-# ring's silent slide (COVERAGE divergence 8).
+# exhaustion surface as a typed host-side error instead of a silent
+# slide (COVERAGE divergence 8).
 # ---------------------------------------------------------------------------
 
 @op_emitter('kv_page_cow')
@@ -238,7 +188,7 @@ def _kv_page_append_emit(ctx, op):
     Positions [slots] int32 (absolute position of the incoming token).
     Every slot writes every step — idle or mid-prefill slots are fed an
     all-zero table row and position 0, so their writes land in the null
-    page (the paged analog of the ring's dead-weight write). With 2-D
+    page. With 2-D
     Positions [slots, R] and X [slots, R, H, dk], R rows are appended
     per slot in one shot — the speculative verify pass's multi-token
     append."""
@@ -304,8 +254,8 @@ def _paged_decode_mask_emit(ctx, op):
     (J = P*pt gathered positions), Positions [slots]. The page table is
     an absolute address space — logical index j holds the token at
     position j, valid iff j <= positions[s] (the token being appended
-    this step included). No ring wrap to undo; same set-to--1e9
-    semantics as decode_mask so masked lanes underflow to exactly 0.0
+    this step included). Same set-to--1e9 semantics as the
+    causal_mask op, so masked lanes underflow to exactly 0.0
     after the softmax's exp — the bit-exactness contract. It is the
     mask inside paged_attention's reference lowering."""
     x = ctx.get(op.single_input('X'))
@@ -423,13 +373,6 @@ def _paged_prefill_mask_emit(ctx, op):
     ctx.set(op.single_output('Out'), jnp.where(valid, x, -1e9))
 
 
-def _kv_cache_update_infer(op, block):
-    cache = block.var_recursive(op.single_input('Cache'))
-    out = block.var_recursive(op.single_output('Out'))
-    out.shape = cache.shape
-    out.dtype = cache.dtype
-
-
 def _decode_mask_infer(op, block):
     x = block.var_recursive(op.single_input('X'))
     out = block.var_recursive(op.single_output('Out'))
@@ -471,11 +414,6 @@ def _kv_page_gather_infer(op, block):
     out.dtype = pool.dtype
 
 
-register_op('kv_cache_write', infer_shape=_kv_cache_update_infer,
-            no_grad=True)
-register_op('kv_cache_append', infer_shape=_kv_cache_update_infer,
-            no_grad=True)
-register_op('decode_mask', infer_shape=_decode_mask_infer, no_grad=True)
 register_op('kv_page_cow', infer_shape=_kv_pool_update_infer,
             no_grad=True)
 register_op('kv_page_write', infer_shape=_kv_pool_update_infer,

@@ -163,7 +163,7 @@ def _mul_emit(ctx, op):
         # after XLA's reshape folding, but the vjp-derived dW becomes a
         # batch-dims contraction over the ORIGINAL shape rather than
         # d/d(reshape) — giving layout assignment the un-flattened view
-        # of the activation (tools/probe_dw_layout.py).
+        # of the activation.
         xq, y2 = amp_cast(ctx, x, y2)
         out = jax.lax.dot_general(
             xq, y2, (((xq.ndim - 1,), (0,)), ((), ())),

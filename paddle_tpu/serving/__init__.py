@@ -3,12 +3,6 @@
 The "millions of users" half of the north star (ROADMAP item 1),
 layered on the inference Predictor ABI:
 
-- decode.py   DecodePredictor: a loaded LM transpiled into a prefill +
-              decode program pair (transpiler/decode_transpiler.py)
-              with per-layer [slots, T, H, dk] K/V ring caches living
-              in a child Scope — weights shared with the base
-              Predictor (and every clone) through the parent Scope,
-              cache state private per worker.
 - paging.py   Host-side paged-cache bookkeeping: PagePool (refcounted
               free-list allocator over [num_pages, page_tokens, H, dk]
               pools, typed retryable CacheExhaustedError when dry),
@@ -16,11 +10,15 @@ layered on the inference Predictor ABI:
               copy-on-write forks), PrefixCache (content-hash chain
               over full pages + partial tails — shared system prompts
               map their prefix pages read-only, zero recompute).
-- paged.py    PagedDecodePredictor: the DecodePredictor contract over
-              the page pool — chunked prefill (one
-              FLAGS_serving_prefill_chunk slice per engine iteration),
-              page index as a decode feed (no recompile per
-              admission), transactional on-demand page allocation.
+- paged.py    PagedDecodePredictor: a loaded LM transpiled into a
+              paged prefill + decode program pair
+              (transpiler/decode_transpiler.py) whose per-layer page
+              pools live in a child Scope — weights shared with the
+              base Predictor (and every clone) through the parent
+              Scope, cache state private per worker. Chunked prefill
+              (one FLAGS_serving_prefill_chunk slice per engine
+              iteration), page index as a decode feed (no recompile
+              per admission), transactional on-demand page allocation.
 - speculative.py  SpeculativeDecodePredictor: draft/verify speculative
               decoding over the paged cache — a layer-truncated
               self-draft (or explicit draft LM) proposes FLAGS_spec_k
@@ -33,7 +31,7 @@ layered on the inference Predictor ABI:
 - engine.py   ServingEngine: continuous batching over a fixed slot
               pool — requests are admitted into the running batch
               between decode steps, finished/cancelled slots are
-              evicted and masked, worker threads share weights via
+              evicted and their pages released, worker threads share weights via
               clone(). serving.* telemetry flows into paddle_tpu/obs/.
 - preempt.py  Preempt-first capacity policy: SLO tiers
               (submit(priority=)), victim selection (lowest tier,
@@ -65,7 +63,6 @@ recompute, and greedy decode is bit-exact against the full-recompute
 path (tests/test_serving.py); the same determinism makes fleet
 failover bit-exact (tests/test_fleet.py).
 """
-from .decode import DecodePredictor
 from .paging import (CacheExhaustedError, PagePool, PageTable,
                      PrefixCache, chain_keys)
 from .paged import PagedDecodePredictor
@@ -78,8 +75,8 @@ from .disagg import ShipError
 from .fleet import (FleetRouter, FleetAutoscaler, FleetRequest,
                     OverloadError, FleetDeployError)
 
-__all__ = ['DecodePredictor', 'PagedDecodePredictor',
-           'DraftModel', 'SpeculativeDecodePredictor',
+__all__ = ['PagedDecodePredictor', 'DraftModel',
+           'SpeculativeDecodePredictor',
            'CacheExhaustedError', 'PagePool', 'PageTable', 'PrefixCache',
            'chain_keys', 'ShipError',
            'ServingEngine', 'Request', 'DeadlineExceededError',

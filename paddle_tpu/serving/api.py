@@ -21,47 +21,35 @@ __all__ = ['LMServer']
 
 class LMServer(object):
     def __init__(self, model_dir_or_predictor, place=None, slots=None,
-                 prefill_batch=None, workers=1, max_queue=None,
-                 paged=False, page_tokens=None, kv_pages=None,
-                 prefill_chunk=None, speculative=False, spec_k=None,
-                 draft_layers=None, mesh=None):
+                 workers=1, max_queue=None, page_tokens=None,
+                 kv_pages=None, prefill_chunk=None, speculative=False,
+                 spec_k=None, draft_layers=None, mesh=None):
         """model_dir_or_predictor: a save_inference_model directory, an
-        AnalysisPredictor, or an already-prepared DecodePredictor.
-        paged=True serves from the page-pool cache (serving/paged.py):
+        AnalysisPredictor, or an already-prepared PagedDecodePredictor.
+        Serves from the page-pool cache (serving/paged.py):
         copy-on-write prefix sharing plus chunked prefill, sized by
         page_tokens / kv_pages / prefill_chunk (each None defaults
-        from FLAGS_serving_*). speculative=True (implies paged) serves
+        from FLAGS_serving_*). speculative=True serves
         through draft/verify speculation (serving/speculative.py);
         spec_k / draft_layers default from FLAGS_spec_*. mesh shards
         the decode programs GSPMD over a device mesh ('tp=2'; None =
         read FLAGS_serve_mesh_shape, '' = single-chip) with greedy
         output bit-exact vs single-chip (serving/mesh.py)."""
-        from .decode import DecodePredictor
+        from .paged import PagedDecodePredictor
         obj = model_dir_or_predictor
-        if isinstance(obj, DecodePredictor):
+        if isinstance(obj, PagedDecodePredictor):
             dec = obj
         else:
             if isinstance(obj, str):
                 from ..inference import AnalysisConfig, AnalysisPredictor
                 obj = AnalysisPredictor(AnalysisConfig(obj, place=place))
-            if speculative:
-                dec = obj.prepare_decoding(slots=slots, speculative=True,
-                                           spec_k=spec_k,
-                                           draft_layers=draft_layers,
-                                           page_tokens=page_tokens,
-                                           kv_pages=kv_pages,
-                                           prefill_chunk=prefill_chunk,
-                                           mesh=mesh)
-            elif paged:
-                dec = obj.prepare_decoding(slots=slots, paged=True,
-                                           page_tokens=page_tokens,
-                                           kv_pages=kv_pages,
-                                           prefill_chunk=prefill_chunk,
-                                           mesh=mesh)
-            else:
-                dec = obj.prepare_decoding(slots=slots,
-                                           prefill_batch=prefill_batch,
-                                           mesh=mesh)
+            dec = obj.prepare_decoding(slots=slots, speculative=speculative,
+                                       spec_k=spec_k,
+                                       draft_layers=draft_layers,
+                                       page_tokens=page_tokens,
+                                       kv_pages=kv_pages,
+                                       prefill_chunk=prefill_chunk,
+                                       mesh=mesh)
         self._decode = dec
         self._engine = ServingEngine(dec, workers=workers,
                                      max_queue=max_queue)
@@ -155,15 +143,9 @@ class LMServer(object):
         self._engine.cancel(self._req(handle))
 
     # -- disaggregated page shipping (serving/disagg.py) -------------------
-    @property
-    def paged(self):
-        """True when serving from the page-pool cache — the only mode
-        page shipping and the fleet prefix directory apply to."""
-        return bool(getattr(self._decode, 'paged', False))
-
     def export_prefix(self, prompt):
         """Longest resident full-page chain for `prompt` as host copies
-        (see ServingEngine.export_prefix); None when non-paged or cold."""
+        (see ServingEngine.export_prefix); None when cold."""
         return self._engine.export_prefix(prompt)
 
     def install_prefix(self, prompt, keys, data, skip=0):

@@ -3,17 +3,17 @@
 Orca-style iteration-level scheduling, TPU-flavored: the decode step is
 ONE compiled program over all `slots` lanes, so admission/eviction
 never changes a shape — a request joining the running batch is a
-prefill (whole-row cache overwrite for its slot) between two decode
-steps, a finished/cancelled request is simply a lane the scheduler
-stops reading (decode_mask already hides whatever the dead lane
-writes). Worker threads each own a DecodePredictor clone — private
-cache scope + executor, weights shared through the parent Scope — and
-pull from one shared queue.
+stream opened on a free slot and prefilled in chunks between decode
+steps, a finished/cancelled request is a lane the scheduler stops
+feeding (its pages released; a lane left out of a step writes to the
+null page). Worker threads each own a PagedDecodePredictor clone —
+private cache scope + executor, weights shared through the parent
+Scope — and pull from one shared queue.
 
-A worker's loop over a paged predictor keeps ONE decode step in flight
-(a predictor that offers `deferred_decode`; the dense and the
-speculative predictors keep the serial loop: fetch, accept, pack,
-dispatch). An iteration admits, advances one prefill chunk, packs step
+A worker's loop keeps ONE decode step in flight (a predictor that
+offers `deferred_decode`; the speculative predictor keeps the serial
+loop: fetch, accept, pack, dispatch). An iteration admits, advances
+one prefill chunk, packs step
 n from what the host knows without step n-1's tokens (positions,
 budgets, page tables; a lane that carries on is fed its token on the
 device), dispatches it, and only then fetches and accepts step n-1. So
@@ -296,7 +296,7 @@ class _Lane(object):
 class ServingEngine(object):
     def __init__(self, predictor, workers=1, max_queue=None,
                  idle_wait=None):
-        """predictor: a DecodePredictor (AnalysisPredictor
+        """predictor: a PagedDecodePredictor (AnalysisPredictor
         .prepare_decoding()); workers > 1 adds clone()-shared-weight
         worker threads, each with its own slot pool."""
         self._predictors = [predictor]
@@ -497,11 +497,7 @@ class ServingEngine(object):
     def export_prefix(self, prompt):
         """Gather the longest resident full-page chain for `prompt`
         across workers into host copies (quiesced at a step boundary —
-        save_pages reads device pools). None on a non-paged engine or
-        a cold cache."""
-        if not getattr(self._predictors[0], 'paged', False):
-            return None
-
+        save_pages reads device pools). None on a cold cache."""
         def _gather():
             best = None
             for p in self._predictors:
@@ -518,8 +514,6 @@ class ServingEngine(object):
         Streams admitted by other workers simply re-prefill locally;
         correctness never depends on the install. Returns (installed,
         deduped)."""
-        if not getattr(self._predictors[0], 'paged', False):
-            raise ValueError('install_prefix needs a paged engine')
         return self.request_swap(
             lambda: self._predictors[0].install_prefix(prompt, keys,
                                                        data, skip=skip),
@@ -528,10 +522,7 @@ class ServingEngine(object):
     def resident_keys(self, prompt):
         """Worker 0's resident leading chain run for `prompt` (hex) —
         advisory, lock-free (see PagedDecodePredictor.resident_keys)."""
-        p0 = self._predictors[0]
-        if not getattr(p0, 'paged', False):
-            return []
-        return p0.resident_keys(prompt)
+        return self._predictors[0].resident_keys(prompt)
 
     def prefix_report(self):
         """Drain registered/evicted prefix-chain deltas from every
@@ -539,8 +530,6 @@ class ServingEngine(object):
         fleet prefix directory."""
         new, gone = [], []
         for p in self._predictors:
-            if not getattr(p, 'paged', False):
-                continue
             got = p.prefix_report()
             new.extend(got['new'])
             gone.extend(got['evicted'])
@@ -551,7 +540,6 @@ class ServingEngine(object):
             depth = self._qsize_locked()
             preempted = self._preempted
         p0 = self._predictors[0]
-        paged = getattr(p0, 'paged', False)
         slot_tokens = [dict(self._slot_tokens.get(i, {}))
                        for i in range(len(self._predictors))]
         out = {'queue_depth': depth, 'active': self._active_total,
@@ -565,7 +553,6 @@ class ServingEngine(object):
                'resumes': self._resumes_n,
                'preempted_streams': preempted,
                'swap_host_bytes': self._swap_budget.used_bytes,
-               'paged': paged,
                # mesh-sharded serving (serving/mesh.py): '' and 1 on
                # the single-chip path
                'mesh_shape': getattr(p0, 'mesh_shape', ''),
@@ -577,21 +564,17 @@ class ServingEngine(object):
                'slot_tokens': slot_tokens,
                'cache_tokens': sum(sum(d.values()) for d in slot_tokens),
                'jit': p0.jit_cache_stats()}
-        if paged:
-            kv = {'pages_in_use': 0, 'pages_free': 0, 'prefix_hits': 0,
-                  'prefix_misses': 0, 'prefix_pages': 0,
-                  'prefix_tokens_reused': 0, 'prefix_entries': 0}
-            for p in self._predictors:
-                for key in kv:
-                    kv[key] += p.pool_stats()[key]
-            kv['page_tokens'] = p0.page_tokens
-            kv['num_pages'] = p0.num_pages
-            out['kv'] = kv
-            out['cache_capacity'] = (len(self._predictors)
-                                     * (p0.num_pages - 1) * p0.page_tokens)
-        else:
-            out['cache_capacity'] = (len(self._predictors)
-                                     * p0.slots * p0.max_len)
+        kv = {'pages_in_use': 0, 'pages_free': 0, 'prefix_hits': 0,
+              'prefix_misses': 0, 'prefix_pages': 0,
+              'prefix_tokens_reused': 0, 'prefix_entries': 0}
+        for p in self._predictors:
+            for key in kv:
+                kv[key] += p.pool_stats()[key]
+        kv['page_tokens'] = p0.page_tokens
+        kv['num_pages'] = p0.num_pages
+        out['kv'] = kv
+        out['cache_capacity'] = (len(self._predictors)
+                                 * (p0.num_pages - 1) * p0.page_tokens)
         if getattr(p0, 'speculative', False):
             sp = [p.spec_stats() for p in self._predictors]
             drafted = sum(s['draft_tokens'] for s in sp)
@@ -721,17 +704,15 @@ class ServingEngine(object):
         _preempt.preempted_streams.set(self._preempted)
         wstate['cache_wait'] = True
 
-    def _finish_lane(self, lanes, slot, state, error=None, pred=None,
-                     wstate=None):
+    def _finish_lane(self, lanes, slot, state, error=None, *, pred,
+                     wstate):
         lane = lanes.pop(slot)
         self._inflight.pop(lane.req.id, None)
         lane.req._finish(state, error)
         self._active_total -= 1
-        if pred is not None and getattr(pred, 'paged', False):
-            # freed pages un-stick any admission waiting on the pool
-            pred.release(slot)
-            if wstate is not None:
-                wstate['cache_wait'] = False
+        # freed pages un-stick any admission waiting on the pool
+        pred.release(slot)
+        wstate['cache_wait'] = False
         if state == DONE:
             _completed.inc()
         elif state == CANCELLED:
@@ -739,7 +720,7 @@ class ServingEngine(object):
         else:
             _failed.inc()
 
-    def _lane_accept(self, lanes, slot, tok, pred=None, wstate=None):
+    def _lane_accept(self, lanes, slot, tok, *, pred, wstate):
         """Record one generated token; returns False if the lane is
         done (eos / budget / cancelled) and was evicted."""
         lane = lanes[slot]
@@ -764,42 +745,8 @@ class ServingEngine(object):
         lane.last_active = now
         return True
 
-    def _admit(self, pred, lanes):
-        """Fill free slots from the queue; one prefill per admitted
-        request (prefill_batch > 1 batches them)."""
-        free = [s for s in range(pred.slots) if s not in lanes]
-        batch = []
-        while free:
-            req = self._pop_next()
-            if req is None:
-                break
-            req.state = RUNNING
-            req._admitted()
-            self._inflight[req.id] = req
-            slot = free.pop(0)
-            batch.append((req, slot))
-            self._active_total += 1
-            _admitted.inc()
-        for i in range(0, len(batch), pred.prefill_batch):
-            chunk = batch[i:i + pred.prefill_batch]
-            try:
-                ids = pred.prefill([r.prompt for r, _ in chunk],
-                                   [s for _, s in chunk])
-            except Exception as e:     # noqa: BLE001 — lane-fatal only
-                for req, _slot in chunk:
-                    self._inflight.pop(req.id, None)
-                    req._finish(FAILED, error=repr(e))
-                    self._active_total -= 1
-                    _failed.inc()
-                continue
-            _prefills.inc(len(chunk))
-            for (req, slot), tok in zip(chunk, ids):
-                lanes[slot] = _Lane(req, pos=len(req.prompt),
-                                    tok=int(tok))
-                self._lane_accept(lanes, slot, int(tok))
-
-    def _admit_paged(self, pred, lanes, prefilling, wstate):
-        """Paged admission: open a stream per free slot (a prefix-cache
+    def _admit(self, pred, lanes, prefilling, wstate):
+        """Admission: open a stream per free slot (a prefix-cache
         match + read-only page adoption — allocates nothing, so
         admission itself can never exhaust the pool) and queue it for
         chunked prefill. While cache_wait is set, a requeued
@@ -965,7 +912,7 @@ class ServingEngine(object):
 
     def _worker_loop(self, wid, pred):
         lanes = {}                       # slot -> _Lane
-        prefilling = collections.deque()  # paged: slots mid-prefill
+        prefilling = collections.deque()  # slots mid-prefill
         # flight: the (slot, lane) pairs fed to the decode step that is
         # dispatched and not yet accepted (the pipelined loop), or None
         wstate = {'cache_wait': False, 'flight': None, 'wid': wid}
@@ -1053,14 +1000,13 @@ class ServingEngine(object):
         `it`: admission, at most one prefill chunk, then one decode
         step over the ready lanes and the acceptance of its tokens.
 
-        With a predictor that defers (`deferred_decode`: paged, not
-        speculative) the pass is one stage of a one-deep pipeline: it
+        With a predictor that defers (`deferred_decode`: not the
+        speculative one) the pass is one stage of a one-deep pipeline: it
         packs step n from what the host knows without step n-1's
         tokens (positions, budgets; a lane that carries on takes its
         token on the device), dispatches it, and accepts the tokens of
         step n-1, which the same call hands back. The host's work
         between two programs then runs beside the one in flight."""
-        paged = getattr(pred, 'paged', False)
         # a speculative predictor's step is one draft->verify iteration
         # (serving/speculative.py): same feed ABI, but each live lane
         # gets 1..k+1 tokens back instead of exactly one — and where a
@@ -1068,14 +1014,10 @@ class ServingEngine(object):
         # packed ahead
         speculative = getattr(pred, 'speculative', False)
         deferred = getattr(pred, 'deferred_decode', False)
-        if paged:
-            with RecordEvent('serve.admit'):
-                self._admit_paged(pred, lanes, prefilling, wstate)
-            with RecordEvent('serve.prefill_tick'):
-                self._prefill_tick(pred, lanes, prefilling, wstate)
-        else:
-            with RecordEvent('serve.admit'):
-                self._admit(pred, lanes)
+        with RecordEvent('serve.admit'):
+            self._admit(pred, lanes, prefilling, wstate)
+        with RecordEvent('serve.prefill_tick'):
+            self._prefill_tick(pred, lanes, prefilling, wstate)
         self._report(wid, lanes)
         # deadline check at the step boundary: an expired ready
         # lane is evicted (pages freed) before it buys another

@@ -1008,7 +1008,7 @@ class FleetRouter(object):
                     meta['prefill_from'] = pf.endpoint
                 if rep.max_len is not None and len(prompt) > rep.max_len:
                     # a failover prefix past the context window cannot
-                    # be re-prefilled bit-exactly (ring slide)
+                    # be re-prefilled (the prompt bound of open_stream)
                     rep.active.pop(req.id, None)
                     self._finalize_locked(
                         req, FAILED,

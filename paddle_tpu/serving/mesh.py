@@ -59,7 +59,7 @@ class MeshDecodeExecutor(Executor):
     """Executor whose whole-block jits compile as SPMD programs over a
     serving mesh.
 
-    state_shardings maps the K/V cache/pool var names to their
+    state_shardings maps the K/V pool var names to their
     heads-sharded NamedSharding; those vars are pinned in BOTH
     in_shardings (they arrive donated from the Scope) and out_shardings
     (the donated update leaves with the identical layout — a host
@@ -79,7 +79,7 @@ class MeshDecodeExecutor(Executor):
         return int(self.mesh.devices.size)
 
     def state_sharding(self, name):
-        """The pinned NamedSharding for a cache/pool var (replicated
+        """The pinned NamedSharding for a pool var (replicated
         for anything unpinned) — paged.py re-places host-restored pools
         with this before writing them back into the Scope."""
         return self._state.get(name, self._replicated)
@@ -124,7 +124,7 @@ class MeshDecodeExecutor(Executor):
                 'out_shardings': out_shardings}
 
     def place_state(self, name, value):
-        """Place (or re-place) a cache/pool value under the var's
+        """Place (or re-place) a pool value under the var's
         pinned sharding. Host arrays upload sharded; device-resident
         jax arrays reshard without a host round-trip — the
         restore_pages `.at[].set` result re-pins in place."""

@@ -1,14 +1,24 @@
 """PagedDecodePredictor: page-table cached decoding over a shared pool.
 
-The DecodePredictor contract (prefill / decode_step / generate /
-clone, weights pinned once in the parent Scope) re-based onto the
-paged cache: per-layer [num_pages, page_tokens, H, dk] pools live in
-this predictor's child Scope, a host-side PagePool/PrefixCache
+Scope layout:
+
+    base Predictor Scope (weights, device-resident, shared)
+        └── this predictor's child Scope (page pools, recurrent state)
+
+Weights are pinned to device ONCE in the parent scope at construction;
+every clone() gets a fresh child scope (private cache state, zeroed)
+over the same parent, so N serving workers share one copy of the
+weights in HBM — the reference PaddlePredictor::Clone contract extended
+to runtime state. Per-layer [num_pages, page_tokens, H, dk] pools live
+in the child Scope, a host-side PagePool/PrefixCache
 (serving/paging.py) decides which physical page every logical position
 maps to, and both compiled programs take the page index as a FEED —
-admission, copy-on-write and prefix sharing never recompile anything.
+admission, copy-on-write and prefix sharing never recompile anything:
+each program is static-shape, compiles exactly once through the
+executor's whole-block jit cache, and the pools ride the executor's
+donation path (in-place update on device).
 
-Streams replace the dense path's whole-row prefill:
+A stream's life:
 
     open_stream(slot, prompt)   match the prefix cache, adopt shared
                                 pages read-only (zero recompute),
@@ -29,9 +39,9 @@ Streams replace the dense path's whole-row prefill:
                                 resume bit-exact
 
 Exhaustion is typed: when the pool runs dry (after prefix-cache LRU
-eviction) prefill_step/decode_step raise CacheExhaustedError — the
-dense ring's silent slide past max_len (COVERAGE divergence 8) cannot
-happen here. decode_step is transactional: on exhaustion every page
+eviction) prefill_step/decode_step raise CacheExhaustedError — a
+stream never slides silently past its window (COVERAGE divergence 8).
+decode_step is transactional: on exhaustion every page
 allocated for THAT call is rolled back, so retrying the same feed
 after a release is deterministic and bit-exact.
 
@@ -77,11 +87,10 @@ from __future__ import annotations
 
 import numpy as np
 
-from ..executor import Scope
+from ..executor import Executor, Scope
 from ..flags import get_flag
 from ..obs import telemetry
 from ..profiler import RecordEvent
-from .decode import DecodePredictor
 from .paging import (CacheExhaustedError, PagePool, PageTable, PrefixCache,
                      chain_keys)
 
@@ -116,12 +125,11 @@ class _PendingPrefill(object):
         self.chunks = 0
 
 
-class PagedDecodePredictor(DecodePredictor):
-    """Drop-in replacement for DecodePredictor with a paged cache.
-    prefer AnalysisPredictor.prepare_decoding(paged=True) over calling
-    this directly."""
+class PagedDecodePredictor(object):
+    """Cached prefill/decode execution over a slot pool and a page
+    pool; prefer AnalysisPredictor.prepare_decoding() over calling this
+    directly."""
 
-    paged = True
     # decode_step(defer=True) and collect(): a caller may keep one step
     # in flight (the serving engine's pipelined loop looks for this)
     deferred_decode = True
@@ -129,13 +137,17 @@ class PagedDecodePredictor(DecodePredictor):
     def __init__(self, predictor, slots=None, page_tokens=None,
                  kv_pages=None, prefill_chunk=None, _clone_of=None,
                  pair=None, mesh=None):
-        """With `pair` (an already-transpiled PagedDecodePair) the
+        """predictor: a (loaded) Predictor/AnalysisPredictor whose
+        program is a decoder-only LM. slots defaults to
+        FLAGS_serving_slots, the page geometry to FLAGS_serving_*.
+        With `pair` (an already-transpiled PagedDecodePair) the
         transpile is skipped — the speculative path builds its target
         and draft pairs in one transpile_spec and hands them here.
-        mesh follows the DecodePredictor contract (None = read
-        FLAGS_serve_mesh_shape; '' = single-chip): the page pool shards
-        its heads axis over tp and every program runs as ONE SPMD
-        program over the mesh (serving/mesh.py)."""
+        mesh (None = read FLAGS_serve_mesh_shape; '' = single-chip)
+        makes every program ONE GSPMD SPMD program over the mesh — the
+        page pool shards its heads axis over tp, weights per
+        DecodeSpec.serve_param_specs, greedy decode stays bit-exact vs
+        single-chip (serving/mesh.py)."""
         self._base = predictor
         if _clone_of is not None:
             self._pair = _clone_of._pair
@@ -150,7 +162,7 @@ class PagedDecodePredictor(DecodePredictor):
                 from ..transpiler.decode_transpiler import DecodeTranspiler
                 slots = int(slots or get_flag('serving_slots'))
                 self._pair = DecodeTranspiler().transpile(
-                    predictor._program, slots=slots, paged=True,
+                    predictor._program, slots=slots,
                     page_tokens=page_tokens, kv_pages=kv_pages,
                     prefill_chunk=prefill_chunk)
             self._weight_scope = predictor._scope
@@ -165,7 +177,67 @@ class PagedDecodePredictor(DecodePredictor):
         self._scope = Scope(parent=self._weight_scope)
         self.reset()
 
+    def _make_executor(self, place):
+        if self._mesh is None:
+            return Executor(place)
+        from .mesh import MeshDecodeExecutor
+        return MeshDecodeExecutor(place, self._mesh,
+                                  self._cache_shardings())
+
+    def _cache_shardings(self):
+        """{pool var name: NamedSharding} — heads axis over tp, adapted
+        by fit_spec (heads % tp != 0 falls back to replicated, never
+        errors)."""
+        from ..parallel.mesh import fit_spec, named_sharding
+        pair = self._pair
+        spec = fit_spec(pair.spec.pool_spec(), pair.pool_shape, self._mesh)
+        sh = named_sharding(self._mesh, spec)
+        return {n: sh for n in pair.cache_names}
+
+    def _param_shardings(self):
+        """{param name: NamedSharding} for the mesh: column-style specs
+        from serve_param_specs, replicated for everything else."""
+        from ..parallel.mesh import fit_spec, named_sharding
+        serve = self._pair.spec.serve_param_specs()
+        out = {}
+        for name in self._pair.spec.param_names():
+            spec = serve.get(name)
+            if spec is not None:
+                val = self._weight_scope.find_var(name)
+                shape = getattr(val, 'shape', None)
+                spec = fit_spec(spec, shape, self._mesh) \
+                    if shape is not None else None
+            out[name] = named_sharding(self._mesh, spec)
+        return out
+
+    # -- mesh introspection ------------------------------------------------
+    @property
+    def mesh_shape(self):
+        """'tp=2'-style axis spec ('' = single-chip) — surfaced through
+        ServingEngine.stats() and SRV_HEALTH."""
+        return self._mesh_shape
+
+    @property
+    def mesh_devices(self):
+        return int(self._mesh.devices.size) if self._mesh is not None \
+            else 1
+
     # -- introspection -----------------------------------------------------
+    @property
+    def slots(self):
+        return self._pair.slots
+
+    @property
+    def max_len(self):
+        return self._pair.spec.max_len
+
+    @property
+    def vocab(self):
+        return self._pair.spec.vocab
+
+    def jit_cache_stats(self):
+        return self._exe.jit_cache_stats()
+
     @property
     def page_tokens(self):
         return self._pair.page_tokens
@@ -221,6 +293,138 @@ class PagedDecodePredictor(DecodePredictor):
         _pages_free.set(self._pool.pages_free)
 
     # -- lifecycle ---------------------------------------------------------
+    def _pin_weights(self):
+        """Pin every referenced parameter to device in the PARENT scope
+        before any child scope exists — otherwise the executor's lazy
+        pin would write per-worker device copies into each child,
+        duplicating the model in HBM once per clone.
+
+        On a mesh this also covers already-device-resident arrays (a
+        predictor that ran before prepare_decoding leaves params
+        committed to one chip): device_put reshards them onto their
+        serve NamedSharding, so the executor's single-device lazy-pin
+        path never fires for a mesh weight."""
+        import jax
+        block = self._pair.decode_program.global_block()
+        shardings = self._param_shardings() if self._mesh is not None \
+            else None
+        for name in self._pair.spec.param_names():
+            val = self._weight_scope.find_var(name)
+            if val is None:
+                raise RuntimeError(
+                    'decode transpile references param %r that is not '
+                    'in the predictor scope — was the model loaded with '
+                    'load_params=True?' % name)
+            if isinstance(val, np.ndarray) and \
+                    val.dtype in (np.int64, np.uint64, np.float64):
+                continue
+            var = block.vars.get(name)
+            if var is None or not var.persistable:
+                continue
+            if shardings is not None:
+                self._weight_scope.set_var(
+                    name, jax.device_put(val, shardings[name]))
+            elif isinstance(val, np.ndarray):
+                self._weight_scope.set_var(
+                    name, jax.device_put(val, self._exe.device))
+
+    def load_sharded(self, ckpt_dir, mesh=None):
+        """Replace the weights from a sharded checkpoint root
+        (checkpoint/sharded.py two-generation layout): each referenced
+        param is assembled from the shard files of the last committed,
+        digest-verified generation and resharded onto `mesh` (default:
+        this predictor's serving mesh, else pinned whole to its
+        device) — serving can roll to a checkpoint saved on ANY
+        training topology; train-on-n/serve-on-m is a pure reshard. On
+        a mesh the params land under their SERVE specs (column-style
+        only; the checkpoint's recorded training spec is deliberately
+        overridden — a row-sharded restore would break the bit-exact
+        decode contract). The pools are runtime state, never
+        checkpointed, never touched here. Raises if no generation is
+        loadable or a referenced param is absent."""
+        import jax
+        from ..checkpoint import restore as restore_mod
+        ckpt = restore_mod.load_checkpoint(ckpt_dir)
+        if ckpt is None:
+            raise RuntimeError(
+                'no committed checkpoint generation under %r' % ckpt_dir)
+        if mesh is None:
+            mesh = self._mesh
+        serve = self._pair.spec.serve_param_specs()
+        for name in self._pair.spec.param_names():
+            if name not in ckpt:
+                raise RuntimeError(
+                    'sharded checkpoint %s (generation %d) is missing '
+                    'param %r' % (ckpt.dirname, ckpt.generation, name))
+            if mesh is not None:
+                # spec=() (not None): None would fall back to the spec
+                # RECORDED at save — the training layout, not the
+                # bit-exact serve layout
+                val = ckpt.as_jax(name, mesh,
+                                  spec=serve.get(name, ()))
+            else:
+                val = jax.device_put(ckpt.read(name), self._exe.device)
+            self._weight_scope.set_var(name, val)
+
+    def param_names(self):
+        """The refreshable weight names: every transpile-referenced
+        param (the pools and recurrent state are per-worker runtime
+        state, not params: never shipped by a parameter server)."""
+        return list(self._pair.spec.param_names())
+
+    def param_digests(self):
+        """{name: crc32 of the param's wire payload} over the served
+        weights — the same digest a pserver stamps into its manifest,
+        so a fleet deploy can prove a replica converged to a published
+        version without shipping the bytes again."""
+        from ..distributed import wire
+        from ..integrity import crc32
+        out = {}
+        for name in self.param_names():
+            val = np.asarray(self._weight_scope.find_var(name))
+            out[name] = crc32(wire._payload_of(val)[1])
+        return out
+
+    def stage_weights(self, params):
+        """Stage a {name: host array} weight update for install: names
+        are validated against the decode programs' param set, shapes
+        against the currently pinned values, and every array is
+        device_put OFF the decode path — the expensive half of a
+        refresh. Returns an opaque staged dict for install_weights.
+        Raises (installing nothing) on an unknown name or a shape
+        mismatch."""
+        import jax
+        known = set(self.param_names())
+        shardings = self._param_shardings() if self._mesh is not None \
+            else None
+        staged = {}
+        for name, val in params.items():
+            if name not in known:
+                raise KeyError(
+                    'refresh carries unknown param %r (this predictor '
+                    'serves %d params)' % (name, len(known)))
+            arr = np.ascontiguousarray(val)
+            cur = self._weight_scope.find_var(name)
+            cur_shape = getattr(cur, 'shape', None)
+            if cur_shape is not None and tuple(cur_shape) != arr.shape:
+                raise ValueError(
+                    'refresh shape mismatch for %r: got %r, serving %r'
+                    % (name, arr.shape, tuple(cur_shape)))
+            if shardings is not None:
+                staged[name] = jax.device_put(arr, shardings[name])
+            else:
+                staged[name] = jax.device_put(arr, self._exe.device)
+        return staged
+
+    def install_weights(self, staged):
+        """Swap staged device arrays into the PARENT weight scope — a
+        few dict-pointer writes, cheap enough to run under the serving
+        engine's step-boundary swap gate. Every clone sees the new
+        weights on its next step (shared parent scope); in-flight steps
+        already read the old arrays."""
+        for name, val in staged.items():
+            self._weight_scope.set_var(name, val)
+
     def reset(self):
         """Zero the page pools and forget every stream and cached
         prefix (fresh allocator state). On a mesh the zeroed pools land
@@ -248,7 +452,18 @@ class PagedDecodePredictor(DecodePredictor):
         _state_bytes.set(self._recurrent_state_bytes())
         self._update_gauges()
 
+    def _place_cache(self, name, value):
+        """Host K/V state -> the executor's pinned device layout (the
+        identity off-mesh: the executor lazy-pins on first run)."""
+        if self._mesh is None:
+            return value
+        return self._exe.place_state(name, value)
+
     def clone(self):
+        """A worker sharing this one's weights and compiled-program
+        identity (same Program objects -> same jit cache keys) with a
+        PRIVATE cache scope + executor — concurrent decode streams
+        can't cross-talk."""
         return PagedDecodePredictor(self._base, _clone_of=self)
 
     # -- streams -----------------------------------------------------------
@@ -546,14 +761,14 @@ class PagedDecodePredictor(DecodePredictor):
 
     def decode_step(self, tokens, positions, return_logits=False,
                     lanes=None, carry=(), defer=False):
-        """One step for the WHOLE pool — same ABI as the dense path:
+        """One step for the WHOLE pool:
         tokens [slots], positions [slots] (each stream's next append
         position, which must be its current length). Only open,
         fully-prefilled streams take part (`lanes`, an iterable of
         slots, names fewer: a stream left out keeps its pages and its
         recurrent state as they are); every other lane is fed the
-        null-page table row, so its mandatory write is dead weight
-        exactly like the dense ring's idle-slot append. New pages are
+        null-page table row, so its mandatory write is dead
+        weight. New pages are
         allocated on demand; if ANY stream cannot grow, the step runs
         nothing, this call's allocations are rolled back, and
         CacheExhaustedError(slots=[...]) names the victims — the
@@ -681,10 +896,9 @@ class PagedDecodePredictor(DecodePredictor):
             return np.asarray(self._last_ids)
 
     def prefill(self, prompts, slot_ids, return_logits=False):
-        """Dense-ABI prefill (the parity / generate() path): each
+        """Whole-prompt prefill (the parity / generate() path): each
         prompt is streamed chunk by chunk to completion; a slot that
-        already holds a stream is released first (the dense path's
-        overwrite-on-admission semantics). Returns first greedy ids
+        already holds a stream is released first. Returns first greedy ids
         [len(prompts)] (+ last-position logits with return_logits)."""
         if not prompts or len(prompts) != len(slot_ids):
             raise ValueError('%d prompts for %d slots'
@@ -708,3 +922,20 @@ class PagedDecodePredictor(DecodePredictor):
         if return_logits:
             return out_ids, np.stack(out_logits)
         return out_ids
+
+    def generate(self, prompt, max_new_tokens, eos_id=None, slot=0):
+        """Solo greedy generation on one slot (the benchmark / parity
+        path; real traffic goes through ServingEngine)."""
+        ids = self.prefill([prompt], [slot])
+        tok = int(ids[0])
+        out = [tok]
+        pos = len(np.asarray(prompt).reshape(-1))
+        toks = np.zeros((self.slots,), np.int64)
+        poss = np.zeros((self.slots,), np.int32)
+        while len(out) < max_new_tokens and tok != eos_id:
+            toks[slot] = tok
+            poss[slot] = pos
+            tok = int(self.decode_step(toks, poss)[slot])
+            out.append(tok)
+            pos += 1
+        return out

@@ -10,8 +10,8 @@ values; everything stateful lives HERE, on the host, in plain Python:
                allocated, the redirect target for dead writes). An
                empty free list first asks the eviction callback (the
                PrefixCache LRU) to give a page back, then raises the
-               typed, retryable CacheExhaustedError — the paged answer
-               to COVERAGE divergence 8's silent ring slide.
+               typed, retryable CacheExhaustedError (COVERAGE
+               divergence 8: never a silent slide).
                save_pages/restore_pages move page contents device<->
                host for the preempt-first capacity engine
                (serving/preempt.py): float32 copies onto freshly
@@ -38,8 +38,8 @@ values; everything stateful lives HERE, on the host, in plain Python:
 Sharing is capped at prompt[:-1]: the last prompt token is always
 recomputed, because its logits produce the stream's first output
 token. Everything here is deterministic — no clocks, no randomness —
-so greedy decode over shared pages stays bit-exact with the dense and
-full-recompute paths.
+so greedy decode over shared pages stays bit-exact with the same
+stream prefilled cold.
 """
 from __future__ import annotations
 

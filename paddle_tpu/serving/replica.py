@@ -234,7 +234,7 @@ class ReplicaServer(object):
         # old router's meta simply lacks the key and decodes to None
         ddl = meta.get('deadline_ms')
         peer = meta.get('prefill_from')
-        if peer and getattr(self._srv, 'paged', False):
+        if peer:
             # disaggregated dispatch: ack now, ship pages off-thread,
             # submit locally when they land (or when the ship fails —
             # local re-prefill, bit-exact by greedy determinism). The

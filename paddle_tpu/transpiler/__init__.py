@@ -13,12 +13,12 @@ from .distribute_transpiler import (DistributeTranspiler,
 from .ps_dispatcher import PSDispatcher, RoundRobin, HashName
 from .inference_transpiler import InferenceTranspiler
 from .decode_transpiler import (DecodeTranspiler, DecodeTranspileError,
-                                DecodePair, extract_decode_spec)
+                                PagedDecodePair, extract_decode_spec)
 from .memory_optimization_transpiler import (memory_optimize,
                                              release_memory)
 
 __all__ = ['DistributeTranspiler', 'DistributeTranspilerConfig',
            'PSDispatcher', 'RoundRobin', 'HashName',
            'InferenceTranspiler', 'DecodeTranspiler',
-           'DecodeTranspileError', 'DecodePair', 'extract_decode_spec',
+           'DecodeTranspileError', 'PagedDecodePair', 'extract_decode_spec',
            'memory_optimize', 'release_memory']

@@ -1,4 +1,4 @@
-"""DecodeTranspiler: loaded LM program -> prefill + decode pair.
+"""DecodeTranspiler: loaded LM program -> paged prefill + decode pair.
 
 The serving-side analog of the DistributeTranspiler: instead of
 rewriting ops in place, it READS the loaded language-model program —
@@ -30,9 +30,9 @@ MLP, every sublayer's output through an rms_norm —
                     matmul/causal_mask/softmax, proj mul]
 then [rms_norm, gate mul, up mul, down mul, rms_norm], a final
 rms_norm and the lm_head mul. Its spec (HybridDecodeSpec) carries the
-layer kinds and the delta rule's sizes; it serves through the paged
-pair only, whose programs keep K/V pools for the full-attention layers
-and per-slot recurrent state for the others. Speculative decoding,
+layer kinds and the delta rule's sizes; its paged pair's programs keep
+K/V pools for the full-attention layers and per-slot recurrent state
+for the others. Speculative decoding,
 page shipping and mesh serving refuse it by the layer kind's name.
 
 Genuinely
@@ -46,64 +46,39 @@ from __future__ import annotations
 
 from ..models import hybrid
 from ..models.transformer import (DecodeSpec, DecodeTranspileError,
-                                  refuse_recurrent, build_prefill_program,
-                                  build_decode_program,
-                                  build_verify_program)
+                                  refuse_recurrent, build_verify_program)
 
-__all__ = ['DecodeTranspileError', 'DecodePair', 'PagedDecodePair',
-           'SpecDecodePair', 'DecodeTranspiler', 'extract_decode_spec',
-           'refuse_recurrent']
+__all__ = ['DecodeTranspileError', 'PagedDecodePair', 'SpecDecodePair',
+           'DecodeTranspiler', 'extract_decode_spec', 'refuse_recurrent']
 
 
-class DecodePair(object):
+class PagedDecodePair(object):
     """The transpile result: spec + both programs and their ABIs.
 
-    fetch order for both programs is [logits, greedy_ids]; cache var
-    names (spec.cache_names()) are shared between the two programs, so
-    one Scope carries the ring state from prefill into decode.
-    """
+    The cache state is per-layer page POOLS ([num_pages, page_tokens,
+    H, dk]), the prefill program runs one `prefill_chunk`-token chunk
+    through one stream's page table, and both programs take the page
+    index as a feed (serving/paged.py computes it). Fetch order for
+    both programs is [logits, greedy_ids]; pool var names
+    (spec.pool_names()) are shared between the two programs, so one
+    Scope carries the K/V state from prefill into decode."""
 
-    def __init__(self, spec, slots, prefill_batch,
+    def __init__(self, spec, slots, page_tokens, pages_per_slot,
+                 num_pages, prefill_chunk,
                  prefill_program, prefill_feeds, prefill_fetches,
                  decode_program, decode_feeds, decode_fetches):
         self.spec = spec
         self.slots = slots
-        self.prefill_batch = prefill_batch
+        self.page_tokens = page_tokens
+        self.pages_per_slot = pages_per_slot
+        self.num_pages = num_pages
+        self.prefill_chunk = prefill_chunk
         self.prefill_program = prefill_program
         self.prefill_feeds = prefill_feeds
         self.prefill_fetches = prefill_fetches
         self.decode_program = decode_program
         self.decode_feeds = decode_feeds
         self.decode_fetches = decode_fetches
-
-    @property
-    def cache_names(self):
-        return self.spec.cache_names()
-
-    paged = False
-
-
-class PagedDecodePair(DecodePair):
-    """Paged transpile result: the cache state is per-layer page POOLS
-    ([num_pages, page_tokens, H, dk]) instead of per-slot rings, the
-    prefill program runs one `prefill_chunk`-token chunk through one
-    stream's page table, and both programs take the page index as a
-    feed (serving/paged.py computes it)."""
-
-    paged = True
-
-    def __init__(self, spec, slots, page_tokens, pages_per_slot,
-                 num_pages, prefill_chunk,
-                 prefill_program, prefill_feeds, prefill_fetches,
-                 decode_program, decode_feeds, decode_fetches):
-        DecodePair.__init__(self, spec, slots, 1,
-                            prefill_program, prefill_feeds,
-                            prefill_fetches, decode_program,
-                            decode_feeds, decode_fetches)
-        self.page_tokens = page_tokens
-        self.pages_per_slot = pages_per_slot
-        self.num_pages = num_pages
-        self.prefill_chunk = prefill_chunk
 
     @property
     def cache_names(self):
@@ -459,29 +434,19 @@ def extract_decode_spec(program):
 
 
 class DecodeTranspiler(object):
-    def transpile(self, program, slots=8, prefill_batch=1, paged=False,
-                  page_tokens=None, kv_pages=None, prefill_chunk=None):
+    def transpile(self, program, slots=8, page_tokens=None, kv_pages=None,
+                  prefill_chunk=None):
         """program: a loaded inference Program (AnalysisPredictor's).
-        Returns a DecodePair (or, with paged=True, a PagedDecodePair
-        whose cache is a page pool sized by page_tokens / kv_pages and
-        whose prefill runs prefill_chunk-token chunks; each None
-        defaults from FLAGS_serving_*, kv_pages 0 auto-sizes to
-        dense-equivalent capacity). Raises DecodeTranspileError if the
-        program is not a recognizable decoder-only LM."""
+        Returns a PagedDecodePair whose cache is a page pool sized by
+        page_tokens / kv_pages and whose prefill runs prefill_chunk-
+        token chunks (each None defaults from FLAGS_serving_*, kv_pages
+        0 auto-sizes to a full window for every slot). Raises
+        DecodeTranspileError if the program is not a recognizable
+        decoder-only LM."""
         if slots < 1:
             raise ValueError('slots must be >= 1, got %r' % (slots,))
-        if not 1 <= prefill_batch <= slots:
-            raise ValueError('prefill_batch must be in [1, slots]')
-        spec = extract_decode_spec(program)
-        if paged:
-            return self._transpile_paged(spec, slots, page_tokens,
-                                         kv_pages, prefill_chunk)
-        refuse_recurrent(spec, 'the dense ring cache (paged=False)')
-        pp, pf, pv = build_prefill_program(spec, slots,
-                                           batch=prefill_batch)
-        dp, df, dv = build_decode_program(spec, slots)
-        return DecodePair(spec, slots, prefill_batch,
-                          pp, pf, pv, dp, df, dv)
+        return self._transpile_paged(extract_decode_spec(program), slots,
+                                     page_tokens, kv_pages, prefill_chunk)
 
     def transpile_spec(self, program, draft_program=None, slots=8,
                        spec_k=None, draft_layers=None, page_tokens=None,
@@ -499,7 +464,7 @@ class DecodeTranspiler(object):
             raise ValueError('spec_k must be >= 1, got %r' % spec_k)
         refuse_recurrent(extract_decode_spec(program),
                          'speculative decoding')
-        target = self.transpile(program, slots=slots, paged=True,
+        target = self.transpile(program, slots=slots,
                                 page_tokens=page_tokens,
                                 kv_pages=kv_pages,
                                 prefill_chunk=prefill_chunk)
@@ -539,8 +504,8 @@ class DecodeTranspiler(object):
         num_pages = int(kv_pages if kv_pages is not None
                         else get_flag('serving_kv_pages'))
         if num_pages == 0:
-            # dense-equivalent HBM: every slot can hold a full window,
-            # plus the reserved null page
+            # every slot can hold a full window, plus the reserved
+            # null page
             num_pages = slots * pages_per_slot + 1
         if num_pages < 2:
             raise ValueError('kv_pages must be >= 2 (page 0 is the '

@@ -72,7 +72,7 @@ SEED = 11
 SESSIONS = 4
 
 
-def build_model(model_dir):
+def build_model(model_dir, cfg=CFG):
     import jax
     jax.config.update('jax_platforms', 'cpu')
     import paddle_tpu as fluid
@@ -80,16 +80,24 @@ def build_model(model_dir):
     prog.random_seed = startup.random_seed = SEED
     with fluid.program_guard(prog, startup):
         toks = fluid.layers.data(name='tokens',
-                                 shape=[1, CFG.max_len, 1],
+                                 shape=[1, cfg.max_len, 1],
                                  dtype='int64', append_batch_size=False)
         from paddle_tpu.models.transformer import language_model_logits
-        logits = language_model_logits(toks, CFG)
+        logits = language_model_logits(toks, cfg)
     exe = fluid.Executor(fluid.CPUPlace())
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         exe.run(startup)
         fluid.io.save_inference_model(model_dir, ['tokens'], [logits],
                                       exe, main_program=prog)
+
+
+def solo_reference(model_dir):
+    """The decoder a fleet's streams are compared with: each stream
+    alone, cold, on a pool of its own that fits it."""
+    from paddle_tpu.inference import AnalysisConfig, AnalysisPredictor
+    return AnalysisPredictor(AnalysisConfig(model_dir)).prepare_decoding(
+        slots=1, page_tokens=4, kv_pages=8)
 
 
 def make_prompts(seed, n, budget):
@@ -220,9 +228,7 @@ def run_overload_driver():
     # dense-decode reference over the same saved bytes — preemption,
     # swap/re-prefill resume and failover may reorder work, never
     # change tokens
-    from paddle_tpu.inference import AnalysisConfig, AnalysisPredictor
-    ref = AnalysisPredictor(AnalysisConfig(model_dir)).prepare_decoding(
-        slots=1, prefill_batch=1)
+    ref = solo_reference(model_dir)
     mismatches = 0
     for (p, _), st, toks in zip(prompts, states, streams):
         if st == 'DONE' and toks != [int(t) for t in
@@ -327,11 +333,9 @@ def run_grayfail_driver():
         router.stop()
     # the in-harness bit-exactness gate: a stream that survived a
     # gray-mark failover (or a deadline near-miss) must be
-    # np.array_equal to the solo dense-decode reference — gray
+    # np.array_equal to the solo decode reference — gray
     # tolerance may move work, never change tokens
-    from paddle_tpu.inference import AnalysisConfig, AnalysisPredictor
-    ref = AnalysisPredictor(AnalysisConfig(model_dir)).prepare_decoding(
-        slots=1, prefill_batch=1)
+    ref = solo_reference(model_dir)
     mismatches = 0
     for (p, _), st, toks in zip(prompts, states, streams):
         want = np.asarray([int(t) for t in ref.generate(p, budget)],
@@ -394,9 +398,7 @@ def run_disagg_driver():
         stats = router.stats()
     finally:
         router.stop()
-    from paddle_tpu.inference import AnalysisConfig, AnalysisPredictor
-    ref = AnalysisPredictor(AnalysisConfig(model_dir)).prepare_decoding(
-        slots=1, prefill_batch=1)
+    ref = solo_reference(model_dir)
     mismatches = 0
     for (p, b), st, toks in zip(work, states, streams):
         want = np.asarray([int(t) for t in ref.generate(p, b)],

@@ -144,7 +144,7 @@ def run_serving(eps, steps, workdir):
     from paddle_tpu.inference import AnalysisConfig, AnalysisPredictor
     pred = AnalysisPredictor(AnalysisConfig(model_dir,
                                             place=fluid.CPUPlace()))
-    dec = pred.prepare_decoding(slots=2, prefill_batch=1)
+    dec = pred.prepare_decoding(slots=2)
     srv = LMServer(dec)
     try:
         before = srv.generate(PROMPT, max_new_tokens=GEN)

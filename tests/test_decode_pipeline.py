@@ -62,7 +62,7 @@ def served(request):
 
 
 def _decoder(pred, **kw):
-    kw = dict(dict(slots=3, paged=True, page_tokens=4, kv_pages=40,
+    kw = dict(dict(slots=3, page_tokens=4, kv_pages=40,
                    prefill_chunk=8), **kw)
     return pred.prepare_decoding(**kw)
 

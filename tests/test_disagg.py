@@ -56,7 +56,7 @@ def model_dir(tmp_path_factory):
 
 
 def _paged_server(model_dir):
-    return LMServer(model_dir, slots=2, paged=True, page_tokens=PT,
+    return LMServer(model_dir, slots=2, page_tokens=PT,
                     kv_pages=33)
 
 

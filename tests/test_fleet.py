@@ -65,7 +65,7 @@ def ref_dec(model_dir):
     """In-process solo-decode reference over the same saved bytes."""
     from paddle_tpu.inference import AnalysisConfig, AnalysisPredictor
     pred = AnalysisPredictor(AnalysisConfig(model_dir))
-    return pred.prepare_decoding(slots=4, prefill_batch=1)
+    return pred.prepare_decoding(slots=4)
 
 
 def _launch_replicas(model_dir, n, slots=4, extra_env=None):
@@ -540,11 +540,24 @@ def test_supervisor_budget_still_bounds_crash_loops(tmp_path):
 
 # -- satellite: engine drain timeout + drain races -------------------------
 
+@pytest.fixture(scope='module')
+def long_model_dir(tmp_path_factory):
+    """A window that a 10**9-token request cannot outgrow before the
+    drains below time out (about 10 s of decode steps on this CPU): a
+    stream past its window ends FAILED, not CANCELLED."""
+    from paddle_tpu.models.transformer import TransformerConfig
+    d = str(tmp_path_factory.mktemp('fleet_long_model'))
+    fw.build_model(d, TransformerConfig(
+        vocab=64, dim=32, heads=2, layers=1, ffn=64, max_len=8192,
+        use_tp=False, use_sp=False))
+    return d
+
+
 @pytest.fixture()
-def engine_dec(model_dir):
+def engine_dec(long_model_dir):
     from paddle_tpu.inference import AnalysisConfig, AnalysisPredictor
-    pred = AnalysisPredictor(AnalysisConfig(model_dir))
-    return pred.prepare_decoding(slots=2, prefill_batch=1)
+    pred = AnalysisPredictor(AnalysisConfig(long_model_dir))
+    return pred.prepare_decoding(slots=2)
 
 
 def _wait_tokens(req, timeout=120.0):
@@ -574,7 +587,7 @@ def test_engine_submit_during_drain_rejected(engine_dec):
     req = eng.submit([1, 2], max_new_tokens=10 ** 9)
     _wait_tokens(req)
     stopper = threading.Thread(
-        target=lambda: eng.stop(drain=True, timeout=5.0), daemon=True)
+        target=lambda: eng.stop(drain=True, timeout=2.0), daemon=True)
     stopper.start()
     time.sleep(0.2)                # stop() flipped _accepting first
     with pytest.raises(RuntimeError, match='draining'):

@@ -14,7 +14,7 @@ from paddle_tpu.inference import AnalysisConfig, AnalysisPredictor
 from paddle_tpu.models import hybrid
 from paddle_tpu.models.transformer import build_verify_program
 from paddle_tpu.transpiler.decode_transpiler import (
-    DecodeTranspileError, DecodeTranspiler, extract_decode_spec)
+    DecodeTranspileError, extract_decode_spec)
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), 'benchmarks'))
@@ -71,7 +71,7 @@ def reference_logits(model):
 
 
 def _decoder(pred, **kw):
-    kw = dict(dict(slots=3, paged=True, page_tokens=4, kv_pages=40,
+    kw = dict(dict(slots=3, page_tokens=4, kv_pages=40,
                    prefill_chunk=16), **kw)
     return pred.prepare_decoding(**kw)
 
@@ -252,9 +252,6 @@ def _refusals():
         build_verify_program(extract_decode_spec(pred._program), 2, 3, 20,
                              4, 12)
 
-    def dense(pred):
-        DecodeTranspiler().transpile(pred._program, slots=2, paged=False)
-
     def mesh(pred):
         _decoder(pred, mesh='tp=2')
 
@@ -264,7 +261,7 @@ def _refusals():
     def install(pred):
         _decoder(pred).install_prefix(list(range(1, 20)), ['00'], [])
 
-    return [speculative, verify, dense, mesh, export, install]
+    return [speculative, verify, mesh, export, install]
 
 
 @pytest.mark.parametrize('ask', _refusals(), ids=lambda f: f.__name__)

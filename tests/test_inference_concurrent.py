@@ -74,7 +74,7 @@ def test_concurrent_cloned_predictors_agree_with_serial(tmp_path):
 
 
 def test_concurrent_cloned_decode_predictors_agree_with_serial(tmp_path):
-    """The serving extension of the clone contract: DecodePredictor
+    """The serving extension of the clone contract: PagedDecodePredictor
     clones share the weight scope but carry PRIVATE K/V cache scopes,
     so concurrent generation streams must equal their serial runs
     (deeper checks live in tests/test_serving.py)."""
@@ -99,7 +99,7 @@ def test_concurrent_cloned_decode_predictors_agree_with_serial(tmp_path):
     from paddle_tpu.inference import AnalysisConfig, AnalysisPredictor
     pred = AnalysisPredictor(AnalysisConfig(str(tmp_path),
                                             place=fluid.CPUPlace()))
-    base = pred.prepare_decoding(slots=1, prefill_batch=1)
+    base = pred.prepare_decoding(slots=1, page_tokens=4, kv_pages=8)
     workers = [base] + [base.clone() for _ in range(2)]
     prompts = [[3, 1, 4], [7, 7], [2, 9, 6, 1]]
     serial = [w.generate(p, 5) for w, p in zip(workers, prompts)]

@@ -119,7 +119,7 @@ def test_mesh_tp2_dense_bit_exact_and_compile_once(lm_dir, ref_stream):
 
 def test_mesh_tp2_paged_bit_exact_pool_sharded(lm_dir, ref_stream):
     dec = _predictor(lm_dir).prepare_decoding(
-        slots=2, paged=True, page_tokens=4, prefill_chunk=8,
+        slots=2, page_tokens=4, prefill_chunk=8,
         mesh='tp=2')
     assert dec.generate(PROMPT, GEN) == ref_stream
 
@@ -207,7 +207,7 @@ def test_cross_topology_resharded_decode_bit_exact(tp_lm_dir,
     # full 2x2 mesh, dense on tp=2 — weights scrambled first so the
     # stream can only come from the resharded checkpoint bytes
     for mesh_spec, kwargs in [
-            ('dp=2,tp=2', dict(paged=True, page_tokens=4,
+            ('dp=2,tp=2', dict(page_tokens=4,
                                prefill_chunk=8)),
             ('tp=2', {})]:
         dec = _predictor(tp_lm_dir).prepare_decoding(

@@ -537,7 +537,7 @@ def _current_weights(dec):
 
 
 def test_stage_install_weights_roundtrip(lm_predictor):
-    dec = lm_predictor.prepare_decoding(slots=2, prefill_batch=1)
+    dec = lm_predictor.prepare_decoding(slots=2)
     cur = _current_weights(dec)
     assert cur, 'decode predictor serves no params?'
     staged = dec.stage_weights(cur)
@@ -573,7 +573,7 @@ def test_identity_swap_midstream_is_bit_exact(lm_predictor):
     no matter which boundary the swap lands on."""
     from paddle_tpu.serving import ServingEngine
     solo = _solo(lm_predictor, [3, 1, 4], 10)
-    dec = lm_predictor.prepare_decoding(slots=2, prefill_batch=1)
+    dec = lm_predictor.prepare_decoding(slots=2)
     staged = dec.stage_weights(_current_weights(dec))
     with ServingEngine(dec) as eng:
         req = eng.submit([3, 1, 4], max_new_tokens=10)
@@ -595,7 +595,7 @@ def test_swap_switches_stream_at_one_boundary(lm_predictor):
     prompt, budget = [9, 9, 1, 5], 12
     solo = _solo(lm_predictor, prompt, budget)
     assert 0 not in solo, 'pick a prompt whose solo stream avoids 0'
-    dec = lm_predictor.prepare_decoding(slots=2, prefill_batch=1)
+    dec = lm_predictor.prepare_decoding(slots=2)
     cur = _current_weights(dec)
     head = [n for n in cur if 'lm_head' in n]
     assert head, sorted(cur)
@@ -624,7 +624,7 @@ def test_swap_switches_stream_at_one_boundary(lm_predictor):
 
 def test_request_swap_runs_inline_when_engine_stopped(lm_predictor):
     from paddle_tpu.serving import ServingEngine
-    dec = lm_predictor.prepare_decoding(slots=1, prefill_batch=1)
+    dec = lm_predictor.prepare_decoding(slots=1)
     eng = ServingEngine(dec)                # never started
     ran = []
     assert eng.request_swap(lambda: ran.append(1) or 'ok') == 'ok'
@@ -634,7 +634,7 @@ def test_request_swap_runs_inline_when_engine_stopped(lm_predictor):
 
 def test_lmserver_stats_report_version_and_staleness(lm_predictor):
     from paddle_tpu.serving import LMServer
-    dec = lm_predictor.prepare_decoding(slots=2, prefill_batch=1)
+    dec = lm_predictor.prepare_decoding(slots=2)
     with LMServer(dec) as srv:
         stats = srv.stats()
         assert stats['param_version'] is None

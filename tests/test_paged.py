@@ -6,9 +6,9 @@ The contract under test (ISSUE 13 acceptance):
 - PagePool refcounting survives randomized alloc/free/share churn with
   the free list and the ref>0 set always partitioning the pool, no
   leak, no double free (property-style, pool.check() as the oracle)
-- greedy decode over the page pool is BIT-EXACT against both the dense
-  ring path and full recompute (np.array_equal, not allclose), with
-  each paged program compiling exactly once (jit_cache_stats)
+- greedy decode over the page pool is BIT-EXACT against full recompute
+  (np.array_equal, not allclose), with each paged program compiling
+  exactly once (jit_cache_stats)
 - chunked prefill produces the same first token + logits as a
   whole-prompt prefill
 - two streams sharing a prefix never cross-talk: the first divergent
@@ -18,8 +18,8 @@ The contract under test (ISSUE 13 acceptance):
   ONE suffix chunk instead of five (zero recompute over the shared
   pages), bit-exact against its own cold prefill
 - pool exhaustion is a typed, retryable CacheExhaustedError naming the
-  victim slots with that step's allocations rolled back — the paged
-  answer to COVERAGE divergence 8's silent ring slide — and the fleet
+  victim slots with that step's allocations rolled back (COVERAGE
+  divergence 8: never a silent slide) — and the fleet
   router requeues such a failure as a shed instead of failing the
   stream
 """
@@ -271,18 +271,15 @@ def _ref_step(pred, cfg, toks):
 
 
 # --------------------------------------------------------------------------
-# bit-exact parity: paged vs dense vs full recompute, compile-once
+# bit-exact parity: paged vs full recompute, compile-once
 # --------------------------------------------------------------------------
 
 def test_paged_parity_bit_exact_and_compiles_once(lm_predictor):
-    dense = lm_predictor.prepare_decoding(slots=3, prefill_batch=1)
-    paged = lm_predictor.prepare_decoding(slots=3, paged=True,
+    paged = lm_predictor.prepare_decoding(slots=3,
                                           page_tokens=4,
                                           prefill_chunk=CFG.max_len)
     prompt = [3, 1, 4, 1, 5]
-    dids, dlg = dense.prefill([prompt], [1], return_logits=True)
     pids, plg = paged.prefill([prompt], [1], return_logits=True)
-    assert np.array_equal(plg, dlg) and int(pids[0]) == int(dids[0])
     assert np.array_equal(plg[0], _ref_step(lm_predictor, CFG, prompt))
     tok, pos = int(pids[0]), len(prompt)
     toks = np.zeros((3,), np.int64)
@@ -290,15 +287,11 @@ def test_paged_parity_bit_exact_and_compiles_once(lm_predictor):
     stream = [tok]
     for _ in range(CFG.max_len - len(prompt)):
         toks[1], poss[1] = tok, pos
-        dn, dl = dense.decode_step(toks, poss, return_logits=True)
         pn, pl = paged.decode_step(toks, poss, return_logits=True)
-        assert np.array_equal(pl[1], dl[1]), \
-            'paged decode step %d diverges from dense' % len(stream)
         assert np.array_equal(
             pl[1], _ref_step(lm_predictor, CFG, prompt + stream)), \
             'paged decode step %d diverges from recompute' % len(stream)
         tok = int(pn[1])
-        assert tok == int(dn[1])
         stream.append(tok)
         pos += 1
     # ONE compiled program per phase across the whole loop — page
@@ -309,10 +302,10 @@ def test_paged_parity_bit_exact_and_compiles_once(lm_predictor):
 
 
 def test_chunked_prefill_matches_whole_prompt(lm_predictor):
-    whole = lm_predictor.prepare_decoding(slots=2, paged=True,
+    whole = lm_predictor.prepare_decoding(slots=2,
                                           page_tokens=4,
                                           prefill_chunk=CFG.max_len)
-    chunked = lm_predictor.prepare_decoding(slots=2, paged=True,
+    chunked = lm_predictor.prepare_decoding(slots=2,
                                             page_tokens=4,
                                             prefill_chunk=4)
     prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9]      # 13 tokens
@@ -329,14 +322,16 @@ def test_chunked_prefill_matches_whole_prompt(lm_predictor):
 
 
 def test_cow_streams_never_cross_talk(lm_predictor):
-    paged = lm_predictor.prepare_decoding(slots=2, paged=True,
+    paged = lm_predictor.prepare_decoding(slots=2,
                                           page_tokens=4,
                                           prefill_chunk=CFG.max_len)
     prompt = [7, 3, 7, 4, 2, 9]
     n = 6
-    # isolated references from the dense path, one stream at a time
-    dense = lm_predictor.prepare_decoding(slots=1, prefill_batch=1)
-    ref_a = dense.generate(prompt, n)
+    # the isolated reference: the same stream alone, cold, on a pool of
+    # its own that fits it
+    ref_a = lm_predictor.prepare_decoding(
+        slots=1, page_tokens=4, kv_pages=8,
+        prefill_chunk=CFG.max_len).generate(prompt, n)
     # stream A prefills cold (registers the prefix), stream B adopts
     # the shared page and both decode interleaved — divergent appends
     # COW-fork, so A's tokens must stay exactly its isolated stream
@@ -362,10 +357,9 @@ def test_cow_streams_never_cross_talk(lm_predictor):
 # --------------------------------------------------------------------------
 
 def test_generate_past_window_raises_typed_not_slides(lm_predictor):
-    # the dense ring slides silently past max_len
-    # (test_serving.test_generate_past_max_len_slides_window); the
-    # paged path instead raises the typed, retryable error
-    paged = lm_predictor.prepare_decoding(slots=1, paged=True,
+    # a stream that outgrows its window never slides silently: the
+    # typed, retryable error
+    paged = lm_predictor.prepare_decoding(slots=1,
                                           page_tokens=4,
                                           prefill_chunk=CFG.max_len)
     with pytest.raises(CacheExhaustedError) as ei:
@@ -380,7 +374,7 @@ def test_decode_exhaustion_rolls_back_and_retries(lm_predictor):
     # 2 streams compete for a pool that can only grow one of them:
     # the step must run NOTHING, name the victim, leave the survivor's
     # state untouched, and succeed bit-exact after a release
-    paged = lm_predictor.prepare_decoding(slots=2, paged=True,
+    paged = lm_predictor.prepare_decoding(slots=2,
                                           page_tokens=4, kv_pages=6,
                                           prefill_chunk=CFG.max_len)
     pa = [1, 2, 3, 4, 5, 6, 7, 8]         # 2 full pages each
@@ -409,7 +403,7 @@ def test_decode_exhaustion_rolls_back_and_retries(lm_predictor):
 
 def test_shared_system_prompt_prefills_suffix_only(big_predictor):
     from paddle_tpu.serving import ServingEngine
-    dec = big_predictor.prepare_decoding(slots=2, paged=True,
+    dec = big_predictor.prepare_decoding(slots=2,
                                          page_tokens=32,
                                          prefill_chunk=128)
     rng = np.random.RandomState(13)
@@ -457,7 +451,7 @@ def test_paged_telemetry_counters_and_gauges(lm_predictor):
     telemetry.enable()
     telemetry.reset()
     try:
-        dec = lm_predictor.prepare_decoding(slots=2, paged=True,
+        dec = lm_predictor.prepare_decoding(slots=2,
                                             page_tokens=4,
                                             prefill_chunk=4)
         dec.prefill([[1, 2, 3, 4, 5, 6]], [0])        # 2 chunks
@@ -478,7 +472,7 @@ def test_paged_telemetry_counters_and_gauges(lm_predictor):
 
 def test_lmserver_stats_expose_cache_pressure(lm_predictor):
     from paddle_tpu.serving import LMServer
-    dec = lm_predictor.prepare_decoding(slots=2, paged=True,
+    dec = lm_predictor.prepare_decoding(slots=2,
                                         page_tokens=4)
     srv = LMServer(dec)
     try:
@@ -493,7 +487,6 @@ def test_lmserver_stats_expose_cache_pressure(lm_predictor):
             time.sleep(0.001)
         srv.result(h, timeout=60)
         st = srv.stats()
-        assert st['paged'] is True
         assert saw_tokens >= 3            # the live stream was visible
         assert st['cache_tokens'] == 0    # and released on completion
         assert st['cache_capacity'] == (dec.num_pages - 1) * 4

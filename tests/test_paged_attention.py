@@ -200,7 +200,7 @@ def _op_counts(program):
 
 def test_decode_program_reads_pages_and_the_others_still_gather(
         lm_predictor):
-    dec = lm_predictor.prepare_decoding(slots=2, paged=True, page_tokens=4,
+    dec = lm_predictor.prepare_decoding(slots=2, page_tokens=4,
                                         prefill_chunk=8, speculative=True,
                                         spec_k=2, draft_layers=1)
     pair = dec._spair
@@ -231,7 +231,7 @@ def test_decode_tables_span_counts_pages_read_of_the_window(lm_predictor):
     trace.clear()
     telemetry.enable()
     try:
-        dec = lm_predictor.prepare_decoding(slots=4, paged=True,
+        dec = lm_predictor.prepare_decoding(slots=4,
                                             page_tokens=4, prefill_chunk=8)
         prompts = {0: list(range(1, 4)), 2: list(range(1, 10)),
                    3: list(range(1, 18))}
@@ -341,7 +341,7 @@ def test_kernel_runs_per_shard_under_a_serving_mesh(tmp_path, monkeypatch,
     def decoder(**kw):
         pred = AnalysisPredictor(AnalysisConfig(str(tmp_path),
                                                 place=fluid.CPUPlace()))
-        return pred.prepare_decoding(slots=2, paged=True, page_tokens=8,
+        return pred.prepare_decoding(slots=2, page_tokens=8,
                                      prefill_chunk=8, **kw)
     seen = []
     kernel = pa.paged_attention

@@ -52,8 +52,10 @@ def predictor(model_dir):
 
 @pytest.fixture(scope='module')
 def ref_dec(predictor):
-    """Solo dense-decode reference over the same saved bytes."""
-    return predictor.prepare_decoding(slots=1, prefill_batch=1)
+    """Solo reference over the same saved bytes: the same stream alone
+    on a pool of its own that fits it (whatever FLAGS_serving_kv_pages
+    a test sets for the server under test)."""
+    return predictor.prepare_decoding(slots=1, page_tokens=4, kv_pages=8)
 
 
 @pytest.fixture()
@@ -67,7 +69,7 @@ def policy_flags():
 def _tight_engine(predictor):
     """2 slots over a pool too small for two full streams: decoding
     both PA and PB to GEN tokens is guaranteed to exhaust it."""
-    dec = predictor.prepare_decoding(slots=2, paged=True, page_tokens=4,
+    dec = predictor.prepare_decoding(slots=2, page_tokens=4,
                                      kv_pages=6,
                                      prefill_chunk=fw.CFG.max_len)
     return dec, ServingEngine(dec)
@@ -125,7 +127,7 @@ def test_preempt_policy_flag_validated(policy_flags):
 # --------------------------------------------------------------------------
 
 def test_tier_queues_order_and_low_tier_only_rejection(predictor):
-    dec = predictor.prepare_decoding(slots=2, prefill_batch=1)
+    dec = predictor.prepare_decoding(slots=2)
     eng = ServingEngine(dec, max_queue=2)     # never started: pure queue
     low_a = eng.submit([1], 2)
     high = eng.submit([2], 2, priority=5)
@@ -422,7 +424,7 @@ def _launch_paged_replicas(model_dir, n):
         s.close()
         env = dict(os.environ, SERVE_MODEL_DIR=model_dir,
                    SERVE_ENDPOINT=ep, SERVE_SLOTS='2',
-                   SERVE_WORKERS='1', SERVE_PAGED='1',
+                   SERVE_WORKERS='1',
                    SERVE_PAGE_TOKENS='4', SERVE_KV_PAGES='6',
                    SERVE_PREFILL_CHUNK=str(fw.CFG.max_len))
         env.pop('XLA_FLAGS', None)

@@ -1,7 +1,7 @@
 """Serving subsystem: KV-cached decode parity + continuous batching.
 
 The contract under test (ISSUE 6 acceptance):
-- greedy decode over the ring caches is BIT-EXACT against the
+- greedy decode over the page pool is BIT-EXACT against the
   full-recompute predictor (same weights, same ops, same reduction
   lengths — np.array_equal, not allclose)
 - each of the two serving programs compiles exactly once across a
@@ -9,7 +9,7 @@ The contract under test (ISSUE 6 acceptance):
 - a request admitted mid-stream into a running pool produces exactly
   the tokens it would have produced alone (lane isolation)
 - clone()d workers share weights but never cross-talk
-plus unit tests for the ring/mask ops and the Predictor dict-input
+plus unit tests for the gather ops and the Predictor dict-input
 validation satellite.
 """
 import threading
@@ -29,53 +29,8 @@ CFG = TransformerConfig(vocab=64, dim=32, heads=2, layers=2, ffn=64,
 
 
 # --------------------------------------------------------------------------
-# ring / mask / gather op units (ops/attention_ops.py)
+# gather op units (ops/attention_ops.py)
 # --------------------------------------------------------------------------
-
-class TestKVCacheWrite(OpTest):
-    def test_whole_row_overwrite(self):
-        rng = np.random.RandomState(0)
-        cache = rng.rand(4, 6, 2, 3).astype('f4')     # stale contents
-        x = rng.rand(2, 6, 2, 3).astype('f4')
-        slots = np.array([3, 1], 'int32')
-        expect = cache.copy()
-        expect[3], expect[1] = x[0], x[1]
-        self.op_type = 'kv_cache_write'
-        self.inputs = {'Cache': cache, 'X': x, 'Slots': slots}
-        self.outputs = {'Out': expect}
-        self.check_output()
-
-
-class TestKVCacheAppend(OpTest):
-    def test_ring_wrap(self):
-        rng = np.random.RandomState(1)
-        cache = rng.rand(3, 4, 2, 2).astype('f4')
-        x = rng.rand(3, 1, 2, 2).astype('f4')
-        step = np.array([0, 5, 3], 'int32')           # 5 % 4 wraps to 1
-        expect = cache.copy()
-        expect[0, 0], expect[1, 1], expect[2, 3] = x[0, 0], x[1, 0], x[2, 0]
-        self.op_type = 'kv_cache_append'
-        self.inputs = {'Cache': cache, 'X': x, 'StepIdx': step}
-        self.outputs = {'Out': expect}
-        self.check_output()
-
-
-class TestDecodeMask(OpTest):
-    def test_pre_and_post_wrap_validity(self):
-        T = 4
-        x = np.zeros((2, 2, 1, T), 'f4')
-        step = np.array([2, 5], 'int32')
-        expect = np.full_like(x, -1e9)
-        # s=2 (< T): ring positions 0..2 hold real history
-        expect[0, :, :, :3] = 0.0
-        # s=5 (wrapped): every ring position holds one of the last T
-        # tokens — all valid
-        expect[1] = 0.0
-        self.op_type = 'decode_mask'
-        self.inputs = {'X': x, 'StepIdx': step}
-        self.outputs = {'Out': expect}
-        self.check_output()
-
 
 class TestPositionEmbeddingAt(OpTest):
     def test_gather_and_wrap(self):
@@ -123,6 +78,12 @@ def lm_predictor(tmp_path_factory):
                                             place=fluid.CPUPlace()))
 
 
+def _decoder(pred, slots):
+    # four pages a window: the default 16-token page would be the whole
+    # window of this model
+    return pred.prepare_decoding(slots=slots, page_tokens=4)
+
+
 def _ref_step(pred, toks):
     """Full-recompute next-token logits for a token list (len <= T)."""
     feed = np.zeros((1, CFG.max_len, 1), np.int64)
@@ -151,8 +112,8 @@ def test_extract_decode_spec(lm_predictor):
             spec.max_len) == (CFG.vocab, CFG.dim, CFG.heads, CFG.layers,
                               CFG.ffn, CFG.max_len)
     assert len(spec.blocks) == CFG.layers
-    assert spec.cache_shape(4) == (4, CFG.max_len, CFG.heads,
-                                   CFG.dim // CFG.heads)
+    assert spec.pool_shape(9, 4) == (9, 4, CFG.heads,
+                                     CFG.dim // CFG.heads)
 
 
 def test_transpile_rejects_non_lm():
@@ -171,7 +132,7 @@ def test_transpile_rejects_non_lm():
 # --------------------------------------------------------------------------
 
 def test_greedy_parity_bit_exact_and_compiles_once(lm_predictor):
-    dec = lm_predictor.prepare_decoding(slots=3, prefill_batch=1)
+    dec = _decoder(lm_predictor, 3)
     prompt = [3, 1, 4, 1, 5]
     ids, logits = dec.prefill([prompt], [1], return_logits=True)
     ref = _ref_step(lm_predictor, prompt)
@@ -201,24 +162,34 @@ def test_greedy_parity_bit_exact_and_compiles_once(lm_predictor):
     assert stats['segment_hits'] >= 1
 
 
-def test_generate_past_max_len_slides_window(lm_predictor):
-    # beyond T the ring overwrites the oldest row — a sliding-window
-    # divergence from full recompute (documented in README); it must
-    # keep producing in-vocab tokens without error
-    dec = lm_predictor.prepare_decoding(slots=1, prefill_batch=1)
-    out = dec.generate([5, 9, 2], CFG.max_len + 6)
-    assert len(out) == CFG.max_len + 6
-    assert all(0 <= t < CFG.vocab for t in out)
+def test_generate_fills_the_window_stops_at_eos_and_reuses_its_slot(
+        lm_predictor):
+    # (past the window: test_paged.test_generate_past_window_raises_typed_
+    # not_slides)
+    dec = _decoder(lm_predictor, 1)
+    prompt = [5, 9, 2]
+    n = CFG.max_len - len(prompt) + 1     # up to the window's last row
+    want = _ref_generate(lm_predictor, prompt, n)
+    assert dec.generate(prompt, n) == want
+    # the next prefill on the slot releases the stream it held
+    eos = want[2]
+    assert dec.generate(prompt, n, eos_id=eos) == \
+        want[:want.index(eos) + 1]
+    assert list(dec.slot_tokens()) == [0]
 
 
 def test_prefill_validation(lm_predictor):
-    dec = lm_predictor.prepare_decoding(slots=2, prefill_batch=1)
+    dec = _decoder(lm_predictor, 2)
     with pytest.raises(ValueError, match='max_len'):
         dec.prefill([list(range(CFG.max_len + 1))], [0])
     with pytest.raises(ValueError, match='slot'):
         dec.prefill([[1, 2]], [2])
+    with pytest.raises(ValueError, match='max_len'):
+        dec.open_stream(0, list(range(CFG.max_len + 1)))
+    with pytest.raises(ValueError, match='slot'):
+        dec.open_stream(2, [1, 2])
     with pytest.raises(ValueError, match='prompts'):
-        dec.prefill([[1], [2]], [0, 1])   # prefill_batch is 1
+        dec.prefill([[1], [2]], [0])
 
 
 # --------------------------------------------------------------------------
@@ -232,7 +203,7 @@ def test_midstream_admission_matches_solo(lm_predictor):
     solo_a = _ref_generate(lm_predictor, [3, 1, 4], 8)
     solo_b = _ref_generate(lm_predictor, [2, 7], 6)
 
-    dec = lm_predictor.prepare_decoding(slots=2, prefill_batch=1)
+    dec = _decoder(lm_predictor, 2)
     ids = dec.prefill([[3, 1, 4]], [0])
     a, pos_a = [int(ids[0])], 3
     toks = np.zeros((2,), np.int64)
@@ -262,7 +233,7 @@ def test_engine_concurrent_requests_match_solo(lm_predictor):
     budgets = [8, 6, 5, 7]
     solo = [_ref_generate(lm_predictor, p, n)
             for p, n in zip(prompts, budgets)]
-    dec = lm_predictor.prepare_decoding(slots=2, prefill_batch=1)
+    dec = _decoder(lm_predictor, 2)
     with ServingEngine(dec) as eng:       # 4 requests over 2 slots
         reqs = [eng.submit(p, max_new_tokens=n)
                 for p, n in zip(prompts, budgets)]
@@ -273,7 +244,7 @@ def test_engine_concurrent_requests_match_solo(lm_predictor):
 
 def test_engine_cancel_and_queue_drain(lm_predictor):
     from paddle_tpu.serving import ServingEngine
-    dec = lm_predictor.prepare_decoding(slots=1, prefill_batch=1)
+    dec = _decoder(lm_predictor, 1)
     eng = ServingEngine(dec)              # not started: both stay queued
     keep = eng.submit([3, 1, 4], max_new_tokens=4)
     drop = eng.submit([2, 7], max_new_tokens=4)
@@ -291,7 +262,7 @@ def test_clone_workers_no_crosstalk(lm_predictor):
     weight scope (one HBM copy) while owning private cache scopes."""
     prompts = [[3, 1, 4, 1], [11, 2]]
     solo = [_ref_generate(lm_predictor, p, 7) for p in prompts]
-    base = lm_predictor.prepare_decoding(slots=2, prefill_batch=1)
+    base = _decoder(lm_predictor, 2)
     workers = [base, base.clone()]
     assert workers[1]._weight_scope is base._weight_scope
     assert workers[1]._scope is not base._scope
@@ -323,7 +294,7 @@ def test_clone_workers_no_crosstalk(lm_predictor):
 def test_lmserver_api_surface(lm_predictor):
     from paddle_tpu.serving import LMServer
     solo = _ref_generate(lm_predictor, [3, 1, 4], 5)
-    dec = lm_predictor.prepare_decoding(slots=2, prefill_batch=1)
+    dec = _decoder(lm_predictor, 2)
     with LMServer(dec) as srv:
         assert srv.generate([3, 1, 4], max_new_tokens=5) == solo
         h = srv.submit([3, 1, 4], max_new_tokens=5)
@@ -339,11 +310,32 @@ def test_lmserver_api_surface(lm_predictor):
             srv.submit(list(range(CFG.max_len + 1)))
 
 
+def test_lmserver_with_no_option_serves_from_a_page_pool(lm_predictor):
+    from paddle_tpu.serving import LMServer
+    solo = _ref_generate(lm_predictor, [3, 1, 4], 5)
+    with LMServer(lm_predictor._config.model_dir,
+                  place=fluid.CPUPlace()) as srv:
+        before = srv._decode.pool_stats()
+        assert before['pages_in_use'] == 0 and before['prefix_entries'] == 0
+        assert srv.generate([3, 1, 4], max_new_tokens=5) == solo
+        after = srv._decode.pool_stats()
+        # the finished stream's prompt stays in the prefix cache, on a page
+        assert after['prefix_entries'] >= 1 and after['pages_in_use'] >= 1
+        assert srv.stats()['kv']['num_pages'] == before['num_pages']
+
+
+def test_prepare_decoding_refuses_the_dense_cache(lm_predictor):
+    with pytest.raises(ValueError, match='dense ring KV cache was removed'):
+        lm_predictor.prepare_decoding(slots=2, paged=False)
+    with pytest.raises(TypeError):
+        lm_predictor.prepare_decoding(slots=2, prefill_batch=1)
+
+
 def test_serving_metrics_flow_into_rollup(lm_predictor):
     from paddle_tpu.obs import telemetry
     from paddle_tpu.obs.report import rollup
     from paddle_tpu.serving import ServingEngine
-    dec = lm_predictor.prepare_decoding(slots=2, prefill_batch=1)
+    dec = _decoder(lm_predictor, 2)
     telemetry.enable()
     try:
         telemetry.reset()

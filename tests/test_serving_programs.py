@@ -1,0 +1,78 @@
+"""The five serving programs, op for op.
+
+What each builder of models/transformer.py and models/hybrid.py emits
+for a tiny spec (its op-type sequence, its feed names, its fetch names)
+against a list recorded from the commit before the dense ring cache was
+deleted (PR 27, d61f26e; tests/serving_programs_pr27.json). The paged
+programs are what every benchmark cell compiles: a change that disturbs
+a builder changes the executables, their cache keys and the numbers, and
+has to record a new list here on purpose.
+"""
+import json
+import os
+
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu import unique_name
+from paddle_tpu.framework import Program, program_guard
+from paddle_tpu.models import hybrid
+from paddle_tpu.models.transformer import (TransformerConfig,
+                                           language_model_logits)
+from paddle_tpu.transpiler.decode_transpiler import DecodeTranspiler
+
+with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       'serving_programs_pr27.json')) as f:
+    RECORDED = json.load(f)
+
+GEOMETRY = dict(slots=3, page_tokens=4, kv_pages=13, prefill_chunk=8)
+
+
+def _lm_program(logits_fn, cfg):
+    main, startup = Program(), Program()
+    with program_guard(main, startup), unique_name.guard():
+        tokens = fluid.layers.data('tokens', shape=[1, cfg.max_len, 1],
+                                   dtype='int64', append_batch_size=False)
+        logits_fn(tokens, cfg)
+    return main
+
+
+def _describe(program, feeds, fetches):
+    return {'ops': ' '.join(op.type for op in program.global_block().ops),
+            'feeds': list(feeds), 'fetches': [v.name for v in fetches]}
+
+
+@pytest.fixture(scope='module')
+def programs():
+    gpt2 = _lm_program(language_model_logits, TransformerConfig(
+        vocab=64, dim=32, heads=2, layers=2, ffn=64, max_len=16,
+        use_tp=False, use_sp=False))
+    with unique_name.guard():
+        spair = DecodeTranspiler().transpile_spec(
+            gpt2, spec_k=2, draft_layers=1, **GEOMETRY)
+    hyb = _lm_program(hybrid.language_model_logits, hybrid.HybridConfig(
+        vocab=64, dim=32, heads=2, ffn=64, max_len=16, key_dim=8,
+        value_dim=16))
+    with unique_name.guard():
+        hpair = DecodeTranspiler().transpile(hyb, **GEOMETRY)
+    t = spair.target
+    return {
+        'gpt2_prefill': _describe(t.prefill_program, t.prefill_feeds,
+                                  t.prefill_fetches),
+        'gpt2_decode': _describe(t.decode_program, t.decode_feeds,
+                                 t.decode_fetches),
+        'gpt2_verify': _describe(spair.verify_program, spair.verify_feeds,
+                                 spair.verify_fetches),
+        'hybrid_prefill': _describe(hpair.prefill_program,
+                                    hpair.prefill_feeds,
+                                    hpair.prefill_fetches),
+        'hybrid_decode': _describe(hpair.decode_program, hpair.decode_feeds,
+                                   hpair.decode_fetches)}
+
+
+@pytest.mark.parametrize('name', sorted(RECORDED))
+def test_serving_program_is_op_for_op_what_it_was(programs, name):
+    got, want = programs[name], RECORDED[name]
+    assert got['feeds'] == want['feeds']
+    assert got['fetches'] == want['fetches']
+    assert got['ops'].split() == want['ops'].split()

@@ -20,7 +20,7 @@ The contracts under test:
 plus the satellites: Trainer(sharded=True) in-process resume,
 io save/load filter_fn + FLAGS_ckpt_verify digests,
 MeshConfig.from_flags / exception-safe mesh_scope / fit_spec,
-DecodePredictor.load_sharded serve-after-reshard parity, and the
+PagedDecodePredictor.load_sharded serve-after-reshard parity, and the
 ckpt.* telemetry instruments + trace spans.
 """
 import json
@@ -553,18 +553,19 @@ def test_fit_spec_adapts_to_new_topology():
 
 
 # ---------------------------------------------------------------------------
-# serving satellite: DecodePredictor.load_sharded serve-after-reshard
+# serving satellite: PagedDecodePredictor.load_sharded serve-after-reshard
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize('paged', [False, True],
-                         ids=['dense', 'paged'])
-def test_serve_after_reshard_parity(tmp_path, paged):
+@pytest.mark.parametrize('geometry', [
+    {}, dict(page_tokens=4, kv_pages=8, prefill_chunk=8)],
+    ids=['default_pool', 'explicit_pool'])
+def test_serve_after_reshard_parity(tmp_path, geometry):
     """Weights saved SHARDED on a dp=2xtp=2 training mesh, loaded by a
-    single-device predictor — both the dense-cache DecodePredictor and
-    the page-pool PagedDecodePredictor: greedy decode is identical to
-    the predictor's original weights (the save/reshard/load round trip
-    is exact), caches and page pools are never part of the checkpoint,
-    and a missing param raises naming it."""
+    single-device PagedDecodePredictor (the flags' page geometry, and
+    one given in the call): greedy decode is identical to the
+    predictor's original weights (the save/reshard/load round trip is
+    exact), page pools are never part of the checkpoint, and a missing
+    param raises naming it."""
     from paddle_tpu import unique_name
     from paddle_tpu.framework import Program, program_guard
     from paddle_tpu.models.transformer import (TransformerConfig,
@@ -588,12 +589,7 @@ def test_serve_after_reshard_parity(tmp_path, paged):
                                       exe, main_program=prog)
     predictor = AnalysisPredictor(AnalysisConfig(model_dir,
                                                  place=fluid.CPUPlace()))
-    if paged:
-        dec = predictor.prepare_decoding(slots=2, paged=True,
-                                         page_tokens=4, kv_pages=8,
-                                         prefill_chunk=cfg.max_len)
-    else:
-        dec = predictor.prepare_decoding(slots=2, prefill_batch=1)
+    dec = predictor.prepare_decoding(slots=2, **geometry)
     prompt = [3, 1, 4]
     ref_tokens = dec.generate(prompt, 4)
 

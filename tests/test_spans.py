@@ -57,7 +57,7 @@ def lm_predictor(tmp_path_factory):
 
 
 def _decoder(lm_predictor, **kw):
-    return lm_predictor.prepare_decoding(slots=2, paged=True, page_tokens=4,
+    return lm_predictor.prepare_decoding(slots=2, page_tokens=4,
                                          prefill_chunk=8, **kw)
 
 

@@ -41,7 +41,7 @@ def lm_predictor(tmp_path_factory):
 def _plain(pred, slots=2, **kw):
     kw.setdefault('page_tokens', 4)
     kw.setdefault('prefill_chunk', CFG.max_len)
-    return pred.prepare_decoding(slots=slots, paged=True, **kw)
+    return pred.prepare_decoding(slots=slots, **kw)
 
 
 def _spec(pred, slots=2, spec_k=3, **kw):
@@ -241,8 +241,8 @@ def test_cow_shared_prefix_streams_never_cross_talk(lm_predictor):
     spec = _spec(lm_predictor)
     prompt = [7, 3, 7, 4, 2, 9]
     n = 6
-    dense = lm_predictor.prepare_decoding(slots=1, prefill_batch=1)
-    ref = dense.generate(prompt, n)
+    # the same stream alone, cold, on a plain pool of its own
+    ref = _plain(lm_predictor, slots=1).generate(prompt, n)
     ia = spec.prefill([prompt], [0])      # cold: registers the prefix
     b = spec.open_stream(1, prompt)
     assert b['shared_tokens'] == 4        # adopted one full page
@@ -257,7 +257,7 @@ def test_cow_shared_prefix_streams_never_cross_talk(lm_predictor):
             acc.extend(int(t) for t in out[s])
             poss[s] += len(out[s])
     # identical prompts: both streams must be exactly the isolated
-    # dense stream — any COW leak across the shared page breaks one
+    # stream — any COW leak across the shared page breaks one
     assert sa[:n] == ref and sb[:n] == ref
 
 

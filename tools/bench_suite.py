@@ -192,12 +192,6 @@ def run_one(model, mode, steps, full, quick=False):
             row['fleet_tokens_per_sec'] = serving['fleet_tokens_per_sec']
         if serving.get('fleet_p99_ttft_ms'):
             row['fleet_p99_ttft_ms'] = serving['fleet_p99_ttft_ms']
-        if serving.get('paged_tokens_per_sec'):
-            row['paged_tokens_per_sec'] = serving['paged_tokens_per_sec']
-        if serving.get('paged_max_streams'):
-            row['paged_max_streams'] = serving['paged_max_streams']
-        if serving.get('prefix_hit_ttft_ms'):
-            row['prefix_hit_ttft_ms'] = serving['prefix_hit_ttft_ms']
         if serving.get('disagg_p99_ttft_ms'):
             row['disagg_p99_ttft_ms'] = serving['disagg_p99_ttft_ms']
         if serving.get('fleet_prefix_hit_rate'):
@@ -431,17 +425,15 @@ _SERVING_QUICK = [None]     # serve_bench --quick, measured at most once
 
 def _serving_quick():
     """Headline serving numbers (tools/serve_bench.py --quick
-    --refresh --fleet --paged --spec --disagg) stamped onto the
+    --refresh --fleet --spec --disagg) stamped onto the
     transformer local-mode row: the cached-vs-recompute decode
     speedup, the online-refresh tail cost (refresh_p99_ratio — token
     p99 with a live ParamSubscriber install loop over the undisturbed
     p99), the fleet leg (fleet_tokens_per_sec / fleet_p99_ttft_ms
     through a FleetRouter over 2 replica subprocesses — perf_gate
-    infers the direction from each suffix), the paged-cache A/B
-    (paged_tokens_per_sec / paged_max_streams at dense-equal HBM,
-    prefix_hit_ttft_ms), the speculative-decoding A/B
-    (spec_tokens_per_sec / spec_accept_rate vs plain paged decode at
-    equal HBM), and the disaggregated prefill/decode A/B
+    infers the direction from each suffix), the speculative-decoding
+    A/B (spec_tokens_per_sec / spec_accept_rate vs plain paged decode
+    at equal HBM), and the disaggregated prefill/decode A/B
     (disagg_p99_ttft_ms / fleet_prefix_hit_rate — a shared-prefix
     burst through a KV-page-shipping prefill tier vs colocated). One
     subprocess, cached across invocations; {} on any failure."""
@@ -452,7 +444,7 @@ def _serving_quick():
                 [sys.executable,
                  os.path.join(os.path.dirname(os.path.abspath(__file__)),
                               'serve_bench.py'), '--quick', '--refresh',
-                 '--fleet', '--paged', '--spec', '--disagg'],
+                 '--fleet', '--spec', '--disagg'],
                 capture_output=True, text=True, timeout=900, env=env)
             line = [ln for ln in out.stdout.splitlines()
                     if ln.startswith('{') and '"summary"' in ln][-1]

@@ -581,7 +581,7 @@ def _run_overload_seed(seed, budget, workdir, model_dir, n_replicas=2,
         # pressure forces preemption instead of merely queueing
         env = dict(base_env, SERVE_MODEL_DIR=model_dir,
                    SERVE_ENDPOINT=ep, SERVE_SLOTS='2',
-                   SERVE_WORKERS='1', SERVE_PAGED='1',
+                   SERVE_WORKERS='1',
                    SERVE_PAGE_TOKENS='4', SERVE_KV_PAGES='6',
                    SERVE_PREFILL_CHUNK='16')
         if i == 0:
@@ -731,7 +731,7 @@ def _run_disagg_seed(seed, budget, workdir, model_dir, streams=16,
     if obs_dir:
         base_env['FLAGS_obs_flush_secs'] = '0.5'
     paged_env = {'SERVE_MODEL_DIR': model_dir, 'SERVE_SLOTS': '4',
-                 'SERVE_WORKERS': '1', 'SERVE_PAGED': '1',
+                 'SERVE_WORKERS': '1',
                  'SERVE_PAGE_TOKENS': '4', 'SERVE_KV_PAGES': '64',
                  'SERVE_PREFILL_CHUNK': '16'}
     sup = Supervisor(max_restarts=2, backoff=0.5, log_dir=workdir,

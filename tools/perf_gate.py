@@ -51,7 +51,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # suffix -> direction: +1 = higher is better, -1 = lower is better
 _HIGHER = ('_per_sec', 'mfu', 'value', 'tflops', 'speedup',
            'vs_baseline', 'samples_per_sec', 'efficiency', 'hits',
-           '_max_streams', '_accept_rate', '_completion_rate',
+           '_accept_rate', '_completion_rate',
            '_win_rate', '_hit_rate', '_per_chip')
 _LOWER = ('_ms', '_secs', 'compile_ms', 'hbm_peak', 'peak_hbm_gb',
           '_bytes', 'misses', 'latency', '_hbm_per_chip_mb')

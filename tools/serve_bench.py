@@ -5,7 +5,7 @@ Measures what paddle_tpu/serving/ buys on a decoder-only LM:
   recompute   the pre-serving decode loop — one full T-prefix forward
               per generated token through the plain AnalysisPredictor
               (O(T) work per token)
-  cached      DecodePredictor decode_step over the K/V ring caches
+  cached      PagedDecodePredictor decode_step over the K/V page pool
               (O(1) per token), swept across slot-pool sizes: each
               batch size is its own transpiled decode program, so the
               row reflects a pool actually compiled at that width
@@ -84,13 +84,20 @@ def _recompute_tokens_per_sec(pred, cfg, iters):
 
 
 def _cached_tokens_per_sec(pred, cfg, slots, iters):
-    """Steady-state decode over a full pool of `slots` lanes, caches
-    warmed to full context. Returns (tokens/s, step_ms, prefill_ms)."""
+    """Steady-state decode over a full pool of `slots` lanes, each
+    stream one token short of its window, the step appending the last.
+    Returns (tokens/s, step_ms, prefill_ms)."""
+    from paddle_tpu.flags import get_flag
     rng = np.random.RandomState(0)
-    dec = pred.prepare_decoding(slots=slots, prefill_batch=1)
+    pages_per_slot = -(-cfg.max_len // get_flag('serving_page_tokens'))
+    # a page more a lane than its window: the step's first append forks
+    # the tail page that the prefix cache shares
+    dec = pred.prepare_decoding(slots=slots,
+                                kv_pages=slots * (pages_per_slot + 1) + 1,
+                                prefill_chunk=cfg.max_len)
     t0 = time.perf_counter()
     for s in range(slots):
-        dec.prefill([rng.randint(0, cfg.vocab, cfg.max_len)], [s])
+        dec.prefill([rng.randint(0, cfg.vocab, cfg.max_len - 1)], [s])
     prefill_ms = (time.perf_counter() - t0) * 1e3 / slots
     toks = rng.randint(0, cfg.vocab, slots).astype('int64')
     pos = np.full((slots,), cfg.max_len - 1, 'int32')
@@ -110,13 +117,12 @@ def _engine_leg(pred, cfg, slots, n_requests, new_tokens):
     TTFT and completion tokens/s measured from the request records."""
     from paddle_tpu.serving import ServingEngine
     rng = np.random.RandomState(1)
-    dec = pred.prepare_decoding(slots=slots, prefill_batch=1)
+    dec = pred.prepare_decoding(slots=slots)
     prompts = [rng.randint(0, cfg.vocab, max(1, cfg.max_len // 2))
                for _ in range(n_requests)]
     # compile both programs outside the measured window, then drop the
     # warmup state — TTFT should price admission + prefill, not XLA
-    dec.prefill([prompts[0]], [0])
-    dec.decode_step(np.zeros(slots, 'int64'), np.zeros(slots, 'int32'))
+    dec.generate(prompts[0], 2)
     dec.reset()
     t0 = time.perf_counter()
     with ServingEngine(dec) as eng:
@@ -149,11 +155,10 @@ def _refresh_leg(pred, cfg, slots, n_requests, new_tokens):
     from paddle_tpu.serving import ServingEngine
 
     rng = np.random.RandomState(3)
-    dec = pred.prepare_decoding(slots=slots, prefill_batch=1)
+    dec = pred.prepare_decoding(slots=slots)
     prompts = [rng.randint(0, cfg.vocab, max(1, cfg.max_len // 2))
                for _ in range(n_requests)]
-    dec.prefill([prompts[0]], [0])      # compile outside the window
-    dec.decode_step(np.zeros(slots, 'int64'), np.zeros(slots, 'int32'))
+    dec.generate(prompts[0], 2)         # compile outside the window
 
     # in-process pserver shard hosting the predictor's own params: a
     # refresh pulls + installs the full model, decode output unchanged
@@ -244,105 +249,6 @@ def _refresh_leg(pred, cfg, slots, n_requests, new_tokens):
             'refresh_p99_ratio': round(ratio, 3)}
 
 
-def _paged_leg(pred, cfg, quick):
-    """Paged-cache A/B leg at EQUAL cache HBM: the dense side gets
-    `slots_d` full-window ring lanes; the paged side gets a pool with
-    exactly the same token capacity (slots_d * pages_per_slot pages +
-    the null page) but 4x the lanes, pages allocated on demand. A
-    mixed short-stream burst then measures what on-demand paging buys:
-    paged_max_streams (peak concurrently-resident streams, sampled
-    from engine stats) vs dense_max_streams (the hard slot bound), and
-    paged vs dense tokens/s. prefix_hit_ttft_ms is the TTFT of a
-    prompt whose system prefix is already registered in the prefix
-    cache, vs prefix_cold_ttft_ms for the registering (cold) stream —
-    the shared-prefix zero-recompute win."""
-    import threading
-
-    from paddle_tpu.serving import ServingEngine
-
-    slots_d = 4 if quick else 8
-    pt = max(2, cfg.max_len // 8)
-    pages_per_slot = -(-cfg.max_len // pt)
-    num_pages = slots_d * pages_per_slot + 1
-    lanes = 4 * slots_d
-    chunk = max(1, cfg.max_len // 4)
-    new_tokens = 4 if quick else 8
-    # streams ~max_len/4 long: 4x lanes fit in dense-equal pool HBM
-    prompt_len = max(1, cfg.max_len // 4 - new_tokens)
-    n_requests = 4 * lanes
-    rng = np.random.RandomState(7)
-    prompts = [rng.randint(1, cfg.vocab, prompt_len)
-               for _ in range(n_requests)]
-
-    def burst(dec):
-        peak = [0]
-        stop = threading.Event()
-
-        def sample(eng):
-            while not stop.wait(0.001):
-                peak[0] = max(peak[0], eng.stats()['active'])
-
-        t0 = time.perf_counter()
-        with ServingEngine(dec) as eng:
-            thr = threading.Thread(target=sample, args=(eng,),
-                                   daemon=True)
-            thr.start()
-            reqs = [eng.submit(p, max_new_tokens=new_tokens)
-                    for p in prompts]
-            for r in reqs:
-                r.result(600)
-            stop.set()
-            thr.join(timeout=10)
-        wall = time.perf_counter() - t0
-        total = sum(len(r.tokens) for r in reqs)
-        return total / wall, peak[0], reqs
-
-    ddec = pred.prepare_decoding(slots=slots_d, prefill_batch=1)
-    ddec.prefill([prompts[0]], [0])     # compile outside the window
-    ddec.decode_step(np.zeros(slots_d, 'int64'),
-                     np.zeros(slots_d, 'int32'))
-    ddec.reset()
-    dense_tps, dense_peak, _ = burst(ddec)
-
-    pdec = pred.prepare_decoding(slots=lanes, paged=True,
-                                 page_tokens=pt, kv_pages=num_pages,
-                                 prefill_chunk=chunk)
-    pdec.open_stream(0, list(prompts[0]))   # compile outside the window
-    while pdec.prefill_step(0) is None:
-        pass
-    warm_tok = np.zeros(lanes, 'int64')
-    warm_pos = np.zeros(lanes, 'int32')
-    warm_pos[0] = prompt_len
-    pdec.decode_step(warm_tok, warm_pos)
-    pdec.reset()
-    paged_tps, paged_peak, _ = burst(pdec)
-
-    # prefix-sharing TTFT: a page-aligned system prefix, cold stream
-    # registers it, warm stream adopts the pages and prefills only the
-    # tail — both through the engine so TTFT prices the same path
-    sys_len = max(pt, (prompt_len // pt) * pt)
-    sys_prefix = list(rng.randint(1, cfg.vocab, sys_len))
-    pdec.reset()
-    with ServingEngine(pdec) as eng:
-        cold = eng.submit(sys_prefix + [1, 2], max_new_tokens=new_tokens)
-        cold.result(600)
-        warm = eng.submit(sys_prefix + [3, 4], max_new_tokens=new_tokens)
-        warm.result(600)
-        hits = eng.stats()['kv']['prefix_hits']
-    cold_ttft = cold.first_token_at - cold.submitted_at
-    warm_ttft = warm.first_token_at - warm.submitted_at
-    return {'mode': 'paged', 'dense_slots': slots_d, 'paged_lanes': lanes,
-            'page_tokens': pt, 'kv_pages': num_pages,
-            'prefill_chunk': chunk, 'requests': n_requests,
-            'dense_tokens_per_sec': round(dense_tps, 2),
-            'paged_tokens_per_sec': round(paged_tps, 2),
-            'dense_max_streams': dense_peak,
-            'paged_max_streams': paged_peak,
-            'prefix_hits': hits,
-            'prefix_cold_ttft_ms': round(cold_ttft * 1e3, 2),
-            'prefix_hit_ttft_ms': round(warm_ttft * 1e3, 2)}
-
-
 def _spec_leg(cfg, quick):
     """Speculative-decoding A/B leg at EQUAL cache HBM: plain paged
     greedy decode vs draft/verify speculation over the same page-pool
@@ -393,7 +299,7 @@ def _spec_leg(cfg, quick):
     prompts = [list(rng.randint(1, scfg.vocab, 2)) for _ in range(slots)]
     iters = scfg.max_len - 4
 
-    plain = spred.prepare_decoding(slots=slots, paged=True,
+    plain = spred.prepare_decoding(slots=slots,
                                    page_tokens=pt, kv_pages=plain_pages,
                                    prefill_chunk=scfg.max_len)
     ids = plain.prefill(prompts, list(range(slots)))
@@ -492,7 +398,7 @@ def _preempt_leg(pred, cfg, quick):
                for _ in range(n_requests)]
     prios = [1 if i % 3 == 0 else 0 for i in range(n_requests)]
 
-    dec = pred.prepare_decoding(slots=lanes, paged=True, page_tokens=pt,
+    dec = pred.prepare_decoding(slots=lanes, page_tokens=pt,
                                 kv_pages=num_pages, prefill_chunk=chunk)
     dec.open_stream(0, list(prompts[0]))    # compile outside the window
     while dec.prefill_step(0) is None:
@@ -879,7 +785,7 @@ def _disagg_leg(cfg, quick, replicas=2):
                     env=dict(env, SERVE_MODEL_DIR=model_dir,
                              SERVE_ENDPOINT=ep,
                              SERVE_SLOTS=str(slots),
-                             SERVE_WORKERS='1', SERVE_PAGED='1',
+                             SERVE_WORKERS='1',
                              SERVE_PAGE_TOKENS=str(pt),
                              SERVE_KV_PAGES=str(kv_pages),
                              SERVE_PREFILL_CHUNK=str(cfg.max_len)),
@@ -1016,7 +922,7 @@ def _mesh_leg(cfg, quick, iters, mesh_shape):
     pred = _build_predictor(cfg)
 
     def run(mesh):
-        dec = pred.prepare_decoding(slots=slots, paged=True,
+        dec = pred.prepare_decoding(slots=slots,
                                     page_tokens=pt,
                                     prefill_chunk=chunk, mesh=mesh)
         stream = dec.generate(probe, n_probe)
@@ -1069,12 +975,6 @@ def main():
                          'burst with vs without a concurrent '
                          'ParamSubscriber install loop '
                          '(refresh_p99_ratio in the summary)')
-    ap.add_argument('--paged', action='store_true',
-                    help='add the paged-cache A/B leg: dense vs paged '
-                         'KV cache at equal HBM under a mixed '
-                         'short-stream burst (paged_tokens_per_sec, '
-                         'paged_max_streams, prefix_hit_ttft_ms in '
-                         'the summary)')
     ap.add_argument('--fleet', action='store_true',
                     help='add the fleet serving leg: a FleetRouter '
                          'over 2 replica subprocesses under burst '
@@ -1198,15 +1098,6 @@ def main():
         print(json.dumps(ref_row), flush=True)
         summary['refresh_p99_ratio'] = ref_row['refresh_p99_ratio']
         summary['refresh_installs'] = ref_row['refresh']['refreshes']
-
-    if args.paged:
-        paged_row = _paged_leg(pred, cfg, args.quick)
-        paged_row['config'] = label
-        print(json.dumps(paged_row), flush=True)
-        for key in ('paged_tokens_per_sec', 'dense_tokens_per_sec',
-                    'paged_max_streams', 'dense_max_streams',
-                    'prefix_hit_ttft_ms', 'prefix_cold_ttft_ms'):
-            summary[key] = paged_row[key]
 
     if args.fleet:
         fleet_row = _fleet_leg(cfg, args.quick)

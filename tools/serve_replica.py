@@ -14,11 +14,9 @@ Environment contract (everything a Supervisor role env can carry):
                         how a launcher learns an ephemeral port
   SERVE_SLOTS           decode slots per worker      (default flags)
   SERVE_WORKERS         engine worker threads        (default 1)
-  SERVE_PREFILL_BATCH   prefill batch                (default flags)
-  SERVE_PAGED           '1' -> paged KV cache (copy-on-write prefix
-                        sharing + chunked prefill); sized by
-                        SERVE_PAGE_TOKENS / SERVE_KV_PAGES /
-                        SERVE_PREFILL_CHUNK   (defaults from flags)
+  SERVE_PAGE_TOKENS / SERVE_KV_PAGES / SERVE_PREFILL_CHUNK
+                        size the paged KV cache (copy-on-write prefix
+                        sharing + chunked prefill; defaults from flags)
   SERVE_MESH_SHAPE      'tp=2'-style axis spec -> the decode programs
                         run GSPMD over a device mesh (serving/mesh.py;
                         '' / unset = single-chip). The LAUNCHER env
@@ -59,16 +57,13 @@ def main():
     endpoint = os.environ.get('SERVE_ENDPOINT', '127.0.0.1:0')
     slots = os.environ.get('SERVE_SLOTS')
     workers = int(os.environ.get('SERVE_WORKERS', '1'))
-    prefill = os.environ.get('SERVE_PREFILL_BATCH')
-    paged = os.environ.get('SERVE_PAGED') == '1'
     page_tokens = os.environ.get('SERVE_PAGE_TOKENS')
     kv_pages = os.environ.get('SERVE_KV_PAGES')
     chunk = os.environ.get('SERVE_PREFILL_CHUNK')
     mesh = os.environ.get('SERVE_MESH_SHAPE', '')
     srv = LMServer(model_dir,
                    slots=int(slots) if slots else None,
-                   prefill_batch=int(prefill) if prefill else None,
-                   workers=workers, paged=paged,
+                   workers=workers,
                    page_tokens=int(page_tokens) if page_tokens else None,
                    kv_pages=int(kv_pages) if kv_pages else None,
                    prefill_chunk=int(chunk) if chunk else None,
