@@ -242,7 +242,13 @@ def fused_softmax_cross_entropy(input, label, num_classes, chunk=1024,
     per-chunk recompute in backward; ops/loss_ops.py). Use in place of
     `fc(act=None)` + `softmax_with_cross_entropy` when num_classes is
     large (LM heads). Owns the projection weight [D, num_classes]
-    (+ bias unless bias_attr=False). Returns Loss [..., 1] f32."""
+    (+ bias unless bias_attr=False). Returns Loss [..., 1] f32.
+
+    `chunk` is the tokens of one scan step on one dp shard: under a
+    mesh whose 'dp' axis divides the batch every shard scans its own
+    rows (batch / dp * T tokens, padded to the chunk) and the weight's
+    gradient is summed over dp; elsewhere the scan walks the whole
+    flattened batch."""
     helper = LayerHelper('fused_softmax_cross_entropy', input=input,
                          param_attr=param_attr, bias_attr=bias_attr,
                          name=name)

@@ -4,6 +4,7 @@ full [N, V] logits), including the padded-chunk and ignore_index paths.
 Reference semantics: softmax_with_cross_entropy_op.cc; the fusion is the
 TPU-native LM-head redesign (no reference analog op)."""
 import numpy as np
+import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.framework import Program, program_guard
@@ -100,3 +101,206 @@ def test_fused_xent_3d_and_no_bias():
     lse = (m + np.log(np.exp(logits - m).sum(-1, keepdims=True)))[:, 0]
     picked = logits[np.arange(B * T), lv.reshape(-1)]
     np.testing.assert_allclose(loss.reshape(-1), lse - picked, rtol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# Under a dp mesh the scan runs per dp shard (ops/loss_ops.py). The head
+# below has 8 distinct rows of T=5 tokens: on dp=4 a shard owns 10 tokens,
+# which chunk=4 does not divide (3 chunks a shard, padded to 12; the global
+# scan has 10 chunks of the 40 tokens), and the ignore_index rows all lie
+# on the second shard.
+# ---------------------------------------------------------------------------
+
+MB, MT, MCHUNK = 8, 5, 4
+_HEAD_FETCH = ['pre.w_0@GRAD', 'head.w_0@GRAD', 'head.w_1@GRAD']
+
+
+def _mesh_head_program():
+    from paddle_tpu import unique_name
+    prog, startup = Program(), Program()
+    with unique_name.guard(), program_guard(prog, startup):
+        x = fluid.layers.data(name='x', shape=[MB, MT, D],
+                              dtype='float32', append_batch_size=False)
+        lbl = fluid.layers.data(name='lbl', shape=[MB, MT, 1],
+                                dtype='int64', append_batch_size=False)
+        h = fluid.layers.fc(input=x, size=D, name='pre', bias_attr=False,
+                            num_flatten_dims=2)
+        loss = fluid.layers.fused_softmax_cross_entropy(
+            h, lbl, V, chunk=MCHUNK, name='head')
+        avg = fluid.layers.mean(loss)
+        fluid.optimizer.SGD(0.0).minimize(avg)
+    return prog, startup, avg
+
+
+def _mesh_head_run(devices=None, strategy=None):
+    """(values of [mean loss, d pre.w, dW, dBias], the executor) of one
+    step on seeded rows and weights: through ParallelExecutor over
+    `devices`, or through a plain Executor when devices is None."""
+    rng = np.random.RandomState(7)
+    xv = rng.randn(MB, MT, D).astype('f4')
+    lv = rng.randint(0, V, (MB, MT, 1)).astype('int64')
+    lv[2:4, 1:4, 0] = -100
+    params = {'pre.w_0': rng.randn(D, D).astype('f4') * 0.3,
+              'head.w_0': rng.randn(D, V).astype('f4') * 0.2,
+              'head.w_1': rng.randn(V).astype('f4') * 0.1}
+    prog, startup, avg = _mesh_head_program()
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    for name, val in params.items():
+        scope.set_var(name, val)
+    feed = {'x': xv, 'lbl': lv}
+    fetch = [avg.name] + _HEAD_FETCH
+    if devices is None:
+        outs = exe.run(prog, feed=feed, fetch_list=fetch, scope=scope)
+    else:
+        exe = fluid.ParallelExecutor(
+            use_cuda=True, loss_name=avg.name, main_program=prog,
+            scope=scope, devices=devices, strategy=strategy)
+        outs = exe.run(fetch_list=fetch, feed=feed)
+    return [np.asarray(o) for o in outs], exe
+
+
+def _routes():
+    from paddle_tpu.obs import telemetry
+    c = telemetry.snapshot()['counters']
+    return c['ops.fused_head.per_shard'], c['ops.fused_head.global']
+
+
+@pytest.fixture
+def counting():
+    from paddle_tpu.obs import telemetry
+    was = telemetry.enabled()
+    telemetry.enable()
+    yield
+    if not was:
+        telemetry.disable()
+
+
+@pytest.mark.parametrize('case', ['dp4', 'dp2_tp2'])
+def test_fused_xent_per_dp_shard_matches_one_device(case, counting):
+    """Mean loss, dW, dBias and the upstream fc's gradient of 8 distinct
+    rows over the mesh against one device: a missing or doubled sum
+    over dp, a shard that read another's labels, or padding that
+    leaked into a shard's loss moves one of them."""
+    import jax
+    from paddle_tpu.parallel.strategy import DistributedStrategy
+    strategy = DistributedStrategy(dp=2, tp=2) if case == 'dp2_tp2' else None
+    want, _ = _mesh_head_run()
+    before = _routes()
+    got, _ = _mesh_head_run(jax.devices()[:4], strategy)
+    after = _routes()
+    # the forward's emission and the grad's re-trace, both per shard
+    assert (after[0] - before[0], after[1] - before[1]) == (2, 0)
+    for name, a, b in zip(['loss', 'd_pre_w', 'dW', 'db'], got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-6,
+                                   err_msg=name)
+
+
+def test_fused_xent_dp4_partition():
+    """What the partitioner made of the dp=4 step: the head's forward
+    and backward loops run the shard's 3 chunks, nothing gathers X to
+    the global batch, and dW [D, V] is summed over the chips.
+
+    On the parent (PR 28, the global scan under GSPMD) the same step
+    read: known_trip_count 10 in both loops on every device, six
+    all-gathers (X to f32[10,4,16] and the labels to s32[10,4], in the
+    forward loop's operands and again in the backward's, plus the
+    [8,5,*] feeds), and no all-reduce of f32[16,37]: every device
+    computed the whole dW itself."""
+    import jax
+    import re
+    _, pe = _mesh_head_run(jax.devices()[:4])
+    text, = [t for t in pe.compiled_hlo_texts()
+             if 'fused_softmax_cross_entropy' in t]
+    trips = re.findall(r'known_trip_count[^}]*"n":"(\d+)"', text)
+    shard_chunks = -(-(MB // 4) * MT // MCHUNK)
+    assert trips == [str(shard_chunks)] * 2, trips
+    global_chunks = MB * MT // MCHUNK
+    gathered = re.findall(r'= (\w+\[[\d,]*\])\S* all-gather\(', text)
+    assert not [g for g in gathered
+                if re.match(r'\w+\[(%d|%d),' % (global_chunks, MB), g)], \
+        gathered
+    reduced = re.findall(r'= \(?([^=]*?)\)? all-reduce\(', text)
+    assert any('f32[%d,%d]' % (D, V) in r for r in reduced), reduced
+
+
+@pytest.mark.parametrize('case', ['no_mesh', 'mesh_of_one',
+                                  'batch_dp_does_not_divide'])
+def test_fused_xent_global_lowering_kept(case, counting):
+    """No mesh, a mesh of one and a batch dp does not divide take the
+    global scan: the lowered op is, text for text, the parent-style
+    lowering written out below, and only `ops.fused_head.global` moves."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.sharding import Mesh
+    from paddle_tpu import registry
+
+    batch = 6 if case == 'batch_dp_does_not_divide' else MB
+    mesh = {'no_mesh': None,
+            'mesh_of_one': Mesh(np.array(jax.devices()[:1]), ('dp',)),
+            'batch_dp_does_not_divide':
+                Mesh(np.array(jax.devices()[:4]), ('dp',))}[case]
+
+    class _Op(object):
+        def single_input(self, slot):
+            return slot
+
+        single_output = single_input
+
+        def input(self, slot):
+            return [slot]
+
+        def attr(self, name, default=None):
+            return {'chunk': MCHUNK, 'ignore_index': -100}[name]
+
+    class _Ctx(object):
+        def __init__(self, env):
+            self.env, self.mesh = env, mesh
+            self.get, self.set = env.__getitem__, env.__setitem__
+
+    def emitted(x, w, bias, label):
+        ctx = _Ctx({'X': x, 'W': w, 'Bias': bias, 'Label': label})
+        registry._REGISTRY['fused_softmax_cross_entropy'].emit(ctx, _Op())
+        return ctx.env['Loss']
+
+    def parent_style(x, w, bias, label):
+        n = batch * MT
+        x2 = x.reshape(n, D)
+        lbl = label.reshape(n).astype(jnp.int32)
+        pad = (-n) % MCHUNK
+        if pad:
+            x2 = jnp.concatenate([x2, jnp.zeros((pad, D), x2.dtype)], axis=0)
+            lbl = jnp.concatenate([lbl, jnp.zeros((pad,), lbl.dtype)])
+
+        def chunk_loss(x_c, l_c):
+            logits = lax.dot_general(
+                x_c, w, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            logits = logits + bias.astype(jnp.float32)
+            lse = jax.scipy.special.logsumexp(logits, axis=-1)
+            picked = jnp.take_along_axis(logits, l_c[:, None], axis=-1)[:, 0]
+            loss = lse - picked
+            return jnp.where(l_c == -100, 0.0, loss)
+
+        body = jax.checkpoint(chunk_loss)
+        _, losses = lax.scan(
+            lambda _, xs: (None, body(*xs)), None,
+            (x2.reshape(-1, MCHUNK, D), lbl.reshape(-1, MCHUNK)))
+        return losses.reshape(-1)[:n].reshape(batch, MT, 1)
+
+    args = (jnp.zeros((batch, MT, D), jnp.float32),
+            jnp.zeros((D, V), jnp.float32), jnp.zeros((V,), jnp.float32),
+            jnp.zeros((batch, MT, 1), jnp.int32))
+
+    def lowered(f):
+        # value and gradients, so the backward's lowering is compared too
+        g = jax.value_and_grad(lambda *a: f(*a).sum(), argnums=(0, 1, 2))
+        return jax.jit(g).lower(*args).as_text()
+
+    before = _routes()
+    text = lowered(emitted)
+    after = _routes()
+    assert (after[0] - before[0], after[1] - before[1]) == (0, 1)
+    assert text == lowered(parent_style)
