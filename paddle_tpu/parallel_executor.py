@@ -31,8 +31,29 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .executor import Executor, TPUPlace, global_scope
 from .framework import default_main_program
+from .obs import telemetry as _tm
 
 __all__ = ['ParallelExecutor', 'ExecutionStrategy', 'BuildStrategy']
+
+# How XLA's TPU compiler schedules the sums GSPMD places behind a mesh
+# program's gradients. By default it combines them into groups of up to
+# 120 MiB, each a plain all-reduce: the chip does nothing else while it
+# runs. A sum can instead ride inside a compute fusion (an "async
+# collective fusion": its steps run beside a matmul of the backward),
+# but only a sum of ONE array. So: the first two options allow the
+# fusion (either alone changes nothing), and the combiner's threshold
+# leaves every gradient of 16 MiB or more a sum of its own. They say
+# how collectives are scheduled, never what is summed or in which
+# dtype. Per executable (jax.jit compiler_options), so a one-device
+# program is not touched; the CPU compiler rejects them, so they go
+# only to a mesh of more than one TPU device (_overlap_options).
+# Measured, and what was tried and not kept: PERF.md section 6, PR 32.
+_OVERLAP_OPTIONS = {
+    'xla_enable_async_all_reduce': True,
+    'xla_tpu_enable_async_collective_fusion_fuse_all_reduce': True,
+    'xla_jf_crs_combiner_threshold_in_bytes': 16 << 20,
+}
+_OVERLAP_COMPILES = _tm.counter('parallel.overlap_compiles')
 
 
 class ExecutionStrategy(object):
@@ -189,6 +210,15 @@ class ParallelExecutor(Executor):
     def _emit_mesh(self):
         return self.mesh
 
+    def _overlap_options(self):
+        """Compiler options for a segment of this mesh, or None: only a
+        mesh of more than one TPU device has collectives the options
+        know (observed from the devices, no flag)."""
+        if len(self._devices) > 1 and \
+                all(d.platform == 'tpu' for d in self._devices):
+            return dict(_OVERLAP_OPTIONS)
+        return None
+
     def _jit_options(self, segment, feed_names):
         feed_set = set(feed_names)
         out_set = set(segment.out_names)
@@ -216,7 +246,13 @@ class ParallelExecutor(Executor):
             {n: spec(n) for n in const_keys},
             self._replicated,
         )
-        return {'in_shardings': in_shardings}
+        options = {'in_shardings': in_shardings}
+        overlap = self._overlap_options()
+        if overlap:
+            # one call a compiled segment (both paths of _compile_segment)
+            _OVERLAP_COMPILES.inc()
+            options['compiler_options'] = overlap
+        return options
 
     def _compile_segment(self, segment, block, program, feed_names=(),
                          donate=True):

@@ -1,0 +1,244 @@
+"""The compiler options ParallelExecutor gives a TPU mesh program so its
+gradient sums run asynchronously (parallel_executor._OVERLAP_OPTIONS):
+who gets them (a mesh of more than one TPU device, observed from the
+devices), who does not (virtual CPU devices, a mesh of one), that a dp
+step is still the single-device step, and -- compiled here for a
+described v5e:2x2, no chip -- that the options still do to the schedule
+what they were added for."""
+import os
+import sys
+import types
+
+import numpy as np
+import pytest
+
+import jax
+import paddle_tpu as fluid
+from paddle_tpu import parallel_executor as pem
+from paddle_tpu.executor import PreparedProgram, _DeviceSegment
+from paddle_tpu.obs import telemetry
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), 'tools'))
+
+from test_parallel_executor import _build  # noqa: E402
+
+
+@pytest.fixture
+def counting():
+    was = telemetry.enabled()
+    telemetry.enable()
+    telemetry.reset()
+    yield lambda: telemetry.snapshot()['counters']['parallel.overlap_compiles']
+    if not was:
+        telemetry.disable()
+
+
+def _segment(prog, loss):
+    prepared = PreparedProgram(prog, 0, ('x', 'y'), [loss.name])
+    return next(s for s in prepared.steps if isinstance(s, _DeviceSegment))
+
+
+def _fake(platform, n):
+    return [types.SimpleNamespace(platform=platform) for _ in range(n)]
+
+
+@pytest.mark.parametrize('n_devices', [2, 4, 8])
+def test_virtual_cpu_mesh_takes_no_compiler_options(n_devices, counting):
+    prog, startup, loss = _build()
+    fluid.Executor(fluid.CPUPlace()).run(startup)
+    pe = fluid.ParallelExecutor(use_cuda=False, loss_name=loss.name,
+                                main_program=prog,
+                                devices=jax.devices()[:n_devices])
+    assert pe._overlap_options() is None
+    options = pe._jit_options(_segment(prog, loss), ('x', 'y'))
+    assert set(options) == {'in_shardings'}
+    # and the step compiles and runs: the CPU compiler would refuse them
+    out = pe.run(fetch_list=[loss.name],
+                 feed={'x': np.ones((16, 8), 'float32'),
+                       'y': np.ones((16, 1), 'float32')})
+    assert np.isfinite(out[0]).all()
+    assert counting() == 0
+
+
+@pytest.mark.parametrize('platform,n,want', [
+    ('tpu', 1, False),      # the one-chip cell: the parent's executable
+    ('tpu', 2, True),
+    ('tpu', 4, True),
+    ('cpu', 4, False),
+    ('gpu', 4, False),
+])
+def test_rule_reads_the_devices(platform, n, want, counting):
+    """The condition is the devices' platform and number, nothing else:
+    no flag, no environment variable, no BuildStrategy field."""
+    prog, startup, loss = _build()
+    pe = fluid.ParallelExecutor(use_cuda=False, loss_name=loss.name,
+                                main_program=prog,
+                                devices=jax.devices()[:1])
+    plain = pe._jit_options(_segment(prog, loss), ('x', 'y'))
+    assert set(plain) == {'in_shardings'} and counting() == 0
+    pe._devices = _fake(platform, n)
+    options = pe._jit_options(_segment(prog, loss), ('x', 'y'))
+    if want:
+        assert options['compiler_options'] == pem._OVERLAP_OPTIONS
+        assert options['compiler_options'] is not pem._OVERLAP_OPTIONS
+        assert counting() == 1
+    else:
+        assert set(options) == {'in_shardings'} and counting() == 0
+    assert options['in_shardings'][2] == plain['in_shardings'][2]
+
+
+def test_mixed_platform_mesh_takes_none():
+    prog, startup, loss = _build()
+    pe = fluid.ParallelExecutor(use_cuda=False, loss_name=loss.name,
+                                main_program=prog,
+                                devices=jax.devices()[:1])
+    pe._devices = _fake('tpu', 2) + _fake('cpu', 2)
+    assert pe._overlap_options() is None
+
+
+def test_no_switch_exists():
+    """Not configurable: nothing in the flag registry or the strategies
+    names the rule."""
+    from paddle_tpu import flags
+    assert not [k for k in flags._FLAGS if 'overlap' in k.lower()
+                or 'async' in k.lower() and 'reduce' in k.lower()]
+    for obj in (fluid.BuildStrategy(), fluid.ExecutionStrategy()):
+        assert not [k for k in vars(obj) if 'overlap' in k]
+
+
+def test_dp4_gradients_match_single_device():
+    """The existing parity (test_parallel_executor), one more case: the
+    gradients themselves, over 4 devices."""
+    rng = np.random.RandomState(7)
+    xb = rng.randn(32, 8).astype('float32')
+    yb = rng.randn(32, 1).astype('float32')
+
+    def grads(run, prog, loss):
+        names = [p.name + '@GRAD' for p in prog.global_block().vars.values()
+                 if isinstance(p, fluid.framework.Parameter)]
+        return names, run([loss.name] + names)
+
+    prog, startup, loss = _build()
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        names, single = grads(
+            lambda f: exe.run(prog, feed={'x': xb, 'y': yb}, fetch_list=f),
+            prog, loss)
+    prog2, startup2, loss2 = _build()
+    with fluid.scope_guard(fluid.Scope()):
+        fluid.Executor(fluid.CPUPlace()).run(startup2)
+        pe = fluid.ParallelExecutor(use_cuda=False, loss_name=loss2.name,
+                                    main_program=prog2,
+                                    devices=jax.devices()[:4])
+        _, multi = grads(
+            lambda f: pe.run(fetch_list=f, feed={'x': xb, 'y': yb}),
+            prog2, loss2)
+    assert len(names) == 4
+    for name, a, b in zip(['loss'] + names, single, multi):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-6, err_msg=name)
+
+
+# -- compiled for a described v5e:2x2 (no chip) ------------------------------
+
+# one block whose two feed-forward gradients are 32 MiB each in bf16 (the
+# cell's [2048, 8192] are too): large enough to stand alone as sums
+_WIDE = {'n_embd': 1024, 'n_head': 8, 'n_inner': 16384, 'n_positions': 128,
+         'vocab_size': 512, 'n_layer': 1,
+         'flags': {'FLAGS_amp_bf16_param_grads': True}}
+
+
+@pytest.fixture(scope='module')
+def v5e_2x2():
+    import mesh_schedule
+    try:
+        devices = mesh_schedule.describe('v5e:2x2')
+    except Exception as e:
+        pytest.skip('no v5e:2x2 topology can be described here: %s' % e)
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it out
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from paddle_tpu import flags
+    was = jax.config.jax_enable_compilation_cache
+    flag_was = flags.get_flag('amp_bf16_param_grads')
+    jax.config.update('jax_enable_compilation_cache', False)
+    cc.reset_cache()
+    yield devices
+    flags.set_flags({'FLAGS_amp_bf16_param_grads': flag_was})
+    jax.config.update('jax_enable_compilation_cache', was)
+    cc.reset_cache()
+
+
+def _rows(devices, per_step=8, options=None, overlap=True):
+    import mesh_schedule
+    from unittest import mock
+    with fluid.unique_name.guard():
+        program, loss = mesh_schedule.build_lm_step(_WIDE, per_step,
+                                                    len(devices))
+    with mock.patch.object(pem, '_OVERLAP_OPTIONS',
+                           pem._OVERLAP_OPTIONS if options is None
+                           else options):
+        text = mesh_schedule.compile_step(program, [loss], devices,
+                                          per_step, overlap=overlap).as_text()
+    return mesh_schedule.list_collectives(text)
+
+
+@pytest.fixture(scope='module')
+def schedules(v5e_2x2):
+    """{overlap: mesh_schedule.list_collectives' rows} of the dp step, with
+    the rule applied and with XLA's default schedule."""
+    return {overlap: _rows(v5e_2x2, overlap=overlap)
+            for overlap in (True, False)}
+
+
+def _big(rows):
+    return [r for r in rows if r['bytes'] >= 32 << 20]
+
+
+def test_tpu_mesh_counts_its_compiles(v5e_2x2, counting):
+    _rows(v5e_2x2)
+    assert counting() == 1
+    _rows(v5e_2x2[:1], per_step=2)
+    assert counting() == 1
+
+
+def test_default_schedule_blocks_on_every_gradient_sum(schedules):
+    rows = schedules[False]
+    assert rows and all(r['kind'] == 'all-reduce' for r in rows)
+    assert all(r['form'] == 'plain' for r in rows), rows
+
+
+def test_rule_fuses_the_large_gradient_sums_into_compute(schedules):
+    """The mark an upgrade of jax or libtpu must not silently lose: with
+    the options a gradient of 32 MiB or more is summed by an async
+    collective fusion that a compute fusion carries; by default none is."""
+    big = _big(schedules[True])
+    fused = [r for r in big if r['form'] == 'fused']
+    # (the backward's last sum has no product left to ride: marked only)
+    assert len(big) == 2 and fused, big
+    assert all('mul_grad' in ' '.join(r['carriers']) for r in fused), fused
+    assert not [r for r in schedules[True] if r['form'] == 'plain']
+    assert not [r for r in schedules[False] if r['form'] == 'fused']
+
+
+def test_rule_moves_no_bytes_and_no_dtype(schedules):
+    """How the sums are scheduled, never what is summed: the same bytes in
+    the same dtypes on both sides."""
+    import mesh_schedule
+    a = mesh_schedule.summarize(schedules[True])
+    b = mesh_schedule.summarize(schedules[False])
+    assert a['bytes_by_dtype'] == b['bytes_by_dtype']
+    assert set(b['bytes_by_form']) == {'plain'}
+    assert sum(a['bytes_by_form'].values()) == b['bytes_by_form']['plain']
+    assert a['bytes_by_form']['fused'] >= 32 << 20
+
+
+@pytest.mark.parametrize('option', sorted(pem._OVERLAP_OPTIONS))
+def test_every_kept_option_is_needed(option, v5e_2x2):
+    """An option that does nothing is not kept: without any one of them
+    no sum of the compiled step rides a compute fusion."""
+    rest = {k: v for k, v in pem._OVERLAP_OPTIONS.items() if k != option}
+    rows = _rows(v5e_2x2, options=rest)
+    assert rows and not [r for r in rows if r['form'] == 'fused'], \
+        (option, rows)
