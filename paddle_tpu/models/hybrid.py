@@ -64,6 +64,8 @@ class HybridDecodeSpec(DecodeSpec):
     'out'. Weights are (name, None) pairs as the named-fc helpers take
     them, norms and the per-head scalars plain names."""
 
+    recurrent_kinds = ('linear_attention',)
+
     def __init__(self, vocab, dim, heads, ffn, max_len, kinds, key_dim,
                  value_dim, conv_kernel, eps, beta_scale, emb_w, blocks,
                  final_norm, head):

@@ -1,0 +1,375 @@
+"""Hybrid language model of three layer kinds, one mixer a layer (the
+Nemotron-H block, `model_type: nemotron_h`): Mamba-2 state-space
+layers, expert layers whose experts work in a latent space beside one
+shared expert, and a few full-attention layers with fewer K/V heads
+than query heads.
+
+Beside models/hybrid.py, whose shape it follows, and on
+models/transformer.py's named-fc helpers, page pools and paged
+attention. One wiring for every kind, pre-norm, no bias but the
+convolution's:
+
+    x <- x + Mixer_i(RMSNorm_i(x));   embedding -> layers -> RMSNorm -> head
+
+`mamba` (H heads of size P, G groups of state size N): [z | xBC | dt]
+= x W_in (widths H P, H P + 2 G N, H); xBC through a causal depthwise
+convolution of K taps with a bias, then silu (op short_conv); the
+recurrence of ops/ssd_ops.py (ops ssd_chunk / ssd_step) over x, B, C
+and dt; then W_out RMSNorm_groups(y * silu(z)) (op gated_group_norm).
+`experts`: the router scores x over all E experts; l = x W_down (the
+latent, width L); op moe_experts gives the part of sum_e w_e W2_e
+relu(W1_e l)^2 that the experts HELD here add (`experts_held` of E from
+`expert_offset`: one chip's share under expert parallelism; all of
+them where experts_held = E); out = r W_up + V2 relu(V1 x)^2, the
+shared expert at the full width.
+`full_attention`: q, k, v = x W_qkv with `heads` query heads and
+`kv_heads` K/V heads of `head_dim`, causal softmax attention, W_o; no
+positional term.
+
+Three programs come from the one block code, as in models/hybrid.py:
+language_model_logits (what save_inference_model writes and the
+DecodeTranspiler reads) and the paged serving pair. K/V pools exist
+for the full-attention layers only, [pages, page_tokens, kv_heads,
+head_dim]; each mamba layer keeps, per slot, its state [slots, H, P, N]
+and the convolution's last K-1 input rows [slots, K-1, H P + 2 G N] as
+scope variables that both programs update in place; an expert layer
+keeps nothing for a stream. Each program of the pair also returns, as
+a third fetch, what its expert layers counted in the call ([4] int32:
+pairs of token and held expert, held experts with a pair, pairs not
+computed, layers): PagedDecodePredictor leaves it on the device and
+sums it when asked (moe_counters()).
+"""
+from __future__ import annotations
+
+import numpy as np
+
+from .. import layers as L
+from .hybrid import (HybridDecodeSpec, _create_state_vars, _data, _param,
+                     _rms)
+from .transformer import (PAGED_DECODE_FEEDS, DecodeSpec, _block_op,
+                          _create_pool_vars, _named_attr, _named_fc,
+                          _paged_decode_attention, _paged_decode_tokens,
+                          _paged_prefill_attention, _qkv_parts, _tmp_var)
+
+KINDS = ('mamba', 'experts', 'full_attention')
+# hybrid_override_pattern's letters
+PATTERN = {'M': 'mamba', 'E': 'experts', '*': 'full_attention'}
+
+
+class NemotronHConfig(object):
+    def __init__(self, vocab=512, dim=64, heads=4, kv_heads=2, head_dim=16,
+                 layer_types=KINDS, max_len=64, mamba_heads=4,
+                 mamba_head_dim=8, groups=2, state=16, conv_kernel=4,
+                 chunk=128, experts=16, experts_held=None, expert_offset=0,
+                 top_k=4, routed_scale=1.0, latent=32, expert_ffn=48,
+                 shared_ffn=96, eps=1e-5):
+        self.vocab, self.dim, self.max_len = vocab, dim, max_len
+        self.heads, self.kv_heads, self.head_dim = heads, kv_heads, head_dim
+        self.layer_types = tuple(layer_types)
+        self.mamba_heads, self.mamba_head_dim = mamba_heads, mamba_head_dim
+        self.groups, self.state = groups, state
+        self.conv_kernel, self.chunk = conv_kernel, chunk
+        self.experts = experts
+        self.experts_held = experts if experts_held is None else experts_held
+        self.expert_offset = expert_offset
+        self.top_k, self.routed_scale = top_k, routed_scale
+        self.latent, self.expert_ffn = latent, expert_ffn
+        self.shared_ffn, self.eps = shared_ffn, eps
+
+
+class NemotronHDecodeSpec(DecodeSpec):
+    """DecodeSpec of the block. blocks[i] holds parameter names by role:
+    every kind 'norm'; mamba 'in', 'conv', 'conv_bias', 'dt_bias',
+    'a_log', 'd', 'gate_norm', 'out'; experts 'router', 'bias', 'down',
+    'w1', 'w2', 'up', 'shared_up', 'shared_down'; full_attention 'qkv',
+    'proj'. Weights of the named-fc helpers are (name, None) pairs,
+    everything else plain names."""
+
+    recurrent_kinds = ('mamba',)
+    state_family = 'ssm'
+
+    def __init__(self, cfg, emb_w, blocks, final_norm, head):
+        kinds = tuple(cfg.layer_types)
+        for kind in kinds:
+            if kind not in KINDS:
+                raise ValueError('layer kind %r is not one of %s'
+                                 % (kind, KINDS))
+        DecodeSpec.__init__(
+            self, vocab=cfg.vocab, dim=cfg.dim, heads=cfg.heads,
+            layers=len(kinds), ffn=cfg.shared_ffn, max_len=cfg.max_len,
+            pos_len=0, emb_w=emb_w, pos_w=None, blocks=blocks,
+            final_ln=(final_norm, None), head=head, kinds=kinds,
+            kv_heads=cfg.kv_heads, head_dim=cfg.head_dim)
+        if not 0 <= cfg.expert_offset <= cfg.experts - cfg.experts_held:
+            raise ValueError('experts %d..%d are not among %d' % (
+                cfg.expert_offset, cfg.expert_offset + cfg.experts_held,
+                cfg.experts))
+        self.cfg = cfg
+        self.eps = cfg.eps
+        self.expert_layers = [i for i, k in enumerate(kinds)
+                              if k == 'experts']
+        self.inner = cfg.mamba_heads * cfg.mamba_head_dim
+        self.conv_dim = self.inner + 2 * cfg.groups * cfg.state
+
+    def state_names(self, layer=None):
+        """(state, convolution rows) var names of the mamba layers;
+        shared by the paged pair."""
+        if layer is not None:
+            return ('ssm_state.layer%d.h' % layer,
+                    'ssm_state.layer%d.conv' % layer)
+        out = []
+        for i in self.recurrent_layers:
+            out.extend(self.state_names(i))
+        return out
+
+    def state_shapes(self, slots):
+        c = self.cfg
+        return ((slots, c.mamba_heads, c.mamba_head_dim, c.state),
+                (slots, c.conv_kernel - 1, self.conv_dim))
+
+    # every name in blocks, whatever the roles: the hybrid spec's walk
+    param_names = HybridDecodeSpec.param_names
+
+    def build_paged_programs(self, slots, chunk, num_pages, page_tokens,
+                             pages_per_slot):
+        return build_paged_prefill_program(
+            self, slots, chunk, num_pages, page_tokens, pages_per_slot) + \
+            build_paged_decode_program(
+                self, slots, num_pages, page_tokens, pages_per_slot)
+
+
+_ROLES = {
+    'mamba': (('in', True), ('conv', False), ('conv_bias', False),
+              ('dt_bias', False), ('a_log', False), ('d', False),
+              ('gate_norm', False), ('out', True)),
+    'experts': (('router', False), ('bias', False), ('down', True),
+                ('w1', False), ('w2', False), ('up', True),
+                ('shared_up', True), ('shared_down', True)),
+    'full_attention': (('qkv', True), ('proj', True)),
+}
+
+
+def spec_from_config(cfg):
+    """The spec of a model built here, with names of its own."""
+    blocks = []
+    for i, kind in enumerate(cfg.layer_types):
+        blk = {'norm': 'layer%d.norm.w' % i}
+        for role, fc in _ROLES[kind]:
+            name = 'layer%d.%s.w' % (i, role)
+            blk[role] = (name, None) if fc else name
+        blocks.append(blk)
+    return NemotronHDecodeSpec(cfg, emb_w='embed.w', blocks=blocks,
+                               final_norm='final_norm.w',
+                               head=('lm_head.w', None))
+
+
+# -- the block ---------------------------------------------------------------
+
+def _mamba_mixer(x, spec, blk, t, ssd_type, state=None, at=None):
+    """The state-space mixer around its two stateful ops. `state` is the
+    layer's (state, convolution rows) pair, which both ops read and
+    write in place, and `at` the inputs that say where and how
+    (Slot/Len/Reset for a chunk, Live for a step); neither for the
+    whole-sequence form."""
+    c = spec.cfg
+    h, inner, conv_dim = c.mamba_heads, spec.inner, spec.conv_dim
+
+    def stateful(var):
+        if state is None:
+            return {}, {}
+        return dict(at, State=[var]), {'StateOut': [var]}
+
+    zxd = _named_fc(x, 2 * inner + 2 * c.groups * c.state + h, blk['in'])
+    z = L.slice(zxd, axes=[2], starts=[0], ends=[inner])
+    xbc = L.slice(zxd, axes=[2], starts=[inner], ends=[inner + conv_dim])
+    dt = L.slice(zxd, axes=[2], starts=[inner + conv_dim],
+                 ends=[inner + conv_dim + h])
+    conv = _tmp_var()
+    ins, outs = stateful(state and state[1])
+    _block_op('short_conv',
+              inputs=dict(ins, X=[xbc],
+                          W=[_param(blk['conv'], [c.conv_kernel, conv_dim])],
+                          Bias=[_param(blk['conv_bias'], [conv_dim])]),
+              outputs=dict(outs, Out=[conv]))
+    y = _tmp_var()
+    ins, outs = stateful(state and state[0])
+    _block_op(ssd_type,
+              inputs=dict(ins, XBC=[conv], DT=[dt],
+                          ALog=[_param(blk['a_log'], [h])],
+                          DtBias=[_param(blk['dt_bias'], [h])],
+                          D=[_param(blk['d'], [h])]),
+              outputs=dict(outs, Out=[y]),
+              attrs={'heads': h, 'head_dim': c.mamba_head_dim,
+                     'groups': c.groups, 'state': c.state,
+                     'block': c.chunk})
+    gated = _tmp_var()
+    _block_op('gated_group_norm',
+              inputs={'X': [y], 'Z': [z],
+                      'Scale': [_param(blk['gate_norm'], [inner])]},
+              outputs={'Y': [gated]},
+              attrs={'groups': c.groups, 'epsilon': float(spec.eps)})
+    return _named_fc(gated, spec.dim, blk['out'])
+
+
+def _experts_mixer(x, spec, blk, stats=None, at=None):
+    """The expert layer: the latent projections and the shared expert
+    as plain matmuls around op moe_experts. `stats` is the list the
+    layer's counts are appended to and `at` the input that marks dead
+    rows (Live or Len); neither for the whole-sequence form."""
+    c = spec.cfg
+    lat = _named_fc(x, c.latent, blk['down'])
+    ins, outs = dict(at or {}), {}
+    if stats is not None:
+        stats.append(_tmp_var('int32'))
+        outs['Stats'] = [stats[-1]]
+    routed = _tmp_var()
+    _block_op('moe_experts',
+              inputs=dict(
+                  ins, X=[x], Lat=[lat],
+                  RouterW=[_param(blk['router'], [spec.dim, c.experts])],
+                  Bias=[_param(blk['bias'], [c.experts])],
+                  W1=[_param(blk['w1'],
+                             [c.experts_held, c.latent, c.expert_ffn])],
+                  W2=[_param(blk['w2'],
+                             [c.experts_held, c.expert_ffn, c.latent])]),
+              outputs=dict(outs, Out=[routed]),
+              attrs={'top_k': c.top_k, 'scale': float(c.routed_scale),
+                     'expert_offset': c.expert_offset})
+    routed = _named_fc(routed, spec.dim, blk['up'])
+    shared = L.square(L.relu(_named_fc(x, c.shared_ffn, blk['shared_up'])))
+    return L.elementwise_add(
+        routed, _named_fc(shared, spec.dim, blk['shared_down']))
+
+
+def _full_attention(x, spec, blk):
+    """Whole-sequence causal attention (the source program's form): the
+    query heads of one K/V head are rows of one product."""
+    t, h, kvh, dh = spec.max_len, spec.heads, spec.kv_heads, spec.dh
+    rep = h // kvh
+    q4, k4, v4 = _qkv_parts(x, spec, blk, t)
+    q, k, v = (L.transpose(a, perm=[0, 2, 1, 3]) for a in (q4, k4, v4))
+    q = L.reshape(q, shape=[-1, kvh, rep * t, dh])
+    scores = L.matmul(q, k, transpose_y=True, alpha=1.0 / np.sqrt(dh))
+    scores = L.reshape(scores, shape=[-1, h, t, t])
+    probs = L.softmax(L.causal_mask_bias(scores))
+    ctx = L.matmul(L.reshape(probs, shape=[-1, kvh, rep * t, t]), v)
+    ctx = L.transpose(L.reshape(ctx, shape=[-1, h, t, dh]),
+                      perm=[0, 2, 1, 3])
+    return _named_fc(L.reshape(ctx, shape=[-1, t, h * dh]), spec.dim,
+                     blk['proj'])
+
+
+def _model(tokens, spec, mixers, last=None):
+    """Embedding -> layers -> final norm -> head. `mixers` maps a layer
+    kind to its mixer; `last` gathers one row a sequence before the
+    head (the prefill's logits)."""
+    x = L.embedding(tokens, size=[spec.vocab, spec.dim],
+                    param_attr=_named_attr(spec.emb_w))
+    for i, kind in enumerate(spec.kinds):
+        blk = spec.blocks[i]
+        x = L.elementwise_add(
+            x, mixers[kind](_rms(x, spec, blk['norm']), spec, blk, i))
+    x = _rms(x, spec, spec.final_ln[0])
+    if last is None:
+        return _named_fc(x, spec.vocab, spec.head)
+    gathered = _tmp_var()
+    _block_op('gather_time', inputs={'X': [x], 'Index': [last]},
+              outputs={'Out': [gathered]})
+    return _named_fc(gathered, spec.vocab, spec.head, num_flatten_dims=1)
+
+
+def language_model_logits(tokens, cfg):
+    """tokens [B, T, 1] int64 (T = cfg.max_len) -> logits [B, T, vocab],
+    every sequence from zero state."""
+    spec = spec_from_config(cfg)
+    return _model(tokens, spec, {
+        'mamba': lambda x, sp, blk, i: _mamba_mixer(
+            x, sp, blk, sp.max_len, 'ssd_chunk'),
+        'experts': lambda x, sp, blk, i: _experts_mixer(x, sp, blk),
+        'full_attention': lambda x, sp, blk, i: _full_attention(x, sp, blk)})
+
+
+# -- the paged pair ------------------------------------------------------------
+
+def _fetches(logits, ids, stats):
+    """[logits, ids], and the expert layers' counts summed over the
+    layers where there are any."""
+    if not stats:
+        return [logits, ids]
+    total = stats[0]
+    for one in stats[1:]:
+        total = L.elementwise_add(total, one)
+    return [logits, ids, total]
+
+
+def build_paged_prefill_program(spec, slots, chunk, num_pages, page_tokens,
+                                pages_per_slot):
+    """One prefill chunk of one stream: models/hybrid.py's paged prefill
+    feeds (prefill_state_slot and prefill_state_reset among them).
+    Rows from prefill_len on leave no trace in any state and are not
+    counted by the expert layers.
+    Returns (program, feed_names, fetch_vars[logits, ids, counts])."""
+    from ..framework import Program, program_guard
+    prog, startup = Program(), Program()
+    prog._is_test = True
+    with program_guard(prog, startup):
+        tokens = _data('prefill_tokens', [1, chunk, 1], 'int64')
+        positions = _data('prefill_positions', [chunk])
+        length = _data('prefill_len', [1])
+        last = _data('prefill_last', [1])
+        table = _data('prefill_page_table', [1, pages_per_slot])
+        cow_src = _data('prefill_cow_src', [1])
+        cow_dst = _data('prefill_cow_dst', [1])
+        slot = _data('prefill_state_slot', [1])
+        reset = _data('prefill_state_reset', [1])
+        pools = _create_pool_vars(spec, num_pages, page_tokens)
+        states = _create_state_vars(spec, slots)
+        stats = []
+
+        logits = _model(tokens, spec, {
+            'mamba': lambda x, sp, blk, i: _mamba_mixer(
+                x, sp, blk, chunk, 'ssd_chunk', states[i],
+                {'Slot': [slot], 'Len': [length], 'Reset': [reset]}),
+            'experts': lambda x, sp, blk, i: _experts_mixer(
+                x, sp, blk, stats, {'Len': [length]}),
+            'full_attention': lambda x, sp, blk, i: _paged_prefill_attention(
+                x, sp, blk, pools[i], table, positions, length, cow_src,
+                cow_dst, chunk)}, last=last)
+        fetches = _fetches(logits, L.argmax(logits, axis=-1), stats)
+    return prog, ['prefill_tokens', 'prefill_positions', 'prefill_len',
+                  'prefill_last', 'prefill_page_table', 'prefill_cow_src',
+                  'prefill_cow_dst', 'prefill_state_slot',
+                  'prefill_state_reset'], fetches
+
+
+def build_paged_decode_program(spec, slots, num_pages, page_tokens,
+                               pages_per_slot):
+    """One token a lane over the whole slot pool: models/hybrid.py's
+    paged decode feeds. decode_state_live marks the lanes that take
+    part: the others' state stays as it was, and the expert layers
+    neither count nor weigh their rows.
+    Returns (program, feed_names, fetch_vars[logits, ids, counts])."""
+    from ..framework import Program, program_guard
+    prog, startup = Program(), Program()
+    prog._is_test = True
+    with program_guard(prog, startup):
+        tokens = _paged_decode_tokens(slots)
+        step_idx = _data('decode_step_idx', [slots])
+        table = _data('decode_page_table', [slots, pages_per_slot])
+        cow_src = _data('decode_cow_src', [slots])
+        cow_dst = _data('decode_cow_dst', [slots])
+        live = _data('decode_state_live', [slots])
+        pools = _create_pool_vars(spec, num_pages, page_tokens)
+        states = _create_state_vars(spec, slots)
+        stats = []
+
+        logits3 = _model(tokens, spec, {
+            'mamba': lambda x, sp, blk, i: _mamba_mixer(
+                x, sp, blk, 1, 'ssd_step', states[i], {'Live': [live]}),
+            'experts': lambda x, sp, blk, i: _experts_mixer(
+                x, sp, blk, stats, {'Live': [live]}),
+            'full_attention': lambda x, sp, blk, i: _paged_decode_attention(
+                x, sp, blk, pools[i], table, step_idx, cow_src, cow_dst)})
+        logits = L.reshape(logits3, shape=[-1, spec.vocab])
+        fetches = _fetches(logits, L.argmax(logits, axis=-1), stats)
+    return prog, PAGED_DECODE_FEEDS + ['decode_state_live'], fetches

@@ -208,13 +208,15 @@ class DecodeTranspileError(ValueError):
 
 
 def refuse_recurrent(spec, what):
-    """Raise for a spec with recurrent layers where `what` knows a
-    stream's state as pages only."""
+    """Raise for a spec with layers that hold recurrent state (of
+    whatever kind: spec.recurrent_layers) where `what` knows a stream's
+    state as pages only."""
     if spec.recurrent_layers:
+        kinds = sorted({spec.kinds[i] for i in spec.recurrent_layers})
         raise DecodeTranspileError(
             '%s cannot serve a model with %s layers (layers %s): their '
             'recurrent state is not in the page pool'
-            % (what, spec.kinds[spec.recurrent_layers[0]],
+            % (what, ' and '.join(kinds),
                ','.join(map(str, spec.recurrent_layers))))
 
 
@@ -233,23 +235,38 @@ class DecodeSpec(object):
     ('tp=2'; '' = single-chip), stamped by prepare_decoding.
 
     kinds names each layer's mixer: 'full_attention' (K/V in the cache:
-    every layer of this block) or 'linear_attention' (a recurrent state
-    instead: models/hybrid.py, whose spec extends this one). The cache
-    variables exist for the layers in kv_layers only.
+    every layer of this block), or a kind of a spec that extends this
+    one (models/hybrid.py, models/nemotron_h.py). What a layer keeps for
+    a stream decides how it is served, not what it is called: K/V pages
+    (kv_layers: the cache variables exist for these only), per-slot
+    recurrent state beside the pools (recurrent_layers: the kinds the
+    spec's class names in `recurrent_kinds`), or nothing.
+
+    kv_heads is the number of K/V heads a page holds (query head h
+    reads K/V head h // (heads / kv_heads)); the model's head count
+    where it is not given.
     """
+
+    recurrent_kinds = ()
+    state_family = None     # names the gauge serving.<family>.state_bytes
 
     def __init__(self, vocab, dim, heads, layers, ffn, max_len, pos_len,
                  emb_w, pos_w, blocks, final_ln, head, use_flash=False,
-                 param_specs=None, mesh='', kinds=None):
+                 param_specs=None, mesh='', kinds=None, kv_heads=None,
+                 head_dim=None):
         self.vocab, self.dim, self.heads = vocab, dim, heads
+        self.kv_heads = int(kv_heads or heads)
+        if heads % self.kv_heads:
+            raise ValueError('%d query heads over %d K/V heads'
+                             % (heads, self.kv_heads))
         self.layers, self.ffn = layers, ffn
         self.kinds = tuple(kinds or ('full_attention',) * layers)
         self.kv_layers = [i for i, k in enumerate(self.kinds)
                           if k == 'full_attention']
         self.recurrent_layers = [i for i, k in enumerate(self.kinds)
-                                 if k == 'linear_attention']
+                                 if k in self.recurrent_kinds]
         self.max_len, self.pos_len = max_len, pos_len
-        self.dh = dim // heads
+        self.dh = int(head_dim or dim // heads)
         self.emb_w, self.pos_w = emb_w, pos_w
         self.blocks = blocks
         self.final_ln = final_ln
@@ -274,9 +291,9 @@ class DecodeSpec(object):
 
     @property
     def pool_heads(self):
-        """Heads a page holds: the model's, except where a spec pads
-        them to whole tiles (models/hybrid.py)."""
-        return self.heads
+        """Heads a page holds: the model's K/V heads, except where a
+        spec pads them to whole tiles (models/hybrid.py)."""
+        return self.kv_heads
 
     def pool_shape(self, num_pages, page_tokens):
         return (num_pages, page_tokens, self.pool_heads, self.dh)
@@ -369,17 +386,19 @@ def _qkv_parts(x, spec, blk, t, qk_norm=None):
     dim stays whole either way, so every element is bit-exact.
     `qk_norm(part, 'q' | 'k')`, where the caller's block norms q and k
     whole before the heads are split."""
-    qkv = _named_fc(x, 3 * spec.dim, blk['qkv'])
-    D = spec.dim
+    D, KV = spec.heads * spec.dh, spec.kv_heads * spec.dh
+    qkv = _named_fc(x, D + 2 * KV, blk['qkv'])
 
-    def part(s, e, which=None):
+    def part(s, e, heads, which=None):
         p = L.slice(qkv, axes=[2], starts=[s], ends=[e])
         if qk_norm is not None and which:
             p = qk_norm(p, which)
-        p = L.reshape(p, shape=[-1, t, spec.heads, spec.dh])
+        p = L.reshape(p, shape=[-1, t, heads, spec.dh])
         return sharding_constraint(p, (None, None, _tp_ax(spec), None))
 
-    return part(0, D, 'q'), part(D, 2 * D, 'k'), part(2 * D, 3 * D)
+    return (part(0, D, spec.heads, 'q'),
+            part(D, D + KV, spec.kv_heads, 'k'),
+            part(D + KV, D + 2 * KV, spec.kv_heads))
 
 
 def _cached_block(x, spec, i, attention):
@@ -444,18 +463,20 @@ def _create_pool_vars(spec, num_pages, page_tokens):
 
 def _pool_heads(x, spec):
     """x [B, t, H, dh] with zero heads appended up to spec.pool_heads
-    (nothing where the pool holds the model's heads as they are)."""
-    extra = spec.pool_heads - spec.heads
+    (nothing where the pool holds the model's K/V heads as they are:
+    padding is for pools with a K/V head a query head)."""
+    extra = spec.pool_heads - spec.kv_heads
     if not extra:
         return x
     return L.pad(x, paddings=[0, 0, 0, 0, 0, extra, 0, 0])
 
 
 def _model_heads(ctx, spec, t):
-    """ctx [B, t, pool_heads, dh] -> [B, t, dim]: the model's heads."""
-    if spec.pool_heads != spec.heads:
+    """ctx [B, t, heads or pool_heads, dh] -> [B, t, heads * dh]: the
+    model's heads."""
+    if spec.pool_heads != spec.kv_heads:
         ctx = L.slice(ctx, axes=[2], starts=[0], ends=[spec.heads])
-    return L.reshape(ctx, shape=[-1, t, spec.dim])
+    return L.reshape(ctx, shape=[-1, t, spec.heads * spec.dh])
 
 
 def _paged_gather(pool_var, table, spec):
@@ -487,16 +508,30 @@ def _paged_prefill_attention(x, spec, blk, pool, table, positions,
                   outputs={'Out': [pool_var]})
     q = sharding_constraint(L.transpose(q4, perm=[0, 2, 1, 3]),
                             (None, _tp_ax(spec), None, None))
-    kt = _paged_gather(pool[0], table, spec)           # [1, H, J, dh]
+    kt = _paged_gather(pool[0], table, spec)           # [1, KVH, J, dh]
     vt = _paged_gather(pool[1], table, spec)
+    # the query heads of one K/V head are rows of one product with its
+    # gathered pages: [1, KVH, rep * C, dh] (nothing to do where every
+    # query head has a K/V head of its own)
+    rep = spec.heads // spec.kv_heads
+    window = int(table.shape[1]) * int(pool[0].shape[1])       # J
+    grouped = [-1, spec.kv_heads, rep * chunk, spec.dh]
+    if rep > 1:
+        q = L.reshape(q, shape=grouped)
     scores = L.matmul(q, kt, transpose_y=True,
-                      alpha=1.0 / np.sqrt(spec.dh))    # [1, H, C, J]
-    masked = _tmp_var()
+                      alpha=1.0 / np.sqrt(spec.dh))
+    if rep > 1:
+        scores = L.reshape(scores, shape=[-1, spec.heads, chunk, window])
+    masked = _tmp_var()                                # [1, H, C, J]
     _block_op('paged_prefill_mask',
               inputs={'X': [scores], 'Positions': [positions]},
               outputs={'Out': [masked]})
     probs = L.softmax(masked)
+    if rep > 1:
+        probs = L.reshape(probs, shape=grouped[:3] + [window])
     ctx = L.matmul(probs, vt)                          # [1, H, C, dh]
+    if rep > 1:
+        ctx = L.reshape(ctx, shape=[-1, spec.heads, chunk, spec.dh])
     ctx = _model_heads(L.transpose(ctx, perm=[0, 2, 1, 3]), spec, chunk)
     ctx = sharding_constraint(ctx, (None, None, None))
     return _named_fc(ctx, spec.dim, blk['proj'])
@@ -510,7 +545,7 @@ def _paged_decode_attention(x, spec, blk, pool, table, positions,
     lowerings)."""
     q1, k1, v1 = (_pool_heads(a, spec)
                   for a in _qkv_parts(x, spec, blk, 1,
-                                      qk_norm))         # [S, 1, H, dh]
+                                      qk_norm))   # [S, 1, H | KVH, dh]
     for pool_var, new in ((pool[0], k1), (pool[1], v1)):
         _block_op('kv_page_cow',
                   inputs={'Pool': [pool_var], 'Src': [cow_src],

@@ -22,3 +22,4 @@ from . import nn3d_ops      # noqa: F401
 from . import ctc_rnn_ops   # noqa: F401
 from . import quant_ops     # noqa: F401
 from . import delta_rule_ops  # noqa: F401
+from . import ssd_ops      # noqa: F401

@@ -272,6 +272,9 @@ def _paged_attention_reference(q, k_pool, v_pool, table, positions,
     qt = pin(jnp.transpose(q, (0, 2, 1, 3)))                   # [S,H,1,dh]
     kt = pin(jnp.transpose(_gather_pages(k_pool, table), (0, 2, 1, 3)))
     vt = pin(jnp.transpose(_gather_pages(v_pool, table), (0, 2, 1, 3)))
+    rep = qt.shape[1] // kt.shape[1]
+    if rep > 1:                 # query head h reads K/V head h // rep
+        kt, vt = jnp.repeat(kt, rep, axis=1), jnp.repeat(vt, rep, axis=1)
     scores = jnp.matmul(qt, jnp.swapaxes(kt, -1, -2)) * sm_scale
     scores = _mask_after(scores, positions)                    # [S,H,1,J]
     probs = jax.nn.softmax(scores.astype(jnp.float32),
@@ -282,7 +285,8 @@ def _paged_attention_reference(q, k_pool, v_pool, table, positions,
 @op_emitter('paged_attention')
 def _paged_attention_emit(ctx, op):
     """One decode step's attention through the page tables: Q
-    [S, 1, H, dh], KPool / VPool [N, pt, H, dh], Table [S, P] int32,
+    [S, 1, H, dh], KPool / VPool [N, pt, KVH, dh] (KVH divides H: query
+    head h reads K/V head h // (H / KVH)), Table [S, P] int32,
     Positions [S] int32, attrs sm_scale and head_axis -> Out
     [S, 1, H, dh]. Lane s attends to its logical positions
     0..Positions[s] (the row appended this step included), which the
@@ -307,7 +311,8 @@ def _paged_attention_emit(ctx, op):
     mesh = getattr(ctx, 'mesh', None)
     axis = op.attr('head_axis', '')
     if mesh is None or axis not in mesh.axis_names \
-            or q.shape[2] % mesh.shape[axis]:
+            or q.shape[2] % mesh.shape[axis] \
+            or k_pool.shape[2] % mesh.shape[axis]:
         axis = None
     on_tpu = jax.default_backend() == 'tpu'
     if _pa.supported(k_pool.shape[1], k_pool.shape[3]) and (

@@ -62,20 +62,23 @@ register_vjp_grad('rms_norm', in_slots=('X', 'Scale'), out_slots=('Y',))
 
 # -- short_conv -------------------------------------------------------------
 
-def _conv_rows(xx, w, n):
+def _conv_rows(xx, w, n, bias=None):
     """xx [..., n + K - 1, C], w [K, C] -> silu of the causal depthwise
-    convolution, [..., n, C]: row t reads xx[t .. t + K - 1], the last
-    of them the token's own."""
+    convolution (plus `bias` [C], where the layer has one), [..., n, C]:
+    row t reads xx[t .. t + K - 1], the last of them the token's own."""
     k = w.shape[0]
     acc = sum(xx[..., j:j + n, :] * w[j] for j in range(k))
+    if bias is not None:
+        acc = acc + bias
     return jax.nn.silu(acc)
 
 
 @op_emitter('short_conv')
 def _short_conv_emit(ctx, op):
     """Causal depthwise convolution of kernel K over the sequence, then
-    silu. X [B, T, C], W [K, C]; row t is sum_j W[j] x[t - (K-1) + j].
-    Three forms, by the inputs given:
+    silu. X [B, T, C], W [K, C], optionally Bias [C] (added before the
+    silu); row t is sum_j W[j] x[t - (K-1) + j]. Three forms, by the
+    inputs given:
 
     whole sequence  no State: zeros stand before each row of the batch.
     chunk           State [slots, K-1, C], Slot [1], Len [1], Reset [1],
@@ -90,15 +93,17 @@ def _short_conv_emit(ctx, op):
     w = ctx.get(op.single_input('W')).astype(x.dtype)
     k = w.shape[0]
     t = x.shape[1]
+    bias = ctx.get(op.single_input('Bias')).astype(x.dtype) \
+        if op.input('Bias') else None
     if not op.input('State'):
         xx = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-        ctx.set(op.single_output('Out'), _conv_rows(xx, w, t))
+        ctx.set(op.single_output('Out'), _conv_rows(xx, w, t, bias))
         return
     state = ctx.get(op.single_input('State'))
     if op.input('Live'):
         live = ctx.get(op.single_input('Live')).astype(bool)
         xx = jnp.concatenate([state, x.astype(state.dtype)], axis=1)
-        ctx.set(op.single_output('Out'), _conv_rows(xx, w, 1))
+        ctx.set(op.single_output('Out'), _conv_rows(xx, w, 1, bias))
         ctx.set(op.single_output('StateOut'),
                 jnp.where(live[:, None, None], xx[:, 1:], state))
         return
@@ -107,7 +112,7 @@ def _short_conv_emit(ctx, op):
     reset = ctx.get(op.single_input('Reset')).astype(bool).reshape(())
     prev = jnp.where(reset, 0.0, state[slot])
     xx = jnp.concatenate([prev, x[0].astype(state.dtype)], axis=0)
-    ctx.set(op.single_output('Out'), _conv_rows(xx, w, t)[None])
+    ctx.set(op.single_output('Out'), _conv_rows(xx, w, t, bias)[None])
     # rows [n, n + K - 1) of xx are inputs n - (K-1) .. n - 1
     tail = jax.lax.dynamic_slice_in_dim(xx, n, k - 1, axis=0)
     ctx.set(op.single_output('StateOut'), state.at[slot].set(tail))

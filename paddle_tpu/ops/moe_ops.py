@@ -145,3 +145,120 @@ def _aux_infer(op, block):
 
 register_op('moe_aux_loss', infer_shape=_aux_infer)
 register_vjp_grad('moe_aux_loss', in_slots=('Gate',))
+
+
+# -- the served expert layer ---------------------------------------------------
+#
+# What a serving chip holds of an expert layer under expert parallelism:
+# `experts_held` consecutive experts of the layer's E, from
+# `expert_offset` on. The router is whole: every token is scored over
+# all E experts and takes its top k of them with no capacity, so no
+# pair of (token, expert) is ever dropped; this chip computes the part
+# of the sum that its own experts give, and nothing stands in for the
+# other chips or for the exchange with them.
+#
+#   s = sigmoid(W_r x);  the top_k largest of s + b;
+#   w = scale * s_sel / sum(s_sel);  r = sum_{e held} w_e W2_e relu(W1_e l)^2
+#
+# with l the token's latent row (the projection down to it and back up
+# are the block's own matmuls, outside this op). The router's product
+# runs at precision "highest", as the published gate computes in
+# float32: a rounded score changes WHICH experts a token takes, not a
+# digit of the result. (A chosen expert's weight is never exactly 0: a
+# sigmoid is not, short of logits under -100.)
+
+def served_weights(x, router_w, bias, top_k, scale):
+    """x [R, D], router_w [D, E], bias [E] -> w [R, E] float32: a row's
+    weight for each expert, 0 for those it did not choose.
+
+    The k largest of s + b are found by rank, not by a sort: an expert
+    is chosen when fewer than k others score higher (an equal score of
+    a lower index counts as higher, lax.top_k's order), one fused
+    compare-and-count over [R, E, E] that the chip runs in tens of
+    microseconds where its top_k takes 0.4 ms for 64 rows of 512."""
+    s = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    b = s + bias.astype(jnp.float32)
+    mine, other = b[:, :, None], b[:, None, :]
+    e = jnp.arange(b.shape[1])
+    ahead = (other > mine) | ((other == mine) & (e[None, :] < e[:, None]))
+    chosen = jnp.sum(ahead, axis=-1) < top_k
+    sel = jnp.where(chosen, s, 0.0)
+    return scale * sel / jnp.sum(sel, -1, keepdims=True)
+
+
+def _relu2(v):
+    return jnp.square(jax.nn.relu(v))
+
+
+def held_experts(lat, w, w1, w2):
+    """lat [R, L], w [R, held], W1 [held, L, F], W2 [held, F, L] ->
+    sum_e w[:, e] W2_e relu(W1_e lat)^2, [R, L]. Every row goes through
+    every held expert in one batched product, a row's result weighted
+    by w (zero where it did not choose the expert): no pair can be
+    dropped, the weights are read once whatever the router chose, and
+    the products hide under that read: on a v5e 64 experts of 1024 x
+    2688 take 1.92 ms at 90 % of the HBM peak for a decode step's 64
+    rows and for a prefill chunk's 256 alike. (Pairs sorted by expert
+    and multiplied by groups, rows x 22 / 8 products in place of rows x
+    64, took 5.0 ms there: each group's weights were copied out of the
+    stack before they were multiplied. PERF.md section 6, PR 36.)"""
+    h = _relu2(jnp.einsum('rl,elf->erf', lat, w1))
+    return jnp.einsum('erf,efl->rl', h * w.T.astype(lat.dtype)[..., None],
+                      w2)
+
+
+@op_emitter('moe_experts')
+def _moe_experts_emit(ctx, op):
+    """The held experts' part of a served expert layer. X [.., D] (what
+    the router scores), Lat [.., L] (what the experts work on), RouterW
+    [D, E], Bias [E], W1 [held, L, F], W2 [held, F, L]; attrs top_k,
+    scale, expert_offset -> Out [.., L]. Rows may be marked dead, by Live [rows] (a decode step's
+    lanes) or Len [1] (a chunk's rows from Len on): they choose nothing
+    and count nothing. Stats [4] int32, where asked for, is this call's
+    (pairs on held experts, held experts with at least one pair, pairs
+    selected here and not computed, 1): the third is 0, there being no
+    capacity to overflow."""
+    x = ctx.get(op.single_input('X'))
+    lat = ctx.get(op.single_input('Lat'))
+    w1 = ctx.get(op.single_input('W1'))
+    w2 = ctx.get(op.single_input('W2'))
+    lead, width = lat.shape[:-1], lat.shape[-1]
+    rows = int(math.prod(lead))
+    offset = int(op.attr('expert_offset', 0))
+    w = served_weights(
+        x.reshape(rows, x.shape[-1]), ctx.get(op.single_input('RouterW')),
+        ctx.get(op.single_input('Bias')), int(op.attr('top_k')),
+        float(op.attr('scale', 1.0)))[:, offset:offset + w1.shape[0]]
+    if op.input('Live'):
+        w = jnp.where(ctx.get(op.single_input('Live')).astype(bool)
+                      .reshape(rows)[:, None], w, 0.0)
+    elif op.input('Len'):
+        n = ctx.get(op.single_input('Len')).astype(jnp.int32).reshape(())
+        w = jnp.where((jnp.arange(rows) < n)[:, None], w, 0.0)
+    out = held_experts(lat.reshape(rows, width), w, w1, w2)
+    ctx.set(op.single_output('Out'), out.reshape(lat.shape))
+    if op.output('Stats'):
+        ctx.set(op.single_output('Stats'), jnp.stack(
+            [jnp.sum(w != 0), jnp.sum(jnp.any(w != 0, axis=0)),
+             0, 1]).astype(jnp.int32))
+
+
+def _moe_experts_infer(op, block):
+    lat = block.var_recursive(op.single_input('Lat'))
+    out = block.var_recursive(op.single_output('Out'))
+    out.shape, out.dtype = lat.shape, lat.dtype
+    if op.output('Stats'):
+        st = block.var_recursive(op.single_output('Stats'))
+        st.shape, st.dtype = (4,), 'int32'
+
+
+def _moe_experts_no_backward(op, block):
+    raise NotImplementedError(
+        'op moe_experts has no backward: the served expert layer is built '
+        'for serving only (train with moe_ffn)')
+
+
+register_op('moe_experts', infer_shape=_moe_experts_infer,
+            grad=_moe_experts_no_backward)

@@ -4,9 +4,11 @@ table and nothing else (the op is `paged_attention`,
 ops/attention_ops.py; the reference lowering there gathers the whole
 window and masks).
 
-One query row per lane: q [S, H, dh], pools [N, pt, H, dh] (the layout
-kv_page_cow/write/append keep), table [S, P] int32, positions [S]
-int32. Lane s attends to logical positions 0..positions[s].
+One query row per lane: q [S, H, dh], pools [N, pt, KVH, dh] (the
+layout kv_page_cow/write/append keep), table [S, P] int32, positions
+[S] int32. Lane s attends to logical positions 0..positions[s]. KVH
+divides H: query head h reads K/V head h // (H / KVH); where the two
+counts are equal every head has its own.
 
 The pools stay in HBM; table and positions are scalar-prefetched. The
 grid is one step per lane, and a lane walks ceil((pos + 1) / pt) pages
@@ -17,13 +19,15 @@ too: a lane's last block starts the next lane's first. Pages past a
 lane's last are never copied.
 
 The heads stay together in a block, so a block is the [C, dh] matrix of
-C = pages * pt * H (token, head) rows and both contractions run on the
-MXU over all of it: scores [H, C] = q . block^T, of which row h keeps
-the columns of its own head (the others are masked like the dead tail
-of the last page) and an online softmax in fp32 (m, l, acc [H, dh])
-folds the blocks. That is H times the multiplies the sum needs, on a
-unit that has nothing else to do in a decode step; what the kernel
-waits for is the pages. The contractions run at Mosaic's default
+C = pages * pt * KVH (token, K/V head) rows and both contractions run
+on the MXU over all of it: scores [H, C] = q . block^T, of which row h
+keeps the columns of its own K/V head (the others are masked like the
+dead tail of the last page) and an online softmax in fp32 (m, l, acc
+[H, dh]) folds the blocks. The H / KVH query heads of one K/V head are
+rows of the one product: a page is read once, not once a query head.
+That is KVH times the multiplies the sum needs, on a unit that has
+nothing else to do in a decode step; what the kernel waits for is the
+pages. The contractions run at Mosaic's default
 precision for float32 operands, one bfloat16 pass like every other
 matmul of the float32 serving path (1.7e-3 of the result on a v5e;
 Precision.HIGHEST reads 1.5e-7 and doubles the kernel's time where few
@@ -54,6 +58,15 @@ _NEG_INF = -1e30
 # where few lanes are live (a block is worked on whole, however few of
 # its pages are).
 _BLOCK_PAGES = 8
+# ... and no fewer than these bytes: a page of 2 K/V heads is 16 KB, and
+# 8 of them a block would be a loop step of fixed cost for 0.3 us of
+# HBM time. (A 16-head page is 128 KB: 8 pages either way.)
+_BLOCK_BYTES = 1 << 19
+
+
+def block_pages(pages_per_slot, page_tokens, kv_heads, head_dim, itemsize=4):
+    page = page_tokens * kv_heads * head_dim * itemsize
+    return min(pages_per_slot, max(_BLOCK_PAGES, _BLOCK_BYTES // page))
 
 
 def supported(page_tokens, head_dim):
@@ -63,10 +76,11 @@ def supported(page_tokens, head_dim):
 
 
 def _kernel(table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sems, slot_ref, *, sm_scale, pt, heads, bp,
-            pages_per_slot, lanes):
+            kbuf, vbuf, sems, slot_ref, *, sm_scale, pt, heads, kv_heads,
+            bp, pages_per_slot, lanes):
     s = pl.program_id(0)
-    cols = bp * pt * heads
+    cols = bp * pt * kv_heads
+    rep = heads // kv_heads
 
     def n_pages(lane):
         return jnp.minimum(pos_ref[lane] // pt + 1, pages_per_slot)
@@ -97,9 +111,10 @@ def _kernel(table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
     n_blk = pl.cdiv(n_pages(s), bp)
     q = q_ref[...].astype(jnp.float32) * sm_scale            # [H, dh]
     col = jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 1)
-    tok = col // heads                      # token of a column, in block
-    own = (col - tok * heads) == \
-        jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 0)
+    tok = col // kv_heads                   # token of a column, in block
+    kv_head = col - tok * kv_heads
+    row = jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 0)
+    own = kv_head == (row if rep == 1 else row // rep)
 
     def block(i, carry):
         m, l, acc = carry
@@ -140,23 +155,26 @@ def _kernel(table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
 @functools.partial(jax.jit, static_argnames=('sm_scale', 'interpret'))
 def paged_attention(q, k_pool, v_pool, table, positions, sm_scale,
                     interpret=False):
-    """q [S, H, dh], pools [N, pt, H, dh], table [S, P] int32,
+    """q [S, H, dh], pools [N, pt, KVH, dh], table [S, P] int32,
     positions [S] int32 -> [S, H, dh]: softmax over lane s's positions
-    0..positions[s] of sm_scale * q . k, times v, in fp32. A table
-    entry is read only below a lane's page count; it must name a page
-    of the pool (the caller clips)."""
+    0..positions[s] of sm_scale * q . k, times v, in fp32, query head h
+    against K/V head h // (H / KVH). A table entry is read only below a
+    lane's page count; it must name a page of the pool (the caller
+    clips)."""
     S, H, dh = q.shape
-    N, pt = k_pool.shape[:2]
+    N, pt, KVH = k_pool.shape[:3]
+    if H % KVH:
+        raise ValueError('%d query heads over %d K/V heads' % (H, KVH))
     P = table.shape[1]
-    bp = min(_BLOCK_PAGES, P)
-    # a page as the [pt * H, dh] matrix it is in memory
-    k3 = k_pool.reshape(N, pt * H, dh)
-    v3 = v_pool.reshape(N, pt * H, dh)
+    bp = block_pages(P, pt, KVH, dh, k_pool.dtype.itemsize)
+    # a page as the [pt * KVH, dh] matrix it is in memory
+    k3 = k_pool.reshape(N, pt * KVH, dh)
+    v3 = v_pool.reshape(N, pt * KVH, dh)
     kernel = functools.partial(
-        _kernel, sm_scale=float(sm_scale), pt=pt, heads=H, bp=bp,
-        pages_per_slot=P, lanes=S)
+        _kernel, sm_scale=float(sm_scale), pt=pt, heads=H, kv_heads=KVH,
+        bp=bp, pages_per_slot=P, lanes=S)
     lane = pl.BlockSpec((None, H, dh), lambda s, *_: (s, 0, 0))
-    buf = pltpu.VMEM((2, bp, pt * H, dh), k_pool.dtype)
+    buf = pltpu.VMEM((2, bp, pt * KVH, dh), k_pool.dtype)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

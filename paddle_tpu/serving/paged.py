@@ -67,7 +67,17 @@ the device);
 serving.state_lanes counter (lanes whose recurrent state the decode
 steps updated; attr `state_lanes` of the same span),
 serving.recurrent_state_bytes and serving.state_resets gauges (bytes
-the recurrent state holds; streams started from zero state so far).
+the recurrent state holds; streams started from zero state so far),
+and serving.<family>.state_bytes for a spec that names its state's
+family (serving.ssm.state_bytes: models/nemotron_h.py).
+What a model's expert layers count they count on the device: a
+program with such layers returns a third fetch, [4] int32 a call. No
+step waits for it: the arrays queue up and moe_counters() (any thread;
+a window's edges, not the loop) sums those whose step has ended into
+the counters serving.moe.pairs, serving.moe.experts_touched,
+serving.moe.pairs_dropped (0: there is no capacity to overflow) and
+serving.moe.layer_calls, each also as serving.moe.decode.* for the
+decode program's share.
 
 Spans (profiler.RecordEvent), the same four in each step:
 `paged.decode.tables` / `paged.prefill.tables` (copy-on-write, page
@@ -84,6 +94,9 @@ save_stream / restore_stream: the recurrent rows' way to the host and
 back.
 """
 from __future__ import annotations
+
+import collections
+import threading
 
 import numpy as np
 
@@ -106,6 +119,7 @@ _decode_pages_window = telemetry.counter('serving.decode_pages_window')
 _state_lanes = telemetry.counter('serving.state_lanes')
 _state_bytes = telemetry.gauge('serving.recurrent_state_bytes')
 _state_resets = telemetry.gauge('serving.state_resets')
+_MOE_COUNTS = ('pairs', 'experts_touched', 'pairs_dropped', 'layer_calls')
 
 
 def _set_row(state, slot, rows):
@@ -275,6 +289,38 @@ class PagedDecodePredictor(object):
         return 4 * len(self._pair.spec.recurrent_layers) * int(
             sum(np.prod(s) for s in shapes))
 
+    def _run(self, program, feed, fetches, decode):
+        """One run of a program of the pair -> (logits, ids); a third
+        fetch, the expert layers' counts, is queued for moe_counters()."""
+        out = self._exe.run(program, feed=feed, fetch_list=fetches,
+                            scope=self._scope, return_numpy=False)
+        if len(out) > 2:
+            self._moe_queue.append((decode, out[2]))
+            self.moe_counters(leave=64)     # nobody asks: keep it short
+        return out[0], out[1]
+
+    def moe_counters(self, leave=0):
+        """What the expert layers have counted since reset(), over the
+        steps that have ended: {'pairs', 'experts_touched',
+        'pairs_dropped', 'layer_calls'} over both programs and
+        'decode.<same>' for the decode program alone; {} for a model
+        without expert layers. Brings the serving.moe.* counters up to
+        date. A step still running is left for the next call, as are
+        the newest `leave` (a step's own call: one small transfer a
+        step, of a step long ended)."""
+        if len(self._pair.decode_fetches) < 3:
+            return {}
+        with self._moe_lock:
+            while len(self._moe_queue) > leave \
+                    and self._moe_queue[0][1].is_ready():
+                decode, counts = self._moe_queue.popleft()
+                for what, n in zip(_MOE_COUNTS, np.asarray(counts)):
+                    for key in (what, 'decode.' + what) if decode \
+                            else (what,):
+                        self._moe_totals[key] += int(n)
+                        telemetry.counter('serving.moe.' + key).inc(int(n))
+            return dict(self._moe_totals)
+
     def pool_stats(self):
         return {'page_tokens': self.page_tokens,
                 'recurrent_state_bytes': self._recurrent_state_bytes(),
@@ -439,6 +485,10 @@ class PagedDecodePredictor(object):
             for name, shape in zip(spec.state_names(layer),
                                    spec.state_shapes(self.slots)):
                 self._scope.set_var(name, np.zeros(shape, np.float32))
+        self._moe_queue = collections.deque()   # (decode?, counts [4])
+        self._moe_lock = threading.Lock()
+        self._moe_totals = {p + what: 0 for what in _MOE_COUNTS
+                            for p in ('', 'decode.')}
         self._pool = PagePool(self.num_pages, self.page_tokens)
         self._prefix = PrefixCache(self._pool)
         self._pool.set_evict(self._prefix.evict_one)
@@ -450,6 +500,9 @@ class PagedDecodePredictor(object):
         self._last_ids = np.zeros((self.slots,), np.int64)
         self._in_flight = False       # a deferred step awaits its fetch
         _state_bytes.set(self._recurrent_state_bytes())
+        if spec.state_family:
+            telemetry.gauge('serving.%s.state_bytes' % spec.state_family) \
+                .set(self._recurrent_state_bytes())
         self._update_gauges()
 
     def _place_cache(self, name, value):
@@ -727,7 +780,7 @@ class PagedDecodePredictor(object):
                     'prefill_page_table': table_feed,
                     'prefill_cow_src': cow_src,
                     'prefill_cow_dst': cow_dst}
-            if self.recurrent:
+            if 'prefill_state_slot' in self._pair.prefill_feeds:
                 # a stream's first chunk starts its slot from zero state
                 feed['prefill_state_slot'] = np.array([slot], np.int32)
                 feed['prefill_state_reset'] = \
@@ -735,10 +788,8 @@ class PagedDecodePredictor(object):
                 if start == 0:
                     self._resets += 1
                     _state_resets.set(self._resets)
-        logits, ids = self._exe.run(
-            self._pair.prefill_program, feed=feed,
-            fetch_list=self._pair.prefill_fetches,
-            scope=self._scope, return_numpy=False)
+        logits, ids = self._run(self._pair.prefill_program, feed,
+                                self._pair.prefill_fetches, False)
         with RecordEvent('paged.prefill.book'):
             for table_, _idx, (src, _dst) in cows:
                 table_.pool.unref(src)
@@ -853,16 +904,14 @@ class PagedDecodePredictor(object):
                     'decode_page_table': table_feed,
                     'decode_cow_src': cow_src,
                     'decode_cow_dst': cow_dst}
-            if self.recurrent:
+            if 'decode_state_live' in self._pair.decode_feeds:
                 state_live = np.zeros((S,), np.int32)
                 state_live[live] = 1
                 feed['decode_state_live'] = state_live
                 ev.attrs['state_lanes'] = len(live)
                 _state_lanes.inc(len(live))
-        logits, ids = self._exe.run(
-            self._pair.decode_program, feed=feed,
-            fetch_list=self._pair.decode_fetches,
-            scope=self._scope, return_numpy=False)
+        logits, ids = self._run(self._pair.decode_program, feed,
+                                self._pair.decode_fetches, True)
         with RecordEvent('paged.decode.book'):
             for table, _idx, (src, _dst) in cows:
                 table.pool.unref(src)

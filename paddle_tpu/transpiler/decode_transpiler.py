@@ -35,8 +35,24 @@ K/V pools for the full-attention layers and per-slot recurrent state
 for the others. Speculative decoding,
 page shipping and mesh serving refuse it by the layer kind's name.
 
+A third is the three-kind hybrid of models/nemotron_h.py
+(`nemotron_h.language_model_logits`): lookup_table, no position op,
+every layer one rms_norm and one mixer, told apart by its marker op —
+  mamba           [in mul, short_conv (with Bias), ssd_chunk,
+                  gated_group_norm, out mul]
+  experts         [down mul, moe_experts, up mul, shared-up mul,
+                  shared-down mul]
+  full_attention  [qkv mul, matmul/causal_mask/softmax, proj mul]
+then a final rms_norm and the lm_head mul. Its spec
+(NemotronHDecodeSpec) carries the kinds, the sizes of each and what the
+expert op was told it holds (experts_held, expert_offset: attributes
+of the model, read back from the op); K/V heads fewer than query heads
+are read from the qkv weight's width. The refusals above hold for it
+alike: they ask whether a layer holds recurrent state, not its name.
+
 Genuinely
-unsupported layouts (MoE expert-sharded FFN, ring attention, a
+unsupported layouts (the training MoE op moe_ffn, whose capacity drops
+tokens; ring attention; a
 constraint on an axis the serving mesh cannot honor) still raise
 DecodeTranspileError naming the offending op/axis — better a loud
 refusal at prepare time than a silently wrong cache layout at serve
@@ -44,7 +60,7 @@ time.
 """
 from __future__ import annotations
 
-from ..models import hybrid
+from ..models import hybrid, nemotron_h
 from ..models.transformer import (DecodeSpec, DecodeTranspileError,
                                   refuse_recurrent, build_verify_program)
 
@@ -320,9 +336,131 @@ def _extract_hybrid_spec(block):
     return spec
 
 
+_NEMOTRON_MARKERS = {'ssd_chunk': 'mamba', 'moe_experts': 'experts',
+                     'causal_mask': 'full_attention'}
+
+
+def _extract_nemotron_spec(block):
+    """The three-kind hybrid's spec (see the module docstring): the ops
+    between one rms_norm and the next are one layer, whose kind its
+    marker op gives and whose sizes the marker's attributes and the
+    weights' shapes give."""
+    def shape(name):
+        return tuple(int(d) for d in block.var_recursive(name).shape)
+
+    emb_w = ids = None
+    layers = []                 # [norm scale, [ops until the next norm]]
+    eps = 1e-5
+    for op in block.ops:
+        t = op.type
+        if t == 'lookup_table' and emb_w is None:
+            emb_w, ids = op.single_input('W'), op.single_input('Ids')
+        elif t == 'rms_norm':
+            layers.append([op.single_input('Scale'), []])
+            eps = op.attr('epsilon', eps)
+        elif t in ('layer_norm', 'position_embedding', 'moe_ffn',
+                   'gated_delta_chunk', 'flash_attention',
+                   'ring_attention'):
+            _fail('op %s inside a model with ssd_chunk or moe_experts '
+                  'layers: not the block of models/nemotron_h.py' % t)
+        elif layers:
+            layers[-1][1].append(op)
+    if emb_w is None:
+        _fail('no lookup_table op (token embedding)')
+    if len(layers) < 2:
+        _fail('no rms_norm before a mixer and before the head')
+    (final_norm, tail), layers = layers[-1], layers[:-1]
+    head = [op.single_input('Y') for op in tail if op.type == 'mul']
+    if len(head) != 1:
+        _fail('%d mul ops after the final rms_norm (want the head)'
+              % len(head))
+    vocab, dim = shape(emb_w)
+    cfg = dict(vocab=vocab, dim=dim, max_len=shape(ids)[1], eps=eps)
+    sizes = {}
+
+    def agree(kind, i, got):
+        if sizes.setdefault(kind, got) != got:
+            _fail('layer %d: %s sizes %r differ from %r'
+                  % (i, kind, got, sizes[kind]))
+        cfg.update(got)
+
+    blocks, kinds = [], []
+    for i, (norm, ops) in enumerate(layers):
+        marks = [op for op in ops if op.type in _NEMOTRON_MARKERS]
+        if len(marks) != 1:
+            _fail('layer %d: %d marker ops (%s) between two rms_norm ops, '
+                  'want one' % (i, len(marks), [m.type for m in marks]))
+        mark, kind = marks[0], _NEMOTRON_MARKERS[marks[0].type]
+        muls = [(op.single_input('Y'), None) for op in ops
+                if op.type == 'mul']
+        want = {'mamba': 2, 'experts': 4, 'full_attention': 2}[kind]
+        if len(muls) != want:
+            _fail('layer %d (%s): %d mul ops, want %d'
+                  % (i, kind, len(muls), want))
+        blk = {'norm': norm}
+        if kind == 'mamba':
+            conv = [op for op in ops if op.type == 'short_conv']
+            gate = [op for op in ops if op.type == 'gated_group_norm']
+            if len(conv) != 1 or not conv[0].input('Bias') \
+                    or len(gate) != 1:
+                _fail('layer %d: ssd_chunk without one short_conv with a '
+                      'Bias and one gated_group_norm' % i)
+            blk.update({'in': muls[0], 'out': muls[1],
+                        'conv': conv[0].single_input('W'),
+                        'conv_bias': conv[0].single_input('Bias'),
+                        'a_log': mark.single_input('ALog'),
+                        'dt_bias': mark.single_input('DtBias'),
+                        'd': mark.single_input('D'),
+                        'gate_norm': gate[0].single_input('Scale')})
+            agree(kind, i, dict(
+                mamba_heads=int(mark.attr('heads')),
+                mamba_head_dim=int(mark.attr('head_dim')),
+                groups=int(mark.attr('groups')),
+                state=int(mark.attr('state')),
+                chunk=int(mark.attr('block', 128)),
+                conv_kernel=shape(blk['conv'])[0]))
+        elif kind == 'experts':
+            blk.update({'down': muls[0], 'up': muls[1],
+                        'shared_up': muls[2], 'shared_down': muls[3],
+                        'router': mark.single_input('RouterW'),
+                        'bias': mark.single_input('Bias'),
+                        'w1': mark.single_input('W1'),
+                        'w2': mark.single_input('W2')})
+            held, latent, ffn = shape(blk['w1'])
+            agree(kind, i, dict(
+                experts=shape(blk['router'])[1], experts_held=held,
+                expert_offset=int(mark.attr('expert_offset', 0)),
+                top_k=int(mark.attr('top_k')),
+                routed_scale=float(mark.attr('scale', 1.0)),
+                latent=latent, expert_ffn=ffn,
+                shared_ffn=shape(muls[2][0])[1]))
+        else:
+            blk.update({'qkv': muls[0], 'proj': muls[1]})
+            heads = int(block.var_recursive(
+                mark.single_input('X')).shape[1])
+            width, wide = shape(muls[1][0])[0], shape(muls[0][0])[1]
+            if width % heads or (wide - width) % (2 * (width // heads)):
+                _fail('layer %d: qkv weight %r and proj weight %r do not '
+                      'split into %d query heads and whole K/V heads'
+                      % (i, shape(muls[0][0]), shape(muls[1][0]), heads))
+            dh = width // heads
+            agree(kind, i, dict(heads=heads, head_dim=dh,
+                                kv_heads=(wide - width) // (2 * dh)))
+        blocks.append(blk)
+        kinds.append(kind)
+    spec = nemotron_h.NemotronHDecodeSpec(
+        nemotron_h.NemotronHConfig(layer_types=kinds, **cfg),
+        emb_w=emb_w, blocks=blocks, final_norm=final_norm,
+        head=(head[0], None))
+    spec.param_specs = {n: None for n in spec.param_names()}
+    return spec
+
+
 def extract_decode_spec(program):
     """Scan the loaded program and return its DecodeSpec."""
     block = program.global_block()
+    if any(op.type in ('ssd_chunk', 'moe_experts') for op in block.ops):
+        return _extract_nemotron_spec(block)
     if any(op.type == 'rms_norm' for op in block.ops):
         return _extract_hybrid_spec(block)
     emb_w = pos_w = None
@@ -351,8 +489,10 @@ def extract_decode_spec(program):
         elif t == 'flash_attention':
             use_flash = True
         elif t == 'moe_ffn':
-            _fail('op moe_ffn: expert-sharded (ep) MoE FFN has no '
-                  'cached-decode equivalent')
+            _fail('op moe_ffn: the training expert layer drops the pairs '
+                  'over its capacity, which a served stream may not; '
+                  'the served expert layer is op moe_experts '
+                  '(models/nemotron_h.py)')
         elif t == 'ring_attention':
             _fail('op ring_attention: sp-ring attention has no '
                   'cached-decode equivalent (serve with the paged '

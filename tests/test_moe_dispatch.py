@@ -158,3 +158,130 @@ def test_moe_topk_on_ep_mesh():
     l2, = pe.run(fetch_list=[loss.name], feed={'x': xv})
     assert np.isfinite(np.asarray(l1)).all()
     assert not np.allclose(np.asarray(l1), np.asarray(l2))
+
+
+# -- the served expert layer (op moe_experts) ---------------------------------
+
+def _served_case(rows, experts, held, offset, k, seed=0, d=24, lat=16, f=20):
+    rng = np.random.default_rng(seed)
+    return dict(
+        x=rng.normal(size=(rows, d)).astype('f4'),
+        lat=rng.normal(size=(rows, lat)).astype('f4'),
+        router=(rng.normal(size=(d, experts)) / math.sqrt(d)).astype('f4'),
+        bias=np.zeros(experts, 'f4'),
+        w1=rng.normal(size=(held, lat, f)).astype('f4'),
+        w2=rng.normal(size=(held, f, lat)).astype('f4'),
+        k=k, offset=offset)
+
+
+def _served_op(case, live=None, length=None):
+    """moe_experts through the executor -> (out [rows, lat], stats [4])."""
+    prog, startup = Program(), Program()
+    feed = {n: case[n] for n in ('x', 'lat', 'router', 'bias', 'w1', 'w2')}
+    with program_guard(prog, startup):
+        v = {n: fluid.layers.data(n, list(a.shape), dtype='float32',
+                                  append_batch_size=False)
+             for n, a in feed.items()}
+        ins = {'X': [v['x']], 'Lat': [v['lat']], 'RouterW': [v['router']],
+               'Bias': [v['bias']], 'W1': [v['w1']], 'W2': [v['w2']]}
+        for slot, name, value in (('Live', 'live', live),
+                                  ('Len', 'len', length)):
+            if value is not None:
+                feed[name] = np.asarray(value, 'i4')
+                ins[slot] = [fluid.layers.data(
+                    name, list(feed[name].shape), dtype='int32',
+                    append_batch_size=False)]
+        block = prog.global_block()
+        out = block.create_var(name='out', dtype='float32')
+        stats = block.create_var(name='stats', dtype='int32')
+        block.append_op(type='moe_experts', inputs=ins,
+                        outputs={'Out': [out], 'Stats': [stats]},
+                        attrs={'top_k': case['k'], 'scale': 2.5,
+                               'expert_offset': case['offset']})
+    return fluid.Executor(fluid.CPUPlace()).run(prog, feed=feed,
+                                                fetch_list=[out, stats])
+
+
+def _served_numpy(case, rows_live=None):
+    """The layer's definition, a loop over rows and their experts, in
+    float64: sigmoid scores, the k largest, renormalised and scaled."""
+    x, lat = case['x'].astype(np.float64), case['lat'].astype(np.float64)
+    s = 1 / (1 + np.exp(-(x @ case['router'].astype(np.float64))))
+    out = np.zeros_like(lat)
+    pairs, touched = 0, set()
+    for r in range(x.shape[0]):
+        if rows_live is not None and not rows_live[r]:
+            continue
+        top = np.argsort(-s[r])[:case['k']]
+        for e in top:
+            j = e - case['offset']
+            if 0 <= j < case['w1'].shape[0]:
+                h = np.maximum(lat[r] @ case['w1'][j], 0) ** 2
+                out[r] += 2.5 * s[r, e] / s[r, top].sum() \
+                    * (h @ case['w2'][j])
+                pairs += 1
+                touched.add(j)
+    return out, pairs, len(touched)
+
+
+@pytest.mark.parametrize('rows,experts,held,offset,k', [
+    (37, 512, 64, 128, 22),     # the published router, a chip's share
+    (5, 32, 32, 0, 22),         # every expert held: the uncut layer
+    (64, 32, 4, 28, 22),        # the last share; most rows choose it all
+])
+def test_served_experts_drop_no_pair(rows, experts, held, offset, k):
+    """Top-22 with no capacity: every pair of a row and a held expert
+    has its product in the sum, whatever the rows chose, and the count
+    of pairs not computed is 0."""
+    case = _served_case(rows, experts, held, offset, k, seed=rows)
+    want, pairs, touched = _served_numpy(case)
+    out, stats = _served_op(case)
+    assert np.abs(out - want).max() <= 1e-4 * np.abs(want).max()
+    assert list(stats) == [pairs, touched, 0, 1]
+    assert pairs > 0
+
+
+def test_served_experts_dead_rows_choose_nothing():
+    case = _served_case(12, 32, 8, 8, 22, seed=3)
+    live = np.array([1, 0, 1, 1, 0, 0, 1, 1, 1, 0, 1, 1])
+    want, pairs, touched = _served_numpy(case, live)
+    out, stats = _served_op(case, live=live)
+    assert np.abs(out - want).max() <= 1e-4 * np.abs(want).max()
+    assert not out[live == 0].any()
+    assert list(stats) == [pairs, touched, 0, 1]
+    head = np.arange(12) < 7                     # a chunk's padded tail
+    want, pairs, touched = _served_numpy(case, head)
+    out, stats = _served_op(case, length=[7])
+    assert np.abs(out - want).max() <= 1e-4 * np.abs(want).max()
+    assert list(stats) == [pairs, touched, 0, 1]
+
+
+def test_moe_ffn_is_refused_by_the_decode_transpiler_by_its_drops():
+    """The training op stays refused, and the message says why and names
+    the served op."""
+    from paddle_tpu.transpiler.decode_transpiler import (
+        DecodeTranspileError, extract_decode_spec)
+    prog, _, _ = _moe_prog(4, 1, 'topk')
+    with pytest.raises(DecodeTranspileError, match='drops.*moe_experts'):
+        extract_decode_spec(prog)
+
+
+def test_served_experts_have_no_backward_and_say_so():
+    case = _served_case(4, 8, 8, 0, 2)
+    prog, startup = Program(), Program()
+    with program_guard(prog, startup):
+        v = {n: fluid.layers.data(n, list(case[n].shape), dtype='float32',
+                                  append_batch_size=False)
+             for n in ('x', 'lat', 'router', 'bias', 'w1', 'w2')}
+        v['lat'].stop_gradient = False
+        out = prog.global_block().create_var(name='out', dtype='float32')
+        out.stop_gradient = False
+        prog.global_block().append_op(
+            type='moe_experts',
+            inputs={'X': [v['x']], 'Lat': [v['lat']],
+                    'RouterW': [v['router']], 'Bias': [v['bias']],
+                    'W1': [v['w1']], 'W2': [v['w2']]},
+            outputs={'Out': [out]}, attrs={'top_k': 2})
+        loss = fluid.layers.mean(out)
+        with pytest.raises(NotImplementedError, match='moe_experts'):
+            fluid.backward.append_backward(loss)

@@ -381,6 +381,15 @@ COVERED_ELSEWHERE = {
     'short_conv': ('test_delta_rule_ops.py',
                    'test_no_backward_and_the_error_names_the_op',
                    'serving only: its grad maker raises by name'),
+    'ssd_chunk': ('test_ssd_ops.py',
+                  'test_no_backward_and_the_error_names_the_op',
+                  'serving only: its grad maker raises by name'),
+    'gated_group_norm': ('test_ssd_ops.py',
+                         'test_no_backward_and_the_error_names_the_op',
+                         'serving only: its grad maker raises by name'),
+    'moe_experts': ('test_moe_dispatch.py',
+                    'test_served_experts_have_no_backward_and_say_so',
+                    'serving only: its grad maker raises by name'),
     'flash_attention': ('test_flash_attention.py',
                         'test_kernel_grads_match_naive',
                         'grad parity vs naive reference'),

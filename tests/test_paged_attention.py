@@ -181,6 +181,61 @@ def test_reference_lowering_is_the_old_composition_bit_for_bit(heads, dh,
 
 
 # ---------------------------------------------------------------------------
+# K/V heads fewer than query heads
+# ---------------------------------------------------------------------------
+
+def _dense_attention(q, kpool, vpool, table, positions):
+    """Each lane's attention over its own tokens, a query head against
+    K/V head h // (H / KVH), in float64 numpy: no pages, no kernel."""
+    pt, rep = kpool.shape[1], q.shape[2] // kpool.shape[2]
+    out = np.zeros(q.shape, np.float64)
+    for s, pos in enumerate(positions):
+        n = int(pos) + 1
+        rows = [(table[s, j // pt], j % pt) for j in range(n)]
+        k = np.stack([kpool[p, o] for p, o in rows]).astype(np.float64)
+        v = np.stack([vpool[p, o] for p, o in rows]).astype(np.float64)
+        for h in range(q.shape[2]):
+            sc = k[:, h // rep] @ q[s, 0, h].astype(np.float64) \
+                / np.sqrt(q.shape[-1])
+            w = np.exp(sc - sc.max())
+            out[s, 0, h] = (w / w.sum()) @ v[:, h // rep]
+    return out
+
+
+@pytest.mark.parametrize('heads,kv_heads,lengths', [
+    (32, 2, [1, PT, PT + 3, 11 * PT, 0, 70]),   # 16 query rows a K/V head
+    (8, 4, [5, 88, 0, 17]),
+    (4, 1, [9 * PT - 2, 1]),                    # every query on one head
+])
+def test_fewer_kv_heads_kernel_and_lowering_are_dense_attention(
+        heads, kv_heads, lengths, interpret_kernel):
+    rng = np.random.default_rng(heads)
+    n_pages = 1 + sum(-(-n // PT) for n in lengths) + 3
+    kpool, vpool = _pools(rng, n_pages, PT, kv_heads)
+    table, positions = _tables(rng, lengths, PT, 11, n_pages)
+    q = rng.standard_normal((len(lengths), 1, heads, DH)).astype('f4')
+    want = _dense_attention(q, kpool, vpool, table, positions)
+    live = np.array(lengths) > 0          # an idle lane's row is not read
+    kernel = _run_op(q, kpool, vpool, table, positions)
+    fluid.set_flags({'pallas_interpret': False})
+    lowering = _run_op(q, kpool, vpool, table, positions)
+    for got in (kernel, lowering):
+        assert got.shape == q.shape
+        assert np.abs(got - want)[live].max() <= TOL * np.abs(want).max()
+
+
+def test_equal_head_counts_read_the_blocks_they_read():
+    """The accepted cells' pools (16 and 32 heads a page of 16 tokens)
+    keep their 8-page blocks; 2 K/V heads a page take more pages a
+    block, for the same bytes."""
+    from paddle_tpu.pallas import paged_attention as pa
+    assert pa.block_pages(128, 16, 16, 128) == 8
+    assert pa.block_pages(192, 16, 32, 128) == 8
+    assert pa.block_pages(128, 16, 2, 128) == 32
+    assert pa.block_pages(4, 16, 2, 128) == 4
+
+
+# ---------------------------------------------------------------------------
 # the programs, and the counter
 # ---------------------------------------------------------------------------
 
