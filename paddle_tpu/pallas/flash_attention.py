@@ -19,13 +19,17 @@ guards and falls back to XLA otherwise); d should be a lane multiple
 (128) for MXU alignment.
 
 Forward grid (bh, qi, ki), ki innermost: the (m, l, o) accumulators for
-one q block live in VMEM scratch across the ki sweep; causal q-blocks
-stop their sweep at the diagonal (pl.when skips both compute and the
-write until the final valid ki). That is the `online` arm; a second
-`twopass` arm (PADDLE_FLASH_FWD, round 6) splits the sweep into a
-stats pass (row max + lse only, no V traffic) and a 1-exp rescale-free
-accumulation pass — the stored-lse trick the backward already uses —
-see the forward-arm comment block below.
+one q block live in VMEM scratch across the ki sweep (m and l
+lane-replicated [bq, 128], the layout a row reduction is born in);
+causal q-blocks stop their sweep at the diagonal (pl.when skips both
+compute and the write until the final valid ki), and a K block is
+walked in chunks of _FWD_CHUNK_K keys. That is the `online` arm, what
+every shape gets (blocks from _BLOCK_TABLE_FWD). A second `twopass`
+arm (PADDLE_FLASH_FWD) splits the sweep into a stats pass (row max +
+lse only, no V traffic) and a 1-exp rescale-free accumulation pass;
+on this chip at BH=64, T=2048, d=128 it reads 3.2-3.7 ms a call
+against the online arm's 0.79 (PERF.md section 6, PR 37) and stays
+only as the tools' A/B hook -- see the forward-arm comment block below.
 
 Backward: delta = rowsum(dO·O) in plain JAX, then the KV-MAJOR
 single-pass kernel (grid (bh, ki, qi), both inner dims sequential;
@@ -64,6 +68,11 @@ _NEG_INF = -1e30
 # [T, T] contraction under a flash label.
 _ROUTE_KERNEL = _tm.counter('pallas.flash.kernel')
 _ROUTE_NAIVE = _tm.counter('pallas.flash.naive')
+# ... and which forward schedule _fwd compiled, once per trace of _fwd:
+# a call its cache answers (the next layer, the grad op's re-trace of
+# the forward) does not count again
+_FWD_SCHEDULE = {'online': _tm.counter('pallas.flash.fwd.online'),
+                 'twopass': _tm.counter('pallas.flash.fwd.twopass')}
 
 # Backward-arm selection. Three arms, all grad-parity-tested:
 #   split    — dq kernel + dk/dv kernel (7 block-matmuls, 2 exp streams)
@@ -110,11 +119,14 @@ _RESOLVED_ARM = ''
 #              one extra QK matmul/read (the kernel is VPU-bound, and
 #              the kvmajor clamp A/B proved skipped-block DMAs hide
 #              under compute) for the whole [bq, d] corr/rescale chain.
-# PADDLE_FLASH_FWD=online|twopass forces an arm; default stays online
-# until a chip A/B ranks them (PERF.md round 6 — the earlier round-5
-# 'boundmax' fwd attempt was dropped for a 4x dq-parity loss; the
-# stored-lse schedule has no such mantissa hazard because lse is exact,
-# not a slack bound).
+# PADDLE_FLASH_FWD=online|twopass forces an arm; every shape gets
+# online. Ranked on this chip at BH=64, T=2048, d=128 bf16 causal
+# (PERF.md section 6, PR 37): twopass 3.67 ms a call at (512, 512)
+# blocks, 3.20 at (1024, 1024) -- three products and two exp streams
+# over 1-D statistics -- against 2.67 for the online kernel as it then
+# was and 0.79 as it is. (The earlier round-5 'boundmax' fwd attempt
+# was dropped for a 4x dq-parity loss; the stored-lse schedule has no
+# such mantissa hazard because lse is exact, not a slack bound.)
 _FWD_ARMS = ('', 'online', 'twopass')
 _FORCE_FWD_ARM = _os.environ.get('PADDLE_FLASH_FWD', '').strip().lower()
 if _FORCE_FWD_ARM not in _FWD_ARMS:
@@ -124,8 +136,10 @@ if _FORCE_FWD_ARM not in _FWD_ARMS:
                      % (_FORCE_FWD_ARM, _FWD_ARMS[1:]))
 # the arm _fwd actually dispatched at its last trace — the twopass
 # residency guard may silently swap a forced arm for 'online', so
-# measurement tools must cross-check this before ranking
+# measurement tools must cross-check this before ranking; and the
+# (block_q, block_k) that trace ran with
 _RESOLVED_FWD_ARM = ''
+_RESOLVED_FWD_BLOCKS = ()
 
 # clamp block index maps during causally-skipped grid steps so the
 # dead prefetch DMAs are elided (trace-time; off only for A/B)
@@ -149,62 +163,117 @@ def _mask_if_straddling(s, qi, ki, block_q, block_k):
                         masked, lambda s_: s_, s)
 
 
+# The online kernel walks a K block in chunks of at most this many keys:
+# a [bq, 512] score tile keeps the per-chunk work long enough to hide the
+# statistics' [bq, 128] passes and short enough for large K blocks (few
+# grid steps, few finalizations) to stay in VMEM. Chip A/B at BH=64,
+# T=2048, d=128: (1024, 1024) blocks 0.885 ms whole, 0.796 ms in chunks
+# of 512; chunks of 256 and 128 lose (PERF.md section 6, PR 37).
+_FWD_CHUNK_K = 512
+
+
+def _lanes(x, n):
+    """A per-row statistic, lane-replicated [rows, 128], as [rows, n]."""
+    if n == 128:
+        return x
+    if n % 128 == 0:
+        return jnp.tile(x, (1, n // 128))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, sm_scale, causal, block_q,
-                block_k, nk):
+                m_scr, l_scr, acc_scr, q_scr, *, sm_scale, causal,
+                block_q, block_k, nk):
+    """One (q block, K block) pair of the online-softmax sweep.
+
+    The running maximum `m` and sum `l` live lane-replicated in
+    [bq, 128] scratch: a row reduction's result is born a column, and
+    every use of it here (against the [bq, 128] scratch, against each
+    128-lane slice of the scores, against the [bq, d] accumulator) takes
+    it in that layout. (As 1-D `(bq,)` vectors each pair paid four
+    sublane<->lane relayouts of bq values: 2.67 ms a call at BH=64,
+    T=2048 against 1.17 ms, same blocks, same arithmetic.) No row is
+    guarded inside the sweep: a row the mask hides entirely so far has
+    m = -1e30 and garbage in l and acc, and the first pair that shows it
+    a key multiplies both by exp(-1e30 - m_new) = 0; a row hidden to the
+    end is zeroed in _finalize, as before."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    d = q_ref.shape[-1]
     last_ki = nk - 1
     if causal:
         last_ki = ((qi + 1) * block_q - 1) // block_k
+    ck = _FWD_CHUNK_K if block_k % _FWD_CHUNK_K == 0 else block_k
 
     @pl.when(ki == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
+        # once a q block, not once a pair (input dtype, as the backward
+        # recomputes it)
+        q_scr[:] = q_ref[0] * sm_scale
 
-    @pl.when(ki <= last_ki)
-    def _step():
-        q = q_ref[0] * sm_scale          # [bq, d] (input dtype)
-        k = k_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [bq, bk]
-        if causal:
-            # the kernel is VPU-bound (PERF.md round-4 flash ladder):
-            # only diagonal-straddling blocks pay for the iota mask —
-            # interior visited blocks are fully visible and skip the
-            # elementwise mask passes entirely
-            s = _mask_if_straddling(s, qi, ki, block_q, block_k)
-        m_prev = m_scr[:]
-        blk_max = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, blk_max)
-        safe_m = jnp.where(m_new <= _NEG_INF / 2, 0.0, m_new)
-        corr = jnp.exp(jnp.where(m_prev <= _NEG_INF / 2, safe_m, m_prev)
-                       - safe_m)
-        # no second mask on p: masked s = -1e30, and exp(-1e30 - m)
-        # underflows to exactly 0 for any finite (or zeroed) safe_m
-        # (an MXU p@1 rewrite of this lane-axis sum was A/B'd and
-        # LOSES ~10% — PERF.md round-5 fwd-kernel probe)
-        p = jnp.exp(s - safe_m[:, None])
-        l_new = l_scr[:] * corr + jnp.sum(p, axis=1)
-        acc = acc_scr[:] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
-        l_scr[:] = l_new
-        acc_scr[:] = acc
+    def sweep(masked):
+        q = q_scr[:]                                  # [bq, d]
+        for c in range(block_k // ck):
+            k = k_ref[0, c * ck:(c + 1) * ck, :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)   # [bq, ck]
+            if masked:
+                q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, ck), 0)
+                k_pos = ki * block_k + c * ck + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, ck), 1)
+                s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            m_prev = m_scr[:]                         # [bq, 128]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            # no second mask on p: masked s = -1e30, and exp(-1e30 - m)
+            # underflows to exactly 0 for any finite m
+            # (an MXU p@1 rewrite of this lane-axis sum was A/B'd and
+            # LOSES ~10% — PERF.md round-5 fwd-kernel probe)
+            p = jnp.exp(s - _lanes(m_new, ck))
+            l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=1,
+                                                 keepdims=True)
+            m_scr[:] = m_new
+            acc_scr[:] = acc_scr[:] * _lanes(corr, d) + \
+                jax.lax.dot_general(
+                    p.astype(v_ref.dtype), v_ref[0, c * ck:(c + 1) * ck, :],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+
+    if causal:
+        # the kernel is VPU-bound (PERF.md round-4 flash ladder): only
+        # diagonal-straddling blocks pay for the iota mask. Two bodies
+        # under pl.when, not a cond that yields the [bq, bk] tile.
+        straddles = ki * block_k + block_k - 1 > qi * block_q
+        pl.when((ki <= last_ki) & straddles)(
+            functools.partial(sweep, True))
+        pl.when((ki <= last_ki) & jnp.logical_not(straddles))(
+            functools.partial(sweep, False))
+    else:
+        sweep(False)
 
     @pl.when(ki == last_ki)
     def _finalize():
-        l = l_scr[:]
-        safe_l = jnp.maximum(l, 1e-30)
-        o_ref[0] = (acc_scr[:] / safe_l[:, None]).astype(o_ref.dtype)
         m = m_scr[:]
-        lse = jnp.where(m <= _NEG_INF / 2, _NEG_INF,
-                        m + jnp.log(safe_l))
-        lse_ref[0] = lse[:, None]
+        safe_l = jnp.maximum(l_scr[:], 1e-30)
+        hidden = m <= _NEG_INF / 2
+        o = acc_scr[:] / _lanes(safe_l, d)
+        o_ref[0] = jnp.where(_lanes(hidden, d), 0.0, o).astype(o_ref.dtype)
+        lse = jnp.where(hidden, _NEG_INF, m + jnp.log(safe_l))
+        if lse_ref.shape[-1] == 1:
+            lse_ref[0] = lse[:, :1]
+        else:
+            # lse leaves as a [1, bq] row (see _fwd_online): the
+            # transpose of a [128, 128] slice of the lane-replicated
+            # value holds its 128 rows along the lanes of every row
+            lse_ref[0] = jnp.concatenate(
+                [lse[r:r + 128, :].T[:1, :]
+                 for r in range(0, block_q, 128)], axis=1)
 
 
 def _fwd_stats_kernel(q_ref, k_ref, lse_ref, m_scr, l_scr, *, sm_scale,
@@ -562,8 +631,17 @@ _BLOCK_TABLE = {
 # per-block corr/rescale chain amortizes with bigger blocks: fwd-only
 # sweep at T=8192 ranks (1024, 1024) 5.26 ms vs the shared-table
 # (512, 1024) 5.88 ms (~10%, 3 interleaved rounds; PERF.md round-5).
+#
+# (2048, 128), the training cells' shape (BH=64 a chip), on this chip
+# (PERF.md section 6, PR 37; interleaved, ms a call): (1024, 1024)
+# 0.79, (512, 1024) 0.83, (512, 512) 0.85, (1024, 512) 0.92,
+# (2048, 1024) 0.96, (2048, 512) 1.09, (256, 512) 1.19. Larger blocks
+# mask more of what they visit (6 of 8 chunk pairs hold a hidden
+# quarter at (1024, 1024)) and still win: 3 grid steps and 2
+# finalizations a head where (512, 512) has 10 and 4.
 _BLOCK_TABLE_FWD = {
     (8192, 128): (1024, 1024),
+    (2048, 128): (1024, 1024),
 }
 
 # The twopass arm shifts the balance again: it has no per-block
@@ -624,19 +702,19 @@ def _fwd_kvmap(causal, bq, bk):
 def _fwd(q, k, v, causal, sm_scale, interpret=False):
     BH, T, d = q.shape
     # Arm selection mirrors _bwd: forced via PADDLE_FLASH_FWD, else
-    # online (the incumbent; twopass is the round-6 challenger — see
-    # the arm comment block at the top). Block sizes resolve per-arm
-    # first because the twopass table may differ; the residency guard
-    # can then swap a forced twopass back to online, in which case the
-    # blocks re-resolve under the online table.
+    # online (see the arm comment block at the top). Block sizes
+    # resolve per-arm first because the twopass table may differ; the
+    # residency guard can then swap a forced twopass back to online, in
+    # which case the blocks re-resolve under the online table.
     arm = _FORCE_FWD_ARM or 'online'
     bq, bk = _block_sizes(T, d, fwd=True, arm=arm)
     if arm == 'twopass' and _twopass_vmem_bytes(
             T, d, bq, bk, q.dtype.itemsize) > _TWOPASS_VMEM_CEILING:
         arm = 'online'
         bq, bk = _block_sizes(T, d, fwd=True, arm=arm)
-    global _RESOLVED_FWD_ARM
-    _RESOLVED_FWD_ARM = arm
+    global _RESOLVED_FWD_ARM, _RESOLVED_FWD_BLOCKS
+    _RESOLVED_FWD_ARM, _RESOLVED_FWD_BLOCKS = arm, (bq, bk)
+    _FWD_SCHEDULE[arm].inc()
     nq, nk = T // bq, T // bk
     if arm == 'twopass':
         return _fwd_twopass(q, k, v, causal, sm_scale, interpret,
@@ -651,6 +729,13 @@ def _fwd_online(q, k, v, causal, sm_scale, interpret, bq, bk, nq, nk):
                              causal=causal, block_q=bq, block_k=bk,
                              nk=nk)
     kvmap = _fwd_kvmap(causal, bq, bk)
+    # lse leaves the kernel as [BH, 1, T] rows where the q block tiles
+    # into lanes: a (bq, 1) column block is lane-padded to 256 KB at
+    # bq=512, in VMEM and in HBM, and writing it cost 0.26 ms of a
+    # 1.17 ms call at BH=64, T=2048 (PERF.md section 6, PR 37). The
+    # reshape below restores the [BH, T, 1] the backward arms and
+    # ring_attention's merge consume.
+    lse_rows = bq % 128 == 0
     o, lse = pl.pallas_call(
         kern,
         grid=(BH, nq, nk),
@@ -663,23 +748,27 @@ def _fwd_online(q, k, v, causal, sm_scale, interpret, bq, bk, nq, nk):
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i),
+                         memory_space=pltpu.VMEM) if lse_rows else
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, T, d), q.dtype),
-            jax.ShapeDtypeStruct((BH, T, 1), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, T) if lse_rows else (BH, T, 1),
+                                 jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 128), jnp.float32),
+            pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, d), q.dtype),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary')),
         interpret=interpret,
     )(q, k, v)
-    return o, lse
+    return o, lse.reshape(BH, T, 1)
 
 
 def _fwd_twopass(q, k, v, causal, sm_scale, interpret, bq, bk, nq, nk):

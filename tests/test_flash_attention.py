@@ -487,3 +487,172 @@ def test_per_direction_block_tables_independent(causal):
         scale_ref = float(jnp.abs(b).max()) + 1e-9
         rel = float(jnp.abs(a - b).max()) / scale_ref
         assert rel < 5e-2, 'd%s rel err %.3e' % (name, rel)
+
+
+# --- the schedule a shape gets (PR 37) -------------------------------
+
+@pytest.fixture
+def flash_counters():
+    from paddle_tpu.obs import telemetry
+    was = telemetry.enabled()
+    telemetry.enable()
+
+    def read():
+        c = telemetry.snapshot()['counters']
+        return {k: c['pallas.flash.fwd.' + k] for k in ('online', 'twopass')}
+    yield read
+    if not was:
+        telemetry.disable()
+
+
+def _trace_fwd(fa, T, d=128, dtype=jnp.bfloat16):
+    """Trace _fwd at [2, T, d] without running it: the arm and blocks
+    bind while tracing."""
+    x = jax.ShapeDtypeStruct((2, T, d), dtype)
+    fa._fwd.clear_cache()
+    fa._fwd.lower(x, x, x, True, d ** -0.5, True)
+    return fa._RESOLVED_FWD_ARM, fa._RESOLVED_FWD_BLOCKS
+
+
+def test_training_cell_shape_gets_its_table_entry():
+    """(2048, 128) is the shape both training cells run: the online
+    sweep at the blocks the chip A/B of PR 37 ranked first, for the
+    forward alone; the backward's blocks, (8192, 128) and an unlisted T
+    are what they were."""
+    from paddle_tpu.pallas import flash_attention as fa
+    assert fa._BLOCK_TABLE_FWD[(2048, 128)] == (1024, 1024)
+    assert fa._block_sizes(2048, 128, fwd=True) == (1024, 1024)
+    assert fa._block_sizes(2048, 128) == (512, 512)
+    assert fa._block_sizes(8192, 128, fwd=True) == (1024, 1024)
+    assert fa._block_sizes(8192, 128) == (512, 1024)
+    assert fa._block_sizes(4096, 128, fwd=True) == (512, 512)
+    assert fa._block_sizes(4096, 128) == (512, 512)
+    assert fa._block_sizes(384, 128, fwd=True) == (384, 384)
+    try:
+        assert _trace_fwd(fa, 2048) == ('online', (1024, 1024))
+        assert _trace_fwd(fa, 4096) == ('online', (512, 512))
+    finally:
+        fa._fwd.clear_cache()
+
+
+@pytest.mark.parametrize('arm', ['online', 'twopass'])
+def test_fwd_schedule_counter_counts_one_a_trace(arm, flash_counters):
+    """`pallas.flash.fwd.<schedule>` says which forward a run compiled:
+    one increment a trace of _fwd, none for a call its cache answers,
+    and the blocks of that trace beside the resolved arm."""
+    from paddle_tpu.pallas import flash_attention as fa
+    other = {'online': 'twopass', 'twopass': 'online'}[arm]
+    rng = np.random.RandomState(11)
+    q = jnp.asarray(rng.randn(2, 256, 128).astype('float32')) * 0.3
+    _force_fwd_arm(fa, arm)
+    try:
+        before = flash_counters()
+        fa._fwd(q, q, q, True, 128 ** -0.5, INTERPRET)
+        fa._fwd(q, q, q, True, 128 ** -0.5, INTERPRET)
+        after = flash_counters()
+        assert fa._RESOLVED_FWD_ARM == arm
+        assert fa._RESOLVED_FWD_BLOCKS == (256, 256)
+    finally:
+        _force_fwd_arm(fa, '')
+    assert after[arm] - before[arm] == 1
+    assert after[other] == before[other]
+
+
+@pytest.mark.parametrize('bq,bk,chunk', [(256, 256, 128),    # the winner's
+                                         (128, 256, 128),    # bq != bk
+                                         (256, 128, 512)])   # no chunks
+@pytest.mark.parametrize('causal', [False, True])
+def test_chunked_online_schedule_matches_naive(causal, bq, bk, chunk,
+                                               monkeypatch):
+    """The structure (2048, 128) runs -- (1024, 1024) blocks walked in
+    chunks of 512 keys: two q blocks, two K blocks of two chunks each,
+    pairs that straddle the diagonal, lse leaving as rows -- at a CPU
+    size: (o, lse) and the gradients against the naive contraction."""
+    from paddle_tpu.pallas import flash_attention as fa
+    rng = np.random.RandomState(12)
+    BH, T, d = 2, 512, 128
+    q = jnp.asarray(rng.randn(BH, T, d).astype('float32')) * 0.3
+    k = jnp.asarray(rng.randn(BH, T, d).astype('float32')) * 0.3
+    v = jnp.asarray(rng.randn(BH, T, d).astype('float32'))
+    scale = d ** -0.5
+    monkeypatch.setattr(fa, '_FWD_CHUNK_K', chunk)
+    monkeypatch.setitem(fa._BLOCK_TABLE_FWD, (T, d), (bq, bk))
+    fa._fwd.clear_cache()
+    try:
+        o, lse = fa._fwd(q, k, v, causal, scale, INTERPRET)
+        assert (fa._RESOLVED_FWD_ARM, fa._RESOLVED_FWD_BLOCKS) \
+            == ('online', (bq, bk))
+
+        def loss_k(q, k, v):
+            return jnp.sum(_flash(q, k, v, causal, scale, INTERPRET) ** 2)
+
+        def loss_n(q, k, v):
+            return jnp.sum(_naive(q, k, v, causal, scale) ** 2)
+
+        gk = jax.grad(loss_k, argnums=(0, 1, 2))(q, k, v)
+        gn = jax.grad(loss_n, argnums=(0, 1, 2))(q, k, v)
+    finally:
+        fa._fwd.clear_cache()
+    assert lse.shape == (BH, T, 1) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(o), np.asarray(_naive(q, k, v, causal, scale)),
+        rtol=2e-2, atol=2e-2)
+    s = jnp.einsum('bqd,bkd->bqk', q, k) * scale
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None], s, -jnp.inf)
+    np.testing.assert_allclose(
+        np.asarray(lse[..., 0]),
+        np.asarray(jax.scipy.special.logsumexp(s, axis=-1)),
+        rtol=1e-4, atol=1e-4)
+    for name, a, b in zip('qkv', gk, gn):
+        scale_ref = float(jnp.abs(b).max()) + 1e-9
+        rel = float(jnp.abs(a - b).max()) / scale_ref
+        assert rel < 5e-2, 'd%s rel err %.3e' % (name, rel)
+
+
+def test_online_forward_in_bf16_keeps_the_contract():
+    """bf16 in, as the cells run it: o in the input dtype, lse float32
+    [BH, T, 1] and exact to float32 rounding of the bf16 products."""
+    from paddle_tpu.pallas import flash_attention as fa
+    rng = np.random.RandomState(13)
+    BH, T, d = 2, 256, 128
+    q, k, v = (jnp.asarray(rng.randn(BH, T, d), jnp.bfloat16)
+               for _ in range(3))
+    scale = d ** -0.5
+    fa._fwd.clear_cache()
+    o, lse = fa._fwd(q, k, v, True, scale, INTERPRET)
+    assert o.dtype == jnp.bfloat16 and lse.dtype == jnp.float32
+    assert o.shape == (BH, T, d) and lse.shape == (BH, T, 1)
+    s = jnp.einsum('bqd,bkd->bqk', q * jnp.asarray(scale, q.dtype), k,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None], s, -jnp.inf)
+    np.testing.assert_allclose(
+        np.asarray(lse[..., 0]),
+        np.asarray(jax.scipy.special.logsumexp(s, axis=-1)),
+        rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(o, np.float32),
+        np.asarray(_naive(q, k, v, True, scale), np.float32),
+        rtol=3e-2, atol=3e-2)
+
+
+def test_flash_autotune_quick_smoke():
+    """tools/flash_autotune.py --quick walks the block sweep (forcing by
+    flag, cache clearing, ranking, the peak share from the benchmark's
+    count) on the interpret backend."""
+    import os
+    import sys
+    tools = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), 'tools')
+    sys.path.insert(0, tools)
+    try:
+        import flash_autotune
+        flash_autotune.main(['--quick'])
+        # 4 x 16 heads of T=2048, d=128 at 0.349 ms are the whole peak
+        assert abs(flash_autotune.peak_share(0.349, 64, 2048, 128)
+                   - 100.0) < 0.5
+        assert abs(flash_autotune.peak_share(3 * 0.349, 64, 2048, 128,
+                                             fwd_only=False)
+                   - 100.0) < 0.5
+    finally:
+        sys.path.remove(tools)

@@ -242,3 +242,24 @@ def test_every_kept_option_is_needed(option, v5e_2x2):
     rows = _rows(v5e_2x2, options=rest)
     assert rows and not [r for r in rows if r['form'] == 'fused'], \
         (option, rows)
+
+
+def test_flash_forward_of_the_training_cells_compiles_for_v5e(v5e_2x2):
+    """The forward both training cells run on each chip (4 sequences x 16
+    heads, T=2048, d=128, bf16, causal), with the schedule and blocks the
+    shape gets, lowered by Mosaic and compiled by the installed TPU
+    compiler: a slice off the tiling, a layout it cannot change or too
+    much VMEM is refused here, without a chip."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.pallas import flash_attention as fa
+    x = jax.ShapeDtypeStruct((64, 2048, 128), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e_2x2[0]))
+    fa._fwd.clear_cache()
+    compiled = fa._fwd.lower(x, x, x, True, 128 ** -0.5, False).compile()
+    assert fa._RESOLVED_FWD_ARM == 'online'
+    assert fa._RESOLVED_FWD_BLOCKS == fa._BLOCK_TABLE_FWD[(2048, 128)]
+    assert 'tpu_custom_call' in compiled.as_text()
+    o, lse = compiled.out_info
+    assert (o.shape, str(o.dtype)) == ((64, 2048, 128), 'bfloat16')
+    assert (lse.shape, str(lse.dtype)) == ((64, 2048, 1), 'float32')

@@ -7,12 +7,16 @@ ONE process, alternate arms across rounds, and difference in-jit N/2N
 loops. This tool re-runs the (block_q, block_k) sweep that way.
 
     python tools/flash_autotune.py [--T 8192] [--bh 16] [--rounds 3]
-        [--fwd-only] [--fwd-arm online|twopass]
+        [--fwd-only] [--fwd-arm online|twopass] [--quick]
 
-Prints per-config fwd+bwd ms (median over rounds) so a real >5%
+Prints per-config fwd+bwd ms a call (median over rounds) beside its
+share of the chip's bf16 peak (peak_share below), so a real >5%
 winner, if one exists, survives the noise floor. Populate
 pallas/flash_attention._BLOCK_TABLE with any config that wins
-consistently.
+consistently. The training cells' shape is `--T 2048 --bh 64` (4
+sequences x 16 heads a chip, d=128, bf16, causal). --quick is the
+tier-1 smoke: one tiny shape, one round, forward only, interpret mode
+off-chip — it walks the harness, its numbers mean nothing.
 
 --fwd-only times the forward alone (the round-5 fwd-table sweep mode,
 now also the round-6 twopass mode); --fwd-arm forces a forward arm for
@@ -36,6 +40,27 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import jax
 import jax.numpy as jnp
+
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def peak_share(ms, bh, T, d, fwd_only=True):
+    """Percent of the v5e's 197 TFLOP/s that `ms` a call is for causal
+    attention over bh heads of T x d, by the benchmark's own count
+    (benchmarks/harness/costs.py: the two forward products, halved for
+    the mask; forward + backward is three times that, the backward's
+    recomputed QK^T not counted) — what `flash_roofline.train` divides
+    by, so a tool's line and a cell's metric read on one scale."""
+    bench = os.path.join(_ROOT, 'benchmarks')
+    if bench not in sys.path:
+        sys.path.append(bench)
+    from harness import costs, peaks
+    flops = costs.attn_flops_fwd({'n_embd': bh * d}, T)
+    if not fwd_only:
+        flops *= 3
+    peak = peaks.peaks_of('TPU v5 lite')['bf16_flops']
+    return flops / (ms * 1e-3) / peak * 100
 
 
 def timed_step(flash, q, k, v, iters):
@@ -101,7 +126,7 @@ def measure(flash, q, k, v, iters=6, fwd_only=False, interpret=False):
     return ((t2 - t1) - (t1 - t0)) / iters * 1e3  # ms per step
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument('--T', type=int, default=8192)
     ap.add_argument('--d', type=int, default=128)
@@ -112,7 +137,17 @@ def main():
     ap.add_argument('--fwd-only', action='store_true')
     ap.add_argument('--fwd-arm', default='',
                     choices=['', 'online', 'twopass'])
-    args = ap.parse_args()
+    ap.add_argument('--quick', action='store_true')
+    args = ap.parse_args(argv)
+
+    interpret = jax.default_backend() != 'tpu'
+    if args.quick:
+        args.T, args.bh, args.rounds = 256, 2, 1
+        args.blocks, args.fwd_only = [128, 256], True
+    elif interpret:
+        raise SystemExit('the sweep needs a TPU backend (interpret-mode '
+                         'timings rank the emulator); use --quick for '
+                         'the harness smoke')
 
     import paddle_tpu as fluid
     from paddle_tpu.pallas import flash_attention as flash
@@ -140,7 +175,10 @@ def main():
             flash._fwd.clear_cache()
             flash._bwd.clear_cache()
             try:
-                ms = measure(flash, q, k, v, fwd_only=args.fwd_only)
+                ms = measure(flash, q, k, v,
+                             iters=2 if args.quick else 6,
+                             fwd_only=args.fwd_only,
+                             interpret=interpret)
             except Exception as e:   # noqa: BLE001 — e.g. VMEM OOM
                 failed.add(cfg)
                 print('round %d  bq=%-5d bk=%-5d  FAILED (%.80s)'
@@ -162,6 +200,8 @@ def main():
     flash._FORCE_FWD_ARM = ''
     fluid.flags.set_flags({'FLAGS_flash_block_q': 0,
                            'FLAGS_flash_block_k': 0})
+    flash._fwd.clear_cache()
+    flash._bwd.clear_cache()
     # drop configs with ANY failure: a transiently-failed arm would
     # otherwise rank on fewer samples, indistinguishable in the table
     configs = [c for c in configs if results[c] and c not in failed]
@@ -171,14 +211,20 @@ def main():
     ranked = sorted(configs, key=lambda c: statistics.median(results[c]))
     base_cfg = (512, 512) if (512, 512) in configs else ranked[0]
     base = statistics.median(results[base_cfg])
-    print('\n| bq | bk | median ms | spread | vs %dx%d |'
+    print('\nBH=%d T=%d d=%d bf16 causal, %s, ms a call'
+          % (args.bh, args.T, args.d,
+             'forward' if args.fwd_only else 'forward + backward'))
+    print('| bq | bk | median ms | spread | vs %dx%d | %% of peak |'
           % base_cfg)
-    print('|---|---|---|---|---|')
+    print('|---|---|---|---|---|---|')
     for cfg in ranked:
         ms = results[cfg]
-        print('| %d | %d | %.2f | %.2f-%.2f | %+.1f%% |'
-              % (cfg[0], cfg[1], statistics.median(ms), min(ms),
-                 max(ms), (statistics.median(ms) / base - 1) * 100))
+        med = statistics.median(ms)
+        print('| %d | %d | %.3f | %.3f-%.3f | %+.1f%% | %.1f |'
+              % (cfg[0], cfg[1], med, min(ms), max(ms),
+                 (med / base - 1) * 100,
+                 peak_share(med, args.bh, args.T, args.d,
+                            args.fwd_only)))
 
 
 if __name__ == '__main__':

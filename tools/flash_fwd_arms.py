@@ -15,6 +15,12 @@ ranked so a guard-swapped arm can never pollute its label's column.
         [--bh 16] [--rounds 3] [--arms online twopass]
         [--blocks-q 0] [--blocks-k 0] [--quick]
 
+Each arm's line is ms a call beside its share of the chip's bf16 peak
+(flash_autotune.peak_share: the benchmark's own count). The training
+cells' shape is `--ladder 2048 --bh 64` (4 sequences x 16 heads a
+chip, d=128, bf16, causal); `--arms default` times what the shape
+gets with nothing forced.
+
 --blocks-q/--blocks-k force one block config for every arm (0 = each
 arm's own tuned table). --quick is the tier-1 smoke: one tiny shape,
 one round, CPU-interpret safe — it validates the harness end to end
@@ -35,7 +41,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 import jax
 import jax.numpy as jnp
 
-from flash_autotune import measure  # noqa: E402 — same harness
+from flash_autotune import measure, peak_share  # noqa: E402
 
 
 def main(argv=None):
@@ -55,10 +61,11 @@ def main(argv=None):
     import paddle_tpu as fluid
     from paddle_tpu.pallas import flash_attention as flash
 
-    bad = [a for a in args.arms if a not in flash._FWD_ARMS[1:]]
+    # 'default' forces nothing: the arm and blocks the shape gets
+    known = list(flash._FWD_ARMS[1:]) + ['default']
+    bad = [a for a in args.arms if a not in known]
     if bad:
-        raise SystemExit('unknown arm(s) %s: expected %s'
-                         % (bad, list(flash._FWD_ARMS[1:])))
+        raise SystemExit('unknown arm(s) %s: expected %s' % (bad, known))
 
     interpret = jax.default_backend() != 'tpu'
     if args.quick:
@@ -97,7 +104,8 @@ def main(argv=None):
                     # force by NAME — '' means "default", which
                     # dispatches online, so a '' spelling would rank
                     # online against itself
-                    flash._FORCE_FWD_ARM = arm
+                    flash._FORCE_FWD_ARM = '' if arm == 'default' \
+                        else arm
                     # the arm binds at TRACE time — stale traces must
                     # go
                     flash._fwd.clear_cache()
@@ -111,7 +119,11 @@ def main(argv=None):
                         print('T=%-6d round %d  %-8s FAILED (%.80s)'
                               % (T, rnd, arm, str(e)), flush=True)
                         continue
-                    if flash._RESOLVED_FWD_ARM != arm:
+                    if arm == 'default':
+                        print('T=%-6d round %d  default is %s %s'
+                              % (T, rnd, flash._RESOLVED_FWD_ARM,
+                                 flash._RESOLVED_FWD_BLOCKS), flush=True)
+                    elif flash._RESOLVED_FWD_ARM != arm:
                         # the residency guard swapped the forced arm —
                         # ranking the substitute under this label
                         # would corrupt the table (a guarded twopass
@@ -134,14 +146,16 @@ def main(argv=None):
             ranked = sorted(
                 arms, key=lambda a: statistics.median(results[a]))
             base = statistics.median(results[arms[0]])
-            print('\nT=%d\n| arm | median ms | spread | vs %s |'
-                  % (T, arms[0]))
-            print('|---|---|---|---|')
+            print('\nBH=%d T=%d d=%d bf16 causal, forward, ms a call\n'
+                  '| arm | median ms | spread | vs %s | %% of peak |'
+                  % (args.bh, T, args.d, arms[0]))
+            print('|---|---|---|---|---|')
             for a in ranked:
                 ms = results[a]
-                print('| %s | %.2f | %.2f-%.2f | %+.1f%% |'
-                      % (a, statistics.median(ms), min(ms), max(ms),
-                         (statistics.median(ms) / base - 1) * 100))
+                med = statistics.median(ms)
+                print('| %s | %.3f | %.3f-%.3f | %+.1f%% | %.1f |'
+                      % (a, med, min(ms), max(ms), (med / base - 1) * 100,
+                         peak_share(med, args.bh, T, args.d)))
             print()
     finally:
         flash._FORCE_FWD_ARM = saved_force
