@@ -29,6 +29,27 @@ the worker's exit. So does a prompt's last chunk, between its dispatch
 and the synchronous wait for its token (the device runs the step in
 flight before the chunk: its tokens need not wait for the chunk).
 
+What stood in front of a token. Every decode step carries a record made
+at its DISPATCH, `(chunks, lanes, sync)`: the prefill chunks this worker
+dispatched since it dispatched the step before (0, 1, rarely more: the
+device runs them in front of this step), the lanes the step was packed
+with (`len(ready)`), and 1 if no step was in flight at the dispatch
+(the step behind a prompt's last chunk, a burst's first, every step of
+a predictor that does not defer), else 0. The count is taken at
+dispatch, not at accept: in the pipelined loop a step's tokens are
+accepted after the NEXT pass's chunk has gone out, and that chunk runs
+on the device behind the step. The record travels with the step's
+tokens and each token that ends a gap (every one but a request's
+first) leaves it on its request: `Request.gap_chunks`, `gap_lanes`,
+`gap_sync`, entry i for the gap between tokens i and i + 1. A gap's
+KIND (`gap_kind`) is `sync` if its step was dispatched with nothing in
+flight; else `chunk` if at least one chunk stood in front; else
+`plain`. A speculative step that yields several tokens for a lane gives
+its record to the first and (0, lanes, 1) to the others; a resumed
+request's first gap after a preemption is `sync` (a token that a
+re-prefill's last chunk made reads (0, 1, 1)). All of it is recorded
+while the registry is on and costs one boolean read while it is off.
+
 Requests carry a PRIORITY tier (submit(priority=), higher = more
 important, 0 = the default lowest tier): one queue per tier, popped
 highest-tier first, and the queue-full admission bound applies only to
@@ -42,7 +63,14 @@ Telemetry (paddle_tpu/obs/, exported when FLAGS_obs_dir is set):
   failed}  counters; serving.tokens_generated / serving.decode_steps /
   serving.decode_steps_overlapped (steps dispatched while the one
   before was in flight) / serving.decode_lanes_dropped /
-  serving.prefills  counters; serving.queue_depth /
+  serving.prefills / serving.tokens_behind_prefill /
+  serving.tokens_behind_sync (tokens whose gap was of kind `chunk` /
+  `sync`: over tokens_generated, the share of generation that another
+  request's prefill and the empty pipeline delay) /
+  serving.loop.seconds / serving.loop.wait_seconds (the workers' passes,
+  and the part of them spent blocked in a step's fetch, the predictor's
+  `fetch_wait_s`: 1 - wait / seconds is how far the host is from
+  setting the pace)  counters; serving.queue_depth /
   serving.slot_occupancy  gauges; serving.ttft /
   serving.token_latency / serving.decode_batch  histograms (seconds /
   seconds / active lanes per step; the last two take one observation
@@ -53,7 +81,10 @@ Telemetry (paddle_tpu/obs/, exported when FLAGS_obs_dir is set):
 
 Spans (profiler.RecordEvent; recorded while the registry is on, and on
 the device trace's clock while a profile runs): `serve.iter` is one
-pass of a worker's loop (attrs lanes, ready, prefilling, queued) with
+pass of a worker's loop (attrs lanes, ready, prefilling, queued; chunk
+and step, 0/1: the pass dispatched a prefill chunk / a decode step;
+wait_ms, the part of it spent blocked in a fetch, so the span's length
+less wait_ms is the host's own section of the pass) with
 children `serve.admit`, `serve.prefill_tick`, `serve.pack` and
 `serve.accept`; the decoder's own spans (serving/paged.py) nest under
 it. In the pipelined loop `serve.accept` follows the call and holds
@@ -65,8 +96,10 @@ three spans of kind 'request' that share sid = its id: `serve.queue`
 (submitted_at -> admitted_at), `serve.prefill` (admitted_at ->
 first_token_at) and
 `serve.decode` (first_token_at -> done_at; attrs n_prompt,
-max_new_tokens, n_tokens, state, prefill_chunks, preemptions and
-gaps_ms, the times between its tokens). A preempted request adds one
+max_new_tokens, n_tokens, state, prefill_chunks, preemptions,
+gaps_ms, the times between its tokens, and beside it gap_chunks,
+gap_lanes and gap_sync, what stood in front of each). A preempted
+request adds one
 `serve.requeue` (preempted -> slot taken again) per resumption.
 """
 from __future__ import annotations
@@ -113,6 +146,10 @@ _decode_steps_overlapped = telemetry.counter(
     'serving.decode_steps_overlapped')
 _decode_lanes_dropped = telemetry.counter('serving.decode_lanes_dropped')
 _prefills = telemetry.counter('serving.prefills')
+_tokens_behind_prefill = telemetry.counter('serving.tokens_behind_prefill')
+_tokens_behind_sync = telemetry.counter('serving.tokens_behind_sync')
+_loop_seconds = telemetry.counter('serving.loop.seconds')
+_loop_wait_seconds = telemetry.counter('serving.loop.wait_seconds')
 _queue_depth = telemetry.gauge('serving.queue_depth')
 _occupancy = telemetry.gauge('serving.slot_occupancy')
 _ttft = telemetry.histogram('serving.ttft')
@@ -122,6 +159,16 @@ _weight_swaps = telemetry.counter('serving.weight_swaps')
 _swap_wait = telemetry.histogram('serving.swap_wait')
 _cache_exhausted = telemetry.counter('serving.cache_exhausted')
 _deadline_expired = telemetry.counter('serving.deadline_expired')
+
+
+def gap_kind(chunks, sync):
+    """The kind of a token's gap from its step's record (the module's
+    docstring): 'sync', 'chunk' or 'plain'."""
+    return 'sync' if sync else 'chunk' if chunks else 'plain'
+
+
+_TOKENS_BEHIND = {'chunk': _tokens_behind_prefill,
+                  'sync': _tokens_behind_sync}
 
 
 class _StepGate(object):
@@ -220,6 +267,10 @@ class Request(object):
         self.admitted_at = None
         self.first_token_at = None
         self.token_at = []
+        # what stood in front of the step that made token i + 1 (the
+        # module's docstring), one entry a gap while the registry is on
+        self.gap_chunks, self.gap_lanes, self.gap_sync = [], [], []
+        self._resumed = False         # its next gap spans a preemption
         self.prefill_chunks = 0
         self.preemptions = 0
         self.done_at = None
@@ -230,6 +281,20 @@ class Request(object):
         preempted): the queue wait ends at the first."""
         if self.admitted_at is None:
             self.admitted_at = time.perf_counter()
+
+    def _gap(self, rec):
+        """One more gap between two tokens, marked by the record of the
+        step that made the later one; None for a token that a
+        re-prefill's last chunk made."""
+        chunks, n_lanes, sync = rec or (0, 1, 1)
+        if self._resumed:
+            sync, self._resumed = 1, False
+        self.gap_chunks.append(chunks)
+        self.gap_lanes.append(n_lanes)
+        self.gap_sync.append(sync)
+        behind = _TOKENS_BEHIND.get(gap_kind(chunks, sync))
+        if behind is not None:
+            behind.inc()
 
     def _finish(self, state, error=None):
         self.state = state
@@ -256,8 +321,11 @@ class Request(object):
         for (name, t0), t1 in zip(marks, ends):
             if name == 'serve.decode':
                 at = self.token_at
-                attrs['gaps_ms'] = [1e3 * (b - a)
-                                    for a, b in zip(at, at[1:])]
+                attrs.update(gaps_ms=[1e3 * (b - a)
+                                      for a, b in zip(at, at[1:])],
+                             gap_chunks=self.gap_chunks,
+                             gap_lanes=self.gap_lanes,
+                             gap_sync=self.gap_sync)
             _trace.record_span(name, 'request', self.id, t0, t1, **attrs)
 
     def wait(self, timeout=None):
@@ -667,6 +735,7 @@ class ServingEngine(object):
         _trace.record_span('serve.requeue', 'request', req.id,
                            req.preempted_at, now)
         req.preempted_at = None
+        req._resumed = True
         with self._cond:
             self._preempted -= 1
             self._resumes_n += 1
@@ -720,9 +789,11 @@ class ServingEngine(object):
         else:
             _failed.inc()
 
-    def _lane_accept(self, lanes, slot, tok, *, pred, wstate):
+    def _lane_accept(self, lanes, slot, tok, *, pred, wstate, rec=None):
         """Record one generated token; returns False if the lane is
-        done (eos / budget / cancelled) and was evicted."""
+        done (eos / budget / cancelled) and was evicted. `rec` is the
+        record of the decode step that made the token (None: a prefill
+        chunk made it)."""
         lane = lanes[slot]
         req = lane.req
         if req.state == CANCELLED:
@@ -736,6 +807,8 @@ class ServingEngine(object):
         if req.first_token_at is None:
             req.first_token_at = now
             _ttft.observe(now - req.submitted_at)
+        elif telemetry._enabled:
+            req._gap(rec)
         if len(req.tokens) >= req.max_new_tokens or \
                 (req.eos_id is not None and int(tok) == req.eos_id):
             self._finish_lane(lanes, slot, DONE, pred=pred,
@@ -901,6 +974,7 @@ class ServingEngine(object):
                                   pred=pred, wstate=wstate)
                 return
             _prefills.inc()
+            wstate['chunks'] += 1
             req.prefill_chunks += 1
             if out is None:
                 return               # more chunks remain — next iteration
@@ -914,8 +988,12 @@ class ServingEngine(object):
         lanes = {}                       # slot -> _Lane
         prefilling = collections.deque()  # slots mid-prefill
         # flight: the (slot, lane) pairs fed to the decode step that is
-        # dispatched and not yet accepted (the pipelined loop), or None
-        wstate = {'cache_wait': False, 'flight': None, 'wid': wid}
+        # dispatched and not yet accepted (the pipelined loop), or None,
+        # and rec, that step's record of what stood in front of it.
+        # chunks / steps: the prefill chunks and decode steps this
+        # worker has dispatched; chunks_seen: chunks at the last step
+        wstate = {'cache_wait': False, 'flight': None, 'rec': None,
+                  'wid': wid, 'chunks': 0, 'chunks_seen': 0, 'steps': 0}
         tokens = np.zeros((pred.slots,), np.int64)
         positions = np.zeros((pred.slots,), np.int32)
         reading = False
@@ -938,10 +1016,21 @@ class ServingEngine(object):
                     self._gate.acquire_read()
                     reading = True
                 with RecordEvent('serve.iter') as it:
+                    timed = telemetry._enabled
+                    if timed:
+                        t0, wait0 = time.perf_counter(), pred.fetch_wait_s
+                        chunks0, steps0 = wstate['chunks'], wstate['steps']
                     self._iterate(it, wid, pred, lanes, prefilling, wstate,
                                   tokens, positions)
                     if self._gate.writer_waiting:
                         self._collect(pred, lanes, wstate)
+                    if timed:
+                        wait = pred.fetch_wait_s - wait0
+                        _loop_seconds.inc(time.perf_counter() - t0)
+                        _loop_wait_seconds.inc(wait)
+                        it.attrs.update(wait_ms=1e3 * wait,
+                                        chunk=wstate['chunks'] - chunks0,
+                                        step=wstate['steps'] - steps0)
                 if wstate['flight'] is None:
                     self._gate.release_read()
                     reading = False
@@ -969,7 +1058,8 @@ class ServingEngine(object):
                                       pred=pred, wstate=wstate)
             return
         with RecordEvent('serve.accept'):
-            self._accept_flight(flight, ids, pred, lanes, wstate)
+            self._accept_flight(flight, wstate['rec'], ids, pred, lanes,
+                                wstate)
         self._report(wstate['wid'], lanes)
 
     def _report(self, wid, lanes):
@@ -979,8 +1069,9 @@ class ServingEngine(object):
         _occupancy.set(self._active_total)
         self._slot_tokens[wid] = {s: ln.pos for s, ln in lanes.items()}
 
-    def _accept_flight(self, flight, ids, pred, lanes, wstate):
-        """The tokens of a deferred step, one fetch late. A lane that
+    def _accept_flight(self, flight, rec, ids, pred, lanes, wstate):
+        """The tokens of a deferred step, one fetch late, each with the
+        step's record `rec`. A lane that
         ended meanwhile on what only the token or the clock could tell
         (an eos_id, a cancel, a deadline) was fed to this step all the
         same: its result is dropped here. Its pages were released with
@@ -992,7 +1083,7 @@ class ServingEngine(object):
                 _decode_lanes_dropped.inc()
                 continue
             self._lane_accept(lanes, slot, int(ids[slot]), pred=pred,
-                              wstate=wstate)
+                              wstate=wstate, rec=rec)
 
     def _iterate(self, it, wid, pred, lanes, prefilling, wstate, tokens,
                  positions):
@@ -1116,15 +1207,25 @@ class ServingEngine(object):
         _decode_steps.inc()
         _token_latency.observe(dt)
         _decode_batch.observe(len(ready))
+        # what stood in front of the step just dispatched (the module's
+        # docstring); its tokens take it with them
+        wstate['steps'] += 1
+        rec = None
+        if telemetry._enabled:
+            rec = (wstate['chunks'] - wstate['chunks_seen'], len(ready),
+                   int(not deferred or wstate['flight'] is None))
+        wstate['chunks_seen'] = wstate['chunks']
         if deferred:
-            flight = wstate['flight']
+            flight, rec_before = wstate['flight'], wstate['rec']
             wstate['flight'] = fed = [(s, lanes[s]) for s in ready]
+            wstate['rec'] = rec
             for _slot, lane in fed:
                 lane.pos += 1
             if flight is not None:
                 _decode_steps_overlapped.inc()
                 with RecordEvent('serve.accept'):
-                    self._accept_flight(flight, ids, pred, lanes, wstate)
+                    self._accept_flight(flight, rec_before, ids, pred,
+                                        lanes, wstate)
             if not any(lanes.get(s) is ln for s, ln in fed):
                 # every lane of the step just dispatched has ended:
                 # nothing waits for it, and the worker may go idle
@@ -1135,16 +1236,19 @@ class ServingEngine(object):
                     # per-slot mixed accept lengths in the SAME
                     # iteration: each lane consumes its own emitted
                     # prefix, stopping early on eos/budget/cancel
+                    more = rec and (0, len(ready), 1)
                     for slot in ready:
-                        for tok in emitted.get(slot, ()):
+                        for i, tok in enumerate(emitted.get(slot, ())):
                             lanes[slot].pos += 1
                             if not self._lane_accept(
                                     lanes, slot, int(tok), pred=pred,
-                                    wstate=wstate):
+                                    wstate=wstate,
+                                    rec=more if i else rec):
                                 break
                 else:
                     for slot in ready:
                         lanes[slot].pos += 1
                         self._lane_accept(lanes, slot, int(ids[slot]),
-                                          pred=pred, wstate=wstate)
+                                          pred=pred, wstate=wstate,
+                                          rec=rec)
         self._report(wid, lanes)

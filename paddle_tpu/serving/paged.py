@@ -88,7 +88,12 @@ and `paged.*.fetch` (`np.asarray(ids)`: the wait for the device and
 the transfer; in prefill only on a prompt's last chunk). In a deferred
 decode step the fetch is that of the step BEFORE, with this one already
 queued behind it: the device's lead over the host, not a whole step's
-time. collect() leaves a `paged.decode.fetch` alone.
+time. collect() leaves a `paged.decode.fetch` alone. The seconds spent
+blocked inside the fetch spans (the `np.asarray` of a step's ids, of a
+prompt's last chunk's, of collect()'s) add up in the attribute
+`fetch_wait_s`, cumulative since construction: the engine's loop reads
+it before and after a pass for the pass's wait (`wait_ms` of
+`serve.iter`, the counter serving.loop.wait_seconds).
 `paged.state.save` / `paged.state.restore` (attr `nbytes`) inside
 save_stream / restore_stream: the recurrent rows' way to the host and
 back.
@@ -97,6 +102,7 @@ from __future__ import annotations
 
 import collections
 import threading
+import time
 
 import numpy as np
 
@@ -189,6 +195,7 @@ class PagedDecodePredictor(object):
         if _clone_of is None:
             self._pin_weights()
         self._scope = Scope(parent=self._weight_scope)
+        self.fetch_wait_s = 0.0       # blocked in a step's fetch, ever
         self.reset()
 
     def _make_executor(self, place):
@@ -288,6 +295,14 @@ class PagedDecodePredictor(object):
             if self.recurrent else ()
         return 4 * len(self._pair.spec.recurrent_layers) * int(
             sum(np.prod(s) for s in shapes))
+
+    def _fetch(self, value):
+        """value on the host: the wait for the device and the transfer,
+        its seconds added to `fetch_wait_s`."""
+        t0 = time.perf_counter()
+        out = np.asarray(value)
+        self.fetch_wait_s += time.perf_counter() - t0
+        return out
 
     def _run(self, program, feed, fetches, decode):
         """One run of a program of the pair -> (logits, ids); a third
@@ -805,9 +820,9 @@ class PagedDecodePredictor(object):
         if before_fetch is not None:
             before_fetch()
         with RecordEvent('paged.prefill.fetch'):
-            tok = int(np.asarray(ids)[0])
+            tok = int(self._fetch(ids)[0])
             if return_logits:
-                return tok, np.asarray(logits)[0]
+                return tok, self._fetch(logits)[0]
         return tok
 
     def decode_step(self, tokens, positions, return_logits=False,
@@ -924,10 +939,10 @@ class PagedDecodePredictor(object):
             if defer:
                 # the wait for the step BEFORE this one, with this one
                 # already queued on the device behind it
-                return np.asarray(prev) if overlapped else None
+                return self._fetch(prev) if overlapped else None
             if return_logits:
-                return np.asarray(ids), np.asarray(logits)
-            return np.asarray(ids)
+                return self._fetch(ids), self._fetch(logits)
+            return self._fetch(ids)
 
     @property
     def in_flight(self):
@@ -942,7 +957,7 @@ class PagedDecodePredictor(object):
             return None
         self._in_flight = False
         with RecordEvent('paged.decode.fetch'):
-            return np.asarray(self._last_ids)
+            return self._fetch(self._last_ids)
 
     def prefill(self, prompts, slot_ids, return_logits=False):
         """Whole-prompt prefill (the parity / generate() path): each
