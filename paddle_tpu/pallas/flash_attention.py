@@ -35,17 +35,25 @@ Backward: delta = rowsum(dO·O) in plain JAX, then the KV-MAJOR
 single-pass kernel (grid (bh, ki, qi), both inner dims sequential;
 S/P/dP/dS computed once per visited pair = the 5-matmul + 1-exp
 minimum; dk/dv in small per-ki scratch, dq accumulated across the
-whole sweep in a full-sequence fp32 scratch written once) — measured
-−25-31% vs the two-kernel split backward at T≥2048 and at parity at
-T=512 (PERF.md round-5). Two alternates stay available via
-PADDLE_FLASH_BWD and carry their own grad-parity tests: `split` (dq
-sweep + dk/dv sweep, 7 block-matmuls + 2 exp streams — also the
-automatic fallback when the kv-major scoped-VMEM request would pass
-the measured-safe 64 MB ceiling, i.e. beyond T=64k/d=128) and
-`onepass` (the qi-major transpose whose ~12 MB
-of resident dk/dv accumulators starve Mosaic's double-buffering — it
-LOSES 10-50% here; kept for chips where the balance differs, same
-lesson as the round-3 conv+BN epilogue kernel).
+whole sweep in a full-sequence fp32 scratch written once). lse and
+delta stay [BH, 1, T] rows from the forward kernel to the backward's
+(1, 1, bq) fetch, and the pair's tiles are computed transposed,
+[keys, queries], where a row broadcasts along sublanes and two of the
+three gradient products need no transpose; a block is walked in chunk
+pairs of _BWD_CHUNK and a pair no query sees is skipped, while tracing
+where the block spans the sequence. On this chip at BH=64, T=2048,
+d=128 (PERF.md section 6, PR 41; ms a call, delta included): 2.07-2.13
+as it was at (512, 512) blocks; 1.59 with the mask as two pl.when
+bodies in place of a cond that yields the [512, 512] tile; 1.25 with
+the whole head one grid step (1.34 of them with the statistics still
+columns). Two alternates stay available via PADDLE_FLASH_BWD and carry
+their own grad-parity tests, on the same pair function and chunk walk:
+`split` (dq sweep + dk/dv sweep, 7 block-matmuls + 2 exp streams: 1.71
+there — also the automatic fallback when the kv-major scoped-VMEM
+request would pass the measured-safe 64 MB ceiling, i.e. from
+T=64k/d=128) and `onepass` (the qi-major transpose with dk/dv as the
+resident accumulators: 1.24 there, level with kv-major, where an
+earlier chip read it 10-50 % behind at T=8192).
 """
 from __future__ import annotations
 
@@ -73,22 +81,28 @@ _ROUTE_NAIVE = _tm.counter('pallas.flash.naive')
 # the forward) does not count again
 _FWD_SCHEDULE = {'online': _tm.counter('pallas.flash.fwd.online'),
                  'twopass': _tm.counter('pallas.flash.fwd.twopass')}
+# ... and which backward arm _bwd compiled, once per trace of _bwd
+_BWD_SCHEDULE = {arm: _tm.counter('pallas.flash.bwd.' + arm)
+                 for arm in ('split', 'onepass', 'kvmajor')}
 
-# Backward-arm selection. Three arms, all grad-parity-tested:
+# Backward-arm selection. Three arms, all grad-parity-tested, one pair
+# function (_pair_grads) and one chunk walk (_visible_pairs):
 #   split    — dq kernel + dk/dv kernel (7 block-matmuls, 2 exp streams)
 #   onepass  — grid (bh, qi, ki), dk/dv in full-sequence VMEM scratch
-#              (5 matmuls, 1 exp; ~12 MB resident — measured 10-50%
-#              SLOWER here: the residency starves Mosaic's
-#              double-buffering, same lesson as the round-3 conv+BN
-#              epilogue kernel)
+#              (5 matmuls, 1 exp; ~12 MB resident at 8k/128)
 #   kvmajor  — grid (bh, ki, qi): the transpose of onepass. dk/dv live
 #              in small per-ki scratch; dq accumulates in a
 #              full-sequence fp32 scratch (T·d·4 = 4 MB at 8k/128 —
 #              HALF the onepass residency) written once at the end.
 #              Same 5-matmul + 1-exp minimum per visited pair.
 # PADDLE_FLASH_BWD=split|onepass|kvmajor forces an arm;
-# PADDLE_FLASH_ONEPASS=1 is the legacy spelling of onepass.
-# Default dispatch is measured per grid size in _bwd below.
+# PADDLE_FLASH_ONEPASS=1 is the legacy spelling of onepass. Every shape
+# gets kvmajor up to the VMEM ceiling in _bwd. Ranked on this chip at
+# BH=64, T=2048, d=128, bf16, causal, (2048, 2048) blocks (PERF.md
+# section 6, PR 41; tools/flash_bwd_arms.py, ms a call): kvmajor 1.25,
+# onepass 1.24, split 1.71. (On an earlier chip onepass read 10-50 %
+# behind kvmajor at T=8192, its residency starving Mosaic's double
+# buffering; at 2048 both hold the whole head and differ by nothing.)
 import os as _os
 _BWD_ARMS = ('', 'split', 'onepass', 'kvmajor')
 _FORCE_ARM = _os.environ.get('PADDLE_FLASH_BWD', '').strip().lower()
@@ -102,8 +116,10 @@ if not _FORCE_ARM and _os.environ.get('PADDLE_FLASH_ONEPASS', '') in (
     _FORCE_ARM = 'onepass'
 # the arm _bwd actually dispatched at its last trace — the residency
 # guards may silently swap a forced arm for 'split', so measurement
-# tools must check this rather than trust the arm they requested
+# tools must check this rather than trust the arm they requested; and
+# the (block_q, block_k) that trace ran with
 _RESOLVED_ARM = ''
+_RESOLVED_BWD_BLOCKS = ()
 
 # Forward-arm selection (round 6). Two arms, both parity-tested on
 # (o, lse, grads):
@@ -386,81 +402,153 @@ def _twopass_vmem_bytes(T, d, bq, bk, io_itemsize):
     return int(acc + 3 * stream) + 6 * 1024 * 1024
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_scr, *, sm_scale, causal, block_q, block_k, nk):
+# The backward walks a block in chunk pairs of at most this many rows a
+# side: a [512, 512] score tile is what the parent's (512, 512) blocks
+# held, so larger blocks cost grid steps and fetches, not VMEM
+# temporaries. Chip A/B at BH=64, T=2048, d=128, the whole head one
+# block: chunks of 512 1.25 ms a call, of 256 1.24, of 1024 1.59
+# (PERF.md section 6, PR 41).
+_BWD_CHUNK = 512
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T: the matrix unit's own form
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+# A grid index is a Python int where its axis has one step, and then
+# what depends on it alone is decided while tracing: these three take a
+# bool or a traced predicate.
+def _when(cond):
+    if isinstance(cond, bool):
+        return (lambda body: body()) if cond else (lambda body: None)
+    return pl.when(cond)
+
+
+def _and(a, b):
+    if isinstance(a, bool):
+        return b if a else False
+    if isinstance(b, bool):
+        return a if b else False
+    return a & b
+
+
+def _not(a):
+    return (not a) if isinstance(a, bool) else jnp.logical_not(a)
+
+
+def _grid_index(axis, steps):
+    return 0 if steps == 1 else pl.program_id(axis)
+
+
+def _chunk(block):
+    return _BWD_CHUNK if block % _BWD_CHUNK == 0 else block
+
+
+def _pair_grads(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qs, ks,
+                q0, k0, sm_scale, masked):
+    """The backward's per-pair math, shared by the three arms, for
+    queries `qs` and keys `ks` of the blocks in hand (q0 and k0 their
+    places in the sequence): recompute the scores, P from the stored
+    lse, dP, dS -- all TRANSPOSED, [keys, queries]. In that orientation
+    lse and delta are [1, cq] rows that broadcast along sublanes (what
+    the forward writes; as (bq, 1) columns each block was lane-padded to
+    256 KB in VMEM and in HBM), `dv += pT do` and `dk += dsT q` contract
+    as the matrix unit does and only `dq += dsT.T k` transposes. Returns
+    (q scaled, k, do, pT, dsT), the tiles cast to the operands' type.
+    lse may be larger than this block's own (the ring's global lse): p
+    is then simply smaller."""
+    q = q_ref[0, qs, :] * sm_scale                     # input dtype, as
+    k = k_ref[0, ks, :]                                # the forward does
+    do = do_ref[0, qs, :]
+    sT = _dot(k, q, _NT)                               # [ck, cq]
+    if masked:
+        k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, sT.shape, 0)
+        q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, sT.shape, 1)
+        sT = jnp.where(q_pos >= k_pos, sT, _NEG_INF)
+    pT = jnp.exp(sT - lse_ref[0, :, qs])
+    dpT = _dot(v_ref[0, ks, :], do, _NT)
+    dsT = pT * (dpT - delta_ref[0, :, qs])
+    return q, k, do, pT.astype(do.dtype), dsT.astype(q.dtype)
+
+
+def _visible_pairs(refs, qi, ki, accumulate, *, sm_scale, causal,
+                   block_q, block_k):
+    """accumulate(qs, ks, *_pair_grads(...)) for every chunk pair of the
+    block pair (qi, ki) in which some query sees some key: masked where
+    the pair straddles the diagonal, plain where every key is visible,
+    not at all where none is. Two bodies under pl.when, not a cond that
+    yields the score tile: at (512, 512) blocks that cond alone was 0.5
+    of 2.1 ms a call (PERF.md section 6, PR 41)."""
+    cq, ck = _chunk(block_q), _chunk(block_k)
+
+    def pair(qs, ks, masked):
+        accumulate(qs, ks, *_pair_grads(
+            *refs, qs, ks, qi * block_q + qs.start,
+            ki * block_k + ks.start, sm_scale, masked))
+
+    for k0 in range(0, block_k, ck):
+        for q0 in range(0, block_q, cq):
+            qs, ks = slice(q0, q0 + cq), slice(k0, k0 + ck)
+            if not causal:
+                pair(qs, ks, False)
+                continue
+            q_first, k_first = qi * block_q + q0, ki * block_k + k0
+            visible = q_first + cq - 1 >= k_first
+            straddles = k_first + ck - 1 > q_first
+            _when(_and(visible, straddles))(
+                functools.partial(pair, qs, ks, True))
+            _when(_and(visible, _not(straddles)))(
+                functools.partial(pair, qs, ks, False))
+
+
+def _dq_kernel(*refs, sm_scale, causal, block_q, block_k, nk):
+    dq_ref, acc_scr = refs[6:]
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    ki = _grid_index(2, nk)
     last_ki = nk - 1
     if causal:
         last_ki = ((qi + 1) * block_q - 1) // block_k
 
-    @pl.when(ki == 0)
+    @_when(ki == 0)
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(ki <= last_ki)
-    def _step():
-        _, k, _, _, ds = _pair_grads(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            qi, ki, sm_scale, causal, block_q, block_k)
-        acc_scr[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def accumulate(qs, ks, q, k, do, pT, dsT):
+        acc_scr[qs, :] += _dot(dsT, k, _TN)
 
-    @pl.when(ki == last_ki)
+    _visible_pairs(refs[:6], qi, ki, accumulate, sm_scale=sm_scale,
+                   causal=causal, block_q=block_q, block_k=block_k)
+
+    @_when(ki == last_ki)
     def _finalize():
         dq_ref[0] = (acc_scr[:] * sm_scale).astype(dq_ref.dtype)
 
 
-def _pair_grads(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                qi, ki, sm_scale, causal, block_q, block_k):
-    """Shared per-(qi, ki)-pair backward math: recompute S (masked only
-    on diagonal-straddling blocks), P from the stored lse, dP, dS.
-    Consumed by the split dkv kernel and the kv-major kernel so the
-    core gradient algebra lives in exactly one place."""
-    q = q_ref[0] * sm_scale
-    k = k_ref[0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    if causal:
-        s = _mask_if_straddling(s, qi, ki, block_q, block_k)
-    p = jnp.exp(s - lse_ref[0])                       # [bq, bk]
-    do = do_ref[0]
-    dp = jax.lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0])                      # [bq, bk]
-    return q, k, do, p, ds
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale, causal,
-                block_q, block_k, nq):
+def _dkv_kernel(*refs, sm_scale, causal, block_q, block_k, nq):
     """dk/dv sweep (grid bh, ki, qi; VMEM-scratch accumulation over
     qi) — the large-T fallback arm of the split backward."""
+    dk_ref, dv_ref, dk_scr, dv_scr = refs[6:]
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    first_qi = 0
-    if causal:
-        first_qi = (ki * block_k) // block_q
+    qi = _grid_index(2, nq)
 
-    @pl.when(qi == 0)
+    @_when(qi == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(qi >= first_qi)
-    def _step():
-        q, k, do, p, ds = _pair_grads(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            qi, ki, sm_scale, causal, block_q, block_k)
-        dv_scr[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [bk, d]
-        dk_scr[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [bk, d]
+    def accumulate(qs, ks, q, k, do, pT, dsT):
+        dv_scr[ks, :] += _dot(pT, do, _NN)                   # [ck, d]
+        dk_scr[ks, :] += _dot(dsT, q, _NN)                   # [ck, d]
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    _visible_pairs(refs[:6], qi, ki, accumulate, sm_scale=sm_scale,
+                   causal=causal, block_q=block_q, block_k=block_k)
+
+    @_when(qi == nq - 1)
     def _finalize():
         # dk needs no extra sm_scale: the accumulation used the
         # already-scaled q, which carries the factor
@@ -483,9 +571,8 @@ def _onepass_vmem_bytes(T, d, bq, bk, out_itemsize):
     return int(acc + outs + 3 * blocks) + 6 * 1024 * 1024
 
 
-def _bwd_onepass_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                        dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
-                        *, sm_scale, causal, block_q, block_k, nq, nk):
+def _bwd_onepass_kernel(*refs, sm_scale, causal, block_q, block_k, nq,
+                        nk):
     """Round-5 single-pass backward: grid (bh, qi, ki), BOTH inner dims
     sequential. Each visited pair computes S, P, dP, dS once and does
     exactly the 5 block-matmuls the gradients need. dq accumulates in a
@@ -495,41 +582,35 @@ def _bwd_onepass_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     every supported long-context shape — and are written to HBM once at
     the final grid step (their output blocks span the whole sequence,
     index-mapped constant, so Pallas keeps one buffer live)."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = refs[6:]
+    qi = _grid_index(1, nq)
+    ki = _grid_index(2, nk)
     last_ki = nk - 1
     if causal:
         last_ki = ((qi + 1) * block_q - 1) // block_k
 
-    @pl.when((qi == 0) & (ki == 0))
+    @_when(_and(qi == 0, ki == 0))
     def _init_kv():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(ki == 0)
+    @_when(ki == 0)
     def _init_q():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(ki <= last_ki)
-    def _step():
-        q, k, do, p, ds = _pair_grads(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            qi, ki, sm_scale, causal, block_q, block_k)
-        dq_scr[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dv_scr[ki] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [bk, d]
-        dk_scr[ki] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [bk, d]
+    def accumulate(qs, ks, q, k, do, pT, dsT):
+        dq_scr[qs, :] += _dot(dsT, k, _TN)                   # [cq, d]
+        dv_scr[ki, ks, :] += _dot(pT, do, _NN)               # [ck, d]
+        dk_scr[ki, ks, :] += _dot(dsT, q, _NN)               # [ck, d]
 
-    @pl.when(ki == last_ki)
+    _visible_pairs(refs[:6], qi, ki, accumulate, sm_scale=sm_scale,
+                   causal=causal, block_q=block_q, block_k=block_k)
+
+    @_when(ki == last_ki)
     def _fin_q():
         dq_ref[0] = (dq_scr[:] * sm_scale).astype(dq_ref.dtype)
 
-    @pl.when((qi == nq - 1) & (ki == nk - 1))
+    @_when(_and(qi == nq - 1, ki == nk - 1))
     def _fin_kv():
         # q carried sm_scale into dk's accumulation already
         dk_ref[0] = dk_scr[:].reshape(dk_ref.shape[1:]) \
@@ -546,8 +627,8 @@ def _kvmajor_vmem_bytes(T, d, bq, bk, out_itemsize):
     dq_out = T * d * out_itemsize
     kv_scr = 2 * bk * d * 4
     # streaming traffic at the I/O dtype: q/do (bq,d) + k/v (bk,d) +
-    # dk/dv output blocks (bk,d), plus fp32 lse/delta (bq,1) — triple-
-    # buffered as the worst case Mosaic schedules
+    # dk/dv output blocks (bk,d), plus fp32 lse/delta (1,bq) rows —
+    # triple-buffered as the worst case Mosaic schedules
     stream = (2 * bq * d + 4 * bk * d) * out_itemsize + 2 * bq * 4
     # Mosaic's stack accounting runs WELL above the component sum and
     # varies with the surrounding program: the isolated 8k/128/BH=16
@@ -555,75 +636,79 @@ def _kvmajor_vmem_bytes(T, d, bq, bk, out_itemsize):
     # longcontext program 16.94M — ~5.7 MB over the raw component sum
     # (est. 11.3M). The margin must absorb that whole class, not just
     # libtpu drift; 8 MB grants 19.3M at 8k/128 and scales with the
-    # component terms at larger T.
-    return int(dq_acc + dq_out + kv_scr + 3 * stream) + 8 * 1024 * 1024
+    # component terms at larger T. (The score tiles are in it: a chunk
+    # pair holds what a (512, 512) block held.)
+    # Past T=8k that is not enough with libtpu 0.0.34: compiled for a
+    # described v5e the kernel asks 36.6M at 32k and 66.8M at 64k, 11.4
+    # and 16.4 MB over the components (PR 41; no chip involved), so the
+    # margin grows with the resident dq block and 64k now goes to split.
+    return int(dq_acc + dq_out + kv_scr + 3 * stream + dq_out // 2) \
+        + 8 * 1024 * 1024
 
 
-def _bwd_kvmajor_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                        dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
-                        *, sm_scale, causal, block_q, block_k, nq, nk):
+def _bwd_kvmajor_kernel(*refs, sm_scale, causal, block_q, block_k, nq,
+                        nk):
     """kv-major single-pass backward: grid (bh, ki, qi), both inner
-    dims sequential. Each visited (ki, qi) pair computes S, P, dP, dS
-    once — the 5-matmul + 1-exp minimum (the split arm pays 7 + 2).
-    dk/dv accumulate in per-ki scratch flushed at each row's end (as in
-    the split dkv kernel); dq accumulates across the WHOLE sweep in a
-    full-sequence (nq, bq, d) fp32 scratch — T·d·4 = 4 MB at 8k/128,
-    HALF the residency of the onepass arm whose 12 MB starved Mosaic's
-    double-buffering — and is written to HBM once at the final grid
-    step (dq's output block spans the sequence, index-mapped constant,
-    so Pallas keeps one live buffer)."""
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    first_qi = 0
-    if causal:
-        first_qi = (ki * block_k) // block_q
+    dims sequential. Each visible chunk pair of a (ki, qi) block pair
+    computes S, P, dP, dS once — the 5-matmul + 1-exp minimum (the split
+    arm pays 7 + 2) — and a hidden one is skipped outright; where a
+    block spans the sequence (nq == nk == 1, the training cells' shape)
+    which pairs those are is known while tracing, and a head is one grid
+    step of ten pairs with no predicate. dk/dv accumulate in per-ki
+    scratch flushed at each row's end (as in the split dkv kernel); dq
+    accumulates across the WHOLE sweep in a full-sequence (nq, bq, d)
+    fp32 scratch — T·d·4 = 4 MB at 8k/128, HALF the residency of the
+    onepass arm whose 12 MB starved Mosaic's double-buffering — and is
+    written to HBM once at the final grid step (dq's output block spans
+    the sequence, index-mapped constant, so Pallas keeps one live
+    buffer)."""
+    dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = refs[6:]
+    ki = _grid_index(1, nk)
+    qi = _grid_index(2, nq)
 
-    @pl.when((ki == 0) & (qi == 0))
+    @_when(_and(ki == 0, qi == 0))
     def _init_dq():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(qi == 0)
+    @_when(qi == 0)
     def _init_kv():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(qi >= first_qi)
-    def _step():
-        q, k, do, p, ds = _pair_grads(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            qi, ki, sm_scale, causal, block_q, block_k)
-        dv_scr[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [bk, d]
-        dk_scr[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [bk, d]
-        dq_scr[qi] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [bq, d]
+    def accumulate(qs, ks, q, k, do, pT, dsT):
+        dv_scr[ks, :] += _dot(pT, do, _NN)                   # [ck, d]
+        dk_scr[ks, :] += _dot(dsT, q, _NN)                   # [ck, d]
+        dq_scr[qi, qs, :] += _dot(dsT, k, _TN)               # [cq, d]
 
-    @pl.when(qi == nq - 1)
+    _visible_pairs(refs[:6], qi, ki, accumulate, sm_scale=sm_scale,
+                   causal=causal, block_q=block_q, block_k=block_k)
+
+    @_when(qi == nq - 1)
     def _fin_kv():
         # dk needs no extra sm_scale: the accumulation used the
         # already-scaled q, which carries the factor
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
-    @pl.when((ki == nk - 1) & (qi == nq - 1))
+    @_when(_and(ki == nk - 1, qi == nq - 1))
     def _fin_dq():
         dq_ref[0] = (dq_scr[:] * sm_scale) \
             .reshape(dq_ref.shape[1:]).astype(dq_ref.dtype)
 
 
-# (T, d) -> (block_q, block_k) overrides. The round-4 one-process-per-
-# config sweep could not resolve differences inside the chip's noise
-# band (honest null, PERF.md round-4); the round-5 INTERLEAVED
-# in-process sweep (tools/flash_autotune.py) did: bk=1024 wins at
-# every bq in every round at T=8192 (median 11.7 vs 20.6 ms for
-# 512x512), and the full long-context bench confirms +8-10% MFU
-# across 3 interleaved rounds (PERF.md round-5 autotune section).
+# (T, d) -> (block_q, block_k) of the backward. (8192, 128): an earlier
+# chip's interleaved sweep (tools/flash_autotune.py) ranked bk=1024
+# first at every bq; on this one that entry reads 6.60 ms a call at
+# BH=16 as the kernel was and 4.60 as it is, (2048, 1024) 4.33 and
+# (2048, 2048) 8.7 (sixteen chunk pairs under predicates in one step).
+# (2048, 128), the training cells' shape (BH=64 a chip): the whole head
+# one block, so a head is one grid step and which ten of its sixteen
+# chunk pairs are visible is fixed while tracing -- 1.25 ms a call
+# against (1024, 1024) 1.45, (2048, 1024) and (1024, 2048) 1.42,
+# (2048, 512) 1.42, (512, 512) 1.58 (PERF.md section 6, PR 41).
 _BLOCK_TABLE = {
     (8192, 128): (512, 1024),
+    (2048, 128): (2048, 2048),
 }
 
 # The forward and backward only share (o, lse), which are block-size
@@ -698,8 +783,12 @@ def _fwd_kvmap(causal, bq, bk):
 
 
 @functools.partial(jax.jit, static_argnames=('causal', 'sm_scale',
-                                             'interpret'))
-def _fwd(q, k, v, causal, sm_scale, interpret=False):
+                                             'interpret', 'lse_rows'))
+def _fwd(q, k, v, causal, sm_scale, interpret=False, lse_rows=False):
+    """(o, lse): lse float32 as [BH, T, 1] columns (what the ring's
+    merge and the tools take), or with lse_rows as the [BH, 1, T] rows
+    the online kernel writes and _bwd reads -- the layout _flash keeps
+    between the two, so that no relayout stands in a training step."""
     BH, T, d = q.shape
     # Arm selection mirrors _bwd: forced via PADDLE_FLASH_FWD, else
     # online (see the arm comment block at the top). Block sizes
@@ -716,11 +805,9 @@ def _fwd(q, k, v, causal, sm_scale, interpret=False):
     _RESOLVED_FWD_ARM, _RESOLVED_FWD_BLOCKS = arm, (bq, bk)
     _FWD_SCHEDULE[arm].inc()
     nq, nk = T // bq, T // bk
-    if arm == 'twopass':
-        return _fwd_twopass(q, k, v, causal, sm_scale, interpret,
-                            bq, bk, nq, nk)
-    return _fwd_online(q, k, v, causal, sm_scale, interpret,
-                       bq, bk, nq, nk)
+    o, lse = (_fwd_twopass if arm == 'twopass' else _fwd_online)(
+        q, k, v, causal, sm_scale, interpret, bq, bk, nq, nk)
+    return o, lse.reshape((BH, 1, T) if lse_rows else (BH, T, 1))
 
 
 def _fwd_online(q, k, v, causal, sm_scale, interpret, bq, bk, nq, nk):
@@ -732,11 +819,10 @@ def _fwd_online(q, k, v, causal, sm_scale, interpret, bq, bk, nq, nk):
     # lse leaves the kernel as [BH, 1, T] rows where the q block tiles
     # into lanes: a (bq, 1) column block is lane-padded to 256 KB at
     # bq=512, in VMEM and in HBM, and writing it cost 0.26 ms of a
-    # 1.17 ms call at BH=64, T=2048 (PERF.md section 6, PR 37). The
-    # reshape below restores the [BH, T, 1] the backward arms and
-    # ring_attention's merge consume.
+    # 1.17 ms call at BH=64, T=2048 (PERF.md section 6, PR 37). _fwd
+    # hands on the layout its caller asked for.
     lse_rows = bq % 128 == 0
-    o, lse = pl.pallas_call(
+    return pl.pallas_call(
         kern,
         grid=(BH, nq, nk),
         in_specs=[
@@ -768,7 +854,6 @@ def _fwd_online(q, k, v, causal, sm_scale, interpret, bq, bk, nq, nk):
             dimension_semantics=('parallel', 'parallel', 'arbitrary')),
         interpret=interpret,
     )(q, k, v)
-    return o, lse.reshape(BH, T, 1)
 
 
 def _fwd_twopass(q, k, v, causal, sm_scale, interpret, bq, bk, nq, nk):
@@ -838,15 +923,23 @@ def _fwd_twopass(q, k, v, causal, sm_scale, interpret, bq, bk, nq, nk):
 @functools.partial(jax.jit, static_argnames=('causal', 'sm_scale',
                                              'interpret'))
 def _bwd(q, k, v, o, lse, do, causal, sm_scale, interpret=False):
+    """(dq, dk, dv). lse is [BH, 1, T] rows (_fwd's lse_rows layout): a
+    caller that holds columns reshapes at this boundary, as the ring
+    does. delta is made as rows too, and every arm fetches both as
+    (1, 1, bq) blocks: 2 KB where a (bq, 1) column block was lane-padded
+    to 256 KB, and no [BH, T, 1] array (67 MB padded at BH=64, T=2048)
+    is written or relaid out anywhere."""
     BH, T, d = q.shape
     bq, bk = _block_sizes(T, d)
+    if bq % 128 and not interpret:
+        raise ValueError('flash backward: block_q=%d does not tile the '
+                         '128 lanes a row of lse is fetched by' % bq)
     nq, nk = T // bq, T // bk
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)            # [BH, T, 1]
+                    axis=-1).reshape(BH, 1, T)
 
-    # Arm selection: forced via PADDLE_FLASH_BWD, else kv-major — the
-    # measured default (−25% vs split at T=2048..16384, parity at
-    # T=512; PERF.md round-5 kv-major section). Residency guards:
+    # Arm selection: forced via PADDLE_FLASH_BWD, else kv-major (the
+    # ranking is in the arm comment at the top). Residency guards:
     # onepass needs its dk/dv full-sequence fp32 accumulators +
     # resident outputs to fit (T=8k/d=128 ~ 18 MB with the raised
     # scoped-vmem limit); kvmajor guards its whole scoped-VMEM request
@@ -861,43 +954,35 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, interpret=False):
     if arm == 'kvmajor' and _kvmajor_vmem_bytes(
             T, d, bq, bk, q.dtype.itemsize) > 64 * 1024 * 1024:
         arm = 'split'
-    global _RESOLVED_ARM
-    _RESOLVED_ARM = arm
-    if arm == 'kvmajor':
-        return _bwd_kvmajor(q, k, v, do, lse, delta, causal, sm_scale,
-                            interpret, bq, bk, nq, nk)
-    if arm != 'onepass':
-        return _bwd_split(q, k, v, do, lse, delta, causal, sm_scale,
-                          interpret, bq, bk, nq, nk)
-    dq, dk, dv = pl.pallas_call(
+    global _RESOLVED_ARM, _RESOLVED_BWD_BLOCKS
+    _RESOLVED_ARM, _RESOLVED_BWD_BLOCKS = arm, (bq, bk)
+    _BWD_SCHEDULE[arm].inc()
+    return {'kvmajor': _bwd_kvmajor, 'split': _bwd_split,
+            'onepass': _bwd_onepass}[arm](
+        q, k, v, do, lse, delta, causal, sm_scale, interpret,
+        bq, bk, nq, nk)
+
+
+def _vmem(block, index_map):
+    return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
+
+
+def _bwd_onepass(q, k, v, do, lse, delta, causal, sm_scale, interpret,
+                 bq, bk, nq, nk):
+    BH, T, d = q.shape
+    qspec = _vmem((1, bq, d), lambda b, i, j: (b, i, 0))
+    kspec = _vmem((1, bk, d), lambda b, i, j: (b, j, 0))
+    rowspec = _vmem((1, 1, bq), lambda b, i, j: (b, 0, i))
+    # dk/dv blocks span the whole sequence, index-mapped constant: one
+    # live buffer, flushed once at the end
+    wholespec = _vmem((1, T, d), lambda b, i, j: (b, 0, 0))
+    return pl.pallas_call(
         functools.partial(_bwd_onepass_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=bq, block_k=bk,
                           nq=nq, nk=nk),
         grid=(BH, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            # dk/dv blocks span the whole sequence, index-mapped
-            # constant: one live buffer, flushed once at the end
-            pl.BlockSpec((1, T, d), lambda b, i, j: (b, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, T, d), lambda b, i, j: (b, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+        in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
+        out_specs=[qspec, wholespec, wholespec],
         out_shape=[
             jax.ShapeDtypeStruct((BH, T, d), q.dtype),
             jax.ShapeDtypeStruct((BH, T, d), k.dtype),
@@ -916,37 +1001,24 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, interpret=False):
                 T, d, bq, bk, k.dtype.itemsize)),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
-    return dq, dk, dv
 
 
 def _bwd_split(q, k, v, do, lse, delta, causal, sm_scale, interpret,
                bq, bk, nq, nk):
-    """Two-kernel backward for LARGE grids: at nk > 2 the fused
-    kernel's per-(ki, qi) dq-partial flush to HBM costs more than the
-    S/dp recompute it saves (measured T=8192: split 16.7 ms vs fused
-    21.3 ms), while at nk <= 2 the fused path wins big (T=512: 1.0 vs
-    2.8 ms — one launch, no recompute). _bwd dispatches on nk."""
+    """Two-kernel backward (a dq sweep, a dk/dv sweep: every pair's
+    tiles are computed twice): what kv-major falls back to when its
+    resident dq block would not fit, and 1.71 ms against 1.25 at the
+    training cells' shape (PERF.md section 6, PR 41)."""
     BH, T, d = q.shape
+    qspec = _vmem((1, bq, d), lambda b, i, j: (b, i, 0))
+    kspec = _vmem((1, bk, d), lambda b, i, j: (b, j, 0))
+    rowspec = _vmem((1, 1, bq), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=bq, block_k=bk, nk=nk),
         grid=(BH, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0),
-                               memory_space=pltpu.VMEM),
+        in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
+        out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((BH, T, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
@@ -954,30 +1026,15 @@ def _bwd_split(q, k, v, do, lse, delta, causal, sm_scale, interpret,
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
+    qspec = _vmem((1, bq, d), lambda b, j, i: (b, i, 0))
+    kspec = _vmem((1, bk, d), lambda b, j, i: (b, j, 0))
+    rowspec = _vmem((1, 1, bq), lambda b, j, i: (b, 0, i))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=bq, block_k=bk, nq=nq),
         grid=(BH, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+        in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
+        out_specs=[kspec, kspec],
         out_shape=[
             jax.ShapeDtypeStruct((BH, T, d), k.dtype),
             jax.ShapeDtypeStruct((BH, T, d), v.dtype),
@@ -999,40 +1056,28 @@ def _bwd_kvmajor(q, k, v, do, lse, delta, causal, sm_scale, interpret,
     row; q-side blocks stream per step as in the split dkv kernel."""
     BH, T, d = q.shape
 
-    def qmap(b, j, i):
+    def first_qi(j, i):
         # During causally-skipped steps (i < first_qi(j)) clamp the
         # q-side fetch to the first visited block: the block index is
         # then unchanged step-to-step, so Mosaic elides the dead DMA.
         # (_CLAMP_SKIPPED_DMA is the trace-time A/B hook.)
         if causal and _CLAMP_SKIPPED_DMA:
             i = jnp.maximum(i, (j * bk) // bq)
-        return (b, i, 0)
+        return i
 
-    dq, dk, dv = pl.pallas_call(
+    qspec = _vmem((1, bq, d), lambda b, j, i: (b, first_qi(j, i), 0))
+    kspec = _vmem((1, bk, d), lambda b, j, i: (b, j, 0))
+    rowspec = _vmem((1, 1, bq), lambda b, j, i: (b, 0, first_qi(j, i)))
+    # dq's block spans the whole sequence, index-mapped constant: one
+    # live buffer, flushed once at the end
+    wholespec = _vmem((1, T, d), lambda b, j, i: (b, 0, 0))
+    return pl.pallas_call(
         functools.partial(_bwd_kvmajor_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=bq, block_k=bk,
                           nq=nq, nk=nk),
         grid=(BH, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), qmap, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, d), qmap, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 1), qmap, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 1), qmap, memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            # dq's block spans the whole sequence, index-mapped
-            # constant: one live buffer, flushed once at the end
-            pl.BlockSpec((1, T, d), lambda b, j, i: (b, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+        in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
+        out_specs=[wholespec, kspec, kspec],
         out_shape=[
             jax.ShapeDtypeStruct((BH, T, d), q.dtype),
             jax.ShapeDtypeStruct((BH, T, d), k.dtype),
@@ -1047,17 +1092,16 @@ def _bwd_kvmajor(q, k, v, do, lse, delta, causal, sm_scale, interpret,
                 T, d, bq, bk, q.dtype.itemsize)),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
-    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash(q, k, v, causal, sm_scale, interpret):
-    o, _ = _fwd(q, k, v, causal, sm_scale, interpret)
+    o, _ = _fwd(q, k, v, causal, sm_scale, interpret, lse_rows=True)
     return o
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, interpret):
-    o, lse = _fwd(q, k, v, causal, sm_scale, interpret)
+    o, lse = _fwd(q, k, v, causal, sm_scale, interpret, lse_rows=True)
     return o, (q, k, v, o, lse)
 
 

@@ -253,7 +253,7 @@ def _flash_bwd_block(q, kb, vb, o, lse, g, causal, scale):
         return x.reshape(B * H, Tl, -1)
     if _supported(Tl, dh) and _kernel_enabled():
         dq, dk, dv = _bwd(flat(q), flat(kb), flat(vb), flat(o),
-                          lse.reshape(B * H, Tl, 1), flat(g),
+                          lse.reshape(B * H, 1, Tl), flat(g),
                           causal, scale,
                           jax.default_backend() != 'tpu')
     else:

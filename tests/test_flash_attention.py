@@ -497,9 +497,11 @@ def flash_counters():
     was = telemetry.enabled()
     telemetry.enable()
 
-    def read():
-        c = telemetry.snapshot()['counters']
-        return {k: c['pallas.flash.fwd.' + k] for k in ('online', 'twopass')}
+    def read(direction='fwd'):
+        prefix = 'pallas.flash.%s.' % direction
+        return {k[len(prefix):]: n
+                for k, n in telemetry.snapshot()['counters'].items()
+                if k.startswith(prefix)}
     yield read
     if not was:
         telemetry.disable()
@@ -516,13 +518,13 @@ def _trace_fwd(fa, T, d=128, dtype=jnp.bfloat16):
 
 def test_training_cell_shape_gets_its_table_entry():
     """(2048, 128) is the shape both training cells run: the online
-    sweep at the blocks the chip A/B of PR 37 ranked first, for the
-    forward alone; the backward's blocks, (8192, 128) and an unlisted T
-    are what they were."""
+    sweep at the blocks the chip A/B of PR 37 ranked first for the
+    forward, and for the backward the whole head in one block (PR 41);
+    (8192, 128) and an unlisted T are what they were."""
     from paddle_tpu.pallas import flash_attention as fa
     assert fa._BLOCK_TABLE_FWD[(2048, 128)] == (1024, 1024)
     assert fa._block_sizes(2048, 128, fwd=True) == (1024, 1024)
-    assert fa._block_sizes(2048, 128) == (512, 512)
+    assert fa._block_sizes(2048, 128) == (2048, 2048)
     assert fa._block_sizes(8192, 128, fwd=True) == (1024, 1024)
     assert fa._block_sizes(8192, 128) == (512, 1024)
     assert fa._block_sizes(4096, 128, fwd=True) == (512, 512)
@@ -656,3 +658,194 @@ def test_flash_autotune_quick_smoke():
                    - 100.0) < 0.5
     finally:
         sys.path.remove(tools)
+
+
+# --- the backward's rows, orientation and chunk walk (PR 41) ---------
+
+def _bwd_blocks(fa, monkeypatch, T, d, bq, bk, chunk):
+    monkeypatch.setattr(fa, '_BWD_CHUNK', chunk)
+    monkeypatch.setitem(fa._BLOCK_TABLE, (T, d), (bq, bk))
+    fa._bwd.clear_cache()
+
+
+@pytest.mark.parametrize('bq,bk,chunk', [
+    (512, 512, 128),    # the cells' structure: the whole sequence one
+                        # block, its 4 x 4 chunk pairs fixed when tracing
+    (256, 512, 128),    # bq != bk, chunks walked under predicates
+    (512, 256, 256),    # bq != bk the other way, one row of chunks
+    (128, 256, 512)])   # no chunk walk: the block is the pair
+@pytest.mark.parametrize('causal', [False, True])
+def test_backward_body_matches_naive(causal, bq, bk, chunk, monkeypatch):
+    """The transposed pair function under the kv-major grid, chunk walk
+    on and off: (dq, dk, dv) against the naive contraction's."""
+    from paddle_tpu.pallas import flash_attention as fa
+    rng = np.random.RandomState(21)
+    BH, T, d = 2, 512, 128
+    q = jnp.asarray(rng.randn(BH, T, d).astype('float32')) * 0.3
+    k = jnp.asarray(rng.randn(BH, T, d).astype('float32')) * 0.3
+    v = jnp.asarray(rng.randn(BH, T, d).astype('float32'))
+    scale = d ** -0.5
+    _bwd_blocks(fa, monkeypatch, T, d, bq, bk, chunk)
+    try:
+        gk = jax.grad(lambda *a: jnp.sum(
+            _flash(*a, causal, scale, INTERPRET) ** 2), (0, 1, 2))(q, k, v)
+        assert (fa._RESOLVED_ARM, fa._RESOLVED_BWD_BLOCKS) \
+            == ('kvmajor', (bq, bk))
+    finally:
+        fa._bwd.clear_cache()
+    gn = jax.grad(lambda *a: jnp.sum(
+        _naive(*a, causal, scale) ** 2), (0, 1, 2))(q, k, v)
+    for name, a, b in zip('qkv', gk, gn):
+        rel = float(jnp.abs(a - b).max()) / (float(jnp.abs(b).max()) + 1e-9)
+        assert rel < 2e-2, 'd%s rel err %.3e' % (name, rel)
+
+
+@pytest.mark.parametrize('arm', ['kvmajor', 'split', 'onepass'])
+def test_backward_takes_a_global_lse_larger_than_the_blocks_own(
+        arm, monkeypatch):
+    """The ring's case: the second half of a causal sequence's queries
+    against the earlier keys (no mask) and its own (causal), each call
+    handed the lse over ALL keys, so exp(s - lse) sums to less than one
+    a block; the two calls' dq add up to the whole's and each block's
+    dk, dv are its own."""
+    from paddle_tpu.pallas import flash_attention as fa
+    rng = np.random.RandomState(22)
+    BH, T, d = 2, 256, 128
+    q, k, v = (jnp.asarray(rng.randn(BH, 2 * T, d).astype('float32')) * s
+               for s in (0.3, 0.3, 1.0))
+    do = jnp.asarray(rng.randn(BH, T, d).astype('float32'))
+    scale = d ** -0.5
+
+    def late_rows(q, k, v):
+        return _naive(q, k, v, True, scale)[:, T:]
+    o, vjp = jax.vjp(late_rows, q, k, v)
+    dq_n, dk_n, dv_n = vjp(do)
+    s = jnp.einsum('bqd,bkd->bqk', q, k) * scale
+    s = jnp.where(jnp.tril(jnp.ones((2 * T, 2 * T), bool))[None], s, -jnp.inf)
+    lse = jax.scipy.special.logsumexp(s, axis=-1)[:, T:].reshape(BH, 1, T)
+    _, own = fa._fwd(q[:, T:], k[:, T:], v[:, T:], True, scale, INTERPRET,
+                     lse_rows=True)
+    assert float((lse - own).min()) > 0        # larger on every row
+
+    monkeypatch.setattr(fa, '_FORCE_ARM', arm)
+    _bwd_blocks(fa, monkeypatch, T, d, 128, 256, 128)
+    try:
+        early = fa._bwd(q[:, T:], k[:, :T], v[:, :T], o, lse, do, False,
+                        scale, INTERPRET)
+        home = fa._bwd(q[:, T:], k[:, T:], v[:, T:], o, lse, do, True,
+                       scale, INTERPRET)
+        assert fa._RESOLVED_ARM == arm
+    finally:
+        fa._bwd.clear_cache()
+    for name, got, want in (
+            ('dq', early[0] + home[0], dq_n[:, T:]),
+            ('dk early', early[1], dk_n[:, :T]), ('dk', home[1], dk_n[:, T:]),
+            ('dv early', early[2], dv_n[:, :T]), ('dv', home[2], dv_n[:, T:])):
+        rel = float(jnp.abs(got - want).max()) \
+            / (float(jnp.abs(want).max()) + 1e-9)
+        assert rel < 2e-2, '%s rel err %.3e' % (name, rel)
+
+
+def test_backward_in_bf16_keeps_the_contract(monkeypatch):
+    """bf16 in, as the cells run it: gradients in the input dtype, and
+    no further from a float32 reference than the arithmetic of the
+    kernel this one replaced -- q scaled in bf16, float32 scores and
+    accumulation, p and ds cast to bf16 before their products -- which
+    it keeps, transposed."""
+    from paddle_tpu.pallas import flash_attention as fa
+    rng = np.random.RandomState(23)
+    BH, T, d = 2, 512, 128
+    q, k, v, do = (jnp.asarray(rng.randn(BH, T, d), jnp.bfloat16)
+                   for _ in range(4))
+    scale = d ** -0.5
+    f32 = jnp.float32
+    _, vjp = jax.vjp(lambda *a: _naive(*a, True, scale),
+                     q.astype(f32), k.astype(f32), v.astype(f32))
+    want = vjp(do.astype(f32))
+
+    _bwd_blocks(fa, monkeypatch, T, d, 512, 512, 256)
+    try:
+        o, lse = fa._fwd(q, k, v, True, scale, INTERPRET, lse_rows=True)
+        got = fa._bwd(q, k, v, o, lse, do, True, scale, INTERPRET)
+    finally:
+        fa._bwd.clear_cache()
+
+    def dot(eq, a, b):
+        return jnp.einsum(eq, a, b, preferred_element_type=f32)
+    qs = q * jnp.asarray(scale, q.dtype)
+    s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None],
+                  dot('bqd,bkd->bqk', qs, k), -1e30)
+    p = jnp.exp(s - lse.reshape(BH, T, 1))
+    delta = jnp.sum(do.astype(f32) * o.astype(f32), -1, keepdims=True)
+    ds = (p * (dot('bqd,bkd->bqk', do, v) - delta)).astype(q.dtype)
+    old = ((dot('bqk,bkd->bqd', ds, k) * scale).astype(q.dtype),
+           dot('bqk,bqd->bkd', ds, qs).astype(k.dtype),
+           dot('bqk,bqd->bkd', p.astype(do.dtype), do).astype(v.dtype))
+
+    for name, g, o_, w in zip(('dq', 'dk', 'dv'), got, old, want):
+        assert g.dtype == jnp.bfloat16 and g.shape == (BH, T, d)
+        ref = float(jnp.abs(w).max())
+        err = float(jnp.abs(g.astype(f32) - w).max()) / ref
+        err_old = float(jnp.abs(o_.astype(f32) - w).max()) / ref
+        assert err <= 1.05 * err_old + 1e-4, (name, err, err_old)
+        assert err < 2e-2, (name, err)
+
+
+@pytest.mark.parametrize('arm', ['kvmajor', 'split', 'onepass'])
+def test_bwd_schedule_counter_counts_one_a_trace(arm, monkeypatch,
+                                                 flash_counters):
+    """`pallas.flash.bwd.<arm>` says which backward a run compiled: one
+    increment a trace of _bwd, none for a call its cache answers, and
+    the blocks of that trace beside the resolved arm."""
+    from paddle_tpu.pallas import flash_attention as fa
+    rng = np.random.RandomState(24)
+    q = jnp.asarray(rng.randn(2, 256, 128).astype('float32')) * 0.3
+    monkeypatch.setattr(fa, '_FORCE_ARM', arm)
+    fa._bwd.clear_cache()
+    try:
+        o, lse = fa._fwd(q, q, q, True, 128 ** -0.5, INTERPRET,
+                         lse_rows=True)
+        before = flash_counters('bwd')
+        fa._bwd(q, q, q, o, lse, q, True, 128 ** -0.5, INTERPRET)
+        fa._bwd(q, q, q, o, lse, q, True, 128 ** -0.5, INTERPRET)
+        after = flash_counters('bwd')
+        assert fa._RESOLVED_ARM == arm
+        assert fa._RESOLVED_BWD_BLOCKS == (256, 256)
+    finally:
+        fa._bwd.clear_cache()
+    assert {a: after[a] - before[a] for a in after} \
+        == {a: int(a == arm) for a in fa._BWD_SCHEDULE}
+
+
+def _stat_blocks(fa, T, bq, bk, monkeypatch):
+    """(array shape, block shape) of lse and delta as the kv-major
+    pallas_call of a traced _bwd at [2, T, 128] fetches them."""
+    _bwd_blocks(fa, monkeypatch, T, 128, bq, bk, 512)
+    x = jax.ShapeDtypeStruct((2, T, 128), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((2, 1, T), jnp.float32)
+    try:
+        jaxpr = jax.make_jaxpr(lambda *a: fa._bwd(
+            *a, True, 128 ** -0.5, True))(x, x, x, x, lse, x)
+    finally:
+        fa._bwd.clear_cache()
+    calls = [e for e in jaxpr.jaxpr.eqns[-1].params['jaxpr'].eqns
+             if e.primitive.name == 'pallas_call']
+    assert len(calls) == 1
+    return [(tuple(m.array_aval.shape),
+             tuple(int(getattr(b, 'block_size', b)) for b in m.block_shape))
+            for m in calls[0].params['grid_mapping'].block_mappings[4:6]]
+
+
+@pytest.mark.parametrize('T,bq,bk', [(2048, 2048, 2048), (512, 128, 256)])
+def test_lse_and_delta_stay_rows_from_forward_to_backward(T, bq, bk,
+                                                          monkeypatch):
+    """Where the q block tiles 128 lanes: the residual _flash_fwd keeps
+    is the [BH, 1, T] the online kernel writes, and _bwd fetches it and
+    delta as (1, 1, bq) blocks -- no [BH, T, 1] column anywhere."""
+    from paddle_tpu.pallas import flash_attention as fa
+    x = jax.ShapeDtypeStruct((2, T, 128), jnp.bfloat16)
+    _, res = jax.eval_shape(
+        lambda *a: fa._flash_fwd(*a, True, 128 ** -0.5, True), x, x, x)
+    assert res[4].shape == (2, 1, T) and res[4].dtype == jnp.float32
+    assert _stat_blocks(fa, T, bq, bk, monkeypatch) \
+        == [((2, 1, T), (1, 1, bq))] * 2

@@ -263,3 +263,23 @@ def test_flash_forward_of_the_training_cells_compiles_for_v5e(v5e_2x2):
     o, lse = compiled.out_info
     assert (o.shape, str(o.dtype)) == ((64, 2048, 128), 'bfloat16')
     assert (lse.shape, str(lse.dtype)) == ((64, 2048, 1), 'float32')
+
+
+def test_flash_backward_of_the_training_cells_compiles_for_v5e(v5e_2x2):
+    """... and the backward they run: the whole head one block of ten
+    chunk pairs, lse and delta fetched as rows, inside the scoped VMEM
+    the kv-major estimate asks for."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.pallas import flash_attention as fa
+    one = SingleDeviceSharding(v5e_2x2[0])
+    x = jax.ShapeDtypeStruct((64, 2048, 128), jnp.bfloat16, sharding=one)
+    lse = jax.ShapeDtypeStruct((64, 1, 2048), jnp.float32, sharding=one)
+    fa._bwd.clear_cache()
+    compiled = fa._bwd.lower(x, x, x, x, lse, x, True, 128 ** -0.5,
+                             False).compile()
+    assert fa._RESOLVED_ARM == 'kvmajor'
+    assert fa._RESOLVED_BWD_BLOCKS == fa._BLOCK_TABLE[(2048, 128)]
+    assert 'tpu_custom_call' in compiled.as_text()
+    assert [(g.shape, str(g.dtype)) for g in compiled.out_info] \
+        == [((64, 2048, 128), 'bfloat16')] * 3

@@ -108,12 +108,16 @@ def timed_fwd(flash, q, k, v, iters, interpret=False):
     return loop
 
 
-def measure(flash, q, k, v, iters=6, fwd_only=False, interpret=False):
-    if fwd_only:
+def measure(flash, q, k, v, iters=6, fwd_only=False, interpret=False,
+            timed=None):
+    """ms a step by differencing an N- and a 2N-step in-jit loop;
+    `timed(flash, q, k, v, iters)` builds another loop than the two
+    here (tools/flash_bwd_arms.py: the backward alone)."""
+    if timed is None and fwd_only:
         def timed(flash, q, k, v, iters):
             return timed_fwd(flash, q, k, v, iters,
                              interpret=interpret)
-    else:
+    elif timed is None:
         # the fwd+bwd loop goes through _flash, which has no interpret
         # plumbing here — it is the chip-sweep path
         timed = timed_step
