@@ -220,6 +220,17 @@ def refuse_recurrent(spec, what):
                ','.join(map(str, spec.recurrent_layers))))
 
 
+def refuse_latent_pages(spec, what):
+    """Raise for a spec whose pages hold one latent row a token
+    (spec.page_kind 'latent': models/axk1.py) where `what` reads a page
+    as K and V heads."""
+    if spec.page_kind == 'latent':
+        raise DecodeTranspileError(
+            '%s cannot serve a model with latent_attention layers: a '
+            'page of theirs holds one latent row a token, not K and V '
+            'heads' % (what,))
+
+
 class DecodeSpec(object):
     """Dims + parameter names extracted from a loaded LM program.
 
@@ -236,11 +247,18 @@ class DecodeSpec(object):
 
     kinds names each layer's mixer: 'full_attention' (K/V in the cache:
     every layer of this block), or a kind of a spec that extends this
-    one (models/hybrid.py, models/nemotron_h.py). What a layer keeps for
-    a stream decides how it is served, not what it is called: K/V pages
-    (kv_layers: the cache variables exist for these only), per-slot
-    recurrent state beside the pools (recurrent_layers: the kinds the
-    spec's class names in `recurrent_kinds`), or nothing.
+    one (models/hybrid.py, models/nemotron_h.py, models/axk1.py). What
+    a layer keeps for a stream decides how it is served, not what it is
+    called: K/V pages (kv_layers: the cache variables exist for these
+    only; page_kind 'kv'), a latent page (kv_layers too, ONE pool a
+    layer of one row a token for all heads; page_kind 'latent'),
+    per-slot recurrent state beside the pools (recurrent_layers: the
+    kinds the spec's class names in `recurrent_kinds`), or nothing (an
+    expert layer). Pages of either kind are what the allocator, the
+    prefix cache, save_pages and page shipping move, sized by
+    pool_shape; only a program that reads a page as K and V heads
+    (speculative verify, the heads-sharded mesh layout) has to refuse
+    the latent kind (refuse_latent_pages).
 
     kv_heads is the number of K/V heads a page holds (query head h
     reads K/V head h // (heads / kv_heads)); the model's head count
@@ -249,6 +267,7 @@ class DecodeSpec(object):
 
     recurrent_kinds = ()
     state_family = None     # names the gauge serving.<family>.state_bytes
+    page_kind = 'kv'        # what a page of kv_layers holds
 
     def __init__(self, vocab, dim, heads, layers, ffn, max_len, pos_len,
                  emb_w, pos_w, blocks, final_ln, head, use_flash=False,
@@ -443,7 +462,8 @@ def _cached_block(x, spec, i, attention):
 
 
 def _create_pool_vars(spec, num_pages, page_tokens):
-    """{layer: (K, V)} page-pool vars of the layers that keep K/V:
+    """{layer: its page-pool vars} ((K, V), or the one pool of a layer
+    that keeps a latent page) of the layers that keep pages:
     persistable (the executor writes them back to the Scope each run —
     and donates them, so the update is in-place on device) but is_cache
     (io.py save/load skip them)."""
@@ -451,13 +471,12 @@ def _create_pool_vars(spec, num_pages, page_tokens):
     block = default_main_program().global_block()
     pools = {}
     for i in spec.kv_layers:
-        kn, vn = spec.pool_names(i)
         pools[i] = tuple(
             block.create_var(name=n,
                              shape=spec.pool_shape(num_pages, page_tokens),
                              dtype='float32', persistable=True,
                              stop_gradient=True, is_cache=True)
-            for n in (kn, vn))
+            for n in spec.pool_names(i))
     return pools
 
 
@@ -605,7 +624,10 @@ def _paged_verify_attention(x, spec, blk, pool, table, positions,
 
 def _paged_pos_embedding(spec, index, rows):
     """Positional rows gathered by absolute index (paged positions
-    never wrap): Index [rows] -> [1, rows, D] / [rows, 1, D]."""
+    never wrap): Index [rows] -> [1, rows, D] / [rows, 1, D]. This
+    block's only positional term: a learned row added at the embedding.
+    (A block whose attention takes a rotary term rotates q and k by the
+    same absolute index before the cache sees k: models/axk1.py.)"""
     from ..layer_helper import LayerHelper
     helper = LayerHelper('position_embedding',
                          param_attr=_named_attr(spec.pos_w))
@@ -779,6 +801,7 @@ def build_verify_program(spec, slots, k1, num_pages, page_tokens,
     """
     from ..framework import Program, program_guard
     refuse_recurrent(spec, 'the speculative verify program')
+    refuse_latent_pages(spec, 'the speculative verify program')
     prog, startup = Program(), Program()
     prog._is_test = True
     with program_guard(prog, startup):

@@ -157,19 +157,44 @@ register_vjp_grad('moe_aux_loss', in_slots=('Gate',))
 # of the sum that its own experts give, and nothing stands in for the
 # other chips or for the exchange with them.
 #
-#   s = sigmoid(W_r x);  the top_k largest of s + b;
+#   s = sigmoid(W_r x);  the top_k largest of s + b (among all experts,
+#   or, group-limited, among those of the topk_group best of n_group
+#   groups, a group scoring the sum of its two largest);
 #   w = scale * s_sel / sum(s_sel);  r = sum_{e held} w_e W2_e relu(W1_e l)^2
+#   or, for experts of three matrices,  sum_{e held} w_e W2_e (silu(W1_e l) * W3_e l)
 #
-# with l the token's latent row (the projection down to it and back up
-# are the block's own matmuls, outside this op). The router's product
+# with l the row the experts work on: the token's latent row (the
+# projection down to it and back up are the block's own matmuls,
+# outside this op), or x itself. The router's product
 # runs at precision "highest", as the published gate computes in
 # float32: a rounded score changes WHICH experts a token takes, not a
 # digit of the result. (A chosen expert's weight is never exactly 0: a
 # sigmoid is not, short of logits under -100.)
 
-def served_weights(x, router_w, bias, top_k, scale):
+def _within_kept_groups(b, n_group, topk_group):
+    """b [R, E] with the scores outside each row's `topk_group` best
+    groups set to -inf: the E experts are `n_group` consecutive groups,
+    a group scores the sum of its two largest entries, and the best
+    groups are found by rank as the experts are (an equal score of a
+    lower index counts as higher)."""
+    g = b.reshape(b.shape[0], n_group, -1)
+    first = jnp.argmax(g, axis=-1)
+    second = jnp.max(jnp.where(
+        jnp.arange(g.shape[-1]) == first[..., None], -jnp.inf, g), axis=-1)
+    score = jnp.max(g, axis=-1) + second                        # [R, G]
+    mine, other = score[:, :, None], score[:, None, :]
+    i = jnp.arange(n_group)
+    ahead = (other > mine) | ((other == mine) & (i[None, :] < i[:, None]))
+    kept = jnp.sum(ahead, axis=-1) < topk_group                 # [R, G]
+    return jnp.where(kept[..., None], g, -jnp.inf).reshape(b.shape)
+
+
+def served_weights(x, router_w, bias, top_k, scale, n_group=1,
+                   topk_group=1):
     """x [R, D], router_w [D, E], bias [E] -> w [R, E] float32: a row's
-    weight for each expert, 0 for those it did not choose.
+    weight for each expert, 0 for those it did not choose. With
+    n_group > 1 the choice is group-limited (the DeepSeek-V3 gate): a
+    row chooses among the experts of its topk_group best groups only.
 
     The k largest of s + b are found by rank, not by a sort: an expert
     is chosen when fewer than k others score higher (an equal score of
@@ -180,6 +205,8 @@ def served_weights(x, router_w, bias, top_k, scale):
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     b = s + bias.astype(jnp.float32)
+    if n_group > 1:
+        b = _within_kept_groups(b, n_group, topk_group)
     mine, other = b[:, :, None], b[:, None, :]
     e = jnp.arange(b.shape[1])
     ahead = (other > mine) | ((other == mine) & (e[None, :] < e[:, None]))
@@ -209,12 +236,26 @@ def held_experts(lat, w, w1, w2):
                       w2)
 
 
+def held_gated_experts(lat, w, w1, w3, w2):
+    """As held_experts for experts of three matrices: sum_e w[:, e]
+    W2_e (silu(W1_e lat) * W3_e lat), W1 and W3 [held, L, F], W2
+    [held, F, L]."""
+    h = jax.nn.silu(jnp.einsum('rl,elf->erf', lat, w1)) \
+        * jnp.einsum('rl,elf->erf', lat, w3)
+    return jnp.einsum('erf,efl->rl', h * w.T.astype(lat.dtype)[..., None],
+                      w2)
+
+
 @op_emitter('moe_experts')
 def _moe_experts_emit(ctx, op):
     """The held experts' part of a served expert layer. X [.., D] (what
     the router scores), Lat [.., L] (what the experts work on), RouterW
     [D, E], Bias [E], W1 [held, L, F], W2 [held, F, L]; attrs top_k,
-    scale, expert_offset -> Out [.., L]. Rows may be marked dead, by Live [rows] (a decode step's
+    scale, expert_offset, and n_group and topk_group where the choice
+    is group-limited (1 and 1: over all experts) -> Out [.., L]. The
+    experts' form follows from the weights handed in: with W3 [held, L,
+    F] beside W1 an expert is W2 (silu(W1 l) * W3 l), without it W2
+    relu(W1 l)^2. Rows may be marked dead, by Live [rows] (a decode step's
     lanes) or Len [1] (a chunk's rows from Len on): they choose nothing
     and count nothing. Stats [4] int32, where asked for, is this call's
     (pairs on held experts, held experts with at least one pair, pairs
@@ -230,14 +271,19 @@ def _moe_experts_emit(ctx, op):
     w = served_weights(
         x.reshape(rows, x.shape[-1]), ctx.get(op.single_input('RouterW')),
         ctx.get(op.single_input('Bias')), int(op.attr('top_k')),
-        float(op.attr('scale', 1.0)))[:, offset:offset + w1.shape[0]]
+        float(op.attr('scale', 1.0)), int(op.attr('n_group', 1)),
+        int(op.attr('topk_group', 1)))[:, offset:offset + w1.shape[0]]
     if op.input('Live'):
         w = jnp.where(ctx.get(op.single_input('Live')).astype(bool)
                       .reshape(rows)[:, None], w, 0.0)
     elif op.input('Len'):
         n = ctx.get(op.single_input('Len')).astype(jnp.int32).reshape(())
         w = jnp.where((jnp.arange(rows) < n)[:, None], w, 0.0)
-    out = held_experts(lat.reshape(rows, width), w, w1, w2)
+    if op.input('W3'):
+        out = held_gated_experts(lat.reshape(rows, width), w, w1,
+                                 ctx.get(op.single_input('W3')), w2)
+    else:
+        out = held_experts(lat.reshape(rows, width), w, w1, w2)
     ctx.set(op.single_output('Out'), out.reshape(lat.shape))
     if op.output('Stats'):
         ctx.set(op.single_output('Stats'), jnp.stack(

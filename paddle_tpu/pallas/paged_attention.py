@@ -37,6 +37,14 @@ A masked column contributes exp(-1e30 - m) = 0.0 times whatever the
 buffer holds there, so the V buffer is zeroed once (a page slot that
 was never copied into must not hold a NaN); K's never reaches the
 result unselected.
+
+`paged_latent_attention` is the same walk for a pool of latent rows
+(multi-head latent attention in its absorbed form,
+ops/latent_attention_ops.py): pool [N, pt, row], ONE row a token for
+all heads, q [S, H, row]. It is the case KVH = 1 of the above with the
+values taken from the keys: scores [H, C] = q . block^T over the whole
+row and the second product reads the same block's first `value_dim`
+columns, so a page is copied once for both.
 """
 from __future__ import annotations
 
@@ -47,7 +55,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ['paged_attention', 'supported']
+__all__ = ['paged_attention', 'supported', 'paged_latent_attention',
+           'latent_supported']
 
 _NEG_INF = -1e30
 # pages a block holds: 8 pages of 16 tokens x 16 heads x 128 floats are
@@ -193,3 +202,120 @@ def paged_attention(q, k_pool, v_pool, table, positions, sm_scale,
         interpret=pltpu.InterpretParams() if interpret else False,
         name='paged_attention',
     )(table.reshape(-1), positions, q, k3, v3)
+
+
+# -- latent rows: one pool, values inside the keys ----------------------------
+
+# pages a block of latent rows holds: a page of 16 rows of 640 floats is
+# 40 KB, so 32 of them are 1.3 MB a block (2.6 MB of VMEM
+# double-buffered) and a [H, 512] tile of scores a step
+_LATENT_BLOCK_PAGES = 32
+
+
+def latent_supported(page_tokens, row, value_dim):
+    """A row is a whole number of lanes, as is the part of it the
+    second product reads, and a page a whole number of sublane tiles."""
+    return row % 128 == 0 and value_dim % 128 == 0 and page_tokens % 8 == 0
+
+
+def _latent_kernel(table_ref, pos_ref, q_ref, pool_hbm, o_ref, buf, sems,
+                   slot_ref, *, sm_scale, pt, bp, pages_per_slot, lanes,
+                   value_dim):
+    s = pl.program_id(0)
+    heads, cols = q_ref.shape[0], bp * pt
+
+    def n_pages(lane):
+        return jnp.minimum(pos_ref[lane] // pt + 1, pages_per_slot)
+
+    def copies(lane, blk, slot, wait=False):
+        live = n_pages(lane)
+        for j in range(bp):
+            g = blk * bp + j
+
+            @pl.when(g < live)
+            def _():
+                dma = pltpu.make_async_copy(
+                    pool_hbm.at[table_ref[lane * pages_per_slot + g]],
+                    buf.at[slot, j], sems.at[slot])
+                dma.wait() if wait else dma.start()
+
+    @pl.when(s == 0)
+    def _():
+        buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+        copies(0, 0, 0)
+
+    slot0 = slot_ref[0]
+    pos = pos_ref[s]
+    n_blk = pl.cdiv(n_pages(s), bp)
+    q = q_ref[...].astype(jnp.float32) * sm_scale           # [H, row]
+    tok = jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 1)
+
+    def block(i, carry):
+        m, l, acc = carry
+        slot = (slot0 + i) % 2
+
+        @pl.when(i + 1 < n_blk)
+        def _():
+            copies(s, i + 1, 1 - slot)
+
+        @pl.when(jnp.logical_and(i + 1 == n_blk, s + 1 < lanes))
+        def _():
+            copies(s + 1, 0, 1 - slot)
+
+        copies(s, i, slot, wait=True)
+        k = buf[slot].reshape(cols, q.shape[-1])
+        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        sc = jnp.where(tok <= pos - i * cols, sc, _NEG_INF)  # [H, cols]
+        m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + p.sum(axis=-1, keepdims=True)
+        acc = alpha * acc + jnp.dot(p, k[:, :value_dim],
+                                    preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m, l, acc = jax.lax.fori_loop(
+        0, n_blk, block,
+        (jnp.full((heads, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((heads, 1), jnp.float32),
+         jnp.zeros((heads, value_dim), jnp.float32)))
+    slot_ref[0] = (slot0 + n_blk) % 2
+    o_ref[...] = (acc / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=('sm_scale', 'value_dim', 'interpret'))
+def paged_latent_attention(q, pool, table, positions, sm_scale, value_dim,
+                           interpret=False):
+    """q [S, H, row], pool [N, pt, row], table [S, P] int32, positions
+    [S] int32 -> [S, H, value_dim]: softmax over lane s's positions
+    0..positions[s] of sm_scale * q . row, times the row's first
+    value_dim columns, in fp32. A table entry is read only below a
+    lane's page count; it must name a page of the pool."""
+    S, H, row = q.shape
+    N, pt = pool.shape[:2]
+    P = table.shape[1]
+    bp = min(P, _LATENT_BLOCK_PAGES)
+    kernel = functools.partial(
+        _latent_kernel, sm_scale=float(sm_scale), pt=pt, bp=bp,
+        pages_per_slot=P, lanes=S, value_dim=value_dim)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S,),
+            in_specs=[pl.BlockSpec((None, H, row), lambda s, *_: (s, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, H, value_dim),
+                                   lambda s, *_: (s, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, bp, pt, row), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((S, H, value_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',)),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name='paged_latent_attention',
+    )(table.reshape(-1), positions, q, pool)

@@ -54,9 +54,16 @@ carry the slot's rows with the pages. The prefix cache hands out
 nothing for such a model: a prefix's pages without the recurrent state
 at that boundary would be a wrong stream, and keeping that state
 (13 MB a boundary at the 7B widths, against 1 MB a page) is not built.
+A model whose layers keep a latent page (models/axk1.py: one pool a
+layer, [pages, page_tokens, row]) keeps nothing else for a stream, so
+everything here that moves pages (the prefix cache with copy-on-write,
+save_stream / restore_stream, export_prefix / install_prefix) serves
+it as it serves K/V pages: each takes its sizes from the pools.
 
 Telemetry: serving.kv_pages_in_use / serving.kv_pages_free gauges,
-serving.prefix_hits / serving.prefix_tokens_reused counters,
+serving.prefix_hits / serving.prefix_tokens_reused counters (beside
+serving.prompt_tokens_admitted: the prompt tokens of every stream
+opened, reused or not),
 serving.prefill_chunks histogram (chunks per admitted prompt),
 serving.decode_pages_read / serving.decode_pages_window counters (the
 pages the decode steps' attention read, of slots x pages_per_slot a
@@ -69,7 +76,12 @@ steps updated; attr `state_lanes` of the same span),
 serving.recurrent_state_bytes and serving.state_resets gauges (bytes
 the recurrent state holds; streams started from zero state so far),
 and serving.<family>.state_bytes for a spec that names its state's
-family (serving.ssm.state_bytes: models/nemotron_h.py).
+family (serving.ssm.state_bytes: models/nemotron_h.py). For a model
+whose pages hold latent rows (models/axk1.py): the gauge
+serving.latent.cache_bytes (pages in use x the bytes a page's rows
+take over all layers) and the counter serving.latent.rows_read (rows
+the decode steps' attention read: each live lane's pos + 1, every
+layer; attr `latent_rows` of `paged.decode.tables`).
 What a model's expert layers count they count on the device: a
 program with such layers returns a third fetch, [4] int32 a call. No
 step waits for it: the arrays queue up and moe_counters() (any thread;
@@ -119,12 +131,15 @@ _pages_in_use = telemetry.gauge('serving.kv_pages_in_use')
 _pages_free = telemetry.gauge('serving.kv_pages_free')
 _prefix_hits = telemetry.counter('serving.prefix_hits')
 _prefix_tokens = telemetry.counter('serving.prefix_tokens_reused')
+_prompt_tokens = telemetry.counter('serving.prompt_tokens_admitted')
 _prefill_chunks = telemetry.histogram('serving.prefill_chunks')
 _decode_pages_read = telemetry.counter('serving.decode_pages_read')
 _decode_pages_window = telemetry.counter('serving.decode_pages_window')
 _state_lanes = telemetry.counter('serving.state_lanes')
 _state_bytes = telemetry.gauge('serving.recurrent_state_bytes')
 _state_resets = telemetry.gauge('serving.state_resets')
+_latent_bytes = telemetry.gauge('serving.latent.cache_bytes')
+_latent_rows = telemetry.counter('serving.latent.rows_read')
 _MOE_COUNTS = ('pairs', 'experts_touched', 'pairs_dropped', 'layer_calls')
 
 
@@ -188,8 +203,10 @@ class PagedDecodePredictor(object):
             self._weight_scope = predictor._scope
             self._mesh, self._mesh_shape = serving_mesh(mesh)
             if self._mesh is not None:
-                self._refuse_recurrent('mesh serving (%s)'
-                                       % self._mesh_shape)
+                from ..models.transformer import refuse_latent_pages
+                what = 'mesh serving (%s)' % self._mesh_shape
+                self._refuse_recurrent(what)
+                refuse_latent_pages(self._pair.spec, what)
             self._pair.spec.mesh = self._mesh_shape
         self._exe = self._make_executor(predictor._place)
         if _clone_of is None:
@@ -352,6 +369,9 @@ class PagedDecodePredictor(object):
     def _update_gauges(self):
         _pages_in_use.set(self._pool.pages_in_use)
         _pages_free.set(self._pool.pages_free)
+        if self._pair.spec.page_kind == 'latent':
+            _latent_bytes.set(self._pool.pages_in_use * self.page_tokens
+                              * self._pair.spec.latent_row_bytes())
 
     # -- lifecycle ---------------------------------------------------------
     def _pin_weights(self):
@@ -559,6 +579,7 @@ class PagedDecodePredictor(object):
             table.adopt_shared(pages, shared)
             _prefix_hits.inc()
             _prefix_tokens.inc(shared)
+        _prompt_tokens.inc(len(prompt))
         self._tables[slot] = table
         self._pending[slot] = _PendingPrefill(prompt)
         self._update_gauges()
@@ -919,10 +940,19 @@ class PagedDecodePredictor(object):
                     'decode_page_table': table_feed,
                     'decode_cow_src': cow_src,
                     'decode_cow_dst': cow_dst}
+            live_feed = np.zeros((S,), np.int32)
+            live_feed[live] = 1
+            if 'decode_live' in self._pair.decode_feeds:
+                feed['decode_live'] = live_feed
+            if self._pair.spec.page_kind == 'latent':
+                # the rows the step's attention reads: a lane's tokens
+                # so far and the one it appends, in every layer
+                ev.attrs['latent_rows'] = rows = \
+                    len(self._pair.spec.kv_layers) \
+                    * sum(int(pos_feed[slot]) + 1 for slot in live)
+                _latent_rows.inc(rows)
             if 'decode_state_live' in self._pair.decode_feeds:
-                state_live = np.zeros((S,), np.int32)
-                state_live[live] = 1
-                feed['decode_state_live'] = state_live
+                feed['decode_state_live'] = live_feed
                 ev.attrs['state_lanes'] = len(live)
                 _state_lanes.inc(len(live))
         logits, ids = self._run(self._pair.decode_program, feed,

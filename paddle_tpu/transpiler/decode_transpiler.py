@@ -50,6 +50,20 @@ of the model, read back from the op); K/V heads fewer than query heads
 are read from the qkv weight's width. The refusals above hold for it
 alike: they ask whether a layer holds recurrent state, not its name.
 
+A fourth is the latent-attention block of models/axk1.py
+(`axk1.language_model_logits`): lookup_table, no position op, every
+layer
+  [rms_norm, q-down mul, rms_norm, q-up mul, rotary_yarn, kv-down mul,
+   rms_norm, rotary_yarn, latent_attention, proj mul, rms_norm]
+then a gated MLP [gate-up mul, down mul] or an expert layer
+[moe_experts with W3, shared gate-up mul, shared down mul], a final
+rms_norm and the lm_head mul. Its spec (AXK1DecodeSpec) carries the
+sizes read from the weights' shapes and the attributes of the
+rotary, attention and expert ops; its pages hold ONE latent row a
+token a layer (page_kind 'latent'), so the prefix cache and page
+shipping serve it, and speculative decoding and mesh serving, which
+read a page as K and V heads, refuse it by that name.
+
 Genuinely
 unsupported layouts (the training MoE op moe_ffn, whose capacity drops
 tokens; ring attention; a
@@ -60,19 +74,24 @@ time.
 """
 from __future__ import annotations
 
-from ..models import hybrid, nemotron_h
+import re
+
+from ..models import axk1, hybrid, nemotron_h
 from ..models.transformer import (DecodeSpec, DecodeTranspileError,
-                                  refuse_recurrent, build_verify_program)
+                                  refuse_latent_pages, refuse_recurrent,
+                                  build_verify_program)
 
 __all__ = ['DecodeTranspileError', 'PagedDecodePair', 'SpecDecodePair',
-           'DecodeTranspiler', 'extract_decode_spec', 'refuse_recurrent']
+           'DecodeTranspiler', 'extract_decode_spec', 'refuse_recurrent',
+           'refuse_latent_pages']
 
 
 class PagedDecodePair(object):
     """The transpile result: spec + both programs and their ABIs.
 
     The cache state is per-layer page POOLS ([num_pages, page_tokens,
-    H, dk]), the prefill program runs one `prefill_chunk`-token chunk
+    H, dk] for K and for V, or one [num_pages, page_tokens, row] of
+    latent rows: spec.pool_shape), the prefill program runs one `prefill_chunk`-token chunk
     through one stream's page table, and both programs take the page
     index as a feed (serving/paged.py computes it). Fetch order for
     both programs is [logits, greedy_ids]; pool var names
@@ -456,9 +475,123 @@ def _extract_nemotron_spec(block):
     return spec
 
 
+def _extract_axk1_spec(block):
+    """The latent-attention block's spec (see the module docstring).
+    The ops that carry a parameter, in order, spell the model: N an
+    rms_norm, M a mul, A latent_attention, E moe_experts; a layer is
+    N M N M M N A M N then M M (a gated MLP) or E M M (experts beside
+    one shared expert), the gated-MLP layers first, and N M ends the
+    model."""
+    def shape(name):
+        return tuple(int(d) for d in block.var_recursive(name).shape)
+
+    emb_w = ids = None
+    letters, ops, rotary = [], [], None
+    for op in block.ops:
+        t = op.type
+        if t == 'lookup_table' and emb_w is None:
+            emb_w, ids = op.single_input('W'), op.single_input('Ids')
+        elif t in ('layer_norm', 'position_embedding', 'moe_ffn', 'ssd_chunk',
+                   'gated_delta_chunk', 'flash_attention', 'ring_attention',
+                   'causal_mask'):
+            _fail('op %s inside a model with latent_attention layers: not '
+                  'the block of models/axk1.py' % t)
+        elif t == 'rotary_yarn':
+            rotary = rotary or op
+        elif t in ('rms_norm', 'mul', 'latent_attention', 'moe_experts'):
+            letters.append({'rms_norm': 'N', 'mul': 'M',
+                            'latent_attention': 'A', 'moe_experts': 'E'}[t])
+            ops.append(op)
+    if emb_w is None:
+        _fail('no lookup_table op (token embedding)')
+    spelled = ''.join(letters)
+    if not re.match(r'^(NMNMMNAMNMM)*(NMNMMNAMNEMM)*NM$', spelled) \
+            or len(spelled) == 2 or rotary is None:
+        _fail('parameter ops spell %r: not layers of [norm, q-down, norm, '
+              'q-up, kv-down, norm, latent_attention, proj, norm] and a '
+              'gated MLP (the first layers) or moe_experts with a shared '
+              'expert, then a final norm and the head (models/axk1.py)'
+              % spelled)
+    vocab, dim = shape(emb_w)
+    eps = ops[0].attr('epsilon', 1e-6)
+    blocks, sizes = [], {}
+
+    def agree(i, got):
+        for k, v in got.items():
+            if sizes.setdefault(k, v) != v:
+                _fail('layer %d: %s %r differs from %r' % (i, k, v, sizes[k]))
+
+    def w(op):
+        return (op.single_input('Y'), None)
+
+    at = 0
+    while at + 2 < len(ops):
+        i = len(blocks)
+        n1, qd, n2, qu, kd, n3, att, proj, n4 = ops[at:at + 9]
+        blk = {'attn_norm': n1.single_input('Scale'), 'q_down': w(qd),
+               'q_norm': n2.single_input('Scale'), 'q_up': w(qu),
+               'kv_down': w(kd), 'kv_norm': n3.single_input('Scale'),
+               'kv_up': att.single_input('WUKV'), 'proj': w(proj),
+               'ffn_norm': n4.single_input('Scale')}
+        heads = int(block.var_recursive(att.single_input('Q')).shape[2])
+        dn = int(att.attr('nope_dim'))
+        kv_rank, up_w = shape(blk['kv_up'])
+        head = shape(blk['q_up'][0])[1] // heads
+        agree(i, dict(
+            heads=heads, nope_dim=dn, rope_dim=head - dn,
+            v_dim=up_w // heads - dn, kv_rank=kv_rank,
+            q_rank=shape(blk['q_down'][0])[1],
+            sm_scale=float(att.attr('sm_scale'))))
+        if shape(blk['kv_down'][0])[1] != kv_rank + head - dn:
+            _fail('layer %d: kv-down weight %r is not kv_rank %d + rope_dim '
+                  '%d wide' % (i, shape(blk['kv_down'][0]), kv_rank,
+                               head - dn))
+        at += 9
+        if letters[at] == 'E':
+            mark, up, down = ops[at:at + 3]
+            if not mark.input('W3'):
+                _fail('layer %d: moe_experts without W3 beside '
+                      'latent_attention: the experts of models/axk1.py are '
+                      'gated (three matrices)' % i)
+            blk.update({'router': mark.single_input('RouterW'),
+                        'bias': mark.single_input('Bias'),
+                        'w1': mark.single_input('W1'),
+                        'w3': mark.single_input('W3'),
+                        'w2': mark.single_input('W2'),
+                        'shared_gate_up': w(up), 'shared_down': w(down)})
+            held, _, ffn = shape(blk['w1'])
+            agree(i, dict(
+                experts=shape(blk['router'])[1], experts_held=held,
+                expert_offset=int(mark.attr('expert_offset', 0)),
+                top_k=int(mark.attr('top_k')),
+                n_group=int(mark.attr('n_group', 1)),
+                topk_group=int(mark.attr('topk_group', 1)),
+                routed_scale=float(mark.attr('scale', 1.0)),
+                expert_ffn=ffn, shared_ffn=shape(down.single_input('Y'))[0]))
+        else:
+            up, down = ops[at:at + 2]
+            blk.update({'gate_up': w(up), 'down': w(down)})
+            agree(i, dict(dense_ffn=shape(down.single_input('Y'))[0]))
+        at += 3 if letters[at] == 'E' else 2
+        blocks.append(blk)
+    final_norm, head = ops[at].single_input('Scale'), w(ops[at + 1])
+    sizes.pop('sm_scale')
+    cfg = axk1.AXK1Config(
+        vocab=vocab, dim=dim, layers=len(blocks),
+        dense_layers=sum('gate_up' in b for b in blocks),
+        max_len=shape(ids)[1], eps=eps,
+        rope={k: rotary.attr(k) for k in axk1.ROPE_KEYS}, **sizes)
+    spec = axk1.AXK1DecodeSpec(cfg, emb_w=emb_w, blocks=blocks,
+                               final_norm=final_norm, head=head)
+    spec.param_specs = {n: None for n in spec.param_names()}
+    return spec
+
+
 def extract_decode_spec(program):
     """Scan the loaded program and return its DecodeSpec."""
     block = program.global_block()
+    if any(op.type == 'latent_attention' for op in block.ops):
+        return _extract_axk1_spec(block)
     if any(op.type in ('ssd_chunk', 'moe_experts') for op in block.ops):
         return _extract_nemotron_spec(block)
     if any(op.type == 'rms_norm' for op in block.ops):
@@ -604,6 +737,8 @@ class DecodeTranspiler(object):
             raise ValueError('spec_k must be >= 1, got %r' % spec_k)
         refuse_recurrent(extract_decode_spec(program),
                          'speculative decoding')
+        refuse_latent_pages(extract_decode_spec(program),
+                            'speculative decoding')
         target = self.transpile(program, slots=slots,
                                 page_tokens=page_tokens,
                                 kv_pages=kv_pages,

@@ -285,3 +285,114 @@ def test_served_experts_have_no_backward_and_say_so():
         loss = fluid.layers.mean(out)
         with pytest.raises(NotImplementedError, match='moe_experts'):
             fluid.backward.append_backward(loss)
+
+
+# -- group-limited choice and gated experts (the A.X-K1 form) -----------------
+
+def _group_limited_numpy(s, n_group, topk_group, k):
+    """A plain sort-based choice, float64: a group scores the sum of its
+    two largest entries, the best groups, the k largest inside them;
+    ties go to the lower index (a stable sort of the negated scores)."""
+    rows, experts = s.shape
+    chosen = np.zeros((rows, experts), bool)
+    for r in range(rows):
+        g = s[r].reshape(n_group, -1)
+        score = np.sort(g, axis=1)[:, -2:].sum(1)
+        kept = np.argsort(-score, kind='stable')[:topk_group]
+        masked = np.full(experts, -np.inf)
+        for j in kept:
+            width = experts // n_group
+            masked[j * width:(j + 1) * width] = g[j]
+        chosen[r, np.argsort(-masked, kind='stable')[:k]] = True
+    return chosen
+
+
+@pytest.mark.parametrize('rows,experts,n_group,topk_group,k', [
+    (41, 192, 8, 4, 8),         # the published gate
+    (7, 32, 4, 2, 4),
+    (16, 24, 3, 1, 5),          # one group kept
+    (9, 16, 4, 4, 6),           # every group kept: no limit at all
+])
+def test_group_limited_choice_is_the_sort_based_one(rows, experts, n_group,
+                                                    topk_group, k):
+    import jax
+    from paddle_tpu.ops import moe_ops
+    rng = np.random.default_rng(rows)
+    x = rng.normal(size=(rows, 24)).astype('f4')
+    router = (rng.normal(size=(24, experts)) / math.sqrt(24)).astype('f4')
+    bias = np.zeros(experts, 'f4')
+    w = np.asarray(moe_ops.served_weights(x, router, bias, k, 2.5, n_group,
+                                          topk_group))
+    s = np.asarray(jax.nn.sigmoid(jax.numpy.matmul(
+        x, router, precision='highest'))).astype(np.float64)
+    chosen = _group_limited_numpy(s, n_group, topk_group, k)
+    assert ((w != 0) == chosen).all()
+    want = np.where(chosen, s, 0)
+    want = 2.5 * want / want.sum(-1, keepdims=True)
+    assert np.abs(w - want).max() < 1e-5
+    if topk_group == n_group:
+        free = np.asarray(moe_ops.served_weights(x, router, bias, k, 2.5))
+        assert (free == w).all()
+
+
+def test_one_group_is_the_choice_over_all_experts_to_the_letter():
+    """n_group 1 and topk_group 1 are today's selection: the same
+    jaxpr as a call that names neither."""
+    import jax
+    from paddle_tpu.ops import moe_ops
+    x, router, bias = (np.zeros((5, 24), 'f4'), np.zeros((24, 32), 'f4'),
+                       np.zeros(32, 'f4'))
+    assert str(jax.make_jaxpr(
+        lambda *a: moe_ops.served_weights(*a, 22, 5.0))(x, router, bias)) \
+        == str(jax.make_jaxpr(
+            lambda *a: moe_ops.served_weights(*a, 22, 5.0, 1, 1))(
+                x, router, bias))
+
+
+def test_gated_experts_of_three_matrices_through_the_op():
+    """With W3 beside W1 the held experts are W2 (silu(W1 x) * W3 x),
+    routed group-limited, on x itself: against a loop in float64."""
+    rng = np.random.default_rng(11)
+    rows, d, f, experts, held, offset, k = 23, 24, 20, 32, 8, 8, 4
+    feed = {'x': rng.normal(size=(rows, d)).astype('f4'),
+            'router': (rng.normal(size=(d, experts))
+                       / math.sqrt(d)).astype('f4'),
+            'bias': np.zeros(experts, 'f4'),
+            'w1': rng.normal(size=(held, d, f)).astype('f4'),
+            'w3': rng.normal(size=(held, d, f)).astype('f4'),
+            'w2': rng.normal(size=(held, f, d)).astype('f4')}
+    prog, startup = Program(), Program()
+    with program_guard(prog, startup):
+        v = {n: fluid.layers.data(n, list(a.shape), dtype='float32',
+                                  append_batch_size=False)
+             for n, a in feed.items()}
+        block = prog.global_block()
+        out = block.create_var(name='out', dtype='float32')
+        stats = block.create_var(name='stats', dtype='int32')
+        block.append_op(
+            type='moe_experts',
+            inputs={'X': [v['x']], 'Lat': [v['x']], 'RouterW': [v['router']],
+                    'Bias': [v['bias']], 'W1': [v['w1']], 'W3': [v['w3']],
+                    'W2': [v['w2']]},
+            outputs={'Out': [out], 'Stats': [stats]},
+            attrs={'top_k': k, 'scale': 2.5, 'expert_offset': offset,
+                   'n_group': 4, 'topk_group': 2})
+    got, counted = fluid.Executor(fluid.CPUPlace()).run(
+        prog, feed=feed, fetch_list=[out, stats])
+    x = feed['x'].astype(np.float64)
+    s = 1 / (1 + np.exp(-(x @ feed['router'].astype(np.float64))))
+    chosen = _group_limited_numpy(s, 4, 2, k)
+    want = np.zeros_like(x)
+    pairs, touched = 0, set()
+    for r in range(rows):
+        for e in np.flatnonzero(chosen[r]):
+            j = e - offset
+            if 0 <= j < held:
+                g = x[r] @ feed['w1'][j]
+                h = g / (1 + np.exp(-g)) * (x[r] @ feed['w3'][j])
+                want[r] += 2.5 * s[r, e] / s[r, chosen[r]].sum() \
+                    * (h @ feed['w2'][j])
+                pairs += 1
+                touched.add(j)
+    assert np.abs(got - want).max() <= 1e-4 * np.abs(want).max()
+    assert list(counted) == [pairs, len(touched), 0, 1] and pairs > 0

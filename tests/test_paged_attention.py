@@ -16,6 +16,7 @@ What must hold:
   of how many a gathered window holds, and the registry counts both.
 """
 import collections
+import functools
 
 import numpy as np
 import pytest
@@ -408,3 +409,88 @@ def test_kernel_runs_per_shard_under_a_serving_mesh(tmp_path, monkeypatch,
     fluid.set_flags({'pallas_interpret': False})
     assert got == decoder().generate(prompt, n)
     assert len(seen) == cfg.layers
+
+
+# -- latent rows: one pool, the values inside the keys -------------------------
+
+def _latent_op(q, pool, table, positions, w_ukv, nope_dim, sm_scale):
+    """One paged_latent_attention op through the real Executor."""
+    prog, startup = Program(), Program()
+    feeds = {'q': q, 'pool': pool, 'table': table, 'positions': positions,
+             'w': w_ukv}
+    with program_guard(prog, startup):
+        block = prog.global_block()
+        v = {n: fluid.layers.data(n, list(a.shape), append_batch_size=False,
+                                  dtype=str(a.dtype))
+             for n, a in feeds.items()}
+        out = block.create_var(name='ctx', dtype='float32')
+        block.append_op(
+            type='paged_latent_attention',
+            inputs={'Q': [v['q']], 'Pool': [v['pool']], 'Table': [v['table']],
+                    'Positions': [v['positions']], 'WUKV': [v['w']]},
+            outputs={'Out': [out]},
+            attrs={'nope_dim': nope_dim, 'sm_scale': sm_scale})
+    with fluid.scope_guard(fluid.Scope()):
+        got, = fluid.Executor(fluid.CPUPlace()).run(prog, feed=feeds,
+                                                    fetch_list=[out])
+    return np.asarray(got)
+
+
+LATENT_CASES = {
+    # name: (heads, pages_per_slot, lengths, shared (child, parent))
+    'one_token': (4, 4, [1], ()),
+    'page_plus_one': (4, 4, [PT + 1], ()),
+    # 40 pages a slot are one block of 32 and a tail of 8
+    'past_one_block': (8, 40, [40 * PT, 33 * PT - 2, 32 * PT, 32 * PT + 1],
+                       ()),
+    'lanes_mixed_and_idle': (
+        8, 11, [0, 5, 88, 0, 17, 64, 65, 1, 0, 33, 80, 8], ()),
+    'pages_shared_between_lanes': (8, 11, [50, 70, 19, 50, 0, 44],
+                                   ((1, 0), (3, 0), (5, 2))),
+}
+
+
+@pytest.mark.parametrize('name', sorted(LATENT_CASES))
+def test_latent_kernel_matches_reference_lowering(name, interpret_kernel):
+    """A row of 128 latent + 64 rotary values stored as 256; 32 + 64 wide
+    query heads against it. The kernel in interpret mode and the op's
+    absorbed composition over the gathered window are the same sum."""
+    heads, pages_per_slot, lengths, share = LATENT_CASES[name]
+    rng = np.random.default_rng(sorted(LATENT_CASES).index(name))
+    dc, dr, dn, dv = 128, 64, 32, 32
+    n_pages = 1 + sum(-(-n // PT) for n in lengths) + 3
+    pool = np.zeros((n_pages, PT, 256), 'f4')
+    pool[..., :dc + dr] = rng.standard_normal((n_pages, PT, dc + dr))
+    table, positions = _tables(rng, lengths, PT, pages_per_slot, n_pages,
+                               share)
+    q = rng.standard_normal((len(lengths), 1, heads, dn + dr)).astype('f4')
+    w = (rng.standard_normal((dc, heads * (dn + dv))) / 8).astype('f4')
+    args = (q, pool, table, positions, w, dn, 0.1)
+    got = _latent_op(*args)
+    fluid.set_flags({'pallas_interpret': False})
+    want = _latent_op(*args)
+    assert got.shape == want.shape == (len(lengths), 1, heads * dv)
+    live = np.array(lengths) > 0          # an idle lane's row is not read
+    assert np.abs(got - want)[live].max() <= TOL * np.abs(want).max()
+
+
+def test_latent_kernel_lowers_for_the_chip_at_the_published_row():
+    """Cross-lowered for a TPU from here at the served shape: 64 heads
+    over rows of 640 (512 + 64 stored in whole lanes), 1024 pages a slot
+    in blocks of 32. One custom call, the pool handed over as it lies."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.pallas import paged_attention as pa
+    S, H, row, N, pt, P = 4, 64, 640, 64, 16, 1024
+    f = functools.partial(pa.paged_latent_attention, sm_scale=0.13,
+                          value_dim=512)
+    text = jax.jit(f).trace(
+        jax.ShapeDtypeStruct((S, H, row), jnp.float32),
+        jax.ShapeDtypeStruct((N, pt, row), jnp.float32),
+        jax.ShapeDtypeStruct((S, P), jnp.int32),
+        jax.ShapeDtypeStruct((S,), jnp.int32)).lower(
+            lowering_platforms=('tpu',)).as_text()
+    assert text.count('tpu_custom_call') == 1
+    assert 'tensor<%dx%dx%dxf32>' % (N, pt, row) in text
+    assert pa.latent_supported(16, 640, 512)
+    assert not pa.latent_supported(16, 576, 512)
