@@ -358,8 +358,9 @@ def phase_serve(cfg, env):
            'identical prompts decoded differently:\n%r\n%r'
            % (streams[2], streams[7]))
     jit = dec.jit_cache_stats()
-    _check(jit['compiled_segments'] == 2,
-           'prefill and decode must each compile once: %r' % jit)
+    _check(jit['compiled_segments'] == 3,
+           'prefill, decode and the page copy program must each compile '
+           'once: %r' % jit)
 
     # the repo's own reference: the plain predictor's full forward. The
     # first served token must be within a quarter of the logits' spread

@@ -224,12 +224,15 @@ def _full_attention(x, spec, blk):
     return _attention_op('latent_attention', spec, blk, q, t, CKV=ckv, KR=kr)
 
 
-def _paged_attention(x, spec, blk, pool, table, positions, cow_src, cow_dst,
-                     chunk=None, length=None):
-    """Copy-on-write, the new rows into their pages (the normed latent
-    and the rotated key side by side, zeros up to the pool's row), then
-    the absorbed attention through the table: one token a lane where
-    `chunk` is None, else one stream's chunk of rows."""
+def _paged_attention(x, spec, blk, pool, table, positions, cow_src=None,
+                     cow_dst=None, chunk=None, length=None):
+    """The new rows into their pages (the normed latent and the rotated
+    key side by side, zeros up to the pool's row), then the absorbed
+    attention through the table: one token a lane where `chunk` is
+    None, else one stream's chunk of rows. A chunk copies its forked
+    page first (cow_src, cow_dst: its pair of feeds); a decode step
+    has no such feeds and copies none: the host ran the page copy
+    program in front of it (models/transformer.build_page_copy_program)."""
     c = spec.cfg
     t = chunk or 1
     q, ckv, kr = _latent_parts(x, spec, blk, t, positions,
@@ -239,9 +242,11 @@ def _paged_attention(x, spec, blk, pool, table, positions, cow_src, cow_dst,
         row = L.pad(row, paddings=[0, 0, 0, 0, 0,
                                    spec.pool_row - spec.latent_row])
     pool, = pool
-    _block_op('kv_page_cow',
-              inputs={'Pool': [pool], 'Src': [cow_src], 'Dst': [cow_dst]},
-              outputs={'Out': [pool]})
+    if cow_src is not None:
+        _block_op('kv_page_cow',
+                  inputs={'Pool': [pool], 'Src': [cow_src],
+                          'Dst': [cow_dst]},
+                  outputs={'Out': [pool]})
     ins = {'Pool': [pool], 'X': [row], 'Table': [table],
            'Positions': [positions]}
     if chunk:
@@ -355,7 +360,8 @@ def build_paged_prefill_program(spec, chunk, num_pages, page_tokens,
 def build_paged_decode_program(spec, slots, num_pages, page_tokens,
                                pages_per_slot):
     """One token a lane over the whole slot pool: models/transformer.py's
-    paged decode feeds and decode_live [slots], which marks the lanes
+    paged decode feeds (no copy-on-write pair: the program copies no
+    page) and decode_live [slots], which marks the lanes
     that take part: the expert layers neither count nor weigh the
     others' rows.
     Returns (program, feed_names, fetch_vars[logits, ids, counts])."""
@@ -366,15 +372,13 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
         tokens = _paged_decode_tokens(slots)
         step_idx = _data('decode_step_idx', [slots])
         table = _data('decode_page_table', [slots, pages_per_slot])
-        cow_src = _data('decode_cow_src', [slots])
-        cow_dst = _data('decode_cow_dst', [slots])
         live = _data('decode_live', [slots])
         pools = _create_pool_vars(spec, num_pages, page_tokens)
         stats = []
         logits3 = _model(
             tokens, spec,
             lambda x, sp, blk, i: _paged_attention(
-                x, sp, blk, pools[i], table, step_idx, cow_src, cow_dst),
+                x, sp, blk, pools[i], table, step_idx),
             lambda x, sp, blk: _experts_ffn(x, sp, blk, stats,
                                             {'Live': [live]}))
         logits = L.reshape(logits3, shape=[-1, spec.vocab])

@@ -325,7 +325,8 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
                                pages_per_slot):
     """One token a lane over the whole slot pool:
     models/transformer.py's paged decode feeds (the carried token's
-    pair among them), and decode_state_live
+    pair among them; no copy-on-write pair: the program copies no
+    page), and decode_state_live
     [slots] (1 for the lanes that take part: the others' recurrent state
     stays as it was, as their K/V writes land on the null page).
     Returns (program, feed_names, fetch_vars[logits, ids])."""
@@ -336,8 +337,6 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
         tokens = _paged_decode_tokens(slots)
         step_idx = _data('decode_step_idx', [slots])
         table = _data('decode_page_table', [slots, pages_per_slot])
-        cow_src = _data('decode_cow_src', [slots])
-        cow_dst = _data('decode_cow_dst', [slots])
         live = _data('decode_state_live', [slots])
         pools = _create_pool_vars(spec, num_pages, page_tokens)
         states = _create_state_vars(spec, slots)
@@ -349,8 +348,7 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
         logits3 = _model(tokens, spec, {
             'linear_attention': linear,
             'full_attention': lambda x, sp, blk, i: _paged_decode_attention(
-                x, sp, blk, pools[i], table, step_idx, cow_src, cow_dst,
-                _qk_norm(sp, blk))})
+                x, sp, blk, pools[i], table, step_idx, _qk_norm(sp, blk))})
         logits = L.reshape(logits3, shape=[-1, spec.vocab])
         ids = L.argmax(logits, axis=-1)
     return prog, PAGED_DECODE_FEEDS + ['decode_state_live'], [logits, ids]

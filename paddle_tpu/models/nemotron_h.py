@@ -345,7 +345,8 @@ def build_paged_prefill_program(spec, slots, chunk, num_pages, page_tokens,
 def build_paged_decode_program(spec, slots, num_pages, page_tokens,
                                pages_per_slot):
     """One token a lane over the whole slot pool: models/hybrid.py's
-    paged decode feeds. decode_state_live marks the lanes that take
+    paged decode feeds (no copy-on-write pair: the program copies no
+    page). decode_state_live marks the lanes that take
     part: the others' state stays as it was, and the expert layers
     neither count nor weigh their rows.
     Returns (program, feed_names, fetch_vars[logits, ids, counts])."""
@@ -356,8 +357,6 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
         tokens = _paged_decode_tokens(slots)
         step_idx = _data('decode_step_idx', [slots])
         table = _data('decode_page_table', [slots, pages_per_slot])
-        cow_src = _data('decode_cow_src', [slots])
-        cow_dst = _data('decode_cow_dst', [slots])
         live = _data('decode_state_live', [slots])
         pools = _create_pool_vars(spec, num_pages, page_tokens)
         states = _create_state_vars(spec, slots)
@@ -369,7 +368,7 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
             'experts': lambda x, sp, blk, i: _experts_mixer(
                 x, sp, blk, stats, {'Live': [live]}),
             'full_attention': lambda x, sp, blk, i: _paged_decode_attention(
-                x, sp, blk, pools[i], table, step_idx, cow_src, cow_dst)})
+                x, sp, blk, pools[i], table, step_idx)})
         logits = L.reshape(logits3, shape=[-1, spec.vocab])
         fetches = _fetches(logits, L.argmax(logits, axis=-1), stats)
     return prog, PAGED_DECODE_FEEDS + ['decode_state_live'], fetches

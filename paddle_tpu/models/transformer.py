@@ -444,7 +444,9 @@ def _cached_block(x, spec, i, attention):
 # pool[table[j // pt], j % pt]. Every program stays static-shape (pool
 # size, table width, chunk width fixed at build time), so each compiles
 # exactly once; allocation, COW and prefix sharing are HOST decisions
-# (serving/paging.py) that only ever change feed VALUES. Physical page
+# (serving/paging.py) that only ever change feed VALUES (and, for a
+# decode step that forks a page, put the page copy program in front of
+# the step's own: build_page_copy_program). Physical page
 # 0 is the reserved null page — dead rows write there, reads of it are
 # always masked. Validity is absolute (j <= position): nothing wraps,
 # so running out of pages is a typed host-side error, never a silent
@@ -557,19 +559,17 @@ def _paged_prefill_attention(x, spec, blk, pool, table, positions,
 
 
 def _paged_decode_attention(x, spec, blk, pool, table, positions,
-                            cow_src, cow_dst, qk_norm=None):
-    """One decode step's attention: COW, append the new K/V row, then
-    ONE paged_attention op that reads each lane's live pages through
-    its table (no gathered window; see the op's docstring for its two
-    lowerings)."""
+                            qk_norm=None):
+    """One decode step's attention: append the new K/V row, then ONE
+    paged_attention op that reads each lane's live pages through its
+    table (no gathered window; see the op's docstring for its two
+    lowerings). No copy-on-write here: a page that forks in a decode
+    step was copied before the step's program was dispatched
+    (build_page_copy_program)."""
     q1, k1, v1 = (_pool_heads(a, spec)
                   for a in _qkv_parts(x, spec, blk, 1,
                                       qk_norm))   # [S, 1, H | KVH, dh]
     for pool_var, new in ((pool[0], k1), (pool[1], v1)):
-        _block_op('kv_page_cow',
-                  inputs={'Pool': [pool_var], 'Src': [cow_src],
-                          'Dst': [cow_dst]},
-                  outputs={'Out': [pool_var]})
         _block_op('kv_page_append',
                   inputs={'Pool': [pool_var], 'X': [new],
                           'Table': [table], 'Positions': [positions]},
@@ -705,8 +705,7 @@ def build_paged_prefill_program(spec, chunk, num_pages, page_tokens,
 
 
 PAGED_DECODE_FEEDS = ['decode_tokens', 'decode_prev_ids', 'decode_carry',
-                      'decode_step_idx', 'decode_page_table',
-                      'decode_cow_src', 'decode_cow_dst']
+                      'decode_step_idx', 'decode_page_table']
 
 
 def _paged_decode_tokens(slots):
@@ -739,11 +738,12 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
             incoming token: the write lands at
             pool[table[pos // pt], pos % pt], never wrapped),
             decode_page_table [slots, P] int32 (all-zero rows for idle
-            or mid-prefill slots: their appends hit the null page),
-            decode_cow_src / decode_cow_dst [slots] int32 (page copies
-            to apply before the appends — (0, 0) where no slot forked).
-    Admission, COW and page allocation are host decisions that only
-    change these feed values — the program compiles exactly once.
+            or mid-prefill slots: their appends hit the null page).
+    Admission and page allocation are host decisions that only change
+    these feed values — the program compiles exactly once. It copies no
+    page: where a lane's append would land on a page it shares, the
+    host has run the page copy program (build_page_copy_program) in
+    front of this one, and the table already names the copy.
     Returns (program, feed_names, fetch_vars[logits, ids]).
     """
     from ..framework import Program, program_guard
@@ -755,10 +755,6 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
                           append_batch_size=False, dtype='int32')
         table = L.data('decode_page_table', [slots, pages_per_slot],
                        append_batch_size=False, dtype='int32')
-        cow_src = L.data('decode_cow_src', [slots],
-                         append_batch_size=False, dtype='int32')
-        cow_dst = L.data('decode_cow_dst', [slots],
-                         append_batch_size=False, dtype='int32')
         pools = _create_pool_vars(spec, num_pages, page_tokens)
         emb = L.embedding(tokens, size=[spec.vocab, spec.dim],
                           param_attr=_named_attr(spec.emb_w))  # [S, 1, D]
@@ -768,13 +764,47 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
             x = _cached_block(
                 x, spec, i,
                 lambda ln, sp, blk, _i=i: _paged_decode_attention(
-                    ln, sp, blk, pools[_i], table, step_idx,
-                    cow_src, cow_dst))
+                    ln, sp, blk, pools[_i], table, step_idx))
         x = _named_ln(x, spec.final_ln)
         logits3 = _named_fc(x, spec.vocab, spec.head)          # [S, 1, V]
         logits = L.reshape(logits3, shape=[-1, spec.vocab])
         ids = L.argmax(logits, axis=-1)
     return prog, list(PAGED_DECODE_FEEDS), [logits, ids]
+
+
+def build_page_copy_program(spec, slots, num_pages, page_tokens):
+    """The copy a forking decode step runs in front of its program: one
+    kv_page_cow a pool of the pair, and nothing else.
+
+    Feeds:  page_copy_src / page_copy_dst [slots] int32 (a step forks at
+            most one page a lane; (0, 0) for the pairs it does not use).
+    The pools are the pair's own vars, donated like a step program's,
+    so every layer's copy is in place and one dispatch moves a page in
+    all of them (serving/paged.py `_fork_pages`: 2 ms of the host for
+    48 pools; a program of one pool run 48 times took 5 ms in bare
+    jitted calls and 45 through Executor.run). A few small scatters:
+    48 pools compile in half a second, in front of the predictor's
+    first decode step.
+    The prefill and verify programs keep their own kv_page_cow.
+    Returns (program, feed_names); nothing to fetch.
+    """
+    from ..framework import Program, program_guard
+    prog, startup = Program(), Program()
+    prog._is_test = True
+    with program_guard(prog, startup):
+        src = L.data('page_copy_src', [slots],
+                     append_batch_size=False, dtype='int32')
+        dst = L.data('page_copy_dst', [slots],
+                     append_batch_size=False, dtype='int32')
+        pools = _create_pool_vars(spec, num_pages, page_tokens)
+        for layer in spec.kv_layers:
+            for pool in pools[layer]:
+                _block_op('kv_page_cow',
+                          inputs={'Pool': [pool], 'Src': [src],
+                                  'Dst': [dst]},
+                          outputs={'Out': [pool]},
+                          attrs={'page_rows': True})
+    return prog, ['page_copy_src', 'page_copy_dst']
 
 
 def build_verify_program(spec, slots, k1, num_pages, page_tokens,

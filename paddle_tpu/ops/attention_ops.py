@@ -142,6 +142,13 @@ def _gather_time_emit(ctx, op):
 # pages are allocated on demand rather than wrapped, which is what lets
 # exhaustion surface as a typed host-side error instead of a silent
 # slide (COVERAGE divergence 8).
+#
+# Who copies a forked page. The PREFILL and VERIFY programs hold one
+# kv_page_cow in front of every pool's write, fed a pair (or a null
+# pair) every call. The DECODE program holds none: the host dispatches
+# the page copy program (models/transformer.build_page_copy_program:
+# this op alone, once a pool) in front of a decode step whose table
+# forked a page, and in front of no other step (serving/paged.py).
 # ---------------------------------------------------------------------------
 
 @op_emitter('kv_page_cow')
@@ -151,11 +158,30 @@ def _kv_page_cow_emit(ctx, op):
     are read before any destination is written (functional scatter), so
     a page freed and reallocated within the same step still donates its
     pre-step contents. (0, 0) pairs are the no-op padding — the null
-    page copied onto itself — which keeps COW inside the ONE compiled
-    program whether or not any fork happened this step."""
+    page copied onto itself: in the prefill and verify programs it
+    keeps COW inside the ONE compiled program whether or not any fork
+    happened in the call; in the page copy program it fills the pairs
+    a forking decode step does not use.
+
+    attr `page_rows` (the page copy program sets it): where a page's
+    [H, dk] face is not whole (8, 128) tiles, the page moves as
+    [pt * H, dk] rows, the same bytes in the same order. As [pt, H, dk]
+    pages the TPU compiler relays a pool of few K/V heads out and back
+    around the gather and the scatter ([8192, 16, 2, 128]: 134 MB three
+    times a call, compiled for a v5e); as rows it updates the donated
+    pool in place. A pool of 16 or 32 heads is updated in place as it
+    is, and that form compiles in half the time (48 pools: 0.47 s
+    against 0.88). Not on a mesh, where the heads axis is sharded and
+    stays an axis of its own."""
     pool = ctx.get(op.single_input('Pool'))
     src = ctx.get(op.single_input('Src')).astype(jnp.int32)
     dst = ctx.get(op.single_input('Dst')).astype(jnp.int32)
+    if op.attr('page_rows') and ctx.mesh is None and pool.ndim == 4 \
+            and pool.shape[2] % 8:
+        rows = pool.reshape(pool.shape[0], -1, pool.shape[-1])
+        ctx.set(op.single_output('Out'),
+                rows.at[dst].set(rows[src]).reshape(pool.shape))
+        return
     ctx.set(op.single_output('Out'), pool.at[dst].set(pool[src]))
 
 

@@ -5,7 +5,9 @@ ops/attention_ops.py; the reference lowering there gathers the whole
 window and masks).
 
 One query row per lane: q [S, H, dh], pools [N, pt, KVH, dh] (the
-layout kv_page_cow/write/append keep), table [S, P] int32, positions
+layout kv_page_write/append keep in the step programs, and
+kv_page_cow wherever a forked page is copied: inside a prefill chunk's
+program, in front of a decode step's), table [S, P] int32, positions
 [S] int32. Lane s attends to logical positions 0..positions[s]. KVH
 divides H: query head h reads K/V head h // (H / KVH); where the two
 counts are equal every head has its own.

@@ -16,7 +16,19 @@ maps to, and both compiled programs take the page index as a FEED —
 admission, copy-on-write and prefix sharing never recompile anything:
 each program is static-shape, compiles exactly once through the
 executor's whole-block jit cache, and the pools ride the executor's
-donation path (in-place update on device).
+donation path (in-place update on device). A prefill chunk copies the
+page it forks inside its program (a pair of feeds, null in most
+calls). The decode program copies none: a decode step in which a page
+forks (a stream's first append onto a page it shares: the host's own
+table says so before anything is dispatched) runs the pair's page copy
+program in front of its own (one kv_page_cow a pool, one dispatch); a
+step without a fork, nearly every one, dispatches nothing for it. The
+device runs programs in the order they were dispatched, so the append
+that follows lands on the copy, also behind a deferred step in flight.
+The copy program compiles where the decode program does, in a run over
+null pairs in front of the predictor's first decode step (a warm-up
+request's, like the other two programs' compiles): no later step's
+fork meets its compile.
 
 A stream's life:
 
@@ -82,6 +94,12 @@ serving.latent.cache_bytes (pages in use x the bytes a page's rows
 take over all layers) and the counter serving.latent.rows_read (rows
 the decode steps' attention read: each live lane's pos + 1, every
 layer; attr `latent_rows` of `paged.decode.tables`).
+Copy-on-write in front of a decode step: serving.cow.dispatches (decode
+steps that forked a page and so ran the copy) and serving.cow.pages
+(the pages they copied: the pairs that are not null). They count forks
+only: a step without one moves neither, nor does the null run in
+front of the first decode step, nor a prefill chunk's copy inside its
+program.
 What a model's expert layers count they count on the device: a
 program with such layers returns a third fetch, [4] int32 a call. No
 step waits for it: the arrays queue up and moe_counters() (any thread;
@@ -92,9 +110,13 @@ serving.moe.layer_calls, each also as serving.moe.decode.* for the
 decode program's share.
 
 Spans (profiler.RecordEvent), the same four in each step:
-`paged.decode.tables` / `paged.prefill.tables` (copy-on-write, page
-allocation, the page-table rows and the other feed arrays), the
-executor's `exe.run` with its children, `paged.*.book` (unref, lengths,
+`paged.decode.tables` / `paged.prefill.tables` (copy-on-write's
+bookkeeping, page allocation, the page-table rows and the other feed
+arrays), `paged.cow` (attr `pages`; its child is the copy program's own
+`exe.run`) in a decode step that forked and in no other
+(`paged.cow.compile` once, around the null run in front of the first
+decode step),
+the executor's `exe.run` with its children, `paged.*.book` (unref, lengths,
 prefix registration, gauges: host work that overlaps the device's),
 and `paged.*.fetch` (`np.asarray(ids)`: the wait for the device and
 the transfer; in prefill only on a prompt's last chunk). In a deferred
@@ -140,6 +162,8 @@ _state_bytes = telemetry.gauge('serving.recurrent_state_bytes')
 _state_resets = telemetry.gauge('serving.state_resets')
 _latent_bytes = telemetry.gauge('serving.latent.cache_bytes')
 _latent_rows = telemetry.counter('serving.latent.rows_read')
+_cow_dispatches = telemetry.counter('serving.cow.dispatches')
+_cow_pages = telemetry.counter('serving.cow.pages')
 _MOE_COUNTS = ('pairs', 'experts_touched', 'pairs_dropped', 'layer_calls')
 
 
@@ -214,6 +238,9 @@ class PagedDecodePredictor(object):
         self._scope = Scope(parent=self._weight_scope)
         self.fetch_wait_s = 0.0       # blocked in a step's fetch, ever
         self.reset()
+        # the copy program is compiled by the first decode step, with
+        # the decode program (decode_step)
+        self._copy_compiled = False
 
     def _make_executor(self, place):
         if self._mesh is None:
@@ -330,6 +357,32 @@ class PagedDecodePredictor(object):
             self._moe_queue.append((decode, out[2]))
             self.moe_counters(leave=64)     # nobody asks: keep it short
         return out[0], out[1]
+
+    def _copy_pages(self, pairs):
+        """The page copy program over `pairs` of (src, dst) pages, at
+        most one a slot: one dispatch copies them in every pool, each
+        donated and updated in place. The rest of the feed is the null
+        page onto itself."""
+        src = np.zeros((self.slots,), np.int32)
+        dst = np.zeros((self.slots,), np.int32)
+        for i, one in enumerate(pairs):
+            src[i], dst[i] = one
+        self._exe.run(self._pair.copy_program,
+                      feed=dict(zip(self._pair.copy_feeds, (src, dst))),
+                      scope=self._scope, return_numpy=False)
+
+    def _fork_pages(self, cows):
+        """Copy the pages a decode step forked, in front of its program:
+        one dispatch if `cows` (the step's (table, index, (src, dst))
+        entries) holds any, nothing otherwise. Called once the
+        tables are settled, so a step that raises CacheExhaustedError
+        has dispatched nothing."""
+        if not cows:
+            return
+        with RecordEvent('paged.cow', pages=len(cows)):
+            self._copy_pages([pair for _table, _idx, pair in cows])
+        _cow_dispatches.inc()
+        _cow_pages.inc(len(cows))
 
     def moe_counters(self, leave=0):
         """What the expert layers have counted since reset(), over the
@@ -856,8 +909,11 @@ class PagedDecodePredictor(object):
         recurrent state as they are); every other lane is fed the
         null-page table row, so its mandatory write is dead
         weight. New pages are
-        allocated on demand; if ANY stream cannot grow, the step runs
-        nothing, this call's allocations are rolled back, and
+        allocated on demand, and a lane whose append would land on a
+        page it shares forks it: the step then runs the page copy
+        program in front of its own (_fork_pages), as no other step
+        does. If ANY stream cannot grow, the step runs
+        nothing, this call's allocations and forks are rolled back, and
         CacheExhaustedError(slots=[...]) names the victims — the
         caller releases or evicts them and retries the same feed.
 
@@ -890,8 +946,6 @@ class PagedDecodePredictor(object):
             positions = np.asarray(positions, np.int32).reshape(S)
             table_feed = np.zeros((S, P), np.int32)
             pos_feed = np.zeros((S,), np.int32)
-            cow_src = np.zeros((S,), np.int32)
-            cow_dst = np.zeros((S,), np.int32)
             carry_feed = np.zeros((S,), np.int32)
             carry_feed[carry] = 1
             cows, grows, failed, live = [], [], [], []
@@ -914,8 +968,6 @@ class PagedDecodePredictor(object):
                     grows.append((table, before))
                 table.row(table_feed[slot])
                 pos_feed[slot] = pos
-                if pair is not None:
-                    cow_src[slot], cow_dst[slot] = pair
                 live.append(slot)
             if failed:
                 self._rollback(cows, grows)
@@ -937,9 +989,7 @@ class PagedDecodePredictor(object):
                     'decode_prev_ids': self._last_ids,
                     'decode_carry': carry_feed,
                     'decode_step_idx': pos_feed,
-                    'decode_page_table': table_feed,
-                    'decode_cow_src': cow_src,
-                    'decode_cow_dst': cow_dst}
+                    'decode_page_table': table_feed}
             live_feed = np.zeros((S,), np.int32)
             live_feed[live] = 1
             if 'decode_live' in self._pair.decode_feeds:
@@ -955,6 +1005,17 @@ class PagedDecodePredictor(object):
                 feed['decode_state_live'] = live_feed
                 ev.attrs['state_lanes'] = len(live)
                 _state_lanes.inc(len(live))
+        if not self._copy_compiled:
+            # a run over null pairs in front of the first decode step:
+            # the copy program compiles where the decode program does
+            # (weights and pools are on the device by now: beside
+            # their transfer, when the predictor is built, 48 pools'
+            # copies took 4.7 s to compile, after it 0.5), and no later
+            # step's fork meets its compile
+            with RecordEvent('paged.cow.compile'):
+                self._copy_pages(())
+            self._copy_compiled = True
+        self._fork_pages(cows)
         logits, ids = self._run(self._pair.decode_program, feed,
                                 self._pair.decode_fetches, True)
         with RecordEvent('paged.decode.book'):

@@ -79,6 +79,7 @@ import re
 from ..models import axk1, hybrid, nemotron_h
 from ..models.transformer import (DecodeSpec, DecodeTranspileError,
                                   refuse_latent_pages, refuse_recurrent,
+                                  build_page_copy_program,
                                   build_verify_program)
 
 __all__ = ['DecodeTranspileError', 'PagedDecodePair', 'SpecDecodePair',
@@ -96,12 +97,16 @@ class PagedDecodePair(object):
     index as a feed (serving/paged.py computes it). Fetch order for
     both programs is [logits, greedy_ids]; pool var names
     (spec.pool_names()) are shared between the two programs, so one
-    Scope carries the K/V state from prefill into decode."""
+    Scope carries the K/V state from prefill into decode. The decode
+    program copies no page: copy_program (feeds copy_feeds, no fetch;
+    models/transformer.build_page_copy_program) is what the host runs
+    in front of a decode step that forks one."""
 
     def __init__(self, spec, slots, page_tokens, pages_per_slot,
                  num_pages, prefill_chunk,
                  prefill_program, prefill_feeds, prefill_fetches,
-                 decode_program, decode_feeds, decode_fetches):
+                 decode_program, decode_feeds, decode_fetches,
+                 copy_program, copy_feeds):
         self.spec = spec
         self.slots = slots
         self.page_tokens = page_tokens
@@ -114,6 +119,8 @@ class PagedDecodePair(object):
         self.decode_program = decode_program
         self.decode_feeds = decode_feeds
         self.decode_fetches = decode_fetches
+        self.copy_program = copy_program
+        self.copy_feeds = copy_feeds
 
     @property
     def cache_names(self):
@@ -789,5 +796,6 @@ class DecodeTranspiler(object):
         chunk = max(1, min(chunk, spec.max_len))
         return PagedDecodePair(
             spec, slots, pt, pages_per_slot, num_pages, chunk,
-            *spec.build_paged_programs(slots, chunk, num_pages, pt,
-                                       pages_per_slot))
+            *(spec.build_paged_programs(slots, chunk, num_pages, pt,
+                                        pages_per_slot)
+              + build_page_copy_program(spec, slots, num_pages, pt)))

@@ -122,7 +122,7 @@ def test_chip_smoke_dry_run_one_chip():
     assert train['flash_route']['pallas.flash.naive'] == 0
     assert train['flash_route']['pallas.flash.kernel'] > 0
     assert train['losses'][-1] < train['losses'][0]
-    assert serve['completed'] == 8 and serve['compiled_segments'] == 2
+    assert serve['completed'] == 8 and serve['compiled_segments'] == 3
     assert rows[-1] == {'ok': True, 'device': {
         'platform': 'cpu', 'kind': 'cpu', 'count': 1}}
 

@@ -263,7 +263,9 @@ def test_decode_program_reads_pages_and_the_others_still_gather(
     decode = _op_counts(pair.target.decode_program)
     assert decode['paged_attention'] == CFG.layers
     assert decode['kv_page_append'] == 2 * CFG.layers
-    assert decode['kv_page_cow'] == 2 * CFG.layers
+    # a decode step's fork is copied in front of the program (PR 44);
+    # a chunk's and a verify pass's inside theirs, as before
+    assert decode['kv_page_cow'] == 0
     for gone in ('kv_page_gather', 'paged_decode_mask', 'softmax',
                  'matmul'):
         assert decode[gone] == 0, gone
@@ -274,6 +276,7 @@ def test_decode_program_reads_pages_and_the_others_still_gather(
         ops = _op_counts(program)
         assert ops['paged_attention'] == 0
         assert ops['kv_page_gather'] == 2 * CFG.layers
+        assert ops['kv_page_cow'] == 2 * CFG.layers
         assert ops[mask] == ops['softmax'] == CFG.layers
         assert ops['matmul'] == 2 * CFG.layers
 

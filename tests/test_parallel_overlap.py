@@ -283,3 +283,51 @@ def test_flash_backward_of_the_training_cells_compiles_for_v5e(v5e_2x2):
     assert 'tpu_custom_call' in compiled.as_text()
     assert [(g.shape, str(g.dtype)) for g in compiled.out_info] \
         == [((64, 2048, 128), 'bfloat16')] * 3
+
+
+# -- the page copy program, compiled for one described chip ------------------
+# (here because this file is the one that describes a TPU: see v5e_2x2)
+
+# (pool shape, slots, pools) of the four serving cells' configurations
+_POOLS = {'gpt1b3': ((1152, 16, 16, 128), 16, 48),
+          'olmohyb': ((3584, 16, 32, 128), 32, 4),
+          'nemo3s': ((8192, 16, 2, 128), 64, 2),
+          'axk1': ((16384, 16, 640), 48, 5)}
+
+
+@pytest.mark.parametrize('cell', sorted(_POOLS))
+def test_page_copy_program_updates_the_donated_pools_in_place(v5e_2x2, cell):
+    """What a forking decode step dispatches (serving/paged.py): at the
+    cells' real pool shapes and counts the compiled module aliases
+    every pool and holds no copy of one. Nemotron's [8192, 16, 2, 128]
+    is the one the compiler relaid out and back (134 MB of temporaries a
+    pool) when its pages moved as [pt, H, dk] and not as rows."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.models.transformer import build_page_copy_program
+    shape, slots, n = _POOLS[cell]
+    names = ['kv_pool.%d' % i for i in range(n)]
+    spec = types.SimpleNamespace(
+        kv_layers=range(n), pool_shape=lambda pages, pt: shape,
+        pool_names=lambda layer=None: names if layer is None
+        else (names[layer],))
+    program, feeds = build_page_copy_program(spec, slots, shape[0], shape[1])
+    prepared = PreparedProgram(program, 0, feeds, [])
+    segment, = [s for s in prepared.steps if isinstance(s, _DeviceSegment)]
+    assert [op.type for op in segment.ops] == ['kv_page_cow'] * n
+    jitted = fluid.Executor(fluid.CPUPlace())._compile_segment(
+        segment, prepared.block, program, feed_names=tuple(feeds))
+    one = SingleDeviceSharding(v5e_2x2[0])
+    pool = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+    pairs = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)
+    compiled = jitted.lower(dict.fromkeys(names, pool),
+                            dict.fromkeys(feeds, pairs), key).compile()
+    mem = compiled.memory_analysis()
+    nbytes = 4 * int(np.prod(shape))
+    assert mem.alias_size_in_bytes == n * nbytes
+    # a few pages of temporaries at most, never a pool
+    assert mem.temp_size_in_bytes < nbytes // 64
+    whole = 'f32[%s]' % ','.join(map(str, shape))
+    assert not [line for line in compiled.as_text().splitlines()
+                if ' copy(' in line and whole in line.split(' copy(')[0]]

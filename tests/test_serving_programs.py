@@ -7,6 +7,12 @@ deleted (PR 27, d61f26e; tests/serving_programs_pr27.json). The paged
 programs are what every benchmark cell compiles: a change that disturbs
 a builder changes the executables, their cache keys and the numbers, and
 has to record a new list here on purpose.
+
+PR 44 did, for the decode programs alone: they hold no kv_page_cow and
+take no decode_cow_* feed (tests/serving_programs_pr44.json, which also
+records the page copy program that took the copies' place). The prefill
+and verify programs are still held to the PR 27 file: their executables
+were not to change.
 """
 import json
 import os
@@ -21,9 +27,14 @@ from paddle_tpu.models.transformer import (TransformerConfig,
                                            language_model_logits)
 from paddle_tpu.transpiler.decode_transpiler import DecodeTranspiler
 
-with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                       'serving_programs_pr27.json')) as f:
-    RECORDED = json.load(f)
+def _recorded(name):
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           name)) as f:
+        return json.load(f)
+
+
+RECORDED = _recorded('serving_programs_pr27.json')
+RECORDED.update(_recorded('serving_programs_pr44.json'))
 
 GEOMETRY = dict(slots=3, page_tokens=4, kv_pages=13, prefill_chunk=8)
 
@@ -67,7 +78,10 @@ def programs():
                                     hpair.prefill_feeds,
                                     hpair.prefill_fetches),
         'hybrid_decode': _describe(hpair.decode_program, hpair.decode_feeds,
-                                   hpair.decode_fetches)}
+                                   hpair.decode_fetches),
+        'gpt2_page_copy': _describe(t.copy_program, t.copy_feeds, ()),
+        'hybrid_page_copy': _describe(hpair.copy_program, hpair.copy_feeds,
+                                      ())}
 
 
 @pytest.mark.parametrize('name', sorted(RECORDED))
@@ -76,3 +90,20 @@ def test_serving_program_is_op_for_op_what_it_was(programs, name):
     assert got['feeds'] == want['feeds']
     assert got['fetches'] == want['fetches']
     assert got['ops'].split() == want['ops'].split()
+
+
+def test_only_the_decode_lists_were_recorded_again():
+    """The prefill and verify programs are held to what PR 27 recorded;
+    the decode programs differ from it by the copies alone."""
+    old = _recorded('serving_programs_pr27.json')
+    new = _recorded('serving_programs_pr44.json')
+    assert sorted(new) == ['gpt2_decode', 'gpt2_page_copy',
+                           'hybrid_decode', 'hybrid_page_copy']
+    for name in ('gpt2_decode', 'hybrid_decode'):
+        assert [op for op in old[name]['ops'].split()
+                if op != 'kv_page_cow'] == new[name]['ops'].split()
+        assert [f for f in old[name]['feeds'] if 'cow' not in f] \
+            == new[name]['feeds']
+        assert old[name]['fetches'] == new[name]['fetches']
+    for name in ('gpt2_page_copy', 'hybrid_page_copy'):
+        assert set(new[name]['ops'].split()) == {'kv_page_cow'}

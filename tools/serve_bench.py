@@ -108,7 +108,8 @@ def _cached_tokens_per_sec(pred, cfg, slots, iters):
         dec.decode_step(toks, pos)
     dt = (time.perf_counter() - t0) / iters
     stats = dec.jit_cache_stats()
-    assert stats['compiled_segments'] == 2, stats   # prefill + decode
+    # prefill + decode + the page copy program (with decode's first step)
+    assert stats['compiled_segments'] == 3, stats
     return slots / dt, dt * 1e3, prefill_ms
 
 
