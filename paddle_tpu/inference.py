@@ -160,7 +160,8 @@ class AnalysisPredictor(Predictor):
     def prepare_decoding(self, slots=None, page_tokens=None, kv_pages=None,
                          prefill_chunk=None, speculative=False,
                          spec_k=None, draft_layers=None,
-                         draft_predictor=None, mesh=None, paged=True):
+                         draft_predictor=None, mesh=None, paged=True,
+                         snapshot_rows=0):
         """Transpile the loaded LM into the paged prefill + decode pair
         and return a serving.PagedDecodePredictor over this predictor's
         weight scope — page-pool cache with copy-on-write prefix sharing
@@ -174,7 +175,12 @@ class AnalysisPredictor(Predictor):
         every decode/prefill/verify program ONE GSPMD SPMD program over
         a device mesh ('tp=2' / MeshConfig / jax Mesh; None = read
         FLAGS_serve_mesh_shape, '' = single-chip) — greedy decode stays
-        bit-exact vs single-chip (serving/mesh.py). Raises
+        bit-exact vs single-chip (serving/mesh.py). snapshot_rows, a
+        size of the deployment beside slots and kv_pages for a model
+        with recurrent layers: how many prefix boundaries keep their
+        recurrent state on the device, so that the prefix cache can
+        hand out their pages (0: none are kept and nothing is shared,
+        as for every such model before). Raises
         transpiler.DecodeTranspileError if the program is not a
         recognizable decoder-only LM."""
         # `paged` is accepted only because benchmarks/builders still pass
@@ -184,6 +190,9 @@ class AnalysisPredictor(Predictor):
                 'prepare_decoding(paged=False): the dense ring KV cache '
                 'was removed; every decoder serves from the page pool')
         if speculative:
+            if snapshot_rows:
+                raise ValueError('snapshot_rows with speculative=True: '
+                                 'speculation refuses recurrent state')
             from .serving import SpeculativeDecodePredictor
             return SpeculativeDecodePredictor(
                 self, slots=slots, spec_k=spec_k,
@@ -196,7 +205,7 @@ class AnalysisPredictor(Predictor):
                                     page_tokens=page_tokens,
                                     kv_pages=kv_pages,
                                     prefill_chunk=prefill_chunk,
-                                    mesh=mesh)
+                                    mesh=mesh, snapshot_rows=snapshot_rows)
 
 
 def create_analysis_predictor(config):

@@ -309,6 +309,13 @@ class DecodeSpec(object):
         return []
 
     @property
+    def sm_scale(self):
+        """What the paged attention multiplies its scores by:
+        1/sqrt(head_dim), unless the block states its own
+        (models/granite_h.py: attention_multiplier)."""
+        return 1.0 / np.sqrt(self.dh)
+
+    @property
     def pool_heads(self):
         """Heads a page holds: the model's K/V heads, except where a
         spec pads them to whole tiles (models/hybrid.py)."""
@@ -540,7 +547,7 @@ def _paged_prefill_attention(x, spec, blk, pool, table, positions,
     if rep > 1:
         q = L.reshape(q, shape=grouped)
     scores = L.matmul(q, kt, transpose_y=True,
-                      alpha=1.0 / np.sqrt(spec.dh))
+                      alpha=spec.sm_scale)
     if rep > 1:
         scores = L.reshape(scores, shape=[-1, spec.heads, chunk, window])
     masked = _tmp_var()                                # [1, H, C, J]
@@ -579,7 +586,7 @@ def _paged_decode_attention(x, spec, blk, pool, table, positions,
               inputs={'Q': [q1], 'KPool': [pool[0]], 'VPool': [pool[1]],
                       'Table': [table], 'Positions': [positions]},
               outputs={'Out': [ctx]},
-              attrs={'sm_scale': float(1.0 / np.sqrt(spec.dh)),
+              attrs={'sm_scale': float(spec.sm_scale),
                      'head_axis': _tp_ax(spec) or ''})  # [S, 1, H, dh]
     ctx = _model_heads(ctx, spec, 1)
     ctx = sharding_constraint(ctx, (None, None, None))
@@ -609,7 +616,7 @@ def _paged_verify_attention(x, spec, blk, pool, table, positions,
     kt = _paged_gather(pool[0], table, spec)           # [S, H, J, dh]
     vt = _paged_gather(pool[1], table, spec)
     scores = L.matmul(q, kt, transpose_y=True,
-                      alpha=1.0 / np.sqrt(spec.dh))    # [S, H, K1, J]
+                      alpha=spec.sm_scale)             # [S, H, K1, J]
     masked = _tmp_var()
     _block_op('spec_verify_mask',
               inputs={'X': [scores], 'Positions': [positions]},
@@ -805,6 +812,58 @@ def build_page_copy_program(spec, slots, num_pages, page_tokens):
                           outputs={'Out': [pool]},
                           attrs={'page_rows': True})
     return prog, ['page_copy_src', 'page_copy_dst']
+
+
+def snapshot_names(spec):
+    """Snapshot-row vars, one beside each recurrent-state var of the
+    pair and in the same order."""
+    return [n + '.snapshot' for n in spec.state_names()]
+
+
+def build_state_copy_programs(spec, slots, rows):
+    """What gives a model with recurrent layers a prefix cache: the two
+    programs that move one stream's recurrent state between its slot
+    and a snapshot row, on the device.
+
+    Beside each state var [slots, ...] of the pair (spec.state_names())
+    lies a snapshot var [rows, ...] (snapshot_names) in the same scope.
+    `snapshot` copies row state_copy_from of every state var to row
+    state_copy_to of its snapshot var; `adopt` copies the other way.
+    One state_row_copy an array: the array written is donated and
+    updated where it lies, the one read is left alone, so a copy moves
+    the stream's state once each way (38.7 MB at the Granite 4.0-H
+    Small widths) whatever `slots` and `rows` are, and the device runs
+    it in the order it was dispatched: a snapshot behind the chunk that
+    ended the prompt, an adoption in front of the stream's first chunk.
+    Returns (snapshot program, adopt program, feed_names); nothing to
+    fetch.
+    """
+    from ..framework import Program, program_guard
+    feeds = ['state_copy_from', 'state_copy_to']
+    out = []
+    for adopt in (False, True):
+        prog, startup = Program(), Program()
+        prog._is_test = True
+        with program_guard(prog, startup):
+            at, to = (L.data(n, [1], append_batch_size=False, dtype='int32')
+                      for n in feeds)
+            block = prog.global_block()
+            for layer in spec.recurrent_layers:
+                for name, shape in zip(spec.state_names(layer),
+                                       spec.state_shapes(slots)):
+                    state, snap = (block.create_var(
+                        name=n, shape=(lead,) + tuple(shape[1:]),
+                        dtype='float32', persistable=True,
+                        stop_gradient=True, is_cache=True)
+                        for n, lead in ((name, slots),
+                                        (name + '.snapshot', rows)))
+                    src, dst = (snap, state) if adopt else (state, snap)
+                    _block_op('state_row_copy',
+                              inputs={'Src': [src], 'Pool': [dst],
+                                      'From': [at], 'To': [to]},
+                              outputs={'Out': [dst]})
+        out.append(prog)
+    return out[0], out[1], feeds
 
 
 def build_verify_program(spec, slots, k1, num_pages, page_tokens,

@@ -185,6 +185,24 @@ def _kv_page_cow_emit(ctx, op):
     ctx.set(op.single_output('Out'), pool.at[dst].set(pool[src]))
 
 
+@op_emitter('state_row_copy')
+def _state_row_copy_emit(ctx, op):
+    """One row from one array into another: Src [A, ...], Pool [B, ...]
+    (rows of the same shape), From [1], To [1] int32 -> Pool with
+    Pool[To] = Src[From]. What moves a slot's recurrent state to a
+    snapshot row and back (models/transformer.build_state_copy_programs):
+    Pool is donated and updated where it lies, Src is only read, so a
+    copy moves one row's bytes each way and nothing else."""
+    src = ctx.get(op.single_input('Src'))
+    pool = ctx.get(op.single_input('Pool'))
+    at = ctx.get(op.single_input('From')).astype(jnp.int32).reshape(())
+    to = ctx.get(op.single_input('To')).astype(jnp.int32).reshape(())
+    row = jax.lax.dynamic_index_in_dim(src, at, axis=0, keepdims=True)
+    ctx.set(op.single_output('Out'),
+            jax.lax.dynamic_update_index_in_dim(pool, row.astype(pool.dtype),
+                                                to, axis=0))
+
+
 @op_emitter('kv_page_write')
 def _kv_page_write_emit(ctx, op):
     """Chunked prefill: scatter a chunk's K or V rows through one page
@@ -446,6 +464,8 @@ def _kv_page_gather_infer(op, block):
 
 
 register_op('kv_page_cow', infer_shape=_kv_pool_update_infer,
+            no_grad=True)
+register_op('state_row_copy', infer_shape=_kv_pool_update_infer,
             no_grad=True)
 register_op('kv_page_write', infer_shape=_kv_pool_update_infer,
             no_grad=True)

@@ -163,6 +163,11 @@ register_vjp_grad('moe_aux_loss', in_slots=('Gate',))
 #   w = scale * s_sel / sum(s_sel);  r = sum_{e held} w_e W2_e relu(W1_e l)^2
 #   or, for experts of three matrices,  sum_{e held} w_e W2_e (silu(W1_e l) * W3_e l)
 #
+# A second scoring (attr gate 'softmax': the GraniteMoe router) ranks by
+# the logits themselves and weighs by a softmax over the chosen ones:
+#
+#   l = W_r x;  the top_k largest of l;  w = scale * softmax(l_sel)
+#
 # with l the row the experts work on: the token's latent row (the
 # projection down to it and back up are the block's own matmuls,
 # outside this op), or x itself. The router's product
@@ -190,28 +195,38 @@ def _within_kept_groups(b, n_group, topk_group):
 
 
 def served_weights(x, router_w, bias, top_k, scale, n_group=1,
-                   topk_group=1):
+                   topk_group=1, gate='sigmoid'):
     """x [R, D], router_w [D, E], bias [E] -> w [R, E] float32: a row's
     weight for each expert, 0 for those it did not choose. With
     n_group > 1 the choice is group-limited (the DeepSeek-V3 gate): a
     row chooses among the experts of its topk_group best groups only.
+    gate 'softmax' ranks by the logits (plus the bias) and weighs by a
+    softmax over the logits of the chosen (a chosen expert's weight is
+    not 0 short of logits 87 apart).
 
     The k largest of s + b are found by rank, not by a sort: an expert
     is chosen when fewer than k others score higher (an equal score of
     a lower index counts as higher, lax.top_k's order), one fused
     compare-and-count over [R, E, E] that the chip runs in tens of
     microseconds where its top_k takes 0.4 ms for 64 rows of 512."""
-    s = jax.nn.sigmoid(jnp.matmul(
-        x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    b = s + bias.astype(jnp.float32)
+    s = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+    if gate == 'sigmoid':
+        s = jax.nn.sigmoid(s)
+    elif gate != 'softmax':
+        raise ValueError('gate %r is not sigmoid or softmax' % (gate,))
+    b = s if bias is None else s + bias.astype(jnp.float32)
     if n_group > 1:
         b = _within_kept_groups(b, n_group, topk_group)
     mine, other = b[:, :, None], b[:, None, :]
     e = jnp.arange(b.shape[1])
     ahead = (other > mine) | ((other == mine) & (e[None, :] < e[:, None]))
     chosen = jnp.sum(ahead, axis=-1) < top_k
-    sel = jnp.where(chosen, s, 0.0)
+    if gate == 'softmax':
+        top = jnp.max(jnp.where(chosen, s, -jnp.inf), -1, keepdims=True)
+        sel = jnp.where(chosen, jnp.exp(s - top), 0.0)
+    else:
+        sel = jnp.where(chosen, s, 0.0)
     return scale * sel / jnp.sum(sel, -1, keepdims=True)
 
 
@@ -250,9 +265,11 @@ def held_gated_experts(lat, w, w1, w3, w2):
 def _moe_experts_emit(ctx, op):
     """The held experts' part of a served expert layer. X [.., D] (what
     the router scores), Lat [.., L] (what the experts work on), RouterW
-    [D, E], Bias [E], W1 [held, L, F], W2 [held, F, L]; attrs top_k,
-    scale, expert_offset, and n_group and topk_group where the choice
-    is group-limited (1 and 1: over all experts) -> Out [.., L]. The
+    [D, E], Bias [E] (none: no selection bias), W1 [held, L, F], W2
+    [held, F, L]; attrs top_k,
+    scale, expert_offset, n_group and topk_group where the choice
+    is group-limited (1 and 1: over all experts), and gate ('sigmoid'
+    where not given, or 'softmax' over the chosen logits) -> Out [.., L]. The
     experts' form follows from the weights handed in: with W3 [held, L,
     F] beside W1 an expert is W2 (silu(W1 l) * W3 l), without it W2
     relu(W1 l)^2. Rows may be marked dead, by Live [rows] (a decode step's
@@ -270,9 +287,11 @@ def _moe_experts_emit(ctx, op):
     offset = int(op.attr('expert_offset', 0))
     w = served_weights(
         x.reshape(rows, x.shape[-1]), ctx.get(op.single_input('RouterW')),
-        ctx.get(op.single_input('Bias')), int(op.attr('top_k')),
+        ctx.get(op.single_input('Bias')) if op.input('Bias') else None,
+        int(op.attr('top_k')),
         float(op.attr('scale', 1.0)), int(op.attr('n_group', 1)),
-        int(op.attr('topk_group', 1)))[:, offset:offset + w1.shape[0]]
+        int(op.attr('topk_group', 1)),
+        op.attr('gate', 'sigmoid'))[:, offset:offset + w1.shape[0]]
     if op.input('Live'):
         w = jnp.where(ctx.get(op.single_input('Live')).astype(bool)
                       .reshape(rows)[:, None], w, 0.0)

@@ -62,10 +62,36 @@ pools, per-slot state that is not pages: `state_names` of the pair,
 [slots, ...] arrays in the same child Scope, updated in place by both
 programs. A stream's first chunk starts its slot from zero state by a
 flag it feeds (no dispatch at open_stream); save_stream / restore_stream
-carry the slot's rows with the pages. The prefix cache hands out
-nothing for such a model: a prefix's pages without the recurrent state
-at that boundary would be a wrong stream, and keeping that state
-(13 MB a boundary at the 7B widths, against 1 MB a page) is not built.
+carry the slot's rows with the pages. For such a model a prefix is
+pages AND state: its pages without the recurrent state at that boundary
+would be a wrong stream, so the prefix cache never hands out the one
+without the other. How many boundaries keep their state is a size of
+the deployment, `snapshot_rows`, beside `slots` and `kv_pages`. With 0
+rows (the default) the cache hands out nothing and registers nothing
+for such a model, and nothing below exists: no array, no program, no
+dispatch. With rows, beside each state array lies a snapshot array
+[snapshot_rows, ...] (38.7 MB a row at the Granite 4.0-H Small widths,
+13 MB at the 7B hybrid's, against 128 KB or 1 MB a page): when a
+prompt's last chunk is booked, the slot's state is copied to a row on
+the device, behind that chunk (the pair's snapshot program), and the
+cache's entry for exactly len(prompt) tokens names the row
+(PrefixCache.register_state); open_stream matches the longest boundary
+that has a snapshot and whose pages are resident (match_state), adopts
+its pages as for any model, and the stream's first prefill_step copies
+the row into the slot (the adopt program, reset flag 0) in front of its
+chunk, which starts at `shared_tokens`, wherever in a page or a chunk
+that is; a partly filled last page forks on the first append as pages
+do. Rows are bounded and LRU, and a row goes before any other once the
+one stream that opened on it has registered a later boundary (a
+conversation that moved on; a second reader makes it a shared prefix
+and plain LRU again), so a session in progress holds one row and not
+the two its last turns touched; a row a stream has matched and not yet
+copied is pinned. Both programs compile in front of the predictor's
+first prefill chunk, so admission, adoption, snapshot and eviction
+recompile nothing. Speculation, export_prefix / install_prefix and
+mesh serving refuse recurrent state by name, as before, and the fleet's
+prefix directory is told nothing of such a model's pages
+(prefix_report, resident_keys).
 A model whose layers keep a latent page (models/axk1.py: one pool a
 layer, [pages, page_tokens, row]) keeps nothing else for a stream, so
 everything here that moves pages (the prefix cache with copy-on-write,
@@ -88,7 +114,12 @@ steps updated; attr `state_lanes` of the same span),
 serving.recurrent_state_bytes and serving.state_resets gauges (bytes
 the recurrent state holds; streams started from zero state so far),
 and serving.<family>.state_bytes for a spec that names its state's
-family (serving.ssm.state_bytes: models/nemotron_h.py). For a model
+family (serving.ssm.state_bytes: models/nemotron_h.py). With snapshot
+rows: the counters serving.state.snapshots_taken, _adopted and
+_evicted (an eviction for its row or with its pages) and the gauge
+serving.state.snapshot_bytes (what the snapshot rows hold on the
+device, all rows); serving.prefix_hits / prefix_tokens_reused count
+such a model's streams as they count the others'. For a model
 whose pages hold latent rows (models/axk1.py): the gauge
 serving.latent.cache_bytes (pages in use x the bytes a page's rows
 take over all layers) and the counter serving.latent.rows_read (rows
@@ -130,7 +161,9 @@ it before and after a pass for the pass's wait (`wait_ms` of
 `serve.iter`, the counter serving.loop.wait_seconds).
 `paged.state.save` / `paged.state.restore` (attr `nbytes`) inside
 save_stream / restore_stream: the recurrent rows' way to the host and
-back.
+back. `paged.state.snapshot` / `paged.state.adopt` (attrs `nbytes`,
+`tokens`: the boundary) around the dispatch of the copy between a slot
+and a snapshot row; the copy itself is the device's.
 """
 from __future__ import annotations
 
@@ -164,6 +197,10 @@ _latent_bytes = telemetry.gauge('serving.latent.cache_bytes')
 _latent_rows = telemetry.counter('serving.latent.rows_read')
 _cow_dispatches = telemetry.counter('serving.cow.dispatches')
 _cow_pages = telemetry.counter('serving.cow.pages')
+_snaps_taken = telemetry.counter('serving.state.snapshots_taken')
+_snaps_adopted = telemetry.counter('serving.state.snapshots_adopted')
+_snaps_evicted = telemetry.counter('serving.state.snapshots_evicted')
+_snap_bytes = telemetry.gauge('serving.state.snapshot_bytes')
 _MOE_COUNTS = ('pairs', 'experts_touched', 'pairs_dropped', 'layer_calls')
 
 
@@ -177,11 +214,12 @@ def _set_row(state, slot, rows):
 
 
 class _PendingPrefill(object):
-    __slots__ = ('prompt', 'chunks')
+    __slots__ = ('prompt', 'chunks', 'snapshot')
 
-    def __init__(self, prompt):
+    def __init__(self, prompt, snapshot=None):
         self.prompt = prompt
         self.chunks = 0
+        self.snapshot = snapshot    # matched, pinned, not yet copied
 
 
 class PagedDecodePredictor(object):
@@ -195,7 +233,7 @@ class PagedDecodePredictor(object):
 
     def __init__(self, predictor, slots=None, page_tokens=None,
                  kv_pages=None, prefill_chunk=None, _clone_of=None,
-                 pair=None, mesh=None):
+                 pair=None, mesh=None, snapshot_rows=0):
         """predictor: a (loaded) Predictor/AnalysisPredictor whose
         program is a decoder-only LM. slots defaults to
         FLAGS_serving_slots, the page geometry to FLAGS_serving_*.
@@ -206,7 +244,9 @@ class PagedDecodePredictor(object):
         makes every program ONE GSPMD SPMD program over the mesh — the
         page pool shards its heads axis over tp, weights per
         DecodeSpec.serve_param_specs, greedy decode stays bit-exact vs
-        single-chip (serving/mesh.py)."""
+        single-chip (serving/mesh.py). snapshot_rows (a model with
+        recurrent layers): the prefix boundaries that keep their
+        recurrent state on the device (module docstring)."""
         self._base = predictor
         if _clone_of is not None:
             self._pair = _clone_of._pair
@@ -223,7 +263,8 @@ class PagedDecodePredictor(object):
                 self._pair = DecodeTranspiler().transpile(
                     predictor._program, slots=slots,
                     page_tokens=page_tokens, kv_pages=kv_pages,
-                    prefill_chunk=prefill_chunk)
+                    prefill_chunk=prefill_chunk,
+                    snapshot_rows=int(snapshot_rows or 0))
             self._weight_scope = predictor._scope
             self._mesh, self._mesh_shape = serving_mesh(mesh)
             if self._mesh is not None:
@@ -239,8 +280,10 @@ class PagedDecodePredictor(object):
         self.fetch_wait_s = 0.0       # blocked in a step's fetch, ever
         self.reset()
         # the copy program is compiled by the first decode step, with
-        # the decode program (decode_step)
+        # the decode program (decode_step), the state copy programs by
+        # the first prefill chunk, with the prefill program
         self._copy_compiled = False
+        self._state_copy_compiled = not self._pair.snapshot_rows
 
     def _make_executor(self, place):
         if self._mesh is None:
@@ -340,6 +383,18 @@ class PagedDecodePredictor(object):
         return 4 * len(self._pair.spec.recurrent_layers) * int(
             sum(np.prod(s) for s in shapes))
 
+    def _snapshot_row_bytes(self):
+        """What one snapshot row holds: one slot's recurrent state."""
+        return self._recurrent_state_bytes() // self.slots
+
+    def _copy_state(self, program, at, to):
+        """One state copy program: row `at` of every array it reads to
+        row `to` of the array beside it, one dispatch."""
+        self._exe.run(program, feed=dict(zip(
+            self._pair.state_copy_feeds,
+            (np.array([at], np.int32), np.array([to], np.int32)))),
+            scope=self._scope, return_numpy=False)
+
     def _fetch(self, value):
         """value on the host: the wait for the device and the transfer,
         its seconds added to `fetch_wait_s`."""
@@ -417,11 +472,17 @@ class PagedDecodePredictor(object):
                 'prefix_hits': self._prefix.hits,
                 'prefix_misses': self._prefix.misses,
                 'prefix_pages': self._prefix.resident_pages,
-                'prefix_tokens_reused': self._prefix.tokens_reused}
+                'prefix_tokens_reused': self._prefix.tokens_reused,
+                'snapshot_rows': self._pair.snapshot_rows,
+                'snapshots': self._prefix.snapshots}
 
     def _update_gauges(self):
         _pages_in_use.set(self._pool.pages_in_use)
         _pages_free.set(self._pool.pages_free)
+        gone = self._prefix.snapshots_dropped
+        if gone > self._snaps_gone:
+            _snaps_evicted.inc(gone - self._snaps_gone)
+            self._snaps_gone = gone
         if self._pair.spec.page_kind == 'latent':
             _latent_bytes.set(self._pool.pages_in_use * self.page_tokens
                               * self._pair.spec.latent_row_bytes())
@@ -573,12 +634,19 @@ class PagedDecodePredictor(object):
             for name, shape in zip(spec.state_names(layer),
                                    spec.state_shapes(self.slots)):
                 self._scope.set_var(name, np.zeros(shape, np.float32))
+        rows = self._pair.snapshot_rows
+        if rows:
+            shapes = spec.state_shapes(rows) * len(spec.recurrent_layers)
+            for name, shape in zip(self._pair.snapshot_names, shapes):
+                self._scope.set_var(name, np.zeros(shape, np.float32))
+            _snap_bytes.set(rows * self._snapshot_row_bytes())
         self._moe_queue = collections.deque()   # (decode?, counts [4])
         self._moe_lock = threading.Lock()
         self._moe_totals = {p + what: 0 for what in _MOE_COUNTS
                             for p in ('', 'decode.')}
         self._pool = PagePool(self.num_pages, self.page_tokens)
-        self._prefix = PrefixCache(self._pool)
+        self._prefix = PrefixCache(self._pool, snapshot_rows=rows)
+        self._snaps_gone = 0          # of them, counted so far
         self._pool.set_evict(self._prefix.evict_one)
         self._tables = {}             # slot -> PageTable
         self._pending = {}            # slot -> _PendingPrefill
@@ -624,17 +692,26 @@ class PagedDecodePredictor(object):
             raise ValueError('prompt length %d outside [1, %d] (max_len)'
                              % (len(prompt), self.max_len))
         table = PageTable(self._pool, self.pages_per_slot)
-        # never pages without their state: nothing is shared where a
-        # stream has recurrent state (see the module docstring)
-        pages, shared = ([], 0) if self.recurrent else \
-            self._prefix.match(prompt, limit=len(prompt) - 1)
+        # never pages without their state: a stream with recurrent
+        # state opens on a boundary that has a snapshot (which comes
+        # pinned until its first chunk has copied it), or on nothing
+        # (see the module docstring)
+        snapshot = None
+        if not self.recurrent:
+            pages, shared = self._prefix.match(prompt,
+                                               limit=len(prompt) - 1)
+        elif self._pair.snapshot_rows:
+            pages, shared, snapshot = self._prefix.match_state(
+                prompt, limit=len(prompt) - 1)
+        else:
+            pages, shared = [], 0
         if shared:
             table.adopt_shared(pages, shared)
             _prefix_hits.inc()
             _prefix_tokens.inc(shared)
         _prompt_tokens.inc(len(prompt))
         self._tables[slot] = table
-        self._pending[slot] = _PendingPrefill(prompt)
+        self._pending[slot] = _PendingPrefill(prompt, snapshot)
         self._update_gauges()
         chunk = self.prefill_chunk
         return {'slot': slot, 'prompt_tokens': len(prompt),
@@ -650,7 +727,9 @@ class PagedDecodePredictor(object):
         device runs it after it."""
         slot = int(slot)
         table = self._tables.pop(slot, None)
-        self._pending.pop(slot, None)
+        st = self._pending.pop(slot, None)
+        if st is not None and st.snapshot is not None:
+            self._prefix.unpin(st.snapshot)
         if table is not None:
             table.release()
             self._update_gauges()
@@ -748,6 +827,8 @@ class PagedDecodePredictor(object):
         skips pages already here. Advisory (no quiesce, no LRU touch):
         install_prefix re-checks residency under the swap gate, so a
         racing eviction only costs wire bytes, never correctness."""
+        if self.recurrent:
+            return []           # such pages are worth nothing elsewhere
         prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
         digests, _ = self._prefix.chain(prompt, limit=len(prompt) - 1)
         return [d.hex() for d in digests]
@@ -806,8 +887,11 @@ class PagedDecodePredictor(object):
 
     def prefix_report(self):
         """Drain the prefix cache's registered/evicted delta (the
-        SRV_HEALTH payload feeding the fleet prefix directory)."""
-        return self._prefix.drain_events()
+        SRV_HEALTH payload feeding the fleet prefix directory). The
+        directory is told nothing of pages that are a prefix only with
+        a snapshot row of this predictor."""
+        events = self._prefix.drain_events()
+        return {'new': [], 'evicted': []} if self.recurrent else events
 
     @staticmethod
     def _rollback(cows, grows):
@@ -877,6 +961,26 @@ class PagedDecodePredictor(object):
                 if start == 0:
                     self._resets += 1
                     _state_resets.set(self._resets)
+        if not self._state_copy_compiled:
+            # the two state copy programs compile where the prefill
+            # program does: a copy of this slot's rows, which the chunk
+            # is about to reset, to a row that holds no snapshot yet,
+            # and back
+            with RecordEvent('paged.state.compile'):
+                self._copy_state(self._pair.snapshot_program, slot, 0)
+                self._copy_state(self._pair.adopt_program, 0, slot)
+            self._state_copy_compiled = True
+        if st.snapshot is not None:
+            # the stream opened on a snapshot: its row into the slot, on
+            # the device, in front of the chunk (the tables are settled:
+            # a chunk that raised has copied nothing and keeps its pin)
+            with RecordEvent('paged.state.adopt', tokens=start,
+                             nbytes=self._snapshot_row_bytes()):
+                self._copy_state(self._pair.adopt_program,
+                                 st.snapshot.row, slot)
+            self._prefix.unpin(st.snapshot)
+            st.snapshot = None
+            _snaps_adopted.inc()
         logits, ids = self._run(self._pair.prefill_program, feed,
                                 self._pair.prefill_fetches, False)
         with RecordEvent('paged.prefill.book'):
@@ -889,6 +993,18 @@ class PagedDecodePredictor(object):
                 return None
             if not self.recurrent:
                 self._prefix.register(prompt, table)
+            elif self._pair.snapshot_rows:
+                row = self._prefix.register_state(prompt, table)
+                if row is not None:
+                    # behind the chunk just dispatched, in front of any
+                    # step that moves the slot's state on
+                    with RecordEvent('paged.state.snapshot',
+                                     tokens=len(prompt),
+                                     nbytes=self._snapshot_row_bytes()):
+                        self._copy_state(self._pair.snapshot_program,
+                                         slot, row)
+                    _snaps_taken.inc()
+                self._update_gauges()
             del self._pending[slot]
             _prefill_chunks.observe(st.chunks)
         if before_fetch is not None:

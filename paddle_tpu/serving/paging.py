@@ -314,6 +314,32 @@ class _Tail(object):
         self.stamp = 0
 
 
+class _Snap(object):
+    """The recurrent state at one prefix boundary: `chain` is the digest
+    of its full pages and `rest` the tokens on its tail page (so it
+    needs the nodes up to `chain` and, with a rest, the tail entry
+    (chain, rest)); `row` is where the state lies on the device. `pins`
+    counts the streams that matched it and have not copied it yet: its
+    row is not handed out again before they have. `reads` counts the
+    streams that opened on it, and `passed` says that a prompt which ran
+    over this boundary has registered a later one: with one reader at
+    most that is a conversation which has moved on, and its row goes
+    before any other (`spent`); a second reader makes it a shared
+    prefix again."""
+    __slots__ = ('row', 'chain', 'rest', 'tokens', 'stamp', 'pins', 'gone',
+                 'reads', 'passed')
+
+    def __init__(self, row, chain, rest, tokens):
+        self.row, self.chain, self.rest = row, chain, rest
+        self.tokens = tokens
+        self.stamp = self.pins = self.reads = 0
+        self.gone = self.passed = False
+
+    @property
+    def spent(self):
+        return self.passed and self.reads <= 1
+
+
 class PrefixCache(object):
     """Content-hash page index for shared prefixes.
 
@@ -328,10 +354,15 @@ class PrefixCache(object):
     LEAF (no children, no tails) so interior chain pages are never
     orphaned while still reachable."""
 
-    def __init__(self, pool):
+    def __init__(self, pool, snapshot_rows=0):
         self.pool = pool
         self._nodes = {}          # chain digest -> _Node
         self._tails = {}          # chain digest -> {tokens: _Tail}
+        # recurrent state at prefix boundaries (match_state /
+        # register_state; nothing here is touched without them)
+        self._snaps = {}          # chain digest -> {rest tokens: _Snap}
+        self._free_rows = list(range(int(snapshot_rows)))
+        self.snapshots_dropped = 0
         self._clock = 0
         self.hits = 0
         self.misses = 0
@@ -348,6 +379,25 @@ class PrefixCache(object):
         self._clock += 1
         entry.stamp = self._clock
 
+    def _digests(self, tokens, full):
+        """The chain digest behind each of the first `full` whole pages
+        of `tokens`, in order: the one place the chain is hashed."""
+        pt = self.pool.page_tokens
+        chain = b''
+        for k in range(full):
+            chain = _digest(chain, tokens[k * pt:(k + 1) * pt])
+            yield chain
+
+    def _resident(self, tokens, full):
+        """(digest, node) along the chain of the first `full` whole
+        pages of `tokens`, as far as it is resident. Because eviction
+        is leaf-first, the resident part of a chain is a prefix of it."""
+        for digest in self._digests(tokens, full):
+            node = self._nodes.get(digest)
+            if node is None:
+                return
+            yield digest, node
+
     # -- lookup ------------------------------------------------------------
     def match(self, prompt, limit=None):
         """Longest shared prefix of `prompt` (at most `limit` tokens;
@@ -359,16 +409,11 @@ class PrefixCache(object):
         pt = self.pool.page_tokens
         limit = len(prompt) if limit is None else min(limit, len(prompt))
         full = limit // pt
-        pages, chain, k = [], b'', 0
-        while k < full:
-            nxt = _digest(chain, prompt[k * pt:(k + 1) * pt])
-            node = self._nodes.get(nxt)
-            if node is None:
-                break
+        pages, chain = [], b''
+        for chain, node in self._resident(prompt, full):
             self._touch(node)
             pages.append(node.page)
-            chain = nxt
-            k += 1
+        k = len(pages)
         tokens = k * pt
         if k == full:             # a tail only connects at chain end
             rest = tuple(int(t) for t in prompt[tokens:limit])
@@ -400,15 +445,10 @@ class PrefixCache(object):
         pt = self.pool.page_tokens
         toks = [int(t) for t in prompt]
         limit = len(toks) if limit is None else min(int(limit), len(toks))
-        digests, pages, chain = [], [], b''
-        for k in range(limit // pt):
-            nxt = _digest(chain, toks[k * pt:(k + 1) * pt])
-            node = self._nodes.get(nxt)
-            if node is None:
-                break
-            digests.append(nxt)
+        digests, pages = [], []
+        for digest, node in self._resident(toks, limit // pt):
+            digests.append(digest)
             pages.append(node.page)
-            chain = nxt
         return digests, pages
 
     def extend_chain(self, parent, digests, pages):
@@ -449,8 +489,8 @@ class PrefixCache(object):
         full = len(prompt) // pt
         chain = b''
         newly_shared = []
-        for k in range(min(full, len(table.pages))):
-            nxt = _digest(chain, prompt[k * pt:(k + 1) * pt])
+        for k, nxt in enumerate(self._digests(
+                prompt, min(full, len(table.pages)))):
             node = self._nodes.get(nxt)
             if node is None:
                 node = _Node(self.pool.share(table.pages[k]), chain)
@@ -482,6 +522,126 @@ class PrefixCache(object):
             table.mark_shared(idx)
         return newly_shared
 
+    # -- pages and state (a model with recurrent layers) --------------------
+    def match_state(self, prompt, limit=None):
+        """match() where a prefix is pages AND state: the longest
+        boundary under `limit` that has a snapshot and whose pages are
+        all resident. Returns (pages, tokens, snapshot); the snapshot
+        comes pinned, and the caller unpin()s it once its row is copied
+        (or the stream is given up). (., 0, None) where nothing
+        matches: pages that run past the newest surviving snapshot are
+        never handed out."""
+        pt = self.pool.page_tokens
+        limit = len(prompt) if limit is None else min(limit, len(prompt))
+        toks = [int(t) for t in prompt[:limit]]
+        nodes = [node for _, node in self._resident(toks, limit // pt)]
+        best = None
+        for k, chain in enumerate(
+                [b''] + list(self._digests(toks, len(nodes)))):
+            for rest, snap in self._snaps.get(chain, {}).items():
+                if tuple(toks[k * pt:k * pt + len(rest)]) == rest \
+                        and (best is None or snap.tokens > best[0].tokens):
+                    best = (snap, k)
+        if best is None:
+            if limit > 0:
+                self.misses += 1
+            return [], 0, None
+        snap, k = best
+        for node in nodes[:k]:
+            self._touch(node)
+        pages = [node.page for node in nodes[:k]]
+        if snap.rest:
+            tail = self._tails[snap.chain][snap.rest]
+            self._touch(tail)
+            pages.append(tail.page)
+        self._touch(snap)
+        snap.pins += 1
+        snap.reads += 1
+        self.hits += 1
+        self.tokens_reused += snap.tokens
+        return pages, snap.tokens, snap
+
+    def unpin(self, snap):
+        snap.pins -= 1
+        if snap.gone and not snap.pins:
+            self._free_rows.append(snap.row)
+
+    def register_state(self, prompt, table):
+        """register() where a prefix is pages AND state: index the
+        prompt's pages and name the row that is to hold the recurrent
+        state at exactly len(prompt) tokens. Returns that row (the
+        caller copies the slot's state there, behind the chunk that
+        ended the prompt), or None where nothing is to be copied: the
+        boundary has its snapshot already, or every row is pinned, and
+        then the pages are not registered either (never pages without
+        their state). A full house gives up a snapshot with the pages
+        that only it kept: the least recently used of those a
+        conversation has moved on from (`_Snap.spent`: the boundary this
+        prompt opened on becomes one here, unless other streams read it
+        too), else the least recently used of all. So a session in
+        progress holds one row and not the two that its last turns
+        touched, and a burst of turns does not take the boundary that a
+        waiting session's next turn will open on."""
+        pt = self.pool.page_tokens
+        full = len(prompt) // pt
+        chains = [b''] + list(self._digests(prompt, full))
+        chain = chains[-1]
+        rest = tuple(int(t) for t in prompt[full * pt:])
+        have = self._snaps.get(chain, {}).get(rest)
+        if have is not None:
+            self._touch(have)
+            self.register(prompt, table)
+            return None
+        if not self._free_rows:
+            idle = [s for at in self._snaps.values() for s in at.values()
+                    if not s.pins]
+            if not idle:
+                return None
+            self._drop_snap(min(idle, key=lambda s: (not s.spent, s.stamp)),
+                            release=True)
+        self.register(prompt, table)
+        # the nearest boundary this prompt ran over
+        for k in range(full, -1, -1):
+            over = [s for r, s in self._snaps.get(chains[k], {}).items()
+                    if tuple(int(t) for t in prompt[k * pt:k * pt + len(r)])
+                    == r]
+            if over:
+                max(over, key=lambda s: s.tokens).passed = True
+                break
+        snap = _Snap(self._free_rows.pop(), chain, rest, len(prompt))
+        self._snaps.setdefault(chain, {})[rest] = snap
+        self._touch(snap)
+        return snap.row
+
+    @property
+    def snapshots(self):
+        return sum(len(at) for at in self._snaps.values())
+
+    def _drop_snap(self, snap, release):
+        """Forget a snapshot; its row is free once no stream has it
+        pinned. With `release`, the pages that no other snapshot can
+        use go too: its tail, then its chain from the end up to the
+        first node that has a child, a tail or a snapshot of its own."""
+        del self._snaps[snap.chain][snap.rest]
+        if not self._snaps[snap.chain]:
+            del self._snaps[snap.chain]
+        snap.gone = True
+        self.snapshots_dropped += 1
+        if not snap.pins:
+            self._free_rows.append(snap.row)
+        if not release:
+            return
+        if snap.rest:
+            self._evict_entry('tail', (snap.chain, snap.rest),
+                              self._tails[snap.chain][snap.rest])
+        digest = snap.chain
+        while digest in self._nodes and digest not in self._snaps:
+            node = self._nodes[digest]
+            if node.children or node.tails:
+                break
+            self._evict_entry('node', digest, node)
+            digest = node.parent
+
     # -- eviction ----------------------------------------------------------
     def _leaves(self):
         for digest, node in self._nodes.items():
@@ -499,6 +659,15 @@ class PrefixCache(object):
         if best is None:
             return False
         _, (kind, key, entry) = best
+        self._evict_entry(kind, key, entry)
+        # a snapshot whose pages go is gone with them
+        at = self._snaps.get(key if kind == 'node' else key[0], {})
+        snap = at.get(() if kind == 'node' else key[1])
+        if snap is not None:
+            self._drop_snap(snap, release=False)
+        return True
+
+    def _evict_entry(self, kind, key, entry):
         if kind == 'node':
             del self._nodes[key]
             parent = self._nodes.get(entry.parent)
@@ -514,7 +683,6 @@ class PrefixCache(object):
             if node is not None:
                 node.tails -= 1
         self.pool.unref(entry.page)
-        return True
 
     def drain_events(self):
         """Take (and clear) the registered/evicted delta since the last

@@ -64,6 +64,19 @@ token a layer (page_kind 'latent'), so the prefix cache and page
 shipping serve it, and speculative decoding and mesh serving, which
 read a page as K and V heads, refuse it by that name.
 
+A fifth is the two-sublayer hybrid of models/granite_h.py
+(`granite_h.language_model_logits`), told from the third by the gate
+of its expert op (attr gate 'softmax'): lookup_table and a scale (the
+embedding's multiplier), no position op, every layer
+  [rms_norm, a mamba or a full_attention mixer as in the third, scale,
+   rms_norm, moe_experts with W3, shared gate-up mul, shared down mul,
+   scale]
+then a final rms_norm and ONE matmul with the embedding transposed (the
+tied head; its alpha is 1 / logits_scaling). Its spec
+(GraniteHDecodeSpec) reads the multipliers back from the scale ops and
+from the attention product's alpha. It holds recurrent state, so the
+refusals above hold for it.
+
 Genuinely
 unsupported layouts (the training MoE op moe_ffn, whose capacity drops
 tokens; ring attention; a
@@ -76,11 +89,12 @@ from __future__ import annotations
 
 import re
 
-from ..models import axk1, hybrid, nemotron_h
+from ..models import axk1, granite_h, hybrid, nemotron_h
 from ..models.transformer import (DecodeSpec, DecodeTranspileError,
                                   refuse_latent_pages, refuse_recurrent,
                                   build_page_copy_program,
-                                  build_verify_program)
+                                  build_state_copy_programs,
+                                  build_verify_program, snapshot_names)
 
 __all__ = ['DecodeTranspileError', 'PagedDecodePair', 'SpecDecodePair',
            'DecodeTranspiler', 'extract_decode_spec', 'refuse_recurrent',
@@ -100,7 +114,15 @@ class PagedDecodePair(object):
     Scope carries the K/V state from prefill into decode. The decode
     program copies no page: copy_program (feeds copy_feeds, no fetch;
     models/transformer.build_page_copy_program) is what the host runs
-    in front of a decode step that forks one."""
+    in front of a decode step that forks one. Where the deployment
+    gives a model with recurrent layers `snapshot_rows` (0: none, and
+    then none of this exists), snapshot_program and adopt_program
+    (feeds state_copy_feeds, no fetch;
+    models/transformer.build_state_copy_programs) move one slot's
+    recurrent state to a row of snapshot_names and back."""
+
+    snapshot_rows = 0
+    snapshot_program = adopt_program = state_copy_feeds = None
 
     def __init__(self, spec, slots, page_tokens, pages_per_slot,
                  num_pages, prefill_chunk,
@@ -135,6 +157,18 @@ class PagedDecodePair(object):
     @property
     def pool_shape(self):
         return self.spec.pool_shape(self.num_pages, self.page_tokens)
+
+    def keep_snapshots(self, rows):
+        """Give the pair `rows` snapshot rows and the two programs that
+        fill and read them (a model with recurrent layers only)."""
+        if not self.state_names:
+            raise ValueError('snapshot_rows=%d for a model without '
+                             'recurrent state: its prefixes are pages '
+                             'alone' % rows)
+        self.snapshot_rows = int(rows)
+        self.snapshot_names = snapshot_names(self.spec)
+        self.snapshot_program, self.adopt_program, self.state_copy_feeds = \
+            build_state_copy_programs(self.spec, self.slots, self.snapshot_rows)
 
 
 class SpecDecodePair(object):
@@ -425,26 +459,9 @@ def _extract_nemotron_spec(block):
                   % (i, kind, len(muls), want))
         blk = {'norm': norm}
         if kind == 'mamba':
-            conv = [op for op in ops if op.type == 'short_conv']
-            gate = [op for op in ops if op.type == 'gated_group_norm']
-            if len(conv) != 1 or not conv[0].input('Bias') \
-                    or len(gate) != 1:
-                _fail('layer %d: ssd_chunk without one short_conv with a '
-                      'Bias and one gated_group_norm' % i)
-            blk.update({'in': muls[0], 'out': muls[1],
-                        'conv': conv[0].single_input('W'),
-                        'conv_bias': conv[0].single_input('Bias'),
-                        'a_log': mark.single_input('ALog'),
-                        'dt_bias': mark.single_input('DtBias'),
-                        'd': mark.single_input('D'),
-                        'gate_norm': gate[0].single_input('Scale')})
-            agree(kind, i, dict(
-                mamba_heads=int(mark.attr('heads')),
-                mamba_head_dim=int(mark.attr('head_dim')),
-                groups=int(mark.attr('groups')),
-                state=int(mark.attr('state')),
-                chunk=int(mark.attr('block', 128)),
-                conv_kernel=shape(blk['conv'])[0]))
+            roles, got = _mamba_roles(i, ops, mark, muls, shape)
+            blk.update(roles)
+            agree(kind, i, got)
         elif kind == 'experts':
             blk.update({'down': muls[0], 'up': muls[1],
                         'shared_up': muls[2], 'shared_down': muls[3],
@@ -461,23 +478,158 @@ def _extract_nemotron_spec(block):
                 latent=latent, expert_ffn=ffn,
                 shared_ffn=shape(muls[2][0])[1]))
         else:
-            blk.update({'qkv': muls[0], 'proj': muls[1]})
-            heads = int(block.var_recursive(
-                mark.single_input('X')).shape[1])
-            width, wide = shape(muls[1][0])[0], shape(muls[0][0])[1]
-            if width % heads or (wide - width) % (2 * (width // heads)):
-                _fail('layer %d: qkv weight %r and proj weight %r do not '
-                      'split into %d query heads and whole K/V heads'
-                      % (i, shape(muls[0][0]), shape(muls[1][0]), heads))
-            dh = width // heads
-            agree(kind, i, dict(heads=heads, head_dim=dh,
-                                kv_heads=(wide - width) // (2 * dh)))
+            roles, got = _attention_roles(i, block, mark, muls, shape)
+            blk.update(roles)
+            agree(kind, i, got)
         blocks.append(blk)
         kinds.append(kind)
     spec = nemotron_h.NemotronHDecodeSpec(
         nemotron_h.NemotronHConfig(layer_types=kinds, **cfg),
         emb_w=emb_w, blocks=blocks, final_norm=final_norm,
         head=(head[0], None))
+    spec.param_specs = {n: None for n in spec.param_names()}
+    return spec
+
+
+def _mamba_roles(i, ops, mark, muls, shape):
+    """(names by role, sizes) of a mamba mixer's ops (those of
+    models/nemotron_h._mamba_mixer)."""
+    conv = [op for op in ops if op.type == 'short_conv']
+    gate = [op for op in ops if op.type == 'gated_group_norm']
+    if len(conv) != 1 or not conv[0].input('Bias') or len(gate) != 1:
+        _fail('layer %d: ssd_chunk without one short_conv with a '
+              'Bias and one gated_group_norm' % i)
+    roles = {'in': muls[0], 'out': muls[1],
+             'conv': conv[0].single_input('W'),
+             'conv_bias': conv[0].single_input('Bias'),
+             'a_log': mark.single_input('ALog'),
+             'dt_bias': mark.single_input('DtBias'),
+             'd': mark.single_input('D'),
+             'gate_norm': gate[0].single_input('Scale')}
+    return roles, dict(
+        mamba_heads=int(mark.attr('heads')),
+        mamba_head_dim=int(mark.attr('head_dim')),
+        groups=int(mark.attr('groups')), state=int(mark.attr('state')),
+        chunk=int(mark.attr('block', 128)),
+        conv_kernel=shape(roles['conv'])[0])
+
+
+def _attention_roles(i, block, mark, muls, shape):
+    """(names by role, sizes) of a whole-sequence attention mixer with
+    whole K/V heads (models/nemotron_h._full_attention)."""
+    heads = int(block.var_recursive(mark.single_input('X')).shape[1])
+    width, wide = shape(muls[1][0])[0], shape(muls[0][0])[1]
+    if width % heads or (wide - width) % (2 * (width // heads)):
+        _fail('layer %d: qkv weight %r and proj weight %r do not '
+              'split into %d query heads and whole K/V heads'
+              % (i, shape(muls[0][0]), shape(muls[1][0]), heads))
+    dh = width // heads
+    return {'qkv': muls[0], 'proj': muls[1]}, dict(
+        heads=heads, head_dim=dh, kv_heads=(wide - width) // (2 * dh))
+
+
+def _extract_granite_spec(block):
+    """The two-sublayer hybrid's spec (see the module docstring): the
+    ops between one rms_norm and the next are one sublayer; sublayers
+    come in pairs, a mixer (marker ssd_chunk or causal_mask) and an
+    expert sublayer (marker moe_experts)."""
+    def shape(name):
+        return tuple(int(d) for d in block.var_recursive(name).shape)
+
+    emb_w = ids = None
+    emb_scale = 1.0
+    subs = []                   # [norm scale, [ops until the next norm]]
+    eps = 1e-5
+    for op in block.ops:
+        t = op.type
+        if t == 'lookup_table' and emb_w is None:
+            emb_w, ids = op.single_input('W'), op.single_input('Ids')
+        elif t == 'rms_norm':
+            subs.append([op.single_input('Scale'), []])
+            eps = op.attr('epsilon', eps)
+        elif t in ('layer_norm', 'position_embedding', 'moe_ffn',
+                   'gated_delta_chunk', 'flash_attention',
+                   'ring_attention', 'latent_attention'):
+            _fail('op %s inside a model whose experts are softmax-gated: '
+                  'not the block of models/granite_h.py' % t)
+        elif subs:
+            subs[-1][1].append(op)
+        elif t == 'scale' and emb_w is not None:
+            emb_scale = float(op.attr('scale'))
+    if emb_w is None:
+        _fail('no lookup_table op (token embedding)')
+    if len(subs) < 3 or len(subs) % 2 != 1:
+        _fail('%d rms_norm ops: want two a layer and one before the head '
+              '(models/granite_h.py)' % len(subs))
+    (final_norm, tail), subs = subs[-1], subs[:-1]
+    head = [op for op in tail if op.type == 'matmul']
+    if len(head) != 1 or head[0].single_input('Y') != emb_w \
+            or not head[0].attr('transpose_Y'):
+        _fail('after the final rms_norm: want one matmul with the '
+              'embedding transposed (the tied head)')
+    vocab, dim = shape(emb_w)
+    cfg = dict(vocab=vocab, dim=dim, max_len=shape(ids)[1], eps=eps,
+               embedding_multiplier=emb_scale,
+               logits_scaling=1.0 / float(head[0].attr('alpha')))
+    sizes = {}
+
+    def agree(kind, i, got):
+        if sizes.setdefault(kind, got) != got:
+            _fail('layer %d: %s sizes %r differ from %r'
+                  % (i, kind, got, sizes[kind]))
+        cfg.update(got)
+
+    def parts(i, ops, markers):
+        marks = [op for op in ops if op.type in markers]
+        scales = [op for op in ops if op.type == 'scale']
+        if len(marks) != 1 or len(scales) != 1:
+            _fail('layer %d: %d marker ops (%s) and %d scale ops between '
+                  'two rms_norm ops, want one of each'
+                  % (i, len(marks), [m.type for m in marks], len(scales)))
+        agree('residual', i, dict(
+            residual_multiplier=float(scales[0].attr('scale'))))
+        return marks[0], [(op.single_input('Y'), None) for op in ops
+                          if op.type == 'mul']
+
+    blocks, kinds = [], []
+    for i in range(len(subs) // 2):
+        (norm, ops), (ffn_norm, ffn_ops) = subs[2 * i], subs[2 * i + 1]
+        mark, muls = parts(i, ops, ('ssd_chunk', 'causal_mask'))
+        if len(muls) != 2:
+            _fail('layer %d: %d mul ops in the mixer, want 2'
+                  % (i, len(muls)))
+        kind = _NEMOTRON_MARKERS[mark.type]
+        if kind == 'mamba':
+            roles, got = _mamba_roles(i, ops, mark, muls, shape)
+        else:
+            roles, got = _attention_roles(i, block, mark, muls, shape)
+            scores = [op for op in ops if op.type == 'matmul'][0]
+            got['attention_multiplier'] = float(scores.attr('alpha'))
+        agree(kind, i, got)
+        blk = dict(roles, norm=norm, ffn_norm=ffn_norm)
+        mark, muls = parts(i, ffn_ops, ('moe_experts',))
+        if len(muls) != 2 or not mark.input('W3') \
+                or mark.attr('gate', 'sigmoid') != 'softmax' \
+                or mark.single_input('X') != mark.single_input('Lat'):
+            _fail('layer %d: the expert sublayer of models/granite_h.py is '
+                  'a softmax-gated moe_experts with W3 on the normed '
+                  'stream itself and two mul ops (the shared expert)' % i)
+        blk.update({'router': mark.single_input('RouterW'),
+                    'w1': mark.single_input('W1'),
+                    'w3': mark.single_input('W3'),
+                    'w2': mark.single_input('W2'),
+                    'shared_up': muls[0], 'shared_down': muls[1]})
+        held, _, ffn = shape(blk['w1'])
+        agree('experts', i, dict(
+            experts=shape(blk['router'])[1], experts_held=held,
+            expert_offset=int(mark.attr('expert_offset', 0)),
+            top_k=int(mark.attr('top_k')), expert_ffn=ffn,
+            shared_ffn=shape(muls[1][0])[0]))
+        blocks.append(blk)
+        kinds.append(kind)
+    spec = granite_h.GraniteHDecodeSpec(
+        granite_h.GraniteHConfig(layer_types=kinds, **cfg),
+        emb_w=emb_w, blocks=blocks, final_norm=final_norm)
     spec.param_specs = {n: None for n in spec.param_names()}
     return spec
 
@@ -599,6 +751,9 @@ def extract_decode_spec(program):
     block = program.global_block()
     if any(op.type == 'latent_attention' for op in block.ops):
         return _extract_axk1_spec(block)
+    if any(op.type == 'moe_experts' and op.attr('gate') == 'softmax'
+           for op in block.ops):
+        return _extract_granite_spec(block)
     if any(op.type in ('ssd_chunk', 'moe_experts') for op in block.ops):
         return _extract_nemotron_spec(block)
     if any(op.type == 'rms_norm' for op in block.ops):
@@ -715,18 +870,23 @@ def extract_decode_spec(program):
 
 class DecodeTranspiler(object):
     def transpile(self, program, slots=8, page_tokens=None, kv_pages=None,
-                  prefill_chunk=None):
+                  prefill_chunk=None, snapshot_rows=0):
         """program: a loaded inference Program (AnalysisPredictor's).
         Returns a PagedDecodePair whose cache is a page pool sized by
         page_tokens / kv_pages and whose prefill runs prefill_chunk-
         token chunks (each None defaults from FLAGS_serving_*, kv_pages
-        0 auto-sizes to a full window for every slot). Raises
+        0 auto-sizes to a full window for every slot). snapshot_rows
+        (a model with recurrent layers; 0: none) is how many prefix
+        boundaries keep their recurrent state on the device. Raises
         DecodeTranspileError if the program is not a recognizable
         decoder-only LM."""
         if slots < 1:
             raise ValueError('slots must be >= 1, got %r' % (slots,))
-        return self._transpile_paged(extract_decode_spec(program), slots,
+        pair = self._transpile_paged(extract_decode_spec(program), slots,
                                      page_tokens, kv_pages, prefill_chunk)
+        if snapshot_rows:
+            pair.keep_snapshots(snapshot_rows)
+        return pair
 
     def transpile_spec(self, program, draft_program=None, slots=8,
                        spec_k=None, draft_layers=None, page_tokens=None,

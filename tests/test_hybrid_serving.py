@@ -206,6 +206,32 @@ def test_a_repeated_prompt_gives_the_same_logits(model):
     assert dec.pool_stats()['prefix_entries'] == 0
 
 
+@pytest.mark.parametrize('first, more', [(22, 11), (16, 20)])
+def test_with_snapshot_rows_a_turn_reopens_on_pages_and_state(
+        model, reference_logits, first, more):
+    """The same model given snapshot rows (a size of the deployment;
+    test_a_repeated_prompt_gives_the_same_logits is the 0 it has without
+    them): the delta rule's state and its convolution rows are copied
+    like any other family's, and a second turn opened on them gives the
+    whole conversation's logits, the boundary inside a page or on one."""
+    pred, toks, _ = model
+    dec = _decoder(pred, snapshot_rows=3)
+    _prefill(dec, 0, toks[:first])
+    dec.release(0)
+    plan = dec.open_stream(1, toks[:first + more])
+    assert plan['shared_tokens'] == first
+    out = None
+    while out is None:
+        out = dec.prefill_step(1, return_logits=True)
+    rows = [out[1]] + [_decode(dec, 1, toks[j], j)
+                       for j in range(first + more, first + more + 3)]
+    want = reference_logits[first + more - 1:first + more + 3]
+    assert ref.rel_l2(np.stack(rows), want) < TOL
+    stats = dec.pool_stats()
+    assert stats['prefix_hits'] == 1 and stats['state_resets'] == 1
+    assert stats['snapshots'] == 2 and stats['snapshot_rows'] == 3
+
+
 def test_pool_stats_report_the_state_beside_the_pages(model):
     dec = _decoder(model[0])
     n_lin = KINDS.count('linear_attention')

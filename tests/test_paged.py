@@ -136,6 +136,138 @@ def test_prefix_cache_register_match_evict():
     assert pool.pages_in_use == 0
 
 
+def _registered(cache, pool, prompt):
+    """A stream that prefilled `prompt`, registered it with its state
+    and ended: (row handed out, pages the cache still holds)."""
+    table = PageTable(pool, 8)
+    pages, shared, snap = cache.match_state(prompt, limit=len(prompt) - 1)
+    if shared:
+        table.adopt_shared(pages, shared)
+        cache.unpin(snap)
+    pair = table.cow_for_append(shared)
+    table.ensure(len(prompt))
+    if pair is not None:
+        pool.unref(pair[0])
+    table.length = len(prompt)
+    row = cache.register_state(prompt, table)
+    table.release()
+    return row
+
+
+@pytest.mark.parametrize('case', ['longest_boundary', 'row_lru_releases',
+                                  'pages_take_the_snapshot', 'pinned',
+                                  'same_prompt_twice', 'spent_goes_first'])
+def test_prefix_cache_where_a_prefix_is_pages_and_state(case):
+    """PrefixCache with snapshot rows, on the host alone: a match ends
+    at a boundary that has a snapshot; rows are bounded and LRU, those
+    a conversation has moved on from first; a snapshot and the pages
+    that only it made usable go together."""
+    pool = PagePool(40, 4)
+    cache = PrefixCache(pool, snapshot_rows=4 if case == 'spent_goes_first'
+                        else 2)
+    pool.set_evict(cache.evict_one)
+    system = list(range(100, 108))                    # two whole pages
+    turn1 = system + list(range(200, 207))            # + a page and 3
+    if case == 'longest_boundary':
+        assert _registered(cache, pool, system) is not None
+        assert _registered(cache, pool, turn1) is not None
+        assert cache.snapshots == 2 and len(cache) == 2 + 2
+        # past both boundaries: the longer; pages and a tail
+        pages, n, snap = cache.match_state(turn1 + [1, 2, 3], limit=17)
+        assert (n, len(pages), snap.pins) == (15, 4, 1)
+        cache.unpin(snap)
+        # the same pages and another continuation inside the tail page:
+        # the tail's boundary is not this prompt's, the system's is
+        pages, n, snap = cache.match_state(turn1[:13] + [9, 9, 9], limit=15)
+        assert (n, len(pages)) == (8, 2)
+        cache.unpin(snap)
+        # whole pages in common and no boundary among them: nothing
+        assert cache.match_state(system[:4] + [5] * 8, limit=11) == \
+            ([], 0, None)
+        # the boundary itself is under the limit of its own prompt + 1
+        assert cache.match_state(system, limit=7)[1] == 0
+        assert cache.match_state(system + [1], limit=8)[1] == 8
+    elif case == 'row_lru_releases':
+        _registered(cache, pool, system)
+        _registered(cache, pool, turn1)
+        held = pool.pages_in_use
+        pages, n, snap = cache.match_state(system + [7], limit=8)   # touch
+        cache.unpin(snap)
+        other = system + list(range(300, 305))
+        assert _registered(cache, pool, other) is not None
+        # turn1's snapshot went for its row, with the page and the tail
+        # that only it kept; the system's pages serve the newcomer
+        assert cache.snapshots == 2 and cache.snapshots_dropped == 1
+        assert pool.pages_in_use == held
+        assert cache.match_state(turn1 + [1], limit=15)[1] == 8
+        pool.check()
+    elif case == 'pages_take_the_snapshot':
+        _registered(cache, pool, turn1)
+        assert cache.snapshots == 1
+        assert cache.evict_one()                      # the tail, a leaf
+        assert cache.snapshots == 0 and cache.snapshots_dropped == 1
+        # three whole pages are still registered and lead to no boundary
+        assert len(cache) == 3
+        assert cache.match_state(turn1 + [1], limit=15) == ([], 0, None)
+        assert len(cache._free_rows) == 2
+    elif case == 'pinned':
+        _registered(cache, pool, system)
+        _registered(cache, pool, turn1)
+        a = cache.match_state(system + [1], limit=8)[2]
+        b = cache.match_state(turn1 + [1], limit=15)[2]
+        other = [5] * 9
+        before = len(cache)
+        assert _registered(cache, pool, other) is None  # no row to give
+        assert len(cache) == before                   # and no pages kept
+        cache.unpin(b)
+        assert _registered(cache, pool, other) is not None
+        assert b.gone and not a.gone
+        cache.unpin(a)
+        pool.check()
+    elif case == 'spent_goes_first':
+        other1 = system + list(range(300, 305))
+        turn2 = turn1 + list(range(400, 406))
+        for prompt in (system, turn1, other1, turn2):
+            assert _registered(cache, pool, prompt) is not None
+        # two sessions opened on the system prompt: a shared prefix; one
+        # opened on turn1 and has registered turn2: a conversation that
+        # moved on, whose row goes before older ones
+        at = {s.tokens: s for rows in cache._snaps.values()
+              for s in rows.values()}
+        assert [at[n].spent for n in (8, 15, 13, 21)] == \
+            [False, True, False, False]
+        assert at[8].passed and at[8].reads == 2
+        assert at[15].stamp > at[13].stamp > at[8].stamp
+        assert _registered(cache, pool, [5] * 9) is not None
+        assert at[15].gone and cache.snapshots_dropped == 1
+
+        def opens_on(prompt):
+            _, n, snap = cache.match_state(prompt, limit=len(prompt) - 1)
+            if snap is not None:
+                cache.unpin(snap)
+            return n
+        assert opens_on(turn2 + [1, 2]) == 21
+        assert opens_on(other1 + [1, 2]) == 13
+        # what turn1 alone could serve falls back to the system prompt
+        assert opens_on(turn1 + [1, 2]) == 8
+        # a boundary two streams went on from is a shared prefix, not a
+        # conversation that moved on: the least recently used rows go
+        for more in ([7], [8]):
+            assert _registered(cache, pool, other1 + more) is not None
+        assert at[13].passed and at[13].reads == 3 and not at[13].spent
+        assert not at[13].gone and not at[8].gone and at[21].gone
+        assert cache.snapshots == 4 and cache.snapshots_dropped == 3
+        pool.check()
+    else:
+        row = _registered(cache, pool, turn1)
+        assert row is not None
+        assert _registered(cache, pool, turn1[:12]) is not None
+        # the boundary has its snapshot: nothing to copy, nothing taken
+        assert _registered(cache, pool, turn1) is None
+        assert cache.snapshots == 2 and cache.snapshots_dropped == 0
+        assert not cache._free_rows
+
+
 # --------------------------------------------------------------------------
 # page op units (ops/attention_ops.py)
 # --------------------------------------------------------------------------
