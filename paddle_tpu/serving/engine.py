@@ -25,17 +25,39 @@ result is dropped (serving.decode_lanes_dropped), its stream is what
 the serial loop gives. Whatever needs the lanes whole on the host
 collects the step in flight first: a preemption, the handling of
 CacheExhaustedError, a weight swap, an iteration with no lane ready,
-the worker's exit. So does a prompt's last chunk, between its dispatch
-and the synchronous wait for its token (the device runs the step in
-flight before the chunk: its tokens need not wait for the chunk).
+the worker's exit.
+
+A prompt's first token stays on the device too. The pass that dispatches
+a prompt's last chunk does not wait for it: the predictor hands back a
+handle on the chunk's id, the lane is ready with its token pending, and
+the same pass's decode step takes the slot among its carried lanes (the
+predictor writes the chunk's id into the ids that step reads, on the
+device). The device then runs the step in flight, the chunk, the next
+step, with nothing of the host between them. The host waits for the step
+in flight first, as the call always does, and for the chunk's token
+directly behind it, in the same pass: the first token is accepted, and
+`first_token_at` taken, when the chunk is done, not a step later. What
+the first token alone can tell is handled like a deferred step's: a
+first token that is the eos_id, a cancel or a deadline ends the lane
+after it was fed to that step, whose result for it is dropped; a
+request of one token is known from its budget and sits the step out.
+Whatever collects the step in flight (above) collects a pending first
+token with it, the step first. A resumed request that re-prefills takes
+the same path: its last chunk's token is its next one.
 
 What stood in front of a token. Every decode step carries a record made
 at its DISPATCH, `(chunks, lanes, sync)`: the prefill chunks this worker
 dispatched since it dispatched the step before (0, 1, rarely more: the
 device runs them in front of this step), the lanes the step was packed
-with (`len(ready)`), and 1 if no step was in flight at the dispatch
-(the step behind a prompt's last chunk, a burst's first, every step of
-a predictor that does not defer), else 0. The count is taken at
+with (`len(ready)`), and 1 if no decode step was in flight at the
+dispatch (a burst's first, the step behind a collect, every step of a
+predictor that does not defer), else 0. The step behind a prompt's last
+chunk is dispatched behind the chunk with the step before still in
+flight: (1, lanes, 0), a `chunk` gap like the one behind any other
+chunk. Behind a lone stream's last chunk no step is in flight, only the
+chunk: that step records (1, 1, 1) and its gap reads `sync`, as it did,
+although the device now runs it straight behind the chunk (it is a
+request's own first gap and no other's). The count is taken at
 dispatch, not at accept: in the pipelined loop a step's tokens are
 accepted after the NEXT pass's chunk has gone out, and that chunk runs
 on the device behind the step. The record travels with the step's
@@ -63,6 +85,9 @@ Telemetry (paddle_tpu/obs/, exported when FLAGS_obs_dir is set):
   failed}  counters; serving.tokens_generated / serving.decode_steps /
   serving.decode_steps_overlapped (steps dispatched while the one
   before was in flight) / serving.decode_lanes_dropped /
+  serving.first_tokens_carried (prompts whose first token was fed to
+  the next decode step on the device: over requests.admitted, the share
+  of prompts that did not empty the pipeline) /
   serving.prefills / serving.tokens_behind_prefill /
   serving.tokens_behind_sync (tokens whose gap was of kind `chunk` /
   `sync`: over tokens_generated, the share of generation that another
@@ -91,7 +116,9 @@ it. In the pipelined loop `serve.accept` follows the call and holds
 the tokens of the step BEFORE the one the call dispatched (none behind
 a burst's first step); a step collected without a call leaves a
 `paged.decode.fetch` and a `serve.accept` of its own in the iteration
-that collected it. A request that reaches a terminal state leaves
+that collected it. The wait for a prompt's first token is a
+`paged.prefill.fetch` of the pass that dispatched its last chunk,
+behind that pass's decode call. A request that reaches a terminal state leaves
 three spans of kind 'request' that share sid = its id: `serve.queue`
 (submitted_at -> admitted_at), `serve.prefill` (admitted_at ->
 first_token_at) and
@@ -145,6 +172,7 @@ _decode_steps = telemetry.counter('serving.decode_steps')
 _decode_steps_overlapped = telemetry.counter(
     'serving.decode_steps_overlapped')
 _decode_lanes_dropped = telemetry.counter('serving.decode_lanes_dropped')
+_first_tokens_carried = telemetry.counter('serving.first_tokens_carried')
 _prefills = telemetry.counter('serving.prefills')
 _tokens_behind_prefill = telemetry.counter('serving.tokens_behind_prefill')
 _tokens_behind_sync = telemetry.counter('serving.tokens_behind_sync')
@@ -352,7 +380,8 @@ class _Lane(object):
     preemption policy sorts victims by within a tier. In the pipelined
     loop a lane fed to the decode step in flight has `pos` already at
     the position after that step, and its next token still on the
-    device (`tok` is then the last one accepted)."""
+    device (`tok` is then the last one accepted; none yet for a lane
+    whose first token is pending behind its prompt's last chunk)."""
     __slots__ = ('req', 'pos', 'tok', 'ready', 'last_active')
 
     def __init__(self, req, pos, tok, ready=True):
@@ -931,13 +960,13 @@ class ServingEngine(object):
                                   pred=pred, wstate=wstate)
                 _deadline_expired.inc()
                 continue
-            # a prompt's last chunk is fetched synchronously. The decode
-            # step in flight runs on the device before the chunk: its
-            # tokens are accepted before that wait, not behind it
-            kw = {} if wstate['flight'] is None else {
-                'before_fetch': lambda: self._collect(pred, lanes, wstate)}
+            # a deferring predictor does not wait for a prompt's last
+            # chunk: it hands back a handle on the token, and this
+            # pass's decode step is dispatched behind the chunk
+            deferred = getattr(pred, 'deferred_decode', False)
             try:
-                out = pred.prefill_step(slot, **kw)
+                out = pred.prefill_step(slot, defer=True) if deferred \
+                    else pred.prefill_step(slot)
             except CacheExhaustedError as e:
                 # a victim's tokens and pages must agree: the decode
                 # step in flight is accepted before one is picked
@@ -980,8 +1009,11 @@ class ServingEngine(object):
                 return               # more chunks remain — next iteration
             prefilling.popleft()
             lane.ready = True
-            self._lane_accept(lanes, slot, int(out), pred=pred,
-                              wstate=wstate)
+            if deferred:
+                wstate['first'] = (slot, lane, out)
+            else:
+                self._lane_accept(lanes, slot, int(out), pred=pred,
+                                  wstate=wstate)
             return
 
     def _worker_loop(self, wid, pred):
@@ -991,9 +1023,12 @@ class ServingEngine(object):
         # dispatched and not yet accepted (the pipelined loop), or None,
         # and rec, that step's record of what stood in front of it.
         # chunks / steps: the prefill chunks and decode steps this
-        # worker has dispatched; chunks_seen: chunks at the last step
+        # worker has dispatched; chunks_seen: chunks at the last step.
+        # first: (slot, lane, handle) of the prompt whose last chunk
+        # this pass dispatched and whose first token is not fetched yet
         wstate = {'cache_wait': False, 'flight': None, 'rec': None,
-                  'wid': wid, 'chunks': 0, 'chunks_seen': 0, 'steps': 0}
+                  'first': None, 'wid': wid, 'chunks': 0,
+                  'chunks_seen': 0, 'steps': 0}
         tokens = np.zeros((pred.slots,), np.int64)
         positions = np.zeros((pred.slots,), np.int32)
         reading = False
@@ -1036,31 +1071,56 @@ class ServingEngine(object):
                     reading = False
         finally:
             # on any way out, nothing stays in flight on the predictor
+            # and no first token unfetched
             self._collect(pred, lanes, wstate)
             if reading:
                 self._gate.release_read()
 
     def _collect(self, pred, lanes, wstate):
         """Fetch and accept the decode step in flight, if there is one,
-        without dispatching another: before anything that needs the
-        lanes' state whole on the host (a preemption's save_stream, the
-        handling of CacheExhaustedError, a weight swap), and when no
-        lane is ready for a next step."""
+        without dispatching another, and behind it a prompt's pending
+        first token: before anything that needs the lanes' state whole
+        on the host (a preemption's save_stream, the handling of
+        CacheExhaustedError, a weight swap), and when no lane is ready
+        for a next step."""
         flight, wstate['flight'] = wstate['flight'], None
-        if flight is None:
+        if flight is None and wstate['first'] is None:
             return
-        try:
-            ids = pred.collect()
-        except Exception as e:   # noqa: BLE001 — engine survives
-            for slot, lane in flight:
-                if lanes.get(slot) is lane:
-                    self._finish_lane(lanes, slot, FAILED, error=repr(e),
-                                      pred=pred, wstate=wstate)
-            return
-        with RecordEvent('serve.accept'):
-            self._accept_flight(flight, wstate['rec'], ids, pred, lanes,
-                                wstate)
+        if flight is not None:
+            try:
+                ids = pred.collect()
+            except Exception as e:   # noqa: BLE001 — engine survives
+                for slot, lane in flight:
+                    if lanes.get(slot) is lane:
+                        self._finish_lane(lanes, slot, FAILED,
+                                          error=repr(e), pred=pred,
+                                          wstate=wstate)
+            else:
+                with RecordEvent('serve.accept'):
+                    self._accept_flight(flight, wstate['rec'], ids, pred,
+                                        lanes, wstate)
+        self._accept_first(pred, lanes, wstate)
         self._report(wstate['wid'], lanes)
+
+    def _accept_first(self, pred, lanes, wstate):
+        """Wait for the first token of the prompt whose last chunk this
+        pass dispatched, if one is pending, and accept it:
+        `first_token_at` is the end of that wait. The device ran the
+        step in flight before the chunk, so the caller has fetched that
+        step first."""
+        first, wstate['first'] = wstate['first'], None
+        if first is None:
+            return
+        slot, lane, handle = first
+        try:
+            tok = pred.first_token(handle)
+        except Exception as e:   # noqa: BLE001 — lane-fatal only
+            if lanes.get(slot) is lane:
+                self._finish_lane(lanes, slot, FAILED, error=repr(e),
+                                  pred=pred, wstate=wstate)
+            return
+        if lanes.get(slot) is lane:
+            self._lane_accept(lanes, slot, tok, pred=pred, wstate=wstate)
 
     def _report(self, wid, lanes):
         """The occupancy gauge and this worker's {slot: tokens held},
@@ -1095,9 +1155,11 @@ class ServingEngine(object):
         speculative one) the pass is one stage of a one-deep pipeline: it
         packs step n from what the host knows without step n-1's
         tokens (positions, budgets; a lane that carries on takes its
-        token on the device), dispatches it, and accepts the tokens of
-        step n-1, which the same call hands back. The host's work
-        between two programs then runs beside the one in flight."""
+        token on the device, from step n-1 or from its prompt's last
+        chunk, which this pass dispatched), dispatches it, accepts the
+        tokens of step n-1, which the same call hands back, and then
+        waits for that chunk's token. The host's work between two
+        programs then runs beside the one in flight."""
         # a speculative predictor's step is one draft->verify iteration
         # (serving/speculative.py): same feed ABI, but each live lane
         # gets 1..k+1 tokens back instead of exactly one — and where a
@@ -1114,11 +1176,14 @@ class ServingEngine(object):
         # lane is evicted (pages freed) before it buys another
         # decode step. Prefilling lanes are checked at the
         # prefill-queue head (_prefill_tick), matching how
-        # cancellation reaches them.
+        # cancellation reaches them. A lane whose first token is
+        # pending is looked at a pass later, with the token its prefill
+        # earned.
+        first = None if wstate['first'] is None else wstate['first'][0]
         now = time.perf_counter()
         for slot, ln in list(lanes.items()):
             if ln.ready and ln.req.deadline_at is not None \
-                    and now > ln.req.deadline_at:
+                    and now > ln.req.deadline_at and slot != first:
                 self._finish_lane(
                     lanes, slot, FAILED,
                     error='DeadlineExceededError: expired '
@@ -1126,10 +1191,13 @@ class ServingEngine(object):
                     pred=pred, wstate=wstate)
                 _deadline_expired.inc()
         # the lanes whose next token is still on the device, in the step
-        # in flight (the pipelined loop alone has any); one whose budget
-        # ends with that token sits this step out
+        # in flight or in the last chunk just dispatched (the pipelined
+        # loop alone has any); one whose budget ends with that token
+        # sits this step out
         carried = {s for s, ln in wstate['flight'] or ()
                    if lanes.get(s) is ln}
+        if first is not None:
+            carried.add(first)
         ready = [s for s, ln in lanes.items() if ln.ready and
                  len(ln.req.tokens) + (s in carried)
                  < ln.req.max_new_tokens]
@@ -1226,6 +1294,11 @@ class ServingEngine(object):
                 with RecordEvent('serve.accept'):
                     self._accept_flight(flight, rec_before, ids, pred,
                                         lanes, wstate)
+            # the chunk's token, behind the step in flight's: the
+            # device ran them in this order
+            if first in ready:
+                _first_tokens_carried.inc()
+            self._accept_first(pred, lanes, wstate)
             if not any(lanes.get(s) is ln for s, ln in fed):
                 # every lane of the step just dispatched has ended:
                 # nothing waits for it, and the worker may go idle

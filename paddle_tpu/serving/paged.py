@@ -37,12 +37,19 @@ A stream's life:
                                 allocate nothing yet
     prefill_step(slot)          run ONE prefill_chunk-token chunk;
                                 returns the first greedy token once the
-                                prompt is complete (None before that)
+                                prompt is complete (None before that);
+                                with defer=True the last chunk returns
+                                at once, with a handle on that token,
+                                which stays on the device
+    first_token(handle)         wait for such a token
     decode_step(tokens, pos)    one compiled step over ALL slots; pages
                                 are allocated on demand per live stream;
                                 with defer=True the step before's ids
                                 come back instead of this step's, which
-                                stay on the device (a one-deep pipeline)
+                                stay on the device (a one-deep pipeline);
+                                a carried lane takes its token there,
+                                from the step before or from its
+                                prompt's deferred last chunk
     collect()                   the ids of the deferred step in flight
     release(slot)               drop the stream's page refs
     save_stream(slot)           copy the stream's pages to host RAM
@@ -107,8 +114,10 @@ serving.decode_pages_read / serving.decode_pages_window counters (the
 pages the decode steps' attention read, of slots x pages_per_slot a
 step; the same pair is on every `paged.decode.tables` span, beside
 `overlapped`, 1 if the step was dispatched while the one before was in
-flight, and `carried`, the lanes whose token it took from that step on
-the device);
+flight, `carried`, the lanes whose token it took from that step on
+the device, and `carried_prefill`, the lanes whose token it took from
+their prompt's last chunk, dispatched in front of it and not waited
+for);
 serving.state_lanes counter (lanes whose recurrent state the decode
 steps updated; attr `state_lanes` of the same span),
 serving.recurrent_state_bytes and serving.state_resets gauges (bytes
@@ -150,7 +159,12 @@ decode step),
 the executor's `exe.run` with its children, `paged.*.book` (unref, lengths,
 prefix registration, gauges: host work that overlaps the device's),
 and `paged.*.fetch` (`np.asarray(ids)`: the wait for the device and
-the transfer; in prefill only on a prompt's last chunk). In a deferred
+the transfer; in prefill only on a prompt's last chunk, inside
+prefill_step, or inside first_token() for a chunk that was deferred).
+`paged.carry` (attr `lanes`) in a decode step that takes a token from a
+deferred last chunk, and in no other: the dispatch of the one small
+executable that writes the chunk's id into the ids the step reads
+(compiled inside `paged.cow.compile`, with the copy program). In a deferred
 decode step the fetch is that of the step BEFORE, with this one already
 queued behind it: the device's lead over the host, not a whole step's
 time. collect() leaves a `paged.decode.fetch` alone. The seconds spent
@@ -172,6 +186,8 @@ import threading
 import time
 
 import numpy as np
+
+import jax
 
 from ..executor import Executor, Scope
 from ..flags import get_flag
@@ -211,6 +227,23 @@ def _set_row(state, slot, rows):
         return state.at[slot].set(rows)
     state[slot] = rows
     return state
+
+
+@jax.jit
+def _with_first(prev, slot, ids):
+    """The ids [slots] a decode step's carried lanes read, with lane
+    `slot`'s entry taken from `ids` [1], a prompt's last chunk's: all
+    three on the device, so the step can be queued behind the chunk."""
+    return prev.at[slot].set(ids[0].astype(prev.dtype))
+
+
+class _FirstToken(object):
+    """A prompt's first greedy token, still on the device: what a
+    deferred last chunk hands back (first_token() waits for it)."""
+    __slots__ = ('slot', 'ids')
+
+    def __init__(self, slot, ids):
+        self.slot, self.ids = slot, ids
 
 
 class _PendingPrefill(object):
@@ -426,6 +459,15 @@ class PagedDecodePredictor(object):
                       feed=dict(zip(self._pair.copy_feeds, (src, dst))),
                       scope=self._scope, return_numpy=False)
 
+    def _carry_first(self, prev, slot, ids):
+        """`prev` (a decode step's ids) with lane `slot`'s entry from a
+        last chunk's `ids`, on the device. Both arrays are placed as a
+        feed is, so that every call is the one executable that the null
+        call beside the copy program's compiled."""
+        put = self._exe._put_feed
+        return _with_first(put('decode_prev_ids', prev), np.int32(slot),
+                           put('decode_prev_ids', ids))
+
     def _fork_pages(self, cows):
         """Copy the pages a decode step forked, in front of its program:
         one dispatch if `cows` (the step's (table, index, (src, dst))
@@ -499,7 +541,6 @@ class PagedDecodePredictor(object):
         committed to one chip): device_put reshards them onto their
         serve NamedSharding, so the executor's single-device lazy-pin
         path never fires for a mesh weight."""
-        import jax
         block = self._pair.decode_program.global_block()
         shardings = self._param_shardings() if self._mesh is not None \
             else None
@@ -537,7 +578,6 @@ class PagedDecodePredictor(object):
         decode contract). The pools are runtime state, never
         checkpointed, never touched here. Raises if no generation is
         loadable or a referenced param is absent."""
-        import jax
         from ..checkpoint import restore as restore_mod
         ckpt = restore_mod.load_checkpoint(ckpt_dir)
         if ckpt is None:
@@ -588,7 +628,6 @@ class PagedDecodePredictor(object):
         refresh. Returns an opaque staged dict for install_weights.
         Raises (installing nothing) on an unknown name or a shape
         mismatch."""
-        import jax
         known = set(self.param_names())
         shardings = self._param_shardings() if self._mesh is not None \
             else None
@@ -655,6 +694,9 @@ class PagedDecodePredictor(object):
         # ran: what a carried lane of the next step is fed from
         self._last_ids = np.zeros((self.slots,), np.int64)
         self._in_flight = False       # a deferred step awaits its fetch
+        # slot -> the _FirstToken of a deferred last chunk that no decode
+        # step has taken and no first_token() has fetched yet
+        self._first = {}
         _state_bytes.set(self._recurrent_state_bytes())
         if spec.state_family:
             telemetry.gauge('serving.%s.state_bytes' % spec.state_family) \
@@ -728,6 +770,7 @@ class PagedDecodePredictor(object):
         slot = int(slot)
         table = self._tables.pop(slot, None)
         st = self._pending.pop(slot, None)
+        self._first.pop(slot, None)
         if st is not None and st.snapshot is not None:
             self._prefix.unpin(st.snapshot)
         if table is not None:
@@ -907,17 +950,26 @@ class PagedDecodePredictor(object):
             table.pool.unref(dst)
 
     # -- execution ---------------------------------------------------------
-    def prefill_step(self, slot, return_logits=False, before_fetch=None):
+    def prefill_step(self, slot, return_logits=False, defer=False):
         """Advance one stream's prefill by ONE chunk. Returns None
         while more chunks remain; on the final chunk, registers the
         prompt with the prefix cache and returns the first greedy
         token (with return_logits: (token, logits [vocab])). Raises
         CacheExhaustedError — with this call's allocations rolled
-        back — when the pool cannot cover the chunk. `before_fetch`,
-        if given, is called on the final chunk once it is dispatched
-        and booked, before the wait for its token: the place to
-        collect() a deferred decode step, which the device runs before
-        this chunk, without waiting for the chunk first."""
+        back — when the pool cannot cover the chunk.
+
+        Called so, the final chunk is synchronous: the call waits for
+        its token. With `defer=True` the final chunk is dispatched and
+        booked (the adoption in front of it and the snapshot behind it
+        as in the other form, in the same order) and the call returns
+        at once, with a handle on the token (never None: the prompt is
+        in), which stays on the device. A decode_step that names the
+        slot in `carry` feeds the lane that token there, so it can be
+        dispatched behind the chunk with nothing fetched in between;
+        first_token(handle) waits for it. A chunk that is not the last
+        returns None in both forms."""
+        if defer and return_logits:
+            raise ValueError('a deferred chunk hands back its token only')
         slot = int(slot)
         st = self._pending[slot]
         table = self._tables[slot]
@@ -1007,13 +1059,26 @@ class PagedDecodePredictor(object):
                 self._update_gauges()
             del self._pending[slot]
             _prefill_chunks.observe(st.chunks)
-        if before_fetch is not None:
-            before_fetch()
+        if defer:
+            first = self._first[slot] = _FirstToken(slot, ids)
+            return first
         with RecordEvent('paged.prefill.fetch'):
             tok = int(self._fetch(ids)[0])
             if return_logits:
                 return tok, self._fetch(logits)[0]
         return tok
+
+    def first_token(self, first):
+        """The token of a deferred last chunk (the handle its
+        prefill_step returned): the wait for the chunk and the
+        transfer, in a `paged.prefill.fetch` span as the synchronous
+        form's, its seconds in `fetch_wait_s`. A decode step that took
+        the token on the device may have been dispatched meanwhile, or
+        not: a lane fed from the host afterwards is fed this."""
+        if self._first.get(first.slot) is first:
+            del self._first[first.slot]
+        with RecordEvent('paged.prefill.fetch'):
+            return int(self._fetch(first.ids)[0])
 
     def decode_step(self, tokens, positions, return_logits=False,
                     lanes=None, carry=(), defer=False):
@@ -1041,11 +1106,18 @@ class PagedDecodePredictor(object):
         behind this step's dispatch, so the device has its next program
         while the host waits for the last. This step's ids stay on the
         device until the next deferred call or collect(). A lane in
-        `carry` (slots that took part in the step in flight) is fed
-        that step's id for it, on the device, by a select inside the
-        program: its `tokens` entry is not read. CacheExhaustedError
-        keeps its contract in both forms, and leaves the step in
-        flight as it was, still to be collected."""
+        `carry` is fed its token on the device, by a select inside the
+        program: its `tokens` entry is not read. The token is the id
+        the step in flight made for it (a slot that took part in that
+        step), or, for a slot whose prompt's last chunk was deferred
+        and has not been fetched, that chunk's id: written into the
+        ids the select reads by one small executable in front of the
+        step (`paged.carry`), so the step is queued behind the chunk
+        with no fetch in between. The ids the caller gets back are the
+        step before's as that step left them. CacheExhaustedError
+        keeps its contract in both forms: nothing of this step ran, the
+        step in flight is as it was, still to be collected, and a
+        deferred chunk's token is still to be taken or fetched."""
         S, P, pt = self.slots, self.pages_per_slot, self.page_tokens
         overlapped = self._in_flight
         if overlapped and not defer:
@@ -1054,9 +1126,11 @@ class PagedDecodePredictor(object):
         if defer and return_logits:
             raise ValueError('a deferred step hands back ids only')
         carry = [int(s) for s in carry]
-        if carry and not overlapped:
+        fresh = [s for s in carry if s in self._first]
+        if len(carry) > len(fresh) and not overlapped:
             raise ValueError('carry names slot(s) %s but no step is in '
-                             'flight to take their tokens from' % carry)
+                             'flight to take their tokens from'
+                             % [s for s in carry if s not in fresh])
         with RecordEvent('paged.decode.tables') as ev:
             tokens = np.asarray(tokens, np.int64).reshape(S, 1, 1)
             positions = np.asarray(positions, np.int32).reshape(S)
@@ -1098,7 +1172,8 @@ class PagedDecodePredictor(object):
                 sum(int(pos_feed[slot]) // pt + 1 for slot in live)
             ev.attrs['pages_window'] = S * P
             ev.attrs['overlapped'] = int(overlapped)
-            ev.attrs['carried'] = len(carry)
+            ev.attrs['carried'] = len(carry) - len(fresh)
+            ev.attrs['carried_prefill'] = len(fresh)
             _decode_pages_read.inc(pages_read)
             _decode_pages_window.inc(S * P)
             feed = {'decode_tokens': tokens,
@@ -1130,8 +1205,16 @@ class PagedDecodePredictor(object):
             # step's fork meets its compile
             with RecordEvent('paged.cow.compile'):
                 self._copy_pages(())
+                self._carry_first(self._last_ids, 0,
+                                  np.zeros((1,), np.int32))
             self._copy_compiled = True
         self._fork_pages(cows)
+        if fresh:
+            with RecordEvent('paged.carry', lanes=len(fresh)):
+                for slot in fresh:
+                    feed['decode_prev_ids'] = self._carry_first(
+                        feed['decode_prev_ids'], slot,
+                        self._first.pop(slot).ids)
         logits, ids = self._run(self._pair.decode_program, feed,
                                 self._pair.decode_fetches, True)
         with RecordEvent('paged.decode.book'):

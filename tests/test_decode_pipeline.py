@@ -13,7 +13,12 @@ What must hold, for the GPT-2 block and for the hybrid block alike:
 - stop(drain=True, timeout=0.0) and drain() return with nothing in
   flight, and no worker thread is left;
 - the decode program still compiles once, and a call's spans are what
-  benchmarks/harness/spans.py `decode_calls` parses.
+  benchmarks/harness/spans.py `decode_calls` parses;
+- a prompt's first token stays on the device (`prefill_step(defer=True)`
+  / `first_token()`; a decode step that carries the slot takes it from
+  the chunk): the step behind a prompt's last chunk is dispatched behind
+  the chunk, the streams are still those of `generate()`, and whatever
+  ends a lane or needs the lanes whole finds no first token unfetched.
 """
 import os
 import sys
@@ -25,6 +30,7 @@ import pytest
 
 from paddle_tpu.models.transformer import TransformerConfig
 from paddle_tpu.obs import telemetry, trace
+from paddle_tpu.flags import set_flags
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.paging import CacheExhaustedError
 
@@ -361,6 +367,368 @@ def test_exhaustion_at_a_deferred_step_leaves_the_one_in_flight(served):
     assert int(dec.decode_step(tokens, positions)[0]) \
         == int(solo.decode_step(tokens, positions, lanes=[0])[0])
 
+
+# --------------------------------------------------------------------------
+# (g) a prompt's first token stays on the device
+# --------------------------------------------------------------------------
+
+def _tables():
+    return sorted((s for s in trace.spans()
+                   if s['name'] == 'paged.decode.tables'),
+                  key=lambda s: s['t0'])
+
+
+def _on_last_chunk(dec, fn):
+    """Run fn(handle) when a deferred last chunk has returned, before
+    the engine looks at it: wrapped on the INSTANCE, passing the
+    arguments through, as the benchmark's probe does."""
+    step = dec.prefill_step
+
+    def prefill_step(slot, *a, **kw):
+        out = step(slot, *a, **kw)
+        if out is not None:
+            fn(out)
+        return out
+    dec.prefill_step = prefill_step
+
+
+def test_a_deferred_last_chunk_hands_back_a_handle_not_none(served,
+                                                            registry_on):
+    pred, toks = served
+    sync, dec = _decoder(pred), _decoder(pred)
+    prompt = toks[:13]                  # two chunks of 8
+    want = list(sync.generate(prompt, 4))
+    seen = []                           # the probe's `out is not None`
+    _on_last_chunk(dec, seen.append)
+    dec.open_stream(1, prompt)
+    assert dec.prefill_step(1, defer=True) is None and not seen
+    assert dec.slot_tokens() == {1: 8}
+    first = dec.prefill_step(1, defer=True)
+    assert first is not None and seen == [first]
+    # the prompt is in: its tokens are held, nothing is pending
+    assert dec.slot_tokens() == {1: 13} and 1 not in dec._pending
+    with pytest.raises(ValueError, match='token only'):
+        dec.prefill_step(1, defer=True, return_logits=True)
+    # the step behind the chunk takes the token on the device; no step
+    # is in flight, and the slot may be carried all the same
+    S = dec.slots
+    tokens, positions = np.zeros(S, np.int64), np.zeros(S, np.int32)
+    positions[1] = 13
+    assert dec.decode_step(tokens, positions, lanes=[1], carry=[1],
+                           defer=True) is None
+    got = [dec.first_token(first)]
+    positions[1] = 14
+    got.append(int(dec.decode_step(tokens, positions, lanes=[1], carry=[1],
+                                   defer=True)[1]))
+    got.append(int(dec.collect()[1]))
+    assert got == want[:3]
+    mine = _tables()[-2:]
+    assert [(t['carried_prefill'], t['carried'], t['overlapped'])
+            for t in mine] == [(1, 0, 0), (0, 1, 1)]
+    assert not dec._first
+    # a slot that no chunk and no step holds a token for is refused
+    dec.release(1)
+    with pytest.raises(ValueError, match='no step is in flight'):
+        dec.decode_step(tokens, positions, lanes=[], carry=[1], defer=True)
+    # the synchronous step takes it too, and a token fetched first is fed
+    # from the host; a released slot forgets its handle
+    for fetch_first in (False, True):
+        dec.open_stream(0, prompt[:6])
+        first = dec.prefill_step(0, defer=True)
+        tokens[:], positions[0] = 0, 6
+        if fetch_first:
+            tokens[0] = dec.first_token(first)
+            assert tokens[0] == sync.generate(prompt[:6], 1)[0]
+        ids = dec.decode_step(tokens, positions, lanes=[0],
+                              carry=[] if fetch_first else [0])
+        assert [dec.first_token(first), int(ids[0])] \
+            == list(sync.generate(prompt[:6], 2))
+        assert not dec._first
+        dec.release(0)
+    dec.open_stream(0, prompt[:6])
+    dec.prefill_step(0, defer=True)
+    dec.release(0)
+    assert not dec._first and not dec.slot_tokens()
+    assert dec.jit_cache_stats()['compiled_segments'] == 3
+
+
+def test_prompts_that_end_at_different_passes_equal_generate(served,
+                                                             registry_on):
+    pred, toks = served
+    # beside a decoding stream: a prompt of one chunk, one of three,
+    # one whose budget is its first token, one of two tokens
+    asks = [(toks[:6], 24), (toks[2:9], 5), (toks[10:30], 6),
+            (toks[7:12], 1), (toks[20:33], 2)]
+    solo = _decoder(pred)
+    want = [list(solo.generate(p, n)) for p, n in asks]
+    telemetry.reset()
+    trace.clear()
+    dec = _decoder(pred)
+    engine = ServingEngine(dec)
+    reqs = [engine.submit(asks[0][0], max_new_tokens=asks[0][1])]
+    _on_call(dec, 3, lambda: reqs.extend(
+        engine.submit(p, max_new_tokens=n) for p, n in asks[1:]))
+    engine.start()
+    try:
+        while len(reqs) < len(asks):
+            time.sleep(0.001)
+        got = [list(r.result(240)) for r in reqs]
+    finally:
+        assert engine.stop(drain=True, timeout=30.0)
+    assert got == want
+    # every prompt but the one-token request's fed its first token to
+    # the step behind its last chunk; nothing was dropped for it
+    assert _counter('serving.first_tokens_carried') == 4 \
+        == sum(t['carried_prefill'] for t in _tables())
+    assert _counter('serving.requests.admitted') == 5
+    assert _counter('serving.decode_lanes_dropped') == 0
+    # only the burst's first step found the pipeline empty
+    assert sum(1 - t['overlapped'] for t in _tables()) == 1
+    assert sum(r.gap_sync[0] for r in reqs[1:] if r.gap_sync) == 0
+    assert not dec.in_flight and not dec._first and not dec.slot_tokens()
+    stats = dec.jit_cache_stats()
+    assert stats['prepared_programs'] == stats['compiled_segments'] == 3
+
+
+def _first_token_ends_it(pred, toks, how, pipelined):
+    """A stream decodes; a second prompt's first token ends its request
+    (`how`) while, in the pipelined loop, it is pending behind the last
+    chunk. Returns (tokens, state, error, lane results dropped, pages in
+    use afterwards)."""
+    prompt, budget = toks[3:14], 9
+    dec = _decoder(pred)
+    if not pipelined:
+        dec.deferred_decode = False     # what the engine looks at
+    ref = list(_decoder(pred).generate(prompt, budget))
+    engine = ServingEngine(dec)
+    dropped = _counter('serving.decode_lanes_dropped')
+    other = engine.submit(toks[:6], max_new_tokens=14)
+    reqs = []
+
+    def end(_out):
+        if not reqs:
+            return                      # the other prompt's last chunk
+        if how == 'cancel':
+            engine.cancel(reqs[0])
+        elif how == 'deadline':
+            reqs[0].deadline_at = time.perf_counter() - 1.0
+    _on_last_chunk(dec, end)
+    _on_call(dec, 3, lambda: reqs.append(engine.submit(
+        prompt, max_new_tokens=budget,
+        eos_id=ref[0] if how == 'eos' else None)))
+    engine.start()
+    try:
+        while not reqs:
+            time.sleep(0.001)
+        assert reqs[0].wait(240) and other.wait(240)
+    finally:
+        assert engine.stop(drain=True, timeout=30.0)
+    assert list(other.tokens) == list(_decoder(pred).generate(toks[:6], 14))
+    req = reqs[0]
+    # a cancel is seen before the token is taken; the others keep it
+    assert req.tokens == ([] if how == 'cancel' else ref[:1])
+    assert not dec.in_flight and not dec.slot_tokens() and not dec._first
+    return (req.state, req.error,
+            _counter('serving.decode_lanes_dropped') - dropped,
+            dec.pool_stats()['pages_in_use'])
+
+
+@pytest.mark.parametrize('how', ['eos', 'cancel', 'deadline'])
+def test_a_first_token_that_ends_its_lane_drops_one_result(served,
+                                                           registry_on, how):
+    pred, toks = served
+    state, error, dropped, pages = _first_token_ends_it(pred, toks, how, True)
+    assert dropped == 1                 # it was fed to the step behind
+    assert state == {'eos': 'DONE', 'cancel': 'CANCELLED',
+                     'deadline': 'FAILED'}[how]
+    assert how != 'deadline' or 'DeadlineExceededError' in error
+    serial = _first_token_ends_it(pred, toks, how, False)
+    assert serial == (state, error, 0, pages)
+
+
+@pytest.fixture()
+def preempt_flags():
+    yield
+    set_flags({'FLAGS_serving_preempt_policy': 'swap'})
+
+
+@pytest.mark.parametrize('policy', ['swap', 'reprefill', 'off'])
+def test_exhaustion_at_the_step_behind_a_last_chunk(served, registry_on,
+                                                    preempt_flags, policy):
+    """Five usable pages of 4 tokens. A stream of 8 prompt tokens is at
+    its third page when a prompt of 8 ends: its chunk takes the last two
+    pages, and the step dispatched behind the chunk finds none for the
+    new lane's first append. The first token is fetched with the step in
+    flight before a victim is picked (the longer stream gives way and
+    resumes) or, with no preemption, before the new lane fails with the
+    token its prefill earned."""
+    pred, toks = served
+    set_flags({'FLAGS_serving_preempt_policy': policy})
+    pa, pb = toks[:8], toks[20:28]
+    want_a = list(_decoder(pred).generate(pa, 9))
+    want_b = list(_decoder(pred).generate(pb, 3))
+    telemetry.reset()
+    dec = _decoder(pred, slots=2, kv_pages=6)
+    engine = ServingEngine(dec)
+    a = engine.submit(pa, max_new_tokens=9)
+    reqs = []
+    _on_call(dec, 3, lambda: reqs.append(
+        engine.submit(pb, max_new_tokens=3, priority=1)))
+    engine.start()
+    try:
+        while not reqs:
+            time.sleep(0.001)
+        b = reqs[0]
+        assert a.wait(240) and b.wait(240)
+    finally:
+        assert engine.stop(drain=True, timeout=30.0)
+    assert _counter('serving.cache_exhausted') >= 1
+    assert list(a.tokens) == want_a
+    if policy == 'off':
+        assert b.state == 'FAILED' and 'CacheExhaustedError' in b.error
+        assert list(b.tokens) == want_b[:1]
+    else:
+        assert list(b.tokens) == want_b and a.preemptions >= 1
+    # the step that failed carried nothing, and the new lane's token
+    # came from the host: the first stream's own first token was carried,
+    # and the one its re-prefill's last chunk made
+    assert _counter('serving.first_tokens_carried') \
+        == 1 + (a.preemptions if policy == 'reprefill' else 0)
+    assert not dec.in_flight and not dec._first and not dec.slot_tokens()
+    assert dec.pool_stats()['pages_in_use'] \
+        == dec.pool_stats()['prefix_pages']
+
+
+@pytest.mark.parametrize('how', ['stop', 'drain', 'swap'])
+def test_stop_drain_and_swap_with_a_first_token_pending(served, how):
+    pred, toks = served
+    asks = [(toks[:6], 30), (toks[4:20], 12)]
+    want = [list(_decoder(pred).generate(p, n)) for p, n in asks]
+    dec = _decoder(pred)
+    engine = ServingEngine(dec)
+    seen, threads, acted = [], [], []
+
+    def act():
+        if how == 'stop':
+            seen.append(engine.stop(drain=True, timeout=0.0))
+        elif how == 'drain':
+            seen.append(engine.drain(timeout=120.0))
+        else:
+            seen.append(engine.request_swap(
+                lambda: (dec.in_flight, len(dec._first),
+                         len(dec.slot_tokens()))))
+
+    def pending(_out):
+        # the second prompt's last chunk is dispatched and not waited
+        # for: the other thread acts now, and the worker goes on only
+        # once it has (a stop has cancelled, a swap waits at the gate)
+        if acted or len(reqs) < 2:
+            return
+        acted.append(True)
+        thread = threading.Thread(target=act)
+        thread.start()
+        threads.append(thread)
+        t0 = time.monotonic()
+        while time.monotonic() - t0 < 30.0 and not (
+                {'stop': not engine._running, 'drain': True,
+                 'swap': engine._gate.writer_waiting}[how]):
+            time.sleep(0.001)
+    _on_last_chunk(dec, pending)
+    reqs = [engine.submit(*asks[0][:1], max_new_tokens=asks[0][1])]
+    _on_call(dec, 4, lambda: reqs.append(
+        engine.submit(asks[1][0], max_new_tokens=asks[1][1])))
+    engine.start()
+    while not threads:
+        time.sleep(0.001)
+    threads[0].join(120)
+    assert not threads[0].is_alive()
+    if how == 'stop':
+        assert all(r.state in ('CANCELLED', 'DONE') for r in reqs)
+        assert [list(r.tokens) for r in reqs] \
+            == [w[:len(r.tokens)] for w, r in zip(want, reqs)]
+    else:
+        assert seen == [True] if how == 'drain' else seen == [(False, 0, 2)]
+        assert [list(r.result(240)) for r in reqs] == want
+        assert engine.stop(drain=True, timeout=30.0)
+    assert not dec.in_flight and not dec._first and not dec.slot_tokens()
+    assert _no_worker_left()
+    assert len(dec.generate(toks[:6], 3)) == 3
+
+
+def test_first_tokens_carried_past_a_snapshot_and_an_adoption(hybrid_served,
+                                                              registry_on):
+    """A model with recurrent state and snapshot rows: the snapshot is
+    dispatched behind a prompt's last chunk and the adoption in front of
+    a first one, as before, and the step that takes the first token on
+    the device runs behind both. A second turn opens on the snapshot its
+    first left, beside a stream that decodes all the while."""
+    pred, toks = hybrid_served
+    solo = _decoder(pred)
+    first_turn = list(solo.generate(toks[:14], 4))
+    again = toks[:14] + first_turn + toks[30:35]
+    want = [list(solo.generate(toks[3:9], 30)), first_turn,
+            list(solo.generate(again, 5))]
+    telemetry.reset()
+    dec = _decoder(pred, snapshot_rows=3)
+    with ServingEngine(dec) as engine:
+        beside = engine.submit(toks[3:9], max_new_tokens=30)
+        while len(beside.tokens) < 3:
+            time.sleep(0.001)
+        one = engine.submit(toks[:14], max_new_tokens=4)
+        got = [list(one.result(240))]
+        two = engine.submit(again, max_new_tokens=5)
+        got.append(list(two.result(240)))
+        got.insert(0, list(beside.result(240)))
+    assert got == want
+    assert _counter('serving.state.snapshots_taken') == 3
+    assert _counter('serving.state.snapshots_adopted') == 1
+    assert _counter('serving.prefix_tokens_reused') == 14
+    assert _counter('serving.first_tokens_carried') == 3
+    assert _counter('serving.decode_lanes_dropped') == 0
+    assert beside.gap_sync.count(1) == 1    # its own first step alone
+
+
+def test_a_first_append_that_forks_takes_its_token_on_the_device(
+        gpt2_served, registry_on):
+    """A's prompt ends inside a page the prefix cache registers, so the
+    step behind its last chunk forks it: chunk, the page copy, the step,
+    with the chunk's token never on the host in between. B opens on that
+    page and forks the tail it registers the same way, beside A."""
+    pred, toks = gpt2_served
+    pa, pb = toks[:6], toks[:7]
+    want = [list(_decoder(pred).generate(pa, 9)),
+            list(_decoder(pred).generate(pb, 5))]
+    telemetry.reset()
+    trace.clear()
+    dec = _decoder(pred)
+    with ServingEngine(dec) as engine:
+        a = engine.submit(pa, max_new_tokens=9)
+        while not a.tokens:
+            time.sleep(0.001)
+        b = engine.submit(pb, max_new_tokens=5)
+        got = [list(a.result(240)), list(b.result(240))]
+    assert got == want
+    assert _counter('serving.prefix_tokens_reused') == 6
+    assert _counter('serving.cow.dispatches') == 2 \
+        == _counter('serving.first_tokens_carried')
+    # each copy stands between the tables and the step of a pass that
+    # carried a chunk's token
+    for cow in (s for s in trace.spans() if s['name'] == 'paged.cow'):
+        mine = [s for s in trace.spans() if s['psid'] == cow['psid']]
+        names = [s['name'] for s in sorted(mine, key=lambda s: s['t0'])
+                 if s['name'] != 'paged.cow.compile']
+        at = names.index('paged.cow')
+        assert names[at - 1] == 'paged.decode.tables'
+        assert names[at + 1:at + 3] == ['paged.carry', 'exe.run']
+        tables = mine[[s['name'] for s in mine]
+                      .index('paged.decode.tables')]
+        assert tables['carried_prefill'] == 1
+
+
+# --------------------------------------------------------------------------
+# (h) a mesh; last in the file: it re-pins the shared weights onto two
+# devices, and no single-chip decoder of the fixture runs after it
+# --------------------------------------------------------------------------
 
 def test_a_mesh_predictor_takes_the_pipelined_loop(gpt2_served,
                                                    registry_on):

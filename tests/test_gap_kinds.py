@@ -79,11 +79,16 @@ def test_the_three_lists_are_as_long_as_the_gaps(gpt2_served, registry_on,
             == (req.gap_chunks, req.gap_lanes, req.gap_sync)
         assert all(1 <= m <= dec.slots for m in req.gap_lanes)
         # a request's own first decode step stands behind its last chunk
-        assert not n or (req.gap_sync[0], req.gap_chunks[0] >= 1) == (1, True)
+        assert not n or req.gap_chunks[0] >= 1
         if loop == 'serial':
             assert set(_kinds(req)) <= {'sync'}
     if loop == 'deferred':
-        assert {'plain', 'sync'} <= {k for r in reqs for k in _kinds(r)}
+        # ... with the step before still in flight, but for the burst's
+        # first: the pipeline was empty once
+        assert [r.gap_sync[0] for r in reqs if r.gap_sync] \
+            == [1] + [0] * (len(reqs) - 2)
+        assert {'plain', 'chunk', 'sync'} \
+            == {k for r in reqs for k in _kinds(r)}
 
 
 def test_gap_kind_is_sync_before_chunk_before_plain():
@@ -100,6 +105,8 @@ def test_a_stream_alone_reads_plain_gaps_after_its_first(gpt2_served,
                                                          registry_on):
     pred, toks = gpt2_served
     req, = _drive(_decoder(pred), [(toks[:6], 20)])
+    # no step was in flight at its first (the module's docstring: the
+    # chunk was, and the record still reads `sync`)
     assert _kinds(req) == ['sync'] + ['plain'] * 18
     assert req.gap_chunks == [1] + [0] * 18
     assert req.gap_lanes == [1] * 19
@@ -116,17 +123,79 @@ def test_a_prompt_of_three_chunks_beside_a_decoding_stream(gpt2_served,
     # the second prompt arrives while step n is dispatched. The pass
     # after it dispatches chunk 1 and step n + 1 and only then accepts
     # step n's token: that token's gap (index n - 1) had no chunk in
-    # front; the chunks stand in front of steps n + 1, n + 2 (a step
-    # was in flight) and n + 3 (the last chunk: fetched synchronously,
-    # so nothing was)
+    # front; the chunks stand in front of steps n + 1, n + 2 and n + 3,
+    # each with a step in flight: the last chunk is not waited for, and
+    # step n + 3 is dispatched behind it
     assert first.gap_chunks == [1] + [0] * (n - 1) + [1, 1, 1] \
         + [0] * (15 - n - 3)
-    assert first.gap_sync == [1] + [0] * (n + 1) + [1] + [0] * (15 - n - 3)
+    assert first.gap_sync == [1] + [0] * 14
     assert _kinds(first)[n - 1:n + 4] \
-        == ['plain', 'chunk', 'chunk', 'sync', 'plain']
+        == ['plain', 'chunk', 'chunk', 'chunk', 'plain']
     # the second stream's own first gap is that same step's
-    assert (second.gap_chunks[0], second.gap_sync[0]) == (1, 1)
-    assert _kinds(second)[1:] == ['plain'] * 2
+    assert (second.gap_chunks[0], second.gap_sync[0]) == (1, 0)
+    assert _kinds(second) == ['chunk'] + ['plain'] * 2
+
+
+def _tables(spans):
+    """The decode steps' `paged.decode.tables` spans, in dispatch order."""
+    return sorted((s for s in spans if s['name'] == 'paged.decode.tables'),
+                  key=lambda s: s['t0'])
+
+
+def test_the_step_behind_a_last_chunk_carries_its_token(gpt2_served,
+                                                        registry_on):
+    pred, toks = gpt2_served
+    dec = _decoder(pred)
+    n = 5
+    first, second, third = _drive(
+        dec, [(toks[:6], 16), (toks[10:30], 4), (toks[3:9], 1)], join_at=n)
+    # every prompt kept the pipeline full but the one whose budget was
+    # its first token, which sat the step behind its chunk out
+    assert _counter('serving.first_tokens_carried') == 2
+    assert _counter('serving.requests.admitted') == 3
+    assert len(third.tokens) == 1 and third.gap_sync == []
+    tables = _tables(trace.spans())
+    took = [i for i, t in enumerate(tables) if t['carried_prefill']]
+    # the lone stream's own (nothing in flight but its chunk), and step
+    # n + 3, behind the second prompt's third chunk and a step in flight
+    assert took == [0, n + 2]
+    assert [(tables[i]['carried_prefill'], tables[i]['carried'],
+             tables[i]['overlapped']) for i in took] \
+        == [(1, 0, 0), (1, 1, 1)]
+    assert _counter('serving.tokens_behind_sync') == 1
+    assert _counter('serving.decode_lanes_dropped') == 0
+
+
+def test_first_token_at_is_taken_before_the_next_pass_dispatches(
+        gpt2_served, registry_on):
+    """The fetch of a prompt's first token is not put off to the next
+    step's: it is issued in the pass that dispatched the last chunk,
+    behind that pass's decode call, and `first_token_at` is its end."""
+    pred, toks = gpt2_served
+    dec = _decoder(pred)
+    n = 5
+    first, second = _drive(dec, [(toks[:6], 16), (toks[10:30], 4)],
+                           join_at=n)
+    spans = trace.spans()
+    tables = _tables(spans)
+    at = [i for i, t in enumerate(tables) if t['carried_prefill']][1]
+    step, after = tables[at], tables[at + 1]
+    fetch, = [s for s in spans if s['name'] == 'paged.prefill.fetch'
+              and s['psid'] == step['psid']]
+    # one pass: the chunk, the step behind it, then the wait for the
+    # chunk's token; the next pass's dispatch comes after all three
+    assert step['t1'] <= fetch['t0'] <= fetch['t1'] \
+        <= second.first_token_at <= after['t0']
+    assert second.first_token_at - fetch['t1'] < 0.05
+    # the step in flight was fetched first: its token is the older one
+    # (step n + 2's; step n + 3's comes a pass later)
+    assert first.token_at[n + 2] <= second.first_token_at \
+        <= first.token_at[n + 3]
+    # and the pass's wait holds both fetches
+    it, = [s for s in spans if s['name'] == 'serve.iter'
+           and s['sid'] == step['psid']]
+    assert (it['chunk'], it['step']) == (1, 1)
+    assert it['wait_ms'] >= 1e3 * (fetch['t1'] - fetch['t0'])
 
 
 def test_gap_lanes_is_the_ready_the_step_was_packed_with(gpt2_served,
@@ -145,9 +214,7 @@ def test_gap_lanes_is_the_ready_the_step_was_packed_with(gpt2_served,
     # ... and the second in those from its own first on, both live
     at = first.gap_lanes.index(2)
     assert second.gap_lanes == ready[at:at + 5] == [2] * 5
-    tables = sorted((s for s in trace.spans()
-                     if s['name'] == 'paged.decode.tables'),
-                    key=lambda s: s['t0'])
+    tables = _tables(trace.spans())
     # a step dispatched with nothing in flight is one the predictor
     # counts as not overlapped: the same steps
     sync_steps = [1 - t['overlapped'] for t in tables]
