@@ -315,6 +315,19 @@ class EmitContext(object):
         return jax.random.fold_in(self.rng_key, self._op_index)
 
 
+def host_value(value):
+    """`value` whole on the host. An array sharded over several
+    processes (the state of a multi-trainer mesh) is gathered first: a
+    collective, so every trainer reads it (a fetch, a save)."""
+    if isinstance(value, jax.Array) and not value.is_fully_addressable:
+        if value.is_fully_replicated:
+            return np.asarray(value.addressable_data(0))
+        from jax.experimental import multihost_utils
+        return np.asarray(
+            multihost_utils.process_allgather(value, tiled=True))
+    return np.asarray(value)
+
+
 class HostContext(object):
     """Host-side environment for host ops (print/save/load/...)."""
 
@@ -327,7 +340,7 @@ class HostContext(object):
         val = self.scope.find_var(name)
         if val is None:
             raise KeyError('host op input %r not found in scope' % name)
-        return np.asarray(val)
+        return host_value(val)
 
     def get_raw(self, name):
         """Like get() but without numpy coercion — for host ops consuming
@@ -639,9 +652,9 @@ class Executor(object):
         return feed_arrays
 
     def _to_numpy(self, value):
-        """Hook: fetch one result to host (ParallelExecutor overrides to
-        all-gather multi-host-sharded results)."""
-        return np.asarray(value)
+        """One fetched result on the host, whole (host_value gathers what
+        a multi-trainer mesh holds as shards across processes)."""
+        return host_value(value)
 
     # -- internals ---------------------------------------------------------
     def _run_prepared(self, prepared, feed_arrays, fetch_names, scope,

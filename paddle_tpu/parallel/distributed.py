@@ -139,20 +139,16 @@ def local_batch_to_global(arr, mesh, pspec):
 
 def host_value_to_global(arr, mesh, pspec):
     """A host value PRESENT IDENTICALLY on every process (startup params
-    run from one seed) -> global Array with the given sharding. For
-    sharded specs each process contributes the rows its devices own
-    (the ncclBcast analog, reference parallel_executor.cc:210)."""
+    run from one seed) -> global Array with the given sharding, whatever
+    dimension it splits: each process hands its own devices their
+    slices (the ncclBcast analog, reference parallel_executor.cc:210)."""
     from jax.sharding import NamedSharding
+    sharding = NamedSharding(mesh, pspec)
     if jax.process_count() == 1:
-        return jax.device_put(arr, NamedSharding(mesh, pspec))
-    from jax.experimental import multihost_utils
+        return jax.device_put(arr, sharding)
     arr = np.asarray(arr)
-    first = pspec[0] if len(pspec) > 0 else None
-    if first is None:
-        return multihost_utils.host_local_array_to_global_array(
-            arr, mesh, pspec)
-    return multihost_utils.host_local_array_to_global_array(
-        shard_rows_for_process(arr, mesh, first), mesh, pspec)
+    return jax.make_array_from_callback(arr.shape, sharding,
+                                        lambda index: arr[index])
 
 
 def shard_rows_for_process(arr, mesh, axis_entry):

@@ -12,14 +12,15 @@ class DistributedStrategy(object):
     """Axis sizes plus engine knobs.
 
     dp/tp/sp/pp/ep: parallel degrees (product must divide device count)
-    sharded_optimizer: ZeRO-1-style optimizer-state sharding over dp
-        (the reference BuildStrategy.kReduce analog; consumed by
-        ParallelExecutor._bcast_params)
-    sharded_params: ZeRO-3-style PARAMETER sharding over dp on top of
-        the optimizer-state sharding (implies sharded_optimizer).
-        Beyond-reference: per-device parameter memory drops ~dp-fold;
-        GSPMD inserts the gather-on-use / reduce-scatter collectives.
-        Parameters whose no dim divides dp stay replicated.
+    sharded_optimizer, sharded_params: accepted, as scripts pass them,
+        and read by nothing. With dp > 1 ParallelExecutor shards what
+        an optimizer op updates over dp by itself (state_sharding:
+        accumulators AND parameters on their first dimension that
+        divides, per-device memory for them drops ~dp-fold, GSPMD
+        gathers a weight where it is used), which is all that either
+        (the reference BuildStrategy.kReduce analog, and ZeRO-3-style
+        parameter sharding on top) was for. What no dimension of
+        divides stays replicated.
     micro_batches: pipeline microbatch count, consumed by the pp engine
         (parallel/pipeline.py pipeline_apply's n_micro)
     """

@@ -7,11 +7,14 @@ details/threaded_ssa_graph_executor.cc:36). The reference replicates the op
 graph per GPU, hand-inserts scale_loss_grad + NCCL AllReduce op-handles, and
 schedules them with a threadpool. Here the SAME single-program block is jit
 compiled over a `jax.sharding.Mesh`: the batch feeds are sharded on the 'dp'
-axis, parameters/optimizer state are replicated (BuildStrategy.kAllReduce) or
-sharded (kReduce -- the ZeRO-1-style analog of the reference's reduce
-strategy), and XLA's SPMD partitioner inserts the gradient AllReduce over ICI
+axis, and XLA's SPMD partitioner inserts the gradient AllReduce over ICI
 automatically -- the entire threaded SSA scheduler collapses into one XLA
-executable.
+executable. On a mesh whose dp axis is larger than 1 what an optimizer op
+updates (the float32 masters and their accumulators) lives as dp shards
+(state_sharding), so the update is computed once and not once a device, and
+a weight is gathered where it is used; BuildStrategy.kReduce, the reference's
+reduce strategy, asked for the accumulators' half of that: it is accepted
+and changes nothing.
 
 Loss scaling: the reference inserts scale_loss_grad (1/ndev). Here the loss
 is a global-batch mean over a sharded array, so XLA computes the exact global
@@ -19,8 +22,8 @@ mean -- no explicit scaling op is needed (GradientScaleStrategy.kCoeffNumDevice
 semantics fall out for free).
 
 BCastParamsToDevices (parallel_executor.cc:210, ncclBcast per param) maps to
-re-laying-out the startup-initialized params into the mesh's replicated
-sharding on first run.
+re-laying-out the startup-initialized state into its place on the mesh on
+first run (_bcast_params).
 """
 from __future__ import annotations
 
@@ -48,12 +51,25 @@ __all__ = ['ParallelExecutor', 'ExecutionStrategy', 'BuildStrategy']
 # program is not touched; the CPU compiler rejects them, so they go
 # only to a mesh of more than one TPU device (_overlap_options).
 # Measured, and what was tried and not kept: PERF.md section 6, PR 32.
+# The fourth is for the state a dp mesh holds as shards (state_sharding):
+# left to itself the compiler sums a gradient whose update is sharded by
+# a custom fusion of its own (all-reduce-scatter) on the product's
+# float32 result, which no reader of collectives sees (not
+# profiler.collective_audit, not the benchmark's exposed share); without
+# that fusion the gradients are summed as a replicated step sums them,
+# in the same dtype (bf16 all-reduces of bf16 gradients), and each chip
+# then updates its slice. PERF.md section 6, PR 47.
 _OVERLAP_OPTIONS = {
     'xla_enable_async_all_reduce': True,
     'xla_tpu_enable_async_collective_fusion_fuse_all_reduce': True,
     'xla_jf_crs_combiner_threshold_in_bytes': 16 << 20,
+    'xla_tpu_enable_all_reduce_scatter_fusion': False,
 }
 _OVERLAP_COMPILES = _tm.counter('parallel.overlap_compiles')
+# bytes of optimizer-updated state (masters, moments) that _bcast_params
+# placed as dp shards and as replicas: whether the sharded update engaged
+_UPDATE_SHARDED = _tm.gauge('parallel.update_sharded_bytes')
+_UPDATE_REPLICATED = _tm.gauge('parallel.update_replicated_bytes')
 
 
 class ExecutionStrategy(object):
@@ -78,8 +94,12 @@ class BuildStrategy(object):
     """Knobs of the reference details/build_strategy.h."""
 
     class ReduceStrategy:
-        AllReduce = 0   # replicated params, grad allreduce (default)
-        Reduce = 1      # sharded optimizer state (ZeRO-1-style)
+        # Accepted, as scripts pass them, and read by nothing: a dp mesh
+        # shards what its optimizer updates by itself (ParallelExecutor.
+        # state_sharding), accumulators and parameters alike, under
+        # either value.
+        AllReduce = 0
+        Reduce = 1
 
     class GradientScaleStrategy:
         CoeffNumDevice = 0
@@ -151,6 +171,12 @@ class ParallelExecutor(Executor):
         self._batch_sharded = NamedSharding(
             self.mesh, P('dp' if 'dp' in self.mesh.axis_names else None))
         self._params_placed = False
+        # the executor whose scope this one shares, if both span one
+        # mesh: state_sharding and _bcast_params follow its placement
+        self._owner = share_vars_from if share_vars_from is not None \
+            and share_vars_from.mesh == self.mesh else None
+        self._updated = None
+        self._state_shardings = {}
         self._run_count = 0
         if self._build_strategy.debug_graphviz_path:
             from .debugger import program_to_dot
@@ -227,19 +253,33 @@ class ParallelExecutor(Executor):
         const_keys = [n for n in segment.in_names
                       if n not in set(donated_keys)]
 
+        block_vars = self._main_program.global_block().vars
+        # on a dp mesh state enters and leaves a step where
+        # state_sharding holds it: left to the partitioner, some came
+        # back from the chip's first step in another layout and the
+        # second step compiled again (2.3 s of set-up), and a fresh
+        # value put in the scope (uncommitted) is laid out here, not
+        # wherever the partitioner likes
+        pinned = self._dp_size > 1
+
+        def state(name):
+            var = block_vars.get(name)
+            if pinned and var is not None and var.persistable:
+                return self.state_sharding(name)
+            return None
+
         def spec(name):
             explicit = self._var_sharding(name)
             if explicit is not None:
                 return explicit
             if name in feed_set:
-                var = self._main_program.global_block().vars.get(name)
+                var = block_vars.get(name)
                 if var is not None and var.shape:
                     return self._batch_sharded
                 return self._replicated
-            # non-annotated state (optimizer moments, bn stats...): None =
-            # inherit the argument's current sharding -- GSPMD may shard
-            # these on step 1 and they must round-trip unchanged
-            return None
+            # non-annotated state with dp 1 (and what is no persistable
+            # variable): None = inherit the argument's current sharding
+            return state(name)
 
         in_shardings = (
             {n: spec(n) for n in donated_keys},
@@ -247,6 +287,9 @@ class ParallelExecutor(Executor):
             self._replicated,
         )
         options = {'in_shardings': in_shardings}
+        if pinned:
+            options['out_shardings'] = tuple(
+                state(n) for n in segment.out_names)
         overlap = self._overlap_options()
         if overlap:
             # one call a compiled segment (both paths of _compile_segment)
@@ -273,61 +316,90 @@ class ParallelExecutor(Executor):
         return super(ParallelExecutor, self)._compile_segment(
             segment, block, program, feed_names, donate)
 
-    # -- public API --------------------------------------------------------
-    def _bcast_params(self):
-        """Re-place startup-initialized params into the mesh's replicated
-        sharding (analog of BCastParamsToDevices ncclBcast,
-        reference parallel_executor.cc:210)."""
-        from .framework import Parameter
-        zero1 = self._dp_size > 1 and (
-            (self._strategy is not None
-             and self._strategy.sharded_optimizer)
-            or self._build_strategy.reduce_strategy ==
-            BuildStrategy.ReduceStrategy.Reduce)
-        zero3 = self._dp_size > 1 and self._strategy is not None and \
-            getattr(self._strategy, 'sharded_params', False)
+    # -- placement -------------------------------------------------------
+    def _dp_shard(self, shape):
+        """`shape` split over dp on its first dimension that divides, or
+        None."""
+        for axis, dim in enumerate(shape):
+            if dim and dim > 0 and dim % self._dp_size == 0:
+                spec = [None] * len(shape)
+                spec[axis] = 'dp'
+                return NamedSharding(self.mesh, P(*spec))
+        return None
 
-        def _first_divisible_dim_sharding(shape):
-            for axis, dim in enumerate(shape or ()):
-                if dim and dim > 0 and dim % self._dp_size == 0:
-                    spec = [None] * len(shape)
-                    spec[axis] = 'dp'
-                    return NamedSharding(self.mesh, P(*spec))
-            return None
+    def _updated_state(self):
+        """Names of the variables the rule of state_sharding shards: what
+        an optimizer op of the program writes (the float32 masters and
+        their accumulators). An op whose Param is annotated (tp/sp/ep)
+        is left out whole: its update is partitioned by that axis, and
+        accumulators laid out against their parameter would be re-laid
+        every step."""
+        if self._updated is None:
+            self._updated = frozenset(
+                n for op in self._main_program.global_block().ops
+                if op.attr('op_role', None) == 'optimize'
+                and not any(self._var_sharding(p) is not None
+                            for p in op.input('Param'))
+                for n in op.output_arg_names())
+        return self._updated
+
+    def state_sharding(self, name):
+        """Where persistable variable `name` lives on this mesh (what
+        _bcast_params places, and what tools/mesh_schedule.py compiles
+        for). An annotation (tp/sp/ep) wins. On a mesh whose dp axis is
+        larger than 1, a variable that an optimizer op updates is a dp
+        shard on its first dimension that divides, so that each chip
+        updates 1/dp of every tensor and GSPMD gathers a weight where
+        it is used, after AMP's cast (what no dimension of divides stays
+        a replica). Everything else is a replica. An executor built with
+        share_vars_from over the same mesh asks the scope's owner about
+        the owner's variables: a test program has no optimizer op, and
+        the state it reads stays where the training step holds it."""
+        if name not in self._state_shardings:
+            # one object a variable: a step's arguments then carry the
+            # very sharding its jit options name, which jit's dispatch
+            # sees without comparing specs
+            owner = self._owner
+            if owner is not None and \
+                    name in owner._main_program.global_block().vars:
+                sharding = owner.state_sharding(name)
+            else:
+                sharding = self._place_state(name)
+            self._state_shardings[name] = sharding
+        return self._state_shardings[name]
+
+    def _place_state(self, name):
+        explicit = self._var_sharding(name)
+        if explicit is not None:
+            return explicit
+        var = self._main_program.global_block().vars[name]
+        if self._dp_size > 1 and var.shape and \
+                name in self._updated_state():
+            return self._dp_shard(var.shape) or self._replicated
+        return self._replicated
+
+    def _bcast_params(self):
+        """Re-place startup-initialized persistable state where
+        state_sharding says it lives on the mesh (analog of
+        BCastParamsToDevices ncclBcast, reference
+        parallel_executor.cc:210). What the owner of a shared scope
+        has placed already is left where it is."""
+        updated = self._updated_state()
+        placed = {True: 0, False: 0}
+        owner = self._owner
+        owner_vars = owner._main_program.global_block().vars \
+            if owner is not None and owner._params_placed else ()
         block = self._main_program.global_block()
         for name, var in block.vars.items():
-            if not var.persistable:
+            if not var.persistable or name in owner_vars:
                 continue
             val = self._scope.find_var(name)
             if val is None:
                 continue
-            sharding = self._var_sharding(name)
-            if sharding is None and zero1 and \
-                    not isinstance(var, Parameter) and var.shape:
-                # ZeRO-1-style: optimizer accumulators (persistable
-                # non-Parameter state) sharded over dp -- the reference
-                # BuildStrategy.kReduce analog (multi_devices_graph_pass
-                # :413-422). Elementwise optimizer math partitions exactly;
-                # GSPMD reshards grads into the shards. Plain ZeRO-1
-                # keeps the dim-0-only rule (r2 semantics); under
-                # ZeRO-3 the accumulators follow the same first-
-                # divisible-dim rule as their parameters, so an
-                # axis-1-sharded weight gets axis-1-sharded moments.
-                if zero3:
-                    sharding = _first_divisible_dim_sharding(var.shape)
-                elif var.shape[0] and var.shape[0] > 0 and \
-                        var.shape[0] % self._dp_size == 0:
-                    sharding = NamedSharding(
-                        self.mesh,
-                        P('dp', *([None] * (len(var.shape) - 1))))
-            if sharding is None and zero3 and isinstance(var, Parameter):
-                # ZeRO-3-style (beyond-reference): the PARAMETERS
-                # themselves shard over dp on the first dp-divisible
-                # dim; GSPMD gathers on use and reduce-scatters the
-                # grads into the shard. Per-device parameter + grad
-                # memory drops ~dp-fold.
-                sharding = _first_divisible_dim_sharding(var.shape)
-            target = sharding or self._replicated
+            target = self.state_sharding(name)
+            if name in updated:
+                placed[not target.is_fully_replicated] += \
+                    int(getattr(val, 'nbytes', 0))
             if jax.process_count() > 1:
                 from .parallel import distributed as dist
                 self._scope.set_var(name, dist.host_value_to_global(
@@ -338,16 +410,12 @@ class ParallelExecutor(Executor):
                 if not isinstance(val, jax.Array):
                     val = np.asarray(val)
                 self._scope.set_var(name, jax.device_put(val, target))
+        if updated:
+            _UPDATE_SHARDED.set(placed[True])
+            _UPDATE_REPLICATED.set(placed[False])
         self._params_placed = True
 
-    def _to_numpy(self, value):
-        if jax.process_count() > 1 and isinstance(value, jax.Array) and \
-                not value.is_fully_replicated:
-            from jax.experimental import multihost_utils
-            return np.asarray(
-                multihost_utils.process_allgather(value, tiled=True))
-        return np.asarray(value)
-
+    # -- public API --------------------------------------------------------
     def run(self, fetch_list, feed=None, feed_dict=None, return_numpy=True):
         feed = feed if feed is not None else feed_dict
         if not self._params_placed:

@@ -111,6 +111,20 @@ def main():
             xl, yl = xv, yv
         l, = pe.run(fetch_list=[loss.name], feed={'x': xl, 'y': yl})
         losses.append(float(np.asarray(l)))
+    # a save on every trainer writes whole arrays, whatever the mesh
+    # holds as shards across the trainers (a collective: all call it)
+    import tempfile
+    with tempfile.TemporaryDirectory() as tmp:
+        with fluid.scope_guard(scope):
+            fluid.io.save_persistables(exe, tmp, main_program=prog)
+        fresh = fluid.Scope()
+        with fluid.scope_guard(fresh):
+            fluid.io.load_persistables(exe, tmp, main_program=prog)
+        saved = sorted(
+            (v.name, float(np.abs(np.asarray(fresh.find_var(v.name))).sum()))
+            for v in prog.global_block().vars.values()
+            if isinstance(v, fluid.framework.Parameter))
+    print('SAVED ' + json.dumps(saved), flush=True)
     print('LOSSES ' + json.dumps(losses), flush=True)
 
 

@@ -24,6 +24,9 @@ def _free_port():
     return port
 
 
+_SAVED = {}      # (trainers, mode) -> [(parameter, sum of |values|)]
+
+
 def _run_workers(n, mode='dp'):
     port = _free_port()
     eps = ','.join('127.0.0.1:%d' % (port + i) for i in range(n))
@@ -47,11 +50,22 @@ def _run_workers(n, mode='dp'):
         outs.append(out)
     for p, out in zip(procs, outs):
         assert p.returncode == 0, out[-3000:]
-    losses = []
+    losses, saved = [], []
     for out in outs:
         line = [ln for ln in out.splitlines() if ln.startswith('LOSSES ')]
         assert line, out[-3000:]
         losses.append(json.loads(line[-1][len('LOSSES '):]))
+        line = [ln for ln in out.splitlines() if ln.startswith('SAVED ')]
+        assert line, out[-3000:]
+        saved.append(json.loads(line[-1][len('SAVED '):]))
+    # what every trainer saved and loaded back is one set of whole
+    # parameters, also where the mesh holds them as shards across the
+    # trainers (ParallelExecutor.state_sharding)
+    for other in saved[1:]:
+        assert [n for n, _ in other] == [n for n, _ in saved[0]]
+        np.testing.assert_allclose([v for _, v in other],
+                                   [v for _, v in saved[0]], rtol=1e-6)
+    _SAVED[(n, mode)] = saved[0]
     return losses
 
 
@@ -67,6 +81,9 @@ def test_two_trainers_match_single():
     np.testing.assert_allclose(single, two[0], rtol=1e-4)
     # training progressed
     assert two[0][-1] < two[0][0]
+    # and the parameters two trainers saved are the single trainer's
+    np.testing.assert_allclose([v for _, v in _SAVED[(2, 'dp')]],
+                               [v for _, v in _SAVED[(1, 'dp')]], rtol=1e-4)
 
 
 @pytest.mark.timeout(600)

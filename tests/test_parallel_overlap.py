@@ -52,7 +52,7 @@ def test_virtual_cpu_mesh_takes_no_compiler_options(n_devices, counting):
                                 devices=jax.devices()[:n_devices])
     assert pe._overlap_options() is None
     options = pe._jit_options(_segment(prog, loss), ('x', 'y'))
-    assert set(options) == {'in_shardings'}
+    assert set(options) == {'in_shardings', 'out_shardings'}
     # and the step compiles and runs: the CPU compiler would refuse them
     out = pe.run(fetch_list=[loss.name],
                  feed={'x': np.ones((16, 8), 'float32'),
@@ -170,7 +170,7 @@ def v5e_2x2():
     cc.reset_cache()
 
 
-def _rows(devices, per_step=8, options=None, overlap=True):
+def _rows(devices, per_step=8, options=None, overlap=True, replicated=False):
     import mesh_schedule
     from unittest import mock
     with fluid.unique_name.guard():
@@ -179,17 +179,26 @@ def _rows(devices, per_step=8, options=None, overlap=True):
     with mock.patch.object(pem, '_OVERLAP_OPTIONS',
                            pem._OVERLAP_OPTIONS if options is None
                            else options):
-        text = mesh_schedule.compile_step(program, [loss], devices,
-                                          per_step, overlap=overlap).as_text()
+        text = mesh_schedule.compile_step(
+            program, [loss], devices, per_step, overlap=overlap,
+            replicated=replicated).as_text()
     return mesh_schedule.list_collectives(text)
 
 
 @pytest.fixture(scope='module')
 def schedules(v5e_2x2):
-    """{overlap: mesh_schedule.list_collectives' rows} of the dp step, with
-    the rule applied and with XLA's default schedule."""
-    return {overlap: _rows(v5e_2x2, overlap=overlap)
+    """{overlap: mesh_schedule.list_collectives' rows} of the dp step with
+    every variable a replica (so that every collective is a gradient's
+    sum), with the rule applied and with XLA's default schedule."""
+    return {overlap: _rows(v5e_2x2, overlap=overlap, replicated=True)
             for overlap in (True, False)}
+
+
+@pytest.fixture(scope='module')
+def sharded_schedule(v5e_2x2):
+    """The rows of the dp step as the executor places and compiles it:
+    what an optimizer op updates a dp shard (state_sharding)."""
+    return _rows(v5e_2x2)
 
 
 def _big(rows):
@@ -239,9 +248,138 @@ def test_every_kept_option_is_needed(option, v5e_2x2):
     """An option that does nothing is not kept: without any one of them
     no sum of the compiled step rides a compute fusion."""
     rest = {k: v for k, v in pem._OVERLAP_OPTIONS.items() if k != option}
-    rows = _rows(v5e_2x2, options=rest)
+    if option == 'xla_tpu_enable_all_reduce_scatter_fusion':
+        return      # its own case: test_sharded_update_keeps_the_gradient_sums
+    rows = _rows(v5e_2x2, options=rest, replicated=True)
     assert rows and not [r for r in rows if r['form'] == 'fused'], \
         (option, rows)
+
+
+def test_sharded_update_keeps_the_gradient_sums(v5e_2x2, schedules,
+                                                sharded_schedule):
+    """With its state held as dp shards the step sums its large
+    gradients as the replicated step does (the same all-reduces in the
+    same dtype, one riding a product), because the fourth option takes the
+    compiler's own all-reduce-scatter fusion away: without the option a
+    large gradient is summed in float32 by a custom fusion that blocks,
+    which the tool lists as a plain reduce-scatter."""
+    def big_sums(rows):
+        return [(r['bytes'], tuple(r['dtypes']), r['form'])
+                for r in _big(rows) if r['kind'] == 'all-reduce']
+    assert not [r for r in sharded_schedule if r['kind'] == 'reduce-scatter']
+    # (at this small batch GSPMD moves the activations of one product
+    # and not its gradient: one sum fewer than the replicated step's)
+    kept, was = big_sums(sharded_schedule), big_sums(schedules[True])
+    assert kept and {k[:2] for k in kept} <= {w[:2] for w in was}, (kept, was)
+    assert [k for k in kept if k[2] == 'fused'], kept
+    rest = {k: v for k, v in pem._OVERLAP_OPTIONS.items()
+            if k != 'xla_tpu_enable_all_reduce_scatter_fusion'}
+    scattered = [r for r in _rows(v5e_2x2, options=rest)
+                 if r['kind'] == 'reduce-scatter']
+    assert _big(scattered), scattered
+    assert all(r['form'] == 'plain' and set(r['dtypes']) == {'f32'}
+               for r in _big(scattered)), scattered
+
+
+def test_sharded_update_gathers_the_weights_after_the_cast(sharded_schedule):
+    """A weight held as a float32 shard is gathered as bf16, after AMP's
+    cast, and the large gathers ride a product (async collective
+    fusions); nothing of float32 larger than a norm's gain or a bias is
+    gathered."""
+    gathers = [r for r in sharded_schedule if r['kind'] == 'all-gather']
+    big = _big(gathers)
+    assert big and all(set(r['dtypes']) == {'bf16'} for r in big), big
+    fused = [r for r in big if r['form'] == 'fused']
+    assert fused and all(
+        'mul' in ' '.join(r['carriers']) for r in fused), big
+    assert all(r['dtypes'].get('f32', 0) <= 1 << 20 for r in gathers)
+
+
+def test_sharded_update_holds_a_quarter_of_the_state(v5e_2x2):
+    """What a chip holds as arguments: the replicated step's masters and
+    moments, a quarter of them under the rule."""
+    import mesh_schedule
+    sizes = {}
+    for replicated in (True, False):
+        with fluid.unique_name.guard():
+            program, loss = mesh_schedule.build_lm_step(_WIDE, 8, 4)
+        mem = mesh_schedule.compile_step(
+            program, [loss], v5e_2x2, 8,
+            replicated=replicated).memory_analysis()
+        sizes[replicated] = mem.argument_size_in_bytes
+    assert sizes[False] < 0.3 * sizes[True], sizes
+
+
+def test_fresh_uncommitted_weights_and_gradient_fetches_compile(v5e_2x2):
+    """What the benchmark's check runs after training: new weights put in
+    the scope as plain (uncommitted) arrays, then the step with
+    gradients fetched. State enters and leaves a dp mesh's step where
+    state_sharding holds it; with only the outputs held there the TPU
+    compiler refused the program ("Expected aliased input ... and
+    output ... to have the same size": a donated weight it had laid out
+    one way against an output held another)."""
+    import mesh_schedule
+    from paddle_tpu.framework import Parameter
+    with fluid.unique_name.guard():
+        program, loss = mesh_schedule.build_lm_step(_WIDE, 8, 4)
+    params = [v.name for v in program.global_block().vars.values()
+              if isinstance(v, Parameter)]
+    fetch = [loss, params[2] + '@GRAD', params[-1] + '@GRAD']
+    compiled = mesh_schedule.compile_step(program, fetch, v5e_2x2, 8,
+                                          uncommitted=params)
+    placed, _, _ = compiled.input_shardings[0]
+    pe = fluid.ParallelExecutor(use_cuda=True, main_program=program,
+                                devices=list(v5e_2x2))
+    for name in params:
+        assert placed[name].is_equivalent_to(
+            pe.state_sharding(name),
+            len(program.global_block().vars[name].shape)), name
+
+
+# what the TPU compiler prints for a gradient summed into a shard by its
+# own fusion (the dp step compiled without the fourth option), cut to
+# the lines the tool reads
+_SCATTER_HLO = """HloModule jit_seg_fn, is_scheduled=true
+
+%add.1.clone (x: f32[], y: f32[]) -> f32[] {
+  %x = f32[]{:T(128)} parameter(0)
+  %y = f32[]{:T(128)} parameter(1)
+  ROOT %add.9 = f32[]{:T(128)} add(%x, %y)
+}
+
+%all-reduce-scatter.7 (input.7: f32[2048,8192]) -> f32[512,8192] {
+  %input.7 = f32[2048,8192]{1,0:T(8,128)S(1)} parameter(0)
+  %all-reduce.36 = f32[2048,8192]{1,0:T(8,128)} all-reduce(%input.7), channel_id=85, replica_groups={{0,1,2,3}}, to_apply=%add.1.clone
+  %partition-id.17 = u32[] partition-id()
+  ROOT %dynamic-slice.80 = f32[512,8192]{1,0:T(8,128)S(1)} dynamic-slice(%all-reduce.36, %partition-id.17), dynamic_slice_sizes={512,8192}
+}
+
+ENTRY %main.1 (p0: bf16[8192,2048], p1: bf16[8192,8192]) -> f32[512,8192] {
+  %p0 = bf16[8192,2048]{1,0} parameter(0)
+  %p1 = bf16[8192,8192]{1,0} parameter(1)
+  %convolution_bitcast_fusion = f32[2048,8192]{1,0:T(8,128)} fusion(%p0, %p1), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(seg_fn)/mul_grad.66/transpose(jvp())/dot_general"}
+  %fusion.11 = f32[512,8192]{1,0:T(8,128)S(1)} fusion(%convolution_bitcast_fusion), kind=kCustom, calls=%all-reduce-scatter.7, metadata={op_name="jit(seg_fn)/mul_grad.66/transpose(jvp())/dot_general"}
+  ROOT %divide_subtract_fusion.6 = f32[512,8192]{1,0} fusion(%fusion.11), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(seg_fn)/adam.133/sub"}
+}
+"""
+
+
+def test_tool_lists_an_all_reduce_scatter_fusion():
+    """A sum no instruction of the entry computation names: the tool
+    lists it as a reduce-scatter that blocks, with the operand's bytes
+    (what crosses the links) and dtype, the op that produced it and the
+    op that waits for it."""
+    import mesh_schedule
+    row, = mesh_schedule.list_collectives(_SCATTER_HLO)
+    assert (row['kind'], row['form'], row['name']) == \
+        ('reduce-scatter', 'plain', 'fusion.11')
+    assert row['bytes'] == 2048 * 8192 * 4 and row['dtypes'] == \
+        {'f32': 2048 * 8192 * 4}
+    assert row['scope'] == 'mul_grad.66'
+    assert row['first_use']['scope'] == 'adam.133' and not row['between']
+    summary = mesh_schedule.summarize([row])
+    assert summary['bytes_by_kind_and_form'] == \
+        {'reduce-scatter': {'plain': 2048 * 8192 * 4}}
 
 
 def test_flash_forward_of_the_training_cells_compiles_for_v5e(v5e_2x2):

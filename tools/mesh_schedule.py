@@ -10,7 +10,10 @@ use on the chip, and lists every collective of the scheduled entry
 computation in schedule order:
 
   kind      all-reduce, all-gather, reduce-scatter, collective-permute,
-            all-to-all
+            all-to-all (a fusion(kind=kCustom) that calls an
+            all-reduce-scatter computation, which is how the TPU compiler
+            sums a gradient into a shard, is a reduce-scatter, plain,
+            with the bytes of its operand: what crosses the links)
   form      plain   an instruction like any other: the chip does nothing
                     else while it runs
             marked  the same, carrying async_collective_name; measured on
@@ -19,7 +22,8 @@ computation in schedule order:
             fused   an async collective fusion (async-collective-start,
                     compute fusions that carry its steps, -done): the one
                     form seen to run beside compute (PERF.md, PR 32)
-  bytes     of the result, with the dtypes
+  bytes     of the result, with the dtypes (of the operand for a
+            reduce-scatter fusion)
   scope     the program op in the instruction's metadata (GSPMD gives a
             gradient's sum the scope of the op that produced it)
   carriers  for a fused one: the program ops of the compute fusions that
@@ -39,7 +43,6 @@ from __future__ import annotations
 
 import argparse
 import collections
-import contextlib
 import json
 import os
 import re
@@ -63,6 +66,7 @@ _INSTR = re.compile(r'^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\((.*)$')
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _SCOPE = re.compile(r'(?:^|/)([a-z_0-9]+\.\d+)(?:/|$)')
 _OPERAND = re.compile(r'%([\w.\-]+)')
+_SCATTER_CALL = re.compile(r'calls=%?(all-reduce-scatter[\w.\-]*)')
 # what the chip spends time on between a start and its done
 _COMPUTE = ('fusion', 'custom-call', 'convolution', 'while', 'dot',
             'call', 'conditional')
@@ -106,14 +110,19 @@ def build_lm_step(cfg, per_step, n_devices):
     return main, loss.name
 
 
-def compile_step(program, fetch_names, devices, per_step, overlap=True):
+def compile_step(program, fetch_names, devices, per_step, overlap=True,
+                 replicated=False, xla=None, uncommitted=()):
     """The compiled device segment (the largest, where a program has
     several) of `program` under a ParallelExecutor over `devices`, which
     may be described and not attached: arguments are shapes with the
-    shardings the executor would give the arrays (persistable state
-    replicated unless annotated, everything else split over dp).
-    overlap=False drops the executor's compiler options: XLA's default
-    schedule, for comparison."""
+    shardings the executor would give the arrays (persistable state where
+    ParallelExecutor.state_sharding puts it, everything else split over
+    dp). overlap=False drops the executor's compiler options: XLA's
+    default schedule, for comparison. replicated=True holds all state
+    as replicas (every mesh step before PR 47), xla adds compiler
+    options to the executor's: arms to compare, never what the chip
+    runs. The variables named in uncommitted arrive with no sharding,
+    as values a script has just put in the scope do."""
     import jax
     import numpy as np
     import paddle_tpu as fluid
@@ -121,6 +130,9 @@ def compile_step(program, fetch_names, devices, per_step, overlap=True):
 
     pe = fluid.ParallelExecutor(use_cuda=True, main_program=program,
                                 devices=list(devices))
+    if replicated:
+        pe.state_sharding = \
+            lambda name: pe._var_sharding(name) or pe._replicated
     prepared = PreparedProgram(program, 0, (), list(fetch_names))
     segment = max((s for s in prepared.steps
                    if isinstance(s, _DeviceSegment)),
@@ -133,9 +145,13 @@ def compile_step(program, fetch_names, devices, per_step, overlap=True):
         shape = tuple(per_step if d in (-1, None) else int(d)
                       for d in (var.shape or ()))
         dtype = jax.dtypes.canonicalize_dtype(np.dtype(var.dtype))
-        sharding = pe._var_sharding(name) or (
-            pe._replicated if var.persistable or not shape
-            else pe._batch_sharded)
+        if name in uncommitted:
+            return jax.ShapeDtypeStruct(shape, dtype)
+        if var.persistable:
+            sharding = pe.state_sharding(name)
+        else:
+            sharding = pe._var_sharding(name) or (
+                pe._batch_sharded if shape else pe._replicated)
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     donated = {n: struct(n) for n in segment.in_names if n in out_set}
@@ -145,10 +161,11 @@ def compile_step(program, fetch_names, devices, per_step, overlap=True):
     # the emitters ask jax.default_backend() which lowering to take (the
     # Mosaic flash kernel on a TPU); here it says "cpu" whatever the
     # program is compiled for
-    default = contextlib.nullcontext() if overlap else mock.patch.object(
-        type(pe), '_overlap_options', lambda self: None)
+    options = dict(pe._overlap_options() or {}, **(xla or {})) \
+        if overlap else None
     with mock.patch.object(jax, 'default_backend', return_value=platform), \
-            default:
+            mock.patch.object(type(pe), '_overlap_options',
+                              lambda self: options):
         jitted = pe._compile_segment(segment, block, program)
         return jitted.lower(donated, const, key).compile()
 
@@ -245,6 +262,17 @@ def list_collectives(hlo_text):
             rows.append({'index': i, 'name': name, 'kind': base,
                          'form': form, 'bytes': nbytes,
                          'dtypes': dict(dtypes), 'scope': _scope(line)})
+        elif opcode == 'fusion' and 'kind=kCustom' in line and \
+                (call := _SCATTER_CALL.search(line)):
+            # the sum of a whole operand that leaves as this chip's
+            # shard: blocking, and no async-collective-* pair around it
+            body = comps.get(call.group(1), '')
+            operand = next((ln for ln in body.splitlines()
+                            if ' parameter(0)' in ln), rtype)
+            nbytes, dtypes = _nbytes(operand.split(' parameter(')[0])
+            rows.append({'index': i, 'name': name, 'kind': 'reduce-scatter',
+                         'form': 'plain', 'bytes': nbytes,
+                         'dtypes': dict(dtypes), 'scope': _scope(line)})
         elif opcode == 'fusion' and name.startswith('async-collective-'):
             tag = name.split('.', 1)[1] if '.' in name else ''
             if name.startswith('async-collective-start'):
@@ -281,14 +309,25 @@ def list_collectives(hlo_text):
     return rows
 
 
+def parse_options(pairs):
+    """{name: value} of NAME=VALUE strings: true, false and whole numbers
+    as such, anything else as text."""
+    return {k: json.loads(v) if v in ('true', 'false') or v.isdigit() else v
+            for k, v in (kv.split('=', 1) for kv in pairs)}
+
+
 def summarize(rows):
     by_form, by_dtype = collections.Counter(), collections.Counter()
+    by_kind = collections.defaultdict(collections.Counter)
     for r in rows:
         by_form[r['form']] += r['bytes']
+        by_kind[r['kind']][r['form']] += r['bytes']
         for k, v in r['dtypes'].items():
             by_dtype[k] += v
     return {'collectives': len(rows), 'bytes_by_form': dict(by_form),
-            'bytes_by_dtype': dict(by_dtype)}
+            'bytes_by_dtype': dict(by_dtype),
+            'bytes_by_kind_and_form': {k: dict(v)
+                                       for k, v in by_kind.items()}}
 
 
 def main(argv=None):
@@ -303,6 +342,12 @@ def main(argv=None):
     ap.add_argument('--no-overlap', action='store_true',
                     help="XLA's default schedule: the executor's compiler "
                          'options left out')
+    ap.add_argument('--replicated', action='store_true',
+                    help='all state held as replicas (every mesh step '
+                         'before PR 47), for comparison')
+    ap.add_argument('--xla', action='append', default=[], metavar='NAME=VALUE',
+                    help="a compiler option beside the executor's, for "
+                         'comparison (repeatable)')
     ap.add_argument('--dump', default=None,
                     help='write the compiled module text here')
     ap.add_argument('--json', action='store_true', help='rows as JSON lines')
@@ -314,8 +359,10 @@ def main(argv=None):
         cfg['n_layer'] = args.layers
     devices = describe(args.topology)
     program, loss = build_lm_step(cfg, args.per_step, len(devices))
+    xla = parse_options(args.xla)
     compiled = compile_step(program, [loss], devices, args.per_step,
-                            overlap=not args.no_overlap)
+                            overlap=not args.no_overlap,
+                            replicated=args.replicated, xla=xla)
     text = compiled.as_text()
     if args.dump:
         with open(args.dump, 'w') as f:
@@ -345,9 +392,15 @@ def main(argv=None):
                 ': ' + top if top else '')
         print(line)
     mem = compiled.memory_analysis()
+    cost = compiled.cost_analysis() or {}
+    if isinstance(cost, (list, tuple)):
+        cost = cost[0] if cost else {}
     print(json.dumps(dict(
         summarize(rows), topology=args.topology, n_layer=cfg['n_layer'],
         per_step=args.per_step, overlap=not args.no_overlap,
+        replicated=args.replicated, xla=xla,
+        flops_a_chip=cost.get('flops'),
+        bytes_accessed_a_chip=cost.get('bytes accessed'),
         temp_bytes=getattr(mem, 'temp_size_in_bytes', None),
         argument_bytes=getattr(mem, 'argument_size_in_bytes', None))))
     return 0
