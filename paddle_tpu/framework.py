@@ -409,6 +409,11 @@ class Program(object):
         self.random_seed = 0
         self._op_role = 'forward'  # forward | backward | optimize | rpc
         self.lr_schedule_hook = None
+        # {'family': ..., 'config': {...}}, JSON-plain: what the block
+        # file of a served LM (models.SERVED_FAMILIES) built this program
+        # from; clone and _prune copy it, to_json writes it, and the
+        # DecodeTranspiler reads the model from it
+        self.served_model = None
 
     # -- mutation tracking -------------------------------------------------
     def _bump_version(self):
@@ -522,6 +527,7 @@ class Program(object):
 
         return json.dumps({
             'version': 1,
+            'served_model': self.served_model,
             'blocks': [{
                 'idx': b.idx, 'parent_idx': b.parent_idx,
                 'vars': [var_d(v) for v in b.vars.values()],
@@ -533,6 +539,7 @@ class Program(object):
     def from_json(s):
         d = json.loads(s)
         p = Program()
+        p.served_model = d.get('served_model')
         p.blocks = []
         for bd in d['blocks']:
             b = Block(p, bd['idx'], bd['parent_idx'])

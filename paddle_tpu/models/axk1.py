@@ -22,10 +22,13 @@ group-limited choice and experts of three matrices, `experts_held` of
 E from `expert_offset` (one chip's share), plus one shared expert of
 the dense form. Gate and up of a dense FFN are one weight [D, 2 F].
 
-Three programs come from the one block code: language_model_logits
-(what save_inference_model writes and the DecodeTranspiler reads: op
+Three programs come from the one block walk (_model):
+language_model_logits (what save_inference_model writes, with the
+description the DecodeTranspiler reads the model from: op
 latent_attention over the whole sequence, the equations as they stand)
-and the paged serving pair. A layer keeps ONE page pool,
+and, through AXK1DecodeSpec.paged_logits, the paged serving pair
+(models/transformer.build_paged_prefill_program and
+build_paged_decode_program). A layer keeps ONE page pool,
 [pages, page_tokens, row]: a token's normed latent and its ALREADY
 ROTATED key side by side (dc + dr values for all heads), padded with
 zeros to whole lanes of 128; there is no V pool and no recurrent state,
@@ -40,11 +43,10 @@ from __future__ import annotations
 
 from .. import layers as L
 from ..ops.latent_attention_ops import yarn_mscale
-from .hybrid import HybridDecodeSpec, _data, _param, _rms
-from .nemotron_h import _fetches
-from .transformer import (PAGED_DECODE_FEEDS, DecodeSpec, _block_op,
-                          _create_pool_vars, _named_attr, _named_fc,
-                          _paged_decode_tokens, _tmp_var)
+from . import describe_served_model
+from .hybrid import HybridDecodeSpec, _param, _rms
+from .transformer import (DecodeSpec, _block_op, _expert_io, _logits_head,
+                          _named_attr, _named_fc, _tmp_var)
 
 KIND = 'latent_attention'
 ROPE_KEYS = ('base', 'factor', 'original_max', 'beta_fast', 'beta_slow',
@@ -81,6 +83,9 @@ class AXK1Config(object):
     def sm_scale(self):
         m = yarn_mscale(self.rope['factor'], self.rope['mscale_all_dim'])
         return (self.nope_dim + self.rope_dim) ** -0.5 * m * m
+
+
+Config = AXK1Config
 
 
 class AXK1DecodeSpec(DecodeSpec):
@@ -135,12 +140,8 @@ class AXK1DecodeSpec(DecodeSpec):
 
     param_names = HybridDecodeSpec.param_names
 
-    def build_paged_programs(self, slots, chunk, num_pages, page_tokens,
-                             pages_per_slot):
-        return build_paged_prefill_program(
-            self, chunk, num_pages, page_tokens, pages_per_slot) + \
-            build_paged_decode_program(
-                self, slots, num_pages, page_tokens, pages_per_slot)
+    def paged_logits(self, tokens, at):
+        return _model(tokens, self, at)
 
 
 _ATTN_ROLES = (('attn_norm', False), ('q_down', True), ('q_norm', False),
@@ -217,45 +218,41 @@ def _attention_op(op_type, spec, blk, q, t, **inputs):
     return _named_fc(ctx, spec.dim, blk['proj'])
 
 
-def _full_attention(x, spec, blk):
-    """The whole sequence, the equations as they stand."""
-    t = spec.max_len
-    q, ckv, kr = _latent_parts(x, spec, blk, t)
-    return _attention_op('latent_attention', spec, blk, q, t, CKV=ckv, KR=kr)
-
-
-def _paged_attention(x, spec, blk, pool, table, positions, cow_src=None,
-                     cow_dst=None, chunk=None, length=None):
-    """The new rows into their pages (the normed latent and the rotated
-    key side by side, zeros up to the pool's row), then the absorbed
-    attention through the table: one token a lane where `chunk` is
-    None, else one stream's chunk of rows. A chunk copies its forked
-    page first (cow_src, cow_dst: its pair of feeds); a decode step
-    has no such feeds and copies none: the host ran the page copy
-    program in front of it (models/transformer.build_page_copy_program)."""
-    c = spec.cfg
-    t = chunk or 1
-    q, ckv, kr = _latent_parts(x, spec, blk, t, positions,
-                               'row' if chunk else 'lane')
+def _attention(x, spec, blk, i, at=None):
+    """The whole sequence, the equations as they stand; or, over layer
+    i's pages: the new rows into their pages (the normed latent and the
+    rotated key side by side, zeros up to the pool's row), then the
+    absorbed attention through the table, one token a lane in a decode
+    step, one stream's chunk of rows in a prefill chunk. A chunk copies
+    its forked page first (at.cow); a decode step copies none: the host
+    ran the page copy program in front of it
+    (models/transformer.build_page_copy_program)."""
+    if at is None:
+        t = spec.max_len
+        q, ckv, kr = _latent_parts(x, spec, blk, t)
+        return _attention_op('latent_attention', spec, blk, q, t, CKV=ckv,
+                             KR=kr)
+    q, ckv, kr = _latent_parts(x, spec, blk, at.rows, at.positions,
+                               'lane' if at.decode else 'row')
     row = L.concat([ckv, kr], axis=2)
     if spec.pool_row > spec.latent_row:
         row = L.pad(row, paddings=[0, 0, 0, 0, 0,
                                    spec.pool_row - spec.latent_row])
-    pool, = pool
-    if cow_src is not None:
+    pool, = at.pools[i]
+    ins = {'Pool': [pool], 'X': [row], 'Table': [at.table],
+           'Positions': [at.positions]}
+    if not at.decode:
         _block_op('kv_page_cow',
-                  inputs={'Pool': [pool], 'Src': [cow_src],
-                          'Dst': [cow_dst]},
+                  inputs={'Pool': [pool], 'Src': [at.cow[0]],
+                          'Dst': [at.cow[1]]},
                   outputs={'Out': [pool]})
-    ins = {'Pool': [pool], 'X': [row], 'Table': [table],
-           'Positions': [positions]}
-    if chunk:
-        ins['Len'] = [length]
-    _block_op('kv_page_write' if chunk else 'kv_page_append', inputs=ins,
+        ins['Len'] = [at.length]
+    _block_op('kv_page_append' if at.decode else 'kv_page_write', inputs=ins,
               outputs={'Out': [pool]})
     return _attention_op(
-        'paged_latent_prefill' if chunk else 'paged_latent_attention',
-        spec, blk, q, t, Pool=pool, Table=table, Positions=positions)
+        'paged_latent_attention' if at.decode else 'paged_latent_prefill',
+        spec, blk, q, at.rows, Pool=pool, Table=at.table,
+        Positions=at.positions)
 
 
 def _gated_mlp(x, spec, width, up, down):
@@ -266,20 +263,17 @@ def _gated_mlp(x, spec, width, up, down):
     return _named_fc(h, spec.dim, down)
 
 
-def _experts_ffn(x, spec, blk, stats=None, at=None):
+def _experts_ffn(x, spec, blk, at=None):
     """The expert layer: op moe_experts on x itself, and the shared
-    expert as plain matmuls. `stats` and `at` as in
+    expert as plain matmuls. `at` as in
     models/nemotron_h._experts_mixer."""
     c = spec.cfg
-    outs = {}
-    if stats is not None:
-        stats.append(_tmp_var('int32'))
-        outs['Stats'] = [stats[-1]]
+    ins, outs = _expert_io(at)
     held = [c.experts_held, spec.dim, c.expert_ffn]
     routed = _tmp_var()
     _block_op('moe_experts',
               inputs=dict(
-                  at or {}, X=[x], Lat=[x],
+                  ins, X=[x], Lat=[x],
                   RouterW=[_param(blk['router'], [spec.dim, c.experts])],
                   Bias=[_param(blk['bias'], [c.experts])],
                   W1=[_param(blk['w1'], held)], W3=[_param(blk['w3'], held)],
@@ -292,95 +286,24 @@ def _experts_ffn(x, spec, blk, stats=None, at=None):
         x, spec, c.shared_ffn, blk['shared_gate_up'], blk['shared_down']))
 
 
-def _model(tokens, spec, attention, experts, last=None):
-    """Embedding -> layers -> final norm -> head. `attention(x, spec,
-    blk, i)` and `experts(x, spec, blk)` are the program's forms of the
-    two sublayers; `last` gathers one row a sequence before the head."""
+def _model(tokens, spec, at=None):
+    """Embedding -> layers -> final norm -> head: the whole sequence, or
+    one paged program's rows (`at`: PagedStep)."""
     c = spec.cfg
     x = L.embedding(tokens, size=[spec.vocab, spec.dim],
                     param_attr=_named_attr(spec.emb_w))
     for i, blk in enumerate(spec.blocks):
         x = L.elementwise_add(
-            x, attention(_rms(x, spec, blk['attn_norm']), spec, blk, i))
+            x, _attention(_rms(x, spec, blk['attn_norm']), spec, blk, i, at))
         h = _rms(x, spec, blk['ffn_norm'])
-        x = L.elementwise_add(x, experts(h, spec, blk)
+        x = L.elementwise_add(x, _experts_ffn(h, spec, blk, at)
                               if ffn_kind(c, i) == 'experts' else
                               _gated_mlp(h, spec, c.dense_ffn,
                                          blk['gate_up'], blk['down']))
-    x = _rms(x, spec, spec.final_ln[0])
-    if last is None:
-        return _named_fc(x, spec.vocab, spec.head)
-    gathered = _tmp_var()
-    _block_op('gather_time', inputs={'X': [x], 'Index': [last]},
-              outputs={'Out': [gathered]})
-    return _named_fc(gathered, spec.vocab, spec.head, num_flatten_dims=1)
+    return _logits_head(_rms(x, spec, spec.final_ln[0]), spec, at)
 
 
 def language_model_logits(tokens, cfg):
     """tokens [B, T, 1] int64 (T = cfg.max_len) -> logits [B, T, vocab]."""
-    return _model(tokens, spec_from_config(cfg),
-                  lambda x, sp, blk, i: _full_attention(x, sp, blk),
-                  _experts_ffn)
-
-
-# -- the paged pair ------------------------------------------------------------
-
-def build_paged_prefill_program(spec, chunk, num_pages, page_tokens,
-                                pages_per_slot):
-    """One prefill chunk of one stream: models/transformer.py's paged
-    prefill feeds. Rows from prefill_len on land in the null page and
-    are not counted by the expert layers.
-    Returns (program, feed_names, fetch_vars[logits, ids, counts])."""
-    from ..framework import Program, program_guard
-    prog, startup = Program(), Program()
-    prog._is_test = True
-    with program_guard(prog, startup):
-        tokens = _data('prefill_tokens', [1, chunk, 1], 'int64')
-        positions = _data('prefill_positions', [chunk])
-        length = _data('prefill_len', [1])
-        last = _data('prefill_last', [1])
-        table = _data('prefill_page_table', [1, pages_per_slot])
-        cow_src = _data('prefill_cow_src', [1])
-        cow_dst = _data('prefill_cow_dst', [1])
-        pools = _create_pool_vars(spec, num_pages, page_tokens)
-        stats = []
-        logits = _model(
-            tokens, spec,
-            lambda x, sp, blk, i: _paged_attention(
-                x, sp, blk, pools[i], table, positions, cow_src, cow_dst,
-                chunk, length),
-            lambda x, sp, blk: _experts_ffn(x, sp, blk, stats,
-                                            {'Len': [length]}), last=last)
-        fetches = _fetches(logits, L.argmax(logits, axis=-1), stats)
-    return prog, ['prefill_tokens', 'prefill_positions', 'prefill_len',
-                  'prefill_last', 'prefill_page_table', 'prefill_cow_src',
-                  'prefill_cow_dst'], fetches
-
-
-def build_paged_decode_program(spec, slots, num_pages, page_tokens,
-                               pages_per_slot):
-    """One token a lane over the whole slot pool: models/transformer.py's
-    paged decode feeds (no copy-on-write pair: the program copies no
-    page) and decode_live [slots], which marks the lanes
-    that take part: the expert layers neither count nor weigh the
-    others' rows.
-    Returns (program, feed_names, fetch_vars[logits, ids, counts])."""
-    from ..framework import Program, program_guard
-    prog, startup = Program(), Program()
-    prog._is_test = True
-    with program_guard(prog, startup):
-        tokens = _paged_decode_tokens(slots)
-        step_idx = _data('decode_step_idx', [slots])
-        table = _data('decode_page_table', [slots, pages_per_slot])
-        live = _data('decode_live', [slots])
-        pools = _create_pool_vars(spec, num_pages, page_tokens)
-        stats = []
-        logits3 = _model(
-            tokens, spec,
-            lambda x, sp, blk, i: _paged_attention(
-                x, sp, blk, pools[i], table, step_idx),
-            lambda x, sp, blk: _experts_ffn(x, sp, blk, stats,
-                                            {'Live': [live]}))
-        logits = L.reshape(logits3, shape=[-1, spec.vocab])
-        fetches = _fetches(logits, L.argmax(logits, axis=-1), stats)
-    return prog, PAGED_DECODE_FEEDS + ['decode_live'], fetches
+    describe_served_model(tokens.block.program, 'axk1', cfg)
+    return _model(tokens, spec_from_config(cfg))

@@ -32,22 +32,21 @@ beside W1: an expert is W2 (silu(W1 u) * W3 u)) gives the part of the
 sum that the experts HELD here add (`experts_held` of E from
 `expert_offset`). Shared: V2 (silu(a) * b), [a | b] = u V1.
 
-Three programs from the one block code, as in models/nemotron_h.py:
-language_model_logits and the paged serving pair. K/V pools for the
-full-attention layers only; every mamba layer keeps its state and its
-convolution rows a slot. The pair's decode program copies no page
-(models/transformer.build_page_copy_program), and each program returns
-what its expert sublayers counted as a third fetch.
+Three programs from the one block walk (_model), as in
+models/nemotron_h.py: language_model_logits and, through
+GraniteHDecodeSpec.paged_logits, the paged serving pair. K/V pools for
+the full-attention layers only; every mamba layer keeps its state and
+its convolution rows a slot. Each program of the pair returns what its
+expert sublayers counted as a third fetch.
 """
 from __future__ import annotations
 
 from .. import layers as L
-from .hybrid import _create_state_vars, _data, _param, _rms
-from .nemotron_h import NemotronHDecodeSpec, _fetches, _mamba_mixer
-from .transformer import (PAGED_DECODE_FEEDS, DecodeSpec, _block_op,
-                          _create_pool_vars, _named_attr, _named_fc,
-                          _paged_decode_attention, _paged_decode_tokens,
-                          _paged_prefill_attention, _qkv_parts, _tmp_var)
+from . import describe_served_model
+from .hybrid import _param, _rms
+from .nemotron_h import NemotronHDecodeSpec, _attention, _mamba_mixer
+from .transformer import (DecodeSpec, _block_op, _expert_io, _logits_head,
+                          _named_attr, _named_fc, _tmp_var)
 
 KINDS = ('mamba', 'full_attention')
 # layer_types' words
@@ -78,6 +77,9 @@ class GraniteHConfig(object):
         self.residual_multiplier = float(residual_multiplier)
         self.attention_multiplier = float(attention_multiplier)
         self.logits_scaling = float(logits_scaling)
+
+
+Config = GraniteHConfig
 
 
 class GraniteHDecodeSpec(NemotronHDecodeSpec):
@@ -113,12 +115,8 @@ class GraniteHDecodeSpec(NemotronHDecodeSpec):
         # the tied head is the embedding: named once
         return NemotronHDecodeSpec.param_names(self)[1:]
 
-    def build_paged_programs(self, slots, chunk, num_pages, page_tokens,
-                             pages_per_slot):
-        return build_paged_prefill_program(
-            self, slots, chunk, num_pages, page_tokens, pages_per_slot) + \
-            build_paged_decode_program(
-                self, slots, num_pages, page_tokens, pages_per_slot)
+    def paged_logits(self, tokens, at):
+        return _model(tokens, self, at)
 
 
 _ROLES = {
@@ -147,21 +145,18 @@ def spec_from_config(cfg):
 
 # -- the block ---------------------------------------------------------------
 
-def _experts(u, spec, blk, stats=None, at=None):
+def _experts(u, spec, blk, at=None):
     """The expert sublayer on the normed stream u: op moe_experts on u
-    itself and the shared expert as plain matmuls. `stats` is the list
-    the sublayer's counts are appended to and `at` the input that marks
-    dead rows (Live or Len); neither for the whole-sequence form."""
+    itself (it passes over the dead rows and counts the others where
+    `at` says which those are) and the shared expert as plain
+    matmuls."""
     c = spec.cfg
-    outs = {}
-    if stats is not None:
-        stats.append(_tmp_var('int32'))
-        outs['Stats'] = [stats[-1]]
+    ins, outs = _expert_io(at)
     held = [c.experts_held, spec.dim, c.expert_ffn]
     routed = _tmp_var()
     _block_op('moe_experts',
               inputs=dict(
-                  at or {}, X=[u], Lat=[u],
+                  ins, X=[u], Lat=[u],
                   RouterW=[_param(blk['router'], [spec.dim, c.experts])],
                   W1=[_param(blk['w1'], held)], W3=[_param(blk['w3'], held)],
                   W2=[_param(blk['w2'], [held[0], held[2], held[1]])]),
@@ -177,30 +172,13 @@ def _experts(u, spec, blk, stats=None, at=None):
         routed, _named_fc(shared, spec.dim, blk['shared_down']))
 
 
-def _full_attention(x, spec, blk):
-    """Whole-sequence causal attention (the source program's form): the
-    query heads of one K/V head are rows of one product."""
-    t, h, kvh, dh = spec.max_len, spec.heads, spec.kv_heads, spec.dh
-    rep = h // kvh
-    q4, k4, v4 = _qkv_parts(x, spec, blk, t)
-    q, k, v = (L.transpose(a, perm=[0, 2, 1, 3]) for a in (q4, k4, v4))
-    q = L.reshape(q, shape=[-1, kvh, rep * t, dh])
-    scores = L.matmul(q, k, transpose_y=True, alpha=spec.sm_scale)
-    scores = L.reshape(scores, shape=[-1, h, t, t])
-    probs = L.softmax(L.causal_mask_bias(scores))
-    ctx = L.matmul(L.reshape(probs, shape=[-1, kvh, rep * t, t]), v)
-    ctx = L.transpose(L.reshape(ctx, shape=[-1, h, t, dh]),
-                      perm=[0, 2, 1, 3])
-    return _named_fc(L.reshape(ctx, shape=[-1, t, h * dh]), spec.dim,
-                     blk['proj'])
+_MIXERS = {'mamba': _mamba_mixer, 'full_attention': _attention}
 
 
-def _model(tokens, spec, mixers, experts, last=None):
+def _model(tokens, spec, at=None):
     """Embedding -> layers of two sublayers -> final norm -> the
-    embedding again as the head. `mixers` maps a layer kind to its
-    mixer and `experts(u, spec, blk)` is the program's form of the
-    expert sublayer; `last` gathers one row a sequence before the
-    head (the prefill's logits)."""
+    embedding again as the head: the whole sequence from zero state, or
+    one paged program's rows (`at`: PagedStep)."""
     c = spec.cfg
     x = L.scale(L.embedding(tokens, size=[spec.vocab, spec.dim],
                             param_attr=_named_attr(spec.emb_w)),
@@ -208,96 +186,19 @@ def _model(tokens, spec, mixers, experts, last=None):
     for i, kind in enumerate(spec.kinds):
         blk = spec.blocks[i]
         x = L.elementwise_add(x, L.scale(
-            mixers[kind](_rms(x, spec, blk['norm']), spec, blk, i),
+            _MIXERS[kind](_rms(x, spec, blk['norm']), spec, blk, i, at),
             scale=c.residual_multiplier))
         x = L.elementwise_add(x, L.scale(
-            experts(_rms(x, spec, blk['ffn_norm']), spec, blk),
+            _experts(_rms(x, spec, blk['ffn_norm']), spec, blk, at),
             scale=c.residual_multiplier))
-    x = _rms(x, spec, spec.final_ln[0])
-    if last is not None:
-        gathered = _tmp_var()
-        _block_op('gather_time', inputs={'X': [x], 'Index': [last]},
-                  outputs={'Out': [gathered]})
-        x = gathered
-    return L.matmul(x, _param(spec.emb_w, [spec.vocab, spec.dim]),
-                    transpose_y=True, alpha=1.0 / c.logits_scaling)
+    return _logits_head(
+        _rms(x, spec, spec.final_ln[0]), spec, at,
+        lambda h, _: L.matmul(h, _param(spec.emb_w, [spec.vocab, spec.dim]),
+                              transpose_y=True, alpha=1.0 / c.logits_scaling))
 
 
 def language_model_logits(tokens, cfg):
     """tokens [B, T, 1] int64 (T = cfg.max_len) -> logits [B, T, vocab],
     every sequence from zero state."""
-    spec = spec_from_config(cfg)
-    return _model(tokens, spec, {
-        'mamba': lambda x, sp, blk, i: _mamba_mixer(
-            x, sp, blk, sp.max_len, 'ssd_chunk'),
-        'full_attention': lambda x, sp, blk, i: _full_attention(x, sp, blk)},
-        _experts)
-
-
-# -- the paged pair ------------------------------------------------------------
-
-def build_paged_prefill_program(spec, slots, chunk, num_pages, page_tokens,
-                                pages_per_slot):
-    """One prefill chunk of one stream: models/nemotron_h.py's paged
-    prefill feeds and fetches.
-    Returns (program, feed_names, fetch_vars[logits, ids, counts])."""
-    from ..framework import Program, program_guard
-    prog, startup = Program(), Program()
-    prog._is_test = True
-    with program_guard(prog, startup):
-        tokens = _data('prefill_tokens', [1, chunk, 1], 'int64')
-        positions = _data('prefill_positions', [chunk])
-        length = _data('prefill_len', [1])
-        last = _data('prefill_last', [1])
-        table = _data('prefill_page_table', [1, pages_per_slot])
-        cow_src = _data('prefill_cow_src', [1])
-        cow_dst = _data('prefill_cow_dst', [1])
-        slot = _data('prefill_state_slot', [1])
-        reset = _data('prefill_state_reset', [1])
-        pools = _create_pool_vars(spec, num_pages, page_tokens)
-        states = _create_state_vars(spec, slots)
-        stats = []
-
-        logits = _model(tokens, spec, {
-            'mamba': lambda x, sp, blk, i: _mamba_mixer(
-                x, sp, blk, chunk, 'ssd_chunk', states[i],
-                {'Slot': [slot], 'Len': [length], 'Reset': [reset]}),
-            'full_attention': lambda x, sp, blk, i: _paged_prefill_attention(
-                x, sp, blk, pools[i], table, positions, length, cow_src,
-                cow_dst, chunk)},
-            lambda u, sp, blk: _experts(u, sp, blk, stats,
-                                        {'Len': [length]}), last=last)
-        fetches = _fetches(logits, L.argmax(logits, axis=-1), stats)
-    return prog, ['prefill_tokens', 'prefill_positions', 'prefill_len',
-                  'prefill_last', 'prefill_page_table', 'prefill_cow_src',
-                  'prefill_cow_dst', 'prefill_state_slot',
-                  'prefill_state_reset'], fetches
-
-
-def build_paged_decode_program(spec, slots, num_pages, page_tokens,
-                               pages_per_slot):
-    """One token a lane over the whole slot pool: models/nemotron_h.py's
-    paged decode feeds and fetches (no copy-on-write pair: the program
-    copies no page).
-    Returns (program, feed_names, fetch_vars[logits, ids, counts])."""
-    from ..framework import Program, program_guard
-    prog, startup = Program(), Program()
-    prog._is_test = True
-    with program_guard(prog, startup):
-        tokens = _paged_decode_tokens(slots)
-        step_idx = _data('decode_step_idx', [slots])
-        table = _data('decode_page_table', [slots, pages_per_slot])
-        live = _data('decode_state_live', [slots])
-        pools = _create_pool_vars(spec, num_pages, page_tokens)
-        states = _create_state_vars(spec, slots)
-        stats = []
-
-        logits3 = _model(tokens, spec, {
-            'mamba': lambda x, sp, blk, i: _mamba_mixer(
-                x, sp, blk, 1, 'ssd_step', states[i], {'Live': [live]}),
-            'full_attention': lambda x, sp, blk, i: _paged_decode_attention(
-                x, sp, blk, pools[i], table, step_idx)},
-            lambda u, sp, blk: _experts(u, sp, blk, stats, {'Live': [live]}))
-        logits = L.reshape(logits3, shape=[-1, spec.vocab])
-        fetches = _fetches(logits, L.argmax(logits, axis=-1), stats)
-    return prog, PAGED_DECODE_FEEDS + ['decode_state_live'], fetches
+    describe_served_model(tokens.block.program, 'granite_h', cfg)
+    return _model(tokens, spec_from_config(cfg))

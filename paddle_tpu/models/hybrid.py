@@ -17,14 +17,18 @@ ops/delta_rule_ops.py); then W_o [RMSNorm_head(o) * silu(x W_g)].
 whole width, v = x W_v, causal softmax attention, W_o; no positional
 term. Embedding -> blocks -> RMSNorm -> untied head.
 
-Three programs come from the one block code:
+Three programs come from the one block walk (_model), which asks the
+value it is handed where K/V and state come from:
 
   language_model_logits   the whole-sequence program that
-                          save_inference_model writes and the
-                          DecodeTranspiler reads;
-  build_paged_prefill_program / build_paged_decode_program
-                          the paged serving pair. K/V pools exist for
-                          the full-attention layers only; each
+                          save_inference_model writes, with the
+                          description the DecodeTranspiler reads the
+                          model from;
+  HybridDecodeSpec.paged_logits
+                          the walk of the paged serving pair
+                          (models/transformer.build_paged_prefill_program
+                          and build_paged_decode_program). K/V pools
+                          exist for the full-attention layers only; each
                           linear-attention layer keeps, per slot, its
                           delta state [slots, H, dk, dv] and the
                           convolution's last K-1 input rows
@@ -36,10 +40,10 @@ from __future__ import annotations
 import numpy as np
 
 from .. import layers as L
-from .transformer import (PAGED_DECODE_FEEDS, DecodeSpec, _block_op,
-                          _create_pool_vars, _named_attr, _named_fc,
-                          _paged_decode_attention, _paged_decode_tokens,
-                          _paged_prefill_attention, _qkv_parts, _tmp_var)
+from . import describe_served_model
+from .transformer import (DecodeSpec, _block_op, _logits_head, _named_attr,
+                          _named_fc, _paged_attention, _qkv_parts, _state_io,
+                          _tmp_var)
 
 KINDS = ('linear_attention', 'full_attention')
 
@@ -54,6 +58,9 @@ class HybridConfig(object):
         self.key_dim, self.value_dim = key_dim, value_dim
         self.conv_kernel, self.eps = conv_kernel, eps
         self.neg_eigval = neg_eigval
+
+
+Config = HybridConfig
 
 
 class HybridDecodeSpec(DecodeSpec):
@@ -114,12 +121,8 @@ class HybridDecodeSpec(DecodeSpec):
                 names.append(v[0] if isinstance(v, tuple) else v)
         return names
 
-    def build_paged_programs(self, slots, chunk, num_pages, page_tokens,
-                             pages_per_slot):
-        return build_paged_prefill_program(
-            self, slots, chunk, num_pages, page_tokens, pages_per_slot) + \
-            build_paged_decode_program(
-                self, slots, num_pages, page_tokens, pages_per_slot)
+    def paged_logits(self, tokens, at):
+        return _model(tokens, self, at)
 
 
 def spec_from_config(cfg):
@@ -164,31 +167,26 @@ def _param(name, shape):
                                    dtype='float32')
 
 
-def _linear_mixer(x, spec, blk, t, delta_type, state=None, at=None):
-    """The linear-attention mixer around its two stateful ops. `state` is
-    the layer's (delta state, convolution rows) pair, which both ops
-    read and write in place, and `at` the inputs that say where and how
-    (Slot/Len/Reset for a chunk, Live for a step); neither for the
-    whole-sequence form."""
+def _linear_mixer(x, spec, blk, i, at=None):
+    """The linear-attention mixer around its two stateful ops, which
+    read and write layer i's (delta state, convolution rows) in place
+    where `at` says (a chunk's slot, or the live lanes of a step); the
+    whole sequence from zero state without one."""
     h, dk, dv = spec.heads, spec.key_dim, spec.value_dim
-
-    def stateful(var):
-        if state is None:
-            return {}, {}
-        return dict(at, State=[var]), {'StateOut': [var]}
-
+    t = at.rows if at else spec.max_len
     qkv = _named_fc(x, spec.conv_dim, blk['qkv'])
     ba = _named_fc(x, 2 * h, blk['ba'])
     gate = _named_fc(x, h * dv, blk['out_gate'], act='swish')
     conv = _tmp_var()
-    ins, outs = stateful(state and state[1])
+    ins, outs = _state_io(at, i, 1)
     _block_op('short_conv',
               inputs=dict(ins, X=[qkv], W=[_param(
                   blk['conv'], [spec.conv_kernel, spec.conv_dim])]),
               outputs=dict(outs, Out=[conv]))
     o = _tmp_var()
-    ins, outs = stateful(state and state[0])
-    _block_op(delta_type,
+    ins, outs = _state_io(at, i, 0)
+    step = at is not None and at.decode
+    _block_op('gated_delta_step' if step else 'gated_delta_chunk',
               inputs=dict(ins, QKV=[conv], BA=[ba],
                           ALog=[_param(blk['a_log'], [h])],
                           DtBias=[_param(blk['dt_bias'], [h])]),
@@ -207,8 +205,11 @@ def _qk_norm(spec, blk):
     return lambda part, which: _rms(part, spec, blk[which + '_norm'])
 
 
-def _full_attention(x, spec, blk):
-    """Whole-sequence causal attention (the source program's form)."""
+def _attention(x, spec, blk, i, at=None):
+    """Causal attention with q and k normed: over layer i's pages, or
+    over the whole sequence (the source program's form)."""
+    if at is not None:
+        return _paged_attention(x, spec, blk, i, at, _qk_norm(spec, blk))
     t = spec.max_len
     q4, k4, v4 = _qkv_parts(x, spec, blk, t, _qk_norm(spec, blk))
     q, k, v = (L.transpose(a, perm=[0, 2, 1, 3]) for a in (q4, k4, v4))
@@ -219,10 +220,13 @@ def _full_attention(x, spec, blk):
     return _named_fc(ctx, spec.dim, blk['proj'])
 
 
-def _block(x, spec, i, mixer):
+_MIXERS = {'linear_attention': _linear_mixer, 'full_attention': _attention}
+
+
+def _block(x, spec, i, at):
     blk = spec.blocks[i]
-    x = L.elementwise_add(x, _rms(mixer(x, spec, blk), spec,
-                                  blk['mixer_norm']))
+    mixed = _MIXERS[spec.kinds[i]](x, spec, blk, i, at)
+    x = L.elementwise_add(x, _rms(mixed, spec, blk['mixer_norm']))
     mlp = L.elementwise_mul(
         _named_fc(x, spec.ffn, blk['gate'], act='swish'),
         _named_fc(x, spec.ffn, blk['up']))
@@ -230,125 +234,18 @@ def _block(x, spec, i, mixer):
     return L.elementwise_add(x, _rms(mlp, spec, blk['mlp_norm']))
 
 
-def _model(tokens, spec, mixers, last=None):
-    """Embedding -> blocks -> final norm -> head. `mixers` maps a layer
-    kind to its mixer; `last` gathers one row a sequence before the
-    head (the prefill's logits)."""
+def _model(tokens, spec, at=None):
+    """Embedding -> blocks -> final norm -> head: the whole sequence
+    from zero state, or one paged program's rows (`at`: PagedStep)."""
     x = L.embedding(tokens, size=[spec.vocab, spec.dim],
                     param_attr=_named_attr(spec.emb_w))
-    for i, kind in enumerate(spec.kinds):
-        x = _block(x, spec, i,
-                   lambda h, sp, blk, _i=i, _k=kind: mixers[_k](h, sp, blk,
-                                                                _i))
-    x = _rms(x, spec, spec.final_ln[0])
-    if last is None:
-        return _named_fc(x, spec.vocab, spec.head)
-    gathered = _tmp_var()
-    _block_op('gather_time', inputs={'X': [x], 'Index': [last]},
-              outputs={'Out': [gathered]})
-    return _named_fc(gathered, spec.vocab, spec.head, num_flatten_dims=1)
+    for i in range(spec.layers):
+        x = _block(x, spec, i, at)
+    return _logits_head(_rms(x, spec, spec.final_ln[0]), spec, at)
 
 
 def language_model_logits(tokens, cfg):
     """tokens [B, T, 1] int64 (T = cfg.max_len) -> logits [B, T, vocab],
     every sequence from zero state."""
-    spec = spec_from_config(cfg)
-    return _model(tokens, spec, {
-        'linear_attention': lambda x, sp, blk, i: _linear_mixer(
-            x, sp, blk, sp.max_len, 'gated_delta_chunk'),
-        'full_attention': lambda x, sp, blk, i: _full_attention(x, sp, blk)})
-
-
-# -- the paged pair ------------------------------------------------------------
-
-def _create_state_vars(spec, slots):
-    """{layer: (delta state, convolution rows)} of the recurrent layers:
-    persistable, donated and updated in place like the page pools, and
-    never checkpointed."""
-    from ..framework import default_main_program
-    block = default_main_program().global_block()
-    return {i: tuple(
-        block.create_var(name=n, shape=shape, dtype='float32',
-                         persistable=True, stop_gradient=True,
-                         is_cache=True)
-        for n, shape in zip(spec.state_names(i), spec.state_shapes(slots)))
-        for i in spec.recurrent_layers}
-
-
-def _data(name, shape, dtype='int32'):
-    return L.data(name, shape, append_batch_size=False, dtype=dtype)
-
-
-def build_paged_prefill_program(spec, slots, chunk, num_pages, page_tokens,
-                                pages_per_slot):
-    """One prefill chunk of one stream: models/transformer.py's paged
-    prefill feeds, and two more for the recurrent layers:
-    prefill_state_slot [1] (the slot whose state the chunk starts from
-    and leaves behind) and prefill_state_reset [1] (1 on a stream's
-    first chunk: start from zero state, whatever the slot held). Rows
-    from prefill_len on leave no trace in either kind of state.
-    Returns (program, feed_names, fetch_vars[logits, ids])."""
-    from ..framework import Program, program_guard
-    prog, startup = Program(), Program()
-    prog._is_test = True
-    with program_guard(prog, startup):
-        tokens = _data('prefill_tokens', [1, chunk, 1], 'int64')
-        positions = _data('prefill_positions', [chunk])
-        length = _data('prefill_len', [1])
-        last = _data('prefill_last', [1])
-        table = _data('prefill_page_table', [1, pages_per_slot])
-        cow_src = _data('prefill_cow_src', [1])
-        cow_dst = _data('prefill_cow_dst', [1])
-        slot = _data('prefill_state_slot', [1])
-        reset = _data('prefill_state_reset', [1])
-        pools = _create_pool_vars(spec, num_pages, page_tokens)
-        states = _create_state_vars(spec, slots)
-
-        def linear(x, sp, blk, i):
-            return _linear_mixer(
-                x, sp, blk, chunk, 'gated_delta_chunk', states[i],
-                {'Slot': [slot], 'Len': [length], 'Reset': [reset]})
-
-        logits = _model(tokens, spec, {
-            'linear_attention': linear,
-            'full_attention': lambda x, sp, blk, i: _paged_prefill_attention(
-                x, sp, blk, pools[i], table, positions, length, cow_src,
-                cow_dst, chunk, _qk_norm(sp, blk))}, last=last)
-        ids = L.argmax(logits, axis=-1)
-    return prog, ['prefill_tokens', 'prefill_positions', 'prefill_len',
-                  'prefill_last', 'prefill_page_table', 'prefill_cow_src',
-                  'prefill_cow_dst', 'prefill_state_slot',
-                  'prefill_state_reset'], [logits, ids]
-
-
-def build_paged_decode_program(spec, slots, num_pages, page_tokens,
-                               pages_per_slot):
-    """One token a lane over the whole slot pool:
-    models/transformer.py's paged decode feeds (the carried token's
-    pair among them; no copy-on-write pair: the program copies no
-    page), and decode_state_live
-    [slots] (1 for the lanes that take part: the others' recurrent state
-    stays as it was, as their K/V writes land on the null page).
-    Returns (program, feed_names, fetch_vars[logits, ids])."""
-    from ..framework import Program, program_guard
-    prog, startup = Program(), Program()
-    prog._is_test = True
-    with program_guard(prog, startup):
-        tokens = _paged_decode_tokens(slots)
-        step_idx = _data('decode_step_idx', [slots])
-        table = _data('decode_page_table', [slots, pages_per_slot])
-        live = _data('decode_state_live', [slots])
-        pools = _create_pool_vars(spec, num_pages, page_tokens)
-        states = _create_state_vars(spec, slots)
-
-        def linear(x, sp, blk, i):
-            return _linear_mixer(x, sp, blk, 1, 'gated_delta_step',
-                                 states[i], {'Live': [live]})
-
-        logits3 = _model(tokens, spec, {
-            'linear_attention': linear,
-            'full_attention': lambda x, sp, blk, i: _paged_decode_attention(
-                x, sp, blk, pools[i], table, step_idx, _qk_norm(sp, blk))})
-        logits = L.reshape(logits3, shape=[-1, spec.vocab])
-        ids = L.argmax(logits, axis=-1)
-    return prog, PAGED_DECODE_FEEDS + ['decode_state_live'], [logits, ids]
+    describe_served_model(tokens.block.program, 'hybrid', cfg)
+    return _model(tokens, spec_from_config(cfg))

@@ -26,30 +26,30 @@ shared expert at the full width.
 `kv_heads` K/V heads of `head_dim`, causal softmax attention, W_o; no
 positional term.
 
-Three programs come from the one block code, as in models/hybrid.py:
-language_model_logits (what save_inference_model writes and the
-DecodeTranspiler reads) and the paged serving pair. K/V pools exist
-for the full-attention layers only, [pages, page_tokens, kv_heads,
-head_dim]; each mamba layer keeps, per slot, its state [slots, H, P, N]
-and the convolution's last K-1 input rows [slots, K-1, H P + 2 G N] as
-scope variables that both programs update in place; an expert layer
-keeps nothing for a stream. Each program of the pair also returns, as
-a third fetch, what its expert layers counted in the call ([4] int32:
-pairs of token and held expert, held experts with a pair, pairs not
-computed, layers): PagedDecodePredictor leaves it on the device and
-sums it when asked (moe_counters()).
+Three programs come from the one block walk (_model), as in
+models/hybrid.py: language_model_logits (what save_inference_model
+writes, with the description the DecodeTranspiler reads the model from)
+and, through NemotronHDecodeSpec.paged_logits, the paged serving pair
+(models/transformer.build_paged_prefill_program and
+build_paged_decode_program). K/V pools exist for the full-attention
+layers only, [pages, page_tokens, kv_heads, head_dim]; each mamba layer
+keeps, per slot, its state [slots, H, P, N] and the convolution's last
+K-1 input rows [slots, K-1, H P + 2 G N] as scope variables that both
+programs update in place; an expert layer keeps nothing for a stream.
+Each program of the pair also returns, as a third fetch, what its
+expert layers counted in the call ([4] int32: pairs of token and held
+expert, held experts with a pair, pairs not computed, layers):
+PagedDecodePredictor leaves it on the device and sums it when asked
+(moe_counters()).
 """
 from __future__ import annotations
 
-import numpy as np
-
 from .. import layers as L
-from .hybrid import (HybridDecodeSpec, _create_state_vars, _data, _param,
-                     _rms)
-from .transformer import (PAGED_DECODE_FEEDS, DecodeSpec, _block_op,
-                          _create_pool_vars, _named_attr, _named_fc,
-                          _paged_decode_attention, _paged_decode_tokens,
-                          _paged_prefill_attention, _qkv_parts, _tmp_var)
+from . import describe_served_model
+from .hybrid import HybridDecodeSpec, _param, _rms
+from .transformer import (DecodeSpec, _block_op, _expert_io, _logits_head,
+                          _named_attr, _named_fc, _paged_attention,
+                          _qkv_parts, _state_io, _tmp_var)
 
 KINDS = ('mamba', 'experts', 'full_attention')
 # hybrid_override_pattern's letters
@@ -75,6 +75,9 @@ class NemotronHConfig(object):
         self.top_k, self.routed_scale = top_k, routed_scale
         self.latent, self.expert_ffn = latent, expert_ffn
         self.shared_ffn, self.eps = shared_ffn, eps
+
+
+Config = NemotronHConfig
 
 
 class NemotronHDecodeSpec(DecodeSpec):
@@ -130,12 +133,8 @@ class NemotronHDecodeSpec(DecodeSpec):
     # every name in blocks, whatever the roles: the hybrid spec's walk
     param_names = HybridDecodeSpec.param_names
 
-    def build_paged_programs(self, slots, chunk, num_pages, page_tokens,
-                             pages_per_slot):
-        return build_paged_prefill_program(
-            self, slots, chunk, num_pages, page_tokens, pages_per_slot) + \
-            build_paged_decode_program(
-                self, slots, num_pages, page_tokens, pages_per_slot)
+    def paged_logits(self, tokens, at):
+        return _model(tokens, self, at)
 
 
 _ROLES = {
@@ -165,35 +164,28 @@ def spec_from_config(cfg):
 
 # -- the block ---------------------------------------------------------------
 
-def _mamba_mixer(x, spec, blk, t, ssd_type, state=None, at=None):
-    """The state-space mixer around its two stateful ops. `state` is the
-    layer's (state, convolution rows) pair, which both ops read and
-    write in place, and `at` the inputs that say where and how
-    (Slot/Len/Reset for a chunk, Live for a step); neither for the
-    whole-sequence form."""
+def _mamba_mixer(x, spec, blk, i, at=None):
+    """The state-space mixer around its two stateful ops, which read and
+    write layer i's (state, convolution rows) in place where `at` says
+    (a chunk's slot, or the live lanes of a step); the whole sequence
+    from zero state without one."""
     c = spec.cfg
     h, inner, conv_dim = c.mamba_heads, spec.inner, spec.conv_dim
-
-    def stateful(var):
-        if state is None:
-            return {}, {}
-        return dict(at, State=[var]), {'StateOut': [var]}
-
     zxd = _named_fc(x, 2 * inner + 2 * c.groups * c.state + h, blk['in'])
     z = L.slice(zxd, axes=[2], starts=[0], ends=[inner])
     xbc = L.slice(zxd, axes=[2], starts=[inner], ends=[inner + conv_dim])
     dt = L.slice(zxd, axes=[2], starts=[inner + conv_dim],
                  ends=[inner + conv_dim + h])
     conv = _tmp_var()
-    ins, outs = stateful(state and state[1])
+    ins, outs = _state_io(at, i, 1)
     _block_op('short_conv',
               inputs=dict(ins, X=[xbc],
                           W=[_param(blk['conv'], [c.conv_kernel, conv_dim])],
                           Bias=[_param(blk['conv_bias'], [conv_dim])]),
               outputs=dict(outs, Out=[conv]))
     y = _tmp_var()
-    ins, outs = stateful(state and state[0])
-    _block_op(ssd_type,
+    ins, outs = _state_io(at, i, 0)
+    _block_op('ssd_step' if at and at.decode else 'ssd_chunk',
               inputs=dict(ins, XBC=[conv], DT=[dt],
                           ALog=[_param(blk['a_log'], [h])],
                           DtBias=[_param(blk['dt_bias'], [h])],
@@ -211,17 +203,13 @@ def _mamba_mixer(x, spec, blk, t, ssd_type, state=None, at=None):
     return _named_fc(gated, spec.dim, blk['out'])
 
 
-def _experts_mixer(x, spec, blk, stats=None, at=None):
+def _experts_mixer(x, spec, blk, i, at=None):
     """The expert layer: the latent projections and the shared expert
-    as plain matmuls around op moe_experts. `stats` is the list the
-    layer's counts are appended to and `at` the input that marks dead
-    rows (Live or Len); neither for the whole-sequence form."""
+    as plain matmuls around op moe_experts, which passes over the dead
+    rows and counts the others where `at` says which those are."""
     c = spec.cfg
     lat = _named_fc(x, c.latent, blk['down'])
-    ins, outs = dict(at or {}), {}
-    if stats is not None:
-        stats.append(_tmp_var('int32'))
-        outs['Stats'] = [stats[-1]]
+    ins, outs = _expert_io(at)
     routed = _tmp_var()
     _block_op('moe_experts',
               inputs=dict(
@@ -241,15 +229,18 @@ def _experts_mixer(x, spec, blk, stats=None, at=None):
         routed, _named_fc(shared, spec.dim, blk['shared_down']))
 
 
-def _full_attention(x, spec, blk):
-    """Whole-sequence causal attention (the source program's form): the
-    query heads of one K/V head are rows of one product."""
+def _attention(x, spec, blk, i, at=None):
+    """Causal attention over layer i's pages, or over the whole
+    sequence (the source program's form): the query heads of one K/V
+    head are rows of one product."""
+    if at is not None:
+        return _paged_attention(x, spec, blk, i, at)
     t, h, kvh, dh = spec.max_len, spec.heads, spec.kv_heads, spec.dh
     rep = h // kvh
     q4, k4, v4 = _qkv_parts(x, spec, blk, t)
     q, k, v = (L.transpose(a, perm=[0, 2, 1, 3]) for a in (q4, k4, v4))
     q = L.reshape(q, shape=[-1, kvh, rep * t, dh])
-    scores = L.matmul(q, k, transpose_y=True, alpha=1.0 / np.sqrt(dh))
+    scores = L.matmul(q, k, transpose_y=True, alpha=spec.sm_scale)
     scores = L.reshape(scores, shape=[-1, h, t, t])
     probs = L.softmax(L.causal_mask_bias(scores))
     ctx = L.matmul(L.reshape(probs, shape=[-1, kvh, rep * t, t]), v)
@@ -259,116 +250,24 @@ def _full_attention(x, spec, blk):
                      blk['proj'])
 
 
-def _model(tokens, spec, mixers, last=None):
-    """Embedding -> layers -> final norm -> head. `mixers` maps a layer
-    kind to its mixer; `last` gathers one row a sequence before the
-    head (the prefill's logits)."""
+_MIXERS = {'mamba': _mamba_mixer, 'experts': _experts_mixer,
+           'full_attention': _attention}
+
+
+def _model(tokens, spec, at=None):
+    """Embedding -> layers -> final norm -> head: the whole sequence
+    from zero state, or one paged program's rows (`at`: PagedStep)."""
     x = L.embedding(tokens, size=[spec.vocab, spec.dim],
                     param_attr=_named_attr(spec.emb_w))
     for i, kind in enumerate(spec.kinds):
         blk = spec.blocks[i]
         x = L.elementwise_add(
-            x, mixers[kind](_rms(x, spec, blk['norm']), spec, blk, i))
-    x = _rms(x, spec, spec.final_ln[0])
-    if last is None:
-        return _named_fc(x, spec.vocab, spec.head)
-    gathered = _tmp_var()
-    _block_op('gather_time', inputs={'X': [x], 'Index': [last]},
-              outputs={'Out': [gathered]})
-    return _named_fc(gathered, spec.vocab, spec.head, num_flatten_dims=1)
+            x, _MIXERS[kind](_rms(x, spec, blk['norm']), spec, blk, i, at))
+    return _logits_head(_rms(x, spec, spec.final_ln[0]), spec, at)
 
 
 def language_model_logits(tokens, cfg):
     """tokens [B, T, 1] int64 (T = cfg.max_len) -> logits [B, T, vocab],
     every sequence from zero state."""
-    spec = spec_from_config(cfg)
-    return _model(tokens, spec, {
-        'mamba': lambda x, sp, blk, i: _mamba_mixer(
-            x, sp, blk, sp.max_len, 'ssd_chunk'),
-        'experts': lambda x, sp, blk, i: _experts_mixer(x, sp, blk),
-        'full_attention': lambda x, sp, blk, i: _full_attention(x, sp, blk)})
-
-
-# -- the paged pair ------------------------------------------------------------
-
-def _fetches(logits, ids, stats):
-    """[logits, ids], and the expert layers' counts summed over the
-    layers where there are any."""
-    if not stats:
-        return [logits, ids]
-    total = stats[0]
-    for one in stats[1:]:
-        total = L.elementwise_add(total, one)
-    return [logits, ids, total]
-
-
-def build_paged_prefill_program(spec, slots, chunk, num_pages, page_tokens,
-                                pages_per_slot):
-    """One prefill chunk of one stream: models/hybrid.py's paged prefill
-    feeds (prefill_state_slot and prefill_state_reset among them).
-    Rows from prefill_len on leave no trace in any state and are not
-    counted by the expert layers.
-    Returns (program, feed_names, fetch_vars[logits, ids, counts])."""
-    from ..framework import Program, program_guard
-    prog, startup = Program(), Program()
-    prog._is_test = True
-    with program_guard(prog, startup):
-        tokens = _data('prefill_tokens', [1, chunk, 1], 'int64')
-        positions = _data('prefill_positions', [chunk])
-        length = _data('prefill_len', [1])
-        last = _data('prefill_last', [1])
-        table = _data('prefill_page_table', [1, pages_per_slot])
-        cow_src = _data('prefill_cow_src', [1])
-        cow_dst = _data('prefill_cow_dst', [1])
-        slot = _data('prefill_state_slot', [1])
-        reset = _data('prefill_state_reset', [1])
-        pools = _create_pool_vars(spec, num_pages, page_tokens)
-        states = _create_state_vars(spec, slots)
-        stats = []
-
-        logits = _model(tokens, spec, {
-            'mamba': lambda x, sp, blk, i: _mamba_mixer(
-                x, sp, blk, chunk, 'ssd_chunk', states[i],
-                {'Slot': [slot], 'Len': [length], 'Reset': [reset]}),
-            'experts': lambda x, sp, blk, i: _experts_mixer(
-                x, sp, blk, stats, {'Len': [length]}),
-            'full_attention': lambda x, sp, blk, i: _paged_prefill_attention(
-                x, sp, blk, pools[i], table, positions, length, cow_src,
-                cow_dst, chunk)}, last=last)
-        fetches = _fetches(logits, L.argmax(logits, axis=-1), stats)
-    return prog, ['prefill_tokens', 'prefill_positions', 'prefill_len',
-                  'prefill_last', 'prefill_page_table', 'prefill_cow_src',
-                  'prefill_cow_dst', 'prefill_state_slot',
-                  'prefill_state_reset'], fetches
-
-
-def build_paged_decode_program(spec, slots, num_pages, page_tokens,
-                               pages_per_slot):
-    """One token a lane over the whole slot pool: models/hybrid.py's
-    paged decode feeds (no copy-on-write pair: the program copies no
-    page). decode_state_live marks the lanes that take
-    part: the others' state stays as it was, and the expert layers
-    neither count nor weigh their rows.
-    Returns (program, feed_names, fetch_vars[logits, ids, counts])."""
-    from ..framework import Program, program_guard
-    prog, startup = Program(), Program()
-    prog._is_test = True
-    with program_guard(prog, startup):
-        tokens = _paged_decode_tokens(slots)
-        step_idx = _data('decode_step_idx', [slots])
-        table = _data('decode_page_table', [slots, pages_per_slot])
-        live = _data('decode_state_live', [slots])
-        pools = _create_pool_vars(spec, num_pages, page_tokens)
-        states = _create_state_vars(spec, slots)
-        stats = []
-
-        logits3 = _model(tokens, spec, {
-            'mamba': lambda x, sp, blk, i: _mamba_mixer(
-                x, sp, blk, 1, 'ssd_step', states[i], {'Live': [live]}),
-            'experts': lambda x, sp, blk, i: _experts_mixer(
-                x, sp, blk, stats, {'Live': [live]}),
-            'full_attention': lambda x, sp, blk, i: _paged_decode_attention(
-                x, sp, blk, pools[i], table, step_idx)})
-        logits = L.reshape(logits3, shape=[-1, spec.vocab])
-        fetches = _fetches(logits, L.argmax(logits, axis=-1), stats)
-    return prog, PAGED_DECODE_FEEDS + ['decode_state_live'], fetches
+    describe_served_model(tokens.block.program, 'nemotron_h', cfg)
+    return _model(tokens, spec_from_config(cfg))

@@ -266,6 +266,7 @@ class DecodeSpec(object):
     """
 
     recurrent_kinds = ()
+    expert_layers = ()      # the layers whose rows an expert op counts
     state_family = None     # names the gauge serving.<family>.state_bytes
     page_kind = 'kv'        # what a page of kv_layers holds
 
@@ -329,9 +330,26 @@ class DecodeSpec(object):
         """The paged pair that serves this spec's block, as (prefill
         program, feeds, fetches, decode program, feeds, fetches)."""
         return build_paged_prefill_program(
-            self, chunk, num_pages, page_tokens, pages_per_slot) + \
+            self, slots, chunk, num_pages, page_tokens, pages_per_slot) + \
             build_paged_decode_program(
                 self, slots, num_pages, page_tokens, pages_per_slot)
+
+    def paged_logits(self, tokens, at):
+        """This block's walk over one paged program (`at`: PagedStep):
+        logits of the chunk's last live row [1, vocab], or of every
+        lane's one row [slots, 1, vocab]. What a block file overrides
+        with its own walk."""
+        emb = L.embedding(tokens, size=[self.vocab, self.dim],
+                          param_attr=_named_attr(self.emb_w))
+        pos = _paged_pos_embedding(self, at.positions)         # [rows, 1, D]
+        if not at.decode:
+            pos = L.reshape(pos, shape=[-1, at.rows, self.dim])    # [1, C, D]
+        x = L.elementwise_add(emb, pos)
+        for i in range(self.layers):
+            x = _cached_block(
+                x, self, i, lambda ln, sp, blk, _i=i: _paged_attention(
+                    ln, sp, blk, _i, at))
+        return _logits_head(_named_ln(x, self.final_ln), self, at)
 
     def pool_spec(self):
         """PartitionSpec (tuple form) for the page pools
@@ -470,23 +488,75 @@ def _cached_block(x, spec, i, attention):
 # copied; verify gathers every slot's.
 
 
+class PagedStep(object):
+    """What a paged program hands its block walk (spec.paged_logits):
+    where THIS program's sublayers find K/V pages, recurrent state and
+    the rows that count. A prefill chunk (`decode` False) is `rows` rows
+    of ONE stream: table [1, P], positions [rows], length [1] (the rows
+    from it on are padding), last [1] (the row whose logits are
+    wanted), cow (src, dst: the page to copy before the write) and,
+    for a model with recurrent state, slot [1] and reset [1]. A decode
+    step is one row of EVERY lane: table [slots, P], positions [slots]
+    (the step index) and, for a model with recurrent state or expert
+    layers, live [slots]. pools and states are {layer: its variables};
+    stats collects what the expert layers counted. A sublayer asks this
+    value, never the program's name."""
+
+    length = last = cow = slot = reset = live = None
+
+    def __init__(self, decode, rows):
+        self.decode, self.rows = decode, rows
+        self.stats = []
+
+
+def _state_io(at, layer, which):
+    """(inputs, outputs) that make a stateful op read and write
+    `layer`'s state variable `which` in place; nothing for the
+    whole-sequence form (at None), which starts every sequence from
+    zero state and keeps none."""
+    if at is None:
+        return {}, {}
+    var = at.states[layer][which]
+    where = {'Live': [at.live]} if at.decode else \
+        {'Slot': [at.slot], 'Len': [at.length], 'Reset': [at.reset]}
+    return dict(where, State=[var]), {'StateOut': [var]}
+
+
+def _expert_io(at):
+    """(inputs, outputs) that make an expert op pass over the dead rows
+    and count the others; nothing for the whole-sequence form."""
+    if at is None:
+        return {}, {}
+    at.stats.append(_tmp_var('int32'))
+    rows = {'Live': [at.live]} if at.decode else {'Len': [at.length]}
+    return rows, {'Stats': [at.stats[-1]]}
+
+
+def _persistable(name, shape):
+    from ..framework import default_main_program
+    return default_main_program().global_block().create_var(
+        name=name, shape=shape, dtype='float32', persistable=True,
+        stop_gradient=True, is_cache=True)
+
+
 def _create_pool_vars(spec, num_pages, page_tokens):
     """{layer: its page-pool vars} ((K, V), or the one pool of a layer
     that keeps a latent page) of the layers that keep pages:
     persistable (the executor writes them back to the Scope each run —
     and donates them, so the update is in-place on device) but is_cache
     (io.py save/load skip them)."""
-    from ..framework import default_main_program
-    block = default_main_program().global_block()
-    pools = {}
-    for i in spec.kv_layers:
-        pools[i] = tuple(
-            block.create_var(name=n,
-                             shape=spec.pool_shape(num_pages, page_tokens),
-                             dtype='float32', persistable=True,
-                             stop_gradient=True, is_cache=True)
-            for n in spec.pool_names(i))
-    return pools
+    shape = spec.pool_shape(num_pages, page_tokens)
+    return {i: tuple(_persistable(n, shape) for n in spec.pool_names(i))
+            for i in spec.kv_layers}
+
+
+def _create_state_vars(spec, slots):
+    """{layer: (the recurrence's state, convolution rows)} of the
+    recurrent layers: persistable, donated and updated in place like
+    the page pools, and never checkpointed."""
+    return {i: tuple(_persistable(n, shape) for n, shape in
+                     zip(spec.state_names(i), spec.state_shapes(slots)))
+            for i in spec.recurrent_layers}
 
 
 def _pool_heads(x, spec):
@@ -516,11 +586,20 @@ def _paged_gather(pool_var, table, spec):
                                (None, _tp_ax(spec), None, None))
 
 
-def _paged_prefill_attention(x, spec, blk, pool, table, positions,
-                             length, cow_src, cow_dst, chunk, qk_norm=None):
+def _paged_attention(x, spec, blk, i, at, qk_norm=None):
+    """Layer i's attention over its K/V pages, in the form `at`'s
+    program takes: a prefill chunk's or a decode step's."""
+    form = _paged_decode_attention if at.decode else _paged_prefill_attention
+    return form(x, spec, blk, at.pools[i], at, qk_norm)
+
+
+def _paged_prefill_attention(x, spec, blk, pool, at, qk_norm=None):
     """One chunk of prefill attention: COW any forked page, scatter the
     chunk's K/V rows through the table, then attend the chunk's queries
     over the WHOLE gathered history (earlier pages + this chunk)."""
+    table, positions, length, chunk = (at.table, at.positions, at.length,
+                                       at.rows)
+    cow_src, cow_dst = at.cow
     q4, k4, v4 = (_pool_heads(a, spec)
                   for a in _qkv_parts(x, spec, blk, chunk,
                                       qk_norm))         # [1, C, H, dh]
@@ -565,14 +644,14 @@ def _paged_prefill_attention(x, spec, blk, pool, table, positions,
     return _named_fc(ctx, spec.dim, blk['proj'])
 
 
-def _paged_decode_attention(x, spec, blk, pool, table, positions,
-                            qk_norm=None):
+def _paged_decode_attention(x, spec, blk, pool, at, qk_norm=None):
     """One decode step's attention: append the new K/V row, then ONE
     paged_attention op that reads each lane's live pages through its
     table (no gathered window; see the op's docstring for its two
     lowerings). No copy-on-write here: a page that forks in a decode
     step was copied before the step's program was dispatched
     (build_page_copy_program)."""
+    table, positions = at.table, at.positions
     q1, k1, v1 = (_pool_heads(a, spec)
                   for a in _qkv_parts(x, spec, blk, 1,
                                       qk_norm))   # [S, 1, H | KVH, dh]
@@ -629,7 +708,7 @@ def _paged_verify_attention(x, spec, blk, pool, table, positions,
     return _named_fc(ctx, spec.dim, blk['proj'])
 
 
-def _paged_pos_embedding(spec, index, rows):
+def _paged_pos_embedding(spec, index):
     """Positional rows gathered by absolute index (paged positions
     never wrap): Index [rows] -> [1, rows, D] / [rows, 1, D]. This
     block's only positional term: a learned row added at the embedding.
@@ -648,9 +727,55 @@ def _paged_pos_embedding(spec, index, rows):
     return pos
 
 
-def build_paged_prefill_program(spec, chunk, num_pages, page_tokens,
+def _logits_head(x, spec, at, head=None):
+    """The normed stream -> logits: of the chunk's last live row
+    [1, vocab] (gather_time by at.last), or of every lane's one row
+    [slots, 1, vocab]. `head(x, flatten)` where the block's head is
+    not a named fc (the tied head of models/granite_h.py)."""
+    head = head or (lambda h, flatten: _named_fc(
+        h, spec.vocab, spec.head, num_flatten_dims=flatten))
+    if at is None or at.decode:
+        return head(x, 2)
+    gathered = _tmp_var()
+    _block_op('gather_time', inputs={'X': [x], 'Index': [at.last]},
+              outputs={'Out': [gathered]})                     # [1, D]
+    return head(gathered, 1)
+
+
+def _paged_fetches(spec, at, tokens, slots, num_pages, page_tokens):
+    """The half both builders share: the pools and state variables,
+    the block's walk, and its fetches: logits [lanes, vocab], greedy
+    ids, and the expert layers' counts summed over the layers where
+    there are any."""
+    at.pools = _create_pool_vars(spec, num_pages, page_tokens)
+    at.states = _create_state_vars(spec, slots)
+    logits = spec.paged_logits(tokens, at)
+    if at.decode:
+        logits = L.reshape(logits, shape=[-1, spec.vocab])
+    fetches = [logits, L.argmax(logits, axis=-1)]
+    if at.stats:
+        total = at.stats[0]
+        for one in at.stats[1:]:
+            total = L.elementwise_add(total, one)
+        fetches.append(total)
+    return fetches
+
+
+def _feeds():
+    """(declare, names): declare(name, shape, dtype) is a feed variable
+    and names the list of those declared, in order."""
+    names = []
+
+    def declare(name, shape, dtype='int32'):
+        names.append(name)
+        return L.data(name, shape, append_batch_size=False, dtype=dtype)
+    return declare, names
+
+
+def build_paged_prefill_program(spec, slots, chunk, num_pages, page_tokens,
                                 pages_per_slot):
-    """One prefill CHUNK through one stream's page table.
+    """One prefill CHUNK through one stream's page table, for whatever
+    block `spec` is of (its walk: spec.paged_logits).
 
     Feeds:  prefill_tokens [1, C, 1] int64 (chunk tokens, zero-padded),
             prefill_positions [C] int32 (absolute position per row —
@@ -661,122 +786,92 @@ def build_paged_prefill_program(spec, chunk, num_pages, page_tokens,
             prefill_page_table [1, P] int32 (the stream's table; entries
             past the written extent are 0, the null page),
             prefill_cow_src / prefill_cow_dst [1] int32 (page copy to
-            apply before the write — (0, 0) when no fork this chunk).
+            apply before the write — (0, 0) when no fork this chunk),
+            and for a model with recurrent state (spec.state_names(),
+            one set a slot: `slots`) prefill_state_slot [1] (the slot
+            whose state the chunk starts from and leaves behind) and
+            prefill_state_reset [1] (1 on a stream's first chunk: start
+            from zero state, whatever the slot held).
     The same program serves chunked prefill AND prefix-hit suffix
     prefill: shared pages arrive pre-populated in the table and the
     chunk simply starts at the first unshared position. Logits are the
-    last live row's — only the FINAL chunk's logits mean anything.
-    Returns (program, feed_names, fetch_vars[logits, ids]).
+    last live row's — only the FINAL chunk's logits mean anything. Rows
+    from prefill_len on leave no trace in pages or state and are not
+    counted by the expert layers.
+    Returns (program, feed_names, fetch_vars[logits, ids(, counts)]).
     """
     from ..framework import Program, program_guard
     prog, startup = Program(), Program()
     prog._is_test = True
     with program_guard(prog, startup):
-        tokens = L.data('prefill_tokens', [1, chunk, 1],
-                        append_batch_size=False, dtype='int64')
-        positions = L.data('prefill_positions', [chunk],
-                           append_batch_size=False, dtype='int32')
-        length = L.data('prefill_len', [1],
-                        append_batch_size=False, dtype='int32')
-        last = L.data('prefill_last', [1],
-                      append_batch_size=False, dtype='int32')
-        table = L.data('prefill_page_table', [1, pages_per_slot],
-                       append_batch_size=False, dtype='int32')
-        cow_src = L.data('prefill_cow_src', [1],
-                         append_batch_size=False, dtype='int32')
-        cow_dst = L.data('prefill_cow_dst', [1],
-                         append_batch_size=False, dtype='int32')
-        pools = _create_pool_vars(spec, num_pages, page_tokens)
-        emb = L.embedding(tokens, size=[spec.vocab, spec.dim],
-                          param_attr=_named_attr(spec.emb_w))  # [1, C, D]
-        pos = _paged_pos_embedding(spec, positions, chunk)     # [C, 1, D]
-        pos = L.reshape(pos, shape=[-1, chunk, spec.dim])      # [1, C, D]
-        x = L.elementwise_add(emb, pos)
-        for i in range(spec.layers):
-            x = _cached_block(
-                x, spec, i,
-                lambda ln, sp, blk, _i=i: _paged_prefill_attention(
-                    ln, sp, blk, pools[_i], table, positions, length,
-                    cow_src, cow_dst, chunk))
-        x = _named_ln(x, spec.final_ln)
-        gathered = _tmp_var()
-        _block_op('gather_time',
-                  inputs={'X': [x], 'Index': [last]},
-                  outputs={'Out': [gathered]})                 # [1, D]
-        logits = _named_fc(gathered, spec.vocab, spec.head,
-                           num_flatten_dims=1)                 # [1, V]
-        ids = L.argmax(logits, axis=-1)
-    return prog, ['prefill_tokens', 'prefill_positions', 'prefill_len',
-                  'prefill_last', 'prefill_page_table',
-                  'prefill_cow_src', 'prefill_cow_dst'], [logits, ids]
-
-
-PAGED_DECODE_FEEDS = ['decode_tokens', 'decode_prev_ids', 'decode_carry',
-                      'decode_step_idx', 'decode_page_table']
-
-
-def _paged_decode_tokens(slots):
-    """The token a lane is fed: the host's (decode_tokens), or, where
-    decode_carry is set, the lane's entry of decode_prev_ids, the ids
-    the step before left on the device. One select in front of the
-    embedding lookup, so the step that carries a token on and the one
-    that takes every token from the host are one executable."""
-    tokens = L.data('decode_tokens', [slots, 1, 1],
-                    append_batch_size=False, dtype='int64')
-    prev = L.data('decode_prev_ids', [slots],
-                  append_batch_size=False, dtype='int64')
-    carry = L.data('decode_carry', [slots],
-                   append_batch_size=False, dtype='int32')
-    return L.where_select(L.cast(carry, 'bool'),
-                          L.reshape(prev, shape=[slots, 1, 1]), tokens)
+        feed, names = _feeds()
+        at = PagedStep(decode=False, rows=chunk)
+        tokens = feed('prefill_tokens', [1, chunk, 1], 'int64')
+        at.positions = feed('prefill_positions', [chunk])
+        at.length = feed('prefill_len', [1])
+        at.last = feed('prefill_last', [1])
+        at.table = feed('prefill_page_table', [1, pages_per_slot])
+        at.cow = (feed('prefill_cow_src', [1]), feed('prefill_cow_dst', [1]))
+        if spec.state_names():
+            at.slot = feed('prefill_state_slot', [1])
+            at.reset = feed('prefill_state_reset', [1])
+        fetches = _paged_fetches(spec, at, tokens, slots, num_pages,
+                                 page_tokens)
+    return prog, names, fetches
 
 
 def build_paged_decode_program(spec, slots, num_pages, page_tokens,
                                pages_per_slot):
-    """One-token decode step over the whole slot pool, page-indexed.
+    """One-token decode step over the whole slot pool, page-indexed, for
+    whatever block `spec` is of.
 
     Feeds:  decode_tokens [slots, 1, 1] int64,
             decode_prev_ids [slots] int64 and decode_carry [slots]
             int32 (a lane with carry set is fed its entry of prev_ids —
             the greedy ids an earlier step left on the device — and not
             its decode_tokens entry: serving/paged.py dispatches a step
-            before it has fetched the one before),
+            before it has fetched the one before; one select in front
+            of the embedding lookup, so the step that carries a token on
+            and the one that takes every token from the host are one
+            executable),
             decode_step_idx [slots] int32 (absolute position of the
             incoming token: the write lands at
             pool[table[pos // pt], pos % pt], never wrapped),
             decode_page_table [slots, P] int32 (all-zero rows for idle
-            or mid-prefill slots: their appends hit the null page).
+            or mid-prefill slots: their appends hit the null page),
+            and the lanes that take part [slots] int32, which
+            serving/paged.py fills under either of its two names:
+            decode_state_live for a model with recurrent state (the
+            others' state stays as it was, and its expert layers
+            neither count nor weigh their rows), decode_live for one
+            with expert layers alone.
     Admission and page allocation are host decisions that only change
     these feed values — the program compiles exactly once. It copies no
     page: where a lane's append would land on a page it shares, the
     host has run the page copy program (build_page_copy_program) in
     front of this one, and the table already names the copy.
-    Returns (program, feed_names, fetch_vars[logits, ids]).
+    Returns (program, feed_names, fetch_vars[logits, ids(, counts)]).
     """
     from ..framework import Program, program_guard
     prog, startup = Program(), Program()
     prog._is_test = True
     with program_guard(prog, startup):
-        tokens = _paged_decode_tokens(slots)
-        step_idx = L.data('decode_step_idx', [slots],
-                          append_batch_size=False, dtype='int32')
-        table = L.data('decode_page_table', [slots, pages_per_slot],
-                       append_batch_size=False, dtype='int32')
-        pools = _create_pool_vars(spec, num_pages, page_tokens)
-        emb = L.embedding(tokens, size=[spec.vocab, spec.dim],
-                          param_attr=_named_attr(spec.emb_w))  # [S, 1, D]
-        pos = _paged_pos_embedding(spec, step_idx, slots)      # [S, 1, D]
-        x = L.elementwise_add(emb, pos)
-        for i in range(spec.layers):
-            x = _cached_block(
-                x, spec, i,
-                lambda ln, sp, blk, _i=i: _paged_decode_attention(
-                    ln, sp, blk, pools[_i], table, step_idx))
-        x = _named_ln(x, spec.final_ln)
-        logits3 = _named_fc(x, spec.vocab, spec.head)          # [S, 1, V]
-        logits = L.reshape(logits3, shape=[-1, spec.vocab])
-        ids = L.argmax(logits, axis=-1)
-    return prog, list(PAGED_DECODE_FEEDS), [logits, ids]
+        feed, names = _feeds()
+        at = PagedStep(decode=True, rows=1)
+        tokens = feed('decode_tokens', [slots, 1, 1], 'int64')
+        prev = feed('decode_prev_ids', [slots], 'int64')
+        carry = feed('decode_carry', [slots])
+        tokens = L.where_select(L.cast(carry, 'bool'),
+                                L.reshape(prev, shape=[slots, 1, 1]), tokens)
+        at.positions = feed('decode_step_idx', [slots])
+        at.table = feed('decode_page_table', [slots, pages_per_slot])
+        if spec.state_names():
+            at.live = feed('decode_state_live', [slots])
+        elif spec.expert_layers:
+            at.live = feed('decode_live', [slots])
+        fetches = _paged_fetches(spec, at, tokens, slots, num_pages,
+                                 page_tokens)
+    return prog, names, fetches
 
 
 def build_page_copy_program(spec, slots, num_pages, page_tokens):
@@ -907,7 +1002,7 @@ def build_verify_program(spec, slots, k1, num_pages, page_tokens,
         pools = _create_pool_vars(spec, num_pages, page_tokens)
         emb = L.embedding(tokens, size=[spec.vocab, spec.dim],
                           param_attr=_named_attr(spec.emb_w))  # [S, K1, D]
-        pos = _paged_pos_embedding(spec, positions, k1)        # [S, K1, D]
+        pos = _paged_pos_embedding(spec, positions)            # [S, K1, D]
         x = L.elementwise_add(emb, pos)
         for i in range(spec.layers):
             x = _cached_block(

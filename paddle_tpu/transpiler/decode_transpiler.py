@@ -1,16 +1,32 @@
 """DecodeTranspiler: loaded LM program -> paged prefill + decode pair.
 
 The serving-side analog of the DistributeTranspiler: instead of
-rewriting ops in place, it READS the loaded language-model program —
-walking the op sequence the models/transformer.py builders emit — to
-recover the architecture (dims, head count, layer count, flash or
-naive attention) and the exact parameter names, then asks the cached-
-attention builders for two fresh programs that bind those names. Both
-run against the Predictor's existing weight Scope, so transpilation
-moves zero bytes of weights.
+rewriting ops in place, it READS the loaded language-model program for
+its DecodeSpec (dims, layer kinds, the exact parameter names), then
+asks the paged builders of models/transformer.py for two fresh programs
+that bind those names. Both run against the Predictor's existing weight
+Scope, so transpilation moves zero bytes of weights.
 
-Recognized source shape: the decoder-only LM (`language_model_logits`
-/ `language_model`, TP-sharded or not) — lookup_table,
+Where the spec comes from (extract_decode_spec), told by what the
+program holds:
+
+A description. Each block file written for serving (the tuple
+models.SERVED_FAMILIES) states its model once: its Config, and
+spec_from_config(cfg), from which language_model_logits builds the
+whole-sequence program. language_model_logits leaves {'family',
+'config'} on the program (Program.served_model), clone and _prune keep
+it, and it rides inside the `program` entry that save_inference_model
+writes. A program that has one gets spec_from_config(Config(**config))
+of its family: the same spec its builder had, never a guess. The file
+is input from outside, so the spec is held to it as far as it goes:
+every parameter the spec names is a persistable variable of the
+program, and the embedding and the attention weights have the shapes
+the spec says (_check_weights).
+
+The GPT shape, for a program with no description: the decoder-only LM
+of models/transformer.py (`language_model_logits` / `language_model`,
+TP-sharded or not), which was written for training, generates its
+parameter names, and is read by walking its op sequence — lookup_table,
 position_embedding, per block [layer_norm, qkv mul, proj mul,
 layer_norm, up mul, down mul] (+ flash_attention or the
 matmul/causal_mask/softmax triple), final layer_norm, lm_head mul.
@@ -20,76 +36,19 @@ DecodeSpec.param_specs — from dist_attr annotations when the program
 is still in memory, else from the sharding_constraint ops that survive
 save_inference_model (see _recover_param_specs).
 
-A second recognized shape is the hybrid LM of models/hybrid.py
-(`hybrid.language_model_logits`): lookup_table, no position op, per
-block one of two mixers, told apart by their marker op, and a gated
-MLP, every sublayer's output through an rms_norm —
-  linear_attention  [qkv mul, ba mul, out_gate mul, short_conv,
-                    gated_delta_chunk, rms_norm (heads), out mul]
-  full_attention    [qkv mul, rms_norm (q), rms_norm (k),
-                    matmul/causal_mask/softmax, proj mul]
-then [rms_norm, gate mul, up mul, down mul, rms_norm], a final
-rms_norm and the lm_head mul. Its spec (HybridDecodeSpec) carries the
-layer kinds and the delta rule's sizes; its paged pair's programs keep
-K/V pools for the full-attention layers and per-slot recurrent state
-for the others. Speculative decoding,
-page shipping and mesh serving refuse it by the layer kind's name.
-
-A third is the three-kind hybrid of models/nemotron_h.py
-(`nemotron_h.language_model_logits`): lookup_table, no position op,
-every layer one rms_norm and one mixer, told apart by its marker op —
-  mamba           [in mul, short_conv (with Bias), ssd_chunk,
-                  gated_group_norm, out mul]
-  experts         [down mul, moe_experts, up mul, shared-up mul,
-                  shared-down mul]
-  full_attention  [qkv mul, matmul/causal_mask/softmax, proj mul]
-then a final rms_norm and the lm_head mul. Its spec
-(NemotronHDecodeSpec) carries the kinds, the sizes of each and what the
-expert op was told it holds (experts_held, expert_offset: attributes
-of the model, read back from the op); K/V heads fewer than query heads
-are read from the qkv weight's width. The refusals above hold for it
-alike: they ask whether a layer holds recurrent state, not its name.
-
-A fourth is the latent-attention block of models/axk1.py
-(`axk1.language_model_logits`): lookup_table, no position op, every
-layer
-  [rms_norm, q-down mul, rms_norm, q-up mul, rotary_yarn, kv-down mul,
-   rms_norm, rotary_yarn, latent_attention, proj mul, rms_norm]
-then a gated MLP [gate-up mul, down mul] or an expert layer
-[moe_experts with W3, shared gate-up mul, shared down mul], a final
-rms_norm and the lm_head mul. Its spec (AXK1DecodeSpec) carries the
-sizes read from the weights' shapes and the attributes of the
-rotary, attention and expert ops; its pages hold ONE latent row a
-token a layer (page_kind 'latent'), so the prefix cache and page
-shipping serve it, and speculative decoding and mesh serving, which
-read a page as K and V heads, refuse it by that name.
-
-A fifth is the two-sublayer hybrid of models/granite_h.py
-(`granite_h.language_model_logits`), told from the third by the gate
-of its expert op (attr gate 'softmax'): lookup_table and a scale (the
-embedding's multiplier), no position op, every layer
-  [rms_norm, a mamba or a full_attention mixer as in the third, scale,
-   rms_norm, moe_experts with W3, shared gate-up mul, shared down mul,
-   scale]
-then a final rms_norm and ONE matmul with the embedding transposed (the
-tied head; its alpha is 1 / logits_scaling). Its spec
-(GraniteHDecodeSpec) reads the multipliers back from the scale ops and
-from the attention product's alpha. It holds recurrent state, so the
-refusals above hold for it.
-
-Genuinely
-unsupported layouts (the training MoE op moe_ffn, whose capacity drops
-tokens; ring attention; a
-constraint on an axis the serving mesh cannot honor) still raise
+What a spec's layers keep for a stream decides what can serve it, not
+its family's name: speculative decoding and mesh serving refuse
+recurrent state (refuse_recurrent) and latent pages
+(refuse_latent_pages). Genuinely unsupported layouts (the training MoE
+op moe_ffn, whose capacity drops tokens; ring attention; a constraint
+on an axis the serving mesh cannot honor) still raise
 DecodeTranspileError naming the offending op/axis — better a loud
 refusal at prepare time than a silently wrong cache layout at serve
 time.
 """
 from __future__ import annotations
 
-import re
-
-from ..models import axk1, granite_h, hybrid, nemotron_h
+from .. import models
 from ..models.transformer import (DecodeSpec, DecodeTranspileError,
                                   refuse_latent_pages, refuse_recurrent,
                                   build_page_copy_program,
@@ -223,9 +182,12 @@ def _truncate_spec(spec, draft_layers):
 
 def _fail(msg):
     raise DecodeTranspileError(
-        'cannot transpile program for cached decoding: %s (expected a '
-        'decoder-only LM from models.transformer.language_model'
-        '[_logits])' % msg)
+        'cannot transpile program for cached decoding: %s (a program is '
+        'recognised by the description a served family\'s '
+        'language_model_logits leaves on it, models.SERVED_FAMILIES %s, '
+        'or, with none, as the decoder-only LM of '
+        'models.transformer.language_model[_logits])'
+        % (msg, models.SERVED_FAMILIES))
 
 
 # sharding_constraint specs emitted by parallel/layers.py directly
@@ -305,459 +267,56 @@ def _recover_param_specs(block, spec, muls, add_out_of, act_out_of,
     spec.param_specs = {n: specs.get(n) for n in spec.param_names()}
 
 
-def _extract_hybrid_spec(block):
-    """The hybrid LM's spec (see the module docstring): weights and norms
-    in op order, dealt out to layers by each layer's marker op."""
-    emb_w = ids = None
-    muls, norms, markers = [], [], []
-    conv_w = None
-    eps = 1e-6
-    for op in block.ops:
-        t = op.type
-        if t == 'lookup_table' and emb_w is None:
-            emb_w, ids = op.single_input('W'), op.single_input('Ids')
-        elif t == 'mul':
-            muls.append(op.single_input('Y'))
-        elif t == 'rms_norm':
-            norms.append(op.single_input('Scale'))
-            eps = op.attr('epsilon', eps)
-        elif t == 'short_conv':
-            conv_w = op.single_input('W')
-        elif t == 'gated_delta_chunk':
-            markers.append(('linear_attention', op, conv_w))
-        elif t == 'causal_mask':
-            markers.append(('full_attention', op, None))
-        elif t in ('layer_norm', 'position_embedding', 'moe_ffn',
-                   'flash_attention', 'ring_attention'):
-            _fail('op %s inside an rms_norm model: not the hybrid block '
-                  'of models/hybrid.py' % t)
-    if emb_w is None:
-        _fail('no lookup_table op (token embedding)')
-    if not markers:
-        _fail('no gated_delta_chunk or causal_mask op marks a layer')
-    want_muls = sum(7 if k == 'linear_attention' else 5
-                    for k, _, _ in markers) + 1
-    want_norms = sum(3 if k == 'linear_attention' else 4
-                     for k, _, _ in markers) + 1
-    if len(muls) != want_muls or len(norms) != want_norms:
-        _fail('%d mul and %d rms_norm ops for layer kinds %s (want %d and '
-              '%d)' % (len(muls), len(norms), [k for k, _, _ in markers],
-                       want_muls, want_norms))
-    vocab, dim = (int(d) for d in block.var_recursive(emb_w).shape)
-    max_len = int(block.var_recursive(ids).shape[1])
-    muls, norms = iter(muls), iter(norms)
-
-    def w():
-        return (next(muls), None)
-    blocks, heads, sizes = [], None, None
-    for i, (kind, op, conv) in enumerate(markers):
-        if kind == 'linear_attention':
-            if conv is None:
-                _fail('layer %d: gated_delta_chunk without a short_conv'
-                      % i)
-            blk = {'qkv': w(), 'ba': w(), 'out_gate': w(), 'conv': conv,
-                   'a_log': op.single_input('ALog'),
-                   'dt_bias': op.single_input('DtBias'),
-                   'head_norm': next(norms), 'out': w()}
-            got = (int(op.attr('heads')), int(op.attr('key_dim')),
-                   int(op.attr('value_dim')),
-                   float(op.attr('beta_scale', 1.0)),
-                   int(block.var_recursive(conv).shape[0]))
-            if sizes not in (None, got):
-                _fail('layer %d: delta rule sizes %r differ from %r'
-                      % (i, got, sizes))
-            sizes, heads = got, got[0]
-        else:
-            blk = {'qkv': w(), 'q_norm': next(norms),
-                   'k_norm': next(norms), 'proj': w()}
-            if tuple(block.var_recursive(blk['qkv'][0]).shape) != \
-                    (dim, 3 * dim):
-                _fail('layer %d qkv weight %r is not the full logical '
-                      '(%d, %d)' % (i, blk['qkv'][0], dim, 3 * dim))
-        blk.update(mixer_norm=next(norms), gate=w(), up=w(), down=w(),
-                   mlp_norm=next(norms))
-        blocks.append(blk)
-    if heads is None:
-        # attention only: the head count is the scores' second axis
-        heads = int(block.var_recursive(
-            markers[0][1].single_input('X')).shape[1])
-    if sizes is None:
-        sizes = (heads, 0, 0, 1.0, 1)
-    if dim % heads:
-        _fail('%d heads do not divide dim %d' % (heads, dim))
-    ffn = int(block.var_recursive(blocks[0]['gate'][0]).shape[1])
-    spec = hybrid.HybridDecodeSpec(
-        vocab=vocab, dim=dim, heads=heads, ffn=ffn, max_len=max_len,
-        kinds=[k for k, _, _ in markers], key_dim=sizes[1],
-        value_dim=sizes[2], conv_kernel=sizes[4], eps=eps,
-        beta_scale=sizes[3], emb_w=emb_w, blocks=blocks,
-        final_norm=next(norms), head=w())
+def _described_spec(described, block):
+    """The spec of a program that carries its description: what its
+    family's spec_from_config says of the Config the fields make."""
+    family = models.served_family(described.get('family'))
+    if family is None:
+        _fail('its description names the family %r'
+              % (described.get('family'),))
+    try:
+        spec = family.spec_from_config(family.Config(**described['config']))
+    except (KeyError, TypeError, ValueError) as err:
+        _fail('the description %r does not make a %s: %s: %s'
+              % (described, family.Config.__name__, type(err).__name__, err))
     spec.param_specs = {n: None for n in spec.param_names()}
+    _check_weights(block, spec)
     return spec
 
 
-_NEMOTRON_MARKERS = {'ssd_chunk': 'mamba', 'moe_experts': 'experts',
-                     'causal_mask': 'full_attention'}
-
-
-def _extract_nemotron_spec(block):
-    """The three-kind hybrid's spec (see the module docstring): the ops
-    between one rms_norm and the next are one layer, whose kind its
-    marker op gives and whose sizes the marker's attributes and the
-    weights' shapes give."""
+def _check_weights(block, spec):
+    """Hold a described spec to the program it came with: every
+    parameter it names is there, and the shapes it states without
+    asking its family (the embedding's, the K/V layers' qkv weights,
+    from whose width a pool's heads follow) are the variables'."""
     def shape(name):
-        return tuple(int(d) for d in block.var_recursive(name).shape)
+        try:
+            var = block.var_recursive(name)
+        except KeyError:
+            var = None
+        if var is None or not var.persistable:
+            _fail('the described model\'s parameter %r is not a persistable '
+                  'variable of the program' % name)
+        return tuple(int(d) for d in var.shape)
 
-    emb_w = ids = None
-    layers = []                 # [norm scale, [ops until the next norm]]
-    eps = 1e-5
-    for op in block.ops:
-        t = op.type
-        if t == 'lookup_table' and emb_w is None:
-            emb_w, ids = op.single_input('W'), op.single_input('Ids')
-        elif t == 'rms_norm':
-            layers.append([op.single_input('Scale'), []])
-            eps = op.attr('epsilon', eps)
-        elif t in ('layer_norm', 'position_embedding', 'moe_ffn',
-                   'gated_delta_chunk', 'flash_attention',
-                   'ring_attention'):
-            _fail('op %s inside a model with ssd_chunk or moe_experts '
-                  'layers: not the block of models/nemotron_h.py' % t)
-        elif layers:
-            layers[-1][1].append(op)
-    if emb_w is None:
-        _fail('no lookup_table op (token embedding)')
-    if len(layers) < 2:
-        _fail('no rms_norm before a mixer and before the head')
-    (final_norm, tail), layers = layers[-1], layers[:-1]
-    head = [op.single_input('Y') for op in tail if op.type == 'mul']
-    if len(head) != 1:
-        _fail('%d mul ops after the final rms_norm (want the head)'
-              % len(head))
-    vocab, dim = shape(emb_w)
-    cfg = dict(vocab=vocab, dim=dim, max_len=shape(ids)[1], eps=eps)
-    sizes = {}
-
-    def agree(kind, i, got):
-        if sizes.setdefault(kind, got) != got:
-            _fail('layer %d: %s sizes %r differ from %r'
-                  % (i, kind, got, sizes[kind]))
-        cfg.update(got)
-
-    blocks, kinds = [], []
-    for i, (norm, ops) in enumerate(layers):
-        marks = [op for op in ops if op.type in _NEMOTRON_MARKERS]
-        if len(marks) != 1:
-            _fail('layer %d: %d marker ops (%s) between two rms_norm ops, '
-                  'want one' % (i, len(marks), [m.type for m in marks]))
-        mark, kind = marks[0], _NEMOTRON_MARKERS[marks[0].type]
-        muls = [(op.single_input('Y'), None) for op in ops
-                if op.type == 'mul']
-        want = {'mamba': 2, 'experts': 4, 'full_attention': 2}[kind]
-        if len(muls) != want:
-            _fail('layer %d (%s): %d mul ops, want %d'
-                  % (i, kind, len(muls), want))
-        blk = {'norm': norm}
-        if kind == 'mamba':
-            roles, got = _mamba_roles(i, ops, mark, muls, shape)
-            blk.update(roles)
-            agree(kind, i, got)
-        elif kind == 'experts':
-            blk.update({'down': muls[0], 'up': muls[1],
-                        'shared_up': muls[2], 'shared_down': muls[3],
-                        'router': mark.single_input('RouterW'),
-                        'bias': mark.single_input('Bias'),
-                        'w1': mark.single_input('W1'),
-                        'w2': mark.single_input('W2')})
-            held, latent, ffn = shape(blk['w1'])
-            agree(kind, i, dict(
-                experts=shape(blk['router'])[1], experts_held=held,
-                expert_offset=int(mark.attr('expert_offset', 0)),
-                top_k=int(mark.attr('top_k')),
-                routed_scale=float(mark.attr('scale', 1.0)),
-                latent=latent, expert_ffn=ffn,
-                shared_ffn=shape(muls[2][0])[1]))
-        else:
-            roles, got = _attention_roles(i, block, mark, muls, shape)
-            blk.update(roles)
-            agree(kind, i, got)
-        blocks.append(blk)
-        kinds.append(kind)
-    spec = nemotron_h.NemotronHDecodeSpec(
-        nemotron_h.NemotronHConfig(layer_types=kinds, **cfg),
-        emb_w=emb_w, blocks=blocks, final_norm=final_norm,
-        head=(head[0], None))
-    spec.param_specs = {n: None for n in spec.param_names()}
-    return spec
-
-
-def _mamba_roles(i, ops, mark, muls, shape):
-    """(names by role, sizes) of a mamba mixer's ops (those of
-    models/nemotron_h._mamba_mixer)."""
-    conv = [op for op in ops if op.type == 'short_conv']
-    gate = [op for op in ops if op.type == 'gated_group_norm']
-    if len(conv) != 1 or not conv[0].input('Bias') or len(gate) != 1:
-        _fail('layer %d: ssd_chunk without one short_conv with a '
-              'Bias and one gated_group_norm' % i)
-    roles = {'in': muls[0], 'out': muls[1],
-             'conv': conv[0].single_input('W'),
-             'conv_bias': conv[0].single_input('Bias'),
-             'a_log': mark.single_input('ALog'),
-             'dt_bias': mark.single_input('DtBias'),
-             'd': mark.single_input('D'),
-             'gate_norm': gate[0].single_input('Scale')}
-    return roles, dict(
-        mamba_heads=int(mark.attr('heads')),
-        mamba_head_dim=int(mark.attr('head_dim')),
-        groups=int(mark.attr('groups')), state=int(mark.attr('state')),
-        chunk=int(mark.attr('block', 128)),
-        conv_kernel=shape(roles['conv'])[0])
-
-
-def _attention_roles(i, block, mark, muls, shape):
-    """(names by role, sizes) of a whole-sequence attention mixer with
-    whole K/V heads (models/nemotron_h._full_attention)."""
-    heads = int(block.var_recursive(mark.single_input('X')).shape[1])
-    width, wide = shape(muls[1][0])[0], shape(muls[0][0])[1]
-    if width % heads or (wide - width) % (2 * (width // heads)):
-        _fail('layer %d: qkv weight %r and proj weight %r do not '
-              'split into %d query heads and whole K/V heads'
-              % (i, shape(muls[0][0]), shape(muls[1][0]), heads))
-    dh = width // heads
-    return {'qkv': muls[0], 'proj': muls[1]}, dict(
-        heads=heads, head_dim=dh, kv_heads=(wide - width) // (2 * dh))
-
-
-def _extract_granite_spec(block):
-    """The two-sublayer hybrid's spec (see the module docstring): the
-    ops between one rms_norm and the next are one sublayer; sublayers
-    come in pairs, a mixer (marker ssd_chunk or causal_mask) and an
-    expert sublayer (marker moe_experts)."""
-    def shape(name):
-        return tuple(int(d) for d in block.var_recursive(name).shape)
-
-    emb_w = ids = None
-    emb_scale = 1.0
-    subs = []                   # [norm scale, [ops until the next norm]]
-    eps = 1e-5
-    for op in block.ops:
-        t = op.type
-        if t == 'lookup_table' and emb_w is None:
-            emb_w, ids = op.single_input('W'), op.single_input('Ids')
-        elif t == 'rms_norm':
-            subs.append([op.single_input('Scale'), []])
-            eps = op.attr('epsilon', eps)
-        elif t in ('layer_norm', 'position_embedding', 'moe_ffn',
-                   'gated_delta_chunk', 'flash_attention',
-                   'ring_attention', 'latent_attention'):
-            _fail('op %s inside a model whose experts are softmax-gated: '
-                  'not the block of models/granite_h.py' % t)
-        elif subs:
-            subs[-1][1].append(op)
-        elif t == 'scale' and emb_w is not None:
-            emb_scale = float(op.attr('scale'))
-    if emb_w is None:
-        _fail('no lookup_table op (token embedding)')
-    if len(subs) < 3 or len(subs) % 2 != 1:
-        _fail('%d rms_norm ops: want two a layer and one before the head '
-              '(models/granite_h.py)' % len(subs))
-    (final_norm, tail), subs = subs[-1], subs[:-1]
-    head = [op for op in tail if op.type == 'matmul']
-    if len(head) != 1 or head[0].single_input('Y') != emb_w \
-            or not head[0].attr('transpose_Y'):
-        _fail('after the final rms_norm: want one matmul with the '
-              'embedding transposed (the tied head)')
-    vocab, dim = shape(emb_w)
-    cfg = dict(vocab=vocab, dim=dim, max_len=shape(ids)[1], eps=eps,
-               embedding_multiplier=emb_scale,
-               logits_scaling=1.0 / float(head[0].attr('alpha')))
-    sizes = {}
-
-    def agree(kind, i, got):
-        if sizes.setdefault(kind, got) != got:
-            _fail('layer %d: %s sizes %r differ from %r'
-                  % (i, kind, got, sizes[kind]))
-        cfg.update(got)
-
-    def parts(i, ops, markers):
-        marks = [op for op in ops if op.type in markers]
-        scales = [op for op in ops if op.type == 'scale']
-        if len(marks) != 1 or len(scales) != 1:
-            _fail('layer %d: %d marker ops (%s) and %d scale ops between '
-                  'two rms_norm ops, want one of each'
-                  % (i, len(marks), [m.type for m in marks], len(scales)))
-        agree('residual', i, dict(
-            residual_multiplier=float(scales[0].attr('scale'))))
-        return marks[0], [(op.single_input('Y'), None) for op in ops
-                          if op.type == 'mul']
-
-    blocks, kinds = [], []
-    for i in range(len(subs) // 2):
-        (norm, ops), (ffn_norm, ffn_ops) = subs[2 * i], subs[2 * i + 1]
-        mark, muls = parts(i, ops, ('ssd_chunk', 'causal_mask'))
-        if len(muls) != 2:
-            _fail('layer %d: %d mul ops in the mixer, want 2'
-                  % (i, len(muls)))
-        kind = _NEMOTRON_MARKERS[mark.type]
-        if kind == 'mamba':
-            roles, got = _mamba_roles(i, ops, mark, muls, shape)
-        else:
-            roles, got = _attention_roles(i, block, mark, muls, shape)
-            scores = [op for op in ops if op.type == 'matmul'][0]
-            got['attention_multiplier'] = float(scores.attr('alpha'))
-        agree(kind, i, got)
-        blk = dict(roles, norm=norm, ffn_norm=ffn_norm)
-        mark, muls = parts(i, ffn_ops, ('moe_experts',))
-        if len(muls) != 2 or not mark.input('W3') \
-                or mark.attr('gate', 'sigmoid') != 'softmax' \
-                or mark.single_input('X') != mark.single_input('Lat'):
-            _fail('layer %d: the expert sublayer of models/granite_h.py is '
-                  'a softmax-gated moe_experts with W3 on the normed '
-                  'stream itself and two mul ops (the shared expert)' % i)
-        blk.update({'router': mark.single_input('RouterW'),
-                    'w1': mark.single_input('W1'),
-                    'w3': mark.single_input('W3'),
-                    'w2': mark.single_input('W2'),
-                    'shared_up': muls[0], 'shared_down': muls[1]})
-        held, _, ffn = shape(blk['w1'])
-        agree('experts', i, dict(
-            experts=shape(blk['router'])[1], experts_held=held,
-            expert_offset=int(mark.attr('expert_offset', 0)),
-            top_k=int(mark.attr('top_k')), expert_ffn=ffn,
-            shared_ffn=shape(muls[1][0])[0]))
-        blocks.append(blk)
-        kinds.append(kind)
-    spec = granite_h.GraniteHDecodeSpec(
-        granite_h.GraniteHConfig(layer_types=kinds, **cfg),
-        emb_w=emb_w, blocks=blocks, final_norm=final_norm)
-    spec.param_specs = {n: None for n in spec.param_names()}
-    return spec
-
-
-def _extract_axk1_spec(block):
-    """The latent-attention block's spec (see the module docstring).
-    The ops that carry a parameter, in order, spell the model: N an
-    rms_norm, M a mul, A latent_attention, E moe_experts; a layer is
-    N M N M M N A M N then M M (a gated MLP) or E M M (experts beside
-    one shared expert), the gated-MLP layers first, and N M ends the
-    model."""
-    def shape(name):
-        return tuple(int(d) for d in block.var_recursive(name).shape)
-
-    emb_w = ids = None
-    letters, ops, rotary = [], [], None
-    for op in block.ops:
-        t = op.type
-        if t == 'lookup_table' and emb_w is None:
-            emb_w, ids = op.single_input('W'), op.single_input('Ids')
-        elif t in ('layer_norm', 'position_embedding', 'moe_ffn', 'ssd_chunk',
-                   'gated_delta_chunk', 'flash_attention', 'ring_attention',
-                   'causal_mask'):
-            _fail('op %s inside a model with latent_attention layers: not '
-                  'the block of models/axk1.py' % t)
-        elif t == 'rotary_yarn':
-            rotary = rotary or op
-        elif t in ('rms_norm', 'mul', 'latent_attention', 'moe_experts'):
-            letters.append({'rms_norm': 'N', 'mul': 'M',
-                            'latent_attention': 'A', 'moe_experts': 'E'}[t])
-            ops.append(op)
-    if emb_w is None:
-        _fail('no lookup_table op (token embedding)')
-    spelled = ''.join(letters)
-    if not re.match(r'^(NMNMMNAMNMM)*(NMNMMNAMNEMM)*NM$', spelled) \
-            or len(spelled) == 2 or rotary is None:
-        _fail('parameter ops spell %r: not layers of [norm, q-down, norm, '
-              'q-up, kv-down, norm, latent_attention, proj, norm] and a '
-              'gated MLP (the first layers) or moe_experts with a shared '
-              'expert, then a final norm and the head (models/axk1.py)'
-              % spelled)
-    vocab, dim = shape(emb_w)
-    eps = ops[0].attr('epsilon', 1e-6)
-    blocks, sizes = [], {}
-
-    def agree(i, got):
-        for k, v in got.items():
-            if sizes.setdefault(k, v) != v:
-                _fail('layer %d: %s %r differs from %r' % (i, k, v, sizes[k]))
-
-    def w(op):
-        return (op.single_input('Y'), None)
-
-    at = 0
-    while at + 2 < len(ops):
-        i = len(blocks)
-        n1, qd, n2, qu, kd, n3, att, proj, n4 = ops[at:at + 9]
-        blk = {'attn_norm': n1.single_input('Scale'), 'q_down': w(qd),
-               'q_norm': n2.single_input('Scale'), 'q_up': w(qu),
-               'kv_down': w(kd), 'kv_norm': n3.single_input('Scale'),
-               'kv_up': att.single_input('WUKV'), 'proj': w(proj),
-               'ffn_norm': n4.single_input('Scale')}
-        heads = int(block.var_recursive(att.single_input('Q')).shape[2])
-        dn = int(att.attr('nope_dim'))
-        kv_rank, up_w = shape(blk['kv_up'])
-        head = shape(blk['q_up'][0])[1] // heads
-        agree(i, dict(
-            heads=heads, nope_dim=dn, rope_dim=head - dn,
-            v_dim=up_w // heads - dn, kv_rank=kv_rank,
-            q_rank=shape(blk['q_down'][0])[1],
-            sm_scale=float(att.attr('sm_scale'))))
-        if shape(blk['kv_down'][0])[1] != kv_rank + head - dn:
-            _fail('layer %d: kv-down weight %r is not kv_rank %d + rope_dim '
-                  '%d wide' % (i, shape(blk['kv_down'][0]), kv_rank,
-                               head - dn))
-        at += 9
-        if letters[at] == 'E':
-            mark, up, down = ops[at:at + 3]
-            if not mark.input('W3'):
-                _fail('layer %d: moe_experts without W3 beside '
-                      'latent_attention: the experts of models/axk1.py are '
-                      'gated (three matrices)' % i)
-            blk.update({'router': mark.single_input('RouterW'),
-                        'bias': mark.single_input('Bias'),
-                        'w1': mark.single_input('W1'),
-                        'w3': mark.single_input('W3'),
-                        'w2': mark.single_input('W2'),
-                        'shared_gate_up': w(up), 'shared_down': w(down)})
-            held, _, ffn = shape(blk['w1'])
-            agree(i, dict(
-                experts=shape(blk['router'])[1], experts_held=held,
-                expert_offset=int(mark.attr('expert_offset', 0)),
-                top_k=int(mark.attr('top_k')),
-                n_group=int(mark.attr('n_group', 1)),
-                topk_group=int(mark.attr('topk_group', 1)),
-                routed_scale=float(mark.attr('scale', 1.0)),
-                expert_ffn=ffn, shared_ffn=shape(down.single_input('Y'))[0]))
-        else:
-            up, down = ops[at:at + 2]
-            blk.update({'gate_up': w(up), 'down': w(down)})
-            agree(i, dict(dense_ffn=shape(down.single_input('Y'))[0]))
-        at += 3 if letters[at] == 'E' else 2
-        blocks.append(blk)
-    final_norm, head = ops[at].single_input('Scale'), w(ops[at + 1])
-    sizes.pop('sm_scale')
-    cfg = axk1.AXK1Config(
-        vocab=vocab, dim=dim, layers=len(blocks),
-        dense_layers=sum('gate_up' in b for b in blocks),
-        max_len=shape(ids)[1], eps=eps,
-        rope={k: rotary.attr(k) for k in axk1.ROPE_KEYS}, **sizes)
-    spec = axk1.AXK1DecodeSpec(cfg, emb_w=emb_w, blocks=blocks,
-                               final_norm=final_norm, head=head)
-    spec.param_specs = {n: None for n in spec.param_names()}
-    return spec
+    want = {spec.emb_w: (spec.vocab, spec.dim)}
+    if spec.page_kind == 'kv':
+        wide = (spec.heads + 2 * spec.kv_heads) * spec.dh
+        want.update({spec.blocks[i]['qkv'][0]: (spec.dim, wide)
+                     for i in spec.kv_layers})
+    for name in spec.param_names():
+        got = shape(name)
+        if got != want.get(name, got):
+            _fail('parameter %r is %r in the program and %r by its '
+                  'description' % (name, got, want[name]))
 
 
 def extract_decode_spec(program):
-    """Scan the loaded program and return its DecodeSpec."""
+    """The DecodeSpec of a loaded program: its description's (see the
+    module docstring), or the GPT walk's."""
     block = program.global_block()
-    if any(op.type == 'latent_attention' for op in block.ops):
-        return _extract_axk1_spec(block)
-    if any(op.type == 'moe_experts' and op.attr('gate') == 'softmax'
-           for op in block.ops):
-        return _extract_granite_spec(block)
-    if any(op.type in ('ssd_chunk', 'moe_experts') for op in block.ops):
-        return _extract_nemotron_spec(block)
-    if any(op.type == 'rms_norm' for op in block.ops):
-        return _extract_hybrid_spec(block)
+    if program.served_model is not None:
+        return _described_spec(program.served_model, block)
     emb_w = pos_w = None
     lns = []          # (scale_name, bias_name) in op order
     muls = []         # (w_name, out_name) in op order
@@ -786,8 +345,8 @@ def extract_decode_spec(program):
         elif t == 'moe_ffn':
             _fail('op moe_ffn: the training expert layer drops the pairs '
                   'over its capacity, which a served stream may not; '
-                  'the served expert layer is op moe_experts '
-                  '(models/nemotron_h.py)')
+                  'the served expert layer is op moe_experts, which '
+                  'the block files of models.SERVED_FAMILIES build')
         elif t == 'ring_attention':
             _fail('op ring_attention: sp-ring attention has no '
                   'cached-decode equivalent (serve with the paged '

@@ -1,16 +1,21 @@
-"""What the serving programs of the four block families trace to, as
+"""What the serving programs of the block families trace to, as
 digests: every program a PagedDecodePredictor runs for a tiny model of
-each family (prefill chunk, page copy, decode step), and the three
-shared pieces a new block could disturb (the sigmoid gate of
-moe_experts, ssd_chunk in blocks of 128, the Pallas paged_attention at
-2, 16 and 32 pool heads). A jaxpr's text holds no file name and no line
-number, so a digest moves only when what is computed moves.
+each family (prefill chunk, page copy, decode step; with snapshot rows
+also the two state copies; under speculation the verify program and the
+self-draft's pair), and the three shared pieces a new block could
+disturb (the sigmoid gate of moe_experts, ssd_chunk in blocks of 128,
+the Pallas paged_attention at 2, 16 and 32 pool heads). A jaxpr's text
+holds no file name and no line number, so a digest moves only when what
+is computed moves.
 
     JAX_PLATFORMS=cpu python tests/serving_jaxprs.py > FILE
 
 writes the record; tests/test_serving_jaxprs.py holds the tree to the
 one recorded from the parent of the PR that added snapshot rows
-(tests/serving_jaxprs_pr44.json). Uses nothing that commit lacks.
+(tests/serving_jaxprs_pr44.json: four families, no snapshot rows) and to
+the one recorded from the parent of the PR that left one paged builder
+(tests/serving_jaxprs_pr47.json: the same, and granite_h with snapshot
+rows and the GPT family under speculation).
 """
 import hashlib
 import json
@@ -33,7 +38,8 @@ def _digest(text):
 
 
 def _models():
-    from paddle_tpu.models import axk1, hybrid, nemotron_h, transformer
+    from paddle_tpu.models import (axk1, granite_h, hybrid, nemotron_h,
+                                   transformer)
     return {
         'gpt2': (transformer.language_model_logits,
                  transformer.TransformerConfig(
@@ -47,6 +53,11 @@ def _models():
                            vocab=64, dim=32, max_len=T, head_dim=8,
                            expert_offset=4, experts_held=8)),
         'axk1': (axk1.language_model_logits, axk1.AXK1Config(max_len=T)),
+        'granite_h': (granite_h.language_model_logits,
+                      granite_h.GraniteHConfig(
+                          vocab=64, dim=32, max_len=T, head_dim=8,
+                          layer_types=('mamba', 'attention', 'mamba'),
+                          expert_offset=4, experts_held=8)),
     }
 
 
@@ -66,28 +77,45 @@ def _predictor(logits_fn, cfg, tmp):
 
 def program_digests(dec):
     """Sorted digests of the jaxprs of every device segment the
-    decoder's executor has compiled."""
+    decoder's executor (and its draft's, under speculation) has
+    compiled."""
     out = []
-    for prepared in dec._exe._prepared_cache.values():
-        for step in prepared.steps:
-            if getattr(step, 'jitted', None) is not None \
-                    and getattr(step, '_arg_struct', None) is not None:
-                out.append(_digest(str(
-                    step.jitted.trace(*step._arg_struct).jaxpr)))
+    for exe in [dec._exe] + ([dec.draft._exe] if hasattr(dec, 'draft')
+                             else []):
+        for prepared in exe._prepared_cache.values():
+            for step in prepared.steps:
+                if getattr(step, 'jitted', None) is not None \
+                        and getattr(step, '_arg_struct', None) is not None:
+                    out.append(_digest(str(
+                        step.jitted.trace(*step._arg_struct).jaxpr)))
     return sorted(out)
 
 
-def served(name):
-    """The digests of model `name` served with no snapshot rows."""
+def served(name, **deployment):
+    """The digests of model `name` served with no snapshot rows, or as
+    `deployment` (prepare_decoding's arguments beside GEOMETRY) says:
+    snapshot_rows adds the two state copy programs, speculative the
+    verify program and the self-draft's prefill, decode and page copy."""
     logits_fn, cfg = _models()[name]
     with tempfile.TemporaryDirectory() as tmp:
-        dec = _predictor(logits_fn, cfg, tmp).prepare_decoding(**GEOMETRY)
+        dec = _predictor(logits_fn, cfg, tmp).prepare_decoding(
+            **dict(GEOMETRY, **deployment))
     dec.prefill([np.arange(1, 12)], [1])
     tokens = np.zeros(dec.slots, np.int64)
     positions = np.zeros(dec.slots, np.int32)
     tokens[1], positions[1] = 5, 11
+    if deployment.get('speculative'):
+        dec.spec_step(tokens, positions)
     dec.decode_step(tokens, positions)
     return program_digests(dec)
+
+
+# beside every family with no snapshot rows: what PR 44's record lacks
+DEPLOYED = {
+    'granite_h_snapshot_rows': ('granite_h', dict(snapshot_rows=2)),
+    'gpt2_speculative': ('gpt2', dict(speculative=True, spec_k=2,
+                                      draft_layers=1)),
+}
 
 
 def pieces():
@@ -116,7 +144,10 @@ def pieces():
 
 
 def record():
-    return dict({name: served(name) for name in _models()}, **pieces())
+    out = {name: served(name) for name in _models()}
+    out.update({key: served(name, **deployment)
+                for key, (name, deployment) in DEPLOYED.items()})
+    return dict(out, **pieces())
 
 
 if __name__ == '__main__':

@@ -4,7 +4,13 @@ block families trace to what they traced to on the commit before it (PR
 44, 1b3d81a; tests/serving_jaxprs_pr44.json, recorded there by
 tests/serving_jaxprs.py), and so do the pieces the new block shares with
 them. A digest that moves means another executable for a cell of the
-benchmark: another cache key, another set-up, other numbers."""
+benchmark: another cache key, another set-up, other numbers.
+
+The fifth family, with and without snapshot rows (prefill, page copy,
+decode, snapshot, adopt), and the GPT family under speculation (the
+verify program and the self-draft's pair beside the target's) are held
+to the record of PR 47 (430eb2b; tests/serving_jaxprs_pr47.json), made
+before the per-family paged builders became one."""
 import json
 import os
 
@@ -12,9 +18,15 @@ import pytest
 
 import serving_jaxprs
 
-with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                       'serving_jaxprs_pr44.json')) as f:
-    RECORDED = json.load(f)
+
+def _recorded(name):
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           name)) as f:
+        return json.load(f)
+
+
+RECORDED = _recorded('serving_jaxprs_pr44.json')
+RECORDED_PR47 = _recorded('serving_jaxprs_pr47.json')
 
 
 @pytest.mark.parametrize('name', ['gpt2', 'hybrid', 'nemotron_h', 'axk1'])
@@ -25,3 +37,20 @@ def test_served_with_no_snapshot_rows_a_model_traces_as_before(name):
 def test_the_shared_pieces_trace_as_before():
     got = serving_jaxprs.pieces()
     assert got == {k: RECORDED[k] for k in got}
+
+
+def test_the_fifth_family_traces_as_before():
+    assert serving_jaxprs.served('granite_h') == RECORDED_PR47['granite_h']
+
+
+@pytest.mark.parametrize('key', sorted(serving_jaxprs.DEPLOYED))
+def test_a_deployment_with_more_programs_traces_as_before(key):
+    name, deployment = serving_jaxprs.DEPLOYED[key]
+    got = serving_jaxprs.served(name, **deployment)
+    assert got == RECORDED_PR47[key]
+    # the programs every deployment of the model runs are among them
+    assert set(RECORDED_PR47[name]) <= set(got)
+
+
+def test_the_two_records_agree_where_both_speak():
+    assert {k: RECORDED_PR47[k] for k in RECORDED} == RECORDED

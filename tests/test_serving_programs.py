@@ -13,6 +13,15 @@ take no decode_cow_* feed (tests/serving_programs_pr44.json, which also
 records the page copy program that took the copies' place). The prefill
 and verify programs are still held to the PR 27 file: their executables
 were not to change.
+
+PR 48 put the eight per-family builders and these two under one prefill
+and one decode builder. Before it did, the lists of the other three
+families (nemotron_h, axk1, granite_h: prefill and decode) were recorded
+from its parent (PR 47, 430eb2b; tests/serving_programs_pr47.json):
+
+    JAX_PLATFORMS=cpu PYTHONPATH=. python tests/test_serving_programs.py
+
+prints that record.
 """
 import json
 import os
@@ -22,7 +31,7 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import unique_name
 from paddle_tpu.framework import Program, program_guard
-from paddle_tpu.models import hybrid
+from paddle_tpu.models import axk1, granite_h, hybrid, nemotron_h
 from paddle_tpu.models.transformer import (TransformerConfig,
                                            language_model_logits)
 from paddle_tpu.transpiler.decode_transpiler import DecodeTranspiler
@@ -35,6 +44,7 @@ def _recorded(name):
 
 RECORDED = _recorded('serving_programs_pr27.json')
 RECORDED.update(_recorded('serving_programs_pr44.json'))
+RECORDED.update(_recorded('serving_programs_pr47.json'))
 
 GEOMETRY = dict(slots=3, page_tokens=4, kv_pages=13, prefill_chunk=8)
 
@@ -53,8 +63,40 @@ def _describe(program, feeds, fetches):
             'feeds': list(feeds), 'fetches': [v.name for v in fetches]}
 
 
+# the families PR 27 and PR 44 did not record
+LATER = {
+    'nemotron_h': (nemotron_h.language_model_logits,
+                   nemotron_h.NemotronHConfig(
+                       vocab=64, dim=32, max_len=16, head_dim=8,
+                       expert_offset=4, experts_held=8)),
+    'axk1': (axk1.language_model_logits, axk1.AXK1Config(max_len=16)),
+    'granite_h': (granite_h.language_model_logits,
+                  granite_h.GraniteHConfig(
+                      vocab=64, dim=32, max_len=16, head_dim=8,
+                      layer_types=('mamba', 'attention', 'mamba'),
+                      expert_offset=4, experts_held=8)),
+}
+
+
+def _later_programs():
+    out = {}
+    for name, (logits_fn, cfg) in LATER.items():
+        lm = _lm_program(logits_fn, cfg)
+        with unique_name.guard():
+            pair = DecodeTranspiler().transpile(lm, **GEOMETRY)
+        out[name + '_prefill'] = _describe(
+            pair.prefill_program, pair.prefill_feeds, pair.prefill_fetches)
+        out[name + '_decode'] = _describe(
+            pair.decode_program, pair.decode_feeds, pair.decode_fetches)
+    return out
+
+
 @pytest.fixture(scope='module')
 def programs():
+    return dict(_first_programs(), **_later_programs())
+
+
+def _first_programs():
     gpt2 = _lm_program(language_model_logits, TransformerConfig(
         vocab=64, dim=32, heads=2, layers=2, ffn=64, max_len=16,
         use_tp=False, use_sp=False))
@@ -107,3 +149,7 @@ def test_only_the_decode_lists_were_recorded_again():
         assert old[name]['fetches'] == new[name]['fetches']
     for name in ('gpt2_page_copy', 'hybrid_page_copy'):
         assert set(new[name]['ops'].split()) == {'kv_page_cow'}
+
+
+if __name__ == '__main__':
+    print(json.dumps(_later_programs(), indent=1, sort_keys=True))
