@@ -161,7 +161,7 @@ class AnalysisPredictor(Predictor):
                          prefill_chunk=None, speculative=False,
                          spec_k=None, draft_layers=None,
                          draft_predictor=None, mesh=None, paged=True,
-                         snapshot_rows=0):
+                         snapshot_rows=0, window_pages=None):
         """Transpile the loaded LM into the paged prefill + decode pair
         and return a serving.PagedDecodePredictor over this predictor's
         weight scope — page-pool cache with copy-on-write prefix sharing
@@ -180,7 +180,11 @@ class AnalysisPredictor(Predictor):
         with recurrent layers: how many prefix boundaries keep their
         recurrent state on the device, so that the prefix cache can
         hand out their pages (0: none are kept and nothing is shared,
-        as for every such model before). Raises
+        as for every such model before). window_pages, for a model
+        with sliding-window layers: the pages the pools of those layers
+        hold, sized apart from kv_pages (None: a full window table for
+        every slot); speculation, a mesh, page shipping and
+        save_stream / restore_stream refuse such a model by name. Raises
         transpiler.DecodeTranspileError if the program is not a
         recognizable decoder-only LM."""
         # `paged` is accepted only because benchmarks/builders still pass
@@ -193,6 +197,9 @@ class AnalysisPredictor(Predictor):
             if snapshot_rows:
                 raise ValueError('snapshot_rows with speculative=True: '
                                  'speculation refuses recurrent state')
+            if window_pages:
+                raise ValueError('window_pages with speculative=True: '
+                                 'speculation refuses sliding layers')
             from .serving import SpeculativeDecodePredictor
             return SpeculativeDecodePredictor(
                 self, slots=slots, spec_k=spec_k,
@@ -205,7 +212,8 @@ class AnalysisPredictor(Predictor):
                                     page_tokens=page_tokens,
                                     kv_pages=kv_pages,
                                     prefill_chunk=prefill_chunk,
-                                    mesh=mesh, snapshot_rows=snapshot_rows)
+                                    mesh=mesh, snapshot_rows=snapshot_rows,
+                                    window_pages=window_pages)
 
 
 def create_analysis_predictor(config):

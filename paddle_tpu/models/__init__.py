@@ -18,7 +18,8 @@ from . import googlenet  # noqa: F401
 # (models/transformer.build_paged_prefill_program). A saved program
 # names its family here, and the DecodeTranspiler looks the module up
 # by that name: a new family is a block file and a name in this tuple.
-SERVED_FAMILIES = ('hybrid', 'nemotron_h', 'axk1', 'granite_h')
+SERVED_FAMILIES = ('hybrid', 'nemotron_h', 'axk1', 'granite_h',
+                   'smallthinker')
 
 
 def describe_served_model(program, family, cfg):

@@ -231,6 +231,16 @@ def refuse_latent_pages(spec, what):
             'heads' % (what,))
 
 
+def refuse_window(spec, what):
+    """Raise for a spec with sliding_attention layers (spec.window_layers)
+    where `what` knows a stream's pages as one table over one pool."""
+    if spec.window_layers:
+        raise DecodeTranspileError(
+            '%s cannot serve a model with sliding_attention layers (layers '
+            '%s): their pages are a second table over a pool of their own'
+            % (what, ','.join(map(str, spec.window_layers))))
+
+
 class DecodeSpec(object):
     """Dims + parameter names extracted from a loaded LM program.
 
@@ -260,6 +270,17 @@ class DecodeSpec(object):
     (speculative verify, the heads-sharded mesh layout) has to refuse
     the latent kind (refuse_latent_pages).
 
+    A second page-holding kind, 'sliding_attention': K/V pages like
+    'full_attention', of which a row reads the last `window` tokens
+    only (the spec's class or instance gives `window`). Such layers are
+    kv_layers too and, apart, window_layers (full_layers: the others):
+    their pools are sized apart (the builders' window_pages), a stream
+    finds their pages through a second table (PagedStep.window_table)
+    that gives up the pages behind the window as it advances
+    (serving/paging.py), and what reads a stream's pages as one table
+    refuses them (refuse_window). A spec with no such layer has none of
+    this: one table, one pool size, the feeds it always had.
+
     kv_heads is the number of K/V heads a page holds (query head h
     reads K/V head h // (heads / kv_heads)); the model's head count
     where it is not given.
@@ -269,6 +290,8 @@ class DecodeSpec(object):
     expert_layers = ()      # the layers whose rows an expert op counts
     state_family = None     # names the gauge serving.<family>.state_bytes
     page_kind = 'kv'        # what a page of kv_layers holds
+    page_kinds = ('full_attention', 'sliding_attention')
+    window = 0              # tokens a row of a sliding_attention layer sees
 
     def __init__(self, vocab, dim, heads, layers, ffn, max_len, pos_len,
                  emb_w, pos_w, blocks, final_ln, head, use_flash=False,
@@ -282,7 +305,9 @@ class DecodeSpec(object):
         self.layers, self.ffn = layers, ffn
         self.kinds = tuple(kinds or ('full_attention',) * layers)
         self.kv_layers = [i for i, k in enumerate(self.kinds)
-                          if k == 'full_attention']
+                          if k in self.page_kinds]
+        self.window_layers = [i for i, k in enumerate(self.kinds)
+                              if k == 'sliding_attention']
         self.recurrent_layers = [i for i, k in enumerate(self.kinds)
                                  if k in self.recurrent_kinds]
         self.max_len, self.pos_len = max_len, pos_len
@@ -325,14 +350,35 @@ class DecodeSpec(object):
     def pool_shape(self, num_pages, page_tokens):
         return (num_pages, page_tokens, self.pool_heads, self.dh)
 
+    @property
+    def full_layers(self):
+        """The page-holding layers that keep a stream's every page."""
+        return [i for i in self.kv_layers if i not in self.window_layers]
+
+    def window_table_pages(self, chunk, page_tokens):
+        """The width of a stream's table of its sliding layers: the
+        pages that window - 1 tokens behind a chunk's first row and the
+        chunk itself can lie on (a decode step needs fewer); 0 without
+        such layers."""
+        if not self.window_layers:
+            return 0
+        if self.window < 1:
+            raise ValueError('sliding_attention layers with window %r'
+                             % (self.window,))
+        return min(-(-(self.window - 1 + chunk) // page_tokens) + 1,
+                   -(-self.max_len // page_tokens))
+
     def build_paged_programs(self, slots, chunk, num_pages, page_tokens,
-                             pages_per_slot):
+                             pages_per_slot, **window):
         """The paged pair that serves this spec's block, as (prefill
-        program, feeds, fetches, decode program, feeds, fetches)."""
+        program, feeds, fetches, decode program, feeds, fetches).
+        `window`: the builders' window_pages and window_pages_per_slot,
+        for a spec with sliding layers."""
         return build_paged_prefill_program(
-            self, slots, chunk, num_pages, page_tokens, pages_per_slot) + \
-            build_paged_decode_program(
-                self, slots, num_pages, page_tokens, pages_per_slot)
+            self, slots, chunk, num_pages, page_tokens, pages_per_slot,
+            **window) + build_paged_decode_program(
+                self, slots, num_pages, page_tokens, pages_per_slot,
+                **window)
 
     def paged_logits(self, tokens, at):
         """This block's walk over one paged program (`at`: PagedStep):
@@ -422,14 +468,17 @@ def _tmp_var(dtype='float32'):
         name=unique_name.generate('kv_decode.tmp'), dtype=dtype)
 
 
-def _qkv_parts(x, spec, blk, t, qk_norm=None):
+def _qkv_parts(x, spec, blk, t, qk_norm=None, rotary=None):
     """qkv fc + per-part slice/reshape to [-1, t, H, dh] — the full
     path's heads() up to (not including) the transpose, which is the
     cache's storage layout. On a mesh each part is pinned heads-sharded
     (the cache/pool layout), a no-op single-chip; the qkv contraction
     dim stays whole either way, so every element is bit-exact.
     `qk_norm(part, 'q' | 'k')`, where the caller's block norms q and k
-    whole before the heads are split."""
+    whole before the heads are split. `rotary(part)`, where the block's
+    attention takes a rotary term: q and k [-1, t, heads, dh] rotated
+    by their rows' positions BEFORE the cache sees a key, so that a
+    page holds rotated keys (as the latent page does: models/axk1.py)."""
     D, KV = spec.heads * spec.dh, spec.kv_heads * spec.dh
     qkv = _named_fc(x, D + 2 * KV, blk['qkv'])
 
@@ -438,6 +487,8 @@ def _qkv_parts(x, spec, blk, t, qk_norm=None):
         if qk_norm is not None and which:
             p = qk_norm(p, which)
         p = L.reshape(p, shape=[-1, t, heads, spec.dh])
+        if rotary is not None and which:
+            p = rotary(p)
         return sharding_constraint(p, (None, None, _tp_ax(spec), None))
 
     return (part(0, D, spec.heads, 'q'),
@@ -499,14 +550,27 @@ class PagedStep(object):
     step is one row of EVERY lane: table [slots, P], positions [slots]
     (the step index) and, for a model with recurrent state or expert
     layers, live [slots]. pools and states are {layer: its variables};
-    stats collects what the expert layers counted. A sublayer asks this
-    value, never the program's name."""
+    stats collects what the expert layers counted. For a model with
+    sliding layers, the second table: window_table ([1, W] or
+    [slots, W]), window_positions (the same rows' positions counted
+    from that table's first row: what its pages are addressed by; a
+    rotary term takes `positions`) and, in a chunk, window_cow.
+    pages_of(spec, layer) is the triple a layer finds its pages
+    through. A sublayer asks this value, never the program's name."""
 
     length = last = cow = slot = reset = live = None
+    window_table = window_positions = window_cow = None
 
     def __init__(self, decode, rows):
         self.decode, self.rows = decode, rows
         self.stats = []
+
+    def pages_of(self, spec, layer):
+        """(table, positions, cow, window) of `layer`'s pages."""
+        if layer in spec.window_layers:
+            return (self.window_table, self.window_positions,
+                    self.window_cow, spec.window)
+        return self.table, self.positions, self.cow, 0
 
 
 def _state_io(at, layer, which):
@@ -539,15 +603,17 @@ def _persistable(name, shape):
         stop_gradient=True, is_cache=True)
 
 
-def _create_pool_vars(spec, num_pages, page_tokens):
+def _create_pool_vars(spec, num_pages, page_tokens, window_pages=0):
     """{layer: its page-pool vars} ((K, V), or the one pool of a layer
     that keeps a latent page) of the layers that keep pages:
     persistable (the executor writes them back to the Scope each run —
     and donates them, so the update is in-place on device) but is_cache
-    (io.py save/load skip them)."""
-    shape = spec.pool_shape(num_pages, page_tokens)
-    return {i: tuple(_persistable(n, shape) for n in spec.pool_names(i))
-            for i in spec.kv_layers}
+    (io.py save/load skip them). A sliding layer's pools hold
+    `window_pages` pages."""
+    sliding = getattr(spec, 'window_layers', ())
+    return {i: tuple(_persistable(n, spec.pool_shape(
+        window_pages if i in sliding else num_pages, page_tokens))
+        for n in spec.pool_names(i)) for i in spec.kv_layers}
 
 
 def _create_state_vars(spec, slots):
@@ -586,23 +652,27 @@ def _paged_gather(pool_var, table, spec):
                                (None, _tp_ax(spec), None, None))
 
 
-def _paged_attention(x, spec, blk, i, at, qk_norm=None):
+def _paged_attention(x, spec, blk, i, at, qk_norm=None, rotary=None):
     """Layer i's attention over its K/V pages, in the form `at`'s
-    program takes: a prefill chunk's or a decode step's."""
+    program takes: a prefill chunk's or a decode step's; through the
+    table of the layer's kind (at.pages_of)."""
     form = _paged_decode_attention if at.decode else _paged_prefill_attention
-    return form(x, spec, blk, at.pools[i], at, qk_norm)
+    return form(x, spec, blk, at.pools[i], at, at.pages_of(spec, i), qk_norm,
+                rotary)
 
 
-def _paged_prefill_attention(x, spec, blk, pool, at, qk_norm=None):
+def _paged_prefill_attention(x, spec, blk, pool, at, pages, qk_norm=None,
+                             rotary=None):
     """One chunk of prefill attention: COW any forked page, scatter the
     chunk's K/V rows through the table, then attend the chunk's queries
-    over the WHOLE gathered history (earlier pages + this chunk)."""
-    table, positions, length, chunk = (at.table, at.positions, at.length,
-                                       at.rows)
-    cow_src, cow_dst = at.cow
+    over the WHOLE gathered history (earlier pages + this chunk): the
+    whole table of the layer's kind, so a sliding layer gathers its own
+    table's width and masks a band."""
+    length, chunk = at.length, at.rows
+    table, positions, (cow_src, cow_dst), band = pages
     q4, k4, v4 = (_pool_heads(a, spec)
-                  for a in _qkv_parts(x, spec, blk, chunk,
-                                      qk_norm))         # [1, C, H, dh]
+                  for a in _qkv_parts(x, spec, blk, chunk, qk_norm,
+                                      rotary))          # [1, C, H, dh]
     for pool_var, new in ((pool[0], k4), (pool[1], v4)):
         _block_op('kv_page_cow',
                   inputs={'Pool': [pool_var], 'Src': [cow_src],
@@ -632,7 +702,8 @@ def _paged_prefill_attention(x, spec, blk, pool, at, qk_norm=None):
     masked = _tmp_var()                                # [1, H, C, J]
     _block_op('paged_prefill_mask',
               inputs={'X': [scores], 'Positions': [positions]},
-              outputs={'Out': [masked]})
+              outputs={'Out': [masked]},
+              attrs={'window': int(band)} if band else None)
     probs = L.softmax(masked)
     if rep > 1:
         probs = L.reshape(probs, shape=grouped[:3] + [window])
@@ -644,29 +715,33 @@ def _paged_prefill_attention(x, spec, blk, pool, at, qk_norm=None):
     return _named_fc(ctx, spec.dim, blk['proj'])
 
 
-def _paged_decode_attention(x, spec, blk, pool, at, qk_norm=None):
+def _paged_decode_attention(x, spec, blk, pool, at, pages, qk_norm=None,
+                            rotary=None):
     """One decode step's attention: append the new K/V row, then ONE
     paged_attention op that reads each lane's live pages through its
     table (no gathered window; see the op's docstring for its two
-    lowerings). No copy-on-write here: a page that forks in a decode
-    step was copied before the step's program was dispatched
+    lowerings), the last `window` positions' for a sliding layer. No
+    copy-on-write here: a page that forks in a decode step was copied
+    before the step's program was dispatched
     (build_page_copy_program)."""
-    table, positions = at.table, at.positions
+    table, positions, _, band = pages
     q1, k1, v1 = (_pool_heads(a, spec)
-                  for a in _qkv_parts(x, spec, blk, 1,
-                                      qk_norm))   # [S, 1, H | KVH, dh]
+                  for a in _qkv_parts(x, spec, blk, 1, qk_norm,
+                                      rotary))    # [S, 1, H | KVH, dh]
     for pool_var, new in ((pool[0], k1), (pool[1], v1)):
         _block_op('kv_page_append',
                   inputs={'Pool': [pool_var], 'X': [new],
                           'Table': [table], 'Positions': [positions]},
                   outputs={'Out': [pool_var]})
     ctx = _tmp_var()
-    _block_op('paged_attention',
+    _block_op('paged_window_attention' if band else 'paged_attention',
               inputs={'Q': [q1], 'KPool': [pool[0]], 'VPool': [pool[1]],
                       'Table': [table], 'Positions': [positions]},
               outputs={'Out': [ctx]},
-              attrs={'sm_scale': float(spec.sm_scale),
-                     'head_axis': _tp_ax(spec) or ''})  # [S, 1, H, dh]
+              attrs=dict({'sm_scale': float(spec.sm_scale),
+                          'head_axis': _tp_ax(spec) or ''},
+                         **({'window': int(band)} if band else {})))
+    #                                                     [S, 1, H, dh]
     ctx = _model_heads(ctx, spec, 1)
     ctx = sharding_constraint(ctx, (None, None, None))
     return _named_fc(ctx, spec.dim, blk['proj'])
@@ -742,12 +817,13 @@ def _logits_head(x, spec, at, head=None):
     return head(gathered, 1)
 
 
-def _paged_fetches(spec, at, tokens, slots, num_pages, page_tokens):
+def _paged_fetches(spec, at, tokens, slots, num_pages, page_tokens,
+                   window_pages=0):
     """The half both builders share: the pools and state variables,
     the block's walk, and its fetches: logits [lanes, vocab], greedy
     ids, and the expert layers' counts summed over the layers where
     there are any."""
-    at.pools = _create_pool_vars(spec, num_pages, page_tokens)
+    at.pools = _create_pool_vars(spec, num_pages, page_tokens, window_pages)
     at.states = _create_state_vars(spec, slots)
     logits = spec.paged_logits(tokens, at)
     if at.decode:
@@ -773,7 +849,8 @@ def _feeds():
 
 
 def build_paged_prefill_program(spec, slots, chunk, num_pages, page_tokens,
-                                pages_per_slot):
+                                pages_per_slot, window_pages=0,
+                                window_pages_per_slot=0):
     """One prefill CHUNK through one stream's page table, for whatever
     block `spec` is of (its walk: spec.paged_logits).
 
@@ -791,7 +868,14 @@ def build_paged_prefill_program(spec, slots, chunk, num_pages, page_tokens,
             one set a slot: `slots`) prefill_state_slot [1] (the slot
             whose state the chunk starts from and leaves behind) and
             prefill_state_reset [1] (1 on a stream's first chunk: start
-            from zero state, whatever the slot held).
+            from zero state, whatever the slot held);
+            and for a model with sliding layers (spec.window_layers;
+            their pools hold `window_pages` pages)
+            prefill_window_table [1, W] (W = window_pages_per_slot: the
+            stream's table of those layers as it stands, a sliding
+            list), prefill_window_positions [C] (each row's position
+            counted from that table's first row) and
+            prefill_window_cow_src / prefill_window_cow_dst [1].
     The same program serves chunked prefill AND prefix-hit suffix
     prefill: shared pages arrive pre-populated in the table and the
     chunk simply starts at the first unshared position. Logits are the
@@ -815,13 +899,20 @@ def build_paged_prefill_program(spec, slots, chunk, num_pages, page_tokens,
         if spec.state_names():
             at.slot = feed('prefill_state_slot', [1])
             at.reset = feed('prefill_state_reset', [1])
+        if spec.window_layers:
+            at.window_table = feed('prefill_window_table',
+                                   [1, window_pages_per_slot])
+            at.window_positions = feed('prefill_window_positions', [chunk])
+            at.window_cow = (feed('prefill_window_cow_src', [1]),
+                             feed('prefill_window_cow_dst', [1]))
         fetches = _paged_fetches(spec, at, tokens, slots, num_pages,
-                                 page_tokens)
+                                 page_tokens, window_pages)
     return prog, names, fetches
 
 
 def build_paged_decode_program(spec, slots, num_pages, page_tokens,
-                               pages_per_slot):
+                               pages_per_slot, window_pages=0,
+                               window_pages_per_slot=0):
     """One-token decode step over the whole slot pool, page-indexed, for
     whatever block `spec` is of.
 
@@ -844,7 +935,11 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
             decode_state_live for a model with recurrent state (the
             others' state stays as it was, and its expert layers
             neither count nor weigh their rows), decode_live for one
-            with expert layers alone.
+            with expert layers alone;
+            and for a model with sliding layers decode_window_table
+            [slots, W] and decode_window_step_idx [slots] (the incoming
+            token's position counted from the lane's window table's
+            first row).
     Admission and page allocation are host decisions that only change
     these feed values — the program compiles exactly once. It copies no
     page: where a lane's append would land on a page it shares, the
@@ -869,12 +964,17 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
             at.live = feed('decode_state_live', [slots])
         elif spec.expert_layers:
             at.live = feed('decode_live', [slots])
+        if spec.window_layers:
+            at.window_table = feed('decode_window_table',
+                                   [slots, window_pages_per_slot])
+            at.window_positions = feed('decode_window_step_idx', [slots])
         fetches = _paged_fetches(spec, at, tokens, slots, num_pages,
-                                 page_tokens)
+                                 page_tokens, window_pages)
     return prog, names, fetches
 
 
-def build_page_copy_program(spec, slots, num_pages, page_tokens):
+def build_page_copy_program(spec, slots, num_pages, page_tokens,
+                            window_pages=0):
     """The copy a forking decode step runs in front of its program: one
     kv_page_cow a pool of the pair, and nothing else.
 
@@ -888,25 +988,32 @@ def build_page_copy_program(spec, slots, num_pages, page_tokens):
     48 pools compile in half a second, in front of the predictor's
     first decode step.
     The prefill and verify programs keep their own kv_page_cow.
+    A model with sliding layers feeds a second pair,
+    page_copy_window_src / page_copy_window_dst [slots], for the pages
+    of their pools (a page that forks in both is copied by the one
+    dispatch).
     Returns (program, feed_names); nothing to fetch.
     """
     from ..framework import Program, program_guard
     prog, startup = Program(), Program()
     prog._is_test = True
+    sliding = getattr(spec, 'window_layers', ())
     with program_guard(prog, startup):
-        src = L.data('page_copy_src', [slots],
-                     append_batch_size=False, dtype='int32')
-        dst = L.data('page_copy_dst', [slots],
-                     append_batch_size=False, dtype='int32')
-        pools = _create_pool_vars(spec, num_pages, page_tokens)
+        feed, names = _feeds()
+        pair = (feed('page_copy_src', [slots]),
+                feed('page_copy_dst', [slots]))
+        wpair = (feed('page_copy_window_src', [slots]),
+                 feed('page_copy_window_dst', [slots])) if sliding else None
+        pools = _create_pool_vars(spec, num_pages, page_tokens, window_pages)
         for layer in spec.kv_layers:
+            src, dst = wpair if layer in sliding else pair
             for pool in pools[layer]:
                 _block_op('kv_page_cow',
                           inputs={'Pool': [pool], 'Src': [src],
                                   'Dst': [dst]},
                           outputs={'Out': [pool]},
                           attrs={'page_rows': True})
-    return prog, ['page_copy_src', 'page_copy_dst']
+    return prog, names
 
 
 def snapshot_names(spec):
@@ -986,6 +1093,7 @@ def build_verify_program(spec, slots, k1, num_pages, page_tokens,
     from ..framework import Program, program_guard
     refuse_recurrent(spec, 'the speculative verify program')
     refuse_latent_pages(spec, 'the speculative verify program')
+    refuse_window(spec, 'the speculative verify program')
     prog, startup = Program(), Program()
     prog._is_test = True
     with program_guard(prog, startup):

@@ -268,11 +268,14 @@ def _gather_pages(pool, table):
                                pool.shape[2], pool.shape[3])
 
 
-def _mask_after(x, positions):
+def _mask_after(x, positions, window=0):
     """x [slots, H, 1, J] with the columns j > positions[s] set to
-    -1e9."""
+    -1e9 and, with a `window`, those at or before positions[s] - window
+    too."""
     j = jnp.arange(x.shape[-1], dtype=jnp.int32)
     valid = j[None, :] <= positions[:, None]           # [slots, J]
+    if window:
+        valid &= j[None, :] > positions[:, None] - window
     valid = valid[:, None, None, :]                    # [slots, 1, 1, J]
     return jnp.where(valid, x, -1e9)
 
@@ -308,7 +311,7 @@ def _paged_decode_mask_emit(ctx, op):
 
 
 def _paged_attention_reference(q, k_pool, v_pool, table, positions,
-                               sm_scale, pin):
+                               sm_scale, pin, window=0):
     """The composition the decode program held before this op, op for
     op (kv_page_gather, transpose, matmul with alpha, paged_decode_mask,
     softmax, matmul): the arithmetic every bit-exact serving contract
@@ -320,21 +323,30 @@ def _paged_attention_reference(q, k_pool, v_pool, table, positions,
     if rep > 1:                 # query head h reads K/V head h // rep
         kt, vt = jnp.repeat(kt, rep, axis=1), jnp.repeat(vt, rep, axis=1)
     scores = jnp.matmul(qt, jnp.swapaxes(kt, -1, -2)) * sm_scale
-    scores = _mask_after(scores, positions)                    # [S,H,1,J]
+    scores = _mask_after(scores, positions, window)            # [S,H,1,J]
     probs = jax.nn.softmax(scores.astype(jnp.float32),
                            axis=-1).astype(scores.dtype)
     return jnp.transpose(jnp.matmul(probs, vt), (0, 2, 1, 3))
 
 
+@op_emitter('paged_window_attention')
 @op_emitter('paged_attention')
 def _paged_attention_emit(ctx, op):
     """One decode step's attention through the page tables: Q
     [S, 1, H, dh], KPool / VPool [N, pt, KVH, dh] (KVH divides H: query
     head h reads K/V head h // (H / KVH)), Table [S, P] int32,
-    Positions [S] int32, attrs sm_scale and head_axis -> Out
+    Positions [S] int32, attrs sm_scale, head_axis and window -> Out
     [S, 1, H, dh]. Lane s attends to its logical positions
     0..Positions[s] (the row appended this step included), which the
-    table maps to pool[Table[s, j // pt], j % pt]. Idle and mid-prefill
+    table maps to pool[Table[s, j // pt], j % pt]; with a `window` (0:
+    none) to the last `window` of them, max(0, Positions[s] - window +
+    1)..Positions[s], and the table's entries before the page that holds
+    the first are never read (the table and the positions of a sliding
+    layer count from the first page its stream still holds:
+    serving/paging.py; such a layer's op goes by the type
+    `paged_window_attention`, the same emitter under a name of its own,
+    so that a device trace tells its calls from the full layers').
+    Idle and mid-prefill
     lanes arrive with a zero table row and position 0: they read the
     null page's first row and their output is discarded downstream.
 
@@ -352,6 +364,7 @@ def _paged_attention_emit(ctx, op):
     table = ctx.get(op.single_input('Table')).astype(jnp.int32)
     positions = ctx.get(op.single_input('Positions')).astype(jnp.int32)
     sm_scale = op.attr('sm_scale')
+    window = int(op.attr('window', 0))
     mesh = getattr(ctx, 'mesh', None)
     axis = op.attr('head_axis', '')
     if mesh is None or axis not in mesh.axis_names \
@@ -363,6 +376,8 @@ def _paged_attention_emit(ctx, op):
             on_tpu or bool(get_flag('pallas_interpret'))):
         kernel = functools.partial(_pa.paged_attention, sm_scale=sm_scale,
                                    interpret=not on_tpu)
+        if window:
+            kernel = functools.partial(kernel, window=window)
         if mesh is not None and mesh.size > 1:
             from jax import shard_map
             from jax.sharding import PartitionSpec as P
@@ -375,14 +390,15 @@ def _paged_attention_emit(ctx, op):
                      positions)[:, None]
     elif mesh is None:
         out = _paged_attention_reference(q, k_pool, v_pool, table,
-                                         positions, sm_scale, lambda x: x)
+                                         positions, sm_scale, lambda x: x,
+                                         window)
     else:
         from ..parallel.mesh import named_sharding
         pin = functools.partial(
             jax.lax.with_sharding_constraint,
             shardings=named_sharding(mesh, (None, axis, None, None)))
         out = _paged_attention_reference(q, k_pool, v_pool, table,
-                                         positions, sm_scale, pin)
+                                         positions, sm_scale, pin, window)
     ctx.set(op.single_output('Out'), out)
 
 
@@ -412,12 +428,19 @@ def _paged_prefill_mask_emit(ctx, op):
     chunk row). Row i may see logical index j iff j <= positions[i] —
     plain causality expressed against the page-table address space, so
     a chunk attends to every previously written page plus its own
-    already-written rows. Padding rows carry garbage positions; their
-    score rows are never gathered downstream."""
+    already-written rows. With attr `window` (a sliding layer's; 0:
+    none) a band: row i sees index j iff positions[i] - window < j <=
+    positions[i]. Padding rows carry garbage positions; their score
+    rows are never gathered downstream. Without Positions the rows are
+    a whole sequence's from its start (a saved model's form)."""
     x = ctx.get(op.single_input('X'))
-    positions = ctx.get(op.single_input('Positions')).astype(jnp.int32)
+    positions = ctx.get(op.single_input('Positions')).astype(jnp.int32) \
+        if op.input('Positions') else jnp.arange(x.shape[-2], dtype=jnp.int32)
     j = jnp.arange(x.shape[-1], dtype=jnp.int32)
     valid = j[None, :] <= positions[:, None]           # [C, J]
+    window = int(op.attr('window', 0))
+    if window:
+        valid &= j[None, :] > positions[:, None] - window
     valid = valid[None, None, :, :]                    # [1, 1, C, J]
     ctx.set(op.single_output('Out'), jnp.where(valid, x, -1e9))
 
@@ -474,6 +497,8 @@ register_op('kv_page_append', infer_shape=_kv_pool_update_infer,
 register_op('kv_page_gather', infer_shape=_kv_page_gather_infer,
             no_grad=True)
 register_op('paged_attention', infer_shape=_ring_infer,
+            no_grad=True)
+register_op('paged_window_attention', infer_shape=_ring_infer,
             no_grad=True)
 register_op('paged_decode_mask', infer_shape=_decode_mask_infer,
             no_grad=True)

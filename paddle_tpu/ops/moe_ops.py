@@ -251,11 +251,14 @@ def held_experts(lat, w, w1, w2):
                       w2)
 
 
-def held_gated_experts(lat, w, w1, w3, w2):
+_GATE_ACT = {'silu': jax.nn.silu, 'relu': jax.nn.relu}
+
+
+def held_gated_experts(lat, w, w1, w3, w2, act='silu'):
     """As held_experts for experts of three matrices: sum_e w[:, e]
-    W2_e (silu(W1_e lat) * W3_e lat), W1 and W3 [held, L, F], W2
-    [held, F, L]."""
-    h = jax.nn.silu(jnp.einsum('rl,elf->erf', lat, w1)) \
+    W2_e (act(W1_e lat) * W3_e lat), W1 and W3 [held, L, F], W2
+    [held, F, L]; `act` silu (SwiGLU) or relu (ReGLU)."""
+    h = _GATE_ACT[act](jnp.einsum('rl,elf->erf', lat, w1)) \
         * jnp.einsum('rl,elf->erf', lat, w3)
     return jnp.einsum('erf,efl->rl', h * w.T.astype(lat.dtype)[..., None],
                       w2)
@@ -271,8 +274,8 @@ def _moe_experts_emit(ctx, op):
     is group-limited (1 and 1: over all experts), and gate ('sigmoid'
     where not given, or 'softmax' over the chosen logits) -> Out [.., L]. The
     experts' form follows from the weights handed in: with W3 [held, L,
-    F] beside W1 an expert is W2 (silu(W1 l) * W3 l), without it W2
-    relu(W1 l)^2. Rows may be marked dead, by Live [rows] (a decode step's
+    F] beside W1 an expert is W2 (act(W1 l) * W3 l), attr act 'silu'
+    where not given or 'relu', without it W2 relu(W1 l)^2. Rows may be marked dead, by Live [rows] (a decode step's
     lanes) or Len [1] (a chunk's rows from Len on): they choose nothing
     and count nothing. Stats [4] int32, where asked for, is this call's
     (pairs on held experts, held experts with at least one pair, pairs
@@ -300,7 +303,8 @@ def _moe_experts_emit(ctx, op):
         w = jnp.where((jnp.arange(rows) < n)[:, None], w, 0.0)
     if op.input('W3'):
         out = held_gated_experts(lat.reshape(rows, width), w, w1,
-                                 ctx.get(op.single_input('W3')), w2)
+                                 ctx.get(op.single_input('W3')), w2,
+                                 op.attr('act', 'silu'))
     else:
         out = held_experts(lat.reshape(rows, width), w, w1, w2)
     ctx.set(op.single_output('Out'), out.reshape(lat.shape))

@@ -10,7 +10,12 @@ kv_page_cow wherever a forked page is copied: inside a prefill chunk's
 program, in front of a decode step's), table [S, P] int32, positions
 [S] int32. Lane s attends to logical positions 0..positions[s]. KVH
 divides H: query head h reads K/V head h // (H / KVH); where the two
-counts are equal every head has its own.
+counts are equal every head has its own. With a `window` (a sliding
+layer's; 0: none) lane s attends to max(0, positions[s] - window +
+1)..positions[s]: its walk starts at the page that holds the first of
+them, the pages before it are never copied, and the first page is
+masked to the window's edge as the last is to the position. Such a call
+carries the name `paged_window_attention` in the device's trace.
 
 The pools stay in HBM; table and positions are scalar-prefetched. The
 grid is one step per lane, and a lane walks ceil((pos + 1) / pt) pages
@@ -88,13 +93,19 @@ def supported(page_tokens, head_dim):
 
 def _kernel(table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
             kbuf, vbuf, sems, slot_ref, *, sm_scale, pt, heads, kv_heads,
-            bp, pages_per_slot, lanes):
+            bp, pages_per_slot, lanes, window=0):
     s = pl.program_id(0)
     cols = bp * pt * kv_heads
     rep = heads // kv_heads
 
+    def first_page(lane):
+        """The page a lane's walk starts at: the one that holds the
+        first position of its window."""
+        return jnp.maximum(pos_ref[lane] - (window - 1), 0) // pt
+
     def n_pages(lane):
-        return jnp.minimum(pos_ref[lane] // pt + 1, pages_per_slot)
+        last = jnp.minimum(pos_ref[lane] // pt + 1, pages_per_slot)
+        return last - first_page(lane) if window else last
 
     def copies(lane, blk, slot, wait=False):
         """Start (or wait for) the copies of block `blk` of `lane`, its
@@ -105,7 +116,8 @@ def _kernel(table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
 
             @pl.when(g < live)
             def _():
-                page = table_ref[lane * pages_per_slot + g]
+                at = g + first_page(lane) if window else g
+                page = table_ref[lane * pages_per_slot + at]
                 for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
                     dma = pltpu.make_async_copy(
                         hbm.at[page], buf.at[slot, j], sems.at[which, slot])
@@ -144,7 +156,11 @@ def _kernel(table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
         v = vbuf[slot].reshape(cols, v_hbm.shape[-1])
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        live = jnp.logical_and(own, tok <= pos - i * (bp * pt))
+        if window:
+            at = tok + (first_page(s) + i * bp) * pt    # a column's position
+            live = own & (at <= pos) & (at > pos - window)
+        else:
+            live = jnp.logical_and(own, tok <= pos - i * (bp * pt))
         sc = jnp.where(live, sc, _NEG_INF)                   # [H, cols]
         m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
         p = jnp.exp(sc - m_new)
@@ -163,15 +179,17 @@ def _kernel(table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[...] = (acc / l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=('sm_scale', 'interpret'))
+@functools.partial(jax.jit,
+                   static_argnames=('sm_scale', 'interpret', 'window'))
 def paged_attention(q, k_pool, v_pool, table, positions, sm_scale,
-                    interpret=False):
+                    interpret=False, window=0):
     """q [S, H, dh], pools [N, pt, KVH, dh], table [S, P] int32,
     positions [S] int32 -> [S, H, dh]: softmax over lane s's positions
-    0..positions[s] of sm_scale * q . k, times v, in fp32, query head h
-    against K/V head h // (H / KVH). A table entry is read only below a
-    lane's page count; it must name a page of the pool (the caller
-    clips)."""
+    0..positions[s] (the last `window` of them where one is given) of
+    sm_scale * q . k, times v, in fp32, query head h against K/V head
+    h // (H / KVH). A table entry is read only below a lane's page count
+    (and from its window's first page on); it must name a page of the
+    pool (the caller clips)."""
     S, H, dh = q.shape
     N, pt, KVH = k_pool.shape[:3]
     if H % KVH:
@@ -183,7 +201,8 @@ def paged_attention(q, k_pool, v_pool, table, positions, sm_scale,
     v3 = v_pool.reshape(N, pt * KVH, dh)
     kernel = functools.partial(
         _kernel, sm_scale=float(sm_scale), pt=pt, heads=H, kv_heads=KVH,
-        bp=bp, pages_per_slot=P, lanes=S)
+        bp=bp, pages_per_slot=P, lanes=S,
+        **({'window': int(window)} if window else {}))
     lane = pl.BlockSpec((None, H, dh), lambda s, *_: (s, 0, 0))
     buf = pltpu.VMEM((2, bp, pt * KVH, dh), k_pool.dtype)
     return pl.pallas_call(
@@ -202,7 +221,7 @@ def paged_attention(q, k_pool, v_pool, table, positions, sm_scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary',)),
         interpret=pltpu.InterpretParams() if interpret else False,
-        name='paged_attention',
+        name='paged_window_attention' if window else 'paged_attention',
     )(table.reshape(-1), positions, q, k3, v3)
 
 

@@ -780,7 +780,7 @@ class ServingEngine(object):
         lane = lanes.pop(slot)
         req = lane.req
         snap = None
-        if policy == 'swap':
+        if policy == 'swap' and getattr(pred, 'swappable', True):
             snap = pred.save_stream(slot)
             if self._swap_budget.reserve(snap['nbytes']):
                 _preempt.swapped_pages.inc(snap['pages'])

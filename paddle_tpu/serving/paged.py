@@ -99,6 +99,21 @@ recompile nothing. Speculation, export_prefix / install_prefix and
 mesh serving refuse recurrent state by name, as before, and the fleet's
 prefix directory is told nothing of such a model's pages
 (prefix_report, resident_keys).
+A model with sliding layers (models/smallthinker.py: spec.window_layers)
+keeps a stream's pages in TWO tables over two pools: the full layers'
+as above, and the sliding layers' in a table with a window
+(serving/paging.py: a sliding list) over a pool sized apart
+(`window_pages`, beside `kv_pages`). Every place below that settles a
+stream's table settles both: a chunk and a step fork and grow both,
+feed both (the window table's rows as they stand, and the positions
+counted from its first row), and, once booked, the window table gives up
+the pages that lie wholly behind the next position's window; the prefix
+cache keeps a window page beside the full one and hands out a boundary
+only with its window tail (PrefixCache.match_window). What moves a
+stream's pages as one table refuses such a model by name: speculation,
+mesh serving, export_prefix / install_prefix, save_stream /
+restore_stream (`swappable` is False: the engine re-prefills a stream it
+preempts). A model without such layers takes none of this.
 A model whose layers keep a latent page (models/axk1.py: one pool a
 layer, [pages, page_tokens, row]) keeps nothing else for a stream, so
 everything here that moves pages (the prefix cache with copy-on-write,
@@ -117,7 +132,9 @@ step; the same pair is on every `paged.decode.tables` span, beside
 flight, `carried`, the lanes whose token it took from that step on
 the device, and `carried_prefill`, the lanes whose token it took from
 their prompt's last chunk, dispatched in front of it and not waited
-for);
+for; for a model with sliding layers also `rows_read` and
+`window_rows_read`, the K/V rows a full layer's and a sliding layer's
+attention has to read in the step);
 serving.state_lanes counter (lanes whose recurrent state the decode
 steps updated; attr `state_lanes` of the same span),
 serving.recurrent_state_bytes and serving.state_resets gauges (bytes
@@ -134,6 +151,14 @@ serving.latent.cache_bytes (pages in use x the bytes a page's rows
 take over all layers) and the counter serving.latent.rows_read (rows
 the decode steps' attention read: each live lane's pos + 1, every
 layer; attr `latent_rows` of `paged.decode.tables`).
+For a model with sliding layers: the counter serving.window_pages_freed
+(pages their tables gave up behind the window), the gauges
+serving.window_pages_in_use (pages of the window pool handed out: open
+streams' and the prefix cache's) and serving.window_pages_live (those
+open streams hold), and the counters serving.prefix.window_tail_adopted
+/ serving.prefix.window_tail_miss (a stream opened on a boundary with
+its window tail; a deeper boundary was resident in the full pool and
+passed over because its window tail was not).
 Copy-on-write in front of a decode step: serving.cow.dispatches (decode
 steps that forked a page and so ran the copy) and serving.cow.pages
 (the pages they copied: the pairs that are not null). They count forks
@@ -217,6 +242,11 @@ _snaps_taken = telemetry.counter('serving.state.snapshots_taken')
 _snaps_adopted = telemetry.counter('serving.state.snapshots_adopted')
 _snaps_evicted = telemetry.counter('serving.state.snapshots_evicted')
 _snap_bytes = telemetry.gauge('serving.state.snapshot_bytes')
+_window_freed = telemetry.counter('serving.window_pages_freed')
+_window_in_use = telemetry.gauge('serving.window_pages_in_use')
+_window_live = telemetry.gauge('serving.window_pages_live')
+_window_tail_adopted = telemetry.counter('serving.prefix.window_tail_adopted')
+_window_tail_miss = telemetry.counter('serving.prefix.window_tail_miss')
 _MOE_COUNTS = ('pairs', 'experts_touched', 'pairs_dropped', 'layer_calls')
 
 
@@ -266,7 +296,7 @@ class PagedDecodePredictor(object):
 
     def __init__(self, predictor, slots=None, page_tokens=None,
                  kv_pages=None, prefill_chunk=None, _clone_of=None,
-                 pair=None, mesh=None, snapshot_rows=0):
+                 pair=None, mesh=None, snapshot_rows=0, window_pages=None):
         """predictor: a (loaded) Predictor/AnalysisPredictor whose
         program is a decoder-only LM. slots defaults to
         FLAGS_serving_slots, the page geometry to FLAGS_serving_*.
@@ -279,7 +309,8 @@ class PagedDecodePredictor(object):
         DecodeSpec.serve_param_specs, greedy decode stays bit-exact vs
         single-chip (serving/mesh.py). snapshot_rows (a model with
         recurrent layers): the prefix boundaries that keep their
-        recurrent state on the device (module docstring)."""
+        recurrent state on the device (module docstring). window_pages
+        (a model with sliding layers): the pages their pools hold."""
         self._base = predictor
         if _clone_of is not None:
             self._pair = _clone_of._pair
@@ -297,14 +328,17 @@ class PagedDecodePredictor(object):
                     predictor._program, slots=slots,
                     page_tokens=page_tokens, kv_pages=kv_pages,
                     prefill_chunk=prefill_chunk,
-                    snapshot_rows=int(snapshot_rows or 0))
+                    snapshot_rows=int(snapshot_rows or 0),
+                    window_pages=window_pages)
             self._weight_scope = predictor._scope
             self._mesh, self._mesh_shape = serving_mesh(mesh)
             if self._mesh is not None:
-                from ..models.transformer import refuse_latent_pages
+                from ..models.transformer import (refuse_latent_pages,
+                                                  refuse_window)
                 what = 'mesh serving (%s)' % self._mesh_shape
                 self._refuse_recurrent(what)
                 refuse_latent_pages(self._pair.spec, what)
+                refuse_window(self._pair.spec, what)
             self._pair.spec.mesh = self._mesh_shape
         self._exe = self._make_executor(predictor._place)
         if _clone_of is None:
@@ -410,6 +444,12 @@ class PagedDecodePredictor(object):
         """True for a model with per-slot recurrent state."""
         return bool(self._pair.state_names)
 
+    @property
+    def swappable(self):
+        """Whether save_stream / restore_stream can carry a stream of
+        this model (not one with a second table: module docstring)."""
+        return not self._pair.window_num_pages
+
     def _recurrent_state_bytes(self):
         shapes = self._pair.spec.state_shapes(self.slots) \
             if self.recurrent else ()
@@ -446,17 +486,21 @@ class PagedDecodePredictor(object):
             self.moe_counters(leave=64)     # nobody asks: keep it short
         return out[0], out[1]
 
-    def _copy_pages(self, pairs):
+    def _copy_pages(self, pairs, window_pairs=()):
         """The page copy program over `pairs` of (src, dst) pages, at
         most one a slot: one dispatch copies them in every pool, each
         donated and updated in place. The rest of the feed is the null
-        page onto itself."""
-        src = np.zeros((self.slots,), np.int32)
-        dst = np.zeros((self.slots,), np.int32)
-        for i, one in enumerate(pairs):
-            src[i], dst[i] = one
+        page onto itself. `window_pairs`: the same for the pools of a
+        model's sliding layers (its second pair of feeds)."""
+        feeds = []
+        for some in (pairs, window_pairs)[:len(self._pair.copy_feeds) // 2]:
+            src = np.zeros((self.slots,), np.int32)
+            dst = np.zeros((self.slots,), np.int32)
+            for i, one in enumerate(some):
+                src[i], dst[i] = one
+            feeds += [src, dst]
         self._exe.run(self._pair.copy_program,
-                      feed=dict(zip(self._pair.copy_feeds, (src, dst))),
+                      feed=dict(zip(self._pair.copy_feeds, feeds)),
                       scope=self._scope, return_numpy=False)
 
     def _carry_first(self, prev, slot, ids):
@@ -468,6 +512,14 @@ class PagedDecodePredictor(object):
         return _with_first(put('decode_prev_ids', prev), np.int32(slot),
                            put('decode_prev_ids', ids))
 
+    @staticmethod
+    def _slide(wtable, length):
+        """A booked chunk or step moved a stream on to `length` tokens:
+        its table of the sliding layers gives up what lies behind the
+        next position's window."""
+        wtable.length = length
+        _window_freed.inc(wtable.slide())
+
     def _fork_pages(self, cows):
         """Copy the pages a decode step forked, in front of its program:
         one dispatch if `cows` (the step's (table, index, (src, dst))
@@ -477,7 +529,9 @@ class PagedDecodePredictor(object):
         if not cows:
             return
         with RecordEvent('paged.cow', pages=len(cows)):
-            self._copy_pages([pair for _table, _idx, pair in cows])
+            self._copy_pages(*(
+                [pair for table, _, pair in cows if bool(table.window) == w]
+                for w in ((False, True) if self._wpool else (False,))))
         _cow_dispatches.inc()
         _cow_pages.inc(len(cows))
 
@@ -516,7 +570,15 @@ class PagedDecodePredictor(object):
                 'prefix_pages': self._prefix.resident_pages,
                 'prefix_tokens_reused': self._prefix.tokens_reused,
                 'snapshot_rows': self._pair.snapshot_rows,
-                'snapshots': self._prefix.snapshots}
+                'snapshots': self._prefix.snapshots,
+                'window_num_pages': self._pair.window_num_pages,
+                'window_pages_in_use': self._wpool.pages_in_use
+                if self._wpool else 0,
+                'window_pages_live': self._window_pages_live()}
+
+    def _window_pages_live(self):
+        """Pages of the window pool that open streams' tables hold."""
+        return sum(len(t.pages) for t in self._wtables.values())
 
     def _update_gauges(self):
         _pages_in_use.set(self._pool.pages_in_use)
@@ -528,6 +590,9 @@ class PagedDecodePredictor(object):
         if self._pair.spec.page_kind == 'latent':
             _latent_bytes.set(self._pool.pages_in_use * self.page_tokens
                               * self._pair.spec.latent_row_bytes())
+        if self._wpool is not None:
+            _window_in_use.set(self._wpool.pages_in_use)
+            _window_live.set(self._window_pages_live())
 
     # -- lifecycle ---------------------------------------------------------
     def _pin_weights(self):
@@ -664,8 +729,7 @@ class PagedDecodePredictor(object):
         prefix (fresh allocator state). On a mesh the zeroed pools land
         under the heads-sharded pin up front (steady-state layout from
         step one)."""
-        shape = self._pair.pool_shape
-        for name in self._pair.cache_names:
+        for name, shape in self._pair.cache_shapes():
             self._scope.set_var(name, self._place_cache(
                 name, np.zeros(shape, np.float32)))
         spec = self._pair.spec
@@ -684,9 +748,19 @@ class PagedDecodePredictor(object):
         self._moe_totals = {p + what: 0 for what in _MOE_COUNTS
                             for p in ('', 'decode.')}
         self._pool = PagePool(self.num_pages, self.page_tokens)
-        self._prefix = PrefixCache(self._pool, snapshot_rows=rows)
+        # a model with sliding layers: their pool and each stream's
+        # table of them (None and empty otherwise)
+        self._wpool = PagePool(self._pair.window_num_pages,
+                               self.page_tokens) \
+            if self._pair.window_num_pages else None
+        self._wtables = {}            # slot -> PageTable with a window
+        self._prefix = PrefixCache(self._pool, snapshot_rows=rows,
+                                   window_pool=self._wpool,
+                                   window=spec.window)
         self._snaps_gone = 0          # of them, counted so far
         self._pool.set_evict(self._prefix.evict_one)
+        if self._wpool is not None:
+            self._wpool.set_evict(self._prefix.evict_window_one)
         self._tables = {}             # slot -> PageTable
         self._pending = {}            # slot -> _PendingPrefill
         self._resets = 0              # streams started from zero state
@@ -739,7 +813,19 @@ class PagedDecodePredictor(object):
         # pinned until its first chunk has copied it), or on nothing
         # (see the module docstring)
         snapshot = None
-        if not self.recurrent:
+        if self._wpool is not None:
+            wtable = self._wtables[slot] = PageTable(
+                self._wpool, self._pair.window_pages_per_slot,
+                window=self._pair.spec.window)
+            missed = self._prefix.window_tail_misses
+            pages, shared, wpages, wfirst = self._prefix.match_window(
+                prompt, limit=len(prompt) - 1)
+            if shared:
+                wtable.adopt_shared(wpages, shared, first=wfirst)
+                _window_tail_adopted.inc()
+            if self._prefix.window_tail_misses > missed:
+                _window_tail_miss.inc()
+        elif not self.recurrent:
             pages, shared = self._prefix.match(prompt,
                                                limit=len(prompt) - 1)
         elif self._pair.snapshot_rows:
@@ -769,10 +855,13 @@ class PagedDecodePredictor(object):
         device runs it after it."""
         slot = int(slot)
         table = self._tables.pop(slot, None)
+        wtable = self._wtables.pop(slot, None)
         st = self._pending.pop(slot, None)
         self._first.pop(slot, None)
         if st is not None and st.snapshot is not None:
             self._prefix.unpin(st.snapshot)
+        if wtable is not None:
+            wtable.release()
         if table is not None:
             table.release()
             self._update_gauges()
@@ -785,6 +874,7 @@ class PagedDecodePredictor(object):
         itself is untouched — the caller release()s the slot only after
         the copy succeeded, so a failed gather never loses pages."""
         slot = int(slot)
+        self._refuse_window('save_stream')
         if slot in self._pending:
             raise RuntimeError('slot %d is still prefilling — requeue '
                                'it, there is nothing worth swapping'
@@ -816,6 +906,7 @@ class PagedDecodePredictor(object):
         `prompt` (the committed token sequence) is unused here; the
         speculative override re-prefills its draft from it."""
         slot = int(slot)
+        self._refuse_window('restore_stream')
         if slot in self._tables:
             raise RuntimeError('slot %d already holds a stream — '
                                'release() it first' % slot)
@@ -851,6 +942,7 @@ class PagedDecodePredictor(object):
         per layer pool), 'nbytes'}. A pure read: refcounts, tables and
         LRU stamps are untouched."""
         self._refuse_recurrent('page shipping (export_prefix)')
+        self._refuse_window('page shipping (export_prefix)')
         prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
         digests, pages = self._prefix.chain(prompt,
                                             limit=len(prompt) - 1)
@@ -870,7 +962,7 @@ class PagedDecodePredictor(object):
         skips pages already here. Advisory (no quiesce, no LRU touch):
         install_prefix re-checks residency under the swap gate, so a
         racing eviction only costs wire bytes, never correctness."""
-        if self.recurrent:
+        if self.recurrent or self._wpool is not None:
             return []           # such pages are worth nothing elsewhere
         prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
         digests, _ = self._prefix.chain(prompt, limit=len(prompt) - 1)
@@ -891,6 +983,7 @@ class PagedDecodePredictor(object):
         page counts; raises the retryable CacheExhaustedError with
         nothing taken when the pool cannot fit the fresh rows."""
         self._refuse_recurrent('page shipping (install_prefix)')
+        self._refuse_window('page shipping (install_prefix)')
         prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
         keys = list(keys)
         skip = int(skip)
@@ -928,13 +1021,18 @@ class PagedDecodePredictor(object):
         from ..models.transformer import refuse_recurrent
         refuse_recurrent(self._pair.spec, what)
 
+    def _refuse_window(self, what):
+        from ..models.transformer import refuse_window
+        refuse_window(self._pair.spec, what)
+
     def prefix_report(self):
         """Drain the prefix cache's registered/evicted delta (the
         SRV_HEALTH payload feeding the fleet prefix directory). The
         directory is told nothing of pages that are a prefix only with
         a snapshot row of this predictor."""
         events = self._prefix.drain_events()
-        return {'new': [], 'evicted': []} if self.recurrent else events
+        return {'new': [], 'evicted': []} \
+            if self.recurrent or self._wpool is not None else events
 
     @staticmethod
     def _rollback(cows, grows):
@@ -977,27 +1075,29 @@ class PagedDecodePredictor(object):
         C, P, pt = self.prefill_chunk, self.pages_per_slot, self.page_tokens
         n = min(C, len(prompt) - start)
         cows, grows = [], []
+        wtable = self._wtables.get(slot)
         with RecordEvent('paged.prefill.tables'):
-            before = len(table.pages)
             try:
-                pair = table.cow_for_append(start)
-                if pair is not None:
-                    cows.append((table, start // pt, pair))
-                table.ensure(start + n)
+                for one in (table, wtable) if wtable else (table,):
+                    before = len(one.pages)
+                    pair = one.cow_for_append(start)
+                    if pair is not None:
+                        cows.append((one, one.index(start), pair))
+                    one.ensure(start + n)
+                    if len(one.pages) > before:
+                        grows.append((one, before))
             except CacheExhaustedError as e:
                 self._rollback(cows, grows)
                 raise CacheExhaustedError(str(e), slots=[slot])
-            if len(table.pages) > before:
-                grows.append((table, before))
             tokens = np.zeros((1, C, 1), np.int64)
             tokens[0, :n, 0] = prompt[start:start + n]
             positions = (start + np.arange(C, dtype=np.int32))
             table_feed = np.zeros((1, P), np.int32)
             table.row(table_feed[0])
-            cow_src = np.zeros((1,), np.int32)
-            cow_dst = np.zeros((1,), np.int32)
-            if cows:
-                cow_src[0], cow_dst[0] = cows[0][2]
+            # a chunk forks at most one page a table: (0, 0) where none
+            forked = {bool(one.window): pair for one, _idx, pair in cows}
+            cow_src, cow_dst = (np.array([p], np.int32)
+                                for p in forked.get(False, (0, 0)))
             feed = {'prefill_tokens': tokens,
                     'prefill_positions': positions,
                     'prefill_len': np.array([n], np.int32),
@@ -1013,6 +1113,16 @@ class PagedDecodePredictor(object):
                 if start == 0:
                     self._resets += 1
                     _state_resets.set(self._resets)
+            if wtable is not None:
+                wfeed = np.zeros((1, wtable.width), np.int32)
+                wtable.row(wfeed[0])
+                wsrc, wdst = (np.array([p], np.int32)
+                              for p in forked.get(True, (0, 0)))
+                feed.update(prefill_window_table=wfeed,
+                            prefill_window_positions=positions - np.int32(
+                                wtable.base),
+                            prefill_window_cow_src=wsrc,
+                            prefill_window_cow_dst=wdst)
         if not self._state_copy_compiled:
             # the two state copy programs compile where the prefill
             # program does: a copy of this slot's rows, which the chunk
@@ -1040,10 +1150,17 @@ class PagedDecodePredictor(object):
                 table_.pool.unref(src)
             table.length = start + n
             st.chunks += 1
+            if wtable is not None:
+                if table.length == len(prompt):
+                    # both tables, before the second gives up what the
+                    # prompt's end no longer reads: a boundary a little
+                    # short of it finds its window tail too
+                    self._prefix.register(prompt, table, wtable)
+                self._slide(wtable, table.length)
             self._update_gauges()
             if table.length < len(prompt):
                 return None
-            if not self.recurrent:
+            if wtable is None and not self.recurrent:
                 self._prefix.register(prompt, table)
             elif self._pair.snapshot_rows:
                 row = self._prefix.register_state(prompt, table)
@@ -1138,26 +1255,35 @@ class PagedDecodePredictor(object):
             pos_feed = np.zeros((S,), np.int32)
             carry_feed = np.zeros((S,), np.int32)
             carry_feed[carry] = 1
+            if self._wpool is not None:
+                wtable_feed = np.zeros(
+                    (S, self._pair.window_pages_per_slot), np.int32)
+                wpos_feed = np.zeros((S,), np.int32)
             cows, grows, failed, live = [], [], [], []
             for slot in sorted(self._tables if lanes is None
                                else map(int, lanes)):
                 if slot in self._pending:
                     continue          # mid-prefill: stays on null pages
                 table = self._tables[slot]
+                wtable = self._wtables.get(slot)
                 pos = int(positions[slot])
-                before = len(table.pages)
                 try:
-                    pair = table.cow_for_append(pos)
-                    if pair is not None:
-                        cows.append((table, pos // pt, pair))
-                    table.ensure(pos + 1)
+                    for one in (table, wtable) if wtable else (table,):
+                        before = len(one.pages)
+                        pair = one.cow_for_append(pos)
+                        if pair is not None:
+                            cows.append((one, one.index(pos), pair))
+                        one.ensure(pos + 1)
+                        if len(one.pages) > before:
+                            grows.append((one, before))
                 except CacheExhaustedError:
                     failed.append(slot)
                     continue
-                if len(table.pages) > before:
-                    grows.append((table, before))
                 table.row(table_feed[slot])
                 pos_feed[slot] = pos
+                if wtable is not None:
+                    wtable.row(wtable_feed[slot])
+                    wpos_feed[slot] = pos - wtable.base
                 live.append(slot)
             if failed:
                 self._rollback(cows, grows)
@@ -1185,6 +1311,17 @@ class PagedDecodePredictor(object):
             live_feed[live] = 1
             if 'decode_live' in self._pair.decode_feeds:
                 feed['decode_live'] = live_feed
+            if self._wpool is not None:
+                feed['decode_window_table'] = wtable_feed
+                feed['decode_window_step_idx'] = wpos_feed
+                # the rows the step's attention has to read: in a full
+                # layer a lane's tokens so far and the one it appends,
+                # in a sliding layer the last `window` of them
+                ev.attrs['rows_read'] = sum(
+                    int(pos_feed[slot]) + 1 for slot in live)
+                ev.attrs['window_rows_read'] = sum(
+                    min(int(pos_feed[slot]) + 1, self._pair.spec.window)
+                    for slot in live)
             if self._pair.spec.page_kind == 'latent':
                 # the rows the step's attention reads: a lane's tokens
                 # so far and the one it appends, in every layer
@@ -1223,6 +1360,8 @@ class PagedDecodePredictor(object):
             for slot in live:
                 table = self._tables[slot]
                 table.length = max(table.length, int(positions[slot]) + 1)
+                if slot in self._wtables:
+                    self._slide(self._wtables[slot], table.length)
             self._update_gauges()
         prev, self._last_ids, self._in_flight = self._last_ids, ids, defer
         with RecordEvent('paged.decode.fetch'):

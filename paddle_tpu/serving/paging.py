@@ -26,6 +26,16 @@ values; everything stateful lives HERE, on the host, in plain Python:
                before writing any destination, a page freed and
                reallocated within the same step still copies its
                pre-step contents.
+               A table with a `window` is a sliding layer's: a SLIDING
+               LIST over a pool of its own. pages[j] is logical page
+               first + j; once a chunk or a step is booked, every page
+               that lies wholly behind the next position's window is
+               given up (unref: one the prefix cache also holds lives
+               on there) and `first` moves up, so the table never holds
+               more than the window and a chunk. What the device is fed
+               is the list as it stands and positions counted from its
+               first page (`base`): the kernels see a table and
+               positions, as they do for a full layer.
   PrefixCache  content-hash chain over FULL pages (h_k = sha1(h_{k-1}
                || tokens of page k) -> physical page) plus
                partial-tail entries keyed by (chain hash, tail tokens)
@@ -33,7 +43,13 @@ values; everything stateful lives HERE, on the host, in plain Python:
                granularity. The cache holds its own +1 ref on every
                registered page so shared prefixes survive stream
                churn; entries are evicted leaf-first by LRU when the
-               pool runs dry.
+               pool runs dry. For a model with sliding layers an entry
+               may hold a page of the window pool beside its page of
+               the full pool: what the registering stream still held,
+               the last `window` tokens' pages. A boundary is handed
+               out only with the window pages that cover its last
+               `window` tokens (match_window); the window pool's
+               eviction drops an entry's window page alone.
 
 Sharing is capped at prompt[:-1]: the last prompt token is always
 recomputed, because its logits produce the stream's first output
@@ -195,14 +211,19 @@ class PagePool(object):
 
 class PageTable(object):
     """One stream's page index: logical position j lives at
-    pages[j // page_tokens] offset j % page_tokens. `shared` marks
-    table indices whose page is referenced elsewhere (prefix cache or
-    another stream) and therefore read-only for this stream."""
+    pages[j // page_tokens - first] offset j % page_tokens. `shared`
+    marks table indices whose page is referenced elsewhere (prefix cache
+    or another stream) and therefore read-only for this stream. `first`
+    is 0 and stays 0 unless the table has a `window` (the sliding list
+    of the module docstring): slide() then gives up the pages behind
+    the window and counts `first` up."""
 
-    def __init__(self, pool, width):
+    def __init__(self, pool, width, window=0):
         self.pool = pool
         self.width = int(width)             # table entries (P)
+        self.window = int(window)           # tokens a row sees; 0: all
         self.pages = []                     # physical page ids
+        self.first = 0                      # logical page of pages[0]
         self.length = 0                     # tokens written so far
         self.shared = set()                 # read-only table indices
 
@@ -210,13 +231,25 @@ class PageTable(object):
     def capacity(self):
         return self.width * self.pool.page_tokens
 
-    def adopt_shared(self, pages, tokens):
+    @property
+    def base(self):
+        """The position of the table's first row: what a sliding
+        layer's positions are counted from."""
+        return self.first * self.pool.page_tokens
+
+    def index(self, position):
+        """The table index of the page that holds `position`."""
+        return int(position) // self.pool.page_tokens - self.first
+
+    def adopt_shared(self, pages, tokens, first=0):
         """Seed a fresh table with prefix-cache pages (the cache's own
-        refs are untouched; this stream takes one more each)."""
+        refs are untouched; this stream takes one more each); `first`,
+        the logical page of pages[0] (a window's tail)."""
         assert not self.pages and not self.length
-        if tokens > len(pages) * self.pool.page_tokens:
+        if tokens > (first + len(pages)) * self.pool.page_tokens:
             raise ValueError('shared prefix %d tokens > %d pages'
-                             % (tokens, len(pages)))
+                             % (tokens, first + len(pages)))
+        self.first = int(first)
         for p in pages:
             self.pool.share(p)
             self.shared.add(len(self.pages))
@@ -231,7 +264,7 @@ class PageTable(object):
         All-or-nothing; raises CacheExhaustedError past `width` pages
         or an empty pool. Idempotent for already-covered extents."""
         tokens = int(tokens)
-        need = -(-tokens // self.pool.page_tokens)      # ceil
+        need = -(-tokens // self.pool.page_tokens) - self.first   # ceil
         if need > self.width:
             raise CacheExhaustedError(
                 'stream needs %d pages, table width is %d (%d-token '
@@ -247,7 +280,7 @@ class PageTable(object):
         only AFTER the device copy actually ran, so a step that fails
         after this fork can roll back (restore src, unref dst) without
         ever touching a freed page."""
-        idx = int(position) // self.pool.page_tokens
+        idx = self.index(position)
         if idx >= len(self.pages) or idx not in self.shared:
             return None
         dst = self.pool.alloc()
@@ -263,12 +296,32 @@ class PageTable(object):
         out[:len(self.pages)] = self.pages
         return out
 
+    def slide(self):
+        """Give up every page that lies wholly behind the window of the
+        next position (`length`: the rows 0..length - window of a
+        stream are never read again) and return how many went; nothing
+        without a window. Called once a chunk or a step is booked, so a
+        call that raised has given up nothing."""
+        if not self.window:
+            return 0
+        keep = max(0, self.length - self.window + 1) \
+            // self.pool.page_tokens
+        n = min(keep - self.first, len(self.pages))
+        if n <= 0:
+            return 0
+        for p in self.pages[:n]:
+            self.pool.unref(p)
+        del self.pages[:n]
+        self.first += n
+        self.shared = {i - n for i in self.shared if i >= n}
+        return n
+
     def release(self):
         for p in self.pages:
             self.pool.unref(p)
         self.pages = []
         self.shared = set()
-        self.length = 0
+        self.length = self.first = 0
 
 
 def _digest(prev, tokens):
@@ -294,10 +347,11 @@ def chain_keys(tokens, page_tokens, limit=None):
 
 
 class _Node(object):
-    __slots__ = ('page', 'parent', 'children', 'tails', 'stamp')
+    __slots__ = ('page', 'wpage', 'parent', 'children', 'tails', 'stamp')
 
     def __init__(self, page, parent):
         self.page = page
+        self.wpage = None        # its page of the window pool, if held
         self.parent = parent     # chain digest of the previous node
         self.children = 0
         self.tails = 0
@@ -305,10 +359,11 @@ class _Node(object):
 
 
 class _Tail(object):
-    __slots__ = ('page', 'tokens', 'chain', 'stamp')
+    __slots__ = ('page', 'wpage', 'tokens', 'chain', 'stamp')
 
     def __init__(self, page, tokens, chain):
         self.page = page
+        self.wpage = None
         self.tokens = tokens
         self.chain = chain
         self.stamp = 0
@@ -354,8 +409,12 @@ class PrefixCache(object):
     LEAF (no children, no tails) so interior chain pages are never
     orphaned while still reachable."""
 
-    def __init__(self, pool, snapshot_rows=0):
+    def __init__(self, pool, snapshot_rows=0, window_pool=None, window=0):
         self.pool = pool
+        # a model with sliding layers: their pool, and the tokens a row
+        # of theirs sees (match_window / register's second table)
+        self.window_pool, self.window = window_pool, int(window)
+        self.window_tail_misses = 0
         self._nodes = {}          # chain digest -> _Node
         self._tails = {}          # chain digest -> {tokens: _Tail}
         # recurrent state at prefix boundaries (match_state /
@@ -398,6 +457,17 @@ class PrefixCache(object):
                 return
             yield digest, node
 
+    def _longest_tail(self, chain, rest):
+        """The longest registered tail behind `chain` that prefixes the
+        tokens `rest`, or None."""
+        rest = tuple(int(t) for t in rest)
+        best = None
+        for tail_tokens, tail in self._tails.get(chain, {}).items():
+            if rest[:len(tail_tokens)] == tail_tokens and \
+                    (best is None or len(tail_tokens) > len(best.tokens)):
+                best = tail
+        return best
+
     # -- lookup ------------------------------------------------------------
     def match(self, prompt, limit=None):
         """Longest shared prefix of `prompt` (at most `limit` tokens;
@@ -416,12 +486,7 @@ class PrefixCache(object):
         k = len(pages)
         tokens = k * pt
         if k == full:             # a tail only connects at chain end
-            rest = tuple(int(t) for t in prompt[tokens:limit])
-            best = None
-            for tail_tokens, tail in self._tails.get(chain, {}).items():
-                if rest[:len(tail_tokens)] == tail_tokens and \
-                        (best is None or len(tail_tokens) > len(best.tokens)):
-                    best = tail
+            best = self._longest_tail(chain, prompt[tokens:limit])
             if best is not None:
                 self._touch(best)
                 pages.append(best.page)
@@ -435,6 +500,55 @@ class PrefixCache(object):
             # can never share and counts as neither)
             self.misses += 1
         return pages, tokens
+
+    def match_window(self, prompt, limit=None):
+        """match() where a prefix is pages of two pools: the deepest
+        boundary under `limit` whose last `window` tokens' pages are
+        resident in the window pool too (a row at the boundary reads
+        the positions boundary - window + 1 .. boundary - 1 of a
+        sliding layer; the rows before them it never reads, so their
+        window pages may be long gone). Returns (pages, tokens, wpages,
+        wfirst): the full pool's pages from logical page 0, and the
+        window pool's from logical page `wfirst`. A boundary whose
+        window tail is not resident is passed over for the deepest one
+        that has it (window_tail_misses counts the match); nothing
+        where none has."""
+        pt = self.pool.page_tokens
+        limit = len(prompt) if limit is None else min(limit, len(prompt))
+        full = limit // pt
+        nodes, chain = [], b''
+        for chain, node in self._resident(prompt, full):
+            nodes.append(node)
+        entries, tokens = list(nodes), len(nodes) * pt
+        if len(nodes) == full:    # a tail only connects at chain end
+            best = self._longest_tail(chain, prompt[tokens:limit])
+            if best is not None:
+                entries.append(best)
+                tokens += len(best.tokens)
+        resident = bool(entries)
+        # the run of entries with a window page that ends at each one
+        run, runs = 0, []
+        for e in entries:
+            run = run + 1 if e.wpage is not None else 0
+            runs.append(run)
+        while entries:
+            wfirst = max(0, tokens - self.window + 1) // pt
+            if runs[len(entries) - 1] >= len(entries) - wfirst:
+                break
+            entries.pop()
+            tokens = len(entries) * pt
+        if resident and len(entries) < len(runs):
+            self.window_tail_misses += 1
+        if not entries:
+            if limit > 0:
+                self.misses += 1
+            return [], 0, [], 0
+        for e in entries:
+            self._touch(e)
+        self.hits += 1
+        self.tokens_reused += tokens
+        return ([e.page for e in entries], tokens,
+                [e.wpage for e in entries[wfirst:]], wfirst)
 
     def chain(self, prompt, limit=None):
         """Walk the FULL-page hash chain registered for prompt[:limit]
@@ -479,12 +593,28 @@ class PrefixCache(object):
             chain = d
 
     # -- registration ------------------------------------------------------
-    def register(self, prompt, table):
+    def _keep_window_page(self, entry, k, wtable):
+        """Give `entry` (logical page k) the page of the window pool
+        that `wtable` holds for it, if the table still holds one and
+        the entry has none; the table's index is marked shared where
+        the entry's page is (now) the table's."""
+        idx = k - wtable.first
+        if not 0 <= idx < len(wtable.pages):
+            return
+        if entry.wpage is None:
+            entry.wpage = self.window_pool.share(wtable.pages[idx])
+        if entry.wpage == wtable.pages[idx]:
+            wtable.mark_shared(idx)
+
+    def register(self, prompt, table, wtable=None):
         """Index a freshly prefilled prompt's pages for future sharing.
         Takes one cache ref per newly registered page and returns the
         TABLE indices that are now shared (the caller marks them so the
         stream's own appends fork instead of scribbling on cached
-        pages)."""
+        pages). With `wtable`, the stream's table of its sliding
+        layers: every entry along the prompt also takes the window page
+        that the stream still holds for it (the last `window` tokens'
+        pages), marked shared there in the same way."""
         pt = self.pool.page_tokens
         full = len(prompt) // pt
         chain = b''
@@ -502,6 +632,8 @@ class PrefixCache(object):
                 self._announced.append(nxt.hex())
             elif node.page == table.pages[k]:
                 newly_shared.append(k)       # already cache-shared
+            if wtable is not None:
+                self._keep_window_page(node, k, wtable)
             self._touch(node)
             chain = nxt
         rest = tuple(int(t) for t in prompt[full * pt:])
@@ -517,6 +649,8 @@ class PrefixCache(object):
                 newly_shared.append(full)
             elif tails[rest].page == table.pages[full]:
                 newly_shared.append(full)
+            if wtable is not None:
+                self._keep_window_page(tails[rest], full, wtable)
             self._touch(tails[rest])
         for idx in newly_shared:
             table.mark_shared(idx)
@@ -643,9 +777,12 @@ class PrefixCache(object):
             digest = node.parent
 
     # -- eviction ----------------------------------------------------------
-    def _leaves(self):
+    def _leaves(self, leaves_only=True):
+        """(stamp, (kind, key, entry)) of every entry that may go: the
+        leaves, or every entry where `leaves_only` is off (a window page
+        may go from the middle of a chain)."""
         for digest, node in self._nodes.items():
-            if not node.children and not node.tails:
+            if not leaves_only or not (node.children or node.tails):
                 yield node.stamp, ('node', digest, node)
         for chain, tails in self._tails.items():
             for tokens, tail in tails.items():
@@ -667,6 +804,20 @@ class PrefixCache(object):
             self._drop_snap(snap, release=False)
         return True
 
+    def evict_window_one(self):
+        """The window pool's eviction: the least recently used entry
+        that holds a window page gives up that page alone (its page of
+        the full pool stays; a boundary that needed the window page is
+        no longer handed out). True if a ref was released."""
+        entry = min((e for _, (_, _, e) in self._leaves(leaves_only=False)
+                     if e.wpage is not None),
+                    default=None, key=lambda e: e.stamp)
+        if entry is None:
+            return False
+        self.window_pool.unref(entry.wpage)
+        entry.wpage = None
+        return True
+
     def _evict_entry(self, kind, key, entry):
         if kind == 'node':
             del self._nodes[key]
@@ -683,6 +834,9 @@ class PrefixCache(object):
             if node is not None:
                 node.tails -= 1
         self.pool.unref(entry.page)
+        if entry.wpage is not None:
+            self.window_pool.unref(entry.wpage)
+            entry.wpage = None
 
     def drain_events(self):
         """Take (and clear) the registered/evicted delta since the last

@@ -38,8 +38,8 @@ save_inference_model (see _recover_param_specs).
 
 What a spec's layers keep for a stream decides what can serve it, not
 its family's name: speculative decoding and mesh serving refuse
-recurrent state (refuse_recurrent) and latent pages
-(refuse_latent_pages). Genuinely unsupported layouts (the training MoE
+recurrent state (refuse_recurrent), latent pages
+(refuse_latent_pages) and sliding layers' second table (refuse_window). Genuinely unsupported layouts (the training MoE
 op moe_ffn, whose capacity drops tokens; ring attention; a constraint
 on an axis the serving mesh cannot honor) still raise
 DecodeTranspileError naming the offending op/axis — better a loud
@@ -51,13 +51,13 @@ from __future__ import annotations
 from .. import models
 from ..models.transformer import (DecodeSpec, DecodeTranspileError,
                                   refuse_latent_pages, refuse_recurrent,
-                                  build_page_copy_program,
+                                  refuse_window, build_page_copy_program,
                                   build_state_copy_programs,
                                   build_verify_program, snapshot_names)
 
 __all__ = ['DecodeTranspileError', 'PagedDecodePair', 'SpecDecodePair',
            'DecodeTranspiler', 'extract_decode_spec', 'refuse_recurrent',
-           'refuse_latent_pages']
+           'refuse_latent_pages', 'refuse_window']
 
 
 class PagedDecodePair(object):
@@ -82,6 +82,9 @@ class PagedDecodePair(object):
 
     snapshot_rows = 0
     snapshot_program = adopt_program = state_copy_feeds = None
+    # a model with sliding layers: the pages of their pools and the
+    # width of a stream's table of them (0: no such layers)
+    window_num_pages = window_pages_per_slot = 0
 
     def __init__(self, spec, slots, page_tokens, pages_per_slot,
                  num_pages, prefill_chunk,
@@ -116,6 +119,15 @@ class PagedDecodePair(object):
     @property
     def pool_shape(self):
         return self.spec.pool_shape(self.num_pages, self.page_tokens)
+
+    def cache_shapes(self):
+        """(pool var name, its shape) of every pool: a sliding layer's
+        hold window_num_pages pages, the others num_pages."""
+        spec = self.spec
+        return [(name, spec.pool_shape(
+            self.window_num_pages if i in spec.window_layers
+            else self.num_pages, self.page_tokens))
+            for i in spec.kv_layers for name in spec.pool_names(i)]
 
     def keep_snapshots(self, rows):
         """Give the pair `rows` snapshot rows and the two programs that
@@ -429,20 +441,24 @@ def extract_decode_spec(program):
 
 class DecodeTranspiler(object):
     def transpile(self, program, slots=8, page_tokens=None, kv_pages=None,
-                  prefill_chunk=None, snapshot_rows=0):
+                  prefill_chunk=None, snapshot_rows=0, window_pages=None):
         """program: a loaded inference Program (AnalysisPredictor's).
         Returns a PagedDecodePair whose cache is a page pool sized by
         page_tokens / kv_pages and whose prefill runs prefill_chunk-
         token chunks (each None defaults from FLAGS_serving_*, kv_pages
         0 auto-sizes to a full window for every slot). snapshot_rows
         (a model with recurrent layers; 0: none) is how many prefix
-        boundaries keep their recurrent state on the device. Raises
+        boundaries keep their recurrent state on the device.
+        window_pages (a model with sliding layers; None or 0 auto-sizes
+        to a full window table for every slot) is how many pages the
+        pools of those layers hold, sized apart from kv_pages. Raises
         DecodeTranspileError if the program is not a recognizable
         decoder-only LM."""
         if slots < 1:
             raise ValueError('slots must be >= 1, got %r' % (slots,))
         pair = self._transpile_paged(extract_decode_spec(program), slots,
-                                     page_tokens, kv_pages, prefill_chunk)
+                                     page_tokens, kv_pages, prefill_chunk,
+                                     window_pages)
         if snapshot_rows:
             pair.keep_snapshots(snapshot_rows)
         return pair
@@ -465,6 +481,7 @@ class DecodeTranspiler(object):
                          'speculative decoding')
         refuse_latent_pages(extract_decode_spec(program),
                             'speculative decoding')
+        refuse_window(extract_decode_spec(program), 'speculative decoding')
         target = self.transpile(program, slots=slots,
                                 page_tokens=page_tokens,
                                 kv_pages=kv_pages,
@@ -496,7 +513,7 @@ class DecodeTranspiler(object):
                               self_draft=draft_program is None)
 
     def _transpile_paged(self, spec, slots, page_tokens, kv_pages,
-                         prefill_chunk):
+                         prefill_chunk, window_pages=None):
         from ..flags import get_flag
         pt = int(page_tokens or get_flag('serving_page_tokens'))
         if pt < 1:
@@ -513,8 +530,32 @@ class DecodeTranspiler(object):
                              'reserved null page), got %d' % num_pages)
         chunk = int(prefill_chunk or get_flag('serving_prefill_chunk'))
         chunk = max(1, min(chunk, spec.max_len))
-        return PagedDecodePair(
+        if not spec.window_layers:
+            if window_pages:
+                raise ValueError('window_pages=%d for a model without '
+                                 'sliding_attention layers' % window_pages)
+            return PagedDecodePair(
+                spec, slots, pt, pages_per_slot, num_pages, chunk,
+                *(spec.build_paged_programs(slots, chunk, num_pages, pt,
+                                            pages_per_slot)
+                  + build_page_copy_program(spec, slots, num_pages, pt)))
+        if spec.recurrent_layers:
+            raise DecodeTranspileError(
+                'a model with sliding_attention layers and recurrent '
+                'layers: a prefix of it would be two tables and state')
+        # the sliding layers' pools and tables, sized apart
+        wide = spec.window_table_pages(chunk, pt)
+        window = {'window_pages': int(window_pages or slots * wide + 1),
+                  'window_pages_per_slot': wide}
+        if window['window_pages'] < 2:
+            raise ValueError('window_pages must be >= 2, got %d'
+                             % window['window_pages'])
+        pair = PagedDecodePair(
             spec, slots, pt, pages_per_slot, num_pages, chunk,
             *(spec.build_paged_programs(slots, chunk, num_pages, pt,
-                                        pages_per_slot)
-              + build_page_copy_program(spec, slots, num_pages, pt)))
+                                        pages_per_slot, **window)
+              + build_page_copy_program(spec, slots, num_pages, pt,
+                                        window['window_pages'])))
+        pair.window_num_pages = window['window_pages']
+        pair.window_pages_per_slot = wide
+        return pair

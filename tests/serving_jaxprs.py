@@ -15,7 +15,9 @@ one recorded from the parent of the PR that added snapshot rows
 (tests/serving_jaxprs_pr44.json: four families, no snapshot rows) and to
 the one recorded from the parent of the PR that left one paged builder
 (tests/serving_jaxprs_pr47.json: the same, and granite_h with snapshot
-rows and the GPT family under speculation).
+rows and the GPT family under speculation). The sixth family
+(smallthinker: two page tables) is held to the record of the PR that
+added it (tests/serving_jaxprs_pr49.json).
 """
 import hashlib
 import json
@@ -39,7 +41,7 @@ def _digest(text):
 
 def _models():
     from paddle_tpu.models import (axk1, granite_h, hybrid, nemotron_h,
-                                   transformer)
+                                   smallthinker, transformer)
     return {
         'gpt2': (transformer.language_model_logits,
                  transformer.TransformerConfig(
@@ -58,6 +60,10 @@ def _models():
                           vocab=64, dim=32, max_len=T, head_dim=8,
                           layer_types=('mamba', 'attention', 'mamba'),
                           expert_offset=4, experts_held=8)),
+        'smallthinker': (smallthinker.language_model_logits,
+                         smallthinker.SmallThinkerConfig(
+                             vocab=64, dim=32, max_len=T, head_dim=8,
+                             window=6)),
     }
 
 

@@ -35,9 +35,11 @@ DH = 128
 TOL = 2e-5
 
 
-def _run_op(q, kpool, vpool, table, positions, op_type='paged_attention'):
+def _run_op(q, kpool, vpool, table, positions, op_type='paged_attention',
+            window=0):
     """One decode-attention program through the real Executor:
-    the op, or (op_type='composition') the ops it replaced."""
+    the op (with a `window`: the sliding layers', under its own type),
+    or (op_type='composition') the ops it replaced."""
     prog, startup = Program(), Program()
     L = fluid.layers
     with program_guard(prog, startup):
@@ -50,12 +52,14 @@ def _run_op(q, kpool, vpool, table, positions, op_type='paged_attention'):
         if op_type == 'paged_attention':
             out = block.create_var(name='ctx', dtype='float32')
             block.append_op(
-                type='paged_attention',
+                type='paged_window_attention' if window
+                else 'paged_attention',
                 inputs={'Q': [v['q']], 'KPool': [v['kpool']],
                         'VPool': [v['vpool']], 'Table': [v['table']],
                         'Positions': [v['positions']]},
                 outputs={'Out': [out]},
-                attrs={'sm_scale': alpha, 'head_axis': ''})
+                attrs=dict({'sm_scale': alpha, 'head_axis': ''},
+                           **({'window': window} if window else {})))
         else:
             def gathered(pool):
                 g = block.create_var(name='g.' + pool.name, dtype='float32')
@@ -185,14 +189,16 @@ def test_reference_lowering_is_the_old_composition_bit_for_bit(heads, dh,
 # K/V heads fewer than query heads
 # ---------------------------------------------------------------------------
 
-def _dense_attention(q, kpool, vpool, table, positions):
-    """Each lane's attention over its own tokens, a query head against
-    K/V head h // (H / KVH), in float64 numpy: no pages, no kernel."""
+def _dense_attention(q, kpool, vpool, table, positions, window=0):
+    """Each lane's attention over its own tokens (the last `window` of
+    them where one is given), a query head against K/V head
+    h // (H / KVH), in float64 numpy: no pages, no kernel."""
     pt, rep = kpool.shape[1], q.shape[2] // kpool.shape[2]
     out = np.zeros(q.shape, np.float64)
     for s, pos in enumerate(positions):
         n = int(pos) + 1
-        rows = [(table[s, j // pt], j % pt) for j in range(n)]
+        rows = [(table[s, j // pt], j % pt)
+                for j in range(max(0, n - window) if window else 0, n)]
         k = np.stack([kpool[p, o] for p, o in rows]).astype(np.float64)
         v = np.stack([vpool[p, o] for p, o in rows]).astype(np.float64)
         for h in range(q.shape[2]):
@@ -497,3 +503,99 @@ def test_latent_kernel_lowers_for_the_chip_at_the_published_row():
     assert 'tensor<%dx%dx%dxf32>' % (N, pt, row) in text
     assert pa.latent_supported(16, 640, 512)
     assert not pa.latent_supported(16, 576, 512)
+
+
+# ---------------------------------------------------------------------------
+# a window: the sliding layers' calls
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize('window,lengths', [
+    # pos < window; the window's edge inside a page; on a page boundary;
+    # the last page full; one token
+    (20, [5, 19, 20, 21, 27, 28, 29, 88, 0, 1]),
+    (PT, [PT, PT + 1, 2 * PT, 3 * PT - 1, 70]),        # one page of keys
+    (3 * PT, [3 * PT, 3 * PT + 1, 4 * PT, 11 * PT, 2]),
+    (1, [1, PT, PT + 1, 40]),                          # the own position
+])
+def test_window_kernel_and_lowering_are_a_dense_band(window, lengths,
+                                                     interpret_kernel):
+    """4 K/V heads under 8 query heads: lane s attends to the last
+    `window` positions up to its own, through the kernel (which starts
+    its walk at the window's first page) and through the reference
+    lowering alike, and neither reads a page behind the window: those
+    table entries name a page of NaNs."""
+    rng = np.random.default_rng(window)
+    n_pages = 2 + sum(-(-n // PT) for n in lengths) + 3
+    kpool, vpool = _pools(rng, n_pages, PT, 4)
+    table, positions = _tables(rng, lengths, PT, 11, n_pages - 1)
+    q = rng.standard_normal((len(lengths), 1, 8, DH)).astype('f4')
+    want = _dense_attention(q, kpool, vpool, table, positions, window)
+    # what lies wholly behind a lane's window was given up long ago
+    poisoned = table.copy()
+    kpool[n_pages - 1] = vpool[n_pages - 1] = np.nan
+    for s, n in enumerate(lengths):
+        poisoned[s, :max(0, n - window) // PT] = n_pages - 1
+    live = np.array(lengths) > 0
+    kernel = _run_op(q, kpool, vpool, poisoned, positions, window=window)
+    fluid.set_flags({'pallas_interpret': False})
+    lowering = _run_op(q, kpool, vpool, table, positions, window=window)
+    for got in (kernel, lowering):
+        assert got.shape == q.shape
+        assert np.abs(got - want)[live].max() <= TOL * np.abs(want).max()
+    # and a band it is: the whole history gives another answer
+    far = np.array(lengths) > window
+    whole = np.abs(_run_op(q, kpool, vpool, table, positions) - want)[far]
+    assert whole.reshape(far.sum(), -1).max(axis=1).min() \
+        > 100 * TOL * np.abs(want).max()
+
+
+def test_window_kernel_carries_its_own_name_and_lowers_for_the_chip():
+    """The sliding layers' calls are told from the full layers' in a
+    device trace: another op type for the harness's labels, another
+    kernel name in the custom call; and the window form passes the
+    Pallas -> Mosaic lowering at 4 K/V heads a page of 16 tokens."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.pallas import paged_attention as pa
+    f4 = jnp.float32
+    args = (jax.ShapeDtypeStruct((48, 28, 128), f4),
+            jax.ShapeDtypeStruct((64, 16, 4, 128), f4),
+            jax.ShapeDtypeStruct((64, 16, 4, 128), f4),
+            jax.ShapeDtypeStruct((48, 273), jnp.int32),
+            jax.ShapeDtypeStruct((48,), jnp.int32))
+    texts = {w: jax.jit(lambda *a, w=w: pa.paged_attention(
+        *a, sm_scale=0.0884, window=w)).trace(*args).lower(
+            lowering_platforms=('tpu',)).as_text() for w in (0, 4096)}
+    assert 'paged_window_attention' in texts[4096]
+    assert 'paged_window_attention' not in texts[0]
+    assert all(t.count('tpu_custom_call') == 1 for t in texts.values())
+
+
+@pytest.mark.parametrize('window', [0, 5])
+def test_prefill_mask_is_causal_or_a_band(window):
+    """paged_prefill_mask: row i sees column j iff j <= positions[i]
+    and, with a window, positions[i] - window < j."""
+    prog = Program()
+    positions = np.array([3, 4, 9, 12], 'int32')
+    x = np.random.default_rng(0).standard_normal((1, 2, 4, 16)).astype('f4')
+    with program_guard(prog, Program()):
+        block = prog.global_block()
+        xv = fluid.layers.data('x', list(x.shape), append_batch_size=False)
+        pv = fluid.layers.data('p', [4], append_batch_size=False,
+                               dtype='int32')
+        out = block.create_var(name='masked', dtype='float32')
+        block.append_op(type='paged_prefill_mask',
+                        inputs={'X': [xv], 'Positions': [pv]},
+                        outputs={'Out': [out]},
+                        attrs={'window': window} if window else {})
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        got, = exe.run(prog, feed={'x': x, 'p': positions},
+                       fetch_list=[out])
+    j = np.arange(16)
+    seen = j[None, :] <= positions[:, None]
+    if window:
+        seen &= j[None, :] > positions[:, None] - window
+    assert np.array_equal(np.asarray(got) == x,
+                          np.broadcast_to(seen, x.shape))
+    assert np.all(np.asarray(got)[~np.broadcast_to(seen, x.shape)] == -1e9)

@@ -27,6 +27,7 @@ def _recorded(name):
 
 RECORDED = _recorded('serving_jaxprs_pr44.json')
 RECORDED_PR47 = _recorded('serving_jaxprs_pr47.json')
+RECORDED_PR49 = _recorded('serving_jaxprs_pr49.json')
 
 
 @pytest.mark.parametrize('name', ['gpt2', 'hybrid', 'nemotron_h', 'axk1'])
@@ -41,6 +42,13 @@ def test_the_shared_pieces_trace_as_before():
 
 def test_the_fifth_family_traces_as_before():
     assert serving_jaxprs.served('granite_h') == RECORDED_PR47['granite_h']
+
+
+def test_the_sixth_family_traces_as_recorded():
+    """smallthinker (a second page table, rotary K/V-head pools): the
+    record of the PR that added it (PR 49)."""
+    assert serving_jaxprs.served('smallthinker') == \
+        RECORDED_PR49['smallthinker']
 
 
 @pytest.mark.parametrize('key', sorted(serving_jaxprs.DEPLOYED))
