@@ -10,16 +10,35 @@ from __future__ import annotations
 
 import os
 import sys
+import time
 
+import jax
+
+from .executor import global_scope
 from .framework import (Program, Parameter, Variable, default_main_program,
                         program_guard)
 from .flags import get_flag
+from .obs import telemetry
+from .ops import io_ops
+from .profiler import RecordEvent
 
 __all__ = ['save_vars', 'save_params', 'save_persistables', 'load_vars',
            'load_params', 'load_persistables', 'save_inference_model',
            'load_inference_model', 'get_inference_program']
 
 _MODEL_FILENAME = '__model__'
+
+# one save_vars / load_vars call each: bytes and files on disk, wall
+# seconds from entry to return, and the seconds the save's ops stood
+# waiting for a device copy (near 0: the files set the pace; near
+# io.save.seconds: the device-to-host link does)
+_SAVE_BYTES = telemetry.counter('io.save.bytes')
+_SAVE_FILES = telemetry.counter('io.save.files')
+_SAVE_SECONDS = telemetry.counter('io.save.seconds')
+_SAVE_COPY_WAIT = telemetry.counter('io.save.copy_wait_seconds')
+_LOAD_BYTES = telemetry.counter('io.load.bytes')
+_LOAD_FILES = telemetry.counter('io.load.files')
+_LOAD_SECONDS = telemetry.counter('io.load.seconds')
 
 
 def is_persistable(var):
@@ -80,7 +99,20 @@ def save_vars(executor, dirname, main_program=None, vars=None,
     main_program = main_program or default_main_program()
     vars = _select_vars(main_program, vars, predicate, filter_fn)
     prog = _build_io_program(main_program, vars, dirname, filename, 'save')
-    executor.run(prog)
+    t0 = time.perf_counter()
+    with RecordEvent('io.save') as ev:
+        # the ops of this one program share a pipeline (ops/io_ops.py):
+        # the device copies run ahead of the op that writes
+        scope = global_scope()
+        stream = prog._io_stream = io_ops.SaveStream(
+            [(v.name, scope.find_var(v.name)) for v in vars])
+        executor.run(prog)
+        ev.attrs.update(bytes=stream.bytes, files=stream.files,
+                        held_max=stream.held_max)
+    _SAVE_BYTES.inc(stream.bytes)
+    _SAVE_FILES.inc(stream.files)
+    _SAVE_COPY_WAIT.inc(stream.copy_wait)
+    _SAVE_SECONDS.inc(time.perf_counter() - t0)
     if get_flag('ckpt_verify', False):
         # record the just-written files in the dir's CHECKPOINT_DIGESTS
         # (merging: __model__ from save_inference_model and a later
@@ -123,7 +155,19 @@ def load_vars(executor, dirname, main_program=None, vars=None,
                 dirname, files=_io_files(vars, filename),
                 var_of=lambda rel: rel if rel in names else None)
     prog = _build_io_program(main_program, vars, dirname, filename, 'load')
-    executor.run(prog)
+    t0 = time.perf_counter()
+    with RecordEvent('io.load') as ev:
+        # the mirror of save_vars' pipeline: in a process with one device
+        # each variable's copy to the executor's device is started as its
+        # file arrives (with more devices a mesh may want it elsewhere:
+        # it stays on the host, as an op alone leaves it)
+        stream = prog._io_stream = io_ops.LoadStream(
+            executor.device if jax.device_count() == 1 else None)
+        executor.run(prog)
+        ev.attrs.update(bytes=stream.bytes, files=stream.files)
+    _LOAD_BYTES.inc(stream.bytes)
+    _LOAD_FILES.inc(stream.files)
+    _LOAD_SECONDS.inc(time.perf_counter() - t0)
 
 
 def load_params(executor, dirname, main_program=None, filename=None,
