@@ -223,10 +223,10 @@ def _attention(x, spec, blk, i, at=None):
     i's pages: the new rows into their pages (the normed latent and the
     rotated key side by side, zeros up to the pool's row), then the
     absorbed attention through the table, one token a lane in a decode
-    step, one stream's chunk of rows in a prefill chunk. A chunk copies
-    its forked page first (at.cow); a decode step copies none: the host
-    ran the page copy program in front of it
-    (models/transformer.build_page_copy_program)."""
+    step, one stream's chunk of rows (and how many of them are live) in
+    a prefill chunk. A chunk copies its forked page first (at.cow); a
+    decode step copies none: the host ran the page copy program in front
+    of it (models/transformer.build_page_copy_program)."""
     if at is None:
         t = spec.max_len
         q, ckv, kr = _latent_parts(x, spec, blk, t)
@@ -249,10 +249,13 @@ def _attention(x, spec, blk, i, at=None):
         ins['Len'] = [at.length]
     _block_op('kv_page_append' if at.decode else 'kv_page_write', inputs=ins,
               outputs={'Out': [pool]})
-    return _attention_op(
-        'paged_latent_attention' if at.decode else 'paged_latent_prefill',
-        spec, blk, q, at.rows, Pool=pool, Table=at.table,
-        Positions=at.positions)
+    if at.decode:
+        return _attention_op('paged_latent_attention', spec, blk, q, at.rows,
+                             Pool=pool, Table=at.table,
+                             Positions=at.positions)
+    return _attention_op('paged_latent_prefill', spec, blk, q, at.rows,
+                         Pool=pool, Table=at.table, Positions=at.positions,
+                         Len=at.length)
 
 
 def _gated_mlp(x, spec, width, up, down):

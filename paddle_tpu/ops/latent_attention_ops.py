@@ -256,14 +256,22 @@ _PREFILL_BLOCK_TOKENS = 1024
 def prefill_absorbed(q, pool, table, positions, w_uk, w_uv, sm_scale,
                      block_tokens=_PREFILL_BLOCK_TOKENS):
     """A chunk's rows against the stream's cached latent, absorbed and
-    folded block by block with an online softmax: q [C, H, dn + dr],
-    pool [N, pt, row], table [P], positions [C] (ascending) -> [C, H,
-    dv]. Only the blocks up to the chunk's last position are read; the
-    window is never gathered whole, nor are keys and values made. (The
-    other form, a block's keys and values made through W_UKV before the
-    products, needs three quarters of the multiplies and took 4.9 and
-    8.1 ms where this takes 4.3 and 6.1, a chunk of 256 rows behind 4 k
-    and 12 k on a v5e: tools/mla_forms.py keeps it; PERF.md, PR 42.)"""
+    folded block by block with an online softmax in plain XLA: q [C, H,
+    dn + dr], pool [N, pt, row], table [P], positions [C] (ascending)
+    -> [C, H, dv]. Only the blocks up to the chunk's last position are
+    read; the window is never gathered whole, nor are keys and values
+    made. Since PR 51 this is the reference the kernel of
+    pallas/latent_prefill.py is held to and the path of every backend
+    but a TPU: its scores, their mask and their exponentials ([C H,
+    1024] float32 each) go through HBM several times a block and every
+    row of the chunk is multiplied whatever the prompt's length. A chunk
+    of 256 rows x 64 heads on a v5e, the op alone (tools/mla_forms.py;
+    PERF.md, PR 51): 4.27 ms behind 4 k cached tokens and 6.07 behind
+    12 k, whatever is live, where the kernel takes 0.86 and 2.03 with
+    138 rows live and 1.39 and 3.47 with all 256. (The other form, a
+    block's keys and values made through W_UKV before the products,
+    needs three quarters of the multiplies and takes 4.95 and 8.05:
+    the tool keeps it.)"""
     C, H = q.shape[:2]
     pt, row = pool.shape[1:]
     dc = w_uk.shape[0]
@@ -301,13 +309,39 @@ def prefill_absorbed(q, pool, table, positions, w_uk, w_uv, sm_scale,
 def _paged_latent_prefill_emit(ctx, op):
     """One prefill chunk's MLA against the stream's pages (the chunk's
     own rows already written): Q [1, C, H, dn + dr], Pool [N, pt, row],
-    Table [1, P], Positions [C] (absolute, ascending; row i sees the
-    positions <= Positions[i]), WUKV; attrs nope_dim, sm_scale -> Out
-    [1, C, H dv]."""
+    Table [1, P], Positions [C] (absolute, one after the other: row i
+    stands at Positions[0] + i and sees the positions up to its own),
+    WUKV, and optionally Len [1] (the chunk's live rows; absent: all of
+    them); attrs nope_dim, sm_scale -> Out [1, C, H dv]. On a TPU (or
+    under FLAGS_pallas_interpret), for pages the kernel tiles and a
+    chunk that is a whole number of its tiles, the absorbed sum is the
+    Pallas kernel of pallas/latent_prefill.py, which keeps the scores in
+    VMEM, does nothing for the tiles past Len and gives zeros for the
+    rows from Len on; elsewhere `prefill_absorbed`, which computes every
+    row. Which of the two an emission took is counted in
+    ops.latent_prefill.kernel / ops.latent_prefill.fallback."""
+    from ..pallas import latent_prefill as _lp
+    from ..flags import get_flag
+    from ..obs import telemetry
     q, pool, table, positions, w_uk, w_uv = _pool_inputs(ctx, op)
-    out = prefill_absorbed(
-        q[0], pool, jnp.clip(table[0], 0, pool.shape[0] - 1), positions,
-        w_uk, w_uv, float(op.attr('sm_scale')))
+    q, table = q[0], jnp.clip(table[0], 0, pool.shape[0] - 1)
+    (C, H), (pt, row), dc = q.shape[:2], pool.shape[1:], w_uk.shape[0]
+    sm_scale = float(op.attr('sm_scale'))
+    on_tpu = jax.default_backend() == 'tpu'
+    if _lp.prefill_supported(C, H, pt, row, dc) and (
+            on_tpu or bool(get_flag('pallas_interpret'))):
+        telemetry.counter('ops.latent_prefill.kernel').inc()
+        length = ctx.get(op.single_input('Len')).reshape(-1)[0] \
+            if op.input('Len') else C
+        u = _lp.paged_latent_prefill(
+            absorb_query(q, w_uk, row) * sm_scale, pool, table, positions[0],
+            jnp.asarray(length, jnp.int32), value_dim=dc,
+            interpret=not on_tpu)
+        out = jnp.einsum('thc,chv->thv', u, w_uv)
+    else:
+        telemetry.counter('ops.latent_prefill.fallback').inc()
+        out = prefill_absorbed(q, pool, table, positions, w_uk, w_uv,
+                               sm_scale)
     ctx.set(op.single_output('Out'), out.reshape(1, out.shape[0], -1))
 
 

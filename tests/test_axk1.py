@@ -55,8 +55,8 @@ SEED = 3600000011
 TOL = 2e-5
 
 
-def _build(tmp):
-    cfg = builder.model_config(DIMS)
+def _build(tmp, dims=DIMS):
+    cfg = builder.model_config(dims)
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
         tokens = fluid.layers.data('tokens', shape=[1, cfg.max_len, 1],
@@ -66,9 +66,9 @@ def _build(tmp):
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         builder.put_seeded_weights(
-            scope, axk1.spec_from_config(cfg), DIMS, SEED)
+            scope, axk1.spec_from_config(cfg), dims, SEED)
         toks = np.random.default_rng(0).integers(
-            1, DIMS.vocab, size=(1, cfg.max_len, 1))
+            1, dims.vocab, size=(1, cfg.max_len, 1))
         full, = exe.run(main, feed={'tokens': toks}, fetch_list=[logits])
         fluid.io.save_inference_model(str(tmp), ['tokens'], [logits], exe,
                                       main_program=main)
@@ -93,12 +93,17 @@ def _decoder(pred, **kw):
     return pred.prepare_decoding(**kw)
 
 
-def _prefill(dec, slot, prompt):
+def _prefill_out(dec, slot, prompt):
+    """A prompt's last chunk: (token, logits)."""
     dec.open_stream(slot, prompt)
     out = None
     while out is None:
         out = dec.prefill_step(slot, return_logits=True)
-    return out[1]
+    return out
+
+
+def _prefill(dec, slot, prompt):
+    return _prefill_out(dec, slot, prompt)[1]
 
 
 def _decode(dec, slot, token, position):
@@ -234,11 +239,11 @@ def test_rotary_op_turns_each_row_by_its_own_position(per):
 # -- the absorbed forms -------------------------------------------------------
 
 def _latent_case(seed, lengths, heads=4, dn=8, dr=8, dv=8, dc=16, pt=4,
-                 pages_per_slot=12):
+                 pages_per_slot=12, row=None):
     """Streams of `lengths` cached tokens in a shuffled pool of latent
     rows [c_KV | k_R | zeros], and what the equations need beside."""
     rng = np.random.default_rng(seed)
-    row = -(-(dc + dr) // 8) * 8 + 8
+    row = row or -(-(dc + dr) // 8) * 8 + 8
     n_pages = 1 + sum(-(-n // pt) for n in lengths) + 2
     pool = np.zeros((n_pages, pt, row), 'f4')
     pool[..., :dc + dr] = rng.standard_normal((n_pages, pt, dc + dr))
@@ -308,6 +313,182 @@ def test_a_prefill_chunk_is_the_unabsorbed_sum(start, block):
     want = _unabsorbed(q, _cached_rows(pool, table, 0, n, 24), w_ukv, 8, 0.3)
     np.testing.assert_allclose(np.asarray(got).reshape(6, -1), want,
                                atol=2e-5)
+
+
+# -- the prefill kernel ---------------------------------------------------------
+
+@pytest.fixture
+def interpret_kernel():
+    fluid.set_flags({'pallas_interpret': True})
+    yield
+    fluid.set_flags({'pallas_interpret': False})
+
+
+# (cached tokens, live rows of a chunk of 16, tokens a tile, pages a block
+# of 8 tokens each)
+KERNEL_CASES = {
+    'nothing cached, a whole chunk, blocks of one page': (0, 16, 8, 1),
+    'one live row': (21, 1, 4, 2),
+    'a whole chunk in one tile, a context inside its second block':
+        (40, 16, 16, 4),
+    'a length inside a tile': (3, 5, 4, 2),
+    'the last live row is a page\'s last token': (19, 5, 8, 1),
+    'a context that ends inside the one block': (33, 9, 8, 32),
+    'a context of several blocks, the chunk over three of them':
+        (60, 16, 8, 2),
+    'the tile and the block the shapes give': (50, 11, None, None),
+}
+
+
+@pytest.mark.parametrize('name', list(KERNEL_CASES))
+def test_the_prefill_kernel_is_the_fold_and_the_unabsorbed_sum(name):
+    """pallas/latent_prefill.paged_latent_prefill in interpret mode
+    (scratch never written holds NaNs there) against prefill_absorbed
+    and against the equations, on the live rows; zeros on the rest.
+    Pages past the last live row's are never copied: the table names a
+    page of NaNs there."""
+    from paddle_tpu.pallas import latent_prefill as lp
+    start, n, tile, bp = KERNEL_CASES[name]
+    C, H, dc, row, pt = 16, 4, 128, 256, 8
+    pool, table, w_ukv, rng = _latent_case(
+        7 + start, [start + n], dc=dc, pt=pt, row=row)
+    q = rng.standard_normal((C, H, 16)).astype('f4')
+    w_uk, w_uv = lat_ops._split_up(jnp.asarray(w_ukv), H, 8)
+    assert lp.prefill_supported(C, H, pt, row, dc)
+    want = lat_ops.prefill_absorbed(
+        jnp.asarray(q), jnp.asarray(pool), jnp.asarray(table[0]),
+        start + jnp.arange(C, dtype=jnp.int32), w_uk, w_uv, 0.3)
+    poisoned, far = pool.copy(), table[0].copy()
+    live_pages = -(-(start + n) // pt)
+    spare = max(set(range(1, len(pool))) - set(far[:live_pages]))
+    poisoned[spare] = np.nan
+    far[live_pages:] = spare
+    u = lp.paged_latent_prefill(
+        lat_ops.absorb_query(jnp.asarray(q), w_uk, row) * 0.3,
+        jnp.asarray(poisoned), jnp.asarray(far), jnp.int32(start),
+        jnp.int32(n), value_dim=dc, tile=tile, block_pages=bp,
+        interpret=True)
+    got = np.asarray(jnp.einsum('thc,chv->thv', u, w_uv)).reshape(C, -1)
+    assert np.isfinite(got).all()
+    assert not got[n:].any()
+    np.testing.assert_allclose(got[:n], np.asarray(want).reshape(C, -1)[:n],
+                               atol=2e-5)
+    eq = _unabsorbed(q[:n], _cached_rows(pool, table, 0, start + n, dc + 8),
+                     w_ukv, 8, 0.3)
+    np.testing.assert_allclose(got[:n], eq, atol=2e-5)
+
+
+def test_the_prefill_kernel_tiles_what_the_shapes_give():
+    from paddle_tpu.pallas import latent_prefill as lp
+    assert lp.tile_tokens(256, 64) == 16          # the served chunk
+    assert lp.tile_tokens(16, 4) == 16
+    assert lp.tile_tokens(512, 64) == 16
+    assert lp.tile_tokens(12, 2) == 12            # rows in whole sublanes,
+    assert lp.tile_tokens(12, 3) == 0             # or no tile
+    assert lp.prefill_supported(256, 64, 16, 640, 512)
+    assert not lp.prefill_supported(256, 64, 16, 576, 512)
+    assert not lp.prefill_supported(12, 3, 16, 640, 512)
+    with pytest.raises(ValueError, match='tiles of 5'):
+        lp.paged_latent_prefill(
+            jnp.zeros((16, 4, 256)), jnp.zeros((3, 8, 256)),
+            jnp.zeros((2,), jnp.int32), jnp.int32(0), jnp.int32(16),
+            value_dim=128, tile=5)
+
+
+def test_the_prefill_kernel_lowers_for_the_chip_at_the_published_row():
+    """Cross-lowered for a TPU from here at the served shape: a chunk of
+    256 tokens x 64 heads over rows of 640, 1024 pages a slot: one custom
+    call, the pool handed over as it lies."""
+    import functools
+    from paddle_tpu.pallas import latent_prefill as lp
+    C, H, row, N, pt, P = 256, 64, 640, 64, 16, 1024
+    text = jax.jit(functools.partial(
+        lp.paged_latent_prefill, value_dim=512)).trace(
+            jax.ShapeDtypeStruct((C, H, row), jnp.float32),
+            jax.ShapeDtypeStruct((N, pt, row), jnp.float32),
+            jax.ShapeDtypeStruct((P,), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32)).lower(
+                lowering_platforms=('tpu',)).as_text()
+    assert text.count('tpu_custom_call') == 1
+    assert 'tensor<%dx%dx%dxf32>' % (N, pt, row) in text
+
+
+def _kernel_body(call):
+    """The serialized Mosaic body in the module `call` lowers to for a
+    TPU (base64, as the custom call's configuration holds it)."""
+    import re
+    text = jax.jit(call).trace(
+        jax.ShapeDtypeStruct((16, 4, 256), jnp.float32),
+        jax.ShapeDtypeStruct((8, 8, 256), jnp.float32),
+        jax.ShapeDtypeStruct((8,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32)).lower(
+            lowering_platforms=('tpu',)).as_text()
+    return re.search(r'\\22body\\22: \\22(.*?)\\22', text, re.S).group(1)
+
+
+def test_the_prefill_kernels_body_holds_no_callers_lines():
+    """ROADMAP S17, for this kernel: its serialized body, part of the
+    prefill executable's cache key, is the same from two call sites and
+    names no file of the checkout."""
+    import base64
+    from paddle_tpu.pallas import latent_prefill as lp
+
+    def one(*args):
+        return lp.paged_latent_prefill.__wrapped__(*args, value_dim=128)
+
+    def other(*args):
+        moved = [a for a in args]
+        return lp.paged_latent_prefill.__wrapped__(*moved, value_dim=128)
+
+    body = _kernel_body(one)
+    assert body == _kernel_body(other)
+    raw = base64.b64decode(body)
+    assert b'paged_latent_prefill' in raw
+    assert os.path.dirname(os.path.abspath(lp.__file__)).encode() not in raw
+    assert b'.py' not in raw
+
+
+# a latent of one whole lane row and pages of 8 tokens: what the kernels
+# tile (MODEL's latent of 16 takes the compositions)
+KERNEL_MODEL = dict(MODEL, kv_lora_rank=128)
+
+
+@pytest.fixture(scope='module')
+def kernel_model(tmp_path_factory):
+    dims = ref.dims_of(KERNEL_MODEL)
+    pred, toks, _ = _build(tmp_path_factory.mktemp('axk1_kernel_lm'), dims)
+    return pred, toks, np.asarray(ref.logits(ref.seed_key(SEED), dims, toks))
+
+
+def test_a_prompt_prefilled_through_the_kernel_is_the_prompt_folded(
+        kernel_model, interpret_kernel):
+    """21 tokens in chunks of 16 (the second has 5 live rows of 16) and
+    a decode step behind them, on the tiny model through
+    PagedDecodePredictor: the emitter takes the kernel under the flag
+    and the fold without it, and both give the reference's token and
+    logits."""
+    pred, toks, want = kernel_model
+    telemetry.enable()
+    took = {k: telemetry.counter('ops.latent_prefill.' + k)
+            for k in ('kernel', 'fallback')}
+    before = {k: c.value for k, c in took.items()}
+    dec = _decoder(pred, page_tokens=8)
+    assert dec._pair.pool_shape == (40, 8, 256)
+    tok, lg = _prefill_out(dec, 1, toks[:21])
+    nxt = _decode(dec, 1, toks[21], 21)
+    # three layers, one trace of the prefill program
+    assert took['kernel'].value - before['kernel'] == 3
+    assert took['fallback'].value == before['fallback']
+    fluid.set_flags({'pallas_interpret': False})
+    fold = _decoder(pred, page_tokens=8)
+    ftok, flg = _prefill_out(fold, 1, toks[:21])
+    assert took['fallback'].value - before['fallback'] == 3
+    assert int(tok) == int(ftok) == int(np.argmax(want[20]))
+    assert ref.rel_l2(lg, flg) < TOL
+    assert ref.rel_l2(lg, want[20]) < TOL
+    assert ref.rel_l2(nxt, want[21]) < TOL
 
 
 # -- latent pages through what moves pages -------------------------------------

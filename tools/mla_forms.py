@@ -1,13 +1,17 @@
 """The latent attention's shapes alone, on the chip, one process: the
 decode kernel (pallas/paged_attention.paged_latent_attention) at a
 serving step's lanes and contexts, and a prefill chunk's attention in
-both forms of its sum (ops/latent_attention_ops.prefill_absorbed, which
-the program keeps, and prefill_materialised here, which it does not) behind 4 k and 12 k of context. Prints ms a call
-and what that is of the chip's HBM or bf16 peak by the benchmark's own
-count (benchmarks/harness/costs_axk1.py), and how many bytes the chip
-gives a pool of 576-wide rows.
+three forms of its sum (the kernel of pallas/latent_prefill.py, which
+the program runs on a TPU; ops/latent_attention_ops.prefill_absorbed,
+the same sum folded in plain XLA, its reference and the CPU's path; and
+prefill_materialised here, which the program never had) behind 4 k and
+12 k of context, 138 and 256 of the chunk's 256 rows live. Prints ms a
+call and what that is of the chip's HBM or bf16 peak by the benchmark's
+own count (benchmarks/harness/costs_axk1.py), and how many bytes the
+chip gives a pool of 576-wide rows.
 
-    python tools/mla_forms.py [--lanes 36] [--context 12288] [--quick]
+    python tools/mla_forms.py [--lanes 36] [--context 12288] [--tiles 8,16]
+        [--prefill-blocks 32,64] [--quick]
 
 --quick walks the same code here on the CPU at a tiny size (interpret
 mode: the harness, not a time).
@@ -85,6 +89,11 @@ def main(argv):
     ap.add_argument('--slots', type=int, default=48)
     ap.add_argument('--context', type=int, default=12288)
     ap.add_argument('--blocks', default='16,32,64')
+    ap.add_argument('--tiles', default='',
+                    help='chunk tokens a tile of the prefill kernel, '
+                         'comma-separated (default: what the shapes give)')
+    ap.add_argument('--prefill-blocks', default='',
+                    help='pages a block of the prefill kernel')
     ap.add_argument('--quick', action='store_true')
     args = ap.parse_args(argv)
     if args.quick:
@@ -144,23 +153,51 @@ def main(argv):
     w_uk = jnp.asarray(rng.standard_normal((dc, H, dn), np.float32) / 20)
     w_uv = jnp.asarray(rng.standard_normal((dc, H, dv), np.float32) / 20)
     q = jnp.asarray(rng.standard_normal((chunk, H, dn + dr), np.float32))
+    from paddle_tpu.pallas import latent_prefill as lp
+    tiles = [int(t) for t in args.tiles.split(',')] if args.tiles \
+        else [lp.tile_tokens(chunk, H)]
+    pblocks = [int(b) for b in args.prefill_blocks.split(',')] \
+        if args.prefill_blocks else [lp._BLOCK_PAGES]
+
+    def kernel_form(tile, bp):
+        def form(q, pool, t, p, n):
+            u = lp.paged_latent_prefill.__wrapped__(
+                lo.absorb_query(q, w_uk, row) * 0.13, pool, t, p[0], n,
+                value_dim=dc, tile=tile, block_pages=bp,
+                interpret=args.quick)
+            return jnp.einsum('thc,chv->thv', u, w_uv)
+        return form
+
+    def whole(form):
+        return lambda q, pool, t, p, n: form(q, pool, t, p, w_uk, w_uv, 0.13)
+
+    forms = [('absorbed', whole(lo.prefill_absorbed)),
+             ('materialised', whole(prefill_materialised))]
+    forms += [('kernel, tiles of %d, blocks of %d pages' % (tile, bp),
+               kernel_form(tile, bp)) for tile in tiles for bp in pblocks]
+    fns = [(name, jax.jit(form)) for name, form in forms]
+    lives = (138, chunk) if not args.quick else (5, chunk)
     for behind in ((ctx // 3, ctx) if not args.quick else (ctx,)):
         pos = behind + jnp.arange(chunk, dtype=jnp.int32)
-        outs = {}
-        for name, form in (('absorbed', lo.prefill_absorbed),
-                           ('materialised', prefill_materialised)):
-            fn = jax.jit(lambda q, pool, t, p, form=form: form(
-                q, pool, t, p, w_uk, w_uv, 0.13))
-            ms = timed(fn, q, pool, table[0], pos, n=reps)
-            outs[name] = fn(q, pool, table[0], pos)
-            flops = 2 * chunk * H * (behind + chunk) * (2 * dc + dr)
-            print('prefill chunk of %d rows behind %d, %s: %.3f ms a call '
-                  '(%.1f %% of the bf16 peak by the absorbed count)'
-                  % (chunk, behind, name, ms,
-                     100 * flops / 197e12 / (ms / 1e3)))
-        print('the two forms differ by %.2e of %.2e'
-              % (float(jnp.abs(outs['absorbed'] - outs['materialised']).max()),
-                 float(jnp.abs(outs['absorbed']).max())))
+        for live in lives:
+            outs = {}
+            for name, fn in fns:
+                n = jnp.int32(live)
+                ms = timed(fn, q, pool, table[0], pos, n, n=reps)
+                outs[name] = fn(q, pool, table[0], pos, n)[:live]
+                # the benchmark's count (costs_axk1.mla_prefill_flops):
+                # the live rows against the context, absorbed
+                flops = 2 * live * H * (behind + live) * (2 * dc + dr)
+                print('prefill chunk of %d rows, %d live, behind %d, %s: '
+                      '%.3f ms a call (%.1f %% of the bf16 peak for the '
+                      'live rows by the absorbed count)'
+                      % (chunk, live, behind, name, ms,
+                         100 * flops / 197e12 / (ms / 1e3)))
+            top = float(jnp.abs(outs['absorbed']).max())
+            for name in list(outs)[1:]:
+                print('  %s differs from absorbed by %.2e of %.2e'
+                      % (name, float(jnp.abs(outs[name]
+                                             - outs['absorbed']).max()), top))
 
 
 if __name__ == '__main__':
