@@ -1,5 +1,10 @@
 """Hybrid language model: gated-delta-rule layers between full-attention
-layers (the Olmo-Hybrid block; Gated DeltaNet, arXiv:2412.06464).
+layers (the Olmo-Hybrid block; Gated DeltaNet, arXiv:2412.06464). The
+rule here has ONE decay a head (ops gated_delta_*) and the block is
+post-norm with a dense MLP; the pre-norm block whose rule decays a key
+channel at a time (ops kda_*), with gated attention and an expert
+sublayer in every layer, is models/solar_open2.py, which takes this
+file's `_param` and `_rms`.
 
 Beside models/transformer.py, whose named-fc helpers, page-pool
 variables and paged attention it shares. One block wiring for both

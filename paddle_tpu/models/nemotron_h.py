@@ -229,12 +229,14 @@ def _experts_mixer(x, spec, blk, i, at=None):
         routed, _named_fc(shared, spec.dim, blk['shared_down']))
 
 
-def _attention(x, spec, blk, i, at=None):
+def _attention(x, spec, blk, i, at=None, out_gate=None):
     """Causal attention over layer i's pages, or over the whole
     sequence (the source program's form): the query heads of one K/V
-    head are rows of one product."""
+    head are rows of one product. `out_gate` [B, t, heads * dh], where
+    the block has one (models/solar_open2.py), multiplies the heads'
+    outputs in front of the output projection."""
     if at is not None:
-        return _paged_attention(x, spec, blk, i, at)
+        return _paged_attention(x, spec, blk, i, at, out_gate=out_gate)
     t, h, kvh, dh = spec.max_len, spec.heads, spec.kv_heads, spec.dh
     rep = h // kvh
     q4, k4, v4 = _qkv_parts(x, spec, blk, t)
@@ -246,8 +248,10 @@ def _attention(x, spec, blk, i, at=None):
     ctx = L.matmul(L.reshape(probs, shape=[-1, kvh, rep * t, t]), v)
     ctx = L.transpose(L.reshape(ctx, shape=[-1, h, t, dh]),
                       perm=[0, 2, 1, 3])
-    return _named_fc(L.reshape(ctx, shape=[-1, t, h * dh]), spec.dim,
-                     blk['proj'])
+    ctx = L.reshape(ctx, shape=[-1, t, h * dh])
+    if out_gate is not None:
+        ctx = L.elementwise_mul(ctx, out_gate)
+    return _named_fc(ctx, spec.dim, blk['proj'])
 
 
 _MIXERS = {'mamba': _mamba_mixer, 'experts': _experts_mixer,

@@ -652,17 +652,26 @@ def _paged_gather(pool_var, table, spec):
                                (None, _tp_ax(spec), None, None))
 
 
-def _paged_attention(x, spec, blk, i, at, qk_norm=None, rotary=None):
+def _paged_attention(x, spec, blk, i, at, qk_norm=None, rotary=None,
+                     out_gate=None):
     """Layer i's attention over its K/V pages, in the form `at`'s
     program takes: a prefill chunk's or a decode step's; through the
-    table of the layer's kind (at.pages_of)."""
+    table of the layer's kind (at.pages_of). `out_gate` [B, rows,
+    heads * dh] multiplies the heads' outputs in front of the output
+    projection, where the block gates them."""
     form = _paged_decode_attention if at.decode else _paged_prefill_attention
     return form(x, spec, blk, at.pools[i], at, at.pages_of(spec, i), qk_norm,
-                rotary)
+                rotary, out_gate)
+
+
+def _gated_proj(ctx, spec, blk, out_gate):
+    if out_gate is not None:
+        ctx = L.elementwise_mul(ctx, out_gate)
+    return _named_fc(ctx, spec.dim, blk['proj'])
 
 
 def _paged_prefill_attention(x, spec, blk, pool, at, pages, qk_norm=None,
-                             rotary=None):
+                             rotary=None, out_gate=None):
     """One chunk of prefill attention: COW any forked page, scatter the
     chunk's K/V rows through the table, then attend the chunk's queries
     over the WHOLE gathered history (earlier pages + this chunk): the
@@ -712,11 +721,11 @@ def _paged_prefill_attention(x, spec, blk, pool, at, pages, qk_norm=None,
         ctx = L.reshape(ctx, shape=[-1, spec.heads, chunk, spec.dh])
     ctx = _model_heads(L.transpose(ctx, perm=[0, 2, 1, 3]), spec, chunk)
     ctx = sharding_constraint(ctx, (None, None, None))
-    return _named_fc(ctx, spec.dim, blk['proj'])
+    return _gated_proj(ctx, spec, blk, out_gate)
 
 
 def _paged_decode_attention(x, spec, blk, pool, at, pages, qk_norm=None,
-                            rotary=None):
+                            rotary=None, out_gate=None):
     """One decode step's attention: append the new K/V row, then ONE
     paged_attention op that reads each lane's live pages through its
     table (no gathered window; see the op's docstring for its two
@@ -744,7 +753,7 @@ def _paged_decode_attention(x, spec, blk, pool, at, pages, qk_norm=None,
     #                                                     [S, 1, H, dh]
     ctx = _model_heads(ctx, spec, 1)
     ctx = sharding_constraint(ctx, (None, None, None))
-    return _named_fc(ctx, spec.dim, blk['proj'])
+    return _gated_proj(ctx, spec, blk, out_gate)
 
 
 def _paged_verify_attention(x, spec, blk, pool, table, positions,

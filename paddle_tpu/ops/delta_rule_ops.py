@@ -1,20 +1,20 @@
-"""Ops of the gated-delta-rule block (Gated DeltaNet, arXiv:2412.06464;
-chunked form arXiv:2406.06484) and of the RMSNorm blocks around it:
-`rms_norm`, `short_conv`, `gated_delta_chunk`, `gated_delta_step`.
-
-The rule, for one head with key size dk and value size dv, state S
-[dk, dv] (float32, zero at a stream's start), token t:
+"""Ops of the delta-rule blocks (Gated DeltaNet, arXiv:2412.06464; the
+chunked form arXiv:2406.06484; Kimi Delta Attention, arXiv:2510.26692)
+and of the RMSNorm blocks around them: `rms_norm`, `short_conv`,
+`gated_delta_chunk` / `gated_delta_step` (ONE decay a head: the Olmo
+hybrid) and, at the end of the file, `kda_chunk` / `kda_step` (a decay
+a KEY CHANNEL: models/solar_open2.py). One head, key size dk, value
+size dv, state S [dk, dv] (float32, zero at a stream's start), token t:
 
     S' = alpha_t S_{t-1};  u_t = beta_t (v_t - S'^T k_t)
     S_t = S' + k_t u_t^T;  o_t = S_t^T q_t
 
 with q = q / |q| * dk^-1/2, k = k / |k|, beta_t = beta_scale *
-sigmoid(b_t) and alpha_t = exp(-exp(A_log) * softplus(a_t + dt_bias)).
-Both delta ops take the layer's raw tensors (QKV after the short
-convolution, the two gate logits BA) and do the normalisation and the
-gates themselves, so the chunk form and the step form cannot drift
-apart in them.
-
+sigmoid(b_t) and alpha_t = exp(-exp(A_log) * softplus(a_t + dt_bias)),
+a scalar a head (gated_delta_*) or Diag of dk values (kda_*). The ops
+take the layer's raw tensors (QKV after the short convolution, the
+gate logits) and do the normalisation and the gates themselves, so a
+chunk form and its step form cannot drift apart in them.
 Recurrent state lives in scope variables that the paged programs
 update in place, as they do the K/V pools: the delta state
 [slots, H, dk, dv] and the convolution's last K-1 input rows
@@ -327,3 +327,229 @@ for _t, _infer in (('short_conv', _short_conv_infer),
                    ('gated_delta_chunk', _delta_infer)):
     register_op(_t, infer_shape=_infer, grad=_no_backward(_t))
 register_op('gated_delta_step', infer_shape=_delta_infer, no_grad=True)
+
+
+# -- the rule with a decay a key channel (Kimi Delta Attention) --------------
+#
+# alpha_t is Diag(exp(g_t)), g_t [dk] <= 0: S' = exp(g_t)[:, None] * S.
+# Ops of their own beside gated_delta_*, whose programs stay what they
+# were: the chunk form below is other algebra (one [C, C] decay matrix
+# a head no longer factors out of the products), and an execution of
+# either is found in a trace by its own op type.
+
+def kda_inputs(qkv, gate, b, a_log, dt_bias, heads, dk, dv, beta_scale):
+    """The layer's raw tensors -> what the rule runs on, in float32:
+    qkv [..., H*(2 dk + dv)] (q, k, v side by side, after the short
+    convolution), gate [..., H*dk] (the decay logits, a value a key
+    channel), b [..., H] (the write-strength logits), a_log [H],
+    dt_bias [H*dk] -> q, k [..., H, dk] normalised, v [..., H, dv],
+    beta [..., H], g [..., H, dk] (log alpha, <= 0)."""
+    lead = qkv.shape[:-1]
+    qkv = qkv.astype(jnp.float32)
+    q = qkv[..., :heads * dk].reshape(lead + (heads, dk))
+    k = qkv[..., heads * dk:2 * heads * dk].reshape(lead + (heads, dk))
+    v = qkv[..., 2 * heads * dk:].reshape(lead + (heads, dv))
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
+        * dk ** -0.5
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+    beta = beta_scale * jax.nn.sigmoid(b.astype(jnp.float32))
+    g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * jax.nn.softplus(
+        (gate.astype(jnp.float32) + dt_bias.astype(jnp.float32))
+        .reshape(lead + (heads, dk)))
+    return q, k, v, beta, g
+
+
+def kda_step(s, q, k, v, beta, g):
+    """One token of the rule on state s [..., dk, dv]: q, k, g [..., dk],
+    v [..., dv], beta [...] -> (o [..., dv], new state). Elementwise
+    float32: no product is rounded."""
+    s = s * jnp.exp(g)[..., None]
+    u = beta[..., None] * (v - jnp.sum(s * k[..., None], axis=-2))
+    s = s + k[..., None] * u[..., None, :]
+    return jnp.sum(s * q[..., None], axis=-2), s
+
+
+def _decayed_products(xs, k, c, sub):
+    """For each x of `xs` [..., C, dk] the matrix P_ij = sum_d x_id k_jd
+    exp(c_id - c_jd) for i >= j (0 above the diagonal), [..., C, C],
+    with c [..., C, dk] the running sum of the log decays inside the
+    block. Every exponent evaluated is <= 0: inside a sub-block of
+    `sub` tokens the differences c_i - c_j (i >= j) themselves; from a
+    sub-block to an earlier one through the boundary r in front of
+    row i's sub-block, (x_i e^{c_i - c_r}) . (k_j e^{c_r - c_j}) with
+    j <= r < i. exp(-c) alone, which overflows float32 after a few
+    hundred tokens of a fast channel, is never formed."""
+    lead, (n, dk) = c.shape[:-2], c.shape[-2:]
+    a = n // sub
+    mm = functools.partial(jnp.matmul, precision=_HI)
+    row = jnp.arange(sub)
+    inside = row[:, None] >= row[None, :]
+
+    def subs(t):                         # [..., C, dk] -> [..., a, sub, dk]
+        return t.reshape(lead + (a, sub, dk))
+
+    cs, ks = subs(c), subs(k)
+    decay = jnp.exp(jnp.where(
+        inside[:, :, None], cs[..., :, None, :] - cs[..., None, :, :],
+        -jnp.inf))                                  # [..., a, sub, sub, dk]
+    # c at the boundary in front of each sub-block (0: the block's start)
+    edge = jnp.concatenate(
+        [jnp.zeros_like(cs[..., :1, -1, :]), cs[..., :-1, -1, :]], axis=-2)
+    before = jnp.arange(n)[None, :] < (jnp.arange(a) * sub)[:, None]
+    k_in = k[..., None, :, :] * jnp.exp(jnp.where(
+        before[:, :, None], edge[..., :, None, :] - c[..., None, :, :],
+        -jnp.inf))                                  # [..., a, C, dk]
+    own = jnp.eye(a, dtype=c.dtype)
+    out = []
+    for x in xs:
+        xs_ = subs(x)
+        near = jnp.sum(xs_[..., :, None, :] * ks[..., None, :, :] * decay,
+                       axis=-1)                     # [..., a, sub, sub]
+        far = mm(xs_ * jnp.exp(cs - edge[..., :, None, :]),
+                 jnp.swapaxes(k_in, -1, -2))        # [..., a, sub, C]
+        full = far.reshape(lead + (a, sub, a, sub)) \
+            + near[..., :, :, None, :] * own[:, None, :, None]
+        out.append(full.reshape(lead + (n, n)))
+    return out
+
+
+KDA_BLOCK, KDA_SUB = 64, 16    # the chunk form's block and its sub-blocks
+
+
+def kda_chunk(s0, q, k, v, beta, g, block=KDA_BLOCK, sub=KDA_SUB):
+    """The rule over T tokens of one stream from state s0 [H, dk, dv],
+    in blocks of `block` tokens (T a multiple of it, `block` of `sub`):
+    q, k, g [T, H, dk], v [T, H, dv], beta [T, H] -> (o [T, H, dv],
+    state).
+
+    delta_chunk's block algebra with Diag(e^c) in place of the scalar
+    e^c: with c [C, dk] the running sum of g inside the block, L the
+    strictly lower part of beta_i sum_d k_id k_jd exp(c_id - c_jd) and
+    A the lower part (diagonal included) of the same with q_i,
+    (I + L) [W | U] = [beta k e^c | beta v], and from the state S
+    entering the block
+        v' = U - W S
+        o  = (q e^c) S + A v'
+        S  = Diag(e^{c_last}) S + (k e^{c_last - c})^T v'.
+    L and A come from _decayed_products, which evaluates no positive
+    exponent. Every product runs at precision "highest", as in
+    delta_chunk and for its reasons."""
+    t, heads, dk = q.shape
+    dv = v.shape[-1]
+    n = t // block
+    mm = functools.partial(jnp.matmul, precision=_HI)
+
+    def blocks(a):                      # [T, H, ...] -> [n, H, block, ...]
+        a = a.reshape((n, block) + a.shape[1:])
+        return jnp.moveaxis(a, 1, 2)
+
+    q, k, v, g = blocks(q), blocks(k), blocks(v), blocks(g)  # [n, H, C, d]
+    beta = blocks(beta)                                      # [n, H, C]
+    c = jnp.cumsum(g, axis=-2)
+    row = jnp.arange(block)
+    kk, attn = _decayed_products((k, q), k, c, sub)
+    lower = jnp.where(row[:, None] > row[None, :],
+                      kk * beta[..., None], 0.0)
+    rhs = jnp.concatenate([k * beta[..., None] * jnp.exp(c),
+                           v * beta[..., None]], axis=-1)
+    wu = jax.lax.linalg.triangular_solve(
+        lower + jnp.eye(block, dtype=lower.dtype), rhs,
+        left_side=True, lower=True, unit_diagonal=True)
+    w, u = wu[..., :dk], wu[..., dk:]
+    q_in = q * jnp.exp(c)
+    c_last = c[..., -1, :]                                   # [n, H, dk]
+    k_out = k * jnp.exp(c_last[..., None, :] - c)
+
+    def step(s, xs):
+        w_b, u_b, attn_b, q_b, k_b, cl = xs
+        v_new = u_b - mm(w_b, s)                               # [H, C, dv]
+        o = mm(q_b, s) + mm(attn_b, v_new)
+        s = s * jnp.exp(cl)[..., None] + mm(jnp.swapaxes(k_b, -1, -2), v_new)
+        return s, o
+
+    s, o = jax.lax.scan(step, s0, (w, u, attn, q_in, k_out, c_last))
+    return jnp.moveaxis(o, 1, 2).reshape(t, heads, dv), s
+
+
+def _kda_op_inputs(ctx, op, qkv, gate, b):
+    heads, dk, dv, beta_scale = _delta_attrs(op)
+    return kda_inputs(qkv, gate, b, ctx.get(op.single_input('ALog')),
+                      ctx.get(op.single_input('DtBias')), heads, dk, dv,
+                      beta_scale)
+
+
+@op_emitter('kda_chunk')
+def _kda_chunk_emit(ctx, op):
+    """gated_delta_chunk's three forms (whole sequence from zero state;
+    one stream's chunk from and to its slot's state, rows from Len on
+    neither decaying nor writing) for a decay a key channel: QKV
+    [B, T, H*(2dk+dv)], G [B, T, H*dk], B [B, T, H], ALog [H], DtBias
+    [H*dk] -> Out [B, T, H*dv], in blocks of KDA_BLOCK tokens."""
+    heads, dk, dv, _ = _delta_attrs(op)
+    qkv = ctx.get(op.single_input('QKV'))
+    bsz, t = qkv.shape[:2]
+    q, k, v, beta, g = _kda_op_inputs(
+        ctx, op, qkv, ctx.get(op.single_input('G')),
+        ctx.get(op.single_input('B')))
+    state = slot = None
+    s0 = jnp.zeros((bsz, heads, dk, dv), jnp.float32)
+    if op.input('State'):
+        state = ctx.get(op.single_input('State'))
+        slot = ctx.get(op.single_input('Slot')).astype(jnp.int32).reshape(())
+        n = ctx.get(op.single_input('Len')).astype(jnp.int32).reshape(())
+        reset = ctx.get(op.single_input('Reset')).astype(bool).reshape(())
+        s0 = jnp.where(reset, 0.0, state[slot])[None]
+        live = (jnp.arange(t) < n)[None, :, None]
+        beta = jnp.where(live, beta, 0.0)
+        g = jnp.where(live[..., None], g, 0.0)
+    pad = -t % KDA_BLOCK
+    if pad:                    # whole blocks: the tail neither decays
+        q, k, v, beta, g = (   # nor writes
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            for a in (q, k, v, beta, g))
+    o, s = jax.vmap(kda_chunk)(s0, q, k, v, beta, g)
+    ctx.set(op.single_output('Out'),
+            o[:, :t].reshape(bsz, t, heads * dv).astype(qkv.dtype))
+    if state is not None:
+        ctx.set(op.single_output('StateOut'), state.at[slot].set(s[0]))
+
+
+def kda_step_reference(state, q, k, v, beta, g, live):
+    """The step op's plain composition: state [S, H, dk, dv], q, k, g
+    [S, H, dk], v [S, H, dv], beta [S, H], live [S] bool -> (o
+    [S, H, dv], state with the live lanes' updated)."""
+    o, new = kda_step(state, q, k, v, beta, g)
+    return o, jnp.where(live[:, None, None, None], new, state)
+
+
+@op_emitter('kda_step')
+def _kda_step_emit(ctx, op):
+    """gated_delta_step for a decay a key channel: one token a lane.
+    QKV [S, 1, H*(2dk+dv)], G [S, 1, H*dk], B [S, 1, H], ALog [H],
+    DtBias [H*dk], State [S, H, dk, dv], Live [S] -> Out [S, 1, H*dv],
+    StateOut. On a TPU (or under FLAGS_pallas_interpret) the Pallas
+    kernel, which takes the decay as a column beside k and q
+    (pallas/gated_delta.kda_step); the plain composition elsewhere."""
+    from ..flags import get_flag
+    from ..pallas import gated_delta as _gd
+    heads, _, dv, _ = _delta_attrs(op)
+    qkv = ctx.get(op.single_input('QKV'))
+    state = ctx.get(op.single_input('State'))
+    live = ctx.get(op.single_input('Live')).astype(bool)
+    q, k, v, beta, g = _kda_op_inputs(
+        ctx, op, qkv[:, 0], ctx.get(op.single_input('G'))[:, 0],
+        ctx.get(op.single_input('B'))[:, 0])
+    on_tpu = jax.default_backend() == 'tpu'
+    if on_tpu or bool(get_flag('pallas_interpret')):
+        o, new = _gd.kda_step(state, q, k, v, beta, jnp.exp(g), live,
+                              interpret=not on_tpu)
+    else:
+        o, new = kda_step_reference(state, q, k, v, beta, g, live)
+    ctx.set(op.single_output('Out'),
+            o.reshape(o.shape[0], 1, heads * dv).astype(qkv.dtype))
+    ctx.set(op.single_output('StateOut'), new)
+
+
+register_op('kda_chunk', infer_shape=_delta_infer,
+            grad=_no_backward('kda_chunk'))
+register_op('kda_step', infer_shape=_delta_infer, no_grad=True)

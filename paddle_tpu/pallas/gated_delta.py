@@ -1,8 +1,8 @@
-"""One token of the gated delta rule for every live lane, in Pallas, for
-TPU: one pass over the live lanes' state and nothing else (the op is
-`gated_delta_step`, ops/delta_rule_ops.py, whose plain composition is
-the reference).
-
+"""One token of the delta rule for every live lane, in Pallas, for TPU:
+one pass over the live lanes' state and nothing else. Two entry points,
+one a decay shape: `gated_delta_step` (ONE decay a head) and, at the
+end of the file, `kda_step` (a decay a KEY CHANNEL); the ops of those
+names (ops/delta_rule_ops.py) hold each one's plain reference.
 state [S, H, dk, dv] float32 stays in HBM and is updated in place (the
 output aliases it); a lane that takes no part is neither read nor
 written. The step is bound by memory: a lane's state is read once and
@@ -18,8 +18,8 @@ pipeline. Per head, on the vector unit in float32:
     S' = alpha S;  u = beta (v - S'^T k);  S = S' + k u^T;  o = S^T q
 
 k and q arrive as columns ([dk, 1], broadcast along lanes) and alpha,
-beta, v as rows ([1, dv], broadcast along sublanes), laid out so by
-the caller, where such transposes are a few kilobytes of XLA work.
+beta, v as rows ([1, dv], broadcast along sublanes; kda_step's alpha is
+a third column), laid out so by the caller: kilobytes of XLA work.
 """
 from __future__ import annotations
 
@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ['gated_delta_step', 'heads_per_block']
+__all__ = ['gated_delta_step', 'heads_per_block', 'kda_step']
 
 _BLOCK_BYTES = 1 << 20
 
@@ -115,6 +115,82 @@ def gated_delta_step(state, q, k, v, beta, alpha, live, interpret=False):
             dimension_semantics=('arbitrary', 'arbitrary')),
         interpret=pltpu.InterpretParams() if interpret else False,
         name='gated_delta_step',
+    )(idx.astype(jnp.int32), n.reshape(1), cols_in.astype(f32),
+      rows_in.astype(f32), state)
+    o = jnp.where(live[:, None, None], o.reshape(S, H, dv), 0.0)
+    return o, new
+
+
+# -- a decay a key channel ---------------------------------------------------
+
+def _kda_kernel(idx_ref, n_ref, cols_ref, rows_ref, s_ref, o_ref, so_ref, *,
+                hb):
+    """_kernel with alpha a column [dk, 1] beside q and k: S' is the
+    state with each ROW scaled, still one pass over the block."""
+    i = pl.program_id(1)
+    n = n_ref[0]
+
+    @pl.when(i < n)
+    def _():
+        for h in range(hb):
+            q = cols_ref[0, 0, :, h:h + 1]                    # [dk, 1]
+            k = cols_ref[0, 0, :, hb + h:hb + h + 1]
+            alpha = cols_ref[0, 0, :, 2 * hb + h:2 * hb + h + 1]
+            beta = rows_ref[0, 0, h:h + 1, :]                 # [1, dv]
+            v = rows_ref[0, 0, hb + h:hb + h + 1, :]
+            s = s_ref[0, h] * alpha                           # [dk, dv]
+            u = beta * (v - jnp.sum(s * k, axis=0, keepdims=True))
+            s = s + k * u
+            so_ref[0, h] = s
+            o_ref[0, 0, h:h + 1, :] = jnp.sum(s * q, axis=0, keepdims=True)
+
+    @pl.when(jnp.logical_and(n == 0, i == 0))
+    def _():
+        so_ref[...] = s_ref[...]
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=('interpret',))
+def kda_step(state, q, k, v, beta, alpha, live, interpret=False):
+    """state [S, H, dk, dv], q, k (normalised), alpha [S, H, dk],
+    v [S, H, dv], beta [S, H], live [S] bool -> (o [S, H, dv], state).
+    Lanes with live False keep their state; their rows of o are zero."""
+    S, H, dk, dv = state.shape
+    hb = heads_per_block(H, dk, dv)
+    G = H // hb
+    n = jnp.sum(live.astype(jnp.int32))
+    order = jnp.argsort(jnp.logical_not(live), stable=True)
+    idx = order[jnp.minimum(jnp.arange(S), jnp.maximum(n - 1, 0))]
+
+    def cols(a):                        # [S, H, dk] -> [S, G, dk, hb]
+        return jnp.swapaxes(a.reshape(S, G, hb, dk), -1, -2)
+
+    cols_in = jnp.concatenate([cols(q), cols(k), cols(alpha)], axis=-1)
+    rows_in = jnp.concatenate(
+        [jnp.broadcast_to(beta.reshape(S, G, hb, 1), (S, G, hb, dv)),
+         v.reshape(S, G, hb, dv)], axis=-2)
+    f32 = jnp.float32
+
+    def lane(shape):
+        return pl.BlockSpec((1, 1) + shape,
+                            lambda g, i, idx, n: (idx[i], g, 0, 0))
+
+    state_spec = pl.BlockSpec((1, hb, dk, dv),
+                              lambda g, i, idx, n: (idx[i], g, 0, 0))
+    o, new = pl.pallas_call(
+        functools.partial(_kda_kernel, hb=hb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(G, S),
+            in_specs=[lane((dk, 3 * hb)), lane((2 * hb, dv)), state_spec],
+            out_specs=[lane((hb, dv)), state_spec]),
+        out_shape=[jax.ShapeDtypeStruct((S, G, hb, dv), f32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary', 'arbitrary')),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name='kda_step',
     )(idx.astype(jnp.int32), n.reshape(1), cols_in.astype(f32),
       rows_in.astype(f32), state)
     o = jnp.where(live[:, None, None], o.reshape(S, H, dv), 0.0)

@@ -137,6 +137,9 @@ for; for a model with sliding layers also `rows_read` and
 attention has to read in the step);
 serving.state_lanes counter (lanes whose recurrent state the decode
 steps updated; attr `state_lanes` of the same span),
+serving.state_chunk_tokens counter (prompt tokens the prefill chunks
+carried through the recurrence's chunk form; attr `state_tokens` of
+`paged.prefill.tables`),
 serving.recurrent_state_bytes and serving.state_resets gauges (bytes
 the recurrent state holds; streams started from zero state so far),
 and serving.<family>.state_bytes for a spec that names its state's
@@ -232,6 +235,7 @@ _prefill_chunks = telemetry.histogram('serving.prefill_chunks')
 _decode_pages_read = telemetry.counter('serving.decode_pages_read')
 _decode_pages_window = telemetry.counter('serving.decode_pages_window')
 _state_lanes = telemetry.counter('serving.state_lanes')
+_state_chunk_tokens = telemetry.counter('serving.state_chunk_tokens')
 _state_bytes = telemetry.gauge('serving.recurrent_state_bytes')
 _state_resets = telemetry.gauge('serving.state_resets')
 _latent_bytes = telemetry.gauge('serving.latent.cache_bytes')
@@ -1076,7 +1080,7 @@ class PagedDecodePredictor(object):
         n = min(C, len(prompt) - start)
         cows, grows = [], []
         wtable = self._wtables.get(slot)
-        with RecordEvent('paged.prefill.tables'):
+        with RecordEvent('paged.prefill.tables') as ev:
             try:
                 for one in (table, wtable) if wtable else (table,):
                     before = len(one.pages)
@@ -1113,6 +1117,9 @@ class PagedDecodePredictor(object):
                 if start == 0:
                     self._resets += 1
                     _state_resets.set(self._resets)
+                # the rows the recurrence's chunk form really carries
+                ev.attrs['state_tokens'] = n
+                _state_chunk_tokens.inc(n)
             if wtable is not None:
                 wfeed = np.zeros((1, wtable.width), np.int32)
                 wtable.row(wfeed[0])

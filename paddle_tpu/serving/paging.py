@@ -380,7 +380,7 @@ class _Snap(object):
     over this boundary has registered a later one: with one reader at
     most that is a conversation which has moved on, and its row goes
     before any other (`spent`); a second reader makes it a shared
-    prefix again."""
+    prefix, and a shared prefix's row goes last."""
     __slots__ = ('row', 'chain', 'rest', 'tokens', 'stamp', 'pins', 'gone',
                  'reads', 'passed')
 
@@ -712,10 +712,13 @@ class PrefixCache(object):
         that only it kept: the least recently used of those a
         conversation has moved on from (`_Snap.spent`: the boundary this
         prompt opened on becomes one here, unless other streams read it
-        too), else the least recently used of all. So a session in
-        progress holds one row and not the two that its last turns
-        touched, and a burst of turns does not take the boundary that a
-        waiting session's next turn will open on."""
+        too), else the least recently used of those that fewer than two
+        streams have opened on, else of all. So a session in progress
+        holds one row and not the two that its last turns touched, a
+        burst of turns does not take the boundary that a waiting
+        session's next turn will open on, and single turns behind a
+        system prompt, whose own ends nobody reads, take turns in the
+        rows the system prompts leave them."""
         pt = self.pool.page_tokens
         full = len(prompt) // pt
         chains = [b''] + list(self._digests(prompt, full))
@@ -731,8 +734,9 @@ class PrefixCache(object):
                     if not s.pins]
             if not idle:
                 return None
-            self._drop_snap(min(idle, key=lambda s: (not s.spent, s.stamp)),
-                            release=True)
+            self._drop_snap(
+                min(idle, key=lambda s: (not s.spent, s.reads > 1, s.stamp)),
+                release=True)
         self.register(prompt, table)
         # the nearest boundary this prompt ran over
         for k in range(full, -1, -1):

@@ -17,7 +17,9 @@ the one recorded from the parent of the PR that left one paged builder
 (tests/serving_jaxprs_pr47.json: the same, and granite_h with snapshot
 rows and the GPT family under speculation). The sixth family
 (smallthinker: two page tables) is held to the record of the PR that
-added it (tests/serving_jaxprs_pr49.json).
+added it (tests/serving_jaxprs_pr49.json), and so is the seventh
+(solar_open2: the rule with a decay a key channel, with and without
+snapshot rows; tests/serving_jaxprs_pr52.json).
 """
 import hashlib
 import json
@@ -41,7 +43,7 @@ def _digest(text):
 
 def _models():
     from paddle_tpu.models import (axk1, granite_h, hybrid, nemotron_h,
-                                   smallthinker, transformer)
+                                   smallthinker, solar_open2, transformer)
     return {
         'gpt2': (transformer.language_model_logits,
                  transformer.TransformerConfig(
@@ -64,6 +66,11 @@ def _models():
                          smallthinker.SmallThinkerConfig(
                              vocab=64, dim=32, max_len=T, head_dim=8,
                              window=6)),
+        'solar_open2': (solar_open2.language_model_logits,
+                        solar_open2.SolarOpen2Config(
+                            vocab=64, dim=32, max_len=T, head_dim=8,
+                            key_dim=8, value_dim=8, gate_rank=4,
+                            expert_offset=4, experts_held=8)),
     }
 
 
@@ -121,6 +128,7 @@ DEPLOYED = {
     'granite_h_snapshot_rows': ('granite_h', dict(snapshot_rows=2)),
     'gpt2_speculative': ('gpt2', dict(speculative=True, spec_k=2,
                                       draft_layers=1)),
+    'solar_open2_snapshot_rows': ('solar_open2', dict(snapshot_rows=2)),
 }
 
 

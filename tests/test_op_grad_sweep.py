@@ -378,6 +378,8 @@ COVERED_ELSEWHERE = {
     'gated_delta_chunk': ('test_delta_rule_ops.py',
                           'test_no_backward_and_the_error_names_the_op',
                           'serving only: its grad maker raises by name'),
+    'kda_chunk': ('test_kda_ops.py', 'test_neither_op_has_a_backward',
+                  'serving only: its grad maker raises by name'),
     'short_conv': ('test_delta_rule_ops.py',
                    'test_no_backward_and_the_error_names_the_op',
                    'serving only: its grad maker raises by name'),

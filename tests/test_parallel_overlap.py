@@ -423,6 +423,47 @@ def test_flash_backward_of_the_training_cells_compiles_for_v5e(v5e_2x2):
         == [((64, 2048, 128), 'bfloat16')] * 3
 
 
+def test_kda_step_kernel_of_the_solar_cell_compiles_for_v5e(v5e_2x2):
+    """The step kernel at the cell's shape (48 lanes, 64 heads of 128 x
+    128, the decay a third column): lowered by Mosaic, compiled by the
+    installed TPU compiler, the state aliased and not copied."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.pallas import gated_delta as gd
+    one = SingleDeviceSharding(v5e_2x2[0])
+
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    state, col, row = arg(48, 64, 128, 128), arg(48, 64, 128), arg(48, 64)
+    compiled = jax.jit(gd.kda_step, donate_argnums=0).lower(
+        state, col, col, col, row, col, arg(48, dtype=jnp.bool_)).compile()
+    assert 'tpu_custom_call' in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 48 * 64 * 128 * 128 * 4
+    assert mem.temp_size_in_bytes < 64 << 20
+
+
+def test_kda_chunk_of_the_solar_cell_compiles_for_v5e(v5e_2x2):
+    """A prefill chunk of 256 tokens through the chunk form at the
+    cell's widths: what it keeps beside its inputs stays far under a
+    lane's state times the blocks (no [C, C, dk] tensor a block is
+    materialised for all heads at once beyond a few hundred MB)."""
+    import functools
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.ops import delta_rule_ops as dr
+    one = SingleDeviceSharding(v5e_2x2[0])
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+
+    compiled = jax.jit(functools.partial(dr.kda_chunk, block=64, sub=16)) \
+        .lower(arg(64, 128, 128), arg(256, 64, 128), arg(256, 64, 128),
+               arg(256, 64, 128), arg(256, 64), arg(256, 64, 128)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 # -- the page copy program, compiled for one described chip ------------------
 # (here because this file is the one that describes a TPU: see v5e_2x2)
 

@@ -28,6 +28,7 @@ def _recorded(name):
 RECORDED = _recorded('serving_jaxprs_pr44.json')
 RECORDED_PR47 = _recorded('serving_jaxprs_pr47.json')
 RECORDED_PR49 = _recorded('serving_jaxprs_pr49.json')
+RECORDED_PR52 = _recorded('serving_jaxprs_pr52.json')
 
 
 @pytest.mark.parametrize('name', ['gpt2', 'hybrid', 'nemotron_h', 'axk1'])
@@ -51,13 +52,22 @@ def test_the_sixth_family_traces_as_recorded():
         RECORDED_PR49['smallthinker']
 
 
+def test_the_seventh_family_traces_as_recorded():
+    """solar_open2 (the rule with a decay a key channel, gated
+    attention, an expert sublayer a layer): the record of the PR that
+    added it (PR 52)."""
+    assert serving_jaxprs.served('solar_open2') == \
+        RECORDED_PR52['solar_open2']
+
+
 @pytest.mark.parametrize('key', sorted(serving_jaxprs.DEPLOYED))
 def test_a_deployment_with_more_programs_traces_as_before(key):
     name, deployment = serving_jaxprs.DEPLOYED[key]
+    recorded = RECORDED_PR52 if key in RECORDED_PR52 else RECORDED_PR47
     got = serving_jaxprs.served(name, **deployment)
-    assert got == RECORDED_PR47[key]
+    assert got == recorded[key]
     # the programs every deployment of the model runs are among them
-    assert set(RECORDED_PR47[name]) <= set(got)
+    assert set(recorded[name]) <= set(got)
 
 
 def test_the_two_records_agree_where_both_speak():
