@@ -221,7 +221,7 @@ class ServeSystem(olmo_hybrid.ServeSystem):
         self.phases.note('prepare_decoding')
         jax.block_until_ready(jax.live_arrays())
         self.phases.note('device_transfers')
-        self.probe = gpt2._StepProbe(self.dec)
+        self.probe = gpt2._StepProbe(self.dec, self.slice_s)
         self.engine = ServingEngine(self.dec).start()
         self._jax = jax
         self.phases.mark('weights')
@@ -246,21 +246,14 @@ class ServeSystem(olmo_hybrid.ServeSystem):
             self.phases.detail.append(('preroll', seconds))
         self.phases.mark('warm')
 
-    def counters(self):
-        """olmo_hybrid's; what the expert layers counted, as
-        builders/nemotron_h.py reports it; the prefix cache's counters
-        beside the prompt tokens admitted; the bytes the latent pages in
-        use hold; and, for the rooflines, the latent rows and the count
-        of the decode steps dispatched in the last `trace_seconds`
-        before this reading: the executions a traced slice holds (both
-        end in `_max` only so that a drive takes them as they stand)."""
-        from paddle_tpu.obs import telemetry, trace
-        c = olmo_hybrid.ServeSystem.counters(self)
-        moe = self.dec.moe_counters()
-        for what in ('pairs', 'experts_touched', 'pairs_dropped',
-                     'layer_calls'):
-            c['moe_' + what] = moe.get('decode.' + what, 0)
-            c['moe_prefill_' + what] = moe.get(what, 0) - c['moe_' + what]
+    def counters(self, slice_since=None):
+        """olmo_hybrid's (the step probe's among them: what the expert
+        layers counted, and the slice's latent rows, `slice_latent_rows`
+        over `slice_decode_calls`); the prefix cache's counters beside
+        the prompt tokens admitted; the bytes the latent pages in use
+        hold."""
+        from paddle_tpu.obs import telemetry
+        c = olmo_hybrid.ServeSystem.counters(self, slice_since)
         snap = telemetry.snapshot()
         for key in ('prefix_hits', 'prefix_tokens_reused',
                     'prompt_tokens_admitted'):
@@ -269,13 +262,6 @@ class ServeSystem(olmo_hybrid.ServeSystem):
             snap['counters'].get('serving.latent.rows_read', 0)
         c['latent_cache_bytes_max'] = \
             snap['gauges'].get('serving.latent.cache_bytes', 0)
-        since = time.perf_counter() \
-            - float(self.traffic['params'].get('trace_seconds', 4))
-        rows = [s['latent_rows'] for s in trace.spans()
-                if s['name'] == 'paged.decode.tables' and s['t0'] >= since
-                and 'latent_rows' in s]
-        c['slice_latent_rows_max'] = sum(rows)
-        c['slice_decode_calls_max'] = len(rows)
         return c
 
     def check(self):

@@ -11,13 +11,15 @@ build_serve  save_inference_model -> AnalysisPredictor ->
 """
 from __future__ import annotations
 
+import bisect
+import collections
 import gc
 import tempfile
 import time
 
 import numpy as np
 
-from harness import trace, traffic as traffic_mod
+from harness import spans, trace, traffic as traffic_mod
 from reference import gpt2 as ref
 
 
@@ -306,77 +308,196 @@ def build_train(**kw):
 
 # -- serving -----------------------------------------------------------------
 
-class _StepProbe:
-    """Wraps the two calls the engine's worker makes on the decoder
-    (decode_step, prefill_step) on the INSTANCE: a host span on the
-    profiler's clock around each, the host time of each, the prompt
-    tokens prefilled and the live tokens a decode step attends to."""
+DECODE_TABLES, PREFILL_TABLES = 'paged.decode.tables', 'paged.prefill.tables'
+# what the program says of a step on its own spans (serving/paged.py)
+LANE_ATTRS = ('pages_read', 'state_lanes', 'latent_rows', 'rows_read',
+              'window_rows_read')
+CHUNK_ATTRS = ('state_tokens',)
+MOE_COUNTS = ('pairs', 'experts_touched', 'layer_calls')
 
-    def __init__(self, dec):
+
+def slice_counts(steps, program_spans, since):
+    """The slice's own counts: sums over the steps that ended at or after
+    `since`. `steps` are the probe's records (t0, t1, lanes, live_tokens,
+    chunk_tokens), `program_spans` the program's span dicts. A step is
+    named by what it carried, which is what the program itself said of
+    it: lanes, if a `paged.decode.tables` span began inside it, a chunk,
+    if a `paged.prefill.tables` span did; a step that carried both holds
+    both.
+
+      slice_decode_calls, slice_<attr>   the decode spans and their
+          attrs' sums (LANE_ATTRS), beside slice_lanes and
+          slice_live_tokens, which the probe counted in the same steps
+      slice_plain_*    the same over the steps that carried lanes and no
+          chunk: the pure decode program's executions
+      slice_prefill_calls, slice_state_tokens   the prefill spans and
+          their attr's sum, beside slice_prefill_tokens (the probe's)
+    """
+    tables = sorted((s['t0'], s['name'], s) for s in program_spans
+                    if s['name'] in (DECODE_TABLES, PREFILL_TABLES))
+    starts = [t for t, _, _ in tables]
+    c = dict.fromkeys(
+        [pre + key for pre in ('slice_', 'slice_plain_')
+         for key in ('decode_calls', 'lanes', 'live_tokens') + LANE_ATTRS]
+        + ['slice_prefill_calls', 'slice_prefill_tokens']
+        + ['slice_' + a for a in CHUNK_ATTRS], 0)
+    for t0, t1, lanes, live, chunk in steps:
+        if t1 < since:
+            continue
+        mine = tables[bisect.bisect_left(starts, t0):
+                      bisect.bisect_left(starts, t1)]
+        carried = [s for _, name, s in mine if name == DECODE_TABLES]
+        chunks = [s for _, name, s in mine if name == PREFILL_TABLES]
+        prefixes = () if not carried else ('slice_',) if chunks \
+            else ('slice_', 'slice_plain_')
+        for pre in prefixes:
+            c[pre + 'decode_calls'] += len(carried)
+            c[pre + 'lanes'] += lanes
+            c[pre + 'live_tokens'] += live
+            for a in LANE_ATTRS:
+                c[pre + a] += sum(s.get(a, 0) for s in carried)
+        if chunks:
+            c['slice_prefill_calls'] += len(chunks)
+            c['slice_prefill_tokens'] += chunk
+            for a in CHUNK_ATTRS:
+                c['slice_' + a] += sum(s.get(a, 0) for s in chunks)
+    return c
+
+
+class _StepProbe:
+    """Wraps every public callable of the decoder whose name ends in
+    `_step` (today `prefill_step` and `decode_step`, the two calls the
+    engine's worker makes) on the INSTANCE: a host span `bench.<name>`
+    on the profiler's clock around each, and a record of what the step
+    carried, read from what the call left behind and not from the
+    method's name. A stream that stands inside its prompt (`open_stream`
+    tells the prompt's length) and grew carried a chunk's rows; a stream
+    past its prompt that grew was a lane. `decode_calls` / `prefill_calls`
+    count the steps that carried lanes / a chunk, `decode_s` the host
+    time of the first, `prefill_s` that of the steps with a chunk and no
+    lane, `live_tokens` the tokens the lanes held after their step,
+    `prefill_tokens` the rows the chunks carried. The records of twice the
+    last `slice_s` seconds (the traffic's `trace_seconds`) stay, for
+    slice_counts(); `on_step` callables run after every step."""
+
+    def __init__(self, dec, slice_s):
         self.decode_calls = self.prefill_calls = 0
         self.decode_s = self.prefill_s = 0.0
         self.prefill_tokens = self.live_tokens = 0
         self.pages_max = self.live_pages_max = 0
-        self._dec = dec
-        self._prefilling = set()
-        decode, prefill, opened = \
-            dec.decode_step, dec.prefill_step, dec.open_stream
+        self.on_step = []
+        self.steps = collections.deque()
+        self.moe_at = collections.deque()   # (when, dec.moe_counters())
+        self._dec, self.slice_s = dec, float(slice_s)
+        self._prompt = {}                   # slot: its prompt's length
+        opened = dec.open_stream
 
         def open_stream(slot, prompt):
-            self._prefilling.add(int(slot))
+            self._prompt[int(slot)] = len(prompt)
             return opened(slot, prompt)
 
-        def prefill_step(slot, *a, **kw):
-            before = dec.slot_tokens().get(int(slot), 0)
+        dec.open_stream = open_stream
+        for name in dir(dec):
+            if name.endswith('_step') and not name.startswith('_') \
+                    and callable(getattr(dec, name)):
+                setattr(dec, name, self._wrapped(name, getattr(dec, name)))
+
+    def _wrapped(self, name, call):
+        dec, span = self._dec, 'bench.' + name
+
+        def step(*a, **kw):
+            before = dec.slot_tokens()
             t0 = time.perf_counter()
-            with trace.span('bench.prefill_step'):
-                out = prefill(slot, *a, **kw)
-            self.prefill_s += time.perf_counter() - t0
-            self.prefill_calls += 1
-            self.prefill_tokens += \
-                dec.slot_tokens().get(int(slot), 0) - before
-            if out is not None:
-                self._prefilling.discard(int(slot))
-            self._see_pages()
+            with trace.span(span):
+                out = call(*a, **kw)
+            t1 = time.perf_counter()
+            self._see(t0, t1, before, dec.slot_tokens())
             return out
 
-        def decode_step(tokens, positions, *a, **kw):
-            t0 = time.perf_counter()
-            with trace.span('bench.decode_step'):
-                out = decode(tokens, positions, *a, **kw)
-            self.decode_s += time.perf_counter() - t0
+        step.__name__ = name
+        return step
+
+    def _see(self, t0, t1, before, after):
+        lanes = live = chunk = 0
+        for slot, n in after.items():
+            grew = n - before.get(slot, 0)
+            if grew <= 0:
+                continue
+            if before.get(slot, 0) < self._prompt.get(slot, 0):
+                chunk += grew
+            else:
+                lanes += 1
+                live += n
+        if lanes:
             self.decode_calls += 1
-            self.live_tokens += sum(
-                n for s, n in dec.slot_tokens().items()
-                if s not in self._prefilling)
-            self._see_pages()
-            return out
-
-        dec.open_stream, dec.prefill_step, dec.decode_step = \
-            open_stream, prefill_step, decode_step
-
-    def _see_pages(self):
-        """After a step: the pages the pool has handed out (open streams
-        and what the prefix cache keeps of finished prompts), and those
-        that open streams alone hold."""
+            self.decode_s += t1 - t0
+            self.live_tokens += live
+        if chunk:
+            self.prefill_calls += 1
+            self.prefill_tokens += chunk
+            if not lanes:
+                self.prefill_s += t1 - t0
+        self.steps.append((t0, t1, lanes, live, chunk))
+        if not self.moe_at or t1 - self.moe_at[-1][0] >= 0.1:
+            # a sample ten times a second: a slice's expert counts are a
+            # difference of two, both ends on a step's boundary
+            self.moe_at.append((t1, self._dec.moe_counters()))
+        while self.steps[0][1] < t1 - 2 * self.slice_s:
+            self.steps.popleft()
+        while self.moe_at[0][0] < t1 - 2 * self.slice_s:
+            self.moe_at.popleft()
+        # the pages the pool has handed out (open streams and what the
+        # prefix cache keeps of finished prompts), and those that open
+        # streams alone hold
         dec = self._dec
         self.pages_max = max(self.pages_max,
                              dec.pool_stats()['pages_in_use'])
         self.live_pages_max = max(self.live_pages_max, sum(
-            -(-n // dec.page_tokens) for n in dec.slot_tokens().values()))
+            -(-n // dec.page_tokens) for n in after.values()))
+        for call in self.on_step:
+            call()
 
-    def counters(self):
-        """Running totals, and two `*_max`: the most since the last
-        reading."""
+    def counters(self, since=None):
+        """Running totals; two `*_max`, the most since the last reading;
+        the `slice_*` sums over the steps that ended at or after `since`
+        (when the profiler's capture began: the steps a traced slice
+        holds), without it over those of the last `slice_s` seconds
+        (slice_counts, and what the expert layers counted since the first
+        sample of those seconds, decode steps and prefill chunks apart),
+        which stand for themselves: a drive takes them as its closing
+        reading has them (harness/drives.py)."""
         c = {k: getattr(self, k) for k in (
             'decode_calls', 'prefill_calls', 'decode_s', 'prefill_s',
             'prefill_tokens', 'live_tokens')}
         c['kv_pages_in_use_max'], self.pages_max = self.pages_max, 0
         c['kv_live_pages_max'], self.live_pages_max = self.live_pages_max, 0
+        if since is None:
+            since = time.perf_counter() - self.slice_s
+        c.update(slice_counts(list(self.steps), spans.program_spans(),
+                              since))
+        moe = self._dec.moe_counters()      # {} without expert layers
+        if moe:
+            then = next((m for t, m in list(self.moe_at) if t >= since), moe)
+            for what in MOE_COUNTS + ('pairs_dropped',):
+                c['moe_' + what] = moe.get('decode.' + what, 0)
+                c['moe_prefill_' + what] = \
+                    moe.get(what, 0) - c['moe_' + what]
+            for what in MOE_COUNTS:
+                lanes = moe.get('decode.' + what, 0) \
+                    - then.get('decode.' + what, 0)
+                c['slice_moe_' + what] = lanes
+                c['slice_moe_prefill_' + what] = \
+                    moe.get(what, 0) - then.get(what, 0) - lanes
         return c
 
 
 class ServeSystem(_System):
+    @property
+    def slice_s(self):
+        """The seconds a traced run traces before its window closes: the
+        slice whose steps the probe's `slice_*` counters sum over."""
+        return float(self.traffic['params'].get('trace_seconds', 4))
+
     def build(self):
         import jax
         import paddle_tpu as fluid
@@ -426,7 +547,7 @@ class ServeSystem(_System):
         # (0.01 s on the chip: prepare_decoding has waited already)
         jax.block_until_ready(jax.live_arrays())
         self.phases.note('device_transfers')
-        self.probe = _StepProbe(self.dec)
+        self.probe = _StepProbe(self.dec, self.slice_s)
         self.engine = ServingEngine(self.dec).start()
         self._jax = jax
         self.phases.mark('weights')
@@ -447,10 +568,16 @@ class ServeSystem(_System):
                 (phase + '_prefill_calls', self.probe.prefill_s),
                 (phase + '_decode_calls', self.probe.decode_s)]
 
-    def counters(self):
+    def counters(self, slice_since=None):
+        """The step probe's (the window's totals, the slice's own counts
+        and, for a model with expert layers, what they counted: the one
+        place every serving builder takes them from), the executors'
+        compiles, preemptions and the lanes fed a decode step.
+        `slice_since`: when the traced slice began, on `perf_counter()`
+        (the drive's closing reading of a traced run)."""
         from paddle_tpu.obs import telemetry
         snap = telemetry.snapshot()
-        c = self.probe.counters()
+        c = self.probe.counters(slice_since)
         c['compiled_segments'] = \
             self.dec.jit_cache_stats()['compiled_segments']
         c['preemptions'] = snap['counters'].get('serving.preemptions', 0)

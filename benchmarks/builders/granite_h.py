@@ -18,7 +18,6 @@ snapshot rows count.
 """
 from __future__ import annotations
 
-import collections
 import gc
 import tempfile
 import time
@@ -137,8 +136,6 @@ class ServeSystem(olmo_hybrid.ServeSystem):
         self.phases, self.rehearse = phases, rehearse
         self.dims = ref.dims_of(config)
         self.streams_opened = 0
-        self.window_open = False
-        self.moe_at = collections.deque()   # (when, moe_counters()) a step
 
     def build(self):
         import jax
@@ -182,7 +179,7 @@ class ServeSystem(olmo_hybrid.ServeSystem):
         self.phases.note('prepare_decoding')
         jax.block_until_ready(jax.live_arrays())
         self.phases.note('device_transfers')
-        self.probe = gpt2._StepProbe(self.dec)
+        self.probe = gpt2._StepProbe(self.dec, self.slice_s)
         opened = self.dec.open_stream
 
         def open_stream(slot, prompt):
@@ -190,18 +187,6 @@ class ServeSystem(olmo_hybrid.ServeSystem):
             return opened(slot, prompt)
 
         self.dec.open_stream = open_stream
-        stepped = self.dec.decode_step
-        keep = 2 * float(self.traffic['params'].get('trace_seconds', 4))
-
-        def decode_step(*a, **kw):
-            out = stepped(*a, **kw)
-            now = time.perf_counter()
-            self.moe_at.append((now, self.dec.moe_counters()))
-            while self.moe_at[0][0] < now - keep:
-                self.moe_at.popleft()
-            return out
-
-        self.dec.decode_step = decode_step
         self.engine = ServingEngine(self.dec).start()
         self._jax = jax
         self.phases.mark('weights')
@@ -234,28 +219,15 @@ class ServeSystem(olmo_hybrid.ServeSystem):
         self.phases.detail.append(('preroll', seconds))
         self.phases.mark('warm')
 
-    def counters(self):
-        """olmo_hybrid's; what the expert sublayers counted and the
-        bytes the state-space state holds, as builders/nemotron_h.py
-        reports them; the prefix cache's counters beside the prompt
-        tokens and the streams admitted; what the snapshot rows counted
-        and hold; and, for the rooflines, the `slice_*` keys: sums over
-        the decode steps dispatched in the last `trace_seconds` before
-        this reading, the executions a traced slice holds: their count
-        (`slice_decode_calls`), their state lanes, their live tokens
-        (the least the pages read can hold: all but a lane's last page
-        full, one token on that), and what the expert sublayers counted
-        in those seconds, decode steps and prefill chunks apart. A drive
-        reads twice and reports the difference (harness/drives.py), so
-        the reading that opens a window gives 0 for each `slice_*` key
-        and the one that closes it the slice's sums."""
-        from paddle_tpu.obs import telemetry, trace
-        c = olmo_hybrid.ServeSystem.counters(self)
-        moe = self.dec.moe_counters()
-        for what in ('pairs', 'experts_touched', 'pairs_dropped',
-                     'layer_calls'):
-            c['moe_' + what] = moe.get('decode.' + what, 0)
-            c['moe_prefill_' + what] = moe.get(what, 0) - c['moe_' + what]
+    def counters(self, slice_since=None):
+        """olmo_hybrid's (the step probe's among them: the window's
+        totals, the slice's own counts for the rooflines and what the
+        expert sublayers counted, gpt2._StepProbe.counters); the bytes
+        the state-space state holds, as builders/nemotron_h.py reports
+        them; the prefix cache's counters beside the prompt tokens and
+        the streams admitted; what the snapshot rows counted and hold."""
+        from paddle_tpu.obs import telemetry
+        c = olmo_hybrid.ServeSystem.counters(self, slice_since)
         snap = telemetry.snapshot()
         c['ssm_state_bytes_max'] = \
             snap['gauges'].get('serving.ssm.state_bytes', 0)
@@ -268,26 +240,6 @@ class ServeSystem(olmo_hybrid.ServeSystem):
         c['state_snapshot_bytes_max'] = \
             snap['gauges'].get('serving.state.snapshot_bytes', 0)
         c['streams_opened'] = self.streams_opened
-        closing, self.window_open = self.window_open, not self.window_open
-        since = time.perf_counter() \
-            - float(self.traffic['params'].get('trace_seconds', 4))
-        steps = [s for s in trace.spans()
-                 if s['name'] == 'paged.decode.tables' and s['t0'] >= since
-                 and 'state_lanes' in s] if closing else []
-        pt = self.dec.page_tokens
-        c['slice_decode_calls'] = len(steps)
-        c['slice_state_lanes'] = sum(s['state_lanes'] for s in steps)
-        c['slice_live_tokens'] = sum(
-            (s['pages_read'] - s['state_lanes']) * pt + s['state_lanes']
-            for s in steps)
-        then = next((m for t, m in self.moe_at if t >= since), moe) \
-            if closing else moe
-        for what in ('pairs', 'experts_touched', 'layer_calls'):
-            dec = moe.get('decode.' + what, 0) \
-                - then.get('decode.' + what, 0)
-            c['slice_moe_' + what] = dec
-            c['slice_moe_prefill_' + what] = \
-                moe.get(what, 0) - then.get(what, 0) - dec
         return c
 
     def check(self):

@@ -140,25 +140,20 @@ class ServeSystem(olmo_hybrid.ServeSystem):
         self.phases.note('prepare_decoding')
         jax.block_until_ready(jax.live_arrays())
         self.phases.note('device_transfers')
-        self.probe = gpt2._StepProbe(self.dec)
+        self.probe = gpt2._StepProbe(self.dec, self.slice_s)
         self.engine = ServingEngine(self.dec).start()
         self._jax = jax
         self.phases.mark('weights')
         return self
 
-    def counters(self):
-        """olmo_hybrid's, and what the expert layers counted (running
-        totals over the steps that have ended: pairs of token and held
-        expert, held experts with at least one pair, pairs not computed,
-        layers run; `moe_*` the decode program's, `moe_prefill_*` the
-        prefill program's) and the bytes the state-space state holds."""
+    def counters(self, slice_since=None):
+        """olmo_hybrid's (what the expert layers counted is the step
+        probe's: `moe_*` the decode program's running totals over the
+        steps that have ended, `moe_prefill_*` the prefill program's,
+        `slice_moe_*` both over the slice's seconds) and the bytes the
+        state-space state holds."""
         from paddle_tpu.obs import telemetry
-        c = olmo_hybrid.ServeSystem.counters(self)
-        moe = self.dec.moe_counters()
-        for what in ('pairs', 'experts_touched', 'pairs_dropped',
-                     'layer_calls'):
-            c['moe_' + what] = moe.get('decode.' + what, 0)
-            c['moe_prefill_' + what] = moe.get(what, 0) - c['moe_' + what]
+        c = olmo_hybrid.ServeSystem.counters(self, slice_since)
         c['ssm_state_bytes_max'] = telemetry.snapshot()['gauges'].get(
             'serving.ssm.state_bytes', 0)
         return c

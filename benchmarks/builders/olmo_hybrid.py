@@ -133,7 +133,7 @@ class ServeSystem(gpt2.ServeSystem):
         self.phases.note('prepare_decoding')
         jax.block_until_ready(jax.live_arrays())
         self.phases.note('device_transfers')
-        self.probe = gpt2._StepProbe(self.dec)
+        self.probe = gpt2._StepProbe(self.dec, self.slice_s)
         self.engine = ServingEngine(self.dec).start()
         self._jax = jax
         self.phases.mark('weights')
@@ -167,13 +167,13 @@ class ServeSystem(gpt2.ServeSystem):
             self.engine.submit(r['prompt'], max_new_tokens=r['max_new'])
         time.sleep(max(0.0, t0 + seconds - time.perf_counter()))
 
-    def counters(self):
+    def counters(self, slice_since=None):
         """gpt2's, and what the recurrent state counted: the lanes whose
         state the decode steps updated (a running total) and the bytes
         the state holds."""
         from paddle_tpu.obs import telemetry
         snap = telemetry.snapshot()
-        c = gpt2.ServeSystem.counters(self)
+        c = gpt2.ServeSystem.counters(self, slice_since)
         c['state_lanes'] = snap['counters'].get('serving.state_lanes', 0)
         c['recurrent_state_bytes_max'] = \
             snap['gauges'].get('serving.recurrent_state_bytes', 0)

@@ -16,10 +16,8 @@ cached document's pages of BOTH pools.
 """
 from __future__ import annotations
 
-import collections
 import gc
 import tempfile
-import time
 
 import numpy as np
 
@@ -160,8 +158,6 @@ class ServeSystem(axk1.ServeSystem):
         self.phases, self.rehearse = phases, rehearse
         self.dims = ref.dims_of(config)
         self.window_live_max = 0
-        self.window_open = False
-        self.moe_at = collections.deque()   # (when, moe_counters()) a step
 
     def build(self):
         import jax
@@ -205,57 +201,31 @@ class ServeSystem(axk1.ServeSystem):
         self.phases.note('prepare_decoding')
         jax.block_until_ready(jax.live_arrays())
         self.phases.note('device_transfers')
-        self.probe = gpt2._StepProbe(self.dec)
-        stepped, chunked = self.dec.decode_step, self.dec.prefill_step
-        keep = 2 * float(self.traffic['params'].get('trace_seconds', 4))
+        self.probe = gpt2._StepProbe(self.dec, self.slice_s)
 
         def see_window():
             self.window_live_max = max(
                 self.window_live_max,
                 self.dec.pool_stats()['window_pages_live'])
 
-        def decode_step(*a, **kw):
-            out = stepped(*a, **kw)
-            see_window()
-            now = time.perf_counter()
-            self.moe_at.append((now, self.dec.moe_counters()))
-            while self.moe_at[0][0] < now - keep:
-                self.moe_at.popleft()
-            return out
-
-        def prefill_step(*a, **kw):
-            out = chunked(*a, **kw)
-            see_window()
-            return out
-
-        self.dec.decode_step, self.dec.prefill_step = decode_step, prefill_step
+        self.probe.on_step.append(see_window)
         self.engine = ServingEngine(self.dec).start()
         self._jax = jax
         self.phases.mark('weights')
         return self
 
-    def counters(self):
-        """olmo_hybrid's; what the expert sublayers counted and the
-        prefix cache's counters beside the prompt tokens admitted, as
+    def counters(self, slice_since=None):
+        """olmo_hybrid's (the step probe's among them: the slice's own
+        counts for the rooflines, the K/V rows a full layer's and a
+        sliding layer's attention had to read in its decode steps, and
+        what the expert sublayers counted, gpt2._StepProbe.counters);
+        the prefix cache's counters beside the prompt tokens admitted, as
         builders/axk1.py reports them; what the second table counted
         (`window_pages_freed`, `prefix_window_tail_miss`: running
         totals; `window_live_pages_max`, `window_pages_in_use_max`: the
-        most since the reading before); and, for the rooflines, the
-        `slice_*` keys: sums over the decode steps dispatched in the
-        last `trace_seconds` before this reading, the executions a
-        traced slice holds: their count, the K/V rows their full layer's
-        attention and a sliding layer's attention had to read, and
-        what the expert sublayers counted in those seconds. A drive
-        reads twice and reports the difference (harness/drives.py), so
-        the reading that opens a window gives 0 for each `slice_*` key
-        and the one that closes it the slice's sums."""
-        from paddle_tpu.obs import telemetry, trace
-        c = olmo_hybrid.ServeSystem.counters(self)
-        moe = self.dec.moe_counters()
-        for what in ('pairs', 'experts_touched', 'pairs_dropped',
-                     'layer_calls'):
-            c['moe_' + what] = moe.get('decode.' + what, 0)
-            c['moe_prefill_' + what] = moe.get(what, 0) - c['moe_' + what]
+        most since the reading before)."""
+        from paddle_tpu.obs import telemetry
+        c = olmo_hybrid.ServeSystem.counters(self, slice_since)
         snap = telemetry.snapshot()
         for key in ('prefix_hits', 'prefix_tokens_reused',
                     'prompt_tokens_admitted', 'window_pages_freed'):
@@ -268,21 +238,6 @@ class ServeSystem(axk1.ServeSystem):
             self.window_live_max, 0
         c['window_pages_in_use_max'] = \
             self.dec.pool_stats()['window_pages_in_use']
-        closing, self.window_open = self.window_open, not self.window_open
-        since = time.perf_counter() \
-            - float(self.traffic['params'].get('trace_seconds', 4))
-        steps = [s for s in trace.spans()
-                 if s['name'] == 'paged.decode.tables' and s['t0'] >= since
-                 and 'window_rows_read' in s] if closing else []
-        c['slice_decode_calls'] = len(steps)
-        c['slice_full_rows_read'] = sum(s['rows_read'] for s in steps)
-        c['slice_window_rows_read'] = sum(
-            s['window_rows_read'] for s in steps)
-        then = next((m for t, m in self.moe_at if t >= since), moe) \
-            if closing else moe
-        for what in ('pairs', 'experts_touched', 'layer_calls'):
-            c['slice_moe_' + what] = moe.get('decode.' + what, 0) \
-                - then.get('decode.' + what, 0)
         return c
 
     def check(self):

@@ -54,9 +54,11 @@ def steps(system, plan, seconds, tracer):
 
 def _serve_counters(system, before, after, t_window):
     """Totals as after minus before; a `*_max` is the most since the
-    reading before it, and stands as `after` has it."""
-    c = {k: after[k] if k.endswith('_max') else after[k] - before[k]
-         for k in after}
+    reading before it and a `slice_*` a sum over the last seconds
+    before its reading (the traced slice's own counts): both stand as
+    `after` has them."""
+    c = {k: after[k] if k.endswith('_max') or k.startswith('slice_')
+         else after[k] - before[k] for k in after}
     c['window_s'] = t_window
     # the name the training drive gives it: one reader reads both
     c['compiles_in_window'] = c.pop('compiled_segments')
@@ -65,6 +67,9 @@ def _serve_counters(system, before, after, t_window):
 
 def open_loop(system, plan, seconds, tracer):
     """Requests submitted when due. Judged: those due inside the window.
+    The closing reading of the system's counters is told when the
+    profiler's capture began (None without one), so that the slice's own
+    counts are of the steps the traced slice holds.
     Arrivals go on after it until the judged have all ended or the
     time-out has passed, so the last judged request decodes under the
     same load as the first."""
@@ -84,7 +89,7 @@ def open_loop(system, plan, seconds, tracer):
         if delay > 0:
             time.sleep(delay)
         if after is None and time.perf_counter() - t0 >= seconds:
-            after = system.counters()
+            after = system.counters(tracer.started_at)
             tracer.stop()
         tracer.poll(time.perf_counter() - t0, seconds)
         try:
@@ -98,7 +103,7 @@ def open_loop(system, plan, seconds, tracer):
     if time.perf_counter() < end:
         time.sleep(end - time.perf_counter())
     if after is None:
-        after = system.counters()
+        after = system.counters(tracer.started_at)
         tracer.stop()
     for _, req in judged:
         if req is not None:

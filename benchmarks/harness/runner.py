@@ -28,6 +28,10 @@ def _args(argv):
     ap.add_argument('--record-trace', metavar='FILE',
                     help='with --trace 1: also write the plain events and '
                          'their labels as JSON (how tests/data was made)')
+    ap.add_argument('--record-run', metavar='FILE',
+                    help='with --trace 1: also write what the readers are '
+                         'handed (the plain data of the run, prompts as their '
+                         'lengths) as JSON, for tools/reread.py')
     return ap.parse_args(argv)
 
 
@@ -113,10 +117,6 @@ def main(argv, wall_at_import):
 
     correct = bool(checks) and all(c['value'] <= c['limit'] for c in checks) \
         and result['failed'] == 0
-    for c in checks:
-        print('compared %-28s %.6g (limit %.6g)%s'
-              % (c['name'], c['value'], c['limit'],
-                 '' if c['value'] <= c['limit'] else '  EXCEEDED'))
     print('setup phases ' + ' '.join(
         '%s=%.3f' % (p, phases.seconds[p]) for p in setup_clock.PHASES)
         + ' total=%.3f compile_misses=%d' % (setup_s, setup_misses))
@@ -154,6 +154,8 @@ def main(argv, wall_at_import):
         run = dict(cell=cell, config=config, traffic=traffic, plan=plan,
                   e2e=e2e, counters=counters, setup=dict(phases.seconds),
                   trace=red, device=device, chips=chips)
+        if args.record_run:
+            _record_run(args.record_run, run)
         line['metrics'] = _layer_metrics(man, cell['name'], run)
     else:
         units = {m['name']: m['unit'] for m in man['end_to_end']}
@@ -173,8 +175,37 @@ def main(argv, wall_at_import):
         line['metrics'] = {k: v for k, v in line['metrics'].items()
                            if k in counts}
         line['rehearsal'] = True
+    # each number compared beside its limit: the last lines of standard
+    # error, and the result line's last key (what a refusal keeps of a run)
+    line['compared'] = {c['name']: {'value': float(c['value']),
+                                    'limit': float(c['limit'])}
+                        for c in checks}
+    line['compared']['failed'] = {
+        'value': float(result['failed']), 'limit': 0.0}
+    sys.stdout.flush()
+    for c in checks:
+        sys.stderr.write('compared %-28s %.6g (limit %.6g)%s\n'
+                         % (c['name'], c['value'], c['limit'],
+                            '' if c['value'] <= c['limit'] else '  EXCEEDED'))
+    sys.stderr.write('compared %-28s %d (limit 0)\n'
+                     % ('failed', result['failed']))
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
+
+
+def _record_run(path, run):
+    """The run's dict as JSON; of the plan only what a reader takes: the
+    judged count and each request's prompt length, output and due time."""
+    plan = run['plan']
+    kept = dict(run, plan={
+        'judged': plan.get('judged'),
+        'requests': [{'prompt_len': len(r['prompt']),
+                      'max_new': int(r['max_new']), 'due': float(r['due'])}
+                     for r in plan.get('requests', ())]})
+    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+    with open(path, 'w') as f:
+        json.dump(kept, f, default=float)
 
 
 def _layer_metrics(man, cell_name, run):

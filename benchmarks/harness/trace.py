@@ -123,13 +123,20 @@ def op_label(name, labels):
 
 
 _HLO_META = re.compile(r'%([\w.\-]+) = [^\n]*metadata=\{[^}\n]*op_name="([^"]+)"')
+_HLO_MODULE = re.compile(r'^HloModule ([\w.\-]+)', re.M)
 
 
 def labels_from_hlo(events, hlo_texts):
     """{module event name: {instruction: op type}}. Instruction names
     are unique only inside one module, and the trace names a module by
-    a fingerprint, so each traced module takes the text that holds the
-    most of the instructions seen running inside it."""
+    its jitted function and a fingerprint (`jit_seg_fn(123)`), so each
+    traced module takes, of the texts of a module of its name
+    (`HloModule jit_seg_fn`), the one that holds the most of the
+    instructions seen running inside it. A module of another name takes
+    none: a small jitted helper of the program (`jit__with_first`, which
+    puts a last chunk's token in front of a decode step) shares
+    instruction names with the executor's programs and was counted as an
+    execution of the prefill program until PR 53."""
     maps = []
     for text in hlo_texts:
         m = {}
@@ -137,13 +144,19 @@ def labels_from_hlo(events, hlo_texts):
             found = _SCOPE.findall(path)
             if found:
                 m[instr] = found[-1]
+        named = _HLO_MODULE.search(text)
         maps.append((set(re.findall(r'^\s*(?:ROOT )?%([\w.\-]+) = ', text,
-                                    re.M)), m))
+                                    re.M)), m,
+                     named.group(1) if named else None))
     first = min((e[0] for e in events if e[0].startswith('/device:')),
                 default=None)
     ops = sorted((e[3], _instr(e[2])) for e in events
                  if e[0] == first and e[1] == OPS_LINE)
     starts = [s for s, _ in ops]
+    # names decide only where the two sides name modules alike at all
+    by_name = {sm[2] for sm in maps} & {
+        e[2].split('(')[0] for e in events
+        if e[0] == first and e[1] == MODULES_LINE}
     out = {}
     for ev in events:
         if ev[0] != first or ev[1] != MODULES_LINE or ev[2] in out:
@@ -151,7 +164,9 @@ def labels_from_hlo(events, hlo_texts):
         lo = bisect.bisect_left(starts, ev[3])
         hi = bisect.bisect_right(starts, ev[3] + ev[4])
         seen = {i for _, i in ops[lo:hi]}
-        best = max(maps, key=lambda sm: len(seen & sm[0]), default=None)
+        mine = ev[2].split('(')[0]
+        best = max((sm for sm in maps if not by_name or sm[2] == mine),
+                   key=lambda sm: len(seen & sm[0]), default=None)
         out[ev[2]] = best[1] if best and seen & best[0] else {}
     return out
 
@@ -223,7 +238,14 @@ def reduce_events(events, window_s, labels=None, programs=None,
     span_calls      {span: how many of the benchmark's host spans}
     programs        {name: {'calls', 'device_s'}} on the first chip: the
                     executions on the 'XLA Modules' line, named by
-                    `programs` = {name: [op labels only it contains]}
+                    `programs` = {name: [marker op labels]}: an
+                    execution that holds the markers of one listed
+                    program goes under its name, one that holds those of
+                    several under their names joined in the listing's
+                    order ('decode+prefill': a step that carried a chunk
+                    AND the lanes), never under the first that matches
+    op_runs         {label: executions on the first chip that held an op
+                    of that label}, whatever program it ran in
     chips           device planes seen
 
     `labels` is labels_from_hlo()'s map; without it ops keep their HLO
@@ -240,7 +262,7 @@ def reduce_events(events, window_s, labels=None, programs=None,
     n = len(planes)
     red = {'window_s': window_s, 'chips': n, 'busy_s': 0.0, 'ops': {},
            'collective_s': 0.0, 'collective_exposed_s': 0.0,
-           'gaps': {}, 'span_calls': {}, 'programs': {}}
+           'gaps': {}, 'span_calls': {}, 'programs': {}, 'op_runs': {}}
     if not n:
         return red
     spans.sort()
@@ -274,7 +296,8 @@ def reduce_events(events, window_s, labels=None, programs=None,
             red['collective_exposed_s'] += \
                 _subtract(coll_u, _union(others)) / 1e9 / n
         if idx == 0:
-            red['programs'] = _programs(mods, evs, label_of, programs or {})
+            red['programs'], red['op_runs'] = _programs(
+                mods, evs, label_of, programs or {})
             for (_, e1), (s2, _) in zip(busy, busy[1:]):
                 gname = _gap_name(spans, e1, s2)
                 red['gaps'][gname] = red['gaps'].get(gname, 0.0) \
@@ -283,23 +306,35 @@ def reduce_events(events, window_s, labels=None, programs=None,
 
 
 def _programs(module_events, op_events, label_of, markers):
-    """Each execution on the modules line, named by the marker labels
-    among the ops that ran inside it."""
+    """(programs, op_runs): each execution on the modules line, named by
+    the listed programs whose marker labels are among the ops that ran
+    inside it, and counted once for every label it held."""
     out = {name: {'calls': 0, 'device_s': 0.0} for name in markers}
-    if not markers:
-        return out
+    runs = {}
     ops = sorted((e[3], label_of(e)) for e in op_events)
     starts = [s for s, _ in ops]
     for ev in module_events:
         lo = bisect.bisect_left(starts, ev[3])
         hi = bisect.bisect_right(starts, ev[3] + ev[4])
         seen = {label for _, label in ops[lo:hi]}
-        for name, marks in markers.items():
-            if seen & set(marks):
-                out[name]['calls'] += 1
-                out[name]['device_s'] += ev[4] / 1e9
-                break
-    return out
+        for label in seen:
+            runs[label] = runs.get(label, 0) + 1
+        names = [name for name, marks in markers.items()
+                 if seen & set(marks)]
+        if names:
+            p = out.setdefault('+'.join(names),
+                               {'calls': 0, 'device_s': 0.0})
+            p['calls'] += 1
+            p['device_s'] += ev[4] / 1e9
+    return out, runs
+
+
+def carried(programs, name):
+    """{'calls', 'device_s'} summed over the executions that carried the
+    listed program `name`, alone or joined with others."""
+    got = [p for key, p in programs.items() if name in key.split('+')]
+    return {'calls': sum(p['calls'] for p in got),
+            'device_s': sum(p['device_s'] for p in got)}
 
 
 def _gap_name(spans, start, end):

@@ -1,8 +1,10 @@
 """Bytes a decode step of the A.X-K1 block has to move (float32 weights outside the routed
 experts once, the held experts its lanes chose, the latent rows of the live tokens in every
-layer; harness/costs_axk1.decode_step_bytes: experts a layer a mean over the window's steps,
-rows a step from the decode steps of the traced slice's own seconds) over the HBM peak,
-over the decode program's device time. Memory-bound: one token a lane."""
+layer; harness/costs_axk1.decode_step_bytes: rows a step a mean over the traced slice's
+steps that carried lanes and no chunk, experts a layer from what the expert layers counted
+for decode steps in the slice's seconds; builders/gpt2.slice_counts and
+_StepProbe.counters) over the HBM peak, over the decode program's device time in the same
+slice. Memory-bound: one token a lane."""
 LAYER = 'kernels (decode program)'
 UNIT = '%'
 BETTER = 'higher'
@@ -15,12 +17,12 @@ from harness import costs_axk1 as costs, peaks
 def read(run):
     p = run['trace']['programs'].get('decode')
     c = run['counters']
-    if not p or not p['calls'] or not c.get('moe_layer_calls') \
-            or not c.get('slice_decode_calls_max'):
+    steps = c.get('slice_plain_decode_calls')
+    if not p or not p['calls'] or not steps \
+            or not c.get('slice_moe_layer_calls'):
         return None
     need = costs.decode_step_bytes(
-        run['config'],
-        c['slice_latent_rows_max'] / c['slice_decode_calls_max'],
-        c['moe_experts_touched'] / c['moe_layer_calls'])
+        run['config'], c['slice_plain_latent_rows'] / steps,
+        c['slice_moe_experts_touched'] / c['slice_moe_layer_calls'])
     bw = peaks.peaks_of(run['device']['kind'])['hbm_bytes_s']
     return 100.0 * (need / bw) / (p['device_s'] / p['calls'])

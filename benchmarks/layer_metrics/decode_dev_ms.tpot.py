@@ -1,4 +1,6 @@
-"""Device time per execution of the decode program, from the trace."""
+"""Device time per execution of the decode program, from the trace: the executions that
+carried lanes and nothing else (one that carried a chunk too goes by its joined name,
+harness/trace.reduce_events)."""
 LAYER = 'model step (serving/paged.py programs)'
 UNIT = 'ms'
 BETTER = 'lower'

@@ -1,7 +1,9 @@
 """Bytes a decode step of the hybrid block has to move (float32 weights once, K/V of the
 live tokens in the full_attention layers, the live lanes' recurrent state read and
-written; harness/costs_hybrid.decode_step_bytes) over the HBM peak, over the decode
-program's device time. Memory-bound: one token per lane."""
+written; harness/costs_hybrid.decode_step_bytes; tokens and lanes a step means over the
+traced slice's steps that carried lanes and no chunk, builders/gpt2.slice_counts'
+`slice_plain_*`) over the HBM peak, over the decode program's device time in the same
+slice. Memory-bound: one token per lane."""
 LAYER = 'kernels (decode program)'
 UNIT = '%'
 BETTER = 'higher'
@@ -14,11 +16,11 @@ from harness import costs_hybrid, peaks
 def read(run):
     p = run['trace']['programs'].get('decode')
     c = run['counters']
-    if not p or not p['calls'] or not c.get('decode_calls') \
-            or 'state_lanes' not in c:
+    steps = c.get('slice_plain_decode_calls')
+    if not p or not p['calls'] or not steps:
         return None
     need = costs_hybrid.decode_step_bytes(
-        run['config'], c['live_tokens'] / c['decode_calls'],
-        c['state_lanes'] / c['decode_calls'])
+        run['config'], c['slice_plain_live_tokens'] / steps,
+        c['slice_plain_state_lanes'] / steps)
     bw = peaks.peaks_of(run['device']['kind'])['hbm_bytes_s']
     return 100.0 * (need / bw) / (p['device_s'] / p['calls'])

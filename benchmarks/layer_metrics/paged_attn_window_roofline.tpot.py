@@ -1,8 +1,9 @@
-"""Bytes the paged_window_attention ops of the traced window have to read (K and V of the
+"""Bytes the paged_window_attention ops of the traced slice have to read (K and V of the
 live window's rows only: each lane's last min(pos + 1, sliding_window_size) tokens, in every
-sliding layer; harness/costs_smallthinker.attention_bytes; rows a step from the decode steps
-of the traced slice's own seconds, builders/smallthinker.py's `slice_*` counters) over the
-HBM peak, over the ops' device time: bytes and time from the same executions."""
+sliding layer; harness/costs_smallthinker.attention_bytes) over the HBM peak, over the ops'
+device time. The ops are those of every execution that held one, in whatever program
+(`op_runs`); rows a step from the program's `window_rows_read` attr of the slice's own steps
+that carried lanes (builders/gpt2.slice_counts): bytes and time from the same executions."""
 LAYER = 'kernels (pallas/paged_attention.py)'
 UNIT = '%'
 BETTER = 'higher'
@@ -15,12 +16,12 @@ from harness import costs_smallthinker as costs, peaks
 def read(run):
     t, c = run['trace'], run['counters']
     op_s = t['ops'].get('paged_window_attention', 0.0)
-    p = t['programs'].get('decode')
+    runs = t['op_runs'].get('paged_window_attention')
     steps = c.get('slice_decode_calls')
-    if not op_s or not p or not p['calls'] or not steps \
-            or 'slice_window_rows_read' not in c:
+    if not op_s or not runs or not steps \
+            or not c.get('slice_window_rows_read'):
         return None
-    need = p['calls'] * costs.layers(run['config'])[1] \
+    need = runs * costs.layers(run['config'])[1] \
         * costs.attention_bytes(run['config'],
                                 c['slice_window_rows_read'] / steps)
     bw = peaks.peaks_of(run['device']['kind'])['hbm_bytes_s']

@@ -1,11 +1,17 @@
-"""The prefill program's share of device busy time."""
+"""The share of device busy time in executions that carried a prefill chunk: the prefill
+program's, and those of a program that carried a chunk beside the lanes."""
 LAYER = 'model step (serving/paged.py programs)'
 UNIT = '%'
 BETTER = 'lower'
 SOURCE = 'device_trace'
 
 
+from harness import trace
+
+
 def read(run):
     t = run['trace']
-    p = t['programs'].get('prefill')
-    return 100.0 * p['device_s'] / t['busy_s'] if p and t['busy_s'] else None
+    if 'prefill' not in t['programs'] or not t['busy_s']:
+        return None
+    return 100.0 * trace.carried(t['programs'], 'prefill')['device_s'] \
+        / t['busy_s']

@@ -1,10 +1,11 @@
 """Bytes a decode step of the Solar-Open2 block has to move (float32 weights outside the routed
 experts once, the untied head among them; the held experts its lanes chose, a mean over the
 layers; K/V of the live tokens in the attention layers; the live lanes' delta state and
-convolution rows read and written; harness/costs_solar2.decode_step_bytes: experts a layer,
-lanes and tokens a step all from the decode steps of the traced slice's own seconds, the
-builder's `slice_*` counters) over the HBM peak, over the decode program's device time.
-Memory-bound: one token a lane."""
+convolution rows read and written; harness/costs_solar2.decode_step_bytes: tokens and lanes a
+step means over the traced slice's steps that carried lanes and no chunk, experts a layer
+from what the expert sublayers counted for decode steps in the slice's seconds;
+builders/gpt2.slice_counts and _StepProbe.counters) over the HBM peak, over the decode
+program's device time in the same slice. Memory-bound: one token a lane."""
 LAYER = 'kernels (decode program)'
 UNIT = '%'
 BETTER = 'higher'
@@ -17,14 +18,13 @@ from harness import costs_solar2 as costs, peaks
 def read(run):
     p = run['trace']['programs'].get('decode')
     c = run['counters']
-    if not p or not p['calls'] or not c.get('slice_moe_layer_calls') \
-            or not c.get('slice_decode_calls') \
-            or 'slice_state_lanes' not in c:
+    steps = c.get('slice_plain_decode_calls')
+    if not p or not p['calls'] or not steps \
+            or not c.get('slice_moe_layer_calls'):
         return None
-    steps = c['slice_decode_calls']
     need = costs.decode_step_bytes(
-        run['config'], c['slice_live_tokens'] / steps,
-        c['slice_state_lanes'] / steps,
+        run['config'], c['slice_plain_live_tokens'] / steps,
+        c['slice_plain_state_lanes'] / steps,
         c['slice_moe_experts_touched'] / c['slice_moe_layer_calls'])
     bw = peaks.peaks_of(run['device']['kind'])['hbm_bytes_s']
     return 100.0 * (need / bw) / (p['device_s'] / p['calls'])

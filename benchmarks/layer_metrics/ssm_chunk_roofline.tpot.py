@@ -1,6 +1,9 @@
-"""The least time the chip could take for the ssd_chunk ops of the traced window (the larger
+"""The least time the chip could take for the ssd_chunk ops of the traced slice (the larger
 of their FLOPs over the bf16 peak and their bytes over the HBM peak, for the prompt tokens
-they really carried; harness/costs_nemotron_h) over the ops' device time."""
+they really carried; harness/costs_nemotron_h) over the ops' device time. The ops are those
+of every execution that held one, in whatever program (`op_runs`); tokens a chunk from the
+program's `state_tokens` attr of the slice's own steps that carried a chunk
+(builders/gpt2.slice_counts)."""
 LAYER = 'kernels (ops/ssd_ops.py)'
 UNIT = '%'
 BETTER = 'higher'
@@ -13,14 +16,15 @@ from harness import costs_nemotron_h as costs, peaks
 def read(run):
     t, c = run['trace'], run['counters']
     op_s = t['ops'].get('ssd_chunk', 0.0)
-    p = t['programs'].get('prefill')
-    if not op_s or not p or not p['calls'] or not c.get('prefill_calls'):
+    runs = t['op_runs'].get('ssd_chunk')
+    chunks = c.get('slice_prefill_calls')
+    if not op_s or not runs or not chunks or not c.get('slice_state_tokens'):
         return None
-    tokens = c['prefill_tokens'] / c['prefill_calls']  # mean a chunk
+    tokens = c['slice_state_tokens'] / chunks           # mean a chunk
     peak = peaks.peaks_of(run['device']['kind'])
     least = max(costs.ssd_chunk_flops(run['config'], tokens)
                 / peak['bf16_flops'],
                 costs.ssd_chunk_bytes(run['config'], tokens)
                 / peak['hbm_bytes_s'])
-    ops = p['calls'] * costs.kinds(run['config']).count('M')
+    ops = runs * costs.kinds(run['config']).count('M')
     return 100.0 * ops * least / op_s

@@ -88,10 +88,11 @@ def _read(name, run):
     return manifest.layer_metric(manifest.load(), name).read(run)
 
 
-def _run(config, ops, programs, counters, plan=None):
+def _run(config, ops, programs, counters, plan=None, op_runs=None):
     return {'config': config, 'device': {'kind': 'TPU v5 lite'},
             'counters': counters, 'plan': plan,
-            'trace': {'busy_s': 2.0, 'ops': ops, 'programs': programs}}
+            'trace': {'busy_s': 2.0, 'ops': ops, 'programs': programs,
+                      'op_runs': op_runs or {}}}
 
 
 def test_readers_on_plain_data(config):
@@ -104,15 +105,26 @@ def test_readers_on_plain_data(config):
                 'moe_pairs': 72_000, 'moe_experts_touched': 37_800,
                 'moe_prefill_layer_calls': 440, 'moe_prefill_pairs': 22_000,
                 'moe_prefill_experts_touched': 3520,
-                'slice_latent_rows_max': 216_000_000,
-                'slice_decode_calls_max': 100,
+                # the slice's own 100 steps (none carried a chunk too) and
+                # 10 chunks of 150 live rows, at the window's means
+                'slice_decode_calls': 100, 'slice_latent_rows': 216_000_000,
+                'slice_plain_decode_calls': 100,
+                'slice_plain_latent_rows': 216_000_000,
+                'slice_prefill_calls': 10, 'slice_prefill_tokens': 1500,
+                'slice_moe_layer_calls': 400, 'slice_moe_pairs': 4800,
+                'slice_moe_experts_touched': 2520,
+                'slice_moe_prefill_layer_calls': 40,
+                'slice_moe_prefill_pairs': 2000,
+                'slice_moe_prefill_experts_touched': 320,
                 'latent_cache_bytes_max': 3_000_000_000,
                 'prefix_tokens_reused': 1_300_000,
                 'prompt_tokens_admitted': 1_316_500}
     plan = {'judged': 2, 'requests': [{'prompt': np.zeros(10_000)},
                                       {'prompt': np.zeros(14_000)},
                                       {'prompt': np.zeros(9)}]}
-    run = _run(config, ops, programs, counters, plan)
+    run = _run(config, ops, programs, counters, plan,
+               {'paged_latent_attention': 100, 'paged_latent_prefill': 10,
+                'moe_experts': 110})
     assert _read('mla_share.tpot', run) == pytest.approx(35.0)
     # 100 steps of 2.16 M rows (over 5 layers) of 2304 B, in 0.6 s
     assert _read('mla_decode_roofline.tpot', run) == pytest.approx(
@@ -152,14 +164,19 @@ def test_readers_find_nothing_on_a_program_without_the_ops(config):
 
 def test_entries_are_listed_at_the_end_and_list_the_cell():
     man = manifest.check(manifest.load())
+    # in order, together, behind the older ones (62 per-layer metrics, 5
+    # cells, 4 configurations), the cell first where it reports; not
+    # "last": later PRs append behind them
     names = [m['name'] for m in man['per_layer']]
-    assert names[-len(NEW):] == NEW
-    for m in man['per_layer'][-len(NEW):]:
-        assert m['workloads'] == [CELL] and m['moves'] == 'tpot_p50_ms'
-    assert man['workloads'][-1]['name'] == CELL
-    assert man['configs'][-1]['name'] == 'axk1-serve'
-    assert len(man['workloads'][-1]['why']) <= 200
-    assert len(man['configs'][-1]['why']) <= 200
+    at = names.index(NEW[0])
+    assert at >= 62 and names[at:at + len(NEW)] == NEW
+    for m in man['per_layer'][at:at + len(NEW)]:
+        assert m['workloads'][0] == CELL and m['moves'] == 'tpot_p50_ms'
+    cells = [w['name'] for w in man['workloads']]
+    configs = [c['name'] for c in man['configs']]
+    assert cells.index(CELL) >= 5 and configs.index('axk1-serve') >= 4
+    assert len(man['workloads'][cells.index(CELL)]['why']) <= 200
+    assert len(man['configs'][configs.index('axk1-serve')]['why']) <= 200
     listed = {m['name'] for m in manifest.metrics_of(man, 'per_layer', CELL)}
     assert set(NEW) | {'moe_share.tpot', 'moe_pairs_per_expert.tpot',
                        'moe_experts_touched_share.tpot',
@@ -169,8 +186,8 @@ def test_entries_are_listed_at_the_end_and_list_the_cell():
                          'paged_attn_gqa_roofline.tpot',
                          'nemo_decode_hbm_roofline.tpot',
                          'decode_hbm_roofline.tpot', 'ssm_share.tpot'}
-    assert next(m for m in man['end_to_end'] if m['name'] == 'tpot_p50_ms'
-                )['workloads'][-1] == CELL
+    assert CELL in next(m for m in man['end_to_end']
+                        if m['name'] == 'tpot_p50_ms')['workloads']
 
 
 def test_the_file_keeps_every_published_key_but_the_reduced(config):

@@ -160,16 +160,20 @@ def test_the_view_is_made_once_a_run(monkeypatch):
 
 
 def test_the_seven_entries_stand_at_the_end_and_agree_with_their_readers():
+    """In order, together, behind the older ones (later PRs append behind
+    them), the three cells first where they report."""
     man = manifest.check(manifest.load())
-    assert [m['name'] for m in man['per_layer']][-7:] == SEVEN
+    names = [m['name'] for m in man['per_layer']]
+    at = names.index(SEVEN[0])
+    assert at > 0 and names[at:at + 7] == SEVEN
     units = dict(zip(SEVEN, ['ms', 'ms', 'ms', '%', '%', 'ms', 'ms']))
-    for e in man['per_layer'][-7:]:
+    for e in man['per_layer'][at:at + 7]:
         mod = manifest.layer_metric(man, e['name'])
         assert (mod.LAYER, mod.UNIT, mod.BETTER, mod.SOURCE) == \
             (e['layer'], e['unit'], e['better'], e['source']), e['name']
         assert e['layer'] == 'engine (serving/engine.py)'
         assert (e['unit'], e['better'], e['source'], e['moves']) == \
             (units[e['name']], 'lower', 'program_span', 'tpot_p50_ms')
-        assert e['workloads'] == CELLS
+        assert e['workloads'][:len(CELLS)] == CELLS
         assert set(e) == {'name', 'unit', 'better', 'source', 'layer',
                           'moves', 'workloads'}

@@ -49,7 +49,7 @@ def test_layer_metric_that_moves_nothing(man):
 
 
 def test_a_pair_of_configuration_and_traffic_twice(man):
-    man['workloads'][-1]['traffic'] = man['workloads'][0]['traffic']
+    man['workloads'].append(dict(man['workloads'][0], name='the_same_again'))
     with pytest.raises(manifest.ManifestError):
         manifest.check(man)
 

@@ -84,10 +84,11 @@ def _read(name, run):
     return manifest.layer_metric(manifest.load(), name).read(run)
 
 
-def _run(config, ops, programs, counters):
+def _run(config, ops, programs, counters, op_runs=None):
     return {'config': config, 'device': {'kind': 'TPU v5 lite'},
             'counters': counters,
-            'trace': {'busy_s': 2.0, 'ops': ops, 'programs': programs}}
+            'trace': {'busy_s': 2.0, 'ops': ops, 'programs': programs,
+                      'op_runs': op_runs or {}}}
 
 
 def test_readers_on_plain_data(config):
@@ -102,8 +103,23 @@ def test_readers_on_plain_data(config):
                 'moe_prefill_layer_calls': 1000,
                 'moe_prefill_pairs': 550_000,
                 'moe_prefill_experts_touched': 64_000,
-                'ssm_state_bytes_max': 1_381_498_880}
-    run = _run(config, ops, programs, counters)
+                'ssm_state_bytes_max': 1_381_498_880,
+                # the slice's own 100 steps (none carried a chunk too) and
+                # 20 chunks, at the window's means
+                'slice_decode_calls': 100, 'slice_state_lanes': 4500,
+                'slice_live_tokens': 3_600_000,
+                'slice_plain_decode_calls': 100,
+                'slice_plain_state_lanes': 4500,
+                'slice_plain_live_tokens': 3_600_000,
+                'slice_prefill_calls': 20, 'slice_state_tokens': 4000,
+                'slice_moe_layer_calls': 500, 'slice_moe_pairs': 62_000,
+                'slice_moe_experts_touched': 30_000,
+                'slice_moe_prefill_layer_calls': 100,
+                'slice_moe_prefill_pairs': 55_000,
+                'slice_moe_prefill_experts_touched': 6400}
+    run = _run(config, ops, programs, counters,
+               {'ssd_step': 100, 'ssd_chunk': 20, 'moe_experts': 120,
+                'paged_attention': 100})
     assert _read('ssm_share.tpot', run) == pytest.approx(20.0)
     assert _read('moe_share.tpot', run) == pytest.approx(45.0)
     # 100 steps x 5 layers x 45 lanes x 2 x 4.19 MB over 819 GB/s, in 0.3 s
@@ -147,7 +163,8 @@ def test_entries_are_listed_in_order_and_list_the_cell():
     assert [n for n in names if n in NEW] == NEW
     for m in man['per_layer']:
         if m['name'] in NEW:
-            assert m['workloads'] == [CELL] and m['moves'] == 'tpot_p50_ms'
+            # the cell first, where it reports; later cells behind it
+            assert m['workloads'][0] == CELL and m['moves'] == 'tpot_p50_ms'
     listed = {m['name'] for m in manifest.metrics_of(man, 'per_layer', CELL)}
     assert set(NEW) <= listed
     assert not listed & {'decode_hbm_roofline.tpot', 'gdn_share.tpot',

@@ -68,10 +68,11 @@ def _read(name, run):
     return manifest.layer_metric(manifest.load(), name).read(run)
 
 
-def _run(config, ops, programs, counters):
+def _run(config, ops, programs, counters, op_runs=None):
     return {'config': config, 'device': {'kind': 'TPU v5 lite'},
             'counters': counters,
-            'trace': {'busy_s': 2.0, 'ops': ops, 'programs': programs}}
+            'trace': {'busy_s': 2.0, 'ops': ops, 'programs': programs,
+                      'op_runs': op_runs or {}}}
 
 
 def test_readers_on_plain_data(config):
@@ -82,8 +83,16 @@ def test_readers_on_plain_data(config):
     counters = {'decode_calls': 1000, 'state_lanes': 20_000,
                 'live_tokens': 25_000_000, 'prefill_calls': 200,
                 'prefill_tokens': 40_000,
-                'recurrent_state_bytes_max': 451_215_360}
-    run = _run(config, ops, programs, counters)
+                'recurrent_state_bytes_max': 451_215_360,
+                # the slice's own 100 steps (none carried a chunk too) and
+                # 20 chunks, at the window's means
+                'slice_decode_calls': 100, 'slice_state_lanes': 2000,
+                'slice_plain_decode_calls': 100,
+                'slice_plain_state_lanes': 2000,
+                'slice_plain_live_tokens': 2_500_000,
+                'slice_prefill_calls': 20, 'slice_state_tokens': 4000}
+    run = _run(config, ops, programs, counters,
+               {'gated_delta_step': 100, 'gated_delta_chunk': 20})
     assert _read('gdn_share.tpot', run) == pytest.approx(5.0)
     # 100 steps x 6 layers x 20 lanes x 2 x 2.21 MB over 819 GB/s, in 0.06 s
     assert _read('gdn_step_roofline.tpot', run) == pytest.approx(
@@ -117,7 +126,8 @@ def test_entries_are_listed_in_order_and_list_the_cell():
     assert [n for n in names if n in NEW] == NEW
     for m in man['per_layer']:
         if m['name'] in NEW:
-            assert m['workloads'] == [CELL] and m['moves'] == 'tpot_p50_ms'
+            # the cell first, where it reports; later cells behind it
+            assert m['workloads'][0] == CELL and m['moves'] == 'tpot_p50_ms'
     listed = {m['name'] for m in manifest.metrics_of(man, 'per_layer', CELL)}
     assert 'decode_hbm_roofline.tpot' not in listed and set(NEW) <= listed
 

@@ -96,10 +96,11 @@ def _read(name, run):
     return manifest.layer_metric(manifest.load(), name).read(run)
 
 
-def _run(config, ops, programs, counters):
+def _run(config, ops, programs, counters, op_runs=None):
     return {'config': config, 'device': {'kind': 'TPU v5 lite'},
             'counters': counters,
-            'trace': {'busy_s': 2.0, 'ops': ops, 'programs': programs}}
+            'trace': {'busy_s': 2.0, 'ops': ops, 'programs': programs,
+                      'op_runs': op_runs or {}}}
 
 
 def test_readers_on_plain_data(config):
@@ -117,11 +118,19 @@ def test_readers_on_plain_data(config):
                 'window_pages_freed': 4321, 'window_live_pages_max': 3900,
                 'prefix_window_tail_miss': 0,
                 'slice_decode_calls': 200,
-                'slice_full_rows_read': 200 * 250_000,
+                'slice_rows_read': 200 * 250_000,
                 'slice_window_rows_read': 200 * 90_000,
-                'slice_moe_layer_calls': 800,
-                'slice_moe_experts_touched': 800 * 60}
-    run = _run(config, ops, programs, counters)
+                'slice_plain_decode_calls': 200,
+                'slice_plain_rows_read': 200 * 250_000,
+                'slice_plain_window_rows_read': 200 * 90_000,
+                'slice_moe_layer_calls': 800, 'slice_moe_pairs': 800 * 175,
+                'slice_moe_experts_touched': 800 * 60,
+                'slice_moe_prefill_layer_calls': 80,
+                'slice_moe_prefill_pairs': 80 * 1200,
+                'slice_moe_prefill_experts_touched': 80 * 64}
+    run = _run(config, ops, programs, counters,
+               {'paged_window_attention': 200, 'paged_attention': 200,
+                'moe_experts': 220})
     assert _read('window_attn_share.tpot', run) == pytest.approx(12.0)
     assert _read('paged_attn_window_roofline.tpot', run) == pytest.approx(
         100 * (200 * 3 * 90_000 * 4096 / 819e9) / 0.24)

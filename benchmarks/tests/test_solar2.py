@@ -107,10 +107,11 @@ def _read(name, run):
     return manifest.layer_metric(manifest.load(), name).read(run)
 
 
-def _run(config, ops, programs, counters):
+def _run(config, ops, programs, counters, op_runs=None):
     return {'config': config, 'device': {'kind': 'TPU v5 lite'},
             'counters': counters,
-            'trace': {'busy_s': 2.0, 'ops': ops, 'programs': programs}}
+            'trace': {'busy_s': 2.0, 'ops': ops, 'programs': programs,
+                      'op_runs': op_runs or {}}}
 
 
 def test_readers_on_plain_data(config):
@@ -127,13 +128,18 @@ def test_readers_on_plain_data(config):
                 'streams_opened': 200, 'snapshots_adopted': 200,
                 'slice_decode_calls': 100, 'slice_state_lanes': 3700,
                 'slice_live_tokens': 7_400_000,
-                'slice_prefill_calls': 20, 'slice_chunk_tokens': 3000,
+                'slice_plain_decode_calls': 100,
+                'slice_plain_state_lanes': 3700,
+                'slice_plain_live_tokens': 7_400_000,
+                'slice_prefill_calls': 20, 'slice_state_tokens': 3000,
                 'slice_moe_layer_calls': 800, 'slice_moe_pairs': 30_000,
                 'slice_moe_experts_touched': 7900,
                 'slice_moe_prefill_layer_calls': 160,
                 'slice_moe_prefill_pairs': 6000,
                 'slice_moe_prefill_experts_touched': 1590}
-    run = _run(config, ops, programs, counters)
+    run = _run(config, ops, programs, counters,
+               {'kda_step': 100, 'kda_chunk': 20, 'moe_experts': 120,
+                'paged_attention': 100})
     assert _read('kda_share.tpot', run) == pytest.approx(20.0)
     # 37 lanes a step in the slice (30 in the window's mean)
     assert _read('kda_step_roofline.tpot', run) == pytest.approx(
@@ -381,5 +387,54 @@ def test_bf16_stored_control_reads_over_the_limits(config):
     # reference at the program's own matmul precision (on the CPU that
     # arithmetic is the truth's)
     assert ref.rel_l2(control, same) > limits['logits_rel_l2']
+    # and by the steady number of the decode rows (PR 53): the row in the
+    # middle, through the builder's own comparison; the reference in the
+    # program's place reads a hundredth of the limit there
+    from builders import solar_open2 as b
+    as_lanes = lambda x: [np.asarray(x)]
+    by_name = lambda checks: {c['name']: c for c in checks}
+    bad = by_name(b.serve_comparisons(*map(as_lanes, (control, truth, same)),
+                                      limits))
+    assert 'decode_logits_rel_l2' not in bad
+    assert bad['decode_rows_rel_l2_median']['limit'] == \
+        limits['decode_rows_rel_l2_median'] < limits['logits_rel_l2']
+    assert bad['decode_rows_rel_l2_median']['value'] > \
+        limits['decode_rows_rel_l2_median']
+    good = by_name(b.serve_comparisons(*map(as_lanes, (same, truth, same)),
+                                       limits))
+    assert all(c['value'] <= 0.01 * c['limit'] for c in good.values())
     # and the reference agrees with itself far under them
     assert ref.rel_l2(same, truth) < 0.01 * limits['logits_rel_l2']
+
+
+def test_one_turned_lane_does_not_move_the_decode_rows_median(config):
+    """What PR 53's refused seed showed: one lane of four wrong by 0.085
+    in all its rows and the others by 0.025. The worst lane's number
+    read 0.085; the median row reads a sound lane's, and the control's
+    rows, all alike at 0.11, read over the limit."""
+    from builders import solar_open2 as b
+    rng = np.random.default_rng(3)
+    limits = config['correct']
+    same = [rng.standard_normal((n, 64)).astype(np.float32)
+            for n in (27, 27, 26, 25)]
+
+    def off_by(lanes, shares):
+        out = []
+        for lane, share in zip(lanes, shares):
+            noise = rng.standard_normal(lane.shape).astype(np.float32)
+            noise *= share * np.linalg.norm(lane, axis=-1, keepdims=True) \
+                / np.linalg.norm(noise, axis=-1, keepdims=True)
+            out.append(lane + noise)
+        return out
+
+    def median(got):
+        checks = b.serve_comparisons(got, same, same, limits)
+        return next(c for c in checks
+                    if c['name'] == 'decode_rows_rel_l2_median')
+
+    turned = median(off_by(same, (0.025, 0.025, 0.085, 0.025)))
+    assert turned['value'] == pytest.approx(0.025, rel=1e-3)
+    assert turned['value'] < turned['limit'] == 0.065
+    control = median(off_by(same, (0.11, 0.11, 0.11, 0.11)))
+    assert control['value'] == pytest.approx(0.11, rel=1e-3)
+    assert control['value'] > control['limit']

@@ -11,7 +11,7 @@ meets.
 
     python benchmarks/tools/readings_solar2.py \\
         --workload solar2_serve_chat_shared --seeds 1,2,3,... \\
-        [--controls 2] [--wrong] [--rehearse]
+        [--controls 2] [--wrong] [--rehearse] [--rows FILE]
 
 A seed changes the weights as well as the inputs: each seed's tensors go
 straight into the decoder's weight scope (a private attribute: a tool
@@ -67,9 +67,43 @@ def control(config, dims, seed, traffic=None):
                                [s for _, s in refs], config['correct'])
 
 
+def recording_rows(path):
+    """Every lane's rows' own relative L2 (to the reference at the
+    program's precision and at highest), in the order print_rows is
+    called, written to `path` as JSON when the tool ends: what a
+    steadier number than the worst lane's is chosen from."""
+    import json
+    from builders import solar_open2 as b
+    kept, print_rows = [], b.print_rows
+
+    def recorded(got, truth, same, who='program'):
+        kept.append({'who': who, 'lanes': [
+            {'same': b.row_errors(g, s_).tolist(),
+             'highest': b.row_errors(g, t).tolist()}
+            for g, t, s_ in zip(got, truth, same)]})
+        return print_rows(got, truth, same, who)
+
+    def write():
+        os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+        with open(path, 'w') as f:
+            json.dump(kept, f)
+
+    b.print_rows = recorded
+    return write
+
+
 def main(argv):
     readings_granite_h.reseed, readings_granite_h.control = reseed, control
-    return readings_granite_h.main(argv)
+    write = None
+    if '--rows' in argv:
+        at = argv.index('--rows')
+        write = recording_rows(argv[at + 1])
+        argv = argv[:at] + argv[at + 2:]
+    try:
+        return readings_granite_h.main(argv)
+    finally:
+        if write:
+            write()
 
 
 if __name__ == '__main__':
