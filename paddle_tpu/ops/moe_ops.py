@@ -175,6 +175,15 @@ register_vjp_grad('moe_aux_loss', in_slots=('Gate',))
 # float32: a rounded score changes WHICH experts a token takes, not a
 # digit of the result. (A chosen expert's weight is never exactly 0: a
 # sigmoid is not, short of logits under -100.)
+#
+# What is read of the held stack follows the rows (`_held_part`): a
+# prefill chunk's 256 rows touch every held expert, and every row goes
+# through every held expert in one batched product that reads the stack
+# once whatever was chosen (`held_experts`); a decode step's rows (at
+# most pallas/moe_experts.STEP_ROWS) choose a quarter to nine tenths of
+# them, and on a TPU a kernel reads the chosen experts' tiles straight
+# out of the stack and no other (pallas/moe_experts.py). The sum is the
+# same: a skipped expert's term was 0 times a finite number.
 
 def _within_kept_groups(b, n_group, topk_group):
     """b [R, E] with the scores outside each row's `topk_group` best
@@ -241,11 +250,16 @@ def held_experts(lat, w, w1, w2):
     by w (zero where it did not choose the expert): no pair can be
     dropped, the weights are read once whatever the router chose, and
     the products hide under that read: on a v5e 64 experts of 1024 x
-    2688 take 1.92 ms at 90 % of the HBM peak for a decode step's 64
-    rows and for a prefill chunk's 256 alike. (Pairs sorted by expert
-    and multiplied by groups, rows x 22 / 8 products in place of rows x
-    64, took 5.0 ms there: each group's weights were copied out of the
-    stack before they were multiplied. PERF.md section 6, PR 36.)"""
+    2688 take 1.92 ms at 90 % of the HBM peak for 64 rows and for a
+    prefill chunk's 256 alike. That is what a chunk's rows take (they
+    touch every held expert) and every row off a TPU; a decode step's
+    rows on a TPU read the chosen experts only, through the kernel of
+    pallas/moe_experts.py, to which this is the reference. (Pairs
+    sorted by expert and multiplied by groups, rows x 22 / 8 products
+    in place of rows x 64, took 5.0 ms there: each group's weights were
+    copied out of the stack before they were multiplied. PERF.md
+    section 6, PR 36; the kernel copies nothing and sorts nothing:
+    section 6, PR 54.)"""
     h = _relu2(jnp.einsum('rl,elf->erf', lat, w1))
     return jnp.einsum('erf,efl->rl', h * w.T.astype(lat.dtype)[..., None],
                       w2)
@@ -280,7 +294,8 @@ def _moe_experts_emit(ctx, op):
     and count nothing. Stats [4] int32, where asked for, is this call's
     (pairs on held experts, held experts with at least one pair, pairs
     selected here and not computed, 1): the third is 0, there being no
-    capacity to overflow."""
+    capacity to overflow. What the sum reads of the stack follows the
+    op's static row count and the backend (`_held_part`)."""
     x = ctx.get(op.single_input('X'))
     lat = ctx.get(op.single_input('Lat'))
     w1 = ctx.get(op.single_input('W1'))
@@ -301,17 +316,41 @@ def _moe_experts_emit(ctx, op):
     elif op.input('Len'):
         n = ctx.get(op.single_input('Len')).astype(jnp.int32).reshape(())
         w = jnp.where((jnp.arange(rows) < n)[:, None], w, 0.0)
-    if op.input('W3'):
-        out = held_gated_experts(lat.reshape(rows, width), w, w1,
-                                 ctx.get(op.single_input('W3')), w2,
-                                 op.attr('act', 'silu'))
-    else:
-        out = held_experts(lat.reshape(rows, width), w, w1, w2)
+    out = _held_part(lat.reshape(rows, width), w, w1,
+                     ctx.get(op.single_input('W3')) if op.input('W3')
+                     else None, w2, op.attr('act', 'silu'))
     ctx.set(op.single_output('Out'), out.reshape(lat.shape))
     if op.output('Stats'):
         ctx.set(op.single_output('Stats'), jnp.stack(
             [jnp.sum(w != 0), jnp.sum(jnp.any(w != 0, axis=0)),
              0, 1]).astype(jnp.int32))
+
+
+def _held_part(lat, w, w1, w3, w2, act):
+    """The held experts' sum for lat [R, L] and w [R, held]. On a TPU
+    (or under FLAGS_pallas_interpret) a decode step's rows (`STEP_ROWS`
+    of pallas/moe_experts.py, by the op's static row count alone) take
+    the kernel there, which reads only the experts a row chose, any(w
+    != 0) by column: the expression Stats counts, so the op's
+    experts_touched is the number of experts read. A chunk's rows, and
+    every row elsewhere, take the batched product over the whole stack.
+    Which of the two an emission took is counted in
+    ops.moe_experts.kernel / ops.moe_experts.fallback."""
+    from ..pallas import moe_experts as _me
+    from ..flags import get_flag
+    from ..obs import telemetry
+    on_tpu = jax.default_backend() == 'tpu'
+    if _me.step_supported(lat.shape[0], lat.shape[1], w1.shape[2]) and (
+            on_tpu or bool(get_flag('pallas_interpret'))):
+        telemetry.counter('ops.moe_experts.kernel').inc()
+        ids, n = _me.touched_ids(jnp.any(w != 0, axis=0))
+        return _me.moe_experts(lat, w, ids, n, w1, w3, w2,
+                               act='relu2' if w3 is None else act,
+                               interpret=not on_tpu)
+    telemetry.counter('ops.moe_experts.fallback').inc()
+    if w3 is None:
+        return held_experts(lat, w, w1, w2)
+    return held_gated_experts(lat, w, w1, w3, w2, act)
 
 
 def _moe_experts_infer(op, block):

@@ -464,6 +464,39 @@ def test_kda_chunk_of_the_solar_cell_compiles_for_v5e(v5e_2x2):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+# the five expert cells' decode programs: tools/moe_experts_arms.CELLS
+_EXPERTS = ('axk1', 'granite4hs', 'nemo3s', 'solar2', 'sthink21b')
+
+
+@pytest.mark.parametrize('cell', _EXPERTS)
+def test_expert_kernel_of_a_decode_step_compiles_for_v5e(v5e_2x2, cell):
+    """A decode step's rows through the held stack at each cell's widths
+    (pallas/moe_experts.py): lowered by Mosaic and compiled by the
+    installed TPU compiler with the tiles the widths give (the VMEM
+    limit follows from them), the stack read where it lies: no
+    temporary beside the kernel's own."""
+    import functools
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    import moe_experts_arms
+    from paddle_tpu.pallas import moe_experts as me
+    assert sorted(moe_experts_arms.CELLS) == list(_EXPERTS)
+    rows, L, F, held, matrices, act, _ = moe_experts_arms.CELLS[cell]
+    assert me.step_supported(rows, L, F)
+    one = SingleDeviceSharding(v5e_2x2[0])
+
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    up = arg(held, L, F)
+    compiled = jax.jit(functools.partial(me.moe_experts, act=act)).lower(
+        arg(rows, L), arg(rows, held), arg(held, dtype=jnp.int32),
+        arg(1, dtype=jnp.int32), up, up if matrices == 3 else None,
+        arg(held, F, L)).compile()
+    assert compiled.as_text().count('tpu_custom_call') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 # -- the page copy program, compiled for one described chip ------------------
 # (here because this file is the one that describes a TPU: see v5e_2x2)
 
