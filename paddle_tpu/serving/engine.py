@@ -95,7 +95,9 @@ Telemetry (paddle_tpu/obs/, exported when FLAGS_obs_dir is set):
   serving.loop.seconds / serving.loop.wait_seconds (the workers' passes,
   and the part of them spent blocked in a step's fetch, the predictor's
   `fetch_wait_s`: 1 - wait / seconds is how far the host is from
-  setting the pace)  counters; serving.queue_depth /
+  setting the pace) / serving.loop.idle_seconds (a worker's stays with
+  no lane and an empty queue, outside any pass: 1 - idle / (idle +
+  seconds) is the engine's utilisation)  counters; serving.queue_depth /
   serving.slot_occupancy  gauges; serving.ttft /
   serving.token_latency / serving.decode_batch  histograms (seconds /
   seconds / active lanes per step; the last two take one observation
@@ -110,9 +112,15 @@ pass of a worker's loop (attrs lanes, ready, prefilling, queued; chunk
 and step, 0/1: the pass dispatched a prefill chunk / a decode step;
 wait_ms, the part of it spent blocked in a fetch, so the span's length
 less wait_ms is the host's own section of the pass) with
-children `serve.admit`, `serve.prefill_tick`, `serve.pack` and
+children `serve.admit` (attr admitted: the streams the pass opened or
+resumed, 0 in nearly every pass; a stream's opening is the decoder's
+`paged.open` under it), `serve.prefill_tick`, `serve.pack` and
 `serve.accept`; the decoder's own spans (serving/paged.py) nest under
-it. In the pipelined loop `serve.accept` follows the call and holds
+it. `serve.idle` is one stay of a worker that has no lane and finds the
+queue empty, from the moment it waits to the moment it stops waiting
+(ONE span a stay, however often `idle_wait` wakes it; top level on the
+worker's thread, like `serve.iter`, and never inside one): the traffic's
+idle time, which the loop's own spans must not be charged with. In the pipelined loop `serve.accept` follows the call and holds
 the tokens of the step BEFORE the one the call dispatched (none behind
 a burst's first step); a step collected without a call leaves a
 `paged.decode.fetch` and a `serve.accept` of its own in the iteration
@@ -178,6 +186,7 @@ _tokens_behind_prefill = telemetry.counter('serving.tokens_behind_prefill')
 _tokens_behind_sync = telemetry.counter('serving.tokens_behind_sync')
 _loop_seconds = telemetry.counter('serving.loop.seconds')
 _loop_wait_seconds = telemetry.counter('serving.loop.wait_seconds')
+_loop_idle_seconds = telemetry.counter('serving.loop.idle_seconds')
 _queue_depth = telemetry.gauge('serving.queue_depth')
 _occupancy = telemetry.gauge('serving.slot_occupancy')
 _ttft = telemetry.histogram('serving.ttft')
@@ -861,10 +870,13 @@ class ServingEngine(object):
         without one, the stream re-prefills (prompt + tokens so far),
         and the final chunk's output token IS its next stream token —
         the fleet-failover contract, equally bit-exact by greedy
-        determinism."""
+        determinism. Returns the streams it opened or resumed (the
+        `admitted` of the pass's `serve.admit` span; 0 in nearly every
+        pass)."""
         if wstate['cache_wait'] and lanes:
-            return
+            return 0
         wstate['cache_wait'] = False
+        admitted = 0
         free = [s for s in range(pred.slots) if s not in lanes]
         while free:
             req = self._pop_next()
@@ -884,7 +896,7 @@ class ServingEngine(object):
                         with self._cond:
                             self._push_locked(req, front=True)
                         wstate['cache_wait'] = True
-                        return
+                        return admitted
                     # nothing live will ever free pages for this
                     # snapshot: drop it and re-prefill instead (the
                     # pool may fit a chunked prefill it cannot fit
@@ -907,6 +919,7 @@ class ServingEngine(object):
                     lanes[slot] = _Lane(req, pos=len(seq) - 1,
                                         tok=req.tokens[-1])
                     _admitted.inc()
+                    admitted += 1
                     continue
             req.state = RUNNING
             req._admitted()
@@ -926,6 +939,8 @@ class ServingEngine(object):
                                 ready=False)
             prefilling.append(slot)
             _admitted.inc()
+            admitted += 1
+        return admitted
 
     def _prefill_tick(self, pred, lanes, prefilling, wstate):
         """Advance chunked prefill by AT MOST one chunk per engine
@@ -1035,9 +1050,16 @@ class ServingEngine(object):
         try:
             while True:
                 with self._cond:
-                    while self._running and not self._qsize_locked() \
+                    if self._running and not self._qsize_locked() \
                             and not lanes:
-                        self._cond.wait(self._idle_wait)
+                        # the traffic's idle time, not the loop's: ONE
+                        # span a stay, however often _idle_wait wakes it
+                        with RecordEvent('serve.idle'):
+                            t0 = time.perf_counter()
+                            while self._running and not lanes \
+                                    and not self._qsize_locked():
+                                self._cond.wait(self._idle_wait)
+                            _loop_idle_seconds.inc(time.perf_counter() - t0)
                     if not self._running and not self._qsize_locked() \
                             and not lanes:
                         return
@@ -1167,8 +1189,9 @@ class ServingEngine(object):
         # packed ahead
         speculative = getattr(pred, 'speculative', False)
         deferred = getattr(pred, 'deferred_decode', False)
-        with RecordEvent('serve.admit'):
-            self._admit(pred, lanes, prefilling, wstate)
+        with RecordEvent('serve.admit') as ev:
+            ev.attrs['admitted'] = self._admit(pred, lanes, prefilling,
+                                               wstate)
         with RecordEvent('serve.prefill_tick'):
             self._prefill_tick(pred, lanes, prefilling, wstate)
         self._report(wid, lanes)

@@ -125,10 +125,9 @@ serving.prefix_hits / serving.prefix_tokens_reused counters (beside
 serving.prompt_tokens_admitted: the prompt tokens of every stream
 opened, reused or not),
 serving.prefill_chunks histogram (chunks per admitted prompt),
-serving.decode_pages_read / serving.decode_pages_window counters (the
-pages the decode steps' attention read, of slots x pages_per_slot a
-step; the same pair is on every `paged.decode.tables` span, beside
-`overlapped`, 1 if the step was dispatched while the one before was in
+serving.decode_pages_read counter (the pages the decode steps'
+attention read; attr `pages_read` of every `paged.decode.tables` span,
+beside `overlapped`, 1 if the step was dispatched while the one before was in
 flight, `carried`, the lanes whose token it took from that step on
 the device, and `carried_prefill`, the lanes whose token it took from
 their prompt's last chunk, dispatched in front of it and not waited
@@ -201,6 +200,26 @@ prompt's last chunk's, of collect()'s) add up in the attribute
 `fetch_wait_s`, cumulative since construction: the engine's loop reads
 it before and after a pass for the pass's wait (`wait_ms` of
 `serve.iter`, the counter serving.loop.wait_seconds).
+What the prefix cache costs the host, where it happens (the cache
+itself, serving/paging.py, imports no telemetry and keeps plain
+integers: `hits`, `misses`, `entries_scanned`, `evictions`):
+`paged.open` (attrs `prompt_tokens`, `shared_tokens`) around a stream's
+opening, under the engine's `serve.admit`, with the child
+`paged.prefix.match` (attrs `pages`: the whole pages hashed,
+`shared_tokens`) around the one question its family asks the cache
+(match / match_window / match_state; none for a model with recurrent
+state and no snapshot rows): once a stream. `paged.prefix.register`
+(attrs `tokens`, `pages`; `row` 0/1 where the model keeps snapshot
+rows: one was handed out) around register / register_state, inside the
+`paged.prefill.book` of a prompt's last chunk: once a prompt.
+`paged.prefix.evict` (attrs `pool` 'full' | 'window', `scanned`: the
+entries the call looked at, `freed` 0/1: it gave a ref up) around every
+call a pool that ran dry makes of evict_one / evict_window_one, under
+the `paged.prefill.tables` / `paged.decode.tables` that asked for the
+page; the same adds up in the counters serving.prefix.evictions,
+serving.prefix.entries_scanned and serving.prefix.evict_seconds, for a
+deployment that runs longer than the span buffer holds. A step that
+meets no arrival, no prompt's end and no dry pool opens none of them.
 `paged.state.save` / `paged.state.restore` (attr `nbytes`) inside
 save_stream / restore_stream: the recurrent rows' way to the host and
 back. `paged.state.snapshot` / `paged.state.adopt` (attrs `nbytes`,
@@ -233,7 +252,6 @@ _prefix_tokens = telemetry.counter('serving.prefix_tokens_reused')
 _prompt_tokens = telemetry.counter('serving.prompt_tokens_admitted')
 _prefill_chunks = telemetry.histogram('serving.prefill_chunks')
 _decode_pages_read = telemetry.counter('serving.decode_pages_read')
-_decode_pages_window = telemetry.counter('serving.decode_pages_window')
 _state_lanes = telemetry.counter('serving.state_lanes')
 _state_chunk_tokens = telemetry.counter('serving.state_chunk_tokens')
 _state_bytes = telemetry.gauge('serving.recurrent_state_bytes')
@@ -251,6 +269,9 @@ _window_in_use = telemetry.gauge('serving.window_pages_in_use')
 _window_live = telemetry.gauge('serving.window_pages_live')
 _window_tail_adopted = telemetry.counter('serving.prefix.window_tail_adopted')
 _window_tail_miss = telemetry.counter('serving.prefix.window_tail_miss')
+_prefix_evictions = telemetry.counter('serving.prefix.evictions')
+_prefix_scanned = telemetry.counter('serving.prefix.entries_scanned')
+_prefix_evict_seconds = telemetry.counter('serving.prefix.evict_seconds')
 _MOE_COUNTS = ('pairs', 'experts_touched', 'pairs_dropped', 'layer_calls')
 
 
@@ -762,9 +783,10 @@ class PagedDecodePredictor(object):
                                    window_pool=self._wpool,
                                    window=spec.window)
         self._snaps_gone = 0          # of them, counted so far
-        self._pool.set_evict(self._prefix.evict_one)
+        self._pool.set_evict(self._evicting(self._prefix.evict_one, 'full'))
         if self._wpool is not None:
-            self._wpool.set_evict(self._prefix.evict_window_one)
+            self._wpool.set_evict(self._evicting(
+                self._prefix.evict_window_one, 'window'))
         self._tables = {}             # slot -> PageTable
         self._pending = {}            # slot -> _PendingPrefill
         self._resets = 0              # streams started from zero state
@@ -780,6 +802,26 @@ class PagedDecodePredictor(object):
             telemetry.gauge('serving.%s.state_bytes' % spec.state_family) \
                 .set(self._recurrent_state_bytes())
         self._update_gauges()
+
+    def _evicting(self, evict, pool):
+        """`evict` (the prefix cache's, for the pool named) as the pool
+        calls it, in a `paged.prefix.evict` span under the `*.tables`
+        span that asked for the page; what the scan cost also adds up in
+        the serving.prefix.* counters."""
+        prefix = self._prefix
+
+        def run():
+            with RecordEvent('paged.prefix.evict', pool=pool) as ev:
+                t0, scanned = time.perf_counter(), prefix.entries_scanned
+                freed = evict()
+                scanned = ev.attrs['scanned'] = \
+                    prefix.entries_scanned - scanned
+                ev.attrs['freed'] = int(freed)
+                _prefix_evictions.inc(int(freed))
+                _prefix_scanned.inc(scanned)
+                _prefix_evict_seconds.inc(time.perf_counter() - t0)
+            return freed
+        return run
 
     def _place_cache(self, name, value):
         """Host K/V state -> the executor's pinned device layout (the
@@ -811,32 +853,52 @@ class PagedDecodePredictor(object):
         if not 1 <= len(prompt) <= self.max_len:
             raise ValueError('prompt length %d outside [1, %d] (max_len)'
                              % (len(prompt), self.max_len))
+        with RecordEvent('paged.open', prompt_tokens=len(prompt)) as ev:
+            shared = ev.attrs['shared_tokens'] = self._open(slot, prompt)
+        chunk = self.prefill_chunk
+        return {'slot': slot, 'prompt_tokens': len(prompt),
+                'shared_tokens': shared,
+                'chunks': -(-(len(prompt) - shared) // chunk)}
+
+    def _open(self, slot, prompt):
+        """open_stream's body: the stream's tables, the one question to
+        the prefix cache its family asks, the adoption. Returns the
+        tokens it shares."""
         table = PageTable(self._pool, self.pages_per_slot)
         # never pages without their state: a stream with recurrent
         # state opens on a boundary that has a snapshot (which comes
         # pinned until its first chunk has copied it), or on nothing
         # (see the module docstring)
+        if self._wpool is not None:
+            match = self._prefix.match_window
+        elif not self.recurrent:
+            match = self._prefix.match
+        elif self._pair.snapshot_rows:
+            match = self._prefix.match_state
+        else:
+            match = None
+        limit = len(prompt) - 1
+        missed = self._prefix.window_tail_misses
+        got = [], 0
+        if match is not None:
+            with RecordEvent('paged.prefix.match',
+                             pages=limit // self.page_tokens) as ev:
+                got = match(prompt, limit=limit)
+                ev.attrs['shared_tokens'] = got[1]
+        pages, shared, *rest = got
         snapshot = None
         if self._wpool is not None:
+            wpages, wfirst = rest
             wtable = self._wtables[slot] = PageTable(
                 self._wpool, self._pair.window_pages_per_slot,
                 window=self._pair.spec.window)
-            missed = self._prefix.window_tail_misses
-            pages, shared, wpages, wfirst = self._prefix.match_window(
-                prompt, limit=len(prompt) - 1)
             if shared:
                 wtable.adopt_shared(wpages, shared, first=wfirst)
                 _window_tail_adopted.inc()
             if self._prefix.window_tail_misses > missed:
                 _window_tail_miss.inc()
-        elif not self.recurrent:
-            pages, shared = self._prefix.match(prompt,
-                                               limit=len(prompt) - 1)
-        elif self._pair.snapshot_rows:
-            pages, shared, snapshot = self._prefix.match_state(
-                prompt, limit=len(prompt) - 1)
-        else:
-            pages, shared = [], 0
+        elif rest:
+            snapshot, = rest
         if shared:
             table.adopt_shared(pages, shared)
             _prefix_hits.inc()
@@ -845,10 +907,7 @@ class PagedDecodePredictor(object):
         self._tables[slot] = table
         self._pending[slot] = _PendingPrefill(prompt, snapshot)
         self._update_gauges()
-        chunk = self.prefill_chunk
-        return {'slot': slot, 'prompt_tokens': len(prompt),
-                'shared_tokens': shared,
-                'chunks': -(-(len(prompt) - shared) // chunk)}
+        return shared
 
     def release(self, slot):
         """Drop a stream's page refs (cache-registered prefix pages
@@ -1052,6 +1111,13 @@ class PagedDecodePredictor(object):
             table.pool.unref(dst)
 
     # -- execution ---------------------------------------------------------
+    def _registering(self, prompt):
+        """The span around a prompt's registration in the prefix cache
+        (once a prompt, inside the `paged.prefill.book` of its last
+        chunk); `pages`: the whole pages hashed."""
+        return RecordEvent('paged.prefix.register', tokens=len(prompt),
+                           pages=len(prompt) // self.page_tokens)
+
     def prefill_step(self, slot, return_logits=False, defer=False):
         """Advance one stream's prefill by ONE chunk. Returns None
         while more chunks remain; on the final chunk, registers the
@@ -1162,15 +1228,19 @@ class PagedDecodePredictor(object):
                     # both tables, before the second gives up what the
                     # prompt's end no longer reads: a boundary a little
                     # short of it finds its window tail too
-                    self._prefix.register(prompt, table, wtable)
+                    with self._registering(prompt):
+                        self._prefix.register(prompt, table, wtable)
                 self._slide(wtable, table.length)
             self._update_gauges()
             if table.length < len(prompt):
                 return None
             if wtable is None and not self.recurrent:
-                self._prefix.register(prompt, table)
+                with self._registering(prompt):
+                    self._prefix.register(prompt, table)
             elif self._pair.snapshot_rows:
-                row = self._prefix.register_state(prompt, table)
+                with self._registering(prompt) as ev:
+                    row = self._prefix.register_state(prompt, table)
+                    ev.attrs['row'] = int(row is not None)
                 if row is not None:
                     # behind the chunk just dispatched, in front of any
                     # step that moves the slot's state on
@@ -1303,12 +1373,10 @@ class PagedDecodePredictor(object):
             # part hold ceil((pos + 1) / pt) pages each
             ev.attrs['pages_read'] = pages_read = \
                 sum(int(pos_feed[slot]) // pt + 1 for slot in live)
-            ev.attrs['pages_window'] = S * P
             ev.attrs['overlapped'] = int(overlapped)
             ev.attrs['carried'] = len(carry) - len(fresh)
             ev.attrs['carried_prefill'] = len(fresh)
             _decode_pages_read.inc(pages_read)
-            _decode_pages_window.inc(S * P)
             feed = {'decode_tokens': tokens,
                     'decode_prev_ids': self._last_ids,
                     'decode_carry': carry_feed,

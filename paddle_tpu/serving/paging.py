@@ -426,6 +426,10 @@ class PrefixCache(object):
         self.hits = 0
         self.misses = 0
         self.tokens_reused = 0
+        # what the two evictions cost: entries their scans looked at,
+        # and the calls that gave a ref up
+        self.entries_scanned = 0
+        self.evictions = 0
         # delta logs for the fleet prefix directory (drained through
         # SRV_HEALTH): hex chain keys of full-page nodes registered /
         # evicted since the last drain_events(). Bounded by cache
@@ -796,9 +800,11 @@ class PrefixCache(object):
         """Drop the LRU leaf entry and unref its page; True if a page
         ref was released (it only FREES the page if no live stream
         still shares it — alloc() loops until one actually frees)."""
+        self.entries_scanned += len(self)
         best = min(self._leaves(), default=None, key=lambda e: e[0])
         if best is None:
             return False
+        self.evictions += 1
         _, (kind, key, entry) = best
         self._evict_entry(kind, key, entry)
         # a snapshot whose pages go is gone with them
@@ -813,11 +819,13 @@ class PrefixCache(object):
         that holds a window page gives up that page alone (its page of
         the full pool stays; a boundary that needed the window page is
         no longer handed out). True if a ref was released."""
+        self.entries_scanned += len(self)
         entry = min((e for _, (_, _, e) in self._leaves(leaves_only=False)
                      if e.wpage is not None),
                     default=None, key=lambda e: e.stamp)
         if entry is None:
             return False
+        self.evictions += 1
         self.window_pool.unref(entry.wpage)
         entry.wpage = None
         return True

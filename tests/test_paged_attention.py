@@ -313,10 +313,8 @@ def test_decode_tables_span_counts_pages_read_of_the_window(lm_predictor):
         tables = [s for s in trace.spans()
                   if s['name'] == 'paged.decode.tables']
         assert [s['pages_read'] for s in tables] == [9, 10]
-        assert [s['pages_window'] for s in tables] == [32, 32]
         counters = telemetry.snapshot()['counters']
         assert counters['serving.decode_pages_read'] == 19
-        assert counters['serving.decode_pages_window'] == 64
     finally:
         telemetry.disable(final_flush=False)
         telemetry.reset()
