@@ -178,8 +178,11 @@ def _ssd_step_emit(ctx, op):
     On a TPU (or under FLAGS_pallas_interpret) the Pallas kernel makes
     one pass over the live lanes' state and skips the others
     (pallas/ssd.py); everywhere else the plain composition above runs,
-    which is what the CPU tests compare with the reference."""
+    which is what the CPU tests compare with the reference. Which of the
+    two an emission took is counted in ops.ssd_step.kernel /
+    ops.ssd_step.fallback."""
     from ..flags import get_flag
+    from ..obs import telemetry
     from ..pallas import ssd as _ssd
     heads, p, groups, n = _ssd_attrs(op)
     xbc = ctx.get(op.single_input('XBC'))
@@ -193,9 +196,11 @@ def _ssd_step_emit(ctx, op):
     on_tpu = jax.default_backend() == 'tpu'
     if (on_tpu or bool(get_flag('pallas_interpret'))) \
             and _ssd.supported(heads, p, groups, n):
+        telemetry.counter('ops.ssd_step.kernel').inc()
         y, new = _ssd.ssd_step(state, x, b, c, dt, jnp.exp(log_a), d, live,
                                interpret=not on_tpu)
     else:
+        telemetry.counter('ops.ssd_step.fallback').inc()
         y, new = ssd_step_reference(state, x, b, c, dt, log_a, d, live)
     ctx.set(op.single_output('Out'),
             y.reshape(y.shape[0], 1, heads * p).astype(xbc.dtype))

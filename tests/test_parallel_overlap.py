@@ -497,6 +497,33 @@ def test_expert_kernel_of_a_decode_step_compiles_for_v5e(v5e_2x2, cell):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+@pytest.mark.parametrize('cell', ['granite4hs', 'nemo3s'])
+def test_ssd_step_kernel_of_a_decode_step_compiles_for_v5e(v5e_2x2, cell):
+    """The Mamba-2 step at each cell's shape (pallas/ssd.py): a whole
+    lane's 4 MiB a block, in and out and double-buffered, lowered by
+    Mosaic and compiled by the installed TPU compiler under the VMEM
+    limit the shapes give; the donated state is updated where it lies
+    and what the caller lays out beside it is kilobytes a lane."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    import ssd_step_arms
+    from paddle_tpu.pallas import ssd
+    S, H, P, N, G, _ = ssd_step_arms.CELLS[cell]
+    assert ssd.supported(H, P, G, N)
+    one = SingleDeviceSharding(v5e_2x2[0])
+
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = jax.jit(ssd.ssd_step.__wrapped__, donate_argnums=(0,)).lower(
+        arg(S, H, P, N), arg(S, H, P), arg(S, G, N), arg(S, G, N),
+        arg(S, H), arg(S, H), arg(H), arg(S, dtype=jnp.bool_)).compile()
+    assert compiled.as_text().count('tpu_custom_call') == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == S * H * P * N * 4
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
 # -- the page copy program, compiled for one described chip ------------------
 # (here because this file is the one that describes a TPU: see v5e_2x2)
 
