@@ -241,6 +241,20 @@ def refuse_window(spec, what):
             % (what, ','.join(map(str, spec.window_layers))))
 
 
+def refuse_blocks(spec, what):
+    """Raise for a spec that generates by diffusion over blocks
+    (spec.block_tokens: models/sdar_moe.py) where `what` steps a lane
+    one token at a time or moves its pages by the token."""
+    if spec.block_tokens:
+        raise DecodeTranspileError(
+            '%s cannot serve a model that generates by diffusion over '
+            'blocks of %d tokens (family %s): its step is %d rows a lane '
+            'at the same positions pass after pass, and only a commit '
+            'pass\'s rows count'
+            % (what, spec.block_tokens,
+               type(spec).__module__.rsplit('.', 1)[-1], spec.block_tokens))
+
+
 class DecodeSpec(object):
     """Dims + parameter names extracted from a loaded LM program.
 
@@ -284,6 +298,14 @@ class DecodeSpec(object):
     kv_heads is the number of K/V heads a page holds (query head h
     reads K/V head h // (heads / kv_heads)); the model's head count
     where it is not given.
+
+    block_tokens (0: none) is the block length of a model that
+    generates by diffusion over blocks (models/sdar_moe.py): its
+    attention is causal over blocks and bidirectional inside one, its
+    prefill runs a prompt's whole blocks, and the pair's second program
+    is a block step (build_paged_block_program) in the place of the
+    one-token decode step. What steps a lane a token at a time refuses
+    such a spec (refuse_blocks).
     """
 
     recurrent_kinds = ()
@@ -292,6 +314,7 @@ class DecodeSpec(object):
     page_kind = 'kv'        # what a page of kv_layers holds
     page_kinds = ('full_attention', 'sliding_attention')
     window = 0              # tokens a row of a sliding_attention layer sees
+    block_tokens = 0        # rows of a block step (0: one token a step)
 
     def __init__(self, vocab, dim, heads, layers, ffn, max_len, pos_len,
                  emb_w, pos_w, blocks, final_ln, head, use_flash=False,
@@ -374,11 +397,12 @@ class DecodeSpec(object):
         program, feeds, fetches, decode program, feeds, fetches).
         `window`: the builders' window_pages and window_pages_per_slot,
         for a spec with sliding layers."""
+        step = build_paged_block_program if self.block_tokens \
+            else build_paged_decode_program
         return build_paged_prefill_program(
             self, slots, chunk, num_pages, page_tokens, pages_per_slot,
-            **window) + build_paged_decode_program(
-                self, slots, num_pages, page_tokens, pages_per_slot,
-                **window)
+            **window) + step(self, slots, num_pages, page_tokens,
+                             pages_per_slot, **window)
 
     def paged_logits(self, tokens, at):
         """This block's walk over one paged program (`at`: PagedStep):
@@ -468,14 +492,16 @@ def _tmp_var(dtype='float32'):
         name=unique_name.generate('kv_decode.tmp'), dtype=dtype)
 
 
-def _qkv_parts(x, spec, blk, t, qk_norm=None, rotary=None):
+def _qkv_parts(x, spec, blk, t, qk_norm=None, rotary=None, head_norm=None):
     """qkv fc + per-part slice/reshape to [-1, t, H, dh] — the full
     path's heads() up to (not including) the transpose, which is the
     cache's storage layout. On a mesh each part is pinned heads-sharded
     (the cache/pool layout), a no-op single-chip; the qkv contraction
     dim stays whole either way, so every element is bit-exact.
     `qk_norm(part, 'q' | 'k')`, where the caller's block norms q and k
-    whole before the heads are split. `rotary(part)`, where the block's
+    whole before the heads are split; `head_norm(part, 'q' | 'k')`,
+    where it norms them a head at a time, behind the split and in front
+    of the rotation (models/sdar_moe.py). `rotary(part)`, where the block's
     attention takes a rotary term: q and k [-1, t, heads, dh] rotated
     by their rows' positions BEFORE the cache sees a key, so that a
     page holds rotated keys (as the latent page does: models/axk1.py)."""
@@ -487,6 +513,8 @@ def _qkv_parts(x, spec, blk, t, qk_norm=None, rotary=None):
         if qk_norm is not None and which:
             p = qk_norm(p, which)
         p = L.reshape(p, shape=[-1, t, heads, spec.dh])
+        if head_norm is not None and which:
+            p = head_norm(p, which)
         if rotary is not None and which:
             p = rotary(p)
         return sharding_constraint(p, (None, None, _tp_ax(spec), None))
@@ -536,7 +564,10 @@ def _cached_block(x, spec, i, attention):
 # table's whole window into a dense [B, P*pt, H, dk] tensor and matmul,
 # a paged mask, softmax and matmul run over all of it. A prefill chunk
 # gathers one slot's window, a sixteenth of what the decode step
-# copied; verify gathers every slot's.
+# copied; verify gathers every slot's. The BLOCK program (a model that
+# generates by diffusion over blocks: build_paged_block_program, in the
+# decode program's place) holds one paged_block_attention op a layer:
+# the same kernel, a lane's B rows riding as further query heads.
 
 
 class PagedStep(object):
@@ -547,9 +578,15 @@ class PagedStep(object):
     from it on are padding), last [1] (the row whose logits are
     wanted), cow (src, dst: the page to copy before the write) and,
     for a model with recurrent state, slot [1] and reset [1]. A decode
-    step is one row of EVERY lane: table [slots, P], positions [slots]
-    (the step index) and, for a model with recurrent state or expert
-    layers, live [slots]. pools and states are {layer: its variables};
+    step (`decode` True, `rows` 1) is one row of EVERY lane: table
+    [slots, P], positions [slots] (the step index) and, for a model with
+    recurrent state or expert layers, live [slots]. A block step
+    (`decode` True, `rows` B: a model that generates by diffusion over
+    blocks) is B rows of every lane: positions [slots, B] (the block's),
+    ends [slots] (the position of each lane's last block row: every row
+    of the block sees the lane's pages up to it), live [slots], and
+    block_ids [slots, B] and transfer [slots] for the unmasking behind
+    the head. pools and states are {layer: its variables};
     stats collects what the expert layers counted. For a model with
     sliding layers, the second table: window_table ([1, W] or
     [slots, W]), window_positions (the same rows' positions counted
@@ -559,6 +596,7 @@ class PagedStep(object):
     through. A sublayer asks this value, never the program's name."""
 
     length = last = cow = slot = reset = live = None
+    ends = block_ids = transfer = None
     window_table = window_positions = window_cow = None
 
     def __init__(self, decode, rows):
@@ -653,15 +691,21 @@ def _paged_gather(pool_var, table, spec):
 
 
 def _paged_attention(x, spec, blk, i, at, qk_norm=None, rotary=None,
-                     out_gate=None):
+                     out_gate=None, head_norm=None):
     """Layer i's attention over its K/V pages, in the form `at`'s
-    program takes: a prefill chunk's or a decode step's; through the
-    table of the layer's kind (at.pages_of). `out_gate` [B, rows,
-    heads * dh] multiplies the heads' outputs in front of the output
-    projection, where the block gates them."""
-    form = _paged_decode_attention if at.decode else _paged_prefill_attention
+    program takes: a prefill chunk's, a decode step's or a block
+    step's; through the table of the layer's kind (at.pages_of).
+    `out_gate` [B, rows, heads * dh] multiplies the heads' outputs in
+    front of the output projection, where the block gates them;
+    `head_norm` is _qkv_parts's."""
+    if not at.decode:
+        form = _paged_prefill_attention
+    elif at.rows > 1:
+        form = _paged_block_attention
+    else:
+        form = _paged_decode_attention
     return form(x, spec, blk, at.pools[i], at, at.pages_of(spec, i), qk_norm,
-                rotary, out_gate)
+                rotary, out_gate, head_norm)
 
 
 def _gated_proj(ctx, spec, blk, out_gate):
@@ -671,17 +715,19 @@ def _gated_proj(ctx, spec, blk, out_gate):
 
 
 def _paged_prefill_attention(x, spec, blk, pool, at, pages, qk_norm=None,
-                             rotary=None, out_gate=None):
+                             rotary=None, out_gate=None, head_norm=None):
     """One chunk of prefill attention: COW any forked page, scatter the
     chunk's K/V rows through the table, then attend the chunk's queries
     over the WHOLE gathered history (earlier pages + this chunk): the
     whole table of the layer's kind, so a sliding layer gathers its own
-    table's width and masks a band."""
+    table's width and masks a band. A model that generates by diffusion
+    over blocks masks by block (spec.block_tokens: a row sees its whole
+    block; its chunks hold whole blocks only)."""
     length, chunk = at.length, at.rows
     table, positions, (cow_src, cow_dst), band = pages
     q4, k4, v4 = (_pool_heads(a, spec)
                   for a in _qkv_parts(x, spec, blk, chunk, qk_norm,
-                                      rotary))          # [1, C, H, dh]
+                                      rotary, head_norm))   # [1, C, H, dh]
     for pool_var, new in ((pool[0], k4), (pool[1], v4)):
         _block_op('kv_page_cow',
                   inputs={'Pool': [pool_var], 'Src': [cow_src],
@@ -709,10 +755,12 @@ def _paged_prefill_attention(x, spec, blk, pool, at, pages, qk_norm=None,
     if rep > 1:
         scores = L.reshape(scores, shape=[-1, spec.heads, chunk, window])
     masked = _tmp_var()                                # [1, H, C, J]
+    attrs = {'window': int(band)} if band else {}
+    if spec.block_tokens:
+        attrs['block'] = int(spec.block_tokens)
     _block_op('paged_prefill_mask',
               inputs={'X': [scores], 'Positions': [positions]},
-              outputs={'Out': [masked]},
-              attrs={'window': int(band)} if band else None)
+              outputs={'Out': [masked]}, attrs=attrs or None)
     probs = L.softmax(masked)
     if rep > 1:
         probs = L.reshape(probs, shape=grouped[:3] + [window])
@@ -725,7 +773,7 @@ def _paged_prefill_attention(x, spec, blk, pool, at, pages, qk_norm=None,
 
 
 def _paged_decode_attention(x, spec, blk, pool, at, pages, qk_norm=None,
-                            rotary=None, out_gate=None):
+                            rotary=None, out_gate=None, head_norm=None):
     """One decode step's attention: append the new K/V row, then ONE
     paged_attention op that reads each lane's live pages through its
     table (no gathered window; see the op's docstring for its two
@@ -735,8 +783,8 @@ def _paged_decode_attention(x, spec, blk, pool, at, pages, qk_norm=None,
     (build_page_copy_program)."""
     table, positions, _, band = pages
     q1, k1, v1 = (_pool_heads(a, spec)
-                  for a in _qkv_parts(x, spec, blk, 1, qk_norm,
-                                      rotary))    # [S, 1, H | KVH, dh]
+                  for a in _qkv_parts(x, spec, blk, 1, qk_norm, rotary,
+                                      head_norm))  # [S, 1, H | KVH, dh]
     for pool_var, new in ((pool[0], k1), (pool[1], v1)):
         _block_op('kv_page_append',
                   inputs={'Pool': [pool_var], 'X': [new],
@@ -753,6 +801,39 @@ def _paged_decode_attention(x, spec, blk, pool, at, pages, qk_norm=None,
     #                                                     [S, 1, H, dh]
     ctx = _model_heads(ctx, spec, 1)
     ctx = sharding_constraint(ctx, (None, None, None))
+    return _gated_proj(ctx, spec, blk, out_gate)
+
+
+def _paged_block_attention(x, spec, blk, pool, at, pages, qk_norm=None,
+                           rotary=None, out_gate=None, head_norm=None):
+    """One block step's attention: write the block's `at.rows` K/V rows
+    of every lane through its table at the block's positions (one
+    kv_page_append with 2-D positions: a later pass over the same block
+    overwrites them, and whether they ever count is the host's
+    bookkeeping), then ONE paged_block_attention op in which every row
+    of a lane reads the lane's live pages up to the END of its block
+    (at.ends): causal over blocks, bidirectional inside one. As in a
+    decode step, a page that forks was copied before the program was
+    dispatched."""
+    table, positions, _, band = pages
+    if band:
+        raise DecodeTranspileError('a block step over sliding_attention '
+                                   'layers')
+    qb, kb, vb = (_pool_heads(a, spec)
+                  for a in _qkv_parts(x, spec, blk, at.rows, qk_norm, rotary,
+                                      head_norm))  # [S, B, H | KVH, dh]
+    for pool_var, new in ((pool[0], kb), (pool[1], vb)):
+        _block_op('kv_page_append',
+                  inputs={'Pool': [pool_var], 'X': [new],
+                          'Table': [table], 'Positions': [positions]},
+                  outputs={'Out': [pool_var]})
+    ctx = _tmp_var()
+    _block_op('paged_block_attention',
+              inputs={'Q': [qb], 'KPool': [pool[0]], 'VPool': [pool[1]],
+                      'Table': [table], 'Positions': [at.ends]},
+              outputs={'Out': [ctx]},
+              attrs={'sm_scale': float(spec.sm_scale)})  # [S, B, H, dh]
+    ctx = _model_heads(ctx, spec, at.rows)
     return _gated_proj(ctx, spec, blk, out_gate)
 
 
@@ -828,21 +909,37 @@ def _logits_head(x, spec, at, head=None):
 
 def _paged_fetches(spec, at, tokens, slots, num_pages, page_tokens,
                    window_pages=0):
-    """The half both builders share: the pools and state variables,
+    """The half the builders share: the pools and state variables,
     the block's walk, and its fetches: logits [lanes, vocab], greedy
     ids, and the expert layers' counts summed over the layers where
-    there are any."""
+    there are any. A block step's: logits [lanes * rows, vocab], the
+    block's ids [lanes, rows] behind its unmasking, the counts, and last
+    each lane's rows still masked."""
     at.pools = _create_pool_vars(spec, num_pages, page_tokens, window_pages)
     at.states = _create_state_vars(spec, slots)
     logits = spec.paged_logits(tokens, at)
+    masked = None
+    if at.block_ids is not None:
+        # a block step: the ids behind this pass's unmasking and, last
+        # of the fetches, each lane's rows still masked
+        ids, masked = _tmp_var('int64'), _tmp_var('int32')
+        _block_op('block_unmask',
+                  inputs={'Logits': [logits], 'Ids': [at.block_ids],
+                          'Transfer': [at.transfer], 'Live': [at.live]},
+                  outputs={'Out': [ids], 'Masked': [masked]},
+                  attrs={'rule': spec.cfg.remasking,
+                         'threshold': float(spec.cfg.threshold),
+                         'mask_id': int(spec.cfg.mask_id)})
     if at.decode:
         logits = L.reshape(logits, shape=[-1, spec.vocab])
-    fetches = [logits, L.argmax(logits, axis=-1)]
+    fetches = [logits, L.argmax(logits, axis=-1) if masked is None else ids]
     if at.stats:
         total = at.stats[0]
         for one in at.stats[1:]:
             total = L.elementwise_add(total, one)
         fetches.append(total)
+    if masked is not None:
+        fetches.append(masked)
     return fetches
 
 
@@ -982,6 +1079,71 @@ def build_paged_decode_program(spec, slots, num_pages, page_tokens,
     return prog, names, fetches
 
 
+def build_paged_block_program(spec, slots, num_pages, page_tokens,
+                              pages_per_slot):
+    """One pass over a block of B = spec.block_tokens tokens of EVERY
+    lane, for a model that generates by diffusion over blocks: the
+    pair's second program, where the others have the one-token decode
+    step. A denoising pass and the pass that commits a block are this
+    one program with the same feed shapes: whether a pass's K/V rows
+    count is the host's bookkeeping (serving/paged.py block_step).
+
+    Feeds:  block_tokens [slots, B, 1] int64 (the block's ids as the
+            host holds them: fixed tokens, the mask id elsewhere),
+            block_prev_ids [slots, B] int64 and block_carry [slots]
+            int32 (a lane with carry set takes its ids from
+            block_prev_ids, what the pass before left on the device
+            behind its unmasking, and not from block_tokens: a pass is
+            dispatched before the one before has been fetched),
+            block_positions [slots, B] int32 (the rows' absolute
+            positions, start + arange(B): the rows' K/V land at
+            pool[table[pos // pt], pos % pt]; all zero for a lane that
+            sits the pass out, whose writes hit the null page),
+            block_ends [slots] int32 (the position of the block's last
+            row: every row attends to 0..end),
+            block_page_table [slots, P] int32,
+            block_live [slots] int32 (the lanes that take part: the
+            expert layers neither count nor weigh the others' rows, and
+            their ids pass through),
+            block_transfer [slots] int32 (the masked rows this pass
+            unmasks, at most; 0 in a commit pass, which has none).
+    It copies no page: a block's pages are grown and its shared frontier
+    page forked by the host before the block's first pass (the page copy
+    program in front of it).
+    Returns (program, feed_names, fetch_vars[logits [slots * B, vocab],
+    ids [slots, B] behind the unmasking, counts, masked [slots]]).
+    """
+    from ..framework import Program, program_guard
+    if spec.window_layers or spec.state_names():
+        raise DecodeTranspileError(
+            'a block step over sliding_attention or recurrent layers')
+    if page_tokens % spec.block_tokens:
+        raise DecodeTranspileError(
+            'pages of %d tokens do not hold whole blocks of %d'
+            % (page_tokens, spec.block_tokens))
+    rows = spec.block_tokens
+    prog, startup = Program(), Program()
+    prog._is_test = True
+    with program_guard(prog, startup):
+        feed, names = _feeds()
+        at = PagedStep(decode=True, rows=rows)
+        tokens = feed('block_tokens', [slots, rows, 1], 'int64')
+        prev = feed('block_prev_ids', [slots, rows], 'int64')
+        carry = feed('block_carry', [slots])
+        tokens = L.where_select(L.cast(carry, 'bool'),
+                                L.reshape(prev, shape=[slots, rows, 1]),
+                                tokens)
+        at.block_ids = L.reshape(tokens, shape=[slots, rows])
+        at.positions = feed('block_positions', [slots, rows])
+        at.ends = feed('block_ends', [slots])
+        at.table = feed('block_page_table', [slots, pages_per_slot])
+        at.live = feed('block_live', [slots])
+        at.transfer = feed('block_transfer', [slots])
+        fetches = _paged_fetches(spec, at, tokens, slots, num_pages,
+                                 page_tokens)
+    return prog, names, fetches
+
+
 def build_page_copy_program(spec, slots, num_pages, page_tokens,
                             window_pages=0):
     """The copy a forking decode step runs in front of its program: one
@@ -1103,6 +1265,7 @@ def build_verify_program(spec, slots, k1, num_pages, page_tokens,
     refuse_recurrent(spec, 'the speculative verify program')
     refuse_latent_pages(spec, 'the speculative verify program')
     refuse_window(spec, 'the speculative verify program')
+    refuse_blocks(spec, 'the speculative verify program')
     prog, startup = Program(), Program()
     prog._is_test = True
     with program_guard(prog, startup):

@@ -24,3 +24,4 @@ from . import quant_ops     # noqa: F401
 from . import delta_rule_ops  # noqa: F401
 from . import ssd_ops      # noqa: F401
 from . import latent_attention_ops  # noqa: F401
+from . import block_diffusion_ops  # noqa: F401

@@ -402,6 +402,62 @@ def _paged_attention_emit(ctx, op):
     ctx.set(op.single_output('Out'), out)
 
 
+@op_emitter('paged_block_attention')
+def _paged_block_attention_emit(ctx, op):
+    """A block step's attention through the page tables: Q [S, R, H, dh]
+    (R rows a lane: a block of a model that generates by diffusion over
+    blocks), KPool / VPool [N, pt, KVH, dh], Table [S, P] int32,
+    Positions [S] int32 (the position of each lane's LAST block row),
+    attr sm_scale -> Out [S, R, H, dh]. Every row of lane s attends to
+    the lane's logical positions 0..Positions[s]: the committed pages
+    and all R rows of its own block, which the program wrote through
+    the table just before. An op of its own beside paged_attention, not
+    a `rows` attribute of it: every existing caller's op and kernel call
+    stay letter for letter what they were, and a device trace tells a
+    block step's attention from a decode step's by name.
+
+    Because every row of a lane sees the same keys, the R rows are to
+    the kernel what the H / KVH query heads of a K/V head already are:
+    more rows of the one product against pages that are read once. On a
+    TPU (or under FLAGS_pallas_interpret), for pages the kernel tiles,
+    q is regrouped to [S, KVH * (H / KVH * R), dh] and goes through the
+    kernel of pallas/paged_attention.py under the name
+    `paged_block_attention`; everywhere else through the reference
+    composition (gather, mask after Positions, softmax), which is the
+    CPU's path and the tests' truth. R = 1 is paged_attention's sum."""
+    from ..pallas import paged_attention as _pa
+    from ..flags import get_flag
+    q = ctx.get(op.single_input('Q'))
+    k_pool = ctx.get(op.single_input('KPool'))
+    v_pool = ctx.get(op.single_input('VPool'))
+    table = ctx.get(op.single_input('Table')).astype(jnp.int32)
+    positions = ctx.get(op.single_input('Positions')).astype(jnp.int32)
+    sm_scale = op.attr('sm_scale')
+    if getattr(ctx, 'mesh', None) is not None and ctx.mesh.size > 1:
+        raise NotImplementedError('paged_block_attention on a mesh')
+    on_tpu = jax.default_backend() == 'tpu'
+    if _pa.supported(k_pool.shape[1], k_pool.shape[3]) and (
+            on_tpu or bool(get_flag('pallas_interpret'))):
+        S, R, H, dh = q.shape
+        KVH = k_pool.shape[2]
+        rep = H // KVH
+        # query row r of head kv * rep + g -> row (g * R + r) of K/V
+        # head kv's group
+        grouped = jnp.transpose(q.reshape(S, R, KVH, rep, dh),
+                                (0, 2, 3, 1, 4)).reshape(S, H * R, dh)
+        out = _pa.paged_attention(
+            grouped, k_pool, v_pool,
+            jnp.clip(table, 0, k_pool.shape[0] - 1), positions,
+            sm_scale=sm_scale, interpret=not on_tpu,
+            name='paged_block_attention')
+        out = jnp.transpose(out.reshape(S, KVH, rep, R, dh),
+                            (0, 3, 1, 2, 4)).reshape(S, R, H, dh)
+    else:
+        out = _paged_attention_reference(q, k_pool, v_pool, table,
+                                         positions, sm_scale, lambda x: x)
+    ctx.set(op.single_output('Out'), out)
+
+
 @op_emitter('spec_verify_mask')
 def _spec_verify_mask_emit(ctx, op):
     """Causal validity mask for the speculative verify pass: X
@@ -430,14 +486,22 @@ def _paged_prefill_mask_emit(ctx, op):
     a chunk attends to every previously written page plus its own
     already-written rows. With attr `window` (a sliding layer's; 0:
     none) a band: row i sees index j iff positions[i] - window < j <=
-    positions[i]. Padding rows carry garbage positions; their score
-    rows are never gathered downstream. Without Positions the rows are
-    a whole sequence's from its start (a saved model's form)."""
+    positions[i]. With attr `block` (a model that generates by
+    diffusion over blocks; 0: none, plain causality) row i sees index j
+    iff j // block <= positions[i] // block: causal over blocks,
+    bidirectional inside one. Padding rows carry garbage positions;
+    their score rows are never gathered downstream. Without Positions
+    the rows are a whole sequence's from its start (a saved model's
+    form)."""
     x = ctx.get(op.single_input('X'))
     positions = ctx.get(op.single_input('Positions')).astype(jnp.int32) \
         if op.input('Positions') else jnp.arange(x.shape[-2], dtype=jnp.int32)
     j = jnp.arange(x.shape[-1], dtype=jnp.int32)
-    valid = j[None, :] <= positions[:, None]           # [C, J]
+    block = int(op.attr('block', 0))
+    if block:
+        valid = j[None, :] // block <= positions[:, None] // block
+    else:
+        valid = j[None, :] <= positions[:, None]       # [C, J]
     window = int(op.attr('window', 0))
     if window:
         valid &= j[None, :] > positions[:, None] - window
@@ -499,6 +563,8 @@ register_op('kv_page_gather', infer_shape=_kv_page_gather_infer,
 register_op('paged_attention', infer_shape=_ring_infer,
             no_grad=True)
 register_op('paged_window_attention', infer_shape=_ring_infer,
+            no_grad=True)
+register_op('paged_block_attention', infer_shape=_ring_infer,
             no_grad=True)
 register_op('paged_decode_mask', infer_shape=_decode_mask_infer,
             no_grad=True)

@@ -104,7 +104,8 @@ def _rotary_yarn_emit(ctx, op):
     """X [B, T, H, dr] or [B, T, dr] with each row rotated by its
     position: arange(T) where no Positions are given (a whole sequence
     from its start), Positions [T] with attr per = 'row' (a chunk's
-    rows), Positions [B] with per = 'lane' (one token a lane). With
+    rows), Positions [B] with per = 'lane' (one token a lane),
+    Positions [B, T] with per = 'each' (a block step's rows). With
     attr start, only X[..., start:] turns and the columns before it
     pass as they are. attrs dim (dr), base, factor, original_max,
     beta_fast, beta_slow, mscale, mscale_all_dim: the table is a
@@ -114,8 +115,9 @@ def _rotary_yarn_emit(ctx, op):
     inv, amp = rope_table(op)
     if op.input('Positions'):
         pos = ctx.get(op.single_input('Positions')).astype(jnp.int32)
-        pos = pos[:, None] if op.attr('per', 'row') == 'lane' \
-            else pos[None, :]
+        per = op.attr('per', 'row')
+        if per != 'each':
+            pos = pos[:, None] if per == 'lane' else pos[None, :]
     else:
         pos = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
     if x.ndim == 4:

@@ -290,7 +290,8 @@ def _moe_experts_emit(ctx, op):
     experts' form follows from the weights handed in: with W3 [held, L,
     F] beside W1 an expert is W2 (act(W1 l) * W3 l), attr act 'silu'
     where not given or 'relu', without it W2 relu(W1 l)^2. Rows may be marked dead, by Live [rows] (a decode step's
-    lanes) or Len [1] (a chunk's rows from Len on): they choose nothing
+    lanes; [lanes] for a block step's [lanes, B] rows: a lane's flag
+    covers its rows) or Len [1] (a chunk's rows from Len on): they choose nothing
     and count nothing. Stats [4] int32, where asked for, is this call's
     (pairs on held experts, held experts with at least one pair, pairs
     selected here and not computed, 1): the third is 0, there being no
@@ -311,8 +312,11 @@ def _moe_experts_emit(ctx, op):
         int(op.attr('topk_group', 1)),
         op.attr('gate', 'sigmoid'))[:, offset:offset + w1.shape[0]]
     if op.input('Live'):
-        w = jnp.where(ctx.get(op.single_input('Live')).astype(bool)
-                      .reshape(rows)[:, None], w, 0.0)
+        live = ctx.get(op.single_input('Live')).astype(bool).reshape(-1)
+        if live.shape[0] != rows:
+            # a block step: a lane's flag covers all its rows
+            live = jnp.repeat(live, rows // live.shape[0])
+        w = jnp.where(live[:, None], w, 0.0)
     elif op.input('Len'):
         n = ctx.get(op.single_input('Len')).astype(jnp.int32).reshape(())
         w = jnp.where((jnp.arange(rows) < n)[:, None], w, 0.0)

@@ -180,9 +180,9 @@ def _kernel(table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=('sm_scale', 'interpret', 'window'))
+                   static_argnames=('sm_scale', 'interpret', 'window', 'name'))
 def paged_attention(q, k_pool, v_pool, table, positions, sm_scale,
-                    interpret=False, window=0):
+                    interpret=False, window=0, name=None):
     """q [S, H, dh], pools [N, pt, KVH, dh], table [S, P] int32,
     positions [S] int32 -> [S, H, dh]: softmax over lane s's positions
     0..positions[s] (the last `window` of them where one is given) of
@@ -221,7 +221,7 @@ def paged_attention(q, k_pool, v_pool, table, positions, sm_scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary',)),
         interpret=pltpu.InterpretParams() if interpret else False,
-        name='paged_window_attention' if window else 'paged_attention',
+        name=name or _NAMES[bool(window)],
     )(table.reshape(-1), positions, q, k3, v3)
 
 
@@ -340,3 +340,13 @@ def paged_latent_attention(q, pool, table, positions, sm_scale, value_dim,
         interpret=pltpu.InterpretParams() if interpret else False,
         name='paged_latent_attention',
     )(table.reshape(-1), positions, q, pool)
+
+
+# The kernel's name in the device's trace, by whether the caller gave a
+# window; `paged_attention(name=)` is another caller's own (a block
+# step's `paged_block_attention`, ops/attention_ops.py: its rows a lane
+# ride as further query heads of their K/V head). Down here, and the
+# function above kept to the lines it had: a Mosaic kernel's serialized
+# text holds its source lines, and a line that moves is another
+# compile-cache key for every cell's decode program.
+_NAMES = ('paged_attention', 'paged_window_attention')

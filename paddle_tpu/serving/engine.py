@@ -72,6 +72,33 @@ request's first gap after a preemption is `sync` (a token that a
 re-prefill's last chunk made reads (0, 1, 1)). All of it is recorded
 while the registry is on and costs one boolean read while it is off.
 
+A lane that is not one token a step. A predictor of a model that
+generates by diffusion over blocks (`block_tokens` = B > 0,
+serving/paged.py "Blocks") is asked once a pass, not once a lane, and
+its lanes ride the same loop: admission, at most one chunk, ONE step
+over the ready lanes (`block_step`). A lane carries its block's state
+(`_Lane.block`: where the block starts, the rows still masked, the
+passes so far); lanes at different passes of their blocks, and lanes
+whose pass is their block's commit, ride in the same step. Most steps
+yield a lane nothing; the commit yields the block's tokens at once.
+They are accepted together, in order, cut at the eos_id or the budget:
+`first_token_at` is the first block's commit, every token of a block
+gets that commit's `token_at`, and a "gap" is the time between two
+DELIVERIES: `_gap` records one entry a delivery (every one but a
+request's first), with the chunks that stood in front of ANY of the
+block's passes, the lanes of its commit's step and `sync` if any of its
+passes was dispatched with nothing in flight (`Request.delivered_at`
+holds the deliveries' times, and the `serve.decode` span's `gaps_ms` the
+differences between them, so the lists stay one entry a gap). Under the
+static unmasking rules the loop is pipelined like the one-token loop: a
+pass is packed from the schedule while the one before runs, the block's
+ids stay on the device between passes, and a commit's tokens are
+accepted one fetch late (a lane that ended on an eos_id, a cancel or a
+deadline was fed to one pass too many, whose result is dropped); under
+low_confidence_dynamic only the device knows how many rows a pass
+unmasked, and the loop is the serial one. A preempted stream
+re-prefills (its tokens so far are whole blocks).
+
 Requests carry a PRIORITY tier (submit(priority=), higher = more
 important, 0 = the default lowest tier): one queue per tier, popped
 highest-tier first, and the queue-full admission bound applies only to
@@ -196,6 +223,8 @@ _weight_swaps = telemetry.counter('serving.weight_swaps')
 _swap_wait = telemetry.histogram('serving.swap_wait')
 _cache_exhausted = telemetry.counter('serving.cache_exhausted')
 _deadline_expired = telemetry.counter('serving.deadline_expired')
+_block_tokens = telemetry.counter('serving.block.tokens')
+_passes_per_block = telemetry.histogram('serving.block.passes_per_block')
 
 
 def gap_kind(chunks, sync):
@@ -304,6 +333,9 @@ class Request(object):
         self.admitted_at = None
         self.first_token_at = None
         self.token_at = []
+        # a lane that delivers a block at a time: one reading a delivery
+        # (None for a token a step, where token_at says the same)
+        self.delivered_at = None
         # what stood in front of the step that made token i + 1 (the
         # module's docstring), one entry a gap while the registry is on
         self.gap_chunks, self.gap_lanes, self.gap_sync = [], [], []
@@ -357,7 +389,8 @@ class Request(object):
                      preemptions=self.preemptions)
         for (name, t0), t1 in zip(marks, ends):
             if name == 'serve.decode':
-                at = self.token_at
+                at = self.token_at if self.delivered_at is None \
+                    else self.delivered_at
                 attrs.update(gaps_ms=[1e3 * (b - a)
                                       for a, b in zip(at, at[1:])],
                              gap_chunks=self.gap_chunks,
@@ -390,13 +423,23 @@ class _Lane(object):
     loop a lane fed to the decode step in flight has `pos` already at
     the position after that step, and its next token still on the
     device (`tok` is then the last one accepted; none yet for a lane
-    whose first token is pending behind its prompt's last chunk)."""
-    __slots__ = ('req', 'pos', 'tok', 'ready', 'last_active')
+    whose first token is pending behind its prompt's last chunk).
+    A lane of a model that generates by diffusion over blocks: `pos` is
+    its committed length (where its block starts), `block` the block's
+    state between its passes (serving/paged.BlockState; None until the
+    prompt is in), `carry` whether the block's ids are on the device
+    (a pass over it was dispatched), `pending` the tokens of commits
+    dispatched and not yet accepted, and `front` what stood in front of
+    the passes since the last delivery ([chunks, sync])."""
+    __slots__ = ('req', 'pos', 'tok', 'ready', 'last_active', 'block',
+                 'carry', 'pending', 'front')
 
     def __init__(self, req, pos, tok, ready=True):
         self.req, self.pos, self.tok = req, pos, tok
         self.ready = ready
         self.last_active = time.perf_counter()
+        self.block, self.carry, self.pending = None, False, 0
+        self.front = [0, 0]
 
 
 class ServingEngine(object):
@@ -1017,14 +1060,21 @@ class ServingEngine(object):
                 self._finish_lane(lanes, slot, FAILED, error=repr(e),
                                   pred=pred, wstate=wstate)
                 return
-            _prefills.inc()
-            wstate['chunks'] += 1
-            req.prefill_chunks += 1
+            if getattr(out, 'chunk_ran', True):
+                _prefills.inc()
+                wstate['chunks'] += 1
+                req.prefill_chunks += 1
             if out is None:
                 return               # more chunks remain — next iteration
             prefilling.popleft()
             lane.ready = True
-            if deferred:
+            if getattr(pred, 'block_tokens', 0):
+                # whole blocks are in: the prompt's last tokens open the
+                # first generated block, whose passes make the stream's
+                # first logits
+                lane.pos = out.start
+                lane.block = pred.new_block(out.start, out.tail)
+            elif deferred:
                 wstate['first'] = (slot, lane, out)
             else:
                 self._lane_accept(lanes, slot, int(out), pred=pred,
@@ -1112,15 +1162,19 @@ class ServingEngine(object):
             try:
                 ids = pred.collect()
             except Exception as e:   # noqa: BLE001 — engine survives
-                for slot, lane in flight:
+                for slot, lane, *_ in flight:
                     if lanes.get(slot) is lane:
                         self._finish_lane(lanes, slot, FAILED,
                                           error=repr(e), pred=pred,
                                           wstate=wstate)
             else:
-                with RecordEvent('serve.accept'):
-                    self._accept_flight(flight, wstate['rec'], ids, pred,
-                                        lanes, wstate)
+                with RecordEvent('serve.accept') as ev:
+                    if getattr(pred, 'block_tokens', 0):
+                        ev.attrs['blocks'] = self._accept_blocks(
+                            flight, ids, pred, lanes, wstate)
+                    else:
+                        self._accept_flight(flight, wstate['rec'], ids,
+                                            pred, lanes, wstate)
         self._accept_first(pred, lanes, wstate)
         self._report(wstate['wid'], lanes)
 
@@ -1166,6 +1220,197 @@ class ServingEngine(object):
                 continue
             self._lane_accept(lanes, slot, int(ids[slot]), pred=pred,
                               wstate=wstate, rec=rec)
+
+    def _step_exhausted(self, e, pred, lanes, wstate):
+        """A step raised CacheExhaustedError: nothing of it ran; the one
+        in flight is accepted first, so that a victim's tokens and pages
+        agree."""
+        self._collect(pred, lanes, wstate)
+        # preempt-first (serving/preempt.py): instead of
+        # failing the named victims, the lowest-tier
+        # longest-idle stream gives its pages back (swap or
+        # drop) and every survivor retries the IDENTICAL
+        # step next iteration — the transactional rollback
+        # already undid this call's allocations, so the
+        # retry is bit-exact. policy 'off' restores the
+        # legacy typed shed (the fleet router retries it
+        # cross-replica).
+        _cache_exhausted.inc()
+        policy = preempt_policy()
+        preempted = False
+        if policy != 'off':
+            for slot in list(e.slots):
+                lane = lanes.get(slot)
+                if lane is not None and lane.pos + max(
+                        1, getattr(pred, 'block_tokens', 0)) > pred.window:
+                    # outgrew its own page window: no
+                    # preemption can ever make it fit
+                    self._finish_lane(
+                        lanes, slot, FAILED,
+                        error='CacheExhaustedError: %s' % e,
+                        pred=pred, wstate=wstate)
+            victim = pick_victim(lanes)
+            if victim is not None:
+                self._preempt_lane(pred, lanes, victim,
+                                   wstate, policy)
+                preempted = True
+        if not preempted:
+            for slot in e.slots:
+                if slot in lanes:
+                    self._finish_lane(
+                        lanes, slot, FAILED,
+                        error='CacheExhaustedError: %s' % e,
+                        pred=pred, wstate=wstate)
+
+    def _step_failed(self, e, ready, pred, lanes, wstate):
+        """A step raised something else: the one in flight is accepted,
+        the lanes this one was packed with fail."""
+        self._collect(pred, lanes, wstate)
+        for slot in ready:
+            if slot in lanes:
+                self._finish_lane(lanes, slot, FAILED, error=repr(e),
+                                  pred=pred, wstate=wstate)
+
+    def _block_pass(self, wid, pred, lanes, ready, wstate):
+        """The step of a pass for a predictor whose lanes hold blocks
+        (the module's docstring, "A lane that is not one token a step"):
+        one block_step over the ready lanes, each at its own pass of its
+        block, and the acceptance of what the step (or, pipelined, the
+        step before) handed back."""
+        B, S = pred.block_tokens, pred.slots
+        deferred = pred.block_defers and \
+            getattr(pred, 'deferred_decode', False)
+        buf = wstate.get('block_feed')
+        if buf is None:
+            buf = wstate['block_feed'] = (np.zeros((S, B), np.int64),
+                                          np.zeros((S,), np.int32),
+                                          np.zeros((S,), np.int32))
+        tokens, starts, transfer = buf
+        plan = []
+        with RecordEvent('serve.pack'):
+            for slot in ready:
+                lane = lanes[slot]
+                if lane.block is None:
+                    lane.block, lane.carry = pred.new_block(lane.pos), False
+                blk = lane.block
+                n, commit = blk.plan(pred.block_schedule)
+                tokens[slot], starts[slot] = blk.ids, blk.start
+                transfer[slot] = n
+                plan.append((slot, lane, blk, n, commit))
+        t0 = time.perf_counter()
+        try:
+            out = pred.block_step(
+                tokens, starts, transfer, ready,
+                carry=[s for s, ln, *_ in plan if ln.carry],
+                commit=[s for s, *_, commit in plan if commit],
+                defer=deferred)
+        except CacheExhaustedError as e:
+            self._step_exhausted(e, pred, lanes, wstate)
+            return
+        except Exception as e:   # noqa: BLE001 — engine survives
+            self._step_failed(e, ready, pred, lanes, wstate)
+            return
+        _decode_steps.inc()
+        _token_latency.observe(time.perf_counter() - t0)
+        _decode_batch.observe(len(ready))
+        wstate['steps'] += 1
+        chunks = wstate['chunks'] - wstate['chunks_seen']
+        sync = int(not deferred or wstate['flight'] is None)
+        wstate['chunks_seen'] = wstate['chunks']
+        fed = []
+        for slot, lane, blk, n, commit in plan:
+            lane.front[0] += chunks
+            lane.front[1] |= sync
+            rec = None
+            if commit:
+                # the tokens are accepted when the ids arrive; the next
+                # block opens behind this one, all of it masked
+                _passes_per_block.observe(blk.passes + 1)
+                lane.pending += B - blk.fixed
+                lane.pos = blk.start + B
+                lane.block, lane.carry = None, False
+                rec, lane.front = (lane.front[0], len(ready),
+                                   lane.front[1]), [0, 0]
+            else:
+                blk.passed(n)
+                lane.carry = True
+            fed.append((slot, lane, blk, commit, rec))
+        if deferred:
+            flight, wstate['flight'] = wstate['flight'], fed
+            if flight is not None:
+                _decode_steps_overlapped.inc()
+                with RecordEvent('serve.accept') as ev:
+                    ev.attrs['blocks'] = self._accept_blocks(
+                        flight, out, pred, lanes, wstate)
+            if not any(lanes.get(s) is ln for s, ln, *_ in fed):
+                self._collect(pred, lanes, wstate)
+        else:
+            with RecordEvent('serve.accept') as ev:
+                ev.attrs['blocks'] = self._accept_blocks(
+                    fed, out, pred, lanes, wstate)
+        self._report(wid, lanes)
+
+    def _accept_blocks(self, flight, out, pred, lanes, wstate):
+        """What a block step handed back, (ids [slots, B], masked
+        [slots]), for the lanes it was fed (`flight`): a lane that ended
+        meanwhile is passed over (its result is dropped, as a deferred
+        decode step's is), a cancelled one ends here, a commit's tokens
+        are delivered, and a synchronous pass's count of rows still
+        masked corrects the schedule's (they differ under
+        low_confidence_dynamic alone). Returns the blocks delivered."""
+        ids, masked = out
+        delivered = 0
+        for slot, lane, blk, commit, rec in flight:
+            if lanes.get(slot) is not lane:
+                _decode_lanes_dropped.inc()
+                continue
+            if lane.req.state == CANCELLED:
+                self._finish_lane(lanes, slot, CANCELLED, pred=pred,
+                                  wstate=wstate)
+                continue
+            if not commit:
+                if lane.block is blk and wstate['flight'] is None:
+                    # the newest step's own result: the device's count,
+                    # and ids the host can feed again
+                    blk.masked = int(masked[slot])
+                    blk.ids = [int(t) for t in ids[slot]]
+                    lane.carry = False
+                continue
+            delivered += 1
+            lane.pending -= pred.block_tokens - blk.fixed
+            self._deliver(lanes, slot, [int(t) for t in ids[slot]][blk.fixed:],
+                          rec, pred=pred, wstate=wstate)
+        return delivered
+
+    def _deliver(self, lanes, slot, toks, rec, *, pred, wstate):
+        """Record one block's tokens, accepted together at its commit,
+        in order, cut at the budget or behind an eos_id; False if the
+        lane is done and was evicted."""
+        lane = lanes[slot]
+        req = lane.req
+        toks = toks[:req.max_new_tokens - len(req.tokens)]
+        if req.eos_id is not None and req.eos_id in toks:
+            toks = toks[:toks.index(req.eos_id) + 1]
+        now = time.perf_counter()
+        req.tokens.extend(toks)
+        req.token_at.extend([now] * len(toks))
+        _tokens_out.inc(len(toks))
+        _block_tokens.inc(len(toks))
+        if req.first_token_at is None:
+            req.first_token_at = now
+            req.delivered_at = [now]
+            _ttft.observe(now - req.submitted_at)
+        else:
+            req.delivered_at.append(now)
+            if telemetry._enabled:
+                req._gap(rec)
+        if len(req.tokens) >= req.max_new_tokens or \
+                (req.eos_id is not None and toks[-1] == req.eos_id):
+            self._finish_lane(lanes, slot, DONE, pred=pred, wstate=wstate)
+            return False
+        lane.tok = toks[-1]
+        lane.last_active = now
+        return True
 
     def _iterate(self, it, wid, pred, lanes, prefilling, wstate, tokens,
                  positions):
@@ -1217,12 +1462,13 @@ class ServingEngine(object):
         # in flight or in the last chunk just dispatched (the pipelined
         # loop alone has any); one whose budget ends with that token
         # sits this step out
-        carried = {s for s, ln in wstate['flight'] or ()
+        block = getattr(pred, 'block_tokens', 0)
+        carried = {s for s, ln, *_ in wstate['flight'] or ()
                    if lanes.get(s) is ln}
         if first is not None:
             carried.add(first)
         ready = [s for s, ln in lanes.items() if ln.ready and
-                 len(ln.req.tokens) + (s in carried)
+                 len(ln.req.tokens) + (ln.pending if block else s in carried)
                  < ln.req.max_new_tokens]
         if telemetry._enabled:
             with self._cond:
@@ -1231,6 +1477,9 @@ class ServingEngine(object):
                             prefilling=len(prefilling), queued=queued)
         if not ready:
             self._collect(pred, lanes, wstate)
+            return
+        if block:
+            self._block_pass(wid, pred, lanes, ready, wstate)
             return
         with RecordEvent('serve.pack'):
             for slot in ready:
@@ -1247,52 +1496,10 @@ class ServingEngine(object):
             else:
                 ids = pred.decode_step(tokens, positions)
         except CacheExhaustedError as e:
-            # nothing of this step ran; the one in flight is accepted
-            # first, so that a victim's tokens and pages agree
-            self._collect(pred, lanes, wstate)
-            # preempt-first (serving/preempt.py): instead of
-            # failing the named victims, the lowest-tier
-            # longest-idle stream gives its pages back (swap or
-            # drop) and every survivor retries the IDENTICAL
-            # step next iteration — the transactional rollback
-            # already undid this call's allocations, so the
-            # retry is bit-exact. policy 'off' restores the
-            # legacy typed shed (the fleet router retries it
-            # cross-replica).
-            _cache_exhausted.inc()
-            policy = preempt_policy()
-            preempted = False
-            if policy != 'off':
-                for slot in list(e.slots):
-                    lane = lanes.get(slot)
-                    if lane is not None and \
-                            lane.pos + 1 > pred.window:
-                        # outgrew its own page window: no
-                        # preemption can ever make it fit
-                        self._finish_lane(
-                            lanes, slot, FAILED,
-                            error='CacheExhaustedError: %s' % e,
-                            pred=pred, wstate=wstate)
-                victim = pick_victim(lanes)
-                if victim is not None:
-                    self._preempt_lane(pred, lanes, victim,
-                                       wstate, policy)
-                    preempted = True
-            if not preempted:
-                for slot in e.slots:
-                    if slot in lanes:
-                        self._finish_lane(
-                            lanes, slot, FAILED,
-                            error='CacheExhaustedError: %s' % e,
-                            pred=pred, wstate=wstate)
+            self._step_exhausted(e, pred, lanes, wstate)
             return
         except Exception as e:   # noqa: BLE001 — engine survives
-            self._collect(pred, lanes, wstate)
-            for slot in ready:
-                if slot in lanes:
-                    self._finish_lane(lanes, slot, FAILED,
-                                      error=repr(e), pred=pred,
-                                      wstate=wstate)
+            self._step_failed(e, ready, pred, lanes, wstate)
             return
         dt = time.perf_counter() - t0
         _decode_steps.inc()

@@ -51,6 +51,11 @@ A stream's life:
                                 from the step before or from its
                                 prompt's deferred last chunk
     collect()                   the ids of the deferred step in flight
+    block_step(tokens, starts, transfer)
+                                in the place of decode_step for a model
+                                that generates by diffusion over blocks
+                                (`block_tokens` > 0: "Blocks" below):
+                                one pass over a block of every lane
     release(slot)               drop the stream's page refs
     save_stream(slot)           copy the stream's pages to host RAM
                                 (preempt-first capacity); paired with
@@ -119,6 +124,39 @@ layer, [pages, page_tokens, row]) keeps nothing else for a stream, so
 everything here that moves pages (the prefix cache with copy-on-write,
 save_stream / restore_stream, export_prefix / install_prefix) serves
 it as it serves K/V pages: each takes its sizes from the pools.
+
+Blocks. A model that generates by diffusion over blocks
+(models/sdar_moe.py: spec.block_tokens = B) has no one-token decode
+step: the pair's second program is a BLOCK step, B rows of every lane
+at the block's positions, each row attending over the lane's pages up
+to the end of its block. A lane's block is passed over several times at
+the SAME positions (each denoising pass hands some masked rows their
+argmax, on the device: op block_unmask) and once more with no row
+masked, the commit. Both are the one program with one feed shape. The
+cache contract this generalises from speculative.py's parked rows: a
+pass writes the block's B K/V rows through the table at the block's
+positions, AHEAD of `table.length`, where the next pass overwrites them;
+only a commit moves `table.length` on, by B, and so only rows computed
+from a block's final tokens ever become visible to later blocks (a
+later block's rows attend to 0..its own end). A block's page is grown,
+and a shared frontier page forked, ONCE, before the block's first pass
+(B divides a page, a block never straddles two), and rolled back as
+decode_step rolls back when the pool is dry. A prompt's WHOLE blocks
+are prefilled under the same mask (a chunk holds whole blocks); its last
+len % B tokens open the first generated block as fixed tokens, so the
+last chunk has no first token to hand back: prefill_step ends with a
+BlockStart (where the first block starts and what it opens with; at
+once, with no program run, where the cache shares every whole block or
+the prompt has none), which is not None in either form of the call. The
+prefix cache hands out and registers boundaries of whole blocks only
+(PrefixCache(block=B)). With the static rules the rows still masked
+behind a pass follow from the schedule, so block_step defers like
+decode_step (the ids stay on the device, `block_carry` lanes take them
+there); with low_confidence_dynamic only the device knows, and the
+engine steps such a predictor synchronously (`block_defers`). Such a
+model is not swappable (a preempted stream re-prefills: its tokens so
+far are whole blocks), and speculation, mesh serving and page shipping
+refuse it by name (models/transformer.refuse_blocks).
 
 Telemetry: serving.kv_pages_in_use / serving.kv_pages_free gauges,
 serving.prefix_hits / serving.prefix_tokens_reused counters (beside
@@ -272,6 +310,11 @@ _window_tail_miss = telemetry.counter('serving.prefix.window_tail_miss')
 _prefix_evictions = telemetry.counter('serving.prefix.evictions')
 _prefix_scanned = telemetry.counter('serving.prefix.entries_scanned')
 _prefix_evict_seconds = telemetry.counter('serving.prefix.evict_seconds')
+_block_passes = telemetry.counter('serving.block.passes')
+_block_commits = telemetry.counter('serving.block.commits')
+_block_masked_rows = telemetry.counter('serving.block.masked_rows')
+_block_rows = telemetry.counter('serving.block.rows')
+_effective_tokens = telemetry.gauge('serving.effective_tokens_per_step')
 _MOE_COUNTS = ('pairs', 'experts_touched', 'pairs_dropped', 'layer_calls')
 
 
@@ -299,6 +342,52 @@ class _FirstToken(object):
 
     def __init__(self, slot, ids):
         self.slot, self.ids = slot, ids
+
+
+class BlockStart(object):
+    """What prefill_step hands back for a model that generates by
+    diffusion over blocks once a prompt's whole blocks are in: where the
+    stream's first generated block starts and the prompt's last tokens,
+    which open it as fixed tokens. `chunk_ran`: the call ran a chunk
+    (False where nothing was left to prefill)."""
+    __slots__ = ('slot', 'start', 'tail', 'chunk_ran')
+
+    def __init__(self, slot, start, tail, chunk_ran):
+        self.slot, self.start, self.tail = slot, start, list(tail)
+        self.chunk_ran = chunk_ran
+
+
+class BlockState(object):
+    """One lane's block between its passes, as the host knows it: where
+    it starts, the ids its first pass is fed (`fixed` tokens, the mask
+    id behind them), the rows still masked and the passes dispatched.
+    The serving engine keeps one a lane; generate() and a benchmark's
+    check drive block_step through the same few lines."""
+    __slots__ = ('start', 'ids', 'fixed', 'masked', 'passes')
+
+    def __init__(self, start, tail, block, mask_id):
+        self.start, self.fixed = int(start), len(tail)
+        self.ids = [int(t) for t in tail] + [mask_id] * (block - len(tail))
+        # a prompt token that is the mask id is a masked row like any
+        # other: the device counts it so (op block_unmask)
+        self.masked, self.passes = self.ids.count(mask_id), 0
+
+    def plan(self, schedule):
+        """(transfer, commit) of the block's next pass: the rows it
+        unmasks at most, never more than are still masked; a block with
+        no row masked is committed."""
+        if not self.masked:
+            return 0, True
+        return min(schedule[min(self.passes, len(schedule) - 1)],
+                   self.masked), False
+
+    def passed(self, transfer, masked=None):
+        """A denoising pass was dispatched: under the static rules the
+        rows still masked follow from `transfer`; `masked` where the
+        device's count is known (the synchronous form)."""
+        self.passes += 1
+        self.masked = self.masked - transfer if masked is None \
+            else int(masked)
 
 
 class _PendingPrefill(object):
@@ -364,12 +453,20 @@ class PagedDecodePredictor(object):
                 self._refuse_recurrent(what)
                 refuse_latent_pages(self._pair.spec, what)
                 refuse_window(self._pair.spec, what)
+                self._refuse_blocks(what)
             self._pair.spec.mesh = self._mesh_shape
+        if self.block_tokens and self.prefill_chunk % self.block_tokens:
+            raise ValueError('a prefill chunk of %d tokens does not hold '
+                             'whole blocks of %d'
+                             % (self.prefill_chunk, self.block_tokens))
         self._exe = self._make_executor(predictor._place)
         if _clone_of is None:
             self._pin_weights()
         self._scope = Scope(parent=self._weight_scope)
         self.fetch_wait_s = 0.0       # blocked in a step's fetch, ever
+        # what the block steps carried, ever (block_stats())
+        self._block_stats = {'passes': 0, 'commits': 0, 'steps': 0,
+                             'rows': 0, 'masked_rows': 0, 'live_tokens': 0}
         self.reset()
         # the copy program is compiled by the first decode step, with
         # the decode program (decode_step), the state copy programs by
@@ -470,10 +567,39 @@ class PagedDecodePredictor(object):
         return bool(self._pair.state_names)
 
     @property
+    def block_tokens(self):
+        """The block length of a model that generates by diffusion over
+        blocks (its step is block_step, not decode_step); 0 for every
+        other."""
+        return self._pair.spec.block_tokens
+
+    @property
+    def block_schedule(self):
+        """Rows a denoising pass unmasks, pass by pass."""
+        return self._pair.spec.block_schedule
+
+    @property
+    def block_mask_id(self):
+        return self._pair.spec.cfg.mask_id
+
+    @property
+    def block_defers(self):
+        """Whether block_step can be one stage of a pipeline: under the
+        static rules the host knows the rows still masked behind a pass
+        without its ids."""
+        return self._pair.spec.cfg.remasking != 'low_confidence_dynamic'
+
+    def new_block(self, start, tail=()):
+        """The BlockState of a block that starts at `start` and opens
+        with the fixed tokens `tail`."""
+        return BlockState(start, tail, self.block_tokens, self.block_mask_id)
+
+    @property
     def swappable(self):
         """Whether save_stream / restore_stream can carry a stream of
-        this model (not one with a second table: module docstring)."""
-        return not self._pair.window_num_pages
+        this model (not one with a second table, nor one with a block
+        between its passes: module docstring)."""
+        return not self._pair.window_num_pages and not self.block_tokens
 
     def _recurrent_state_bytes(self):
         shapes = self._pair.spec.state_shapes(self.slots) \
@@ -569,7 +695,7 @@ class PagedDecodePredictor(object):
         date. A step still running is left for the next call, as are
         the newest `leave` (a step's own call: one small transfer a
         step, of a step long ended)."""
-        if len(self._pair.decode_fetches) < 3:
+        if len(self._pair.decode_fetches) - bool(self.block_tokens) < 3:
             return {}
         with self._moe_lock:
             while len(self._moe_queue) > leave \
@@ -781,7 +907,16 @@ class PagedDecodePredictor(object):
         self._wtables = {}            # slot -> PageTable with a window
         self._prefix = PrefixCache(self._pool, snapshot_rows=rows,
                                    window_pool=self._wpool,
-                                   window=spec.window)
+                                   window=spec.window,
+                                   block=spec.block_tokens)
+        # slot -> the start of the block whose page is grown and forked
+        self._block_grown = {}
+        # the block step before's (ids [slots, B], rows still masked
+        # [slots]): on the device once a step ran
+        self._last_block = (
+            np.zeros((self.slots, max(spec.block_tokens, 1)), np.int64),
+            np.zeros((self.slots,), np.int32))
+        self._block_masked = {}       # slot -> rows still masked
         self._snaps_gone = 0          # of them, counted so far
         self._pool.set_evict(self._evicting(self._prefix.evict_one, 'full'))
         if self._wpool is not None:
@@ -858,7 +993,13 @@ class PagedDecodePredictor(object):
         chunk = self.prefill_chunk
         return {'slot': slot, 'prompt_tokens': len(prompt),
                 'shared_tokens': shared,
-                'chunks': -(-(len(prompt) - shared) // chunk)}
+                'chunks': -(-(self._prefilled(len(prompt)) - shared)
+                            // chunk)}
+
+    def _prefilled(self, n):
+        """Of a prompt of `n` tokens, those the prefill program takes:
+        all of them, or its whole blocks."""
+        return n - n % self.block_tokens if self.block_tokens else n
 
     def _open(self, slot, prompt):
         """open_stream's body: the stream's tables, the one question to
@@ -877,7 +1018,10 @@ class PagedDecodePredictor(object):
             match = self._prefix.match_state
         else:
             match = None
-        limit = len(prompt) - 1
+        # a prompt's last token is always computed (its logits make the
+        # first token), except where whole blocks are prefilled and the
+        # first block's pass makes the stream's first logits
+        limit = len(prompt) - (0 if self.block_tokens else 1)
         missed = self._prefix.window_tail_misses
         got = [], 0
         if match is not None:
@@ -921,6 +1065,8 @@ class PagedDecodePredictor(object):
         wtable = self._wtables.pop(slot, None)
         st = self._pending.pop(slot, None)
         self._first.pop(slot, None)
+        self._block_grown.pop(slot, None)
+        self._block_masked.pop(slot, None)
         if st is not None and st.snapshot is not None:
             self._prefix.unpin(st.snapshot)
         if wtable is not None:
@@ -938,6 +1084,7 @@ class PagedDecodePredictor(object):
         the copy succeeded, so a failed gather never loses pages."""
         slot = int(slot)
         self._refuse_window('save_stream')
+        self._refuse_blocks('save_stream')
         if slot in self._pending:
             raise RuntimeError('slot %d is still prefilling — requeue '
                                'it, there is nothing worth swapping'
@@ -970,6 +1117,7 @@ class PagedDecodePredictor(object):
         speculative override re-prefills its draft from it."""
         slot = int(slot)
         self._refuse_window('restore_stream')
+        self._refuse_blocks('restore_stream')
         if slot in self._tables:
             raise RuntimeError('slot %d already holds a stream — '
                                'release() it first' % slot)
@@ -1006,6 +1154,7 @@ class PagedDecodePredictor(object):
         LRU stamps are untouched."""
         self._refuse_recurrent('page shipping (export_prefix)')
         self._refuse_window('page shipping (export_prefix)')
+        self._refuse_blocks('page shipping (export_prefix)')
         prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
         digests, pages = self._prefix.chain(prompt,
                                             limit=len(prompt) - 1)
@@ -1025,7 +1174,7 @@ class PagedDecodePredictor(object):
         skips pages already here. Advisory (no quiesce, no LRU touch):
         install_prefix re-checks residency under the swap gate, so a
         racing eviction only costs wire bytes, never correctness."""
-        if self.recurrent or self._wpool is not None:
+        if self.recurrent or self._wpool is not None or self.block_tokens:
             return []           # such pages are worth nothing elsewhere
         prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
         digests, _ = self._prefix.chain(prompt, limit=len(prompt) - 1)
@@ -1047,6 +1196,7 @@ class PagedDecodePredictor(object):
         nothing taken when the pool cannot fit the fresh rows."""
         self._refuse_recurrent('page shipping (install_prefix)')
         self._refuse_window('page shipping (install_prefix)')
+        self._refuse_blocks('page shipping (install_prefix)')
         prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
         keys = list(keys)
         skip = int(skip)
@@ -1088,14 +1238,18 @@ class PagedDecodePredictor(object):
         from ..models.transformer import refuse_window
         refuse_window(self._pair.spec, what)
 
+    def _refuse_blocks(self, what):
+        from ..models.transformer import refuse_blocks
+        refuse_blocks(self._pair.spec, what)
+
     def prefix_report(self):
         """Drain the prefix cache's registered/evicted delta (the
         SRV_HEALTH payload feeding the fleet prefix directory). The
         directory is told nothing of pages that are a prefix only with
         a snapshot row of this predictor."""
         events = self._prefix.drain_events()
-        return {'new': [], 'evicted': []} \
-            if self.recurrent or self._wpool is not None else events
+        return {'new': [], 'evicted': []} if self.recurrent \
+            or self._wpool is not None or self.block_tokens else events
 
     @staticmethod
     def _rollback(cows, grows):
@@ -1135,15 +1289,31 @@ class PagedDecodePredictor(object):
         slot in `carry` feeds the lane that token there, so it can be
         dispatched behind the chunk with nothing fetched in between;
         first_token(handle) waits for it. A chunk that is not the last
-        returns None in both forms."""
+        returns None in both forms.
+
+        For a model that generates by diffusion over blocks the chunks
+        hold the prompt's whole blocks and the last has no token to hand
+        back: the call that ends the prefill (at once and with no
+        program run, where no whole block is left to compute) returns a
+        BlockStart in both forms (with return_logits: (BlockStart,
+        logits [vocab] of the chunk's last row, None where no chunk
+        ran))."""
         if defer and return_logits:
             raise ValueError('a deferred chunk hands back its token only')
         slot = int(slot)
         st = self._pending[slot]
         table = self._tables[slot]
         prompt, start = st.prompt, table.length
+        whole = self._prefilled(len(prompt))
         C, P, pt = self.prefill_chunk, self.pages_per_slot, self.page_tokens
-        n = min(C, len(prompt) - start)
+        if start >= whole:
+            # whole blocks alone are prefilled, and the cache shared them
+            # all (or the prompt is shorter than a block)
+            del self._pending[slot]
+            _prefill_chunks.observe(st.chunks)
+            out = BlockStart(slot, whole, prompt[whole:], False)
+            return (out, None) if return_logits else out
+        n = min(C, whole - start)
         cows, grows = [], []
         wtable = self._wtables.get(slot)
         with RecordEvent('paged.prefill.tables') as ev:
@@ -1232,7 +1402,7 @@ class PagedDecodePredictor(object):
                         self._prefix.register(prompt, table, wtable)
                 self._slide(wtable, table.length)
             self._update_gauges()
-            if table.length < len(prompt):
+            if table.length < whole:
                 return None
             if wtable is None and not self.recurrent:
                 with self._registering(prompt):
@@ -1253,6 +1423,12 @@ class PagedDecodePredictor(object):
                 self._update_gauges()
             del self._pending[slot]
             _prefill_chunks.observe(st.chunks)
+        if self.block_tokens:
+            out = BlockStart(slot, whole, prompt[whole:], True)
+            if return_logits:
+                with RecordEvent('paged.prefill.fetch'):
+                    return out, self._fetch(logits)[0]
+            return out
         if defer:
             first = self._first[slot] = _FirstToken(slot, ids)
             return first
@@ -1313,6 +1489,7 @@ class PagedDecodePredictor(object):
         step in flight is as it was, still to be collected, and a
         deferred chunk's token is still to be taken or fetched."""
         S, P, pt = self.slots, self.pages_per_slot, self.page_tokens
+        self._refuse_blocks('decode_step')
         overlapped = self._in_flight
         if overlapped and not defer:
             raise RuntimeError('a deferred decode step is in flight — '
@@ -1448,6 +1625,186 @@ class PagedDecodePredictor(object):
                 return self._fetch(ids), self._fetch(logits)
             return self._fetch(ids)
 
+    def block_step(self, tokens, starts, transfer, lanes, carry=(),
+                   commit=(), return_logits=False, defer=False):
+        """One pass over a block of every lane in `lanes`, for a model
+        that generates by diffusion over blocks (module docstring,
+        "Blocks"): tokens [slots, B] (each lane's block as the host
+        holds it: fixed tokens, the mask id elsewhere; not read for a
+        lane in `carry`, which takes the ids the step before left on the
+        device behind its unmasking), starts [slots] (where each lane's
+        block starts: its stream's committed length, a multiple of B),
+        transfer [slots] (the masked rows this pass unmasks, at most).
+        A lane in `commit` has no row masked: its pass's K/V rows are
+        the ones that count, and its stream's length grows by B; every
+        other lane's rows are overwritten by its next pass. A lane
+        left out of `lanes` keeps its pages as they are.
+
+        A lane's first pass over a block grows its table to the block's
+        end and forks the page the block lands on if the stream shares
+        it (the page copy program, in front of this step's: once a
+        block). If ANY lane cannot grow, the step runs nothing, this
+        call's allocations and forks are rolled back, and
+        CacheExhaustedError(slots=[...]) names the victims, as
+        decode_step does.
+
+        Returns (ids [slots, B] behind this pass's unmasking, masked
+        [slots]: rows still masked), with return_logits also logits
+        [slots, B, vocab] (row i scores the token at position i of the
+        block). With `defer=True` (not under low_confidence_dynamic:
+        `block_defers`) the step is dispatched and what comes back is
+        the pair of the deferred step BEFORE it, or None: a one-deep
+        pipeline, as decode_step's."""
+        S, P, pt = self.slots, self.pages_per_slot, self.page_tokens
+        B = self.block_tokens
+        if not B:
+            raise RuntimeError('block_step on a model that decodes one '
+                               'token a lane a step')
+        overlapped = self._in_flight
+        if overlapped and not defer:
+            raise RuntimeError('a deferred block step is in flight — '
+                               'collect() it before a synchronous one')
+        if defer and (return_logits or not self.block_defers):
+            raise ValueError('a deferred block step hands back ids only, '
+                             'and not under low_confidence_dynamic')
+        carry = [int(s) for s in carry]
+        commit = [int(s) for s in commit]
+        with RecordEvent('paged.decode.tables') as ev:
+            # copies: the caller packs its next pass into the same
+            # arrays while this one may still be on its way to the device
+            tokens = np.array(tokens, np.int64).reshape(S, B, 1)
+            starts = np.asarray(starts, np.int32).reshape(S)
+            table_feed = np.zeros((S, P), np.int32)
+            pos_feed = np.zeros((S, B), np.int32)
+            end_feed = np.zeros((S,), np.int32)
+            live_feed = np.zeros((S,), np.int32)
+            # a lane that sits the pass out keeps the ids it left on the
+            # device: it is carried through, with nothing to unmask
+            carry_feed = np.ones((S,), np.int32)
+            transfer_feed = np.array(transfer, np.int32).reshape(S)
+            cows, grows, failed, live, grown = [], [], [], [], []
+            for slot in sorted(map(int, lanes)):
+                if slot in self._pending:
+                    continue          # mid-prefill: stays on null pages
+                table = self._tables[slot]
+                start = int(starts[slot])
+                if start % B or start != table.length:
+                    raise ValueError(
+                        'slot %d: a block at %d over %d committed tokens'
+                        % (slot, start, table.length))
+                if self._block_grown.get(slot) != start:
+                    try:
+                        before = len(table.pages)
+                        pair = table.cow_for_append(start)
+                        if pair is not None:
+                            cows.append((table, table.index(start), pair))
+                        table.ensure(start + B)
+                        if len(table.pages) > before:
+                            grows.append((table, before))
+                    except CacheExhaustedError:
+                        failed.append(slot)
+                        continue
+                    grown.append((slot, start))
+                table.row(table_feed[slot])
+                pos_feed[slot] = start + np.arange(B, dtype=np.int32)
+                end_feed[slot] = start + B - 1
+                live.append(slot)
+            if failed:
+                self._rollback(cows, grows)
+                self._update_gauges()
+                raise CacheExhaustedError(
+                    'KV page pool exhausted for slot(s) %s'
+                    % ','.join(map(str, failed)), slots=failed)
+            self._block_grown.update(grown)
+            live_feed[live] = 1
+            carry_feed[[s for s in live if s not in carry]] = 0
+            # rows still masked going in, as the host knows them: a
+            # fresh block's by its ids, a carried one's by the schedule
+            # (or, behind a synchronous step, by the device's count)
+            going_in = {
+                slot: self._block_masked.get(slot, 0) if slot in carry
+                else int(np.sum(tokens[slot] == self.block_mask_id))
+                for slot in live}
+            masked_in = sum(going_in.values())
+            commit = [slot for slot in commit if slot in live]
+            ev.attrs['pages_read'] = pages_read = \
+                sum(int(end_feed[slot]) // pt + 1 for slot in live)
+            ev.attrs['overlapped'] = int(overlapped)
+            ev.attrs['carried'] = len(carry)
+            ev.attrs['block_rows'] = len(live) * B
+            ev.attrs['live_tokens'] = live_tokens = int(sum(
+                int(end_feed[slot]) + 1 for slot in live))
+            ev.attrs['masked_rows'] = masked_in
+            ev.attrs['commit_lanes'] = len(commit)
+            _decode_pages_read.inc(pages_read)
+            feed = {'block_tokens': tokens,
+                    'block_prev_ids': self._last_block[0],
+                    'block_carry': carry_feed,
+                    'block_positions': pos_feed,
+                    'block_ends': end_feed,
+                    'block_page_table': table_feed,
+                    'block_live': live_feed,
+                    'block_transfer': transfer_feed}
+        if not self._copy_compiled:
+            # as in front of the first decode step: the copy program
+            # compiles where the step's program does
+            with RecordEvent('paged.cow.compile'):
+                self._copy_pages(())
+            self._copy_compiled = True
+        self._fork_pages(cows)
+        out = self._exe.run(self._pair.decode_program, feed=feed,
+                            fetch_list=self._pair.decode_fetches,
+                            scope=self._scope, return_numpy=False)
+        logits, ids, left = out[0], out[1], out[-1]
+        if len(out) > 3:
+            self._moe_queue.append((True, out[2]))
+            self.moe_counters(leave=64)
+        with RecordEvent('paged.decode.book'):
+            for table, _idx, (src, _dst) in cows:
+                table.pool.unref(src)
+            for slot in live:
+                # the rows still masked behind this pass, by the schedule
+                # (exact under the static rules)
+                self._block_masked[slot] = max(
+                    0, going_in[slot] - int(transfer_feed[slot]))
+            for slot in commit:
+                self._tables[slot].length = int(starts[slot]) + B
+                del self._block_grown[slot], self._block_masked[slot]
+            st = self._block_stats
+            st['steps'] += 1
+            st['passes'] += len(live)
+            st['commits'] += len(commit)
+            st['rows'] += len(live) * B
+            st['masked_rows'] += masked_in
+            st['live_tokens'] += live_tokens
+            _block_passes.inc(len(live))
+            _block_commits.inc(len(commit))
+            _block_rows.inc(len(live) * B)
+            _block_masked_rows.inc(masked_in)
+            _effective_tokens.set(B * st['commits'] / st['steps'])
+            self._update_gauges()
+        prev, self._last_block, self._in_flight = \
+            self._last_block, (ids, left), defer
+        with RecordEvent('paged.decode.fetch'):
+            if defer:
+                return (self._fetch(prev[0]), self._fetch(prev[1])) \
+                    if overlapped else None
+            ids, left = self._fetch(ids), self._fetch(left)
+            for slot in live:
+                if slot not in commit:
+                    self._block_masked[slot] = int(left[slot])
+            if return_logits:
+                return ids, left, self._fetch(logits).reshape(
+                    S, B, self.vocab)
+            return ids, left
+
+    def block_stats(self):
+        """Lane-passes, commits, steps, rows carried, rows that went in
+        masked and the tokens the passes' lanes held (committed and the
+        block's own), since construction (cumulative, like
+        `fetch_wait_s`: a reset() leaves them)."""
+        return dict(self._block_stats)
+
     @property
     def in_flight(self):
         """True while a deferred step's ids have not been fetched."""
@@ -1456,11 +1813,14 @@ class PagedDecodePredictor(object):
     def collect(self):
         """Fetch the ids [slots] of the deferred step in flight without
         dispatching another (the last step of a burst, a drain, an
-        error path); None if none is. Not a step: no tables, no run."""
+        error path); None if none is. Not a step: no tables, no run.
+        A deferred block step's (ids [slots, B], masked [slots])."""
         if not self._in_flight:
             return None
         self._in_flight = False
         with RecordEvent('paged.decode.fetch'):
+            if self.block_tokens:
+                return tuple(self._fetch(a) for a in self._last_block)
             return self._fetch(self._last_ids)
 
     def prefill(self, prompts, slot_ids, return_logits=False):
@@ -1471,6 +1831,7 @@ class PagedDecodePredictor(object):
         if not prompts or len(prompts) != len(slot_ids):
             raise ValueError('%d prompts for %d slots'
                              % (len(prompts), len(slot_ids)))
+        self._refuse_blocks('prefill() (a first token a prompt)')
         out_ids = np.zeros((len(prompts),), np.int64)
         out_logits = []
         for i, (prompt, slot) in enumerate(zip(prompts, slot_ids)):
@@ -1494,6 +1855,9 @@ class PagedDecodePredictor(object):
     def generate(self, prompt, max_new_tokens, eos_id=None, slot=0):
         """Solo greedy generation on one slot (the benchmark / parity
         path; real traffic goes through ServingEngine)."""
+        if self.block_tokens:
+            return self._generate_blocks(prompt, max_new_tokens, eos_id,
+                                         int(slot))
         ids = self.prefill([prompt], [slot])
         tok = int(ids[0])
         out = [tok]
@@ -1507,3 +1871,35 @@ class PagedDecodePredictor(object):
             out.append(tok)
             pos += 1
         return out
+
+    def _generate_blocks(self, prompt, max_new_tokens, eos_id, slot):
+        """generate() for a model that generates by diffusion over
+        blocks: block after block, each passed over until no row is
+        masked and then committed; the tokens past eos_id or the budget
+        are dropped."""
+        if slot in self._tables:
+            self.release(slot)
+        self.open_stream(slot, prompt)
+        start = None
+        while start is None:
+            start = self.prefill_step(slot)
+        B, S = self.block_tokens, self.slots
+        tokens = np.zeros((S, B), np.int64)
+        starts = np.zeros((S,), np.int32)
+        transfer = np.zeros((S,), np.int32)
+        blk = self.new_block(start.start, start.tail)
+        out = []
+        while True:
+            n, commit = blk.plan(self.block_schedule)
+            tokens[slot], starts[slot], transfer[slot] = blk.ids, blk.start, n
+            ids, left = self.block_step(tokens, starts, transfer, [slot],
+                                        commit=[slot] if commit else ())
+            blk.ids = [int(t) for t in ids[slot]]
+            if not commit:
+                blk.passed(n, left[slot])
+                continue
+            for tok in blk.ids[blk.fixed:]:
+                out.append(tok)
+                if len(out) >= max_new_tokens or tok == eos_id:
+                    return out
+            blk = self.new_block(blk.start + B)

@@ -409,8 +409,19 @@ class PrefixCache(object):
     LEAF (no children, no tails) so interior chain pages are never
     orphaned while still reachable."""
 
-    def __init__(self, pool, snapshot_rows=0, window_pool=None, window=0):
+    def __init__(self, pool, snapshot_rows=0, window_pool=None, window=0,
+                 block=0):
         self.pool = pool
+        # a model that generates by diffusion over blocks of `block`
+        # tokens: a token's K/V depends on the later tokens of its
+        # block, so a prefix is handed out (match) and registered
+        # (register) at boundaries that are whole blocks only. Every
+        # whole page is one (the block divides a page); the partly
+        # filled last page is what the two guards are for
+        self.block = int(block)
+        if self.block and pool.page_tokens % self.block:
+            raise ValueError('pages of %d tokens do not hold whole blocks '
+                             'of %d' % (pool.page_tokens, self.block))
         # a model with sliding layers: their pool, and the tokens a row
         # of theirs sees (match_window / register's second table)
         self.window_pool, self.window = window_pool, int(window)
@@ -467,6 +478,8 @@ class PrefixCache(object):
         rest = tuple(int(t) for t in rest)
         best = None
         for tail_tokens, tail in self._tails.get(chain, {}).items():
+            if self.block and len(tail_tokens) % self.block:
+                continue        # no boundary of whole blocks
             if rest[:len(tail_tokens)] == tail_tokens and \
                     (best is None or len(tail_tokens) > len(best.tokens)):
                 best = tail
@@ -482,6 +495,8 @@ class PrefixCache(object):
         no refs."""
         pt = self.pool.page_tokens
         limit = len(prompt) if limit is None else min(limit, len(prompt))
+        if self.block:
+            limit -= limit % self.block
         full = limit // pt
         pages, chain = [], b''
         for chain, node in self._resident(prompt, full):
@@ -620,6 +635,8 @@ class PrefixCache(object):
         that the stream still holds for it (the last `window` tokens'
         pages), marked shared there in the same way."""
         pt = self.pool.page_tokens
+        if self.block:
+            prompt = prompt[:len(prompt) - len(prompt) % self.block]
         full = len(prompt) // pt
         chain = b''
         newly_shared = []

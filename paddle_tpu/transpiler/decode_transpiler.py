@@ -39,7 +39,8 @@ save_inference_model (see _recover_param_specs).
 What a spec's layers keep for a stream decides what can serve it, not
 its family's name: speculative decoding and mesh serving refuse
 recurrent state (refuse_recurrent), latent pages
-(refuse_latent_pages) and sliding layers' second table (refuse_window). Genuinely unsupported layouts (the training MoE
+(refuse_latent_pages) and sliding layers' second table (refuse_window), and
+both refuse a model whose step is a block of rows (refuse_blocks). Genuinely unsupported layouts (the training MoE
 op moe_ffn, whose capacity drops tokens; ring attention; a constraint
 on an axis the serving mesh cannot honor) still raise
 DecodeTranspileError naming the offending op/axis — better a loud
@@ -50,14 +51,15 @@ from __future__ import annotations
 
 from .. import models
 from ..models.transformer import (DecodeSpec, DecodeTranspileError,
-                                  refuse_latent_pages, refuse_recurrent,
+                                  refuse_blocks, refuse_latent_pages,
+                                  refuse_recurrent,
                                   refuse_window, build_page_copy_program,
                                   build_state_copy_programs,
                                   build_verify_program, snapshot_names)
 
 __all__ = ['DecodeTranspileError', 'PagedDecodePair', 'SpecDecodePair',
            'DecodeTranspiler', 'extract_decode_spec', 'refuse_recurrent',
-           'refuse_latent_pages', 'refuse_window']
+           'refuse_latent_pages', 'refuse_window', 'refuse_blocks']
 
 
 class PagedDecodePair(object):
@@ -482,6 +484,7 @@ class DecodeTranspiler(object):
         refuse_latent_pages(extract_decode_spec(program),
                             'speculative decoding')
         refuse_window(extract_decode_spec(program), 'speculative decoding')
+        refuse_blocks(extract_decode_spec(program), 'speculative decoding')
         target = self.transpile(program, slots=slots,
                                 page_tokens=page_tokens,
                                 kv_pages=kv_pages,

@@ -19,7 +19,11 @@ rows and the GPT family under speculation). The sixth family
 (smallthinker: two page tables) is held to the record of the PR that
 added it (tests/serving_jaxprs_pr49.json), and so is the seventh
 (solar_open2: the rule with a decay a key channel, with and without
-snapshot rows; tests/serving_jaxprs_pr52.json).
+snapshot rows; tests/serving_jaxprs_pr52.json), and the eighth (sdar_moe:
+generation by diffusion over blocks; its pair's second program is a
+block step, which `served` drives through block_step; with it the piece
+`paged_block_attention_4_rows`, the Pallas kernel as the block step's op
+calls it; tests/serving_jaxprs_pr57.json).
 """
 import hashlib
 import json
@@ -43,7 +47,8 @@ def _digest(text):
 
 def _models():
     from paddle_tpu.models import (axk1, granite_h, hybrid, nemotron_h,
-                                   smallthinker, solar_open2, transformer)
+                                   sdar_moe, smallthinker, solar_open2,
+                                   transformer)
     return {
         'gpt2': (transformer.language_model_logits,
                  transformer.TransformerConfig(
@@ -71,6 +76,10 @@ def _models():
                             vocab=64, dim=32, max_len=T, head_dim=8,
                             key_dim=8, value_dim=8, gate_rank=4,
                             expert_offset=4, experts_held=8)),
+        'sdar_moe': (sdar_moe.language_model_logits,
+                     sdar_moe.SdarMoeConfig(
+                         vocab=64, dim=32, max_len=T, head_dim=8,
+                         expert_offset=4, experts_held=8)),
     }
 
 
@@ -113,6 +122,18 @@ def served(name, **deployment):
     with tempfile.TemporaryDirectory() as tmp:
         dec = _predictor(logits_fn, cfg, tmp).prepare_decoding(
             **dict(GEOMETRY, **deployment))
+    if dec.block_tokens:
+        # whole blocks of the prompt, then one pass over the first block
+        dec.open_stream(1, np.arange(1, 12))
+        start = None
+        while start is None:
+            start = dec.prefill_step(1)
+        blk = dec.new_block(start.start, start.tail)
+        ids = np.zeros((dec.slots, dec.block_tokens), np.int64)
+        starts = np.zeros(dec.slots, np.int32)
+        ids[1], starts[1] = blk.ids, blk.start
+        dec.block_step(ids, starts, starts * 0 + 1, [1])
+        return program_digests(dec)
     dec.prefill([np.arange(1, 12)], [1])
     tokens = np.zeros(dec.slots, np.int64)
     positions = np.zeros(dec.slots, np.int32)
@@ -154,6 +175,12 @@ def pieces():
                     np.zeros((40, 16, kvh, 128), f4),
                     np.zeros((40, 16, kvh, 128), f4),
                     np.zeros((4, 8), np.int32), np.zeros(4, np.int32))))
+    out['paged_block_attention_4_rows'] = _digest(str(jax.make_jaxpr(
+        lambda *a: pa.paged_attention(
+            *a, sm_scale=0.0883883461356163, name='paged_block_attention'))(
+                np.zeros((4, 128, 128), f4), np.zeros((40, 16, 4, 128), f4),
+                np.zeros((40, 16, 4, 128), f4), np.zeros((4, 8), np.int32),
+                np.zeros(4, np.int32))))
     return out
 
 

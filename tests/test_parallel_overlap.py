@@ -464,6 +464,33 @@ def test_kda_chunk_of_the_solar_cell_compiles_for_v5e(v5e_2x2):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def test_block_attention_of_the_diffusion_cell_compiles_for_v5e(v5e_2x2):
+    """The block step's attention at the cell's shape (32 lanes, 4 rows
+    x 32 query heads = 128 rows a lane on 4 K/V heads of 128, a table of
+    96 pages of 16 tokens over 3073): the rows folded into the head
+    groups of pallas/paged_attention.py's kernel, lowered by Mosaic
+    under its own name and compiled by the installed TPU compiler; it
+    keeps nothing beside its arguments."""
+    import functools
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.pallas import paged_attention as pa
+    one = SingleDeviceSharding(v5e_2x2[0])
+
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    pool = arg(3073, 16, 4, 128)
+    compiled = jax.jit(functools.partial(
+        pa.paged_attention, sm_scale=128 ** -0.5,
+        name='paged_block_attention')).lower(
+            arg(32, 128, 128), pool, pool, arg(32, 96, dtype=jnp.int32),
+            arg(32, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert 'tpu_custom_call' in text and 'paged_block_attention' in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 # the five expert cells' decode programs: tools/moe_experts_arms.CELLS
 _EXPERTS = ('axk1', 'granite4hs', 'nemo3s', 'solar2', 'sthink21b')
 

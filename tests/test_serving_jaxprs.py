@@ -29,6 +29,7 @@ RECORDED = _recorded('serving_jaxprs_pr44.json')
 RECORDED_PR47 = _recorded('serving_jaxprs_pr47.json')
 RECORDED_PR49 = _recorded('serving_jaxprs_pr49.json')
 RECORDED_PR52 = _recorded('serving_jaxprs_pr52.json')
+RECORDED_PR57 = _recorded('serving_jaxprs_pr57.json')
 
 
 @pytest.mark.parametrize('name', ['gpt2', 'hybrid', 'nemotron_h', 'axk1'])
@@ -38,7 +39,10 @@ def test_served_with_no_snapshot_rows_a_model_traces_as_before(name):
 
 def test_the_shared_pieces_trace_as_before():
     got = serving_jaxprs.pieces()
-    assert got == {k: RECORDED[k] for k in got}
+    new = {'paged_block_attention_4_rows'}      # PR 57's, held below
+    assert {k: v for k, v in got.items() if k not in new} \
+        == {k: RECORDED[k] for k in got if k not in new}
+    assert {k: got[k] for k in new} == {k: RECORDED_PR57[k] for k in new}
 
 
 def test_the_fifth_family_traces_as_before():
@@ -58,6 +62,24 @@ def test_the_seventh_family_traces_as_recorded():
     added it (PR 52)."""
     assert serving_jaxprs.served('solar_open2') == \
         RECORDED_PR52['solar_open2']
+
+
+def test_the_eighth_family_traces_as_recorded():
+    """sdar_moe (generation by diffusion over blocks: a prefill chunk
+    masked by block, the page copy, the block step with its unmasking
+    behind the head): the record of the PR that added it (PR 57)."""
+    assert serving_jaxprs.served('sdar_moe') == RECORDED_PR57['sdar_moe']
+
+
+def test_the_older_families_trace_as_the_parent_of_the_eighth():
+    """PR 57's record was written on its finished tree and holds every
+    family: what it says of the seven older ones is what their own
+    records say (nothing the other cells run changed its text)."""
+    older = dict(RECORDED_PR47, **{k: RECORDED_PR49[k] for k in RECORDED_PR49})
+    older.update(RECORDED_PR52)
+    new = {'sdar_moe', 'paged_block_attention_4_rows'}
+    assert {k: v for k, v in RECORDED_PR57.items() if k not in new} \
+        == {k: older[k] for k in RECORDED_PR57 if k not in new}
 
 
 @pytest.mark.parametrize('key', sorted(serving_jaxprs.DEPLOYED))
