@@ -179,11 +179,12 @@ register_vjp_grad('moe_aux_loss', in_slots=('Gate',))
 # What is read of the held stack follows the rows (`_held_part`): a
 # prefill chunk's 256 rows touch every held expert, and every row goes
 # through every held expert in one batched product that reads the stack
-# once whatever was chosen (`held_experts`); a decode step's rows (at
-# most pallas/moe_experts.STEP_ROWS) choose a quarter to nine tenths of
-# them, and on a TPU a kernel reads the chosen experts' tiles straight
-# out of the stack and no other (pallas/moe_experts.py). The sum is the
-# same: a skipped expert's term was 0 times a finite number.
+# once whatever was chosen (`held_experts`); a step's rows (the slots, or
+# slots x a block's rows: at most pallas/moe_experts.STEP_ROWS) choose a
+# quarter to nine tenths of them, and on a TPU a kernel reads the chosen
+# experts' tiles straight out of the stack and no other
+# (pallas/moe_experts.py). The sum is the same: a skipped expert's term
+# was 0 times a finite number.
 
 def _within_kept_groups(b, n_group, topk_group):
     """b [R, E] with the scores outside each row's `topk_group` best
@@ -332,19 +333,23 @@ def _moe_experts_emit(ctx, op):
 
 def _held_part(lat, w, w1, w3, w2, act):
     """The held experts' sum for lat [R, L] and w [R, held]. On a TPU
-    (or under FLAGS_pallas_interpret) a decode step's rows (`STEP_ROWS`
-    of pallas/moe_experts.py, by the op's static row count alone) take
-    the kernel there, which reads only the experts a row chose, any(w
-    != 0) by column: the expression Stats counts, so the op's
-    experts_touched is the number of experts read. A chunk's rows, and
-    every row elsewhere, take the batched product over the whole stack.
-    Which of the two an emission took is counted in
-    ops.moe_experts.kernel / ops.moe_experts.fallback."""
+    (or under FLAGS_pallas_interpret) a step's rows (a decode step's
+    slots or a block step's slots x B, up to `STEP_ROWS` of
+    pallas/moe_experts.py where the walk's blocks fit the kernel's
+    memory: by the op's static shapes alone) take the kernel there,
+    which reads only the experts a row chose, any(w != 0) by column: the
+    expression Stats counts, so the op's experts_touched is the number
+    of experts read. A chunk's rows, and every row elsewhere, take the
+    batched product over the whole stack. Which of the two an emission
+    took is counted in ops.moe_experts.kernel /
+    ops.moe_experts.fallback."""
     from ..pallas import moe_experts as _me
     from ..flags import get_flag
     from ..obs import telemetry
     on_tpu = jax.default_backend() == 'tpu'
-    if _me.step_supported(lat.shape[0], lat.shape[1], w1.shape[2]) and (
+    if _me.step_supported(lat.shape[0], lat.shape[1], w1.shape[2],
+                          w1.shape[0], 2 if w3 is None else 3,
+                          w1.dtype.itemsize) and (
             on_tpu or bool(get_flag('pallas_interpret'))):
         telemetry.counter('ops.moe_experts.kernel').inc()
         ids, n = _me.touched_ids(jnp.any(w != 0, axis=0))

@@ -1,9 +1,11 @@
-"""A decode step's rows through the held experts they chose, in Pallas,
-for TPU: the experts at least one row chose are read straight out of
-the held stack, tile by tile, and the others are never touched (the op
-is `moe_experts`, ops/moe_ops.py; `held_experts` / `held_gated_experts`
+"""A step's rows through the held experts they chose, in Pallas, for TPU:
+the experts at least one row chose are read straight out of the held
+stack, tile by tile, and the others are never touched (the op is
+`moe_experts`, ops/moe_ops.py; `held_experts` / `held_gated_experts`
 there are the same sum as one batched product over the whole stack, the
-reference, the CPU's path and what a prefill chunk's rows take).
+reference, the CPU's path and what a prefill chunk's rows take). A
+step's rows are the engine's slots, one row a lane, or slots x B where a
+lane's step is a block of B rows (`STEP_ROWS`).
 
 lat [R, L] are the rows, w [R, held] a row's weight for each held
 expert (0 where it did not choose it), W1 and W3 [held, L, F], W2
@@ -41,14 +43,23 @@ from .latent_prefill import _traced_from_nowhere
 __all__ = ['moe_experts', 'touched_ids', 'step_supported', 'tile_width',
            'STEP_ROWS']
 
-# The most rows an op may have to take the kernel: a decode program's rows
-# are the engine's slots (32, 48, 64 in the benchmark's cells) and choose a
-# quarter to nine tenths of a chip's held experts; a prefill chunk has 256,
-# and 256 rows x 8 / 320 is 6.4 pairs an expert (11 at 22 of 512), so over
-# 99 % of the held experts are touched, there is nothing to skip, and the
-# batched product reads the whole stack at 90 % of the HBM peak. One
-# algorithm on two regimes that the op's static shape tells apart.
-STEP_ROWS = 64
+# The most rows an op may have to take the kernel: a step program's rows
+# are the engine's slots (32, 48, 64 in the benchmark's decode programs) or
+# slots x a block's rows (32 x 4 where a lane's step is a block of 4: its
+# masked rows share an embedding and route alike) and choose a quarter to
+# nine tenths of a chip's held experts; a prefill chunk has 256, and 256
+# rows x 8 / 320 is 6.4 pairs an expert (11 at 22 of 512), so over 99 % of
+# the held experts are touched, there is nothing to skip, and the batched
+# product reads the whole stack at 90 % of the HBM peak. One algorithm on
+# two regimes that the op's static shape tells apart. Under it the bound
+# is the kernel's memory (`step_supported`): the rows, the accumulator and
+# the output are whole [rows, L] blocks beside the stack's tiles.
+STEP_ROWS = 128
+
+# what the kernel may ask of VMEM (a v5e core has 128 MiB), and the room
+# left above `_vmem_bytes`' count for what the compiler lays out itself
+_VMEM_CAP = 100 << 20
+_VMEM_ROOM = 8 << 20
 
 # bytes of the stack's tiles in flight (each matrix's tile, double-buffered):
 # a tile of several MB amortises a grid step's fixed cost, and the rows'
@@ -85,12 +96,18 @@ def tile_width(L, F, matrices, itemsize=4):
     return max(fits, default=128)
 
 
-def step_supported(rows, L, F):
-    """Shapes the kernel takes: a decode step's rows (see `STEP_ROWS`), a
-    whole number of sublanes of them, and widths that are whole lane
-    rows."""
-    return 0 < rows <= STEP_ROWS and rows % 8 == 0 and L % 128 == 0 \
-        and F % 128 == 0
+def step_supported(rows, L, F, held, matrices, itemsize=4):
+    """Shapes the kernel takes: a step's rows (see `STEP_ROWS`), a whole
+    number of sublanes of them, widths that are whole lane rows, and a
+    walk of `held` experts of `matrices` matrices each whose blocks, at
+    the tile the widths give (`_vmem_bytes`), fit under the limit the
+    call sets."""
+    if not (0 < rows <= STEP_ROWS and rows % 8 == 0 and L % 128 == 0
+            and F % 128 == 0):
+        return False
+    tf = tile_width(L, F, matrices, itemsize)
+    return _vmem_bytes(rows, L, tf, held, matrices, itemsize) \
+        + _VMEM_ROOM <= _VMEM_CAP
 
 
 def touched_ids(touched):
@@ -201,7 +218,8 @@ def moe_experts(lat, w, ids, n, w1, w3, w2, act='silu', tile=None,
         # the accumulator is carried from step to step
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary', 'arbitrary'),
-            vmem_limit_bytes=min(100 << 20, max(32 << 20, need + (8 << 20)))),
+            vmem_limit_bytes=min(_VMEM_CAP,
+                                 max(32 << 20, need + _VMEM_ROOM))),
         interpret=pltpu.InterpretParams() if interpret else False,
         name='moe_experts')
     mats = (w1, w2) if w3 is None else (w1, w3, w2)

@@ -1,12 +1,14 @@
-"""The kernel a decode step's rows take through the held experts
+"""The kernel a step's rows take through the held experts
 (pallas/moe_experts.py) against the batched product over the whole
 stack (ops/moe_ops.held_experts / held_gated_experts), in interpret mode
 on the CPU: the same sum whatever was touched, nothing read past the
-touched, and which rows the op `moe_experts` sends where."""
+touched, and which rows the op `moe_experts` sends where. A step's rows
+are a decode step's slots or a block step's slots x 4 (128 here)."""
 import base64
 import math
 import os
 import re
+import sys
 
 import numpy as np
 import pytest
@@ -20,9 +22,14 @@ from paddle_tpu.obs import telemetry
 from paddle_tpu.ops import moe_ops
 from paddle_tpu.pallas import moe_experts as me
 
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), 'tools'))
+import moe_experts_arms  # noqa: E402
+
 # (matrices an expert, act): Nemotron's relu^2 of one product, SwiGLU, ReGLU
 FORMS = {'relu2': (2, 'relu2'), 'silu': (3, 'silu'), 'relu': (3, 'relu')}
 ROWS, L, F, HELD = 16, 128, 256, 6
+BLOCK_ROWS = 128        # a block step's rows: 32 slots x 4
 TOUCHED = {'none': [], 'one': [3], 'some': [0, 2, 5],
            'all': list(range(HELD))}
 
@@ -57,16 +64,18 @@ def _product(lat, w, w1, w3, w2, act):
     return moe_ops.held_gated_experts(lat, w, w1, w3, w2, act)
 
 
+@pytest.mark.parametrize('rows', [ROWS, BLOCK_ROWS])
 @pytest.mark.parametrize('touched', list(TOUCHED))
 @pytest.mark.parametrize('form', list(FORMS))
-def test_the_kernel_is_the_batched_product(form, touched):
+def test_the_kernel_is_the_batched_product(form, touched, rows):
     """Both forms and the three activations, with 0, 1, some and all of
-    the held experts touched, one tile an expert and two."""
+    the held experts touched, one tile an expert and two, at a decode
+    step's rows and at a block step's."""
     matrices, act = FORMS[form]
     rng = np.random.default_rng(len(touched) + matrices)
-    lat = jnp.asarray(rng.normal(size=(ROWS, L)), jnp.float32)
+    lat = jnp.asarray(rng.normal(size=(rows, L)), jnp.float32)
     w1, w3, w2 = _stack(rng, matrices)
-    w = _choices(rng, ROWS, HELD, TOUCHED[touched])
+    w = _choices(rng, rows, HELD, TOUCHED[touched])
     ids, n = me.touched_ids(jnp.any(w != 0, axis=0))
     assert int(n[0]) == len(TOUCHED[touched])
     want = np.asarray(_product(lat, w, w1, w3, w2, act))
@@ -93,13 +102,14 @@ def test_the_touched_come_first_in_rising_order(touched, ids, n):
     assert got_ids.dtype == jnp.int32 and got_n.dtype == jnp.int32
 
 
-def test_an_untouched_expert_is_never_read(interpret_kernel):
+@pytest.mark.parametrize('rows', [ROWS, BLOCK_ROWS])
+def test_an_untouched_expert_is_never_read(interpret_kernel, rows):
     """NaNs in the experts no row chose do not reach the result: their
     tiles are skipped, not multiplied by zero."""
     rng = np.random.default_rng(5)
-    lat = jnp.asarray(rng.normal(size=(ROWS, L)), jnp.float32)
+    lat = jnp.asarray(rng.normal(size=(rows, L)), jnp.float32)
     w1, w3, w2 = _stack(rng, 3)
-    w = _choices(rng, ROWS, HELD, [1, 4])
+    w = _choices(rng, rows, HELD, [1, 4])
     ids, n = me.touched_ids(jnp.any(w != 0, axis=0))
     want = np.asarray(moe_ops.held_gated_experts(lat, w, w1, w3, w2))
     dead = jnp.asarray([0, 2, 3, 5])
@@ -123,12 +133,43 @@ def test_tiles_follow_from_the_widths(L_, F_, matrices, tile):
 
 
 def test_only_a_steps_rows_are_supported():
-    assert me.step_supported(48, 4096, 1280)
-    assert me.step_supported(me.STEP_ROWS, 1024, 2688)
-    assert not me.step_supported(256, 4096, 1280)       # a chunk's rows
-    assert not me.step_supported(me.STEP_ROWS + 8, 1024, 2688)
-    assert not me.step_supported(12, 4096, 1280)        # no whole sublanes
-    assert not me.step_supported(48, 16, 20)            # a tiny model
+    assert me.step_supported(48, 4096, 1280, 10, 3)
+    assert me.step_supported(64, 1024, 2688, 64, 2)
+    assert me.step_supported(me.STEP_ROWS, 1024, 2688, 64, 2)
+    assert not me.step_supported(256, 4096, 1280, 10, 3)    # a chunk's rows
+    assert not me.step_supported(me.STEP_ROWS + 8, 1024, 2688, 64, 2)
+    assert not me.step_supported(12, 4096, 1280, 10, 3)     # no whole sublanes
+    assert not me.step_supported(48, 16, 20, 6, 3)          # a tiny model
+
+
+@pytest.mark.parametrize('cell', sorted(moe_experts_arms.CELLS))
+def test_every_cells_step_takes_the_kernel(cell):
+    """The five decode steps (32-64 rows) and SDAR's block step (32 x 4)
+    at float32, what the cells hold: a "no" here is a cell back on the
+    product."""
+    rows, L_, F_, held, matrices, _, _ = moe_experts_arms.CELLS[cell]
+    assert me.step_supported(rows, L_, F_, held, matrices)
+
+
+@pytest.mark.parametrize('rows,L_,F_,held,matrices,takes', [
+    (128, 2048, 768, 16, 3, True),      # SDAR's block step: 32 slots x 4
+    (136, 2048, 768, 16, 3, False),     # past a step's rows
+    (128, 7168, 2048, 8, 3, True),      # A.X-K1's widths would still fit
+    (128, 1024, 2688, 64, 2, True),
+    (64, 16384, 128, 8, 3, True),       # one lane row a tile, and it fits
+    (128, 16384, 128, 8, 3, False),     # the rows' blocks no longer do
+    (128, 32768, 128, 8, 2, False),
+])
+def test_a_steps_rows_are_bounded_by_the_kernels_memory(rows, L_, F_, held,
+                                                        matrices, takes):
+    """Up to `STEP_ROWS` the bound is what the walk keeps in VMEM at the
+    tile the widths give, against the limit the call sets: no bare
+    constant, and the same count `moe_experts` hands the compiler."""
+    assert me.step_supported(rows, L_, F_, held, matrices) is takes
+    need = me._vmem_bytes(rows, L_, me.tile_width(L_, F_, matrices), held,
+                          matrices, 4)
+    if rows <= me.STEP_ROWS:
+        assert (need + me._VMEM_ROOM <= me._VMEM_CAP) is takes
 
 
 # -- the op: which rows go where, and what it counts --------------------------
@@ -234,6 +275,35 @@ def test_a_chunks_rows_take_the_product_and_a_steps_the_kernel(
     fluid.set_flags({'pallas_interpret': False})
     _run_op(_op_case(48))
     assert took() == {'kernel': 1, 'fallback': 2}
+
+
+@pytest.mark.parametrize('form', ['relu2', 'silu'])
+def test_a_block_steps_rows_take_the_kernel_and_sum_alike(form, took,
+                                                          interpret_kernel):
+    """[lanes, 4] rows with Live [lanes], a lane's flag for its four
+    rows: 32 lanes (128 rows) take the kernel and 64 lanes (256, a
+    chunk's count) the product; at 128 the kernel's sum and Stats are
+    the product's, and a dead lane's rows are zeros."""
+    matrices, act = FORMS[form]
+    case = _op_case(BLOCK_ROWS, matrices=matrices, seed=7 + matrices)
+    for name in ('x', 'lat'):
+        case[name] = case[name].reshape(32, 4, -1)
+    live = (np.arange(32) % 3 != 0).astype('i4')
+    got, got_stats = _run_op(case, live=live, act=act)
+    assert took() == {'kernel': 1, 'fallback': 0}
+    wide = _op_case(256, matrices=matrices)
+    for name in ('x', 'lat'):
+        wide[name] = wide[name].reshape(64, 4, -1)
+    _run_op(wide, live=np.ones(64, 'i4'), act=act)
+    assert took() == {'kernel': 1, 'fallback': 1}
+    fluid.set_flags({'pallas_interpret': False})
+    want, want_stats = _run_op(case, live=live, act=act)
+    assert took() == {'kernel': 1, 'fallback': 2}
+    assert got.shape == (32, 4, L)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert got_stats.tolist() == want_stats.tolist()
+    assert 0 < got_stats[1] <= HELD
+    assert not got[live == 0].any() and got[live == 1].any()
 
 
 def test_no_live_row_gives_zeros(took, interpret_kernel):

@@ -491,13 +491,14 @@ def test_block_attention_of_the_diffusion_cell_compiles_for_v5e(v5e_2x2):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-# the five expert cells' decode programs: tools/moe_experts_arms.CELLS
-_EXPERTS = ('axk1', 'granite4hs', 'nemo3s', 'solar2', 'sthink21b')
+# the expert cells' step programs: tools/moe_experts_arms.CELLS (five decode
+# steps of 32-64 rows, and sdar30b's block step of 32 slots x 4 rows)
+_EXPERTS = ('axk1', 'granite4hs', 'nemo3s', 'sdar30b', 'solar2', 'sthink21b')
 
 
 @pytest.mark.parametrize('cell', _EXPERTS)
 def test_expert_kernel_of_a_decode_step_compiles_for_v5e(v5e_2x2, cell):
-    """A decode step's rows through the held stack at each cell's widths
+    """A step's rows through the held stack at each cell's widths
     (pallas/moe_experts.py): lowered by Mosaic and compiled by the
     installed TPU compiler with the tiles the widths give (the VMEM
     limit follows from them), the stack read where it lies: no
@@ -509,7 +510,7 @@ def test_expert_kernel_of_a_decode_step_compiles_for_v5e(v5e_2x2, cell):
     from paddle_tpu.pallas import moe_experts as me
     assert sorted(moe_experts_arms.CELLS) == list(_EXPERTS)
     rows, L, F, held, matrices, act, _ = moe_experts_arms.CELLS[cell]
-    assert me.step_supported(rows, L, F)
+    assert me.step_supported(rows, L, F, held, matrices)
     one = SingleDeviceSharding(v5e_2x2[0])
 
     def arg(*shape, dtype=jnp.float32):

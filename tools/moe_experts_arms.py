@@ -2,13 +2,14 @@
 process: the batched product over the whole held stack
 (ops/moe_ops.held_experts / held_gated_experts, what a prefill chunk's
 rows take and every row off a TPU) against the kernel that reads the
-touched experts only (pallas/moe_experts.py, what a decode step's rows
-take on a TPU), at the five serving cells' shapes, with every held
-expert touched and with the share of them a step of the cell touches
-(ledger, PR 53). Prints ms a call, the bytes read (the whole stack for
-the product, the touched experts for the kernel) as a share of the
-chip's HBM peak, and each arm's distance from the same sum at
-Precision.HIGHEST.
+touched experts only (pallas/moe_experts.py, what a step's rows take on
+a TPU), at the six expert cells' shapes (five decode steps of 32-64
+rows and SDAR's block step of 32 x 4), with every held expert touched
+and with the share of them a step of the cell touches (ledger, PRs 53
+and 57; SDAR's second share is its light load's). Prints ms a call, the
+bytes read (the whole stack for the product, the touched experts for the
+kernel) as a share of the chip's HBM peak, and each arm's distance from
+the same sum at Precision.HIGHEST.
 
     python tools/moe_experts_arms.py [--cells solar2,nemo3s] [--tiles 128,256]
         [--rows 16] [--quick]
@@ -27,17 +28,19 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 HBM_BYTES_S = 819e9     # one v5e chip (benchmarks/harness/peaks.py)
 
-# cell: (rows, L, F, held, matrices, act, share of the held experts a step
-# touches)
+# cell: (rows, L, F, held, matrices, act, the shares of the held experts a
+# step touches: the cell's, then a lighter load's where one was read)
 CELLS = {
-    'solar2': (48, 4096, 1280, 10, 3, 'silu', 0.235),
-    'nemo3s': (64, 1024, 2688, 64, 2, 'relu2', 0.654),
-    'axk1': (32, 7168, 2048, 8, 3, 'silu', 0.66),
-    'granite4hs': (64, 4096, 768, 9, 3, 'silu', 0.859),
-    'sthink21b': (64, 2560, 768, 64, 3, 'relu', 0.911),
+    'solar2': (48, 4096, 1280, 10, 3, 'silu', (0.235,)),
+    'nemo3s': (64, 1024, 2688, 64, 2, 'relu2', (0.654,)),
+    'axk1': (32, 7168, 2048, 8, 3, 'silu', (0.66,)),
+    'granite4hs': (64, 4096, 768, 9, 3, 'silu', (0.859,)),
+    'sthink21b': (64, 2560, 768, 64, 3, 'relu', (0.911,)),
+    'sdar30b': (128, 2048, 768, 16, 3, 'silu', (0.78, 0.53)),
 }
-QUICK = {'solar2': (16, 256, 256, 5, 3, 'silu', 0.4),
-         'nemo3s': (8, 128, 384, 6, 2, 'relu2', 0.5)}
+QUICK = {'solar2': (16, 256, 256, 5, 3, 'silu', (0.4,)),
+         'nemo3s': (8, 128, 384, 6, 2, 'relu2', (0.5,)),
+         'sdar30b': (32, 128, 256, 4, 3, 'silu', (0.75, 0.5))}
 
 
 def _weights(rng, rows, held, touched):
@@ -88,7 +91,7 @@ def main():
     for name in args.cells.split(','):
         if name not in cells:
             continue
-        rows, L, F, held, matrices, act, share = cells[name]
+        rows, L, F, held, matrices, act, shares = cells[name]
         rows = args.rows or rows
         lat = jnp.asarray(rng.normal(size=(rows, L)), jnp.float32)
         key = jax.random.PRNGKey(1)
@@ -112,8 +115,10 @@ def main():
             return me.moe_experts(x, w, ids, n, w1, w3, w2, act=act,
                                   tile=tile, interpret=args.quick)
 
-        for label, touched in (('all', held),
-                               ('cell', max(1, round(share * held)))):
+        loads = [('all', held)] + [
+            (label, max(1, round(share * held)))
+            for label, share in zip(('cell', 'light'), shares)]
+        for label, touched in loads:
             w = jnp.asarray(_weights(rng, rows, held, touched))
             with jax.default_matmul_precision('highest'):
                 exact = np.asarray(jax.jit(product)(lat, w, stack))
@@ -126,7 +131,7 @@ def main():
                 fn = jax.jit(fn)
                 err = np.abs(np.asarray(fn(lat, w, stack)) - exact).max()
                 ms = _ms(fn, lat, w, stack)
-                print('%-11s rows %d L %d F %d held %d  %-4s touched %2d  '
+                print('%-11s rows %d L %d F %d held %d  %-5s touched %2d  '
                       '%-14s %7.3f ms  %5.1f %% of the HBM peak  err %.1e'
                       % (name, rows, L, F, held, label, touched, arm, ms,
                          100 * read * expert_bytes / (ms / 1e3) / HBM_BYTES_S,
