@@ -19,7 +19,7 @@ from . import googlenet  # noqa: F401
 # names its family here, and the DecodeTranspiler looks the module up
 # by that name: a new family is a block file and a name in this tuple.
 SERVED_FAMILIES = ('hybrid', 'nemotron_h', 'axk1', 'granite_h',
-                   'smallthinker', 'solar_open2', 'sdar_moe')
+                   'smallthinker', 'solar_open2', 'sdar_moe', 'lfm2')
 
 
 def describe_served_model(program, family, cfg):

@@ -241,6 +241,19 @@ def refuse_window(spec, what):
             % (what, ','.join(map(str, spec.window_layers))))
 
 
+def refuse_page_state(spec, what):
+    """Raise for a spec whose recurrent layers keep their rows by the
+    page (spec.page_state_layers: models/lfm2.py) where `what` knows a
+    page as the K/V pools' alone."""
+    if spec.page_state_layers:
+        kinds = sorted({spec.kinds[i] for i in spec.page_state_layers})
+        raise DecodeTranspileError(
+            '%s cannot serve a model with %s layers (layers %s): their '
+            'rows lie in a pool of their own beside the K/V pools, a page '
+            'in each' % (what, ' and '.join(kinds),
+                         ','.join(map(str, spec.page_state_layers))))
+
+
 def refuse_blocks(spec, what):
     """Raise for a spec that generates by diffusion over blocks
     (spec.block_tokens: models/sdar_moe.py) where `what` steps a lane
@@ -299,6 +312,22 @@ class DecodeSpec(object):
     reads K/V head h // (heads / kv_heads)); the model's head count
     where it is not given.
 
+    A layer whose recurrent state is small enough to lie WITH the page
+    (page_state_layers: the kinds the spec's class names in
+    `page_state_kinds`; models/lfm2.py, whose short convolutions keep
+    K-1 rows) is neither of the above: it has one more pool,
+    [num_pages, ...] (page_state_names, page_state_shape), indexed by
+    the stream's one page table, whose entry for a page holds the state
+    at that page's fill point. Such pools are allocated, forked, saved,
+    restored and evicted with the K/V page of the same number, so a
+    stream's state is still pages, and nothing per-slot exists.
+
+    head_pack (1: none) is how many K/V heads share one lane row of the
+    pool, for heads narrower than a lane row (two heads of 64:
+    pool_shape's last two axes are then [kv_heads / 2, 128], the same
+    bytes in the same order as [kv_heads, 64], which the TPU would lay
+    out in rows of 128 lanes half empty).
+
     block_tokens (0: none) is the block length of a model that
     generates by diffusion over blocks (models/sdar_moe.py): its
     attention is causal over blocks and bidirectional inside one, its
@@ -309,6 +338,8 @@ class DecodeSpec(object):
     """
 
     recurrent_kinds = ()
+    page_state_kinds = ()   # kinds whose recurrent rows lie by the page
+    head_pack = 1           # K/V heads that share a lane row of the pool
     expert_layers = ()      # the layers whose rows an expert op counts
     state_family = None     # names the gauge serving.<family>.state_bytes
     page_kind = 'kv'        # what a page of kv_layers holds
@@ -333,6 +364,8 @@ class DecodeSpec(object):
                               if k == 'sliding_attention']
         self.recurrent_layers = [i for i, k in enumerate(self.kinds)
                                  if k in self.recurrent_kinds]
+        self.page_state_layers = [i for i, k in enumerate(self.kinds)
+                                  if k in self.page_state_kinds]
         self.max_len, self.pos_len = max_len, pos_len
         self.dh = int(head_dim or dim // heads)
         self.emb_w, self.pos_w = emb_w, pos_w
@@ -371,7 +404,16 @@ class DecodeSpec(object):
         return self.kv_heads
 
     def pool_shape(self, num_pages, page_tokens):
-        return (num_pages, page_tokens, self.pool_heads, self.dh)
+        return (num_pages, page_tokens, self.pool_heads // self.head_pack,
+                self.dh * self.head_pack)
+
+    def page_state_names(self, layer=None):
+        """Pool var names of the layers that keep their recurrent rows
+        by the page (one pool a layer); shared by the paged pair."""
+        if layer is not None:
+            return ('page_state.layer%d' % layer,)
+        return [n for i in self.page_state_layers
+                for n in self.page_state_names(i)]
 
     @property
     def full_layers(self):
@@ -586,7 +628,10 @@ class PagedStep(object):
     ends [slots] (the position of each lane's last block row: every row
     of the block sees the lane's pages up to it), live [slots], and
     block_ids [slots, B] and transfer [slots] for the unmasking behind
-    the head. pools and states are {layer: its variables};
+    the head. pools, states and page_states (the pools of the layers
+    that keep their recurrent rows by the page, found through `table`
+    like the K/V pools; page_tokens beside them) are {layer: its
+    variables};
     stats collects what the expert layers counted. For a model with
     sliding layers, the second table: window_table ([1, W] or
     [slots, W]), window_positions (the same rows' positions counted
@@ -598,6 +643,7 @@ class PagedStep(object):
     length = last = cow = slot = reset = live = None
     ends = block_ids = transfer = None
     window_table = window_positions = window_cow = None
+    page_tokens = 0
 
     def __init__(self, decode, rows):
         self.decode, self.rows = decode, rows
@@ -622,6 +668,26 @@ def _state_io(at, layer, which):
     where = {'Live': [at.live]} if at.decode else \
         {'Slot': [at.slot], 'Len': [at.length], 'Reset': [at.reset]}
     return dict(where, State=[var]), {'StateOut': [var]}
+
+
+def _page_state_io(at, layer):
+    """(inputs, outputs, attrs) that make a stateful op read and write
+    `layer`'s rows BY THE PAGE, through the stream's page table (a
+    chunk's, or every lane's); nothing for the whole-sequence form. A
+    chunk copies the page it forks in this pool too, in front of the op
+    (the decode step's forks are the page copy program's)."""
+    if at is None:
+        return {}, {}, {}
+    pool = at.page_states[layer]
+    if not at.decode:
+        _block_op('kv_page_cow',
+                  inputs={'Pool': [pool], 'Src': [at.cow[0]],
+                          'Dst': [at.cow[1]]},
+                  outputs={'Out': [pool]})
+    where = {'Live': [at.live]} if at.decode else {'Len': [at.length]}
+    return (dict(where, Pool=[pool], Table=[at.table],
+                 Positions=[at.positions]),
+            {'PoolOut': [pool]}, {'page_tokens': int(at.page_tokens)})
 
 
 def _expert_io(at):
@@ -654,6 +720,15 @@ def _create_pool_vars(spec, num_pages, page_tokens, window_pages=0):
         for n in spec.pool_names(i)) for i in spec.kv_layers}
 
 
+def _create_page_state_vars(spec, num_pages):
+    """{layer: its page-state pool var} of the layers that keep their
+    recurrent rows by the page: persistable, donated and never
+    checkpointed, like the K/V pools."""
+    return {i: _persistable(spec.page_state_names(i)[0],
+                            spec.page_state_shape(num_pages))
+            for i in getattr(spec, 'page_state_layers', ())}
+
+
 def _create_state_vars(spec, slots):
     """{layer: (the recurrence's state, convolution rows)} of the
     recurrent layers: persistable, donated and updated in place like
@@ -673,6 +748,15 @@ def _pool_heads(x, spec):
     return L.pad(x, paddings=[0, 0, 0, 0, 0, extra, 0, 0])
 
 
+def _pool_rows(x, spec, t):
+    """K or V [B, t, pool_heads, dh] as the pool holds a token's row:
+    as it is, or `head_pack` heads side by side in one lane row."""
+    if spec.head_pack == 1:
+        return x
+    return L.reshape(x, shape=[-1, t, spec.pool_heads // spec.head_pack,
+                               spec.dh * spec.head_pack])
+
+
 def _model_heads(ctx, spec, t):
     """ctx [B, t, heads or pool_heads, dh] -> [B, t, heads * dh]: the
     model's heads."""
@@ -686,6 +770,10 @@ def _paged_gather(pool_var, table, spec):
     _block_op('kv_page_gather',
               inputs={'Pool': [pool_var], 'Table': [table]},
               outputs={'Out': [g]})                    # [B, J, H, dh]
+    if spec.head_pack > 1:      # the packed rows as heads again
+        g = L.reshape(g, shape=[-1, int(table.shape[1])
+                                * int(pool_var.shape[1]),
+                                spec.pool_heads, spec.dh])
     return sharding_constraint(L.transpose(g, perm=[0, 2, 1, 3]),
                                (None, _tp_ax(spec), None, None))
 
@@ -728,7 +816,8 @@ def _paged_prefill_attention(x, spec, blk, pool, at, pages, qk_norm=None,
     q4, k4, v4 = (_pool_heads(a, spec)
                   for a in _qkv_parts(x, spec, blk, chunk, qk_norm,
                                       rotary, head_norm))   # [1, C, H, dh]
-    for pool_var, new in ((pool[0], k4), (pool[1], v4)):
+    for pool_var, new in ((pool[0], _pool_rows(k4, spec, chunk)),
+                          (pool[1], _pool_rows(v4, spec, chunk))):
         _block_op('kv_page_cow',
                   inputs={'Pool': [pool_var], 'Src': [cow_src],
                           'Dst': [cow_dst]},
@@ -785,7 +874,8 @@ def _paged_decode_attention(x, spec, blk, pool, at, pages, qk_norm=None,
     q1, k1, v1 = (_pool_heads(a, spec)
                   for a in _qkv_parts(x, spec, blk, 1, qk_norm, rotary,
                                       head_norm))  # [S, 1, H | KVH, dh]
-    for pool_var, new in ((pool[0], k1), (pool[1], v1)):
+    for pool_var, new in ((pool[0], _pool_rows(k1, spec, 1)),
+                          (pool[1], _pool_rows(v1, spec, 1))):
         _block_op('kv_page_append',
                   inputs={'Pool': [pool_var], 'X': [new],
                           'Table': [table], 'Positions': [positions]},
@@ -917,6 +1007,8 @@ def _paged_fetches(spec, at, tokens, slots, num_pages, page_tokens,
     each lane's rows still masked."""
     at.pools = _create_pool_vars(spec, num_pages, page_tokens, window_pages)
     at.states = _create_state_vars(spec, slots)
+    at.page_states = _create_page_state_vars(spec, num_pages)
+    at.page_tokens = page_tokens
     logits = spec.paged_logits(tokens, at)
     masked = None
     if at.block_ids is not None:
@@ -1147,7 +1239,9 @@ def build_paged_block_program(spec, slots, num_pages, page_tokens,
 def build_page_copy_program(spec, slots, num_pages, page_tokens,
                             window_pages=0):
     """The copy a forking decode step runs in front of its program: one
-    kv_page_cow a pool of the pair, and nothing else.
+    kv_page_cow a pool of the pair (the K/V pools and, for a model
+    whose recurrent layers keep their rows by the page, those pools
+    too), and nothing else.
 
     Feeds:  page_copy_src / page_copy_dst [slots] int32 (a step forks at
             most one page a lane; (0, 0) for the pairs it does not use).
@@ -1184,6 +1278,12 @@ def build_page_copy_program(spec, slots, num_pages, page_tokens,
                                   'Dst': [dst]},
                           outputs={'Out': [pool]},
                           attrs={'page_rows': True})
+        # the rows a layer keeps by the page fork with it
+        for pool in _create_page_state_vars(spec, num_pages).values():
+            _block_op('kv_page_cow',
+                      inputs={'Pool': [pool], 'Src': [pair[0]],
+                              'Dst': [pair[1]]},
+                      outputs={'Out': [pool]})
     return prog, names
 
 
@@ -1266,6 +1366,7 @@ def build_verify_program(spec, slots, k1, num_pages, page_tokens,
     refuse_latent_pages(spec, 'the speculative verify program')
     refuse_window(spec, 'the speculative verify program')
     refuse_blocks(spec, 'the speculative verify program')
+    refuse_page_state(spec, 'the speculative verify program')
     prog, startup = Program(), Program()
     prog._is_test = True
     with program_guard(prog, startup):

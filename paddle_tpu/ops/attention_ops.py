@@ -355,7 +355,11 @@ def _paged_attention_emit(ctx, op):
     (pallas/paged_attention.supported), the Pallas kernel reads each
     lane's live pages out of the pool and nothing else; on a mesh it
     runs per shard of the heads axis, as flash_attention does.
-    Everywhere else the reference composition gathers the window."""
+    Everywhere else the reference composition gathers the window.
+    Pools whose rows are wider than Q's heads hold several K/V heads a
+    row (two heads of 64: [N, pt, KVH / 2, 128]): the kernel reads them
+    as pairs (pallas/paged_attention.paged_attention_d64), the
+    reference as the [N, pt, KVH, dh] they are."""
     from ..pallas import paged_attention as _pa
     from ..flags import get_flag
     q = ctx.get(op.single_input('Q'))
@@ -372,7 +376,25 @@ def _paged_attention_emit(ctx, op):
             or k_pool.shape[2] % mesh.shape[axis]:
         axis = None
     on_tpu = jax.default_backend() == 'tpu'
-    if _pa.supported(k_pool.shape[1], k_pool.shape[3]) and (
+    pack = k_pool.shape[3] // q.shape[3]
+    if pack > 1:
+        # heads narrower than a lane row lie `pack` to a row of the pool
+        # (DecodeSpec.head_pack; Q keeps the model's heads)
+        if window or (mesh is not None and mesh.size > 1):
+            raise NotImplementedError(
+                'paged attention over packed heads with a window or a mesh')
+        if pack == 2 and _pa.supported(k_pool.shape[1], q.shape[3]) and (
+                on_tpu or bool(get_flag('pallas_interpret'))):
+            out = _pa.paged_attention_d64(
+                q[:, 0], k_pool, v_pool,
+                jnp.clip(table, 0, k_pool.shape[0] - 1), positions,
+                sm_scale=sm_scale, interpret=not on_tpu)[:, None]
+        else:
+            heads = k_pool.shape[:2] + (k_pool.shape[2] * pack, q.shape[3])
+            out = _paged_attention_reference(
+                q, k_pool.reshape(heads), v_pool.reshape(heads), table,
+                positions, sm_scale, lambda x: x)
+    elif _pa.supported(k_pool.shape[1], k_pool.shape[3]) and (
             on_tpu or bool(get_flag('pallas_interpret'))):
         kernel = functools.partial(_pa.paged_attention, sm_scale=sm_scale,
                                    interpret=not on_tpu)

@@ -18,7 +18,10 @@ chunk form and its step form cannot drift apart in them.
 Recurrent state lives in scope variables that the paged programs
 update in place, as they do the K/V pools: the delta state
 [slots, H, dk, dv] and the convolution's last K-1 input rows
-[slots, K-1, C]. No op here has a gradient: `append_backward` over one
+[slots, K-1, C]; a model whose recurrent layers keep those rows alone
+(models/lfm2.py) keeps them BY THE PAGE, in a pool [pages, K-1, C] its
+streams reach through their page tables (`short_conv`'s paged forms).
+No op here has a gradient: `append_backward` over one
 of them raises an error that names it.
 """
 from __future__ import annotations
@@ -62,25 +65,72 @@ register_vjp_grad('rms_norm', in_slots=('X', 'Scale'), out_slots=('Y',))
 
 # -- short_conv -------------------------------------------------------------
 
-def _conv_rows(xx, w, n, bias=None):
-    """xx [..., n + K - 1, C], w [K, C] -> silu of the causal depthwise
-    convolution (plus `bias` [C], where the layer has one), [..., n, C]:
-    row t reads xx[t .. t + K - 1], the last of them the token's own."""
+_CONV_ACT = {'silu': jax.nn.silu, 'none': lambda v: v}
+
+
+def _conv_rows(xx, w, n, bias=None, act='silu'):
+    """xx [..., n + K - 1, C], w [K, C] -> `act` (silu, or none) of the
+    causal depthwise convolution (plus `bias` [C], where the layer has
+    one), [..., n, C]: row t reads xx[t .. t + K - 1], the last of them
+    the token's own."""
     k = w.shape[0]
     acc = sum(xx[..., j:j + n, :] * w[j] for j in range(k))
     if bias is not None:
         acc = acc + bias
-    return jax.nn.silu(acc)
+    return _CONV_ACT[act](acc)
+
+
+def _paged_conv_chunk(pool, x, w, bias, act, table, positions, n, pt):
+    """The paged chunk form: x [1, T, C] at positions[0].., of which n
+    rows are live -> (out [1, T, C], pool). The rows before the chunk
+    are those of the page that holds the token before its first (zeros
+    at position 0); every page the live rows touch takes the K-1 inputs
+    before its new fill point, the page's end or the chunk's."""
+    k, t = w.shape[0], x.shape[1]
+    table = table.reshape(-1)
+    start = positions[0]
+    last = table.shape[0] - 1
+    prev = jnp.where(start > 0,
+                     pool[table[jnp.clip((start - 1) // pt, 0, last)]], 0.0)
+    xx = jnp.concatenate([prev, x[0].astype(pool.dtype)], axis=0)
+    j = start // pt + jnp.arange(-(-t // pt) + 1, dtype=jnp.int32)
+    fill = jnp.minimum(start + n, (j + 1) * pt)
+    # rows fill - start .. of xx are the inputs fill - (K-1) .. fill - 1
+    at = jnp.clip(fill - start, 0, t)[:, None] + jnp.arange(k - 1)[None, :]
+    touched = (j * pt < start + n) & (j <= last)
+    page = jnp.where(touched, table[jnp.clip(j, 0, last)], pool.shape[0])
+    return (_conv_rows(xx, w, t, bias, act)[None],
+            pool.at[page].set(xx[at], mode='drop'))
+
+
+def _paged_conv_step(pool, x, w, bias, act, table, positions, live, pt):
+    """The paged step form: x [S, 1, C], one token a lane at positions
+    [S]. A lane reads the rows of the page that holds the token before
+    its own and leaves its new rows on the page its token lands on (the
+    same page, or the next: a lane that enters a page carries its rows
+    forward into it); a dead lane writes nothing."""
+    lanes = jnp.arange(table.shape[0], dtype=jnp.int32)
+    last = table.shape[1] - 1
+    prev = jnp.where(
+        (positions > 0)[:, None, None],
+        pool[table[lanes, jnp.clip((positions - 1) // pt, 0, last)]], 0.0)
+    xx = jnp.concatenate([prev, x.astype(pool.dtype)], axis=1)
+    page = jnp.where(live, table[lanes, jnp.clip(positions // pt, 0, last)],
+                     pool.shape[0])
+    return (_conv_rows(xx, w, 1, bias, act),
+            pool.at[page].set(xx[:, 1:], mode='drop'))
 
 
 @op_emitter('short_conv')
 def _short_conv_emit(ctx, op):
     """Causal depthwise convolution of kernel K over the sequence, then
-    silu. X [B, T, C], W [K, C], optionally Bias [C] (added before the
-    silu); row t is sum_j W[j] x[t - (K-1) + j]. Three forms, by the
-    inputs given:
+    attr `activation` ('silu' where not given; 'none': the LFM2 mixer,
+    whose convolution sits between two gates). X [B, T, C], W [K, C],
+    optionally Bias [C] (added before the activation); row t is sum_j
+    W[j] x[t - (K-1) + j]. Five forms, by the inputs given:
 
-    whole sequence  no State: zeros stand before each row of the batch.
+    whole sequence  no State, no Pool: zeros stand before each row of
+                    the batch.
     chunk           State [slots, K-1, C], Slot [1], Len [1], Reset [1],
                     X [1, T, C]: the rows before the chunk are the
                     slot's (zeros if Reset), and the slot's rows become
@@ -88,22 +138,46 @@ def _short_conv_emit(ctx, op):
                     leaves nothing behind.
     step            State, Live [S], X [S, 1, C]: every lane is its own
                     slot; lanes with Live 0 keep their rows.
+    paged chunk     Pool [pages, K-1, C], Table [1, P], Positions [T],
+                    Len [1], attr page_tokens, X [1, T, C]: the rows
+                    live BY THE PAGE, an entry the K-1 inputs before its
+                    page's fill point, found through the stream's page
+                    table as its K/V rows are (_paged_conv_chunk).
+    paged step      Pool, Table [S, P], Positions [S], Live [S], X
+                    [S, 1, C] (_paged_conv_step).
     """
     x = ctx.get(op.single_input('X'))
     w = ctx.get(op.single_input('W')).astype(x.dtype)
     k = w.shape[0]
     t = x.shape[1]
+    act = op.attr('activation', 'silu')
     bias = ctx.get(op.single_input('Bias')).astype(x.dtype) \
         if op.input('Bias') else None
+    if op.input('Pool'):
+        pool = ctx.get(op.single_input('Pool'))
+        table = ctx.get(op.single_input('Table')).astype(jnp.int32)
+        positions = ctx.get(op.single_input('Positions')).astype(jnp.int32)
+        pt = int(op.attr('page_tokens'))
+        if op.input('Live'):
+            live = ctx.get(op.single_input('Live')).astype(bool)
+            out, pool = _paged_conv_step(pool, x, w, bias, act, table,
+                                         positions, live, pt)
+        else:
+            n = ctx.get(op.single_input('Len')).astype(jnp.int32).reshape(())
+            out, pool = _paged_conv_chunk(pool, x, w, bias, act, table,
+                                          positions, n, pt)
+        ctx.set(op.single_output('Out'), out.astype(x.dtype))
+        ctx.set(op.single_output('PoolOut'), pool)
+        return
     if not op.input('State'):
         xx = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-        ctx.set(op.single_output('Out'), _conv_rows(xx, w, t, bias))
+        ctx.set(op.single_output('Out'), _conv_rows(xx, w, t, bias, act))
         return
     state = ctx.get(op.single_input('State'))
     if op.input('Live'):
         live = ctx.get(op.single_input('Live')).astype(bool)
         xx = jnp.concatenate([state, x.astype(state.dtype)], axis=1)
-        ctx.set(op.single_output('Out'), _conv_rows(xx, w, 1, bias))
+        ctx.set(op.single_output('Out'), _conv_rows(xx, w, 1, bias, act))
         ctx.set(op.single_output('StateOut'),
                 jnp.where(live[:, None, None], xx[:, 1:], state))
         return
@@ -112,7 +186,7 @@ def _short_conv_emit(ctx, op):
     reset = ctx.get(op.single_input('Reset')).astype(bool).reshape(())
     prev = jnp.where(reset, 0.0, state[slot])
     xx = jnp.concatenate([prev, x[0].astype(state.dtype)], axis=0)
-    ctx.set(op.single_output('Out'), _conv_rows(xx, w, t, bias)[None])
+    ctx.set(op.single_output('Out'), _conv_rows(xx, w, t, bias, act)[None])
     # rows [n, n + K - 1) of xx are inputs n - (K-1) .. n - 1
     tail = jax.lax.dynamic_slice_in_dim(xx, n, k - 1, axis=0)
     ctx.set(op.single_output('StateOut'), state.at[slot].set(tail))
@@ -126,6 +200,10 @@ def _short_conv_infer(op, block):
         state = block.var_recursive(op.single_input('State'))
         so = block.var_recursive(op.single_output('StateOut'))
         so.shape, so.dtype = state.shape, state.dtype
+    if op.output('PoolOut'):
+        pool = block.var_recursive(op.single_input('Pool'))
+        po = block.var_recursive(op.single_output('PoolOut'))
+        po.shape, po.dtype = pool.shape, pool.dtype
 
 
 # -- the gated delta rule ---------------------------------------------------

@@ -63,7 +63,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ['paged_attention', 'supported', 'paged_latent_attention',
-           'latent_supported']
+           'latent_supported', 'paged_attention_d64']
 
 _NEG_INF = -1e30
 # pages a block holds: 8 pages of 16 tokens x 16 heads x 128 floats are
@@ -87,8 +87,8 @@ def block_pages(pages_per_slot, page_tokens, kv_heads, head_dim, itemsize=4):
 
 def supported(page_tokens, head_dim):
     """Shapes the kernel tiles: a (token, head) row is a whole number
-    of lanes and a page a whole number of sublane tiles."""
-    return head_dim % 128 == 0 and page_tokens % 8 == 0
+    of lanes (or half a row: paged_attention_d64) and a page tiles."""
+    return head_dim % 128 in (0, 64) and page_tokens % 8 == 0
 
 
 def _kernel(table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
@@ -350,3 +350,41 @@ def paged_latent_attention(q, pool, table, positions, sm_scale, value_dim,
 # text holds its source lines, and a line that moves is another
 # compile-cache key for every cell's decode program.
 _NAMES = ('paged_attention', 'paged_window_attention')
+
+
+# -- heads of 64: two K/V heads a lane row ------------------------------------
+
+def paged_attention_d64(q, k_pool, v_pool, table, positions, sm_scale,
+                        interpret=False):
+    """paged_attention for heads of 64 values, half a lane row: q
+    [S, H, 64], pools [N, pt, KVH / 2, 128] (K/V heads 2m and 2m + 1 of a
+    token side by side in row m: the bytes of [N, pt, KVH, 64] in the
+    same order, which the TPU would otherwise lay out in rows of 128
+    lanes half empty and relay out around every scatter), table [S, P],
+    positions [S] -> [S, H, 64].
+
+    The kernel above runs as it stands, on pairs: a query head is
+    widened to a lane row with its 64 values in the half its K/V head
+    occupies and zeros in the other, so q . row is q . k of its own head
+    (the other head's lanes meet zeros); the H / (KVH / 2) query heads
+    of a PAIR are rows of the one product against pages that are read
+    once, as the H / KVH heads of one K/V head are at d 128; p . row
+    gives both heads' values side by side and each query head keeps its
+    half. The pool is read once and whole, no byte of it padded; the
+    products carry 128 lanes where 64 count, on a unit that waits for
+    the pages. Such a call carries the name `paged_attention_d64` in
+    the device's trace."""
+    S, H, dh = q.shape
+    pairs, row = k_pool.shape[2:]
+    if row != 2 * dh or H % (2 * pairs):
+        raise ValueError('%d query heads of %d over %d rows of %d'
+                         % (H, dh, pairs, row))
+    # which half of its pair's row a query head's K/V head occupies
+    upper = ((jnp.arange(H) // (H // (2 * pairs))) % 2 == 1)[None, :, None]
+    zeros = jnp.zeros_like(q)
+    wide = jnp.where(upper, jnp.concatenate([zeros, q], axis=-1),
+                     jnp.concatenate([q, zeros], axis=-1))
+    out = paged_attention(wide, k_pool, v_pool, table, positions,
+                          sm_scale=sm_scale, interpret=interpret,
+                          name='paged_attention_d64')
+    return jnp.where(upper, out[..., dh:], out[..., :dh])

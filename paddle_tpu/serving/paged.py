@@ -124,6 +124,28 @@ layer, [pages, page_tokens, row]) keeps nothing else for a stream, so
 everything here that moves pages (the prefix cache with copy-on-write,
 save_stream / restore_stream, export_prefix / install_prefix) serves
 it as it serves K/V pages: each takes its sizes from the pools.
+A model whose recurrent layers keep K-1 rows keeps them by the page
+(models/lfm2.py: spec.page_state_layers, gated short convolutions whose
+whole state is the last K-1 inputs, 16 KB a layer at the published
+widths against 64 KB of K/V a page). Beside the K/V pools lies one pool
+a such layer, [num_pages, K-1, C], indexed by the SAME page table:
+entry p holds the rows at page p's fill point, written by whatever chunk
+or step last touched the page (op short_conv's paged forms). A full
+page, or one registered as a tail, is frozen, so its entry is the state
+at exactly its boundary, and "a stream's state is pages" holds for this
+family as for the latent page: the pools are among the pair's
+`cache_names`, so allocation, copy-on-write (a chunk's kv_page_cow in
+its program, the page copy program in front of a forking step),
+save_stream / restore_stream, export_prefix / install_prefix and
+eviction move them with the page of the same number, sizes taken from
+the pools. Such a model has no `state_names`, so nothing of the
+paragraph above exists for it: no per-slot array, no reset flag, no
+snapshot rows, no adopt or snapshot program. open_stream asks the cache
+the plain `match`: the longest resident chain of whole pages plus a
+registered tail, whether or not a prompt ever ended there, which the
+snapshot design cannot give. Speculation's verify program and mesh
+serving refuse it by name (models/transformer.refuse_page_state: they
+know a page as K and V heads alone).
 
 Blocks. A model that generates by diffusion over blocks
 (models/sdar_moe.py: spec.block_tokens = B) has no one-token decode
@@ -191,6 +213,17 @@ serving.latent.cache_bytes (pages in use x the bytes a page's rows
 take over all layers) and the counter serving.latent.rows_read (rows
 the decode steps' attention read: each live lane's pos + 1, every
 layer; attr `latent_rows` of `paged.decode.tables`).
+For a model whose recurrent layers keep their rows by the page: the
+gauge serving.page_state.bytes (pages in use x the bytes a page's rows
+take over all such layers), the counters serving.page_state.streams_adopted
+(streams that opened on a cached page and so took their rows from it),
+serving.page_state.rows_chunk / .rows_step (rows the chunks and the
+steps wrote: page entries touched x K-1 x layers; attr `page_state_rows`
+of `paged.prefill.tables` / `paged.decode.tables`). For every model that
+asks the cache the plain `match`: the counter
+serving.prefix.offprompt_tokens and the attr `offprompt_tokens` of
+`paged.open` / `paged.prefix.match`, the reused tokens that came from a
+boundary of whole pages where no prompt had ended.
 For a model with sliding layers: the counter serving.window_pages_freed
 (pages their tables gave up behind the window), the gauges
 serving.window_pages_in_use (pages of the window pool handed out: open
@@ -310,6 +343,11 @@ _window_tail_miss = telemetry.counter('serving.prefix.window_tail_miss')
 _prefix_evictions = telemetry.counter('serving.prefix.evictions')
 _prefix_scanned = telemetry.counter('serving.prefix.entries_scanned')
 _prefix_evict_seconds = telemetry.counter('serving.prefix.evict_seconds')
+_page_state_bytes = telemetry.gauge('serving.page_state.bytes')
+_page_state_adopted = telemetry.counter('serving.page_state.streams_adopted')
+_page_state_rows_chunk = telemetry.counter('serving.page_state.rows_chunk')
+_page_state_rows_step = telemetry.counter('serving.page_state.rows_step')
+_prefix_offprompt = telemetry.counter('serving.prefix.offprompt_tokens')
 _block_passes = telemetry.counter('serving.block.passes')
 _block_commits = telemetry.counter('serving.block.commits')
 _block_masked_rows = telemetry.counter('serving.block.masked_rows')
@@ -448,11 +486,13 @@ class PagedDecodePredictor(object):
             self._mesh, self._mesh_shape = serving_mesh(mesh)
             if self._mesh is not None:
                 from ..models.transformer import (refuse_latent_pages,
+                                                  refuse_page_state,
                                                   refuse_window)
                 what = 'mesh serving (%s)' % self._mesh_shape
                 self._refuse_recurrent(what)
                 refuse_latent_pages(self._pair.spec, what)
                 refuse_window(self._pair.spec, what)
+                refuse_page_state(self._pair.spec, what)
                 self._refuse_blocks(what)
             self._pair.spec.mesh = self._mesh_shape
         if self.block_tokens and self.prefill_chunk % self.block_tokens:
@@ -744,6 +784,9 @@ class PagedDecodePredictor(object):
         if self._wpool is not None:
             _window_in_use.set(self._wpool.pages_in_use)
             _window_live.set(self._window_pages_live())
+        if self._page_state_rows:
+            _page_state_bytes.set(self._pool.pages_in_use
+                                  * self._page_state_page_bytes)
 
     # -- lifecycle ---------------------------------------------------------
     def _pin_weights(self):
@@ -894,6 +937,12 @@ class PagedDecodePredictor(object):
             for name, shape in zip(self._pair.snapshot_names, shapes):
                 self._scope.set_var(name, np.zeros(shape, np.float32))
             _snap_bytes.set(rows * self._snapshot_row_bytes())
+        # a model whose recurrent layers keep their rows by the page:
+        # the rows and the bytes a page's entries hold, all such layers
+        shapes = [spec.page_state_shape(1) for _ in spec.page_state_layers]
+        self._page_state_rows = int(sum(sh[1] for sh in shapes))
+        self._page_state_page_bytes = 4 * int(sum(
+            np.prod(sh) for sh in shapes))
         self._moe_queue = collections.deque()   # (decode?, counts [4])
         self._moe_lock = threading.Lock()
         self._moe_totals = {p + what: 0 for what in _MOE_COUNTS
@@ -977,7 +1026,10 @@ class PagedDecodePredictor(object):
         """Begin a stream on `slot`: match the prefix cache and adopt
         any shared pages (read-only, zero recompute). Allocates no new
         pages, so admission itself can never exhaust the pool. Returns
-        {'shared_tokens', 'chunks'} — the suffix prefill plan."""
+        {'shared_tokens', 'chunks'} — the suffix prefill plan — and
+        'offprompt_tokens': of the shared tokens, those that came from a
+        boundary of whole pages where no prompt had ended (0 for a
+        family that does not ask the cache the plain match)."""
         slot = int(slot)
         if not 0 <= slot < self.slots:
             raise ValueError('slot %r outside [0, %d)' % (slot, self.slots))
@@ -989,10 +1041,13 @@ class PagedDecodePredictor(object):
             raise ValueError('prompt length %d outside [1, %d] (max_len)'
                              % (len(prompt), self.max_len))
         with RecordEvent('paged.open', prompt_tokens=len(prompt)) as ev:
-            shared = ev.attrs['shared_tokens'] = self._open(slot, prompt)
+            shared, off = self._open(slot, prompt)
+            ev.attrs['shared_tokens'] = shared
+            if off is not None:
+                ev.attrs['offprompt_tokens'] = off
         chunk = self.prefill_chunk
         return {'slot': slot, 'prompt_tokens': len(prompt),
-                'shared_tokens': shared,
+                'shared_tokens': shared, 'offprompt_tokens': off or 0,
                 'chunks': -(-(self._prefilled(len(prompt)) - shared)
                             // chunk)}
 
@@ -1004,7 +1059,9 @@ class PagedDecodePredictor(object):
     def _open(self, slot, prompt):
         """open_stream's body: the stream's tables, the one question to
         the prefix cache its family asks, the adoption. Returns the
-        tokens it shares."""
+        tokens it shares and, where the question was the plain match,
+        those of them from a boundary where no prompt had ended (None
+        otherwise)."""
         table = PageTable(self._pool, self.pages_per_slot)
         # never pages without their state: a stream with recurrent
         # state opens on a boundary that has a snapshot (which comes
@@ -1023,12 +1080,18 @@ class PagedDecodePredictor(object):
         # first block's pass makes the stream's first logits
         limit = len(prompt) - (0 if self.block_tokens else 1)
         missed = self._prefix.window_tail_misses
+        before, off = self._prefix.offprompt_tokens, None
         got = [], 0
         if match is not None:
             with RecordEvent('paged.prefix.match',
                              pages=limit // self.page_tokens) as ev:
                 got = match(prompt, limit=limit)
                 ev.attrs['shared_tokens'] = got[1]
+                if match == self._prefix.match:
+                    # of them, those from a boundary no prompt ended at
+                    off = self._prefix.offprompt_tokens - before
+                    ev.attrs['offprompt_tokens'] = off
+                    _prefix_offprompt.inc(off)
         pages, shared, *rest = got
         snapshot = None
         if self._wpool is not None:
@@ -1047,11 +1110,14 @@ class PagedDecodePredictor(object):
             table.adopt_shared(pages, shared)
             _prefix_hits.inc()
             _prefix_tokens.inc(shared)
+            if self._page_state_rows:
+                # its recurrent rows came with the page it opened on
+                _page_state_adopted.inc()
         _prompt_tokens.inc(len(prompt))
         self._tables[slot] = table
         self._pending[slot] = _PendingPrefill(prompt, snapshot)
         self._update_gauges()
-        return shared
+        return shared, off
 
     def release(self, slot):
         """Drop a stream's page refs (cache-registered prefix pages
@@ -1356,6 +1422,11 @@ class PagedDecodePredictor(object):
                 # the rows the recurrence's chunk form really carries
                 ev.attrs['state_tokens'] = n
                 _state_chunk_tokens.inc(n)
+            if self._page_state_rows:
+                # every page the chunk touches takes its layers' rows
+                ev.attrs['page_state_rows'] = rows = self._page_state_rows \
+                    * ((start + n - 1) // pt - start // pt + 1)
+                _page_state_rows_chunk.inc(rows)
             if wtable is not None:
                 wfeed = np.zeros((1, wtable.width), np.int32)
                 wtable.row(wfeed[0])
@@ -1585,6 +1656,10 @@ class PagedDecodePredictor(object):
                 feed['decode_state_live'] = live_feed
                 ev.attrs['state_lanes'] = len(live)
                 _state_lanes.inc(len(live))
+            if self._page_state_rows:
+                ev.attrs['page_state_rows'] = rows = \
+                    self._page_state_rows * len(live)
+                _page_state_rows_step.inc(rows)
         if not self._copy_compiled:
             # a run over null pairs in front of the first decode step:
             # the copy program compiles where the decode program does

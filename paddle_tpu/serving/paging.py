@@ -43,7 +43,12 @@ values; everything stateful lives HERE, on the host, in plain Python:
                granularity. The cache holds its own +1 ref on every
                registered page so shared prefixes survive stream
                churn; entries are evicted leaf-first by LRU when the
-               pool runs dry. For a model with sliding layers an entry
+               pool runs dry. A page number may index more pools than
+               the K/V ones (a model whose recurrent layers keep their
+               rows by the page, models/lfm2.py): the cache neither
+               knows nor cares, a page it hands out carries whatever
+               every pool holds under its number. For a model with
+               sliding layers an entry
                may hold a page of the window pool beside its page of
                the full pool: what the registering stream still held,
                the last `window` tokens' pages. A boundary is handed
@@ -347,10 +352,12 @@ def chain_keys(tokens, page_tokens, limit=None):
 
 
 class _Node(object):
-    __slots__ = ('page', 'wpage', 'parent', 'children', 'tails', 'stamp')
+    __slots__ = ('page', 'wpage', 'parent', 'children', 'tails', 'stamp',
+                 'ended')
 
     def __init__(self, page, parent):
         self.page = page
+        self.ended = False       # a registered prompt ended on this page
         self.wpage = None        # its page of the window pool, if held
         self.parent = parent     # chain digest of the previous node
         self.children = 0
@@ -403,8 +410,9 @@ class PrefixCache(object):
     0..k and maps to the physical page holding page k's K/V. A prompt
     matches greedily along the chain; an optional partial TAIL entry
     (chain digest + the tail's exact tokens) shares the last,
-    partially filled page — the matcher picks the longest registered
-    tail that prefixes the prompt remainder. The cache owns one ref
+    partially filled page — where the prompt's resident chain ends the
+    matcher picks the longest registered tail that the prompt goes on
+    with, however far it goes on after it. The cache owns one ref
     per registered page; evict_one() drops the least-recently-used
     LEAF (no children, no tails) so interior chain pages are never
     orphaned while still reachable."""
@@ -437,6 +445,9 @@ class PrefixCache(object):
         self.hits = 0
         self.misses = 0
         self.tokens_reused = 0
+        # of them (match() alone counts it): tokens handed out at a
+        # boundary of whole pages on which no registered prompt ended
+        self.offprompt_tokens = 0
         # what the two evictions cost: entries their scans looked at,
         # and the calls that gave a ref up
         self.entries_scanned = 0
@@ -472,12 +483,18 @@ class PrefixCache(object):
                 return
             yield digest, node
 
-    def _longest_tail(self, chain, rest):
-        """The longest registered tail behind `chain` that prefixes the
-        tokens `rest`, or None."""
-        rest = tuple(int(t) for t in rest)
+    def _longest_tail(self, chain, prompt, at, limit):
+        """The longest registered tail behind `chain`, the resident run
+        of whole pages that ends at token `at`, that prompt[at:limit]
+        goes on with, however far it goes on after it; or None. A tail
+        is less than a page, so no more of the prompt is looked at."""
+        tails = self._tails.get(chain)
+        if not tails:
+            return None
+        rest = tuple(int(t) for t in prompt[at:min(
+            limit, at + self.pool.page_tokens - 1)])
         best = None
-        for tail_tokens, tail in self._tails.get(chain, {}).items():
+        for tail_tokens, tail in tails.items():
             if self.block and len(tail_tokens) % self.block:
                 continue        # no boundary of whole blocks
             if rest[:len(tail_tokens)] == tail_tokens and \
@@ -492,27 +509,34 @@ class PrefixCache(object):
         computed). Returns (pages, tokens): the physical pages to adopt
         (the last may be partial) and how many tokens they carry. The
         caller must adopt_shared() them promptly — match() itself takes
-        no refs."""
+        no refs. The match is the resident run of the chain of whole
+        pages and, where that run ends, the longest registered tail
+        whose tokens the prompt goes on with. Tokens handed out at a
+        boundary of whole pages on which no registered prompt ended add
+        to `offprompt_tokens`."""
         pt = self.pool.page_tokens
         limit = len(prompt) if limit is None else min(limit, len(prompt))
         if self.block:
             limit -= limit % self.block
         full = limit // pt
-        pages, chain = [], b''
+        pages, chain, ended = [], b'', True
         for chain, node in self._resident(prompt, full):
             self._touch(node)
             pages.append(node.page)
+            ended = node.ended
         k = len(pages)
         tokens = k * pt
-        if k == full:             # a tail only connects at chain end
-            best = self._longest_tail(chain, prompt[tokens:limit])
-            if best is not None:
-                self._touch(best)
-                pages.append(best.page)
-                tokens += len(best.tokens)
+        best = self._longest_tail(chain, prompt, tokens, limit)
+        if best is not None:
+            self._touch(best)
+            pages.append(best.page)
+            tokens += len(best.tokens)
+            ended = True
         if tokens:
             self.hits += 1
             self.tokens_reused += tokens
+            if not ended:
+                self.offprompt_tokens += tokens
         elif limit > 0:
             # a shareable prompt found nothing — the miss half of the
             # fleet_prefix_hit_rate metric (a 1-token prompt, limit 0,
@@ -521,7 +545,8 @@ class PrefixCache(object):
         return pages, tokens
 
     def match_window(self, prompt, limit=None):
-        """match() where a prefix is pages of two pools: the deepest
+        """match() where a prefix is pages of two pools (the same run
+        of whole pages and the same tail where it ends): the deepest
         boundary under `limit` whose last `window` tokens' pages are
         resident in the window pool too (a row at the boundary reads
         the positions boundary - window + 1 .. boundary - 1 of a
@@ -539,11 +564,10 @@ class PrefixCache(object):
         for chain, node in self._resident(prompt, full):
             nodes.append(node)
         entries, tokens = list(nodes), len(nodes) * pt
-        if len(nodes) == full:    # a tail only connects at chain end
-            best = self._longest_tail(chain, prompt[tokens:limit])
-            if best is not None:
-                entries.append(best)
-                tokens += len(best.tokens)
+        best = self._longest_tail(chain, prompt, tokens, limit)
+        if best is not None:
+            entries.append(best)
+            tokens += len(best.tokens)
         resident = bool(entries)
         # the run of entries with a window page that ends at each one
         run, runs = 0, []
@@ -658,6 +682,8 @@ class PrefixCache(object):
             self._touch(node)
             chain = nxt
         rest = tuple(int(t) for t in prompt[full * pt:])
+        if not rest and chain in self._nodes:
+            self._nodes[chain].ended = True
         if rest and full < len(table.pages):
             tails = self._tails.setdefault(chain, {})
             if rest not in tails:

@@ -110,7 +110,10 @@ class PagedDecodePair(object):
 
     @property
     def cache_names(self):
-        return self.spec.pool_names()
+        """Every pool a page number indexes: the K/V (or latent) pools
+        and, behind them, the pools of the layers that keep their
+        recurrent rows by the page."""
+        return self.spec.pool_names() + self.spec.page_state_names()
 
     @property
     def state_names(self):
@@ -129,7 +132,9 @@ class PagedDecodePair(object):
         return [(name, spec.pool_shape(
             self.window_num_pages if i in spec.window_layers
             else self.num_pages, self.page_tokens))
-            for i in spec.kv_layers for name in spec.pool_names(i)]
+            for i in spec.kv_layers for name in spec.pool_names(i)] + [
+            (name, spec.page_state_shape(self.num_pages))
+            for name in spec.page_state_names()]
 
     def keep_snapshots(self, rows):
         """Give the pair `rows` snapshot rows and the two programs that

@@ -23,7 +23,10 @@ snapshot rows; tests/serving_jaxprs_pr52.json), and the eighth (sdar_moe:
 generation by diffusion over blocks; its pair's second program is a
 block step, which `served` drives through block_step; with it the piece
 `paged_block_attention_4_rows`, the Pallas kernel as the block step's op
-calls it; tests/serving_jaxprs_pr57.json).
+calls it; tests/serving_jaxprs_pr57.json), and the ninth (lfm2: short
+convolutions whose rows lie by the page, K/V heads of 64 two a lane row;
+with it the piece `paged_attention_d64`, the kernel as the decode step's
+op calls it on pairs; tests/serving_jaxprs_pr60.json).
 """
 import hashlib
 import json
@@ -46,9 +49,9 @@ def _digest(text):
 
 
 def _models():
-    from paddle_tpu.models import (axk1, granite_h, hybrid, nemotron_h,
-                                   sdar_moe, smallthinker, solar_open2,
-                                   transformer)
+    from paddle_tpu.models import (axk1, granite_h, hybrid, lfm2,
+                                   nemotron_h, sdar_moe, smallthinker,
+                                   solar_open2, transformer)
     return {
         'gpt2': (transformer.language_model_logits,
                  transformer.TransformerConfig(
@@ -80,6 +83,9 @@ def _models():
                      sdar_moe.SdarMoeConfig(
                          vocab=64, dim=32, max_len=T, head_dim=8,
                          expert_offset=4, experts_held=8)),
+        'lfm2': (lfm2.language_model_logits, lfm2.Lfm2Config(
+            vocab=64, dim=32, max_len=T, head_dim=64,
+            layer_types=('conv', 'full_attention', 'conv'))),
     }
 
 
@@ -181,6 +187,11 @@ def pieces():
                 np.zeros((4, 128, 128), f4), np.zeros((40, 16, 4, 128), f4),
                 np.zeros((40, 16, 4, 128), f4), np.zeros((4, 8), np.int32),
                 np.zeros(4, np.int32))))
+    out['paged_attention_d64'] = _digest(str(jax.make_jaxpr(
+        lambda *a: pa.paged_attention_d64(*a, sm_scale=0.125))(
+            np.zeros((4, 32, 64), f4), np.zeros((40, 16, 4, 128), f4),
+            np.zeros((40, 16, 4, 128), f4), np.zeros((4, 8), np.int32),
+            np.zeros(4, np.int32))))
     return out
 
 

@@ -598,3 +598,51 @@ def test_page_copy_program_updates_the_donated_pools_in_place(v5e_2x2, cell):
     whole = 'f32[%s]' % ','.join(map(str, shape))
     assert not [line for line in compiled.as_text().splitlines()
                 if ' copy(' in line and whole in line.split(' copy(')[0]]
+
+
+def test_the_agent_loop_cell_s_new_pieces_compile_for_v5e(v5e_2x2):
+    """lfm2_serve_agentloop's shapes (64 lanes, 32 query heads of 64 on 8
+    K/V heads two a lane row, a table of 552 pages of 16 tokens over
+    12288; six conv pools [12288, 2, 2048]): the kernel of
+    pallas/paged_attention.py on pairs of heads, lowered by Mosaic under
+    the name paged_attention_d64 with nothing kept beside its arguments
+    but the widened query and its output; the paged short_conv step and
+    chunk, plain gathers and scatters that update the donated pool where
+    it lies (no copy of a pool, no padded layout)."""
+    import functools
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.ops import delta_rule_ops as dr
+    from paddle_tpu.pallas import paged_attention as pa
+    one = SingleDeviceSharding(v5e_2x2[0])
+
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    kv = arg(12288, 16, 4, 128)
+    compiled = jax.jit(functools.partial(
+        pa.paged_attention_d64, sm_scale=64 ** -0.5)).lower(
+            arg(64, 32, 64), kv, kv, arg(64, 552, dtype=jnp.int32),
+            arg(64, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert 'tpu_custom_call' in text and 'paged_attention_d64' in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+    pool, w = arg(12288, 2, 2048), arg(3, 2048)
+    nbytes = 4 * 12288 * 2 * 2048
+    step = jax.jit(
+        lambda pool, x, w, table, pos, live: dr._paged_conv_step(
+            pool, x, w, None, 'none', table, pos, live, 16),
+        donate_argnums=0).lower(
+            pool, arg(64, 1, 2048), w, arg(64, 552, dtype=jnp.int32),
+            arg(64, dtype=jnp.int32), arg(64, dtype=jnp.bool_)).compile()
+    chunk = jax.jit(
+        lambda pool, x, w, table, pos, n: dr._paged_conv_chunk(
+            pool, x, w, None, 'none', table, pos, n, 16),
+        donate_argnums=0).lower(
+            pool, arg(1, 256, 2048), w, arg(1, 552, dtype=jnp.int32),
+            arg(256, dtype=jnp.int32), arg(dtype=jnp.int32)).compile()
+    for compiled in (step, chunk):
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == nbytes
+        assert mem.temp_size_in_bytes < nbytes // 16

@@ -42,8 +42,9 @@ def loaded(tmp_path_factory):
 
 
 def test_the_tuple_names_every_block_file_written_for_serving():
-    assert sorted(FAMILIES) == ['axk1', 'granite_h', 'hybrid', 'nemotron_h',
-                                'sdar_moe', 'smallthinker', 'solar_open2']
+    assert sorted(FAMILIES) == ['axk1', 'granite_h', 'hybrid', 'lfm2',
+                                'nemotron_h', 'sdar_moe', 'smallthinker',
+                                'solar_open2']
     for name in FAMILIES:
         family = models.served_family(name)
         assert family.__name__ == 'paddle_tpu.models.' + name

@@ -30,6 +30,7 @@ RECORDED_PR47 = _recorded('serving_jaxprs_pr47.json')
 RECORDED_PR49 = _recorded('serving_jaxprs_pr49.json')
 RECORDED_PR52 = _recorded('serving_jaxprs_pr52.json')
 RECORDED_PR57 = _recorded('serving_jaxprs_pr57.json')
+RECORDED_PR60 = _recorded('serving_jaxprs_pr60.json')
 
 
 @pytest.mark.parametrize('name', ['gpt2', 'hybrid', 'nemotron_h', 'axk1'])
@@ -39,10 +40,12 @@ def test_served_with_no_snapshot_rows_a_model_traces_as_before(name):
 
 def test_the_shared_pieces_trace_as_before():
     got = serving_jaxprs.pieces()
-    new = {'paged_block_attention_4_rows'}      # PR 57's, held below
+    new = {'paged_block_attention_4_rows',      # PR 57's, held below
+           'paged_attention_d64'}               # PR 60's, held below
     assert {k: v for k, v in got.items() if k not in new} \
         == {k: RECORDED[k] for k in got if k not in new}
-    assert {k: got[k] for k in new} == {k: RECORDED_PR57[k] for k in new}
+    assert got['paged_block_attention_4_rows'] == \
+        RECORDED_PR57['paged_block_attention_4_rows']
 
 
 def test_the_fifth_family_traces_as_before():
@@ -94,3 +97,25 @@ def test_a_deployment_with_more_programs_traces_as_before(key):
 
 def test_the_two_records_agree_where_both_speak():
     assert {k: RECORDED_PR47[k] for k in RECORDED} == RECORDED
+
+
+def test_the_ninth_family_traces_as_recorded():
+    """lfm2 (short convolutions whose rows lie by the page: a prefill
+    chunk, the page copy with the conv pools' copies, the decode step;
+    no state copy program): the record of the PR that added it (PR 60),
+    and the kernel as its decode step calls it, on pairs of heads."""
+    assert serving_jaxprs.served('lfm2') == RECORDED_PR60['lfm2']
+    assert len(RECORDED_PR60['lfm2']) == 3
+    assert serving_jaxprs.pieces()['paged_attention_d64'] == \
+        RECORDED_PR60['paged_attention_d64']
+
+
+def test_the_older_families_trace_as_the_parent_of_the_ninth():
+    """PR 60's record was written on its finished tree and holds every
+    family: what it says of the eight older ones, their deployments and
+    the shared pieces is what PR 57's record says (`short_conv` with
+    silu, the kernel at whole lane rows and the plain `match` families'
+    programs did not change their text)."""
+    new = {'lfm2', 'paged_attention_d64'}
+    assert {k: v for k, v in RECORDED_PR60.items() if k not in new} \
+        == RECORDED_PR57

@@ -131,6 +131,37 @@ def test_match_hands_out_a_boundary_only_with_its_window_tail():
     assert pool.pages_in_use == 0
 
 
+@pytest.mark.parametrize('windowed', [False, True])
+@pytest.mark.parametrize('more', [1, 2, PT, 3 * PT + 1])
+def test_a_tail_connects_however_far_the_prompt_goes_on(windowed, more):
+    """One rule for match and match_window: where the resident run of
+    whole pages ends, the longest registered tail the prompt goes on
+    with, whether the follow-up adds a token or pages."""
+    pool, wpool = PagePool(60, PT), PagePool(20, PT)
+    cache = PrefixCache(pool, window_pool=wpool, window=WINDOW) \
+        if windowed else PrefixCache(pool)
+    parent = list(range(100, 130))              # 7 pages and 2 tokens
+    if windowed:
+        tables = _stream(pool, wpool, 30, cache, parent)[:2]
+    else:
+        table = PageTable(pool, 64)
+        table.ensure(30)
+        table.length = 30
+        cache.register(parent, table)
+        tables = [table]
+    for t in tables:
+        t.release()
+    follow = parent + list(range(1, more + 1))
+    got = cache.match_window(follow, len(follow) - 1) if windowed \
+        else cache.match(follow, len(follow) - 1)
+    assert got[1] == 30 and len(got[0]) == 8
+    # another continuation inside the tail's page does not take it
+    other = parent[:29] + [5] * (more + 1)
+    got = cache.match_window(other, len(other) - 1) if windowed \
+        else cache.match(other, len(other) - 1)
+    assert got[1] == 28
+
+
 def test_a_second_stream_gives_an_entry_the_window_page_it_lost():
     pool, wpool = PagePool(60, PT), PagePool(20, PT)
     cache = PrefixCache(pool, window_pool=wpool, window=WINDOW)
